@@ -277,13 +277,6 @@ class Daemon:
             self.dynamic_port = self._dynamic_server.sockets[0].getsockname()[1]
 
     async def close(self) -> None:
-        for server in (self._server, self._dynamic_server):
-            if server is not None:
-                server.close()
-                try:
-                    await server.wait_closed()
-                except Exception:
-                    pass
         for df in list(self.dataflows.values()):
             for t in df.timer_tasks:
                 t.cancel()
@@ -296,11 +289,23 @@ class Daemon:
             # checker.py processes in round 2). The graceful path
             # (stop_dataflow + grace kill) has already run by the time a
             # healthy dataflow gets here, so these are stragglers: kill.
+            # Before the servers close, not after: ``wait_closed`` waits
+            # for every open connection, and a node wedged in a device
+            # call (a timed-out dataflow's reason for being here) keeps
+            # its connection open for ever — the daemon then never
+            # reached this line and the node kept the chip.
             self._kill_stragglers(df)
             self._close_shmem_conns(df)
             for region in df.mapped_regions.values():
                 try:
                     region.close(unlink=False, force=True)
+                except Exception:
+                    pass
+        for server in (self._server, self._dynamic_server):
+            if server is not None:
+                server.close()
+                try:
+                    await asyncio.wait_for(server.wait_closed(), timeout=5)
                 except Exception:
                     pass
 

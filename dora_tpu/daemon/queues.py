@@ -147,8 +147,8 @@ class NodeEventQueue:
     #: (a queue_size=1 camera input still yields at most 1 per poll).
     #: Raised 4 -> 64 in round 6: at 4, a 1 KiB-message stream paid one
     #: node<->daemon round trip per 4 events, which capped the daemon
-    #: route at a fraction of its wire capacity (see BENCHMARKS.md
-    #: small-message axis).
+    #: route at a fraction of its wire capacity (the small-message
+    #: axis of bench.py).
     MAX_BATCH = 64
 
     async def next_batch(self) -> list[QueueEntry]:

@@ -1,6 +1,6 @@
 """Continuous batching: B concurrent decode streams on one weight pass.
 
-Round-4 gap (VERDICT r4 "what's weak" #1): the fused decode tier was
+Round-4 gap: the fused decode tier was
 batch-1 — the OpenAI server serialized concurrent requests through one
 stream. Batch-1 decode is HBM-bandwidth-bound: every token pays the full
 LM weight stream. The batched kernels (ops.decode_block.

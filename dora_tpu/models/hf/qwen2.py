@@ -18,13 +18,28 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dora_tpu import profiling
+from dora_tpu import backend, profiling
 from dora_tpu.models import layers as L
 from dora_tpu.models.hf.loader import (
     linear,
     maybe_bias,
     read_config,
     read_safetensors,
+)
+
+
+#: What the chip's compiler says to the three int8-KV paged kernels
+#: (tests/test_chip_compile.py holds the compiles as strict xfails). The
+#: engine refuses at construction rather than discover it mid-serve.
+KV_INT8_REFUSED = (
+    "DORA_KV_INT8 does not compile for the TPU yet: Mosaic refuses the "
+    "[P, KV, page] f32 scale planes of the paged attention kernels — "
+    "'Mosaic failed to compile TPU kernel: Slice shape along dimension 2 "
+    "must be aligned to tiling (128), but is 8' (16 for the chunk kernel). "
+    "The planes' minor dimension (the page, 16 rows) is narrower than a "
+    "128-lane tile, so even a whole-page slice is refused; they need a "
+    "lane-dense layout (KNOWN_ISSUES.md, ROADMAP speed item 5). Serve "
+    "with fp KV pages on the chip."
 )
 
 
@@ -234,18 +249,21 @@ def make_batch_engine(params, cfg: Qwen2Config, *, max_slots: int = 4,
         "batch engine needs quantize_decode params (DORA_INT8_DECODE / "
         "DORA_INT4_DECODE)"
     )
+    # params is an ARGUMENT of the jitted program, not a closure: a
+    # closed-over array lowers to a constant, which would bake the
+    # weights into every executable and compile-cache entry.
     step = jax.jit(
-        lambda tokens, caches, positions: fused_batch_step(
-            params, cfg, tokens, caches, positions
+        lambda p, tokens, caches, positions: fused_batch_step(
+            p, cfg, tokens, caches, positions
         ),
-        donate_argnums=(1,),
+        donate_argnums=(2,),
     )
     return BatchEngine(
         init_caches=lambda n: init_cache(cfg, n),
         prefill=lambda ids, true_len: prefill_padded(
             params, cfg, ids, true_len
         ),
-        batch_step=step,
+        batch_step=partial(step, params),
         max_slots=max_slots,
         max_seq=cfg.max_seq,
         eos=eos,
@@ -493,6 +511,8 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
     chunk = chunk or min(256, cfg.max_seq)
     if kv_int8 is None:
         kv_int8 = os.environ.get("DORA_KV_INT8", "0") != "0"
+    if kv_int8 and backend.on_tpu():
+        raise NotImplementedError(KV_INT8_REFUSED)
     if num_pages is None:
         num_pages = 4 * cfg.max_seq // page_size
         if kv_int8:
@@ -533,69 +553,48 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
             rank=int(rank_env) if rank_env else None,
         )
 
+    # Every program takes ``params`` as its FIRST ARGUMENT and the engine
+    # gets ``partial(program, params)``: a closed-over array lowers to a
+    # constant, which would bake gigabytes of weights into the window
+    # program, the chunk program and every autotune rung (compile time,
+    # HBM per executable and compile-cache entry size all scale with it).
+    # Pools are argument 2 of the jitted callable, hence the donation.
+    has_lora = lora_pool is not None
+
+    def with_lora(step_fn):
+        # (params, ids, pools, positions, bts[, adapters, stacks]) ->
+        # step_fn(..., lora=...) — one spelling for all three programs.
+        def step(p, ids, pools, positions, bts, *lora_args):
+            lora = None
+            if has_lora:
+                adapters, ls = lora_args
+                lora = (adapters, ls["a"], ls["b"])
+            return step_fn(p, cfg, ids, pools, positions, bts, lora=lora)
+        return step
+
     def window_factory(k, sk):
         # (k, spec) -> jitted window program; PagedBatchEngine caches
         # built programs so the autotuner's ladder compiles each rung
         # once per process.
         if sk:
-            if lora_pool is not None:
-                def spec_step(chunks, pools, positions, bts, adapters, ls):
-                    return fused_paged_spec_step(
-                        params, cfg, chunks, pools, positions, bts,
-                        lora=(adapters, ls["a"], ls["b"]),
-                    )
-            else:
-                def spec_step(chunks, pools, positions, bts):
-                    return fused_paged_spec_step(
-                        params, cfg, chunks, pools, positions, bts
-                    )
-            return jax.jit(
-                _vlm.make_paged_spec_window(
-                    spec_step,
-                    k=k,
-                    spec_k=sk,
-                    ngram=spec_ngram,
-                    eos=eos,
-                    lora=lora_pool is not None,
-                ),
-                donate_argnums=(1,),
-            )
-        if lora_pool is not None:
-            def batch_step(tokens, pools, positions, bts, adapters, ls):
-                return fused_paged_batch_step(
-                    params, cfg, tokens, pools, positions, bts,
-                    lora=(adapters, ls["a"], ls["b"]),
-                )
+            step = with_lora(fused_paged_spec_step)
+            make = partial(_vlm.make_paged_spec_window, k=k, spec_k=sk,
+                           ngram=spec_ngram, eos=eos, lora=has_lora)
         else:
-            def batch_step(tokens, pools, positions, bts):
-                return fused_paged_batch_step(
-                    params, cfg, tokens, pools, positions, bts
-                )
-        return jax.jit(
-            _vlm.make_paged_window(
-                batch_step, k=k, eos=eos, lora=lora_pool is not None,
-            ),
-            donate_argnums=(1,),
-        )
+            step = with_lora(fused_paged_batch_step)
+            make = partial(_vlm.make_paged_window, k=k, eos=eos,
+                           lora=has_lora)
+
+        def program(p, *args):
+            return make(partial(step, p))(*args)
+
+        return partial(jax.jit(program, donate_argnums=(2,)), params)
 
     window_fn = window_factory(window, spec_k)
-    if lora_pool is not None:
-        chunk_fn = jax.jit(
-            lambda ids, pools, position, bt, adapter, ls: (
-                fused_paged_chunk_step(
-                    params, cfg, ids, pools, position, bt,
-                    lora=(adapter, ls["a"], ls["b"]),
-                )
-            ),
-            donate_argnums=(1,),
-        )
-    else:
-        chunk_fn = jax.jit(
-            lambda ids, pools, position, bt: fused_paged_chunk_step(
-                params, cfg, ids, pools, position, bt
-            ),
-            donate_argnums=(1,),
-        )
+    chunk_fn = partial(
+        jax.jit(with_lora(fused_paged_chunk_step), donate_argnums=(2,)),
+        params,
+    )
     engine = PagedBatchEngine(
         init_pool=lambda n: init_page_pool(cfg, n, page_size,
                                            kv_int8=kv_int8),

@@ -15,9 +15,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from dora_tpu import backend
+
 
 def compute_dtype():
-    return jnp.bfloat16 if jax.default_backend() in ("tpu", "gpu") else jnp.float32
+    return backend.compute_dtype()
 
 
 # ---------------------------------------------------------------------------
@@ -72,7 +74,7 @@ def matmul(x, w):
     int8 bytes only; larger-M calls (prefill/training, MXU-bound)
     prefer the bf16 sidecar when the quantizer kept one. Int4 decode
     normally rides the fused kernel tier (ops.decode_block); this
-    fallback dequantizes on the fly for any path that lands here."""
+    path dequantizes on the fly for any call that lands here."""
     if isinstance(w, dict):
         m = math.prod(x.shape[:-1])
         if m > 32 and "bf16" in w:
@@ -81,6 +83,12 @@ def matmul(x, w):
             from dora_tpu.ops.int4 import dequantize_int4
 
             return x @ dequantize_int4(w, x.dtype)
+        if backend.partitioned_by_xla():
+            # Inside a program XLA partitions over a mesh the Pallas
+            # kernel is not an option (it cannot be partitioned
+            # automatically): the same int8 weights through plain XLA
+            # (per-output-channel scales commute with the matmul).
+            return (x @ w["int8"].astype(x.dtype)) * w["scale"].astype(x.dtype)
         from dora_tpu.ops.int8_matmul import int8_matmul
 
         return int8_matmul(x, w["int8"], w["scale"])
@@ -139,13 +147,18 @@ def use_flash() -> bool:
     dora_tpu.ops.flash_attention). Default ON on TPU (the kernel's VMEM
     use is flat in T, so it is safe at any length); elsewhere the Pallas
     interpreter would be slower than dense, so default OFF. Override
-    either way with DORA_FLASH_ATTENTION=1/0."""
+    either way with DORA_FLASH_ATTENTION=1/0. Never inside a program
+    XLA partitions over a mesh: the kernel cannot be partitioned
+    automatically, and the sharded paths (DORA_MESH) take dense
+    attention, which XLA shards by heads."""
     import os
 
+    if backend.partitioned_by_xla():
+        return False
     v = os.environ.get("DORA_FLASH_ATTENTION")
     if v is not None:
         return v not in ("", "0")
-    return jax.default_backend() == "tpu"
+    return backend.on_tpu()
 
 
 def causal_mask(tq: int, tk: int, offset: int = 0):

@@ -38,7 +38,7 @@ SPEC_HEADROOM = SPEC_K + 1  # single-pass slack; gates use spec_headroom()
 
 def body_passes() -> int:
     """Speculation passes fused into one while_loop body (DORA_SPEC_BODY,
-    default 4). Round-5 profiling (tools_r5/spec_profile.py) showed the
+    default 4). Round-5 profiling showed the
     whole worst-case floor gap is the while_loop losing the decode
     scan's unroll amortization: a fused chunk-5 pass costs the SAME as
     one un-unrolled single step (0.99x), while unroll=4 makes single
@@ -138,8 +138,8 @@ def run_loop(*, caches, history, hist_len, first, max_new_tokens: int,
         # Default OFF: measured on-chip the lax.cond dual-mode costs
         # ~1 ms/pass (the branch carries the KV pytree) — more than the
         # chunk/plain delta it saves; the fused M-row chunk verify is
-        # the mechanism that actually bounds the worst case
-        # (BENCHMARKS.md round-4 speculation matrix).
+        # the mechanism that actually bounds the worst case (round-4
+        # speculation matrix).
         adaptive = os.environ.get("DORA_SPEC_ADAPTIVE", "0") not in ("", "0")
     ppb = body_passes() if body is None else max(1, body)
     out = jnp.zeros((max_new_tokens + ppb * (k + 1),), jnp.int32)

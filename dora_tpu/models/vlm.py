@@ -974,7 +974,7 @@ def _generate_spec_jit(params, cfg: VLMConfig, images, prompt_ids,
             # chunk kernel streams the weights once for all rows), so a
             # verification pass costs ~one fused decode step and
             # speculation cannot meaningfully lose even at zero
-            # acceptance — see BENCHMARKS.md.
+            # acceptance.
             return decode_chunk_fused(
                 params, cfg, chunk, caches, cache_index
             )

@@ -2,8 +2,8 @@
 
 The reference routes every message through the daemon; the measured
 cost here is ~0.5-0.9 ms p50 per hop chain (sender control channel →
-daemon pump thread → asyncio routing → receiver event channel —
-BENCHMARKS.md "Known gap"). This module moves the data plane of
+daemon pump thread → asyncio routing → receiver event channel).
+This module moves the data plane of
 eligible edges onto direct shared-memory channels between the two node
 processes, keeping the daemon as the control plane:
 

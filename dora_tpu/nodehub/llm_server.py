@@ -919,7 +919,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     # windows mean finer admission boundaries and faster first tokens;
     # a saturated window (tokens/dispatch >= 3/4 of K) with no burn
     # steps K UP and resumes speculation — decode-heavy mixes drift
-    # toward K=16 (BENCHMARKS round 10). Hysteresis: a signal must hold
+    # toward K=16. Hysteresis: a signal must hold
     # for DORA_AUTOTUNE_HYSTERESIS consecutive intervals, and after a
     # retune the loop cools down as many intervals (change-rate cap:
     # at most one rung per hysteresis window). The loop never acts
@@ -1044,14 +1044,18 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     }
 
     def _finish_profile() -> None:
-        artifact = profiling.stop_capture(
-            profile_state["dir"], profile_state["start_error"]
-        )
+        artifact, error = "", None
+        try:
+            artifact = profiling.stop_capture(
+                profile_state["dir"], profile_state["start_error"]
+            )
+        except Exception as exc:  # on the chip a failed capture is said so
+            error = f"{type(exc).__name__}: {exc}"
         profile_state["active"] = False
         profile_state["start_error"] = None
-        tracer.instant("profile_stop", "(engine)", artifact)
+        tracer.instant("profile_stop", "(engine)", artifact or error)
         try:
-            node.report_profile(artifact, None)
+            node.report_profile(artifact, error)
         except Exception:
             pass  # capture is best-effort; serving never blocks on it
 
@@ -1070,7 +1074,16 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                 f"capture-{os.getpid()}-{int(time.time())}",
             )
             profile_state["dir"] = out_dir
-            profile_state["start_error"] = profiling.start_capture(out_dir)
+            try:
+                profile_state["start_error"] = profiling.start_capture(
+                    out_dir
+                )
+            except Exception as exc:  # on the chip: an error reply
+                try:
+                    node.report_profile("", f"{type(exc).__name__}: {exc}")
+                except Exception:
+                    pass
+                return
             profile_state["active"] = True
             profile_state["deadline"] = clock() + float(
                 md.get("seconds") or 0.0
@@ -1577,9 +1590,15 @@ def _stub_main() -> None:
 
 
 def main() -> None:
+    from dora_tpu import backend, telemetry
     from dora_tpu.metrics import ServingMetrics
     from dora_tpu.models.hf import qwen2
 
+    # The chip, or an explicit JAX_PLATFORMS=cpu — never a silent
+    # fallback; and the compile cache placed before the first jit.
+    backend.init_compile_cache()
+    backend.require_accelerator("llm_server")
+    telemetry.install_compile_listener()
     path = os.environ.get("DORA_HF_CHECKPOINT")
     if not path:
         if os.environ.get("DORA_STUB_ENGINE", "") not in ("", "0"):
@@ -1627,11 +1646,21 @@ def main() -> None:
     metrics = ServingMetrics(
         engine="paged" if hasattr(engine, "free_pages") else "dense"
     )
-    serve(
-        Node(), engine, metrics,
-        encode=encode, decode_one=decode_one, eos=eos,
-        max_new_cap=max_new_cap,
-    )
+    backend.report("engine_built", {
+        "engine": metrics.engine, "layers": cfg.layers, "dim": cfg.dim,
+        "memory": backend.memory_report(),
+    })
+    try:
+        serve(
+            Node(), engine, metrics,
+            encode=encode, decode_one=decode_one, eos=eos,
+            max_new_cap=max_new_cap,
+        )
+    finally:
+        backend.report("compiles", {
+            "count": telemetry.compile_count(),
+            "seconds": round(telemetry.compile_seconds(), 3),
+        })
 
 
 if __name__ == "__main__":

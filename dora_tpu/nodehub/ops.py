@@ -13,6 +13,7 @@ with ``DORA_CHECKPOINT`` (orbax directory, see dora_tpu.models.checkpoint).
 
 from __future__ import annotations
 
+import logging
 import os
 
 import jax
@@ -271,11 +272,35 @@ def make_vlm() -> JaxOperator:
             step=hf_step, init_state=params, sharding=_tp_sharding()
         )
 
+    from dora_tpu import backend
+    from dora_tpu.parallel import fused_tp as FTP
+    from dora_tpu.tpu.fuse import mesh_from_env
+
     cfg = vlm.VLMConfig.tiny() if _size() == "tiny" else vlm.VLMConfig.bench_2b()
     params = _maybe_restore(vlm.init_params(jax.random.PRNGKey(0), cfg), "vlm")
-    if os.environ.get("DORA_INT8_DECODE") or os.environ.get(
-        "DORA_INT4_DECODE"
+    mesh = mesh_from_env()
+    tp = FTP.tp_degree(mesh)
+    quantize = bool(
+        os.environ.get("DORA_INT8_DECODE") or os.environ.get("DORA_INT4_DECODE")
+    )
+    if quantize and mesh is not None and not FTP.tp_compatible(
+        tp, heads=cfg.heads, kv_heads=cfg.kv_heads, ffn=cfg.ffn,
+        vocab=cfg.vocab,
     ):
+        # The quantized layout exists for the Pallas kernels, and a
+        # kernel runs on a mesh only inside the tensor-parallel tier's
+        # shard_map (XLA cannot partition it). Where tp does not tile the
+        # model (tp=4 over the 2b shape's 2 KV heads) the mesh serves the
+        # float weights through plain XLA, sharded by tp_rules — said
+        # aloud, because it is a different tier with different speed.
+        logging.getLogger(__name__).warning(
+            "DORA_MESH with tp=%d has no tensor-parallel kernel tier for "
+            "this model (kv_heads=%d): DORA_INT8_DECODE/INT4 ignored, "
+            "serving float weights on the unfused XLA path",
+            tp, cfg.kv_heads,
+        )
+        quantize = False
+    if quantize:
         # Bandwidth lever: quantized LM weights, dequantized at the MXU
         # edge (ops.int8_matmul / ops.int4 — quantize_decode picks the
         # width from the env). Applied after cast/restore so the stored
@@ -294,53 +319,58 @@ def make_vlm() -> JaxOperator:
         batch_ok=prompt.shape[0] == 1,
     )
 
-    # Round-5 composition: on a DORA_MESH with tp>1 and a quantized
-    # fused layout, the decode scan rides the tensor-parallel KERNEL
-    # tier (parallel/fused_tp.py) instead of the unfused XLA path — the
-    # fastest path and the multi-chip path are the same path. The
-    # prepared tp tree lives in the closure (not operator state): the
-    # executor's sharding rules must not re-place its per-rank layout.
+    # On a DORA_MESH with tp>1 and a quantized fused layout, the decode
+    # scan rides the tensor-parallel KERNEL tier (parallel/fused_tp.py)
+    # instead of the unfused XLA path — the fastest path and the
+    # multi-chip path are the same path. The prepared tp tree rides in
+    # operator state beside the params (a closed-over array would lower
+    # to a constant and bake the weights into the program); it is
+    # already placed on the mesh, so the executor's sharding rules leave
+    # its per-rank layout alone (parallel/mesh.shard_params).
+    fused = vlm.fused_decode_ready(params, prompt.shape[0])
     tp_setup = None
-    if vlm.fused_decode_ready(params, prompt.shape[0]) and not speculative:
-        from dora_tpu.parallel import fused_tp as FTP
-        from dora_tpu.tpu.fuse import mesh_from_env
-
-        mesh = mesh_from_env()
-        tp = FTP.tp_degree(mesh)
-        if mesh is not None and FTP.tp_compatible(
-            tp, heads=cfg.heads, kv_heads=cfg.kv_heads, ffn=cfg.ffn,
-            vocab=cfg.vocab,
-        ):
-            try:
-                tp_setup = (
-                    FTP.prepare_decode_params(
-                        params, mesh, heads=cfg.heads,
-                        kv_heads=cfg.kv_heads, head_dim=cfg.head_dim,
-                        layers=cfg.layers,
-                    ),
-                    mesh,
-                )
-            except ValueError:  # int4 groups do not tile on this mesh
-                tp_setup = None
+    if fused and not speculative and mesh is not None:
+        try:
+            tp_setup = FTP.prepare_decode_params(
+                params, mesh, heads=cfg.heads, kv_heads=cfg.kv_heads,
+                head_dim=cfg.head_dim, layers=cfg.layers,
+            )
+        except ValueError:  # int4 groups do not tile on this mesh
+            tp_setup = None
+    # Say which tier serves — nobody should have to infer it from the
+    # frame rate.
+    tier = (
+        "speculative" if speculative
+        else "fused_tp" if tp_setup is not None
+        else "fused" if fused
+        else "unfused"
+    )
+    backend.report("vlm_tier", {
+        "tier": tier, "mesh": dict(mesh.shape) if mesh is not None else None,
+        "tp": tp, "fused_layout": bool(fused),
+    })
 
     def step(state, inputs):
         image = _normalize(inputs["image"])[None]
+        lm = state["lm"]
         if speculative:
             # Prompt-lookup speculation: identical greedy tokens, up to
             # k+1 per model pass (vlm.generate_speculative).
             tokens, _ = vlm.generate_speculative(
-                state, cfg, image, prompt, max_new
+                lm, cfg, image, prompt, max_new
             )
-        elif tp_setup is not None:
+        elif "tp" in state:
             tokens = vlm.generate_tp(
-                state, tp_setup[0], cfg, image, prompt, max_new,
-                tp_setup[1],
+                lm, state["tp"], cfg, image, prompt, max_new, mesh
             )
         else:
-            tokens = vlm.generate(state, cfg, image, prompt, max_new)
+            tokens = vlm.generate(lm, cfg, image, prompt, max_new)
         return state, {"tokens": tokens[0]}
 
-    return JaxOperator(step=step, init_state=params, sharding=_tp_sharding())
+    state = {"lm": params}
+    if tp_setup is not None:
+        state["tp"] = tp_setup
+    return JaxOperator(step=step, init_state=state, sharding=_tp_sharding())
 
 
 def make_asr() -> JaxOperator:

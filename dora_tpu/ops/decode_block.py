@@ -1,7 +1,7 @@
 """Fused batch-1 decode blocks as Pallas TPU kernels.
 
-Why: int8 vanilla decode sits at ~70% of its own HBM-bandwidth bound
-(BENCHMARKS.md). The residue is not the weight stream — it is the other
+Why: int8 vanilla decode sits at ~70% of its own HBM-bandwidth bound.
+The residue is not the weight stream — it is the other
 ~10 XLA ops per layer (norms, rope, cache update, attention, residuals)
 plus 4 Pallas launches per layer, each a fixed ~2.4 us entry and a break
 in DMA overlap. These kernels collapse one decode step to TWO Pallas
@@ -42,13 +42,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dora_tpu.ops import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from dora_tpu.backend import interpret as _interpret
 
 _LANE = 128
 
-
-def _interpret() -> bool:
-    return jax.default_backend() not in ("tpu",)
+#: Scoped-VMEM budget of the M-row chunk kernel. It keeps the whole chunk
+#: resident (fused qkv/o weights cast to bf16, [M*H, hd] f32 q/k/v and the
+#: [KV*M*group, M] in-chunk score tile): 17.2 MB at M=256 and the 1.5B
+#: widths, over the compiler's 16 MiB default scope. The v5e has 128 MiB
+#: of VMEM; half of it leaves the surrounding XLA fusions their room.
+_CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _rms(x_ref, w_ref, eps: float):
@@ -1613,6 +1616,7 @@ def attention_paged_chunk_step(
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT,
         ),
         interpret=_interpret(),
     )(

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dora_tpu.ops import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from dora_tpu.backend import interpret as _interpret
 
 BLOCK_Q = 128
 BLOCK_K = 256
@@ -165,7 +165,7 @@ def flash_attention(q, k, v, causal: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=jax.default_backend() not in ("tpu",),
+        interpret=_interpret(),
     )(q, k, v)
 
     out = out.reshape(b, h, t_pad, d_pad)

@@ -1,7 +1,7 @@
 """Int4 weight quantization for the fused decode tier.
 
 Batch-1 decode is HBM-bandwidth-bound; int8 weights reach 84% of their
-own bound (BENCHMARKS.md), so the next factor-of-two lives in the
+own bound (round 4, chip), so the next factor-of-two lives in the
 weight bytes themselves. Here weights pack two 4-bit values per byte
 with **group-wise scales** (one f32 scale per 128 input rows per output
 column — per-channel scales are too coarse at 4 bits to serve real

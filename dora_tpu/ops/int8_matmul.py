@@ -5,7 +5,7 @@ full LM weight set from HBM once, so tokens/s is capped at
 ``peak_bandwidth / weight_bytes``. Storing weights in int8 halves the
 bytes vs bf16 — but only if the dequantize happens *at the MXU edge*:
 a naive ``(q * scale).astype(bf16)`` materializes the full bf16 weight
-in HBM first and wins nothing (measured, BENCHMARKS.md round 2). This
+in HBM first and wins nothing (measured, round 2). This
 kernel streams int8 blocks HBM→VMEM, converts to the compute dtype
 in-register, runs the MXU dot, and applies the per-output-channel scale
 once on the f32 accumulator — HBM traffic is the int8 bytes, nothing
@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dora_tpu.ops import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from dora_tpu.backend import interpret as _interpret
 
 _SUBLANE = 16  # bf16 sublane; f32's 8 divides it
 _LANE = 128
@@ -190,7 +190,7 @@ def int8_matmul(x, q, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=jax.default_backend() not in ("tpu",),
+        interpret=_interpret(),
     )(x2, q, scale)
 
     return out[:m, :n].reshape(*lead, n)

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dora_tpu.ops import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from dora_tpu.backend import interpret as _interpret
 
 _LANE = 128
 
@@ -52,11 +52,11 @@ def _kernel(g_ref, x_ref, a_ref, b_ref, o_ref):
     # g_ref is consumed by the BlockSpec index maps (scalar prefetch);
     # the body sees the row's own pre-gathered A/B slabs.
     del g_ref
-    x = x_ref[...].astype(jnp.float32)  # [1, D]
+    x = x_ref[0].astype(jnp.float32)  # [1, D]
     a = a_ref[0].astype(jnp.float32)  # [D, r]
     t = jax.lax.dot(x, a, preferred_element_type=jnp.float32)  # [1, r]
     b = b_ref[0].astype(jnp.float32)  # [r, N]
-    o_ref[...] = jax.lax.dot(
+    o_ref[0] = jax.lax.dot(
         t, b, preferred_element_type=jnp.float32
     ).astype(o_ref.dtype)
 
@@ -87,23 +87,26 @@ def lora_gather_matmul(x, groups, a_stack, b_stack):
     if (r_pad, n_pad) != (rank, n):
         b2 = jnp.pad(b2, ((0, 0), (0, r_pad - rank), (0, n_pad - n)))
 
+    # One row per grid step. x/out ride as [R, 1, D] so the row block's
+    # last two dims (1, D) equal the array's: Mosaic refuses a (1, D)
+    # block of an [R, D] array (second-minor must be 8-aligned or full).
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(r_rows,),
         in_specs=[
-            pl.BlockSpec((1, d_pad), lambda i, g: (i, 0)),
+            pl.BlockSpec((1, 1, d_pad), lambda i, g: (i, 0, 0)),
             pl.BlockSpec((1, d_pad, r_pad), lambda i, g: (g[i], 0, 0)),
             pl.BlockSpec((1, r_pad, n_pad), lambda i, g: (g[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, n_pad), lambda i, g: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, n_pad), lambda i, g: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r_rows, n_pad), x.dtype),
-        interpret=jax.default_backend() not in ("tpu",),
-    )(groups.astype(jnp.int32), x2, a2, b2)
-    return out[:, :n]
+        out_shape=jax.ShapeDtypeStruct((r_rows, 1, n_pad), x.dtype),
+        interpret=_interpret(),
+    )(groups.astype(jnp.int32), x2[:, None, :], a2, b2)
+    return out[:, 0, :n]
 
 
 def lora_gather_matmul_ref(x, groups, a_stack, b_stack):

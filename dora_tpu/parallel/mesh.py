@@ -55,7 +55,10 @@ def shard_params(params: Any, mesh, rules) -> Any:
     default is full replication. A matched leaf whose dimension does not
     divide the mesh axis falls back to replication instead of crashing —
     real checkpoint shapes (odd vocab sizes, 196-patch position tables)
-    must serve on any mesh.
+    must serve on any mesh. A leaf that already sits on THIS mesh keeps
+    its placement: a tree prepared for a per-rank kernel layout
+    (parallel/fused_tp.prepare_decode_params) rides in operator state
+    untouched.
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
@@ -76,6 +79,9 @@ def shard_params(params: Any, mesh, rules) -> Any:
         return True
 
     def place(path, leaf):
+        placed = getattr(leaf, "sharding", None)
+        if isinstance(placed, NamedSharding) and placed.mesh == mesh:
+            return leaf
         name = str(getattr(path[-1], "key", path[-1])) if path else ""
         for match, spec in rules:
             if name == match:

@@ -20,9 +20,8 @@ Three tiers, cheapest first:
 
 3. **Deep capture** — :func:`start_capture` / :func:`stop_capture` wrap
    ``jax.profiler`` behind the control plane's StartProfile/StopProfile
-   messages. A backend without a working profiler still produces an
-   artifact (a synthetic marker file) so the control-plane reply always
-   carries a path.
+   messages. On the chip a profiler that fails is an error reply; only
+   the CPU-for-tests mode substitutes a synthetic marker file.
 
 The FLOPs model is deliberately analytic (config arithmetic, no device
 introspection): it is hand-checkable in tests and identical on CPU stub
@@ -40,6 +39,8 @@ from __future__ import annotations
 import json
 import os
 import time
+
+from dora_tpu import backend
 
 
 def monitor_enabled() -> bool:
@@ -118,26 +119,26 @@ _PEAK_FLOPS_BY_KIND = (
 
 def detect_peak_flops(device=None) -> float:
     """Peak FLOP/s for the device driving MFU's denominator.
-    ``DORA_DEVICE_PEAK_FLOPS`` wins; else the device-kind table; else 0.0
-    (MFU renders as a dash rather than a fabricated number)."""
+    ``DORA_DEVICE_PEAK_FLOPS`` wins; else the device-kind table. On the
+    chip a device the table does not know is an error, not a default
+    (a utilization against a made-up peak is worse than none); in the
+    CPU-for-tests mode it is 0.0 and MFU renders as a dash."""
     raw = os.environ.get("DORA_DEVICE_PEAK_FLOPS", "")
     if raw:
-        try:
-            return float(raw)
-        except ValueError:
-            pass
-    kind = ""
-    try:
-        if device is None:
-            import jax
+        return float(raw)
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", "")).lower()
-    except Exception:
-        return 0.0
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
     for needle, peak in _PEAK_FLOPS_BY_KIND:
-        if needle in kind:
+        if needle in kind.lower():
             return peak
+    if backend.on_tpu():
+        raise LookupError(
+            f"no peak FLOP/s known for device kind {kind!r}: add it to "
+            "profiling._PEAK_FLOPS_BY_KIND or set DORA_DEVICE_PEAK_FLOPS"
+        )
     return 0.0
 
 
@@ -200,47 +201,55 @@ def profile_dir() -> str:
 
 
 def start_capture(out_dir: str) -> str | None:
-    """Start a ``jax.profiler`` trace into ``out_dir``. Returns an error
-    string when the backend's profiler cannot start (the caller falls
-    back to a synthetic artifact at stop time), else None."""
+    """Start a ``jax.profiler`` trace into ``out_dir``. On the chip a
+    profiler that cannot start raises — the caller turns that into an
+    error reply. In the CPU-for-tests mode (no profiler plugin in the
+    container) it returns the error string instead, and
+    :func:`stop_capture` writes a synthetic marker so the control plane
+    stays testable end to end."""
+    import jax
+
     os.makedirs(out_dir, exist_ok=True)
     try:
-        import jax
-
         jax.profiler.start_trace(out_dir)
         return None
     except Exception as exc:  # no profiler plugin / already active
+        if backend.on_tpu():
+            raise
         return f"{type(exc).__name__}: {exc}"
 
 
 def stop_capture(out_dir: str, start_error: str | None = None) -> str:
-    """Stop the capture and return the artifact path (always a real
-    path). If the profiler never started or stop fails — CPU-only
-    containers without the profiler plugin are the common case — a
-    synthetic JSON marker is written instead so the control-plane reply
-    and the e2e tests have a concrete artifact either way."""
+    """Stop the capture and return the artifact path. On the chip a
+    capture that fails to stop or left no file raises; the CPU-for-tests
+    mode writes ``profile_synthetic.json`` (marked ``"synthetic": true``,
+    with the reason) in its place."""
+    import jax
+
     error = start_error
     if error is None:
         try:
-            import jax
-
             jax.profiler.stop_trace()
         except Exception as exc:
+            if backend.on_tpu():
+                raise
             error = f"{type(exc).__name__}: {exc}"
+    if error is None and _has_capture_files(out_dir):
+        return out_dir
+    if backend.on_tpu():
+        raise RuntimeError(f"profiler left no artifact under {out_dir}")
     os.makedirs(out_dir, exist_ok=True)
-    if error is not None or not _has_capture_files(out_dir):
-        marker = os.path.join(out_dir, "profile_synthetic.json")
-        with open(marker, "w") as f:
-            json.dump(
-                {
-                    "synthetic": True,
-                    "reason": error or "profiler produced no artifact",
-                    "unix_time": time.time(),
-                },
-                f,
-            )
-        return marker
-    return out_dir
+    marker = os.path.join(out_dir, "profile_synthetic.json")
+    with open(marker, "w") as f:
+        json.dump(
+            {
+                "synthetic": True,
+                "reason": error or "profiler produced no artifact",
+                "unix_time": time.time(),
+            },
+            f,
+        )
+    return marker
 
 
 def _has_capture_files(out_dir: str) -> bool:

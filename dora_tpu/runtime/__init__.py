@@ -138,9 +138,15 @@ def run() -> int:
         # the DORA_JAX_* contract, this runtime joins the global mesh
         # (one runtime node per TPU host) before any operator loads, so
         # DORA_MESH sharding spans hosts — ICI within a slice, DCN across.
+        from dora_tpu import backend
         from dora_tpu.parallel.distributed import maybe_init_distributed
 
         maybe_init_distributed()
+        # The chip or an explicit JAX_PLATFORMS=cpu — never a silent
+        # fallback (dora_tpu/backend.py); and the compile cache placed
+        # before the first operator jits.
+        backend.init_compile_cache()
+        backend.require_accelerator(f"runtime node {config.node_id}")
     for op in me.kind.operators:
         if isinstance(op.source, WasmSource):
             # Reference parity: declared, not runnable

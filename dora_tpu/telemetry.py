@@ -370,7 +370,7 @@ class ServingTracer:
 # ---------------------------------------------------------------------------
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_state = {"count": 0, "installed": False}
+_compile_state = {"count": 0, "seconds": 0.0, "installed": False}
 
 
 def install_compile_listener() -> bool:
@@ -385,19 +385,18 @@ def install_compile_listener() -> bool:
     counter that ``ServingMetrics`` ships to ``dora-tpu metrics`` — a
     nonzero delta while serving steady traffic IS the regression.
 
-    Idempotent; returns False when jax's monitoring hook is
-    unavailable (no jax, or an incompatible internal API)."""
+    Idempotent. Reaches into ``jax._src.monitoring`` (there is no
+    public hook); it works on the installed JAX, and a JAX that moves it
+    fails here loudly rather than serving with the guard silently off."""
     if _compile_state["installed"]:
         return True
-    try:
-        from jax._src import monitoring
-    except Exception:
-        return False
+    from jax._src import monitoring
 
     def _on_duration(event: str, duration: float, **kwargs) -> None:
         if event != _COMPILE_EVENT:
             return
         _compile_state["count"] += 1
+        _compile_state["seconds"] += duration
         FLIGHT.record(
             "xla_compile",
             str(kwargs.get("fun_name", "") or "backend_compile"),
@@ -405,12 +404,15 @@ def install_compile_listener() -> bool:
             int(duration * 1e9),
         )
 
-    try:
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        return False
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _compile_state["installed"] = True
     return True
+
+
+def compile_seconds() -> float:
+    """Seconds inside XLA backend compiles (or persistent-cache
+    retrievals standing in for them) since the listener was installed."""
+    return _compile_state["seconds"]
 
 
 def compile_count() -> int:

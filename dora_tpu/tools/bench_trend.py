@@ -18,7 +18,7 @@ different state) are not comparable, so every appended record carries:
 A watched metric that is >10% worse than the previous fingerprint-matched
 run (with calibration within 20%) is reported in ``regressions`` — the
 caller prints them and ships them inside the bench JSON line; the history
-file is the long-term record BENCHMARKS.md rounds are written from.
+file is the long-term record.
 """
 
 from __future__ import annotations

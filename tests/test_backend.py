@@ -1,0 +1,276 @@
+"""The rules of dora_tpu/backend.py and what chip_smoke.py leans on:
+one decision about the device, no silent fallback, a compile cache placed
+from outside, weights passed to the engine's programs as arguments, and a
+smoke-test parent that never touches JAX."""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dora_tpu import backend
+
+ROOT = Path(__file__).resolve().parent.parent
+
+
+def _run(code: str, **env) -> subprocess.CompletedProcess:
+    full = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    full.update(env)
+    for k in [k for k, v in full.items() if v is None]:
+        del full[k]
+    return subprocess.run(
+        [sys.executable, "-c", code], env=full, cwd=ROOT,
+        capture_output=True, text=True, timeout=120,
+    )
+
+
+# -- one decision, in one place ---------------------------------------------
+
+
+def test_on_tpu_steers_dtype_and_interpret(monkeypatch):
+    from dora_tpu.models import layers
+
+    assert backend.interpret() and layers.compute_dtype() == jnp.float32
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    assert not backend.interpret()
+    assert layers.compute_dtype() == jnp.bfloat16
+    assert layers.use_flash() is True
+
+
+def test_no_module_asks_the_backend_for_itself():
+    """Only backend.py may call jax.default_backend(): a second caller is
+    a second decision, and the compile tests could no longer steer it."""
+    offenders = [
+        str(p.relative_to(ROOT))
+        for p in (ROOT / "dora_tpu").rglob("*.py")
+        if p.name != "backend.py" and "tools" not in p.parts
+        and "default_backend()" in p.read_text()
+    ]
+    assert offenders == []
+
+
+# -- no fallback that hides the device --------------------------------------
+
+
+def test_cpu_is_allowed_only_on_purpose():
+    ok = _run(
+        "from dora_tpu import backend; print(backend.require_accelerator('t'))",
+        JAX_PLATFORMS="cpu",
+    )
+    assert ok.returncode == 0, ok.stderr
+    assert "'platform': 'cpu'" in ok.stdout
+    # the once-per-process device line names dtype and interpret flag
+    line = re.search(r"dora_tpu\.backend device: (\{.*\})", ok.stderr)
+    assert json.loads(line.group(1))["pallas_interpret"] is True
+
+
+@pytest.mark.parametrize("entry", [
+    "from dora_tpu import backend; backend.require_accelerator('t')",
+    "from dora_tpu.nodehub import llm_server; llm_server.main()",
+], ids=["backend", "llm_server"])
+def test_without_a_chip_and_without_saying_cpu_it_fails(entry):
+    # JAX_PLATFORMS unset: JAX falls back to the CPU here by itself, and
+    # the program must refuse that, naming the backend it found.
+    bad = _run(entry, JAX_PLATFORMS=None, DORA_STUB_ENGINE="1")
+    assert bad.returncode != 0
+    assert "JAX backend is 'cpu'" in bad.stderr, bad.stderr[-2000:]
+
+
+def test_int8_kv_refuses_at_construction_on_tpu(monkeypatch):
+    from dora_tpu.models.hf import qwen2
+
+    cfg, params = _tiny_model()
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    with pytest.raises(NotImplementedError, match="aligned to tiling"):
+        qwen2.make_paged_engine(params, cfg, kv_int8=True)
+
+
+def test_peak_flops_knows_the_v5e_and_refuses_the_unknown(monkeypatch):
+    from dora_tpu import profiling
+
+    class Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.delenv("DORA_DEVICE_PEAK_FLOPS", raising=False)
+    assert profiling.detect_peak_flops(Dev("TPU v5 lite")) == 197e12
+    assert profiling.detect_peak_flops(Dev("cpu")) == 0.0  # explicit CPU mode
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    with pytest.raises(LookupError, match="TPU v9"):
+        profiling.detect_peak_flops(Dev("TPU v9"))
+
+
+def test_profiler_that_cannot_start_is_an_error_on_tpu(monkeypatch, tmp_path):
+    from dora_tpu import profiling
+
+    def boom(_dir):
+        raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", boom)
+    # CPU-for-tests: the synthetic marker keeps the control plane testable.
+    err = profiling.start_capture(str(tmp_path / "a"))
+    assert "no profiler here" in err
+    assert profiling.stop_capture(str(tmp_path / "a"), err).endswith(
+        "profile_synthetic.json"
+    )
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="no profiler here"):
+        profiling.start_capture(str(tmp_path / "b"))
+
+
+# -- compile cache placed from outside --------------------------------------
+
+_CACHE_PROBE = (
+    "import jax, jax.numpy as jnp\n"
+    "from dora_tpu import backend\n"
+    "where = backend.init_compile_cache()\n"
+    "jax.jit(lambda x: x * 2 + 1)(jnp.ones((8, 8))).block_until_ready()\n"
+    "print(where, '|', jax.config.jax_compilation_cache_dir)\n"
+)
+
+
+def test_compile_cache_goes_where_the_environment_says(tmp_path):
+    placed = tmp_path / "placed"
+    out = _run(_CACHE_PROBE, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(placed))
+    assert out.returncode == 0, out.stderr
+    where, configured = out.stdout.strip().split(" | ")
+    assert where == configured == str(placed)  # nothing else set in code
+    assert any(placed.iterdir()), "the compiling process wrote no entry"
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch):
+    assert backend.COMPILE_CACHE_DIR == ROOT / ".jax_cache"
+    assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
+    # The CPU-for-tests mode caches only where it is told to.
+    out = _run(_CACHE_PROBE, JAX_PLATFORMS="cpu")
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "None | None"
+    # On the chip (steered) the default is the fixed path.
+    seen = {}
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: seen.__setitem__(k, v)
+    )
+    assert backend.init_compile_cache() == str(ROOT / ".jax_cache")
+    assert seen["jax_compilation_cache_dir"] == str(ROOT / ".jax_cache")
+
+
+# -- weights are arguments, not constants -----------------------------------
+
+
+def _tiny_model():
+    from dora_tpu.models.hf import qwen2
+
+    cfg = qwen2.Qwen2Config(
+        vocab=256, dim=64, layers=2, heads=4, kv_heads=2, ffn=128,
+        rope_theta=10000.0, norm_eps=1e-6, tie_embeddings=True, max_seq=32,
+    )
+    rng = np.random.default_rng(0)
+    r = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.05, jnp.float32)
+    kv = cfg.kv_heads * cfg.head_dim
+    params = {
+        "embed": r(cfg.vocab, cfg.dim), "out_norm": jnp.ones((cfg.dim,)),
+        "blocks": {
+            str(i): {
+                "attn_norm": jnp.ones((cfg.dim,)), "ffn_norm": jnp.ones((cfg.dim,)),
+                "wq": r(cfg.dim, cfg.dim), "wk": r(cfg.dim, kv),
+                "wv": r(cfg.dim, kv), "wo": r(cfg.dim, cfg.dim),
+                "w_gate": r(cfg.dim, cfg.ffn), "w_up": r(cfg.dim, cfg.ffn),
+                "w_down": r(cfg.ffn, cfg.dim),
+            }
+            for i in range(cfg.layers)
+        },
+    }
+    return cfg, qwen2.quantize_decode(params, cfg)
+
+
+def _largest_constant(lowered_text: str) -> int:
+    biggest = 0
+    for m in re.finditer(r"stablehlo\.constant.*?: tensor<([0-9x]*)x?\w+>", lowered_text):
+        dims = [int(d) for d in m.group(1).split("x") if d]
+        biggest = max(biggest, int(np.prod(dims)) if dims else 1)
+    return biggest
+
+
+@pytest.mark.parametrize("engine_kind", ["paged", "dense"])
+def test_engine_programs_take_the_weights_as_arguments(engine_kind):
+    """A closed-over array lowers to a stablehlo.constant — gigabytes of
+    weights inside every executable and cache entry at full width. The
+    window, chunk and dense step programs must hold no weight-sized
+    constant: the smallest weight matrix here has dim*kv = 2048 elements,
+    the largest legitimate constant (a rope table) 32*16 = 512."""
+    from dora_tpu.models.hf import qwen2
+
+    cfg, params = _tiny_model()
+    make = (qwen2.make_paged_engine if engine_kind == "paged"
+            else qwen2.make_batch_engine)
+    engine = make(params, cfg, max_slots=2)
+    seen: dict[str, tuple] = {}
+    names = (("chunk_prefill", "window_step") if engine_kind == "paged"
+             else ("batch_step",))
+    programs = {name: getattr(engine, name) for name in names}
+    for name, program in programs.items():
+        assert program.args[0] is params  # functools.partial(jit, params)
+
+        def spy(*args, _name=name, _program=program):
+            seen.setdefault(_name, args)
+            return _program(*args)
+
+        setattr(engine, name, spy)
+    engine.submit("r", [5, 7, 11], 4)
+    for _ in range(20):
+        engine.step()
+        if not engine.active:
+            break
+    assert set(seen) == set(names)
+    for name, program in programs.items():
+        text = program.func.lower(params, *seen[name]).as_text()
+        assert "stablehlo" in text
+        assert _largest_constant(text) < 1024, name
+
+
+# -- the smoke test's parent stays off JAX ----------------------------------
+
+
+def test_chip_smoke_parent_never_imports_jax():
+    out = _run(
+        "import chip_smoke, sys; assert 'jax' not in sys.modules; "
+        "import bench_vlm, dora_tpu.daemon, dora_tpu.native; "
+        "assert 'jax' not in sys.modules",
+        JAX_PLATFORMS="cpu",
+    )
+    assert out.returncode == 0, out.stderr
+
+
+def test_chip_smoke_refuses_to_pass_off_the_chip():
+    """With the CPU it exits non-zero after its first child reports the
+    platform, and prints no ok line — in seconds, not after a CPU grind
+    through a 28-layer model."""
+    out = subprocess.run(
+        [sys.executable, str(ROOT / "chip_smoke.py")], cwd=ROOT,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False and "no TPU" in last["error"]
+
+
+def test_chip_smoke_token_codes_round_trip():
+    import chip_smoke
+
+    ids = [0, 61, 62, 3843, 3844, 151935]
+    text = "".join(map(chip_smoke.token_code, ids))
+    assert chip_smoke.code_tokens(text) == ids
+    assert chip_smoke.agreed([1, 2, 3, 4], [1, 2, 9, 4]) == 2

@@ -136,8 +136,14 @@ def test_kill9_mid_generation_resumes_byte_identical(tmp_path, monkeypatch):
                 "path": "module:dora_tpu.nodehub.llm_server",
                 "inputs": {"text": "client/text"},
                 "outputs": ["response"],
+                # 12 tokens at K=2 are 6 windows: at 0.5 s each the
+                # generation lasts 3 s, so the strike below (>= 4 chunks
+                # seen, pid found, checkpoint on disk — each a poll on a
+                # machine that may be loaded) still lands mid-generation.
+                # At 0.1 s the whole generation was 0.6 s and a slow poll
+                # struck after it had finished.
                 "env": _llm_env(
-                    DORA_STEP_DELAY_S="0.1",
+                    DORA_STEP_DELAY_S="0.5",
                     DORA_CHECKPOINT_DIR=str(ckpt_dir),
                     DORA_CHECKPOINT_EVERY="1",
                 ),

@@ -8,6 +8,7 @@ mode), with assertion-fixture nodes
 
 from __future__ import annotations
 
+import os
 import textwrap
 
 import pytest
@@ -134,16 +135,25 @@ def test_queue_size_drop_oldest(tmp_path):
         with Node() as node:
             for i in range(20):
                 node.send_output("data", pa.array([i]))
+            open("burst_sent", "w").close()
     """))
     receiver = tmp_path / "slow_receiver.py"
     receiver.write_text(textwrap.dedent("""
+        import os
         import sys
         import time
 
         from dora_tpu.node import Node
 
         node = Node()
-        time.sleep(1.0)  # let the burst arrive and overflow the queue
+        # Let the whole burst arrive and overflow the queue before the
+        # first read. Wait for the sender's own word, not for a fixed
+        # second: on a loaded machine the sender can take longer than
+        # that to get its 20 sends out.
+        deadline = time.time() + 30
+        while not os.path.exists("burst_sent") and time.time() < deadline:
+            time.sleep(0.05)
+        time.sleep(0.5)  # routed and queued on the daemon side too
         values = []
         for event in node:
             if event["type"] == "INPUT":
@@ -171,6 +181,43 @@ def test_queue_size_drop_oldest(tmp_path):
     }
     result = run_dataflow(write_dataflow(tmp_path, spec), timeout_s=60)
     assert result.is_ok(), result.errors()
+
+
+def test_timeout_returns_and_kills_a_wedged_node(tmp_path):
+    """A dataflow that passes its time limit with a node that will not
+    die politely (wedged in a device call, or ignoring SIGTERM as here)
+    must still give the caller back control and leave no process behind:
+    the daemon kills stragglers before it waits for its servers to close
+    (``Server.wait_closed`` waits for every open connection)."""
+    import time
+
+    wedged = tmp_path / "wedged.py"
+    wedged.write_text(textwrap.dedent("""
+        import os
+        import signal
+        import time
+
+        from dora_tpu.node import Node
+
+        signal.signal(signal.SIGTERM, signal.SIG_IGN)
+        node = Node()
+        open("wedged.pid", "w").write(str(os.getpid()))
+        time.sleep(600)
+    """))
+    spec = {"nodes": [{"id": "wedged", "path": "wedged.py"}]}
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError):
+        run_dataflow(write_dataflow(tmp_path, spec), timeout_s=5)
+    assert time.monotonic() - t0 < 30
+    pid = int((tmp_path / "wedged.pid").read_text())
+    deadline = time.monotonic() + 10
+    while os.path.exists(f"/proc/{pid}") and time.monotonic() < deadline:
+        state = open(f"/proc/{pid}/stat").read().split(")")[-1].split()[0]
+        if state == "Z":  # killed, not yet reaped
+            break
+        time.sleep(0.1)
+    else:
+        assert not os.path.exists(f"/proc/{pid}"), "wedged node survived"
 
 
 def test_allocate_sample_zero_copy_send(tmp_path):

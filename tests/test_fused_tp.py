@@ -187,6 +187,74 @@ def test_make_vlm_serves_fused_tier_on_mesh(monkeypatch):
     )
 
 
+def test_make_vlm_state_on_mesh_shares_no_buffer(monkeypatch):
+    """The executor donates the whole operator state on the chip, and the
+    chip refuses one buffer donated twice (first four-chip run, PR 21:
+    the tp tree's replicated norms and scales were the params' own
+    buffers). No buffer may appear twice in the placed state."""
+    monkeypatch.setenv("DORA_INT8_DECODE", "1")
+    monkeypatch.setenv("DORA_MESH", "tp=2")  # dp takes the other devices
+    from dora_tpu.nodehub import ops as hub
+    from dora_tpu.parallel.mesh import shard_params
+    from dora_tpu.tpu.fuse import mesh_from_env
+
+    op = hub.make_vlm()
+    assert set(op.init_state) == {"lm", "tp"}
+    state = shard_params(op.init_state, mesh_from_env(), op.sharding)
+    seen = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state):
+        for shard in leaf.addressable_shards:
+            key = (shard.device.id, shard.data.unsafe_buffer_pointer())
+            name = jax.tree_util.keystr(path)
+            assert key not in seen, f"{name} shares a buffer with {seen[key]}"
+            seen[key] = name
+
+
+def test_make_vlm_on_a_mesh_tp_cannot_tile_says_so(monkeypatch, caplog):
+    """tp=4 over 2 KV heads has no kernel tier: the node must not put
+    Pallas kernels into a program XLA partitions (the chip's compiler
+    refuses that), so it serves float weights — and says so."""
+    monkeypatch.setenv("DORA_INT8_DECODE", "1")
+    monkeypatch.setenv("DORA_MAX_NEW_TOKENS", "2")
+    monkeypatch.setenv("DORA_MESH", "tp=4")
+    from dora_tpu.models import vlm
+    from dora_tpu.nodehub import ops as hub
+
+    with caplog.at_level("WARNING"):
+        op = hub.make_vlm()
+    assert "no tensor-parallel kernel tier" in caplog.text
+    assert set(op.init_state) == {"lm"}
+    assert not vlm.fused_decode_ready(op.init_state["lm"])
+
+
+def test_kernel_call_sites_take_the_xla_twin_in_a_partitioned_program():
+    from dora_tpu import backend
+    from dora_tpu.models import layers as L
+    from dora_tpu.ops.int8_matmul import quantize_int8
+    from dora_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(dp=2, tp=2, devices=jax.devices()[:4])
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 64))
+    w = quantize_int8(jax.random.normal(jax.random.PRNGKey(1), (64, 128)), False)
+    seen = {}
+
+    @jax.jit
+    def f(x, w):
+        seen["partitioned"] = backend.partitioned_by_xla()
+        seen["flash"] = L.use_flash()
+        return L.matmul(x, w)
+
+    want = f(x, w)
+    assert seen == {"partitioned": False, "flash": False}
+    with jax.set_mesh(mesh):
+        f.clear_cache()
+        got = f(x, w)
+        text = f.lower(x, w).as_text()
+    assert seen["partitioned"] is True
+    assert "pallas" not in text and "custom_call" not in text
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
+
+
 def test_tp_incompatible_shapes_gate():
     assert not FTP.tp_compatible(8, heads=12, kv_heads=2, ffn=8960,
                                  vocab=151936)

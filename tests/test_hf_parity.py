@@ -1374,8 +1374,8 @@ def test_marian_speculative_matches_greedy(marian_checkpoint):
 def test_vits_bucketed_synthesis_bounded_compiles(vits_checkpoint):
     """synthesize_bucketed: N varying-length inputs produce (a) the same
     waveform as the unpadded run on the true prefix and (b) a jit cache
-    that grows with the bucket grid, not with the input lengths —
-    VERDICT r3 item 4 (models/hf/vits.py shape note)."""
+    that grows with the bucket grid, not with the input lengths
+    (models/hf/vits.py shape note)."""
     from dora_tpu.models.hf import vits
 
     path, _ = vits_checkpoint

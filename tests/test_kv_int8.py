@@ -339,8 +339,8 @@ def test_greedy_identity_and_compile_discipline(quantized, window, spec_k):
         f"{len(_COMPILE_EVENTS) - compiled} new XLA program(s)"
     )
     assert {**q8_warm, **q8_fresh} == fp_tokens
-    assert q8.chunk_prefill._cache_size() == 1
-    assert q8.window_step._cache_size() == 1
+    assert q8.chunk_prefill.func._cache_size() == 1
+    assert q8.window_step.func._cache_size() == 1
 
 
 # ---------------------------------------------------------------------------

@@ -67,8 +67,12 @@ def test_gather_matmul_matches_reference_and_base_slot_is_zero():
 
     got = lora_gather_matmul(x, groups, a, b)
     want = lora_gather_matmul_ref(x, groups, a, b)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # Slot 0 rows: delta is exactly zero, not merely small.
+    # Same two matmuls in a different accumulation order: equal to float
+    # rounding, which moves with the installed JAX ...
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
+    )
+    # ... but slot 0 rows are exactly zero, not merely small.
     assert np.all(np.asarray(got)[np.asarray(groups) == 0] == 0.0)
 
 

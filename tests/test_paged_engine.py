@@ -504,8 +504,8 @@ def test_steady_state_adds_zero_compiles_and_one_chunk_shape(quantized):
         # Exactly one chunk shape and one window shape ever: each jit's
         # cache holds one entry after prompt lengths from 2 to 33 and
         # every slot-membership pattern the drains walked through.
-        assert engine.chunk_prefill._cache_size() == 1, f"K={k}"
-        assert engine.window_step._cache_size() == 1, f"K={k}"
+        assert engine.chunk_prefill.func._cache_size() == 1, f"K={k}"
+        assert engine.window_step.func._cache_size() == 1, f"K={k}"
 
 
 def test_spec_steady_state_adds_zero_compiles(quantized):
@@ -544,8 +544,8 @@ def test_spec_steady_state_adds_zero_compiles(quantized):
         f"spec-on steady state compiled "
         f"{len(_COMPILE_EVENTS) - warm} new XLA program(s)"
     )
-    assert engine.chunk_prefill._cache_size() == 1
-    assert engine.window_step._cache_size() == 1
+    assert engine.chunk_prefill.func._cache_size() == 1
+    assert engine.window_step.func._cache_size() == 1
 
 
 def test_dense_engine_mask_cached_across_unchanged_passes(quantized):

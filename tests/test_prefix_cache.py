@@ -403,7 +403,7 @@ def test_real_shared_vs_cold_identity(quantized, window, spec_k):
         f"cache-hit admissions compiled "
         f"{len(_COMPILE_EVENTS) - compiled} new XLA program(s)"
     )
-    assert eng.chunk_prefill._cache_size() == 1
+    assert eng.chunk_prefill.func._cache_size() == 1
     pc = eng.prefix_cache
     assert pc.hits == 2 and pc.misses == 1 and pc.cow_copies >= 1
     assert wc[0] < cc[1]  # the hit skipped the shared chunks

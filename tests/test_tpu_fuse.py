@@ -390,7 +390,7 @@ def test_fetch_ring_linger_timer_flushes_partial_group(tmp_path):
 
 
 def test_fetch_ring_amortizes_injected_latency(tmp_path, monkeypatch):
-    """The VERDICT-r4 weakness: FPS was hostage to per-frame fetch RTT.
+    """The round-4 weakness: FPS was hostage to per-frame fetch RTT.
     Inject +60 ms per fetch: the grouped ring (fetch_every=8) must push
     N frames per round trip, beating per-tick fetching by the group
     factor (within scheduling noise) — steady throughput decoupled from
