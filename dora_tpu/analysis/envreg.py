@@ -98,7 +98,7 @@ REGISTRY: dict[str, EnvVar] = dict((
     _e("DORA_MAX_SEQ", "int", "1024", "max sequence length", True),
     _e("DORA_MAX_NEW_TOKENS", "int", "128", "default completion token budget", True),
     _e("DORA_MULTISTEP_K", "int", "8", "fused decode window size K", True),
-    _e("DORA_STEP_DELAY_S", "float", "0", "artificial per-step delay (tests)"),
+    _e("DORA_STEP_DELAY_S", "float", "0", "stub engine: modelled device time per decode window (tests)"),
     _e("DORA_PREFILL_CHUNK", "int", "0", "chunked prefill size", True),
     _e("DORA_PAGED_KV", "bool", "0", "paged KV-cache pool", True),
     _e("DORA_PAGE_SIZE", "int", "64", "KV page size (tokens)", True),

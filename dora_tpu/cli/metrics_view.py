@@ -161,6 +161,10 @@ def render_metrics(
         for nid in sorted(serving):
             s = serving[nid]
             ttft = s.get("ttft_us", {})
+            # GAP: host time from one window's tokens reaching the
+            # host to the launch of the next device work — what the
+            # device idles for each period (sending tokens beside a
+            # running window is not in it).
             gap = s.get("dispatch_gap_us", {})
             fetch = s.get("fetch_us", {})
             toks = s.get("decode_tokens", 0)

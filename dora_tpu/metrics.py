@@ -175,7 +175,7 @@ class ServingMetrics:
 
     __slots__ = (
         "ttft", "dispatch_gap", "fetch_latency", "backlog_wait",
-        "grant_pages", "decode_tokens", "prefill_chunks",
+        "grant_pages", "decode_tokens", "emit_overlapped", "prefill_chunks",
         "requests", "rejected", "slots_active", "slots_total",
         "free_pages", "total_pages", "used_pages", "peak_used_pages",
         "largest_contig_free", "backlog_depth", "host_dispatches",
@@ -200,9 +200,12 @@ class ServingMetrics:
 
     def __init__(self, engine: str = "dense"):
         self.ttft = Histogram()
-        #: host time between consecutive engine dispatches while decode
-        #: is active — the per-step host overhead the multi-step window
-        #: amortizes (each gap now buys up to K tokens, not 1).
+        #: host time from one window's tokens reaching the host
+        #: (``collect()`` returning) to the launch of the next device
+        #: work, while decode is active — the time the device sits idle
+        #: for in every period, which the multi-step window amortizes
+        #: (each gap buys up to K tokens, not 1). Sending tokens counts
+        #: only where no window ran beside it (see ``emit_overlapped``).
         #: Split from fetch_latency on purpose: the gap is pure
         #: host/scheduler time, the fetch is the blocking device->host
         #: transfer — a slow device->host path moves the fetch track, a
@@ -219,6 +222,12 @@ class ServingMetrics:
         #: are small ints; fed by the paged engine at submit)
         self.grant_pages: dict[int, int] = {}
         self.decode_tokens = 0
+        #: of ``decode_tokens``, those sent while a decode window was
+        #: in flight on the device (the serving loop emits window N
+        #: beside window N+1); the rest were sent with the device
+        #: waiting — a share well under 1 at steady load means the
+        #: pipelining does not engage
+        self.emit_overlapped = 0
         self.prefill_chunks = 0
         self.requests = 0
         self.rejected = 0
@@ -349,6 +358,7 @@ class ServingMetrics:
             "requests": self.requests,
             "rejected": self.rejected,
             "decode_tokens": self.decode_tokens,
+            "emit_overlapped": self.emit_overlapped,
             "prefill_chunks": self.prefill_chunks,
             "slots_active": self.slots_active,
             "slots_total": self.slots_total,

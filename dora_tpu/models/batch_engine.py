@@ -105,12 +105,6 @@ class BatchEngine:
         #: pay one attribute check per hook site, nothing more.
         self.tracer = None
         self.serving_metrics = None
-        #: request_id -> seconds its first token sat host-side between
-        #: being fetched and the engine call returning; the server pops
-        #: this and subtracts it from wall-clock TTFT (zero here — the
-        #: dense submit returns the first token synchronously — but the
-        #: field exists so the server reads either engine uniformly).
-        self.emit_lag_s: dict[str, float] = {}
 
     # -- admission -----------------------------------------------------------
 
@@ -602,14 +596,13 @@ class PagedBatchEngine:
         #: hook site on the step path.
         self.tracer = None
         self.serving_metrics = None
-        #: request_id -> seconds its FIRST token sat host-side between
-        #: the final-chunk fetch and step() returning. The K-tick window
-        #: runs after the chunk inside the same step, so wall-clock TTFT
-        #: measured at the server is inflated by up to one whole window;
-        #: the server pops this lag and subtracts it (the PR-5 TTFT
-        #: quantization fix). Only fed while serving_metrics is attached,
-        #: so the dict stays empty for raw-engine tests/benches.
-        self.emit_lag_s: dict[str, float] = {}
+        #: the window :meth:`dispatch` launched and :meth:`collect` has
+        #: not fetched yet: ``(mat, launch start, launch end)``. While
+        #: it is set the device-carried state (``tokens``, ``positions``,
+        #: ``pools`` …) is one window AHEAD of the host slots, so every
+        #: reader of both (checkpoint, preempt, drain) runs only after
+        #: ``collect()``.
+        self._flight: tuple | None = None
         #: device utilization plane (dora_tpu.profiling): when the
         #: monitor is on, the step path splits each window/chunk's wall
         #: time into host-dispatch / device-compute / device-fetch (a
@@ -949,9 +942,10 @@ class PagedBatchEngine:
     def preempt(self, request_id: str) -> dict | None:
         """Evict a live stream, freeing its slot and its whole page
         grant (all-or-nothing grants make the victim's footprint exact).
-        Call between step()s — a window boundary, where host slots and
-        device vectors agree; the freed row's zeroed block table routes
-        any stale in-flight writes to the null page.
+        Call between step()s — a window boundary (after ``collect()``),
+        where host slots and device vectors agree; the freed row's
+        zeroed block table routes any stale in-flight writes to the
+        null page.
 
         Returns ``{"emitted", "max_new", "pages", "was_decoding"}`` for
         the caller's resume bookkeeping, or None if the id is not live.
@@ -960,6 +954,7 @@ class PagedBatchEngine:
         prompt + emitted with the remaining budget: chunked prefill is
         deterministic, making the recomputed stream token-identical
         (recompute-on-resume; no pool serialization on the hot path)."""
+        assert self._flight is None, "preempt() with a window in flight"
         for b, s in enumerate(self.slots):
             if s is not None and s.request_id == request_id:
                 break
@@ -1001,6 +996,7 @@ class PagedBatchEngine:
         are identical at every K and spec setting, so retuning never
         perturbs in-flight streams' tokens."""
         assert k >= 1, k
+        assert self._flight is None, "set_window() with a window in flight"
         if self._window_factory is None:
             return False
         if spec_on is None:
@@ -1022,6 +1018,11 @@ class PagedBatchEngine:
 
     # -- the interleaved step ------------------------------------------------
 
+    @property
+    def in_flight(self) -> bool:
+        """A window is launched and not collected yet."""
+        return self._flight is not None
+
     def step(self) -> list[tuple[str, int, bool]]:
         """One scheduler tick = one WINDOW boundary: ONE prefill chunk
         for the head-of-line prefilling stream, then ONE fused K-tick
@@ -1029,14 +1030,25 @@ class PagedBatchEngine:
         (device-side completion freezes finished streams mid-window).
         Returns [(request_id, token, done)] in stream order; a stream's
         first token appears the tick its final chunk lands, the rest
-        arrive up to K per tick off a single device round-trip."""
+        arrive up to K per tick off a single device round-trip.
+
+        ``dispatch()`` then ``collect()``: the serving loop calls the
+        halves itself and sends the previous window's tokens between
+        them, while the device runs this one."""
+        return self.dispatch() + self.collect()
+
+    def dispatch(self) -> list[tuple[str, int, bool]]:
+        """The launching half of :meth:`step`: the prefill chunk, the
+        membership / block-table rebuild and the launch of the window
+        program — everything up to the point where the host would start
+        to wait. Returns what the host already knows: the first token
+        of a stream whose final chunk just ran (that read blocks, the
+        window needs the token), usually nothing."""
+        assert self._flight is None, "dispatch() before collect()"
         jnp = self._jnp
         np = self._np
         emitted: list[tuple[str, int, bool]] = []
         sm = self.serving_metrics
-        #: (request_id, fetch time) of a first token emitted this step —
-        #: its host-side sit time until step() returns is the TTFT lag.
-        first_emit: tuple[str, float] | None = None
 
         if self._prefillq:
             t_chunk = time.perf_counter()
@@ -1123,7 +1135,6 @@ class PagedBatchEngine:
                 self.fetches += 1
                 if sm is not None:
                     sm.fetch_latency.observe((t_first - t_fetch) * 1e6)
-                    first_emit = (s.request_id, t_first)
                 s.emitted = 1
                 done = (
                     self.eos is not None and token == self.eos
@@ -1251,108 +1262,122 @@ class PagedBatchEngine:
                     *extra,
                 )
             self.dispatches += 1
-            t_fetch = time.perf_counter()
+            t_launched = time.perf_counter()
             if self.device_monitor:
-                self.host_dispatch_ns += int((t_fetch - t_win) * 1e9)
+                self.host_dispatch_ns += int((t_launched - t_win) * 1e9)
                 if self.tracer is not None:
                     self.tracer.span(
                         "s_dev_dispatch", "window",
-                        dur_ns=int((t_fetch - t_win) * 1e9),
+                        dur_ns=int((t_launched - t_win) * 1e9),
                     )
-                # Block BEFORE the host read so compute and transfer
-                # separate cleanly; np.asarray alone conflates them.
-                mat.block_until_ready()
-                t_ready = time.perf_counter()
-                self.device_compute_ns += int((t_ready - t_fetch) * 1e9)
-                if self.tracer is not None:
-                    self.tracer.span(
-                        "s_dev_compute", "window",
-                        dur_ns=int((t_ready - t_fetch) * 1e9),
-                    )
-            host = np.asarray(mat)  # ONE [B, K+1] device->host transfer
-            t_done = time.perf_counter()
-            self.fetches += 1
-            if self.device_monitor:
-                self.device_fetch_ns += int((t_done - t_ready) * 1e9)
-                if self.tracer is not None:
-                    self.tracer.span(
-                        "s_dev_fetch", "window",
-                        dur_ns=int((t_done - t_ready) * 1e9),
-                    )
-                if self.flops_per_token:
-                    self.dispatched_flops += profiling.window_flops(
-                        flops_per_token=self.flops_per_token,
-                        active=sum(self._decode), k=self.window,
-                        spec_k=self.spec_k,
-                    )
-            if sm is not None:
-                sm.fetch_latency.observe((t_done - t_fetch) * 1e6)
-            if self.tracer is not None:
-                # Span per decoding stream BEFORE the unpack loop frees
-                # finished slots; all rows share the window's host span
-                # (one dispatch serves them all).
-                from dora_tpu.models.vlm import (
-                    spec_window_row_stats, window_row_stats,
-                )
+            self._flight = (mat, t_win, t_launched)
+        return emitted
 
-                win_ns = int((t_done - t_win) * 1e9)
-                for b, slot in enumerate(self.slots):
-                    if slot is None or not self._decode[b]:
-                        continue
-                    if self.spec_k:
-                        n_emit, frozen = spec_window_row_stats(
-                            host[b], self.window, self.spec_k + 1
-                        )
-                    else:
-                        n_emit, frozen = window_row_stats(
-                            host[b], self.window
-                        )
-                    self.tracer.span(
-                        "s_decode_window", slot.request_id,
-                        f"K={self.window} emitted={n_emit} "
-                        f"frozen_at={frozen}",
-                        dur_ns=win_ns,
-                    )
-            n_before = len(emitted)
-            if self.spec_k:
-                self._unpack_spec(host, emitted, sm)
-            else:
-                for b, slot in enumerate(self.slots):
-                    if slot is None or not self._decode[b]:
-                        continue
-                    # Unpack this row up to its done offset: the host
-                    # completion test mirrors the device's exactly (same
-                    # emitted counter, same cap, same eos), so the first
-                    # host-done token is precisely where the device froze
-                    # the row; later columns hold the -1 sentinel.
-                    for j in range(self.window):
-                        token = int(host[b, j])
-                        if token < 0:
-                            break
-                        slot.emitted += 1
-                        if self._spec_cfg:
-                            # Speculation is paused, not absent: keep the
-                            # host history mirror current so resuming it
-                            # rebuilds warm draft lookup state.
-                            self._hist[b].append(token)
-                        done = (
-                            slot.emitted >= slot.max_new
-                            or (self.eos is not None and token == self.eos)
-                        )
-                        emitted.append((slot.request_id, token, done))
-                        if done:
-                            self._free_slot(b)
-                            break
-            if self.device_monitor and self.flops_per_token:
-                # Useful work = tokens this window actually emitted;
-                # dispatched-minus-useful is the frozen-row + rejected-
-                # tail overhead MFU deliberately excludes.
-                self.useful_flops += (
-                    (len(emitted) - n_before) * self.flops_per_token
+    def collect(self) -> list[tuple[str, int, bool]]:
+        """The waiting half of :meth:`step`: block on the window
+        :meth:`dispatch` launched, fetch its one [B, K+1] matrix, unpack
+        it and free the slots of finished streams. Returns the window's
+        tokens; nothing when no window was launched."""
+        if self._flight is None:
+            return []
+        np = self._np
+        emitted: list[tuple[str, int, bool]] = []
+        sm = self.serving_metrics
+        mat, t_win, t_launched = self._flight
+        self._flight = None
+        t_fetch = time.perf_counter()
+        if self.device_monitor:
+            # Block BEFORE the host read so compute and transfer
+            # separate cleanly; np.asarray alone conflates them.
+            # Compute is counted from the launch: what the host did
+            # between dispatch() and here ran beside the window (an
+            # upper bound where that outlasted it).
+            mat.block_until_ready()
+            t_ready = time.perf_counter()
+            self.device_compute_ns += int((t_ready - t_launched) * 1e9)
+            if self.tracer is not None:
+                self.tracer.span(
+                    "s_dev_compute", "window",
+                    dur_ns=int((t_ready - t_launched) * 1e9),
                 )
-        if first_emit is not None:
-            key, t_first = first_emit
-            self.emit_lag_s[key] = time.perf_counter() - t_first
+        host = np.asarray(mat)  # ONE [B, K+1] device->host transfer
+        t_done = time.perf_counter()
+        self.fetches += 1
+        if self.device_monitor:
+            self.device_fetch_ns += int((t_done - t_ready) * 1e9)
+            if self.tracer is not None:
+                self.tracer.span(
+                    "s_dev_fetch", "window",
+                    dur_ns=int((t_done - t_ready) * 1e9),
+                )
+            if self.flops_per_token:
+                self.dispatched_flops += profiling.window_flops(
+                    flops_per_token=self.flops_per_token,
+                    active=sum(self._decode), k=self.window,
+                    spec_k=self.spec_k,
+                )
+        if sm is not None:
+            sm.fetch_latency.observe((t_done - t_fetch) * 1e6)
+        if self.tracer is not None:
+            # Span per decoding stream BEFORE the unpack loop frees
+            # finished slots; all rows share the window's host span
+            # (one dispatch serves them all).
+            from dora_tpu.models.vlm import (
+                spec_window_row_stats, window_row_stats,
+            )
+
+            win_ns = int((t_done - t_win) * 1e9)
+            for b, slot in enumerate(self.slots):
+                if slot is None or not self._decode[b]:
+                    continue
+                if self.spec_k:
+                    n_emit, frozen = spec_window_row_stats(
+                        host[b], self.window, self.spec_k + 1
+                    )
+                else:
+                    n_emit, frozen = window_row_stats(
+                        host[b], self.window
+                    )
+                self.tracer.span(
+                    "s_decode_window", slot.request_id,
+                    f"K={self.window} emitted={n_emit} "
+                    f"frozen_at={frozen}",
+                    dur_ns=win_ns,
+                )
+        if self.spec_k:
+            self._unpack_spec(host, emitted, sm)
+        else:
+            for b, slot in enumerate(self.slots):
+                if slot is None or not self._decode[b]:
+                    continue
+                # Unpack this row up to its done offset: the host
+                # completion test mirrors the device's exactly (same
+                # emitted counter, same cap, same eos), so the first
+                # host-done token is precisely where the device froze
+                # the row; later columns hold the -1 sentinel.
+                for j in range(self.window):
+                    token = int(host[b, j])
+                    if token < 0:
+                        break
+                    slot.emitted += 1
+                    if self._spec_cfg:
+                        # Speculation is paused, not absent: keep the
+                        # host history mirror current so resuming it
+                        # rebuilds warm draft lookup state.
+                        self._hist[b].append(token)
+                    done = (
+                        slot.emitted >= slot.max_new
+                        or (self.eos is not None and token == self.eos)
+                    )
+                    emitted.append((slot.request_id, token, done))
+                    if done:
+                        self._free_slot(b)
+                        break
+        if self.device_monitor and self.flops_per_token:
+            # Useful work = tokens this window actually emitted;
+            # dispatched-minus-useful is the frozen-row + rejected-
+            # tail overhead MFU deliberately excludes.
+            self.useful_flops += len(emitted) * self.flops_per_token
         return emitted
 
     def _unpack_spec(self, host, emitted, sm) -> None:
@@ -1401,9 +1426,12 @@ class PagedBatchEngine:
     def checkpoint_state(self) -> dict:
         """JSON-able snapshot of every live stream: slot metadata, page
         grants, per-slot last token and position. Call between step()s —
-        a window boundary, where host slots and device vectors agree.
+        a window boundary (after ``collect()``), where host slots and
+        device vectors agree: with a window in flight the device's
+        tokens and positions are a window ahead of the slots' counters.
         Pool CONTENTS are not included; :meth:`save_pools` covers engines
         whose decode reads KV (the stub's affine rule does not)."""
+        assert self._flight is None, "checkpoint with a window in flight"
         np = self._np
         toks = np.asarray(self.tokens)
         pos = np.asarray(self.positions)
@@ -1663,10 +1691,15 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
 
     This is the engine the observability tests and the serving-trace
     bench drive, and what a 3-process demo dataflow serves when no
-    checkpoint is available. ``tick_sleep_s`` adds a host sleep of
-    ``tick_sleep_s * window`` per decode window (after device sync) to
-    emulate per-tick device cost — the TTFT-quantization regression
-    test needs windows that measurably take K ticks.
+    checkpoint is available. ``tick_sleep_s`` and ``chunk_sleep_s``
+    model device time: a window occupies the modelled device for
+    ``tick_sleep_s * window``, a prefill chunk for ``chunk_sleep_s``,
+    one after the other from their launch, and the host feels that
+    where it waits for the device — at the top of ``collect()`` and at
+    a final chunk's first-token read — not where it launches. What
+    the host does between ``dispatch()`` and ``collect()`` so runs
+    beside the window, as on the chip (the TTFT regression test needs
+    windows that measurably take K ticks).
 
     ``spec_k > 0`` swaps in ``vlm.make_paged_spec_window`` (prompt-
     lookup speculation, the production serving path's window) with the
@@ -1754,43 +1787,58 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
                     step_fn, k=k, eos=eos, lora=lora_pool is not None,
                 )
             )
-
-        def window_step(*args):
-            out = base(*args)
-            if tick_sleep_s:
-                jax.block_until_ready(out[0])
-                time.sleep(tick_sleep_s * k)
-            return out
-
-        return window_step
+        return base
 
     if lora_pool is not None:
-        chunk_jit = jax.jit(
+        chunk_fn = jax.jit(
             lambda ids, pools, position, bt, adapter, shifts: (
                 (rule(ids) + shifts[adapter]) % vocab, pools
             ),
             donate_argnums=(1,),
         )
     else:
-        chunk_jit = jax.jit(
+        chunk_fn = jax.jit(
             lambda ids, pools, position, bt: (rule(ids), pools),
             # Same donation contract as the real chunk fns (hf/qwen2.py):
             # the engine replaces its pools reference with the return value,
             # so the stale buffer must not stay alive.
             donate_argnums=(1,),
         )
-    if chunk_sleep_s:
-        # Emulate per-chunk device cost (the prefix-cache A/B bench
-        # needs prefills that measurably take chunk-count time, same
-        # idea as tick_sleep_s for windows).
-        def chunk_fn(*args):
-            out = chunk_jit(*args)
-            time.sleep(chunk_sleep_s)
-            return out
-    else:
-        chunk_fn = chunk_jit
 
-    engine = PagedBatchEngine(
+    class StubEngine(PagedBatchEngine):
+        """The modelled device: one queue, busy until ``_busy_until``."""
+
+        _busy_until = 0.0
+
+        def _occupy(self, seconds: float) -> None:
+            self._busy_until = (
+                max(self._busy_until, time.perf_counter()) + seconds
+            )
+
+        def _wait_device(self) -> None:
+            wait = self._busy_until - time.perf_counter()
+            if wait > 0:
+                time.sleep(wait)
+
+        def dispatch(self):
+            final = False
+            if chunk_sleep_s and self._prefillq:
+                s = self.slots[self._prefillq[0]]
+                final = s.chunk_base + self.chunk >= s.true_len
+                self._occupy(chunk_sleep_s)
+            if final:
+                self._wait_device()  # the first token's blocking read
+            first = super().dispatch()
+            if tick_sleep_s and self.in_flight:
+                self._occupy(tick_sleep_s * self.window)
+            return first
+
+        def collect(self):
+            if self.in_flight:
+                self._wait_device()
+            return super().collect()
+
+    engine = StubEngine(
         init_pool=lambda n: {"null": jnp.zeros((1,), jnp.int32)},
         chunk_prefill=chunk_fn,
         window_step=window_factory(window, spec_k),

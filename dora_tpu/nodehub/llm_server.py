@@ -30,8 +30,14 @@ The event loop runs at WINDOW granularity: each engine step launches
 one fused K-tick decode window (DORA_MULTISTEP_K, default 8) and gets
 up to K tokens per stream back off a single device round-trip, so host
 dispatch/fetch cost amortizes across K tokens. Admissions, prefill
-chunks and backlog draining happen at window boundaries — TTFT and
-backlog latency quantize to one window.
+chunks and backlog draining happen at window boundaries — backlog
+latency quantizes to one window. The loop is pipelined by one window:
+it launches window N+1 (``engine.dispatch()``), sends window N's
+tokens while the device runs, and only then waits
+(``engine.collect()``); a prompt's first token leaves right after the
+launch, before its window is collected. Tokens collected and not yet
+sent are flushed before anything reads per-request state (preemption,
+migration, checkpoints, errors, exit, an engine gone idle).
 
 Env: DORA_BATCH_SLOTS (default 16 paged / 4 dense) concurrent streams;
 DORA_MAX_NEW_TOKENS (default 32) per-request cap (a request's
@@ -389,30 +395,69 @@ class AdmissionQueue:
         return out
 
 
+def _flush(held: list, emit) -> int:
+    """Send every held token, oldest first; returns how many went."""
+    n = len(held)
+    for key, token, done in held:
+        emit(key, token, done)
+    held.clear()
+    return n
+
+
 def _run_loop(node, engine, backlog, metrics, handle_input, emit,
               report, clock=time.monotonic, on_tick=None, on_step=None,
               handle_migrate=None, handle_profile=None,
               on_engine_error=None, keep_alive=False,
-              fleet_tick=None) -> None:
+              fleet_tick=None, held: list | None = None) -> None:
     """Window-granular serving loop, factored out of :func:`main` so
     tests can drive it with fake nodes/engines. Each iteration: drain
-    one event, run one engine step (one prefill chunk + one K-tick
-    decode window), then ALWAYS drain the backlog — capacity appears
+    the pending events, ``engine.dispatch()`` (one prefill chunk, then
+    the launch of one K-tick decode window), emit the tokens HELD from
+    the previous window and the first token the dispatch returned —
+    the device runs the new window meanwhile — then
+    ``engine.collect()`` waits for the window and its tokens become
+    the held ones. Then ALWAYS drain the backlog — capacity appears
     when a step frees slots/pages, but also the idle path must admit
     (a parked request with zero active streams used to sit until
-    unrelated traffic arrived).
+    unrelated traffic arrived). An engine with ``step()`` alone (the
+    dense engine, test fakes) runs it as its ``collect()``: the same
+    order, with nothing in flight to send beside.
+
+    ``held`` is state the wire has not seen, and whatever reads
+    per-request state sends it first (:func:`_flush`). The loop does
+    so wherever it sees the reader: before a MIGRATE event, before
+    ``on_engine_error()``, when a ``collect()`` leaves the engine idle
+    (the loop may then park in ``recv`` or exit) and when it stops.
+    Readers it cannot see — preemption inside admission, a checkpoint
+    inside ``on_tick``/``on_step`` — share the list (``held=``) and
+    flush it themselves.
 
     Recovery hooks (all optional, wired by :func:`serve` when the env
     enables them): ``on_tick()`` runs first each iteration and returns
-    True to stop (SIGTERM checkpoint), ``on_step()`` runs after a step's
-    tokens are emitted (checkpoint cadence — never between step and
-    emit, where the snapshot would count tokens the wire never saw),
-    ``handle_migrate(event)`` drains live streams at this window
-    boundary, ``on_engine_error()`` fails in-flight requests before a
-    step exception propagates. ``keep_alive`` parks instead of exiting
-    when the input stream ends (migration targets wait for handoffs
-    until STOP)."""
-    last_step_end: float | None = None
+    True to stop (SIGTERM checkpoint), ``on_step()`` runs after
+    ``collect()`` (checkpoint cadence — never with a window in flight,
+    where the device's state is a window ahead of the slots', and never
+    with tokens held, where the snapshot would count tokens the wire
+    never saw), ``handle_migrate(event)`` drains live streams at this
+    window boundary, ``on_engine_error()`` fails in-flight requests
+    before a step exception propagates. ``keep_alive`` parks instead of
+    exiting when the input stream ends (migration targets wait for
+    handoffs until STOP)."""
+    if held is None:
+        held = []
+
+    def half(call):
+        """Run one half of the engine's step; if it raises, every
+        stream is told before the exception goes on."""
+        try:
+            return call()
+        except Exception:
+            _flush(held, emit)
+            if on_engine_error is not None:
+                on_engine_error()
+            raise
+
+    last_collect_end: float | None = None
     report_last = clock()
     while True:
         if on_tick is not None and on_tick():
@@ -439,6 +484,7 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             if event["type"] == "INPUT":
                 handle_input(event)
             elif event["type"] == "MIGRATE" and handle_migrate is not None:
+                _flush(held, emit)
                 handle_migrate(event)
             elif event["type"] == "PROFILE" and handle_profile is not None:
                 handle_profile(event)
@@ -456,25 +502,35 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             # (recv returns immediately once the queue is closed).
             time.sleep(0.05)
         if engine.active:
-            now = clock()
-            if last_step_end is not None:
-                # Host time between the end of the previous dispatch
-                # and the start of this one: the gap the K-window
-                # exists to amortize (p50/p99 in the SERVING table).
-                metrics.dispatch_gap.observe((now - last_step_end) * 1e6)
-            try:
-                stepped = engine.step()
-            except Exception:
-                if on_engine_error is not None:
-                    on_engine_error()
-                raise
-            for key, token, done in stepped:
-                emit(key, token, done)
-            last_step_end = clock()
+            first = half(getattr(engine, "dispatch", list))
+            overlapped = getattr(engine, "in_flight", False)
+            t_launch = clock()
+            held.extend(first)  # after the previous window's tokens
+            sent = _flush(held, emit)
+            if overlapped:
+                metrics.emit_overlapped += sent
+            else:
+                # Nothing ran beside the emit (a prefill-only dispatch,
+                # a step-only engine): the device waited for it too.
+                t_launch = clock()
+            if last_collect_end is not None:
+                # Host time from the previous window's tokens reaching
+                # the host to the launch of the next device work: what
+                # the device sits idle for in each period (p50/p99 in
+                # the SERVING table).
+                metrics.dispatch_gap.observe(
+                    (t_launch - last_collect_end) * 1e6
+                )
+            held.extend(half(getattr(engine, "collect", None) or engine.step))
+            last_collect_end = clock()
+            if engine.active == 0:
+                # The loop may now park in recv or exit: nothing stays
+                # in hand.
+                _flush(held, emit)
             if on_step is not None:
                 on_step()
         else:
-            last_step_end = None  # a gap across idle is queue wait
+            last_collect_end = None  # a gap across idle is queue wait
         backlog.drain()
         now = clock()
         if now - report_last >= 1.0:
@@ -485,6 +541,7 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             # (DORA_FLEET_DIGEST_S below 1); report() itself also ticks
             # the publisher, so the slow cadence costs nothing extra.
             fleet_tick(now)
+    _flush(held, emit)
 
 
 def serve(node, engine, metrics, *, encode, decode_one, eos=None,
@@ -619,16 +676,10 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             meta["request_id"] = rid
         t0 = t_admitted.pop(key, None)
         if t0 is not None:
-            # The paged engine runs its K-tick window AFTER the prefill
-            # chunk that produced this first token, inside the same
-            # step() — the token sat host-side for up to a whole window
-            # before the loop could emit it. The engine measured that
-            # sit time (emit_lag_s); subtracting it recovers sub-window
-            # TTFT instead of quantizing to window granularity.
-            lag = engine.emit_lag_s.pop(key, 0.0) if hasattr(
-                engine, "emit_lag_s"
-            ) else 0.0
-            metrics.ttft.observe(max(0.0, clock() - t0 - lag) * 1e6)
+            # The loop sends a first token right after the dispatch
+            # that read it, before the window it joins is collected:
+            # what is observed here is what the client saw.
+            metrics.ttft.observe(max(0.0, clock() - t0) * 1e6)
         node.send_output("response", pa.array([text]), meta)
         if done:
             wire_ids.pop(key, None)
@@ -643,6 +694,14 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         if can_preempt and not done and key in req_emitted:
             req_emitted[key].append(token)
         emit_text(key, decode_one(token), done, finish)
+
+    #: tokens the engine has handed over and the wire has not seen: the
+    #: loop sends them while the next window runs (_run_loop). Readers
+    #: of per-request state the loop cannot see flush() first.
+    held: list[tuple[str, int, bool]] = []
+
+    def flush() -> None:
+        _flush(held, emit)
 
     #: keys whose backlog wait was attributed to adapter residency —
     #: the next wire chunk (first token or shed) carries the tag so the
@@ -710,6 +769,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         so multi-victim evictions happen one grant at a time."""
         if not can_preempt:
             return False
+        flush()  # req_emitted must match the victim's slot.emitted
         rank = QOS_CLASSES.index(cls)
         victim, vkey = None, (-1, -1)
         for s in engine.slots:
@@ -1200,9 +1260,11 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         """Snapshot everything a respawn needs to resume mid-generation
         token-identically. Written atomically (tmp + rename) so a kill
         mid-write leaves the previous snapshot intact. Only ever called
-        at a window boundary — never between step() and emit, where the
-        engine's emitted counters would count tokens the wire hasn't
-        seen (restore must produce duplicates, never gaps)."""
+        at a window boundary, after ``collect()``, and it sends the
+        held tokens first: the engine's emitted counters must not count
+        tokens the wire hasn't seen (restore must produce duplicates,
+        never gaps)."""
+        flush()
         t0 = clock()
         state = {
             "engine": engine.checkpoint_state(),
@@ -1521,6 +1583,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             on_engine_error=on_engine_error,
             keep_alive=bool(migrate_dir),
             fleet_tick=fleet_pub.tick if fleet_pub.enabled else None,
+            held=held,
         )
         clean = True
     finally:
@@ -1550,9 +1613,11 @@ def _stub_main() -> None:
     from dora_tpu.models.batch_engine import make_stub_paged_engine
 
     cycle_env = os.environ.get("DORA_STUB_CYCLE", "")
+    window = int(os.environ.get("DORA_MULTISTEP_K", "4"))
+    delay = float(os.environ.get("DORA_STEP_DELAY_S", "0") or 0)
     engine = make_stub_paged_engine(
         max_slots=int(os.environ.get("DORA_BATCH_SLOTS", "4")),
-        window=int(os.environ.get("DORA_MULTISTEP_K", "4")),
+        window=window,
         spec_k=int(os.environ.get("DORA_SPEC_K", "0") or 0),
         spec_ngram=int(os.environ.get("DORA_SPEC_NGRAM", "2") or 2),
         cycle=int(cycle_env) if cycle_env else None,
@@ -1567,20 +1632,13 @@ def _stub_main() -> None:
         lora_max_resident=int(
             os.environ.get("DORA_LORA_MAX_RESIDENT", "0") or 0
         ),
-    )
-    delay = float(os.environ.get("DORA_STEP_DELAY_S", "0") or 0)
-    if delay > 0:
         # Chaos-harness hook: the stub decodes in microseconds, far too
         # fast to land a mid-generation kill deterministically. A
-        # per-window sleep stretches generation into a predictable
-        # strike window without touching token content.
-        orig_step = engine.step
-
-        def _throttled_step():
-            time.sleep(delay)
-            return orig_step()
-
-        engine.step = _throttled_step
+        # window that takes DORA_STEP_DELAY_S of modelled device time
+        # stretches generation into a predictable strike window
+        # without touching token content.
+        tick_sleep_s=delay / window,
+    )
     serve(
         Node(), engine, ServingMetrics(engine="paged"),
         encode=lambda text: [ord(ch) % 97 for ch in text] or [1],
@@ -1660,6 +1718,14 @@ def main() -> None:
         backend.report("compiles", {
             "count": telemetry.compile_count(),
             "seconds": round(telemetry.compile_seconds(), 3),
+        })
+        # Whether the loop's pipelining engaged over this process's
+        # life: the share of tokens sent beside a running window, and
+        # the host time the device was left waiting each period.
+        backend.report("serving", {
+            "decode_tokens": metrics.decode_tokens,
+            "emit_overlapped": metrics.emit_overlapped,
+            "dispatch_gap_us": metrics.dispatch_gap.snapshot(),
         })
 
 

@@ -427,15 +427,17 @@ def test_engine_exception_fails_inflight_with_error_finish():
 
     engine = _mk_engine(max_slots=1)
     steps = [0]
-    orig_step = engine.step
+    orig_collect = engine.collect
 
     def wedge():
+        # The wait is where a wedged device shows: the loop drives the
+        # engine by dispatch() + collect(), not step().
         steps[0] += 1
         if steps[0] > 2:
             raise RuntimeError("device wedged")
-        return orig_step()
+        return orig_collect()
 
-    engine.step = wedge
+    engine.collect = wedge
     node = _ServeNode([_req("ab", 8), _req("cd", 8)])
     with pytest.raises(RuntimeError, match="device wedged"):
         serve(

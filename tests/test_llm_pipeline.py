@@ -1,0 +1,580 @@
+"""The serving loop is pipelined by one window (nodehub/llm_server
+``_run_loop`` over ``engine.dispatch()`` / ``engine.collect()``).
+
+* ORDER: in steady state the loop launches window N+1, sends window N's
+  tokens while it runs, and only then waits — and a prompt's first
+  token leaves right after the dispatch that read it.
+* FLUSH POINTS: tokens collected and not yet sent are state the wire
+  has not seen. Whatever reads per-request state — preemption,
+  migration, a checkpoint, the error path, STOP, the end of the input
+  stream, an engine gone idle — finds every one of them on the wire
+  first, in order, with consecutive ``seq``.
+* TOKEN IDENTITY: streams served through ``dispatch()`` + ``collect()``
+  under the loop equal those of ``step()`` called in a loop, on the
+  real paged engine at a tiny size (plain / speculative / LoRA /
+  prefix-cache hit).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+from dora_tpu.metrics import ServingMetrics
+from dora_tpu.nodehub.llm_server import AdmissionQueue, _run_loop, serve
+
+
+# ---------------------------------------------------------------------------
+# fakes
+# ---------------------------------------------------------------------------
+
+
+class _Streams:
+    """One stream per slot, ``k`` tokens a window; token values count
+    up from 1 so order and loss read off the numbers."""
+
+    def __init__(self, log: list, slots: int = 2, k: int = 3):
+        self.log = log
+        self.max_slots = slots
+        self.k = k
+        self.streams: dict[str, list[int]] = {}  # key -> [emitted, cap]
+        self.fresh: list[str] = []
+
+    @property
+    def active(self) -> int:
+        return len(self.streams)
+
+    def fits(self, plen: int, max_new: int) -> bool:
+        return True
+
+    def can_admit(self, plen: int, max_new: int) -> bool:
+        return self.active < self.max_slots
+
+    def submit(self, key: str, ids, max_new: int):
+        self.streams[key] = [0, max_new]
+        self.fresh.append(key)
+
+    def _advance(self, key: str) -> tuple[str, int, bool]:
+        s = self.streams[key]
+        s[0] += 1
+        done = s[0] >= s[1]
+        if done:
+            del self.streams[key]
+        return key, s[0], done
+
+    def _first(self) -> list:
+        """A new stream's first token (its "final prefill chunk")."""
+        return [self._advance(self.fresh.pop(0))] if self.fresh else []
+
+    def _window(self, keys) -> list:
+        out = []
+        for key in keys:
+            for _ in range(self.k):
+                tok = self._advance(key)
+                out.append(tok)
+                if tok[2]:
+                    break
+        return out
+
+
+class SplitEngine(_Streams):
+    """Split like the paged engine: ``dispatch()`` launches (and hands
+    back a new stream's first token), ``collect()`` returns the
+    window's tokens. Every call lands in ``log``, which the test's
+    ``emit`` shares."""
+
+    in_flight = False
+
+    def dispatch(self):
+        self.log.append(("dispatch",))
+        first = self._first()
+        self.in_flight = bool(self.streams)
+        return first
+
+    def collect(self):
+        self.log.append(("collect",))
+        if not self.in_flight:
+            return []
+        self.in_flight = False
+        return self._window(
+            [k for k in self.streams if k not in self.fresh]
+        )
+
+
+class StepOnlyEngine(_Streams):
+    """The same engine with ``step()`` alone, as the dense engine and
+    the fakes of test_llm_backlog are."""
+
+    def step(self):
+        self.log.append(("step",))
+        first = self._first()
+        return first + self._window(
+            [k for k in self.streams if k not in self.fresh]
+        )
+
+
+class ScriptNode:
+    """Delivers event i once ``sent()`` has reached its threshold; the
+    stream ends when the script is empty (unless ``hold_open``)."""
+
+    def __init__(self, script, sent=lambda: 0, hold_open: int = 0):
+        self._script = list(script)
+        self._sent = sent
+        self._hold = hold_open
+        self.stream_ended = False
+        self.recvs: list[tuple[float | None, int]] = []
+
+    def recv(self, timeout=None):
+        self.recvs.append((timeout, self._sent()))
+        if self._script and self._sent() >= self._script[0][0]:
+            return self._script.pop(0)[1]
+        if not self._script:
+            if self._hold > 0:
+                # an open stream: STOP after a few parked (idle) polls
+                self._hold -= bool(timeout)
+                if self._hold == 0:
+                    return {"type": "STOP"}
+            else:
+                self.stream_ended = True
+        return None
+
+
+def _drive(engine, log, script, max_new=7, **hooks):
+    metrics = ServingMetrics()
+    backlog = AdmissionQueue(
+        engine, lambda k, ids, mn: engine.submit(k, ids, mn)
+    )
+
+    def emit(key, token, done):
+        log.append(("emit", key, token, done, bool(
+            getattr(engine, "in_flight", False)
+        )))
+
+    def handle_input(event):
+        backlog.push(event["metadata"]["request_id"], [1, 2], max_new)
+
+    node = ScriptNode(
+        script, sent=lambda: sum(e[0] == "emit" for e in log),
+        hold_open=hooks.pop("hold_open", 0),
+    )
+    _run_loop(node, engine, backlog, metrics, handle_input, emit,
+              lambda now: None, **hooks)
+    return metrics, node
+
+
+def _input(rid: str) -> dict:
+    return {"type": "INPUT", "metadata": {"request_id": rid}, "value": rid}
+
+
+def _emits(log, key=None):
+    return [e for e in log if e[0] == "emit" and key in (None, e[1])]
+
+
+# ---------------------------------------------------------------------------
+# the order
+# ---------------------------------------------------------------------------
+
+
+def test_steady_state_is_dispatch_then_previous_windows_tokens_then_collect():
+    log: list = []
+    metrics, _ = _drive(SplitEngine(log, slots=1, k=3), log, [(0, _input("a"))])
+    kinds = [e[0] for e in log]
+    # first token right after the dispatch that read it, before collect
+    assert kinds[:3] == ["dispatch", "emit", "collect"]
+    assert log[1][1:4] == ("a", 1, False)
+    # then: dispatch, the three tokens of the window before, collect
+    assert kinds[3:8] == ["dispatch", "emit", "emit", "emit", "collect"]
+    assert [e[2] for e in log[4:7]] == [2, 3, 4]
+    # in order, nothing lost, done last
+    assert [e[2] for e in _emits(log)] == [1, 2, 3, 4, 5, 6, 7]
+    assert [e[3] for e in _emits(log)] == [False] * 6 + [True]
+    # a collect is never followed by an emit of ITS tokens before the
+    # next dispatch — except where the engine went idle (the last one)
+    for i, e in enumerate(log[:-4]):
+        if e[0] == "collect":
+            assert log[i + 1][0] == "dispatch", log[i : i + 3]
+
+
+def test_emit_overlapped_counts_tokens_sent_beside_a_window():
+    log: list = []
+    metrics, _ = _drive(
+        SplitEngine(log, slots=2, k=3), log,
+        [(0, _input("a")), (0, _input("b"))], max_new=20,
+    )
+    beside = sum(e[4] for e in _emits(log))
+    assert metrics.emit_overlapped == beside
+    # everything but the idle flush of the two streams' last window
+    # went out beside a running window
+    assert len(_emits(log)) == 40
+    assert 0 < len(_emits(log)) - beside <= 6
+    assert metrics.dispatch_gap.count >= 6
+
+
+def test_step_only_engine_runs_the_same_order_with_nothing_overlapped():
+    log: list = []
+    metrics, _ = _drive(
+        StepOnlyEngine(log, slots=2, k=3), log,
+        [(0, _input("a")), (0, _input("b"))],
+    )
+    assert [e[2] for e in _emits(log, "a")] == [1, 2, 3, 4, 5, 6, 7]
+    assert [e[2] for e in _emits(log, "b")] == [1, 2, 3, 4, 5, 6, 7]
+    assert metrics.emit_overlapped == 0
+    assert "dispatch" not in {e[0] for e in log}
+
+
+def test_loop_flushes_before_migrate_error_stop_and_idle():
+    """The readers the loop itself can see: when each acts, what the
+    engine has produced equals what has been emitted."""
+
+    def produced_and_emitted(engine, log):
+        return engine.streams["a"][0], len(_emits(log))
+
+    # MIGRATE: held tokens are out before the hook runs
+    log: list = []
+    engine = SplitEngine(log, slots=1, k=3)
+    seen: list = []
+    _drive(
+        engine, log,
+        [(0, _input("a")), (2, {"type": "MIGRATE", "metadata": {}})],
+        max_new=20,
+        handle_migrate=lambda ev: seen.append(
+            produced_and_emitted(engine, log)
+        ),
+    )
+    assert len(seen) == 1 and seen[0][0] == seen[0][1] >= 4, seen
+    # engine error in dispatch(): the held window, then the hook
+    log = []
+    engine = SplitEngine(log, slots=1, k=3)
+    real = engine.dispatch
+    calls = [0]
+
+    def wedge():
+        calls[0] += 1
+        if calls[0] == 3:
+            raise RuntimeError("wedged")
+        return real()
+
+    engine.dispatch = wedge
+    seen = []
+    with pytest.raises(RuntimeError, match="wedged"):
+        _drive(engine, log, [(0, _input("a"))], max_new=20,
+               on_engine_error=lambda: seen.append(
+                   produced_and_emitted(engine, log)
+               ))
+    assert seen == [(7, 7)]  # first token + two collected windows
+    # STOP mid-generation: the loop leaves with nothing in hand
+    log = []
+    engine = SplitEngine(log, slots=1, k=3)
+    _drive(engine, log, [(0, _input("a")), (3, {"type": "STOP"})],
+           max_new=50)
+    produced, emitted = produced_and_emitted(engine, log)
+    assert produced == emitted >= 4
+    # idle: the tokens are out BEFORE the loop parks in recv(0.25)
+    log = []
+    _, node = _drive(SplitEngine(log, slots=1, k=3), log,
+                     [(0, _input("a"))], hold_open=3)
+    parks = [n for t, n in node.recvs[1:] if t == 0.25]
+    assert parks and parks[0] == 7
+
+
+# ---------------------------------------------------------------------------
+# flush points through serve(), over the stub paged engine
+# ---------------------------------------------------------------------------
+
+
+class _Wire(ScriptNode):
+    """Node fake for serve(): the script is paced by chunks sent so
+    far; captures the chunks' metadata."""
+
+    def __init__(self, script, hold_open: int = 0):
+        super().__init__(script, sent=lambda: len(self.sent),
+                         hold_open=hold_open)
+        self.sent: list[dict] = []
+        self.closed = False
+
+    def count(self, rid: str) -> int:
+        return sum(m.get("request_id") == rid for m in self.sent)
+
+    def send_output(self, output_id, value, metadata=None):
+        self.sent.append(dict(metadata or {}))
+
+    def report_serving(self, snapshot):
+        pass
+
+    def close(self):
+        self.closed = True
+
+
+def _req(rid: str, max_new: int, qos: str | None = None) -> dict:
+    meta: dict = {"request_id": rid, "max_new_tokens": max_new}
+    if qos:
+        meta["qos_class"] = qos
+    return {"type": "INPUT", "metadata": meta, "value": b"hello world"}
+
+
+def _audit(engine, name: str, wire: _Wire, rids: list[str], seen: list):
+    """Wrap ``engine.<name>``: when it is called, what the engine counts
+    as emitted for every live stream must already be on the wire (engine
+    keys are ``req-N`` in arrival order)."""
+    real = getattr(engine, name)
+
+    def wrapped(*a, **kw):
+        for s in engine.slots:
+            if s is not None and s.request_id.startswith("req-"):
+                rid = rids[int(s.request_id[4:]) - 1]
+                seen.append((name, rid, s.emitted, wire.count(rid)))
+        return real(*a, **kw)
+
+    setattr(engine, name, wrapped)
+
+
+def _assert_consecutive(wire: _Wire) -> dict[str, list[dict]]:
+    by_rid: dict[str, list[dict]] = {}
+    for m in wire.sent:
+        by_rid.setdefault(m["request_id"], []).append(m)
+    for rid, chunks in by_rid.items():
+        assert [m["seq"] for m in chunks] == list(range(len(chunks))), rid
+        assert all(not m["done"] for m in chunks[:-1]), rid
+    return by_rid
+
+
+FLUSH_CASES = [
+    "preempt", "migrate", "checkpoint", "engine_error", "stop",
+    "stream_end", "idle",
+]
+
+
+@pytest.mark.parametrize("case", FLUSH_CASES)
+def test_every_held_token_is_on_the_wire_before(case, monkeypatch, tmp_path):
+    pytest.importorskip("jax")
+    from dora_tpu.models.batch_engine import make_stub_paged_engine
+
+    engine = make_stub_paged_engine(max_slots=1, window=4, max_seq=128)
+    seen: list = []
+    rids = ["w-a", "w-b"]
+    script = [(0, _req("w-a", 24))]
+    hold_open = 0
+    raises = None
+    if case == "preempt":
+        monkeypatch.setenv("DORA_QOS_PREEMPT", "1")
+        script = [(0, _req("w-a", 24, "batch")),
+                  (6, _req("w-b", 4, "interactive"))]
+    elif case == "migrate":
+        script.append((6, {"type": "MIGRATE",
+                           "metadata": {"handoff_dir": str(tmp_path / "h")}}))
+    elif case == "checkpoint":
+        monkeypatch.setenv("DORA_CHECKPOINT_DIR", str(tmp_path / "ck"))
+        monkeypatch.setenv("DORA_CHECKPOINT_EVERY", "2")
+    elif case == "engine_error":
+        real = engine.dispatch
+        calls = [0]
+
+        def wedge():
+            calls[0] += 1
+            if calls[0] == 4:
+                raise RuntimeError("device wedged")
+            return real()
+
+        engine.dispatch = wedge
+        raises = "device wedged"
+    elif case == "stop":
+        script.append((6, {"type": "STOP"}))
+    elif case == "idle":
+        hold_open = 3
+    wire = _Wire(script, hold_open=hold_open)
+    audited = {"preempt": "preempt", "migrate": "drain_streams",
+               "checkpoint": "checkpoint_state"}.get(case)
+    if audited:
+        _audit(engine, audited, wire, rids, seen)
+    metrics = ServingMetrics(engine="paged")
+
+    def run():
+        serve(
+            wire, engine, metrics,
+            encode=lambda text: [ord(ch) % 97 + 1 for ch in text] or [1],
+            decode_one=lambda tok: f" t{tok}",
+            max_new_cap=64,
+        )
+
+    if raises:
+        with pytest.raises(RuntimeError, match=raises):
+            run()
+    else:
+        run()
+    by_rid = _assert_consecutive(wire)
+    if audited:
+        live = [s for s in seen if s[2] > 0]
+        assert live, seen  # the action met a stream mid-generation
+        for name, rid, emitted, on_wire in seen:
+            assert emitted == on_wire, (name, rid, emitted, on_wire)
+    if case == "preempt":
+        assert metrics.preempted >= 1 and metrics.resumed >= 1
+        assert by_rid["w-a"][-1]["done"] and len(by_rid["w-a"]) == 24
+    elif case == "migrate":
+        assert metrics.migrated_out == 1
+        assert not by_rid["w-a"][-1]["done"]  # it moved, it did not end
+    elif case == "checkpoint":
+        assert metrics.checkpoints >= 3
+        assert len(by_rid["w-a"]) == 24 and by_rid["w-a"][-1]["done"]
+    elif case == "engine_error":
+        # every token the engine counts (the first, then three
+        # collected windows: the last of them was held), then the error
+        chunks = by_rid["w-a"]
+        assert len(chunks) - 1 == engine.slots[0].emitted == 1 + 3 * 4
+        assert chunks[-1]["finish"] == "error" and chunks[-1]["done"]
+    elif case == "stop":
+        slot = engine.slots[0]
+        assert slot is not None and len(by_rid["w-a"]) == slot.emitted
+    elif case == "stream_end":
+        assert len(by_rid["w-a"]) == 24
+        assert by_rid["w-a"][-1]["finish"] == "length"
+    elif case == "idle":
+        # the loop parked (recv with a timeout) only with all 24 out
+        after = [n for t, n in wire.recvs if t and n > 0]
+        assert after and after[0] == 24
+    assert metrics.emit_overlapped > 0
+    if case in ("stream_end", "idle"):
+        # all but the idle flush of the last window went out beside one
+        assert metrics.decode_tokens - metrics.emit_overlapped <= 4
+
+
+# ---------------------------------------------------------------------------
+# token identity on the real paged engine, tiny
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny(tmp_path_factory):
+    """Tiny random Qwen2 in the fused int8 layout, plus two adapters."""
+    import os
+
+    import torch
+    from transformers import Qwen2Config, Qwen2ForCausalLM
+
+    from dora_tpu.models.hf import qwen2
+
+    config = Qwen2Config(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128, rope_theta=10000.0,
+        rms_norm_eps=1e-6, tie_word_embeddings=False,
+        attn_implementation="eager",
+    )
+    torch.manual_seed(0)
+    path = tmp_path_factory.mktemp("qwen2-pipeline")
+    Qwen2ForCausalLM(config).eval().save_pretrained(
+        path, safe_serialization=True
+    )
+    lora_dir = tmp_path_factory.mktemp("adapters")
+    rng = np.random.default_rng(7)
+    for name, scale, rank in (("ta", 0.3, 4), ("tb", 0.5, 8)):
+        np.savez(
+            lora_dir / f"{name}.npz",
+            **{f"a_{i}": rng.normal(size=(64, rank)).astype(np.float32)
+               * scale for i in range(2)},
+            **{f"b_{i}": rng.normal(size=(rank, 64)).astype(np.float32)
+               * scale for i in range(2)},
+        )
+    cfg, params = qwen2.load(str(path), max_seq=64)
+    os.environ["DORA_INT8_DECODE"] = "1"
+    try:
+        params = qwen2.quantize_decode(params, cfg)
+    finally:
+        os.environ.pop("DORA_INT8_DECODE", None)
+    return cfg, params, str(lora_dir)
+
+
+#: (prompt, max_new, adapter) by request; "p2" repeats "p0"'s prompt,
+#: two full pages of it, and arrives after p0 finished its prefill.
+LONG = [7, 3, 11, 5, 2, 13, 17, 19, 23, 29, 31, 37, 41, 43, 47, 53, 59, 61]
+REQUESTS = {
+    "p0": (LONG, 9, None),
+    "p1": ([9, 4, 6], 12, "ta"),
+    "p2": (LONG, 7, None),
+    "p3": ([1, 2, 3, 4, 5], 10, "tb"),
+}
+
+
+@pytest.mark.parametrize(
+    "variant", ["plain", "spec_k", "lora", "prefix_hit"]
+)
+def test_dispatch_collect_under_the_loop_equals_step_in_a_loop(tiny, variant):
+    from dora_tpu.models.hf import qwen2
+
+    cfg, params, lora_dir = tiny
+    kw: dict = {}
+    if variant == "spec_k":
+        kw["spec_k"] = 2
+    elif variant == "lora":
+        kw["lora_dir"] = lora_dir
+    elif variant == "prefix_hit":
+        kw["prefix_cache"] = True
+    engine = qwen2.make_paged_engine(
+        params, cfg, max_slots=3, page_size=8, chunk=8, window=4, **kw
+    )
+    reqs = {
+        k: (ids, mn, ad if variant == "lora" else None)
+        for k, (ids, mn, ad) in REQUESTS.items()
+    }
+
+    def submit(key):
+        ids, mn, ad = reqs[key]
+        if ad:
+            engine.submit(key, ids, mn, adapter=ad)
+        else:
+            engine.submit(key, ids, mn)
+
+    # reference: step() in a loop; p2 joins once p0's prompt is cached
+    want: dict[str, list[tuple[int, bool]]] = {}
+    for key in ("p0", "p1"):
+        submit(key)
+    pending = ["p2", "p3"]
+    for _ in range(200):
+        for key, tok, done in engine.step():
+            want.setdefault(key, []).append((int(tok), bool(done)))
+        while pending and len(want.get("p0", ())) >= 2 and engine.free_slots:
+            submit(pending.pop(0))
+        if not engine.active and not pending:
+            break
+    assert {k: len(v) for k, v in want.items()} == {
+        k: mn for k, (_i, mn, _a) in reqs.items()
+    }
+    hits_before = engine.prefix_cache.hits if variant == "prefix_hit" else 0
+
+    # the same requests through _run_loop
+    got: dict[str, list[tuple[int, bool]]] = {}
+    beside = [0]
+    metrics = ServingMetrics(engine="paged")
+    backlog = AdmissionQueue(
+        engine,
+        lambda k, ids, mn, adapter=None: (
+            engine.submit(k, ids, mn, adapter=adapter) if adapter
+            else engine.submit(k, ids, mn)
+        ),
+    )
+
+    def emit(key, tok, done):
+        got.setdefault(key, []).append((int(tok), bool(done)))
+        beside[0] += engine.in_flight
+
+    def handle_input(event):
+        key = event["metadata"]["request_id"]
+        ids, mn, ad = reqs[key]
+        backlog.push(key, ids, mn, adapter=ad)
+
+    sent = lambda: sum(len(v) for v in got.values())  # noqa: E731
+    node = ScriptNode(
+        [(0, _input("p0")), (0, _input("p1")), (3, _input("p2")),
+         (3, _input("p3"))],
+        sent=sent,
+    )
+    _run_loop(node, engine, backlog, metrics, handle_input, emit,
+              lambda now: None)
+    assert got == want
+    assert metrics.emit_overlapped == beside[0] > 0
+    assert not engine.in_flight and engine.active == 0
+    engine.check_invariants()
+    if variant == "prefix_hit":
+        assert engine.prefix_cache.hits > hits_before
+    if variant == "spec_k":
+        assert engine.spec_k == 2

@@ -225,15 +225,19 @@ def test_rejects_record_instants_not_spans(tracing_on):
 
 def test_ttft_not_quantized_to_the_decode_window():
     """Satellite regression: the first token of a request lands host-side
-    when its final prefill chunk fetches, but step() only returns after
-    the same tick's K-step decode window — at K=16 with a measurable
-    per-tick cost the uncorrected TTFT inflates by the whole window.
-    The engine's emit_lag correction recovers the sub-window fetch time.
+    when its final prefill chunk fetches, and the same tick's K-step
+    decode window is launched right after — a loop that sent the token
+    only once that window was back would inflate TTFT by the whole
+    window at K=16 with a measurable per-tick cost. The loop sends it
+    after ``dispatch()``, before it waits in ``collect()``: the client
+    really gets it a window earlier, and the server's histogram needs
+    no correction.
 
-    With tick_sleep_s=8ms the K=16 window holds the first token >=128ms
-    (uncorrected histogram bucket >=131072us); corrected TTFT is the
-    admission->fetch interval only, asserted an order of magnitude
-    under the window (octave-resolution histogram: bucket <=65536us)."""
+    With tick_sleep_s=8ms the K=16 window takes >=128ms of modelled
+    device time (a held first token would land in histogram bucket
+    >=131072us); the observed TTFT is the admission->fetch->send
+    interval only, asserted an order of magnitude under the window
+    (octave-resolution histogram: bucket <=65536us)."""
     pytest.importorskip("jax")
     from dora_tpu.models.batch_engine import make_stub_paged_engine
 
