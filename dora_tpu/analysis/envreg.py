@@ -137,6 +137,7 @@ REGISTRY: dict[str, EnvVar] = dict((
     _e("DORA_KV_INT8", "bool", "0", "int8 KV pages with per-page scales",
        True),
     _e("DORA_WEIGHT_BITS", "str", "", "decode weight bits (4 or 8)", True),
+    _e("DORA_EP_RANK", "int", "0", "this process's rank in a kimi_k2 expert group (the size is config.json's ep_size)", True),
     _e("DORA_LORA_DIR", "path", "", "LoRA adapter catalog directory", True),
     _e("DORA_LORA_MAX_RESIDENT", "int", "8",
        "resident LoRA adapter slots", True),

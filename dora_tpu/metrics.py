@@ -195,7 +195,7 @@ class ServingMetrics:
         "kv_dtype", "kv_pool_bytes", "kv_quant_err",
         "lora_resident", "lora_max_resident", "lora_resident_bytes",
         "lora_loads", "lora_evictions", "adapter_streams",
-        "adapter_stalls",
+        "adapter_stalls", "model",
     )
 
     def __init__(self, engine: str = "dense"):
@@ -349,6 +349,24 @@ class ServingMetrics:
         #: wire chunk carries the same attribution as
         #: ``stall_reason="adapter_residency"``.
         self.adapter_stalls = 0
+        #: the model module's own counters, as the engine's
+        #: ``model_counters()`` returns them at the 1 Hz report (a dict,
+        #: merged into the snapshot and into the node's exit line; empty
+        #: for models that have none). models/hf/kimi_k2.MoeCounters:
+        #: ``moe_tokens`` — rows routed, summed over expert layers (a
+        #: chunk's right padding and frozen decode rows are not
+        #: counted); ``moe_local_pairs`` — (row, expert) pairs that
+        #: landed on an expert this rank holds (expect ``top_k * held /
+        #: n_experts`` of ``moe_tokens``: 0.25 for 12 of 384 at top-8);
+        #: ``moe_expert_tokens`` — those pairs per held expert, summed
+        #: over layers (the load skew); ``moe_experts_touched`` — mean
+        #: distinct held experts a layer a decode tick had to read;
+        #: ``latent_rows_in_use`` / ``latent_pool_bytes`` — cache rows
+        #: held by streams and the prefix cache, and the pool's size.
+        #: Device counters that the window and the chunk program take
+        #: and give back beside the pools, read after ``collect()``: no
+        #: extra device->host fetch per window.
+        self.model: dict = {}
 
     def snapshot(self) -> dict:
         import time
@@ -442,6 +460,7 @@ class ServingMetrics:
             "lora_evictions": self.lora_evictions,
             "adapter_streams": dict(self.adapter_streams),
             "adapter_stalls": self.adapter_stalls,
+            **self.model,
         }
 
 

@@ -473,7 +473,8 @@ class PagedBatchEngine:
     Closures (see models/hf/qwen2.make_paged_engine):
       * ``init_pool(num_pages)`` -> pools pytree
       * ``chunk_prefill(ids [C], pools, position, bt_row)`` ->
-        (greedy [C], pools)
+        (greedy [C], pools); with ``chunk_valid_rows`` a fifth operand,
+        the count of the chunk's rows that are the prompt's
       * ``window_step(tokens [B], pools, positions [B], bts [B, P],
         active [B], emitted [B], max_new [B])`` ->
         (mat [B, K+1], tokens, positions, active, emitted, pools)
@@ -494,7 +495,8 @@ class PagedBatchEngine:
                  chunk: int, num_pages: int, eos: int | None = None,
                  window: int = 8, spec_k: int = 0, spec_ngram: int = 2,
                  window_factory=None, prefix_cache: bool = False,
-                 prefix_cache_pages: int = 0, lora_pool=None):
+                 prefix_cache_pages: int = 0, lora_pool=None,
+                 chunk_valid_rows: bool = False):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -512,6 +514,10 @@ class PagedBatchEngine:
         self.chunk = chunk
         self.eos = eos
         self.chunk_prefill = chunk_prefill
+        #: ``chunk_prefill`` takes a trailing traced operand: how many of
+        #: the chunk's rows are the prompt's (the rest is the tail
+        #: chunk's right padding), for closures that count rows.
+        self.chunk_valid_rows = chunk_valid_rows
         self.window_step = window_step
         self.window = window
         self.max_pages = max_seq // page_size
@@ -623,6 +629,10 @@ class PagedBatchEngine:
         #: peak FLOP/s for MFU's denominator — set by engine factories.
         self.flops_per_token = 0
         self.device_peak_flops = 0.0
+        #: the model's own counters, ``() -> dict`` merged into
+        #: ``ServingMetrics.model`` at llm_server's 1 Hz report (None =
+        #: the model has none) — set by engine factories.
+        self.model_counters = None
         #: KV number format, detected from the pool layout: int8 pools
         #: carry parallel ``ks``/``vs`` scale planes per layer
         #: (models/hf/qwen2.init_page_pool). Checkpoint custody keys on
@@ -710,9 +720,14 @@ class PagedBatchEngine:
     def can_admit(self, prompt_len: int, max_new: int,
                   adapter: str | None = None) -> bool:
         avail = self.free_pages
-        if self.prefix_cache is not None:
+        if (
+            self.prefix_cache is not None
+            and self.pages_needed(prompt_len, max_new) > avail
+        ):
             # Eviction yields to admission: unpinned, unshared cached
-            # pages are free-in-waiting, never a reason to shed.
+            # pages are free-in-waiting, never a reason to shed. Counted
+            # only when the free list alone falls short: the count walks
+            # every cached page.
             avail += self.prefix_cache.evictable_pages()
         if adapter and (self.lora is None or not self.lora.fits(adapter)):
             # Adapter residency is admission state like pages: every
@@ -857,7 +872,8 @@ class PagedBatchEngine:
         # can_admit checked the no-cache grant against free+evictable.
         while shared:
             need = self.pages_needed(len(ids), max_new, lo) - len(shared)
-            if need <= self.allocator.free_pages + cache.evictable_pages():
+            free = self.allocator.free_pages
+            if need <= free or need <= free + cache.evictable_pages():
                 break
             self.allocator.unref([shared.pop()])
             lo -= ps
@@ -1056,6 +1072,10 @@ class PagedBatchEngine:
             s = self.slots[b]
             base = s.chunk_base
             piece = s.prompt[base : base + self.chunk]
+            valid = (
+                (jnp.asarray(len(piece), jnp.int32),)
+                if self.chunk_valid_rows else ()
+            )
             piece = piece + [0] * (self.chunk - len(piece))
             if self.lora is not None:
                 # Adapter id rides as a traced operand (an int32 device
@@ -1072,6 +1092,7 @@ class PagedBatchEngine:
                 greedy, self.pools = self.chunk_prefill(
                     jnp.asarray(piece, jnp.int32), self.pools,
                     jnp.asarray(base, jnp.int32), jnp.asarray(self._bt[b]),
+                    *valid,
                 )
             t_disp = time.perf_counter()
             s.chunk_base = base + self.chunk

@@ -114,6 +114,59 @@ def rope_table(max_len: int, head_dim: int, base: float = 10000.0):
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+def yarn_inv_freq(head_dim: int, base: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's blended inverse frequencies [head_dim/2] (numpy float64;
+    HF ``DeepseekV3YarnRotaryEmbedding``): pair ``i`` keeps its plain
+    frequency ``base^(-2i/d)`` where it turns more than ``beta_fast``
+    times over the original context, is divided by ``factor`` where it
+    turns fewer than ``beta_slow`` times, and is blended linearly over
+    the pairs between the two (the ramp's ends are ``floor``/``ceil``
+    of the correction dimensions, clipped to the pair range)."""
+    import numpy as np
+
+    def correction_dim(rotations: float) -> float:
+        return (
+            head_dim * math.log(original_max / (rotations * 2 * math.pi))
+        ) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # HF: no division by zero
+    exponent = np.arange(0, head_dim, 2, dtype=np.float64) / head_dim
+    plain = 1.0 / base ** exponent
+    ramp = np.clip(
+        (np.arange(head_dim // 2, dtype=np.float64) - low) / (high - low),
+        0.0, 1.0,
+    )
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term: ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_rope_table(max_len: int, head_dim: int, base: float, factor: float,
+                    original_max: int, beta_fast: float = 32.0,
+                    beta_slow: float = 1.0, mscale: float = 1.0,
+                    mscale_all_dim: float = 0.0):
+    """(cos, sin) tables [max_len, head_dim/2] in float32 under YaRN
+    scaling, beside :func:`rope_table`. Both are multiplied by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``
+    as HF does (1 where the two are equal, as Kimi-K2 has them)."""
+    inv_freq = jnp.asarray(
+        yarn_inv_freq(head_dim, base, factor, original_max, beta_fast,
+                      beta_slow),
+        jnp.float32,
+    )
+    ratio = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    freqs = jnp.outer(jnp.arange(max_len, dtype=jnp.float32), inv_freq)
+    return jnp.cos(freqs) * ratio, jnp.sin(freqs) * ratio
+
+
 def apply_rope(x, cos, sin, positions):
     """x: [B, H, T, D]; positions: [B, T] absolute token positions."""
     return apply_rope_tables(x, cos[positions], sin[positions])

@@ -252,25 +252,28 @@ class PrefixCache:
         pinned or in-use descendant keeps its ancestors reachable).
         Admission counts these as free-in-waiting."""
 
-        roots = set(self._roots.values())
-
-        def walk(n: _Node) -> tuple[bool, int]:
-            total = 0
-            ok_all = True
-            for c in n.children.values():
-                ok, cnt = walk(c)
-                total += cnt
-                ok_all = ok_all and ok
-            if n in roots:
-                return True, total
-            ok = (
-                ok_all
-                and n.pins == 0
-                and self.allocator.refcount(n.page) == 1
-            )
-            return ok, total + (1 if ok else 0)
-
-        return sum(walk(root)[1] for root in roots)
+        # Post-order over an explicit stack: a 16k-token prompt is a
+        # chain 1,024 pages deep, past Python's recursion limit.
+        total = 0
+        for root in set(self._roots.values()):
+            blocked: set[_Node] = set()  # a descendant of these stays
+            stack = [(c, False) for c in root.children.values()]
+            while stack:
+                n, seen = stack.pop()
+                if not seen:
+                    stack.append((n, True))
+                    stack.extend((c, False) for c in n.children.values())
+                    continue
+                ok = (
+                    n not in blocked
+                    and n.pins == 0
+                    and self.allocator.refcount(n.page) == 1
+                )
+                if ok:
+                    total += 1
+                elif n.parent is not None:
+                    blocked.add(n.parent)
+        return total
 
     def evict(self, need: int) -> int:
         """Free up to ``need`` pages, least-recently-used leaves first

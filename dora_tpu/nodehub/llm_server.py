@@ -100,16 +100,43 @@ from dora_tpu.metrics import percentile_from_counts
 from dora_tpu.node import Node
 
 
-def make_engine(params, cfg, eos=None):
-    """Build the serving engine from the env knobs (paged by default)."""
-    from dora_tpu.models.hf import qwen2
+#: checkpoint ``model_type`` -> the module under ``models/hf/`` that
+#: serves it (``load``, ``quantize_decode``, ``make_paged_engine``). A
+#: ``config.json`` without the key is taken for the Qwen2 family, as
+#: before the table existed.
+MODEL_MODULES = {
+    "qwen2": "qwen2",
+    "kimi_k2": "kimi_k2",
+    "deepseek_v3": "kimi_k2",
+}
+
+
+def model_module(model_type: str | None):
+    """The ``models/hf`` module for a checkpoint's ``model_type``; an
+    unknown type is refused by name."""
+    import importlib
+
+    name = MODEL_MODULES.get(model_type or "qwen2")
+    if name is None:
+        raise RuntimeError(
+            f"llm_server cannot serve model_type {model_type!r}: it knows "
+            f"{sorted(MODEL_MODULES)}"
+        )
+    return importlib.import_module(f"dora_tpu.models.hf.{name}")
+
+
+def make_engine(params, cfg, eos=None, module=None):
+    """Build the serving engine from the env knobs (paged by default).
+    ``module`` is the model's ``models/hf`` module (default: qwen2)."""
+    if module is None:
+        from dora_tpu.models.hf import qwen2 as module
 
     paged = os.environ.get("DORA_PAGED_KV", "1") != "0"
     slots = int(
         os.environ.get("DORA_BATCH_SLOTS", "16" if paged else "4")
     )
     if not paged:
-        return qwen2.make_batch_engine(
+        return module.make_batch_engine(
             params, cfg, max_slots=slots, eos=eos
         )
     page_size = int(os.environ.get("DORA_PAGE_SIZE", "16"))
@@ -120,7 +147,7 @@ def make_engine(params, cfg, eos=None):
     # (DORA_PREFIX_CACHE=0 restores the exact pre-cache program).
     prefix_on = os.environ.get("DORA_PREFIX_CACHE", "1") != "0"
     prefix_pages = int(os.environ.get("DORA_PREFIX_CACHE_PAGES", "0"))
-    return qwen2.make_paged_engine(
+    return module.make_paged_engine(
         params, cfg, max_slots=slots, eos=eos, page_size=page_size,
         chunk=chunk, window=window, prefix_cache=prefix_on,
         prefix_cache_pages=prefix_pages,
@@ -1211,6 +1238,12 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                 metrics.lora_loads = lp.loads
                 metrics.lora_evictions = lp.evictions
                 metrics.adapter_streams = lp.streams_by_adapter()
+        counters = getattr(engine, "model_counters", None)  # paged only
+        if counters is not None:
+            # The model's own counters (an expert layer's routing, the
+            # latent pool): read here, after collect(), with no window
+            # in flight, so the read waits on nothing.
+            metrics.model = counters()
         metrics.qos_depth = backlog.depths()
         metrics.autotune_k = getattr(engine, "window", 0)
         if monitor is not None:
@@ -1650,7 +1683,7 @@ def _stub_main() -> None:
 def main() -> None:
     from dora_tpu import backend, telemetry
     from dora_tpu.metrics import ServingMetrics
-    from dora_tpu.models.hf import qwen2
+    from dora_tpu.models.hf.loader import read_config
 
     # The chip, or an explicit JAX_PLATFORMS=cpu — never a silent
     # fallback; and the compile cache placed before the first jit.
@@ -1662,19 +1695,20 @@ def main() -> None:
         if os.environ.get("DORA_STUB_ENGINE", "") not in ("", "0"):
             return _stub_main()
         raise RuntimeError(
-            "llm_server needs DORA_HF_CHECKPOINT (a Qwen2-family "
-            "safetensors directory; or DORA_STUB_ENGINE=1 for the "
-            "weight-free stub engine)"
+            "llm_server needs DORA_HF_CHECKPOINT (a Qwen2-family or "
+            "kimi_k2/deepseek_v3 safetensors directory; or "
+            "DORA_STUB_ENGINE=1 for the weight-free stub engine)"
         )
     max_seq = int(os.environ.get("DORA_MAX_SEQ", "2048"))
     max_new_cap = int(os.environ.get("DORA_MAX_NEW_TOKENS", "32"))
 
-    cfg, params = qwen2.load(path, max_seq=max_seq)
+    module = model_module(read_config(path).get("model_type"))
+    cfg, params = module.load(path, max_seq=max_seq)
     if not os.environ.get("DORA_INT8_DECODE") and not os.environ.get(
         "DORA_INT4_DECODE"
     ):
         os.environ["DORA_INT8_DECODE"] = "1"  # engine needs the fused layout
-    params = qwen2.quantize_decode(params, cfg)
+    params = module.quantize_decode(params, cfg)
 
     from dora_tpu.nodehub.ops import _hf_tokenizer
 
@@ -1700,7 +1734,7 @@ def main() -> None:
 
         return tokenizer.decode([token])
 
-    engine = make_engine(params, cfg, eos=eos)
+    engine = make_engine(params, cfg, eos=eos, module=module)
     metrics = ServingMetrics(
         engine="paged" if hasattr(engine, "free_pages") else "dense"
     )
@@ -1726,6 +1760,7 @@ def main() -> None:
             "decode_tokens": metrics.decode_tokens,
             "emit_overlapped": metrics.emit_overlapped,
             "dispatch_gap_us": metrics.dispatch_gap.snapshot(),
+            **metrics.model,
         })
 
 

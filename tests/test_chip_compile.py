@@ -242,3 +242,109 @@ def test_flash_attention_compiles_at_2b_vision_widths(chip):
     qkv = _s((1, v.vision_heads, v.n_patches, v.vision_dim // v.vision_heads),
              jnp.bfloat16)
     _compile(flash_attention, *chip((qkv, qkv, qkv)))
+
+
+# -- Kimi-K2 / DeepSeek-V3: the latent pool's programs at published widths ----
+
+KIMI_HF = dict(
+    model_type="kimi_k2", hidden_size=7168, num_attention_heads=64,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, intermediate_size=18432,
+    moe_intermediate_size=2048, n_routed_experts=384, ep_size=32,
+    num_experts_per_tok=8, n_shared_experts=1, first_k_dense_replace=1,
+    num_hidden_layers=2, vocab_size=20480, rms_norm_eps=1e-5,
+    rope_theta=50000, routed_scaling_factor=2.827, norm_topk_prob=True,
+    scoring_func="sigmoid", n_group=1, topk_group=1,
+    rope_scaling=dict(type="yarn", factor=64, beta_fast=32, beta_slow=1,
+                      original_max_position_embeddings=4096, mscale=1,
+                      mscale_all_dim=1),
+)
+KIMI_SEQ = 16384
+
+
+def _kimi():
+    """Config and parameter shapes of rank 0 of 32 (12 experts a layer) at
+    Kimi-K2.5's widths, depth cut to the dense layer and one expert
+    layer, as ``kimi_k2.load`` builds them (int8 from the start)."""
+    from dora_tpu.models.hf import kimi_k2
+
+    cfg = kimi_k2.KimiK2Config.from_hf(KIMI_HF, max_seq=KIMI_SEQ)
+    d, h, bf = cfg.dim, cfg.heads, jnp.bfloat16
+    shapes = {
+        "self_attn.q_a_proj.weight": (cfg.q_rank, d),
+        "self_attn.q_a_layernorm.weight": (cfg.q_rank,),
+        "self_attn.q_b_proj.weight": (h * (cfg.nope + cfg.rope), cfg.q_rank),
+        "self_attn.kv_a_proj_with_mqa.weight": (cfg.latent, d),
+        "self_attn.kv_a_layernorm.weight": (cfg.kv_rank,),
+        "self_attn.kv_b_proj.weight": (h * (cfg.nope + cfg.v_dim), cfg.kv_rank),
+        "self_attn.o_proj.weight": (d, h * cfg.v_dim),
+        "input_layernorm.weight": (d,),
+        "post_attention_layernorm.weight": (d,),
+        "mlp.gate.weight": (cfg.n_experts, d),
+        "mlp.gate.e_score_correction_bias": (cfg.n_experts,),
+    }
+
+    def get(name):
+        tail = name.split(".", 3)[3]  # after "model.layers.<i>."
+        if tail in shapes:
+            return jnp.zeros(shapes[tail], bf)
+        # a SwiGLU matrix: the dense layer's, or an expert's / the shared one's
+        dense = tail.startswith(("mlp.gate_proj", "mlp.up_proj", "mlp.down_proj"))
+        width = cfg.ffn if dense else cfg.moe_ffn
+        return jnp.zeros((d, width) if "down_proj" in tail else (width, d), bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": kimi_k2._quantize_t(jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): kimi_k2.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(
+        lambda: kimi_k2.init_page_pool(cfg, SLOTS * KIMI_SEQ // PAGE, PAGE))
+    stats = jax.eval_shape(lambda: kimi_k2.init_counters(cfg))
+    return kimi_k2, cfg, jax.eval_shape(build), pools, stats
+
+
+def _pool_bytes(pools):
+    return max(x.size * x.dtype.itemsize for x in jax.tree.leaves(pools))
+
+
+def test_kimi_chunk_program_compiles_and_updates_the_pool_in_place(chip):
+    """The prefill chunk over the latent pool at real widths. The pool's
+    rows are stored 640 wide because a 576-wide minor dimension made
+    XLA:TPU keep the pool transposed and copy it into and out of the
+    program: the program's temporaries must stay under one layer's pool."""
+    kimi_k2, cfg, params, pools, stats = _kimi()
+    assert cfg.row == 640 and cfg.latent == 576 and cfg.experts_held == 12
+    compiled = jax.jit(
+        lambda p, *a: kimi_k2.fused_paged_chunk_step(p, cfg, *a),
+        donate_argnums=(2, 3),
+    ).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((KIMI_SEQ // PAGE,), I32), _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the int8 matmul kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)
+
+
+def test_kimi_window_program_compiles_and_updates_the_pool_in_place(chip):
+    """The K=8 decode window (vlm.make_paged_window over the absorbed
+    batch step) over the latent pool at real widths, 16 slots."""
+    kimi_k2, cfg, params, pools, stats = _kimi()
+
+    def program(p, *args):
+        return kimi_k2.window_program(p, cfg, 8, None, kimi_k2.ATTN_BLOCK,
+                                      *args)
+
+    compiled = jax.jit(program, donate_argnums=(2, 3)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, KIMI_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)

@@ -229,6 +229,62 @@ def test_radix_lru_eviction_leaf_first_skips_pinned_and_shared():
     a.check_invariants()
 
 
+def test_evictable_pages_of_a_chain_deeper_than_the_recursion_limit():
+    """A 16k-token prompt at page 16 is a chain of 1,024 nodes (the
+    kimi_k2 cell's max_seq): the count must not recurse a level a page.
+    A live stream's hold on the LAST page keeps the whole chain."""
+    import sys
+
+    depth = sys.getrecursionlimit() + 24
+    a, c = _cache(num_pages=depth + 2, page_size=1)
+    pages = a.alloc(depth)
+    c.insert(list(range(1, depth + 1)), pages)
+    assert c.evictable_pages() == 0  # the stream still holds every page
+    a.unref(pages[:-1])
+    assert c.evictable_pages() == 0  # its last page holds up the ancestors
+    a.unref(pages[-1:])
+    assert c.evictable_pages() == depth
+    assert c.evict(depth) == depth
+    a.check_invariants()
+
+
+@pytest.mark.parametrize("free_enough", [True, False])
+def test_admission_counts_evictable_pages_only_when_the_free_list_is_short(
+    free_enough,
+):
+    """can_admit and the prefix grant walk every cached page to count the
+    evictable ones: with 10k cached pages that is milliseconds of host
+    time an admission, beside a decode window. The answer is the same
+    without the walk whenever the free list alone covers the need."""
+    from types import SimpleNamespace
+
+    from dora_tpu.models.batch_engine import PagedBatchEngine
+
+    a, c = _cache(num_pages=32, page_size=4)
+    held = a.alloc(4)
+    c.insert(list(range(1, 17)), held)
+    a.unref(held)  # cache custody only: 4 evictable pages
+    if not free_enough:
+        a.alloc(a.free_pages - 1)  # leave one free page
+    walks = []
+    count = c.evictable_pages
+    c.evictable_pages = lambda: walks.append(1) or count()
+    eng = SimpleNamespace(
+        allocator=a, prefix_cache=c, page_size=4, chunk=4, max_seq=64,
+        lora=None, free_slots=1, _spec_cfg=0, max_slots=1,
+    )
+    for name in ("can_admit", "fits", "pages_needed", "spec_headroom",
+                 "_prefix_grant"):
+        setattr(eng, name, getattr(PagedBatchEngine, name).__get__(eng))
+    eng.free_pages = a.free_pages
+    ids = list(range(1, 17)) + [99, 98]  # 4 cached pages + a tail
+    assert eng.can_admit(len(ids), 2)  # needs 5 pages
+    base, shared = eng._prefix_grant(ids, 2)
+    assert (base, len(shared)) == (16, 4)
+    assert bool(walks) == (not free_enough)
+    a.unref(shared)
+
+
 def test_radix_max_pages_cap_evicts_on_insert():
     a, c = _cache(max_pages=2)
     ids = list(range(1, 13))
