@@ -911,6 +911,180 @@ def attention_batch_step(
 # tokens actually held (vLLM's PagedAttention insight). Physical page 0
 # is reserved as the idle dump: inactive rows point at it and their
 # position-0 writes land there harmlessly.
+#
+# The decode kernel's flash sweep over those pages is a software
+# pipeline (:func:`_paged_sweep`): a page is 16 rows, far too little for
+# one DMA round trip and one matmul each, so the sweep moves in GROUPS
+# of ``_SWEEP_COLS // page`` pages (8 at page 16: one 128-column MXU
+# tile), walks every live row's groups as ONE flat (row, group)
+# schedule built from ``positions``, and keeps the next group's page
+# copies in flight while it multiplies the current one — across row
+# boundaries, and from kernel entry, before this tick's q exists. The
+# chunk and spec kernels below still fetch one page, wait, and multiply
+# (ROADMAP design debt 3).
+
+#: Cache rows (columns of the score tile) one sweep step covers.
+_SWEEP_COLS = 128
+#: Buffer slots of the sweep. Step t lives in slot t % SLOTS; while it
+#: is multiplied, steps t+1 .. t+SLOTS-1 have their copies started. One
+#: group ahead is enough on the v5e: 2, 3 and 4 slots measured the same
+#: to 1 % at 4, 15 and 16 live rows (PERF.md section 6, PR 28) — a step
+#: is bound by issuing its copies and by its own products, not by their
+#: latency.
+_SWEEP_SLOTS = 2
+
+
+def _sweep_pages(page: int, max_pages: int) -> int:
+    """Pages per sweep group: one ``_SWEEP_COLS``-wide tile's worth, never
+    more than a block table holds."""
+    return max(1, min(_SWEEP_COLS // page, max_pages))
+
+
+def _sweep_scratch(batch, max_pages, kv_heads, rows, head_dim, page,
+                   pool_dtype, q_dtype, kv_quant):
+    """Scratch of :func:`_paged_sweep`, in the order it unpacks them:
+    group buffers, their DMA semaphores, the SMEM schedule, the query
+    rows and the online-softmax state."""
+    gp = _sweep_pages(page, max_pages)
+    steps = batch * pl.cdiv(max_pages, gp)
+    group_buf = pltpu.VMEM(
+        (_SWEEP_SLOTS, kv_heads, gp * page, head_dim), pool_dtype
+    )
+    state = (batch, kv_heads, rows)
+    return [
+        group_buf,                                            # kbuf
+        group_buf,                                            # vbuf
+        *(
+            [pltpu.VMEM((_SWEEP_SLOTS, 2, kv_heads, gp * page), jnp.float32)]
+            if kv_quant else []                               # sbuf
+        ),
+        pltpu.SemaphoreType.DMA((_SWEEP_SLOTS, 4 if kv_quant else 2)),
+        pltpu.SMEM((steps,), jnp.int32),                      # step -> row
+        pltpu.SMEM((steps,), jnp.int32),                      # step -> group
+        pltpu.VMEM((*state, head_dim), q_dtype),              # q
+        pltpu.VMEM((*state, 1), jnp.float32),                 # running max
+        pltpu.VMEM((*state, 1), jnp.float32),                 # running sum
+        pltpu.VMEM((*state, head_dim), jnp.float32),          # accumulator
+    ]
+
+
+def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
+                 scale: float, dtype):
+    """Pipelined flash sweep of B paged contexts; call at kernel entry.
+
+    ``pools``: (k, v) HBM pools [P, KV, page, hd], plus the (k, v) scale
+    pools [P, KV, page] on the int8-KV path. ``scratch``: what
+    :func:`_sweep_scratch` declared. Row b attends pool rows
+    ``idx < pos_ref[b]`` through ``bt_ref[b]``, with ``rows`` query rows
+    per kv head (group size x m; m = 1 in the decode kernel) that the
+    caller stores in ``q[b, kv]`` before it calls the returned function.
+
+    On entry this zeroes the group buffers (a group's tail beyond the
+    context is never fetched, and what it holds must be finite for the
+    masked product to ignore it), resets the state to an empty softmax
+    (max -inf, sum 0), writes the flat schedule — every (row, group)
+    with at least one page below the row's position, rows in order, so a
+    row at position 0 contributes no step — and starts the copies of the
+    first SLOTS-1 steps. They need no q: they land under the caller's
+    RMSNorm and qkv product.
+
+    The returned ``run()`` walks the schedule. Step t starts the copies
+    of step t+SLOTS-1 (into the slot step t-1 just released: the next
+    group of this row or the first of the next live row), waits for its
+    own, then does one score product, one online-softmax update and one
+    value product per kv head over the whole group. Columns at or past
+    the position are masked in ``s`` and again in ``p``. ``run()``
+    leaves (max, sum, accumulator) of every row in the state refs, which
+    it returns; the caller folds in what it holds in registers.
+    """
+    kv_quant = len(pools) == 4
+    *bufs, sem, row_ref, grp_ref, q_ref, m_ref, l_ref, acc_ref = scratch
+    kbuf, vbuf = bufs[:2]
+    sbuf = bufs[2] if kv_quant else None
+    slots, kv_heads, cols, _ = kbuf.shape
+    gp = cols // page
+
+    def nblocks(b):  # prior context in pages, the partial one included
+        return (pos_ref[b] + page - 1) // page
+
+    for buf in bufs:
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    total = jnp.int32(0)
+    for b in range(batch):
+        ngroups = (nblocks(b) + gp - 1) // gp
+
+        def fill(g, carry, b=b, base=total):
+            row_ref[base + g] = jnp.int32(b)
+            grp_ref[base + g] = g
+            return carry
+
+        jax.lax.fori_loop(0, ngroups, fill, 0)
+        total = total + ngroups
+
+    def copies(t, go: str):
+        """``start`` or ``wait`` for every page copy of step t."""
+        b, first = row_ref[t], grp_ref[t] * gp
+        slot = jax.lax.rem(t, slots)
+
+        def one(j, carry):
+            pg = bt_ref[b, first + j]
+            at = pl.ds(pl.multiple_of(j * page, page), page)
+            dsts = [kbuf.at[slot, :, at, :], vbuf.at[slot, :, at, :]]
+            if kv_quant:
+                dsts += [sbuf.at[slot, 0, :, at], sbuf.at[slot, 1, :, at]]
+            for i, (pool, dst) in enumerate(zip(pools, dsts)):
+                copy = pltpu.make_async_copy(pool.at[pg], dst, sem.at[slot, i])
+                getattr(copy, go)()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(nblocks(b) - first, gp), one, 0)
+
+    for t in range(slots - 1):
+        pl.when(t < total)(functools.partial(copies, t, "start"))
+
+    def step(t, carry):
+        ahead = t + slots - 1
+        pl.when(ahead < total)(functools.partial(copies, ahead, "start"))
+        copies(t, "wait")
+        b, g = row_ref[t], grp_ref[t]
+        slot = jax.lax.rem(t, slots)
+        live = (
+            jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) + g * cols
+        ) < pos_ref[b]
+        for h in range(kv_heads):
+            if kv_quant:
+                k_h = kv_dequant(kbuf[slot, h], sbuf[slot, 0, h], dtype)
+                v_h = kv_dequant(vbuf[slot, h], sbuf[slot, 1, h], dtype)
+            else:
+                k_h = kbuf[slot, h].astype(dtype)
+                v_h = vbuf[slot, h].astype(dtype)
+            s = jax.lax.dot_general(
+                q_ref[b, h], k_h, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [rows, cols]
+            s = jnp.where(live, s, -jnp.inf)
+            m_old = m_ref[b, h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            m_ref[b, h] = m_new
+            l_ref[b, h] = l_ref[b, h] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True
+            )
+            acc_ref[b, h] = acc_ref[b, h] * alpha + jax.lax.dot(
+                p.astype(dtype), v_h, preferred_element_type=jnp.float32
+            )
+        return carry
+
+    def run():
+        jax.lax.fori_loop(0, total, step, 0)
+        return m_ref, l_ref, acc_ref
+
+    return q_ref, run
 
 
 def _attn_paged_batch_kernel(
@@ -926,29 +1100,88 @@ def _attn_paged_batch_kernel(
     flash sweep walks pool pages through the row's block table, and the
     in-place row write targets the row's CURRENT page.
 
+    Order of events, so that page DMAs are always in flight: (1) at
+    entry, every row's 8-row window of its current page is requested
+    (one semaphore per row, no wait yet) and :func:`_paged_sweep` starts
+    the first group of the flat (row, group) schedule; (2) RMSNorm,
+    the fused qkv product and RoPE run over all rows while those land;
+    (3) one wait for the windows, the current token's K/V inserted in
+    registers, the write-backs started (waited for at the very end);
+    (4) the sweep: groups of 8 pages (128 cache rows at page 16) in
+    two slots, one group ahead, one score and one value product per
+    kv head per group; (5) each row's current token folded in from
+    registers (it never round-trips the pool within its own step), the
+    output projection and the residual.
+
+    The sweep may read a row's current page while its window is being
+    written back: the window's seven other rows are rewritten with the
+    bytes they held, and the eighth is column ``pos``, which the sweep
+    masks.
+
     ``kv_quant`` adds the int8-KV pools: values are
     :func:`kv_quant_rows`-quantized in-register right before the RMW
     insert, per-(row, kv-head) f32 scales ride parallel [P, KV, page]
     scale pools through the SAME page ids, and the flash sweep
-    dequantizes each streamed page in-register — HBM traffic per page
-    is the int8 bytes plus a [KV, page] scale plane. The current row's
-    fold stays exact fp from registers either way (it never round-trips
-    the pool within its own step)."""
+    dequantizes each streamed group in-register — HBM traffic per page
+    is the int8 bytes plus a [KV, page] scale plane."""
     if kv_quant:
         (x_ref, nw_ref, wqkv_ref, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
          kp_in, vp_in, ks_in, vs_in, wo_ref, swo_ref,
          out_ref, kp_out, vp_out, ks_out, vs_out,
-         kv_row, s_row, kblk, vblk, sblk, sem, wsem) = refs
+         kv_row, s_row, wsem, *sweep_scratch) = refs
+        pools = (kp_out, vp_out, ks_out, vs_out)
     else:
         (x_ref, nw_ref, wqkv_ref, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
          kp_in, vp_in, wo_ref, swo_ref,
          out_ref, kp_out, vp_out,
-         kv_row, kblk, vblk, sem, wsem) = refs
+         kv_row, wsem, *sweep_scratch) = refs
+        pools = (kp_out, vp_out)
     half = head_dim // 2
     dtype = x_ref.dtype
     int4 = wqkv_ref.dtype == jnp.uint8
     group = heads // kv_heads
     scale = 1.0 / (head_dim ** 0.5)
+
+    # --- every row's current 8-row window, requested together ---------------
+    # The aligned 8-row read-modify-write of the dense kernel, but the
+    # window lives inside pool page bt[b, pos // page] at in-page offset
+    # pos % page (page is a multiple of 8, so the window never crosses a
+    # page boundary). ``windows(b)`` pairs each pool window with its
+    # VMEM twin.
+    def windows(b):
+        pos = pos_ref[b]
+        cur = bt_ref[b, pos // page]
+        inpage = pos - pos // page * page
+        at = pl.ds(pl.multiple_of(inpage // 8 * 8, 8), 8)
+        pairs = [
+            (kp_out.at[cur, :, at, :], kv_row.at[0, b]),
+            (vp_out.at[cur, :, at, :], kv_row.at[1, b]),
+        ]
+        if kv_quant:
+            # The 8-row scale windows RMW alongside the value windows:
+            # old rows keep their scales (written once, never
+            # requantized), only the current row's slot is replaced.
+            pairs += [
+                (ks_out.at[cur, :, at], s_row.at[0, b]),
+                (vs_out.at[cur, :, at], s_row.at[1, b]),
+            ]
+        return inpage - inpage // 8 * 8, pairs
+
+    # (wsem[i, b] serves row b's read, then, once every read has been
+    # waited for, its write-back.)
+    wins = [windows(b) for b in range(batch)]
+    reads = [
+        pltpu.make_async_copy(hbm, vmem, wsem.at[i, b])
+        for b, (_, pairs) in enumerate(wins)
+        for i, (hbm, vmem) in enumerate(pairs)
+    ]
+    for rd in reads:
+        rd.start()
+
+    q_ref, sweep = _paged_sweep(
+        pos_ref, bt_ref, pools, sweep_scratch,
+        batch=batch, page=page, scale=scale, dtype=dtype,
+    )
 
     # --- projections (all rows at once: one weight pass) --------------------
     h = _rms(x_ref, nw_ref, eps).astype(dtype)  # [B, D]
@@ -973,52 +1206,18 @@ def _attn_paged_batch_kernel(
 
     q = _rotate(qf, _expand(cos_b, heads), _expand(sin_b, heads), half)
     k = _rotate(kf, _expand(cos_b, kv_heads), _expand(sin_b, kv_heads), half)
-    q_b = q.reshape(batch, heads, head_dim)
+    q_b = q.reshape(batch, kv_heads, group, head_dim)
     k_b = k.reshape(batch, kv_heads, head_dim)
     v_b = vf.reshape(batch, kv_heads, head_dim)
 
-    # --- per-row cache RMW into the row's current page ----------------------
-    # Same aligned 8-row read-modify-write as the dense kernel, but the
-    # window lives inside pool page bt[b, pos // page] at in-page offset
-    # pos % page (page is a multiple of 8, so the window never crosses a
-    # page boundary).
+    # --- insert the current token, start the write-backs --------------------
+    for rd in reads:
+        rd.wait()
     pending = []
-    for b in range(batch):
-        pos = pos_ref[b]
-        cur = bt_ref[b, pos // page]
-        inpage = pos - pos // page * page
-        aligned = pl.multiple_of(inpage // 8 * 8, 8)
-        reads = [
-            pltpu.make_async_copy(
-                kp_out.at[cur, :, pl.ds(aligned, 8), :], kv_row.at[0, b],
-                sem.at[0],
-            ),
-            pltpu.make_async_copy(
-                vp_out.at[cur, :, pl.ds(aligned, 8), :], kv_row.at[1, b],
-                sem.at[1],
-            ),
-        ]
-        if kv_quant:
-            # The 8-row scale windows RMW alongside the value windows:
-            # old rows keep their scales (written once, never
-            # requantized), only the current row's slot is replaced.
-            reads += [
-                pltpu.make_async_copy(
-                    ks_out.at[cur, :, pl.ds(aligned, 8)], s_row.at[0, b],
-                    sem.at[6],
-                ),
-                pltpu.make_async_copy(
-                    vs_out.at[cur, :, pl.ds(aligned, 8)], s_row.at[1, b],
-                    sem.at[7],
-                ),
-            ]
-        for rd in reads:
-            rd.start()
-        for rd in reads:
-            rd.wait()
+    for b, (inwin, pairs) in enumerate(wins):
         row_sel = (
             jax.lax.broadcasted_iota(jnp.int32, (kv_heads, 8, head_dim), 1)
-            == inpage - aligned
+            == inwin
         )
         if kv_quant:
             kq, ksc = kv_quant_rows(k_b[b])
@@ -1026,8 +1225,7 @@ def _attn_paged_batch_kernel(
             kv_row[0, b] = jnp.where(row_sel, kq[:, None, :], kv_row[0, b])
             kv_row[1, b] = jnp.where(row_sel, vq[:, None, :], kv_row[1, b])
             s_sel = (
-                jax.lax.broadcasted_iota(jnp.int32, (kv_heads, 8), 1)
-                == inpage - aligned
+                jax.lax.broadcasted_iota(jnp.int32, (kv_heads, 8), 1) == inwin
             )
             s_row[0, b] = jnp.where(s_sel, ksc[:, None], s_row[0, b])
             s_row[1, b] = jnp.where(s_sel, vsc[:, None], s_row[1, b])
@@ -1039,114 +1237,33 @@ def _attn_paged_batch_kernel(
                 row_sel, v_b[b][:, None, :].astype(kv_row.dtype), kv_row[1, b]
             )
         writes = [
-            pltpu.make_async_copy(
-                kv_row.at[0, b], kp_out.at[cur, :, pl.ds(aligned, 8), :],
-                wsem.at[0, b],
-            ),
-            pltpu.make_async_copy(
-                kv_row.at[1, b], vp_out.at[cur, :, pl.ds(aligned, 8), :],
-                wsem.at[1, b],
-            ),
+            pltpu.make_async_copy(vmem, hbm, wsem.at[i, b])
+            for i, (hbm, vmem) in enumerate(pairs)
         ]
-        if kv_quant:
-            writes += [
-                pltpu.make_async_copy(
-                    s_row.at[0, b], ks_out.at[cur, :, pl.ds(aligned, 8)],
-                    wsem.at[2, b],
-                ),
-                pltpu.make_async_copy(
-                    s_row.at[1, b], vs_out.at[cur, :, pl.ds(aligned, 8)],
-                    wsem.at[3, b],
-                ),
-            ]
         for wr in writes:
             wr.start()
         pending += writes
 
-    # --- per-row flash sweep: pool pages through the block table ------------
+    # --- the pipelined sweep over every row's prior context -----------------
+    q_ref[...] = q_b.astype(q_ref.dtype)
+    m_ref, l_ref, acc_ref = sweep()
+
+    # --- fold in each row's current position from registers (exact merge) ---
     attn_rows = []
     for b in range(batch):
-        pos = pos_ref[b]
-        nblocks = (pos + page - 1) // page  # prior context, incl. partial page
-        qb = q_b[b]
-
-        def body(blk, carry, pos=pos, qb=qb, b=b):
-            m_run, l_run, acc = carry
-            pg = bt_ref[b, blk]
-            copies = [
-                pltpu.make_async_copy(kp_out.at[pg], kblk, sem.at[2]),
-                pltpu.make_async_copy(vp_out.at[pg], vblk, sem.at[3]),
-            ]
-            if kv_quant:
-                copies += [
-                    pltpu.make_async_copy(
-                        ks_out.at[pg], sblk.at[0], sem.at[4]
-                    ),
-                    pltpu.make_async_copy(
-                        vs_out.at[pg], sblk.at[1], sem.at[5]
-                    ),
-                ]
-            for cp in copies:
-                cp.start()
-            for cp in copies:
-                cp.wait()
-            live = (
-                jax.lax.broadcasted_iota(jnp.int32, (1, page), 1) + blk * page
-            ) < pos
-            scores = []
-            for g in range(kv_heads):
-                if kv_quant:
-                    k_g = kv_dequant(kblk[g], sblk[0, g], dtype)
-                else:
-                    k_g = kblk[g].astype(dtype)
-                s_g = jax.lax.dot_general(
-                    qb[g * group : (g + 1) * group].astype(dtype),
-                    k_g,
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                scores.append(s_g)
-            s = jnp.concatenate(scores, axis=0) * scale
-            s = jnp.where(live, s, -jnp.inf)
-            m_new = jnp.maximum(m_run, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_run - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_run * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            pv = []
-            for g in range(kv_heads):
-                if kv_quant:
-                    v_g = kv_dequant(vblk[g], sblk[1, g], dtype)
-                else:
-                    v_g = vblk[g].astype(dtype)
-                pv.append(
-                    jax.lax.dot(
-                        p[g * group : (g + 1) * group].astype(dtype),
-                        v_g,
-                        preferred_element_type=jnp.float32,
-                    )
-                )
-            acc_new = acc * alpha + jnp.concatenate(pv, axis=0)
-            return m_new, l_new, acc_new
-
-        m0 = jnp.full((heads, 1), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((heads, 1), jnp.float32)
-        a0 = jnp.zeros((heads, head_dim), jnp.float32)
-        m_fin, l_fin, acc = jax.lax.fori_loop(0, nblocks, body, (m0, l0, a0))
-
-        # fold in the current position from registers (exact merge)
-        q3 = qb.reshape(kv_heads, group, head_dim)
-        s_new = (
-            jnp.sum(q3 * k_b[b][:, None, :], axis=-1).reshape(heads, 1)
-            * scale
-        )
+        m_fin, l_fin, acc = m_ref[b], l_ref[b], acc_ref[b]
+        s_new = jnp.sum(
+            q_b[b] * k_b[b][:, None, :], axis=-1, keepdims=True
+        ) * scale  # [KV, group, 1]
         m2 = jnp.maximum(m_fin, s_new)
         alpha = jnp.exp(m_fin - m2)
         w_new = jnp.exp(s_new - m2)
         l2 = l_fin * alpha + w_new
-        v_full = jnp.broadcast_to(
-            v_b[b][:, None, :], (kv_heads, group, head_dim)
-        ).reshape(heads, head_dim)
-        attn_rows.append((acc * alpha + w_new * v_full) / l2)
+        attn_rows.append(
+            ((acc * alpha + w_new * v_b[b][:, None, :]) / l2).reshape(
+                heads, head_dim
+            )
+        )
 
     attn = jnp.stack(attn_rows, axis=0).reshape(batch, heads * head_dim)
 
@@ -1177,6 +1294,17 @@ def attention_paged_batch_step(
     [B, max_pages] int32 physical page ids (0 = the reserved idle page).
     Weight layout matches :func:`attention_batch_step`. Returns
     (x_out [B, D], k_pool, v_pool).
+
+    One kernel call, ``grid=(1,)``. The rows' contexts are swept by
+    :func:`_paged_sweep`: groups of ``128 // page`` pages (8 pages, 128
+    cache rows, at page 16) in ``_SWEEP_SLOTS`` = 2 buffers of
+    [KV, 128, hd] each for K and V; only (row, group) pairs below a
+    row's position are scheduled, so a frozen row (position 0) costs no
+    step and a 20-token row one. In flight at any time: from kernel
+    entry, every row's 8-row write window and the first group; during
+    the sweep, the group after the one being multiplied (the next row's
+    first when a row ends); from the insert of the current token to the
+    end, the 2B window write-backs.
 
     ``k_scale``/``v_scale`` (both or neither) switch on the int8-KV
     path: pools must be int8 and the scales are parallel [P, KV, page]
@@ -1256,14 +1384,11 @@ def attention_paged_batch_step(
         scratch_shapes=[
             pltpu.VMEM((2, batch, kv_heads, 8, head_dim), k_pool.dtype),
             *scale_scratch,
-            pltpu.VMEM((kv_heads, page, head_dim), k_pool.dtype),
-            pltpu.VMEM((kv_heads, page, head_dim), v_pool.dtype),
-            *(
-                [pltpu.VMEM((2, kv_heads, page), jnp.float32)]  # sblk
-                if kv_quant else []
+            pltpu.SemaphoreType.DMA((len(pool_specs), batch)),  # wsem
+            *_sweep_scratch(
+                batch, block_tables.shape[1], kv_heads, heads // kv_heads,
+                head_dim, page, k_pool.dtype, x.dtype, kv_quant,
             ),
-            pltpu.SemaphoreType.DMA((8 if kv_quant else 4,)),
-            pltpu.SemaphoreType.DMA((4 if kv_quant else 2, batch)),
         ],
     )
     operands = [k_pool, v_pool]

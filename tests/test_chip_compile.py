@@ -154,6 +154,44 @@ def test_paged_batch_step_compiles(chip, kv_int8):
     )
 
 
+@pytest.mark.parametrize("slots", [SLOTS, 8])
+def test_paged_batch_attention_compiles_with_its_group_buffers(chip, slots):
+    """The decode attention kernel alone at the serve cells' shape (16
+    rows, tables of 128 pages of 16, 12/2 heads of 128; 8 rows as
+    ``DORA_BATCH_SLOTS=8`` would give it): the pipelined sweep's scratch
+    — two slots of [KV, 128, hd] for K and for V, the SMEM schedule of
+    rows x 16 groups, q and the softmax state per row — beside the 5.5
+    MB of int8 qkv and output weights the call keeps in VMEM."""
+    from dora_tpu.ops import decode_block as DB
+
+    blk = _qparams(CFG)["blocks"]["0"]
+    pool = _pools(False)["0"]
+    hd = CFG.head_dim
+    assert DB._sweep_pages(PAGE, MAX_PAGES) * PAGE == 128
+    scratch = DB._sweep_scratch(
+        slots, MAX_PAGES, CFG.kv_heads, CFG.heads // CFG.kv_heads, hd,
+        PAGE, jnp.bfloat16, jnp.bfloat16, False)
+    assert scratch[0].shape == (DB._SWEEP_SLOTS, CFG.kv_heads, 128, hd)
+    assert scratch[3].shape == (slots * MAX_PAGES // 8,)  # step -> row
+
+    def step(x, blk, cos, sin, kp, vp, positions, tables):
+        w, o = blk["wqkv"], blk["wo"]
+        return DB.attention_paged_batch_step(
+            x, blk["attn_norm"], w["int8"], w["scale"], blk["bqkv"], cos,
+            sin, kp, vp, o["int8"], o["scale"], positions, tables,
+            heads=CFG.heads, kv_heads=CFG.kv_heads, head_dim=hd,
+            eps=CFG.norm_eps,
+        )
+
+    _compile(
+        step,
+        *chip((_s((slots, CFG.dim), jnp.bfloat16), blk,
+               _s((slots, hd), jnp.float32), _s((slots, hd), jnp.float32),
+               pool["k"], pool["v"], _s((slots,), I32),
+               _s((slots, MAX_PAGES), I32))),
+    )
+
+
 @KV_KINDS
 def test_paged_spec_step_compiles(chip, kv_int8):
     m = 5  # DORA_SPEC_K=4 drafts + the last emitted token

@@ -1,0 +1,194 @@
+"""The paged decode kernel's pipelined sweep against plain attention.
+
+``ops.decode_block.attention_paged_batch_step`` walks every live row's
+pages as one flat schedule of page GROUPS (8 pages = 128 cache rows at
+page 16), several groups' copies in flight. What that could get wrong is
+exactly what the token-identity tests of ``test_paged_engine.py`` are too
+coarse to pin down: a group's tail beyond the context, a group that ends
+on the context's last row, a row that has no group at all, a buffer slot
+reused before it was read, a page id taken from the wrong row. So this
+file holds the kernel, under the Pallas interpreter, to a per-row
+attention written in numpy over the same pool, at the positions where
+those cases live, with page ids shuffled over the pool, live and frozen
+rows mixed, and loud stale content everywhere the context is not.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp
+
+from dora_tpu.models import layers as L
+from dora_tpu.ops import decode_block as DB
+
+D, H, KV, HD, PAGE = 64, 4, 2, 16, 16
+MAX_PAGES = 20  # not a multiple of the group: the last group is short
+SEQ = MAX_PAGES * PAGE
+GROUP = DB._sweep_pages(PAGE, MAX_PAGES) * PAGE  # cache rows a step covers
+#: 0 = a frozen row's position; the last one fills every page of a row
+POSITIONS = (0, 1, PAGE - 1, PAGE, GROUP - 1, GROUP, GROUP + 1, SEQ - 1)
+#: what a page holds where no context was written: finite and far from it
+STALE = 40.0
+
+
+def test_group_is_one_lane_tile_of_cache_rows():
+    assert GROUP == 128 and DB._sweep_pages(16, 128) == 8
+    assert DB._sweep_pages(8, 8) == 8       # 64 rows: all a table holds
+    assert DB._sweep_pages(256, 8) == 1     # a page wider than a tile
+    assert DB._SWEEP_SLOTS >= 2             # one group ahead at least
+
+
+def _weights(rng):
+    from dora_tpu.ops.int8_matmul import quantize_int8
+
+    nw = jnp.asarray(rng.standard_normal(D), jnp.float32)
+    wqkv = quantize_int8(jnp.asarray(
+        rng.standard_normal((D, (H + 2 * KV) * HD)) * 0.2, jnp.float32))
+    wo = quantize_int8(jnp.asarray(
+        rng.standard_normal((H * HD, D)) * 0.2, jnp.float32))
+    bqkv = jnp.asarray(rng.standard_normal((H + 2 * KV) * HD), jnp.float32)
+    return nw, wqkv, bqkv, wo
+
+
+def _setup(rng, positions, active, kv_int8):
+    """Pools whose every row is stale except the live rows' contexts,
+    block tables over shuffled page ids, and the operands of one tick."""
+    batch = len(positions)
+    pages = 1 + batch * MAX_PAGES
+    ids = 1 + rng.permutation(pages - 1).astype(np.int32)
+    bt = ids.reshape(batch, MAX_PAGES)  # every row owns MAX_PAGES ids
+    sign = rng.choice([-1.0, 1.0], size=(2, pages, KV, PAGE, HD))
+    kf, vf = (STALE * sign).astype(np.float32)
+    for b, pos in enumerate(positions):
+        for idx in range(pos):
+            pg, off = bt[b, idx // PAGE], idx % PAGE
+            kf[pg, :, off] = rng.standard_normal((KV, HD))
+            vf[pg, :, off] = rng.standard_normal((KV, HD))
+    if kv_int8:
+        kq, ks = DB.kv_quant_rows(jnp.asarray(kf))
+        vq, vs = DB.kv_quant_rows(jnp.asarray(vf))
+        pools = (kq, vq, ks, vs)
+        kf = np.asarray(DB.kv_dequant(kq, ks, jnp.float32))
+        vf = np.asarray(DB.kv_dequant(vq, vs, jnp.float32))
+    else:
+        pools = (jnp.asarray(kf), jnp.asarray(vf))
+    x = jnp.asarray(rng.standard_normal((batch, D)), jnp.float32)
+    pos_in, bt_in = DB.freeze_inactive(
+        jnp.asarray(positions, jnp.int32), jnp.asarray(bt),
+        jnp.asarray(active),
+    )
+    return x, pools, (kf, vf), pos_in, bt_in
+
+
+def _reference(x, weights, kf, vf, positions, bt):
+    """Per-row attention in float64 numpy: the kernel's own projection
+    formulas, then a plain softmax over the row's gathered context and
+    its current token. Returns (x_out, k_new, v_new)."""
+    nw, wqkv, bqkv, wo = weights
+    x = np.asarray(x, np.float64)
+    h = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * np.asarray(nw)
+    h = h.astype(np.float32).astype(np.float64)
+    qkv = h @ np.asarray(wqkv["int8"], np.float64) * np.asarray(
+        wqkv["scale"], np.float64) + np.asarray(bqkv)
+    cos_t, sin_t = L.rope_table(SEQ, HD)
+    cos, sin = (np.asarray(t, np.float64) for t in DB.rope_rows_at(
+        cos_t, sin_t, jnp.asarray(positions, jnp.int32)))
+
+    def rot(t):  # [B, n, HD]
+        swapped = np.concatenate([t[..., HD // 2:], t[..., : HD // 2]], -1)
+        return t * cos[:, None] + swapped * sin[:, None]
+
+    batch = x.shape[0]
+    q = rot(qkv[:, : H * HD].reshape(batch, H, HD))
+    k_new = rot(qkv[:, H * HD: (H + KV) * HD].reshape(batch, KV, HD))
+    v_new = qkv[:, (H + KV) * HD:].reshape(batch, KV, HD)
+    attn = np.zeros((batch, H, HD))
+    for b, pos in enumerate(positions):
+        idx = np.arange(pos)
+        pg, off = bt[b, idx // PAGE], idx % PAGE
+        for head in range(H):
+            g = head // (H // KV)
+            keys = np.concatenate([kf[pg, g, off], k_new[b, g][None]])
+            vals = np.concatenate([vf[pg, g, off], v_new[b, g][None]])
+            s = keys @ q[b, head] / np.sqrt(HD)
+            p = np.exp(s - s.max())
+            attn[b, head] = p @ vals / p.sum()
+    attn = attn.reshape(batch, H * HD).astype(np.float32).astype(np.float64)
+    out = x + attn @ np.asarray(wo["int8"], np.float64) * np.asarray(
+        wo["scale"], np.float64)
+    return out, k_new, v_new
+
+
+def _check(seed, positions, active, kv_int8):
+    rng = np.random.default_rng(seed)
+    weights = _weights(rng)
+    nw, wqkv, bqkv, wo = weights
+    x, pools, (kf, vf), pos_in, bt_in = _setup(rng, positions, active, kv_int8)
+    cos_t, sin_t = L.rope_table(SEQ, HD)
+    cosr, sinr = DB.rope_rows_at(cos_t, sin_t, pos_in)
+    out = DB.attention_paged_batch_step(
+        x, nw, wqkv["int8"], wqkv["scale"], bqkv, cosr, sinr,
+        pools[0], pools[1], wo["int8"], wo["scale"], pos_in, bt_in,
+        *pools[2:], heads=H, kv_heads=KV, head_dim=HD,
+    )
+    pos_np, bt_np = np.asarray(pos_in), np.asarray(bt_in)
+    want, k_new, v_new = _reference(x, weights, kf, vf, pos_np, bt_np)
+    got = np.asarray(out[0])
+    assert np.isfinite(got).all()
+    # float32 sums in another order; one stale row let in moves it by 1e-1
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+    # the pools: one row written per batch row, every other bit as it was
+    # (frozen rows all write row 0 of the null page: any of them may win)
+    new = [np.asarray(a) for a in out[1:]]
+    old = [np.asarray(a).copy() for a in pools]
+    dumped = int((bt_np[np.arange(len(pos_np)), pos_np // PAGE] == 0).sum())
+    for b, pos in enumerate(pos_np):
+        pg, off = bt_np[b, pos // PAGE], pos % PAGE
+        if kv_int8:
+            rows = (*DB.kv_quant_rows(jnp.asarray(k_new[b], jnp.float32)),
+                    *DB.kv_quant_rows(jnp.asarray(v_new[b], jnp.float32)))
+            rows = [np.asarray(rows[i]) for i in (0, 2, 1, 3)]
+        else:
+            rows = [k_new[b], v_new[b]]
+        for plane, was, row in zip(new, old, rows):
+            if pg == 0 and dumped > 1:
+                assert pos == 0
+                was[pg, :, off] = plane[pg, :, off]
+                continue
+            if plane.dtype == np.int8:  # round-to-nearest of a near-tie
+                assert np.abs(plane[pg, :, off].astype(int) - row).max() <= 1
+            else:
+                np.testing.assert_allclose(
+                    plane[pg, :, off], row, rtol=1e-5, atol=1e-5)
+            was[pg, :, off] = plane[pg, :, off]
+    for plane, was in zip(new, old):
+        np.testing.assert_array_equal(plane, was)
+
+
+KV_KINDS = pytest.mark.parametrize(
+    "kv_int8", [False, True], ids=["fp_kv", "int8_kv"])
+
+
+@KV_KINDS
+@pytest.mark.parametrize("pos", POSITIONS)
+def test_one_row_matches_plain_attention(pos, kv_int8):
+    _check(100 + pos, [pos], [pos > 0], kv_int8)
+
+
+@KV_KINDS
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sixteen_rows_live_and_frozen_match_plain_attention(seed, kv_int8):
+    """Every boundary position twice over 16 rows in a seeded order, five
+    of them frozen mid-life (their positions and tables zeroed by
+    ``freeze_inactive``, as a decode window does the tick they finish):
+    a live row's first group follows a frozen row's none, short rows sit
+    between long ones, and every slot is reused across row boundaries."""
+    rng = np.random.default_rng(seed)
+    positions = rng.permutation(np.repeat(POSITIONS, 2)).tolist()
+    active = np.ones(16, bool)
+    active[rng.choice(16, size=5, replace=False)] = False
+    _check(seed, positions, active.tolist(), kv_int8)
