@@ -75,6 +75,11 @@ def measure(ctx, run: dict) -> dict:
         "attempted": len(inside), "failed": len(bad),
         "correct": bool(inside) and not bad and len(distinct) <= ctx.traffic["frames"],
         "lines": lines,
+        "compared": {
+            "outputs": stats.compared(len(inside), 1, at_most=False),
+            "bad_outputs": stats.compared(len(bad), 0),
+            "distinct_outputs": stats.compared(len(distinct), ctx.traffic["frames"]),
+        },
     }
 
 

@@ -85,6 +85,32 @@ def sleep_until(t: float) -> None:
         time.sleep(wait - 0.002 if wait > 0.004 else min(wait, 0.002))
 
 
+class Heartbeat(threading.Thread):
+    """Sleeps ``step_s`` at a time and keeps every wake that came more
+    than ``over_s`` late, as ``[when it should have woken, seconds late]``:
+    a pause of this process or of its whole machine, seen from the load
+    generator's side. A pause the server makes alone does not show here."""
+
+    def __init__(self, step_s: float = 0.02, over_s: float = 0.1):
+        super().__init__(daemon=True)
+        self.step_s, self.over_s = step_s, over_s
+        self.pauses: list[list[float]] = []
+        self._halt = threading.Event()
+
+    def run(self) -> None:
+        while not self._halt.is_set():
+            due = time.monotonic() + self.step_s
+            time.sleep(self.step_s)
+            late = time.monotonic() - due
+            if late > self.over_s:
+                self.pauses.append([due, late])
+
+    def stop(self) -> list[list[float]]:
+        self._halt.set()
+        self.join(1.0)
+        return list(self.pauses)
+
+
 def _record(i: int, req: dict, due: float, got: dict) -> dict:
     return {"i": i, "due": due, "max_tokens": req["max_tokens"],
             "prompt_tokens": req["prompt_tokens"], **got}
@@ -199,6 +225,8 @@ def main(plan_fn) -> int:
     plan = plan_fn(ctx["traffic"], ctx["seed"], ctx["seconds"], ctx["config"])
     wait_for_server(ctx["port"], ctx["timeout_s"])
     say("server_up")
+    beat = Heartbeat()
+    beat.start()
     if plan["mode"] == "closed":
         raw = run_closed(ctx["port"], plan["requests"], plan["callers"],
                          ctx["seconds"], ctx["timeout_s"])
@@ -206,6 +234,7 @@ def main(plan_fn) -> int:
         raw = run_open(ctx["port"], plan["warm"], plan["requests"],
                        plan["lead_s"], ctx["seconds"], plan["drain_s"],
                        ctx["timeout_s"])
+    raw["generator_pauses"] = beat.stop()
     json.dump(raw, open(ctx["result"], "w"))
     say("done")
     return 0

@@ -51,7 +51,13 @@ def measure(ctx, run: dict, plan: dict) -> dict:
         "completed_in_window": m["completed_in_window"],
         "requests_per_s": m["requests_per_s"],
         "ttft_p50_ms": m.get("ttft_p50_ms"), "tpot_p50_ms": m.get("tpot_p50_ms"),
+        "ttft_p95_ms": m.get("ttft_p95_ms"), "tpot_p95_ms": m.get("tpot_p95_ms"),
+        "tokens_per_s": m["tokens_per_s"],
         "generator_lateness_ms": stats.lateness_ms(reqs, t0, t1),
+        "delta_stalls": stats.stalls(reqs, t0, t1),
+        "generator_pauses": stats.pauses_in_window(raw.get("generator_pauses", []), t0, t1),
+        "dispatch_gap_us": stats.hist_delta(
+            run.get("serving_before"), run.get("serving_after"), "dispatch_gap_us"),
         "plan_exhausted": raw["plan_exhausted"],
         "errors": sorted({str(r["error"])[:120] for r in reqs if r.get("error")})[:5],
     }}]
@@ -104,17 +110,25 @@ def measure(ctx, run: dict, plan: dict) -> dict:
         ref_ok = ref is not None and all(
             s["max_deficit_bf16_ulps"] <= NEAR_TIE_ULPS for s in ref["samples"]
         )
+    compared = {
+        "short_streams": stats.compared(len(short), 0),
+        "requests_due": stats.compared(m["attempted"], 1, at_most=False),
+        "reference_samples": stats.compared(len(ref["samples"]) if ref else 0, 1, at_most=False),
+        "max_deficit_bf16_ulps": stats.compared(
+            max((s["max_deficit_bf16_ulps"] for s in ref["samples"]), default=None)
+            if ref else None, NEAR_TIE_ULPS),
+    }
     metrics = {
         "tokens_per_s": {"value": m["tokens_per_s"], "unit": "tokens/s"},
     }
-    for key in ("ttft_p95_ms", "tpot_p95_ms"):
+    for key in ("ttft_p95_ms", "tpot_p50_ms", "tpot_p95_ms"):
         if key in m:
             metrics[key] = {"value": m[key], "unit": "ms"}
     return {
         "metrics": metrics, "attempted": m["attempted"], "failed": m["failed"],
         "correct": (not short and ref_ok and m["attempted"] > 0
                     and not raw["plan_exhausted"]),
-        "lines": lines, "reference_device": ref and ref["device"],
+        "lines": lines, "reference_device": ref and ref["device"], "compared": compared,
     }
 
 

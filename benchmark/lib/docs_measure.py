@@ -185,6 +185,20 @@ def measure(ctx, run: dict, plan: dict) -> dict:
         ref_ok = ref is not None and off_top <= OFF_TOP_SHARE and all(
             s["max_deficit_bf16_ulps"] <= NEAR_TIE_ULPS for s in ref["samples"]
         ) and bool(first_layer) and max(first_layer) <= LATENT_ROW_REL_ERR
+    samples = ref["samples"] if ref else []
+    compared = {
+        "short_streams": stats.compared(len(short), 0),
+        "requests_due": stats.compared(m["attempted"], 1, at_most=False),
+        "reference_samples": stats.compared(len(samples), 1, at_most=False),
+        "max_deficit_bf16_ulps": stats.compared(
+            max((s["max_deficit_bf16_ulps"] for s in samples), default=None), NEAR_TIE_ULPS),
+        "off_top_share": stats.compared(off_top if samples else None, OFF_TOP_SHARE),
+        "cache_rel_err_layer0": stats.compared(
+            max(first_layer) if samples and first_layer else None, LATENT_ROW_REL_ERR),
+        "cache_bytes_a_value": stats.compared(
+            row_bytes / (model["kv_lora_rank"] + model["qk_rope_head_dim"]),
+            CACHE_BYTES_PER_VALUE, at_most=False),
+    }
     metrics = (
         {"ttft_p95_ms": {"value": m["ttft_p95_ms"], "unit": "ms"}}
         if "ttft_p95_ms" in m else {}
@@ -193,7 +207,7 @@ def measure(ctx, run: dict, plan: dict) -> dict:
         "metrics": metrics, "attempted": m["attempted"], "failed": m["failed"],
         "correct": (not short and ref_ok and bytes_ok and m["attempted"] > 0
                     and not raw["plan_exhausted"]),
-        "lines": lines, "reference_device": ref and ref["device"],
+        "lines": lines, "reference_device": ref and ref["device"], "compared": compared,
     }
 
 

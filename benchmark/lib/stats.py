@@ -105,6 +105,64 @@ def lateness_ms(requests: list[dict], t0: float, t1: float) -> dict:
             "max": max(late)}
 
 
+def stalls(requests: list[dict], t0: float, t1: float, over_ms: float = 250.0) -> dict:
+    """Gaps between consecutive content deltas of one stream that are
+    longer than ``over_ms`` and end inside the window: how many there
+    were, on how many episodes (gaps that overlap in time are one: a
+    pause of the host or of its machine falls on every live stream at
+    once), the seconds those episodes cover and the longest gap. Deltas
+    come a decode window apart (tens of ms), so 250 ms is a pause."""
+    spans = sorted(
+        (a, b) for r in requests if ok(r)
+        for (a, _), (b, _) in zip(r["deltas"], r["deltas"][1:])
+        if (b - a) * 1e3 > over_ms and t0 <= b < t1
+    )
+    episodes: list[list[float]] = []
+    for a, b in spans:
+        if episodes and a < episodes[-1][1]:
+            episodes[-1][1] = max(episodes[-1][1], b)
+        else:
+            episodes.append([a, b])
+    return {
+        "over_ms": over_ms, "gaps": len(spans), "episodes": len(episodes),
+        "episode_s": sum(b - a for a, b in episodes),
+        "longest_ms": max(((b - a) * 1e3 for a, b in spans), default=0.0),
+        "episodes_at_s": [[a - t0, b - a] for a, b in episodes[:8]],
+    }
+
+
+def pauses_in_window(pauses: list[list[float]], t0: float, t1: float) -> dict:
+    """The load process's own late wakes (``chat_client.Heartbeat``:
+    [when due, seconds late]) that fell inside the window."""
+    inside = [(at, late) for at, late in pauses if t0 <= at < t1]
+    return {
+        "n": len(inside), "total_s": sum(late for _, late in inside),
+        "longest_ms": max((late * 1e3 for _, late in inside), default=0.0),
+        "at_s": [[at - t0, late] for at, late in inside[:8]],
+    }
+
+
+def hist_delta(before: dict | None, after: dict | None, key: str) -> dict | None:
+    """What one of ``ServingMetrics``' histograms (``count``, ``sum_us``,
+    octave ``counts``: bucket i holds [2^(i-1), 2^i) us) gained between
+    two snapshots of the node; None where either snapshot lacks it."""
+    a, b = (before or {}).get(key), (after or {}).get(key)
+    if not a or not b or not all(k in h for h in (a, b) for k in ("count", "sum_us")):
+        return None
+    return {
+        "count": b["count"] - a["count"], "sum_us": b["sum_us"] - a["sum_us"],
+        "counts": [y - x for x, y in zip(a.get("counts", []), b.get("counts", []))],
+    }
+
+
+def compared(value, limit, at_most: bool = True) -> dict:
+    """One number that ``correct`` rests on, beside its limit: it holds
+    where the value is at most (or, ``at_most=False``, at least) the
+    limit. A value that could not be read (None) does not hold."""
+    holds = value is not None and (value <= limit if at_most else value >= limit)
+    return {"value": value, "limit": limit, "rule": "<=" if at_most else ">=", "holds": holds}
+
+
 def gaps_ms(stamps: list[float], t0: float, t1: float) -> list[float]:
     inside = [s for s in stamps if t0 <= s < t1]
     return [(b - a) * 1e3 for a, b in zip(inside, inside[1:])]

@@ -160,6 +160,16 @@ async def run_dataflow(ctx, graph, generator) -> dict:
             await asyncio.sleep(max(0.0, at - time.monotonic()))
             if graph.PROFILE_BY == "daemon":
                 daemon.profile_node(df, graph.MODEL_NODE, "start", ctx.trace_seconds)
+                # The capture is written at the node's next report, and
+                # writing it holds the server's loop for seconds (9-12 s at
+                # Qwen2.5-1.5B) inside one dispatch gap: the profiler's time,
+                # not the program's. A third snapshot, two reports after the
+                # capture was written, lets a reader of the serving
+                # histograms start behind it.
+                while not profile and time.monotonic() < at + 75.0:
+                    await asyncio.sleep(0.1)
+                await asyncio.sleep(2.5)
+                run["serving_traced"] = serving()
             else:
                 (ctx.workdir / "trace.go").touch()
 
@@ -183,21 +193,17 @@ async def run_dataflow(ctx, graph, generator) -> dict:
                 run.setdefault("timeline_s", {})[event["event"]] = event["t"] - T_START
                 if event["event"] == "window_start":
                     run["t0"] = event["t0"]
-                    run["compiles"]["before"] = serving().get("compiles")
+                    run["serving_before"] = serving()
+                    run["compiles"]["before"] = run["serving_before"].get("compiles")
                     if ctx.trace:
                         tasks.append(asyncio.create_task(start_trace(event["t0"] + 1.0)))
                 elif event["event"] == "window_end":
                     run["t1"] = event["t1"]
                     await asyncio.sleep(1.3)  # the node reports once a second
-                    run["compiles"]["after"] = serving().get("compiles")
                     run["serving_after"] = serving()
+                    run["compiles"]["after"] = run["serving_after"].get("compiles")
             if await load.wait() != 0:
                 raise RuntimeError(f"load process exited {load.returncode}")
-            if ctx.trace:  # the capture is written at the node's next report
-                for _ in range(100):
-                    if profile:
-                        break
-                    await asyncio.sleep(0.1)
         else:
             first = ctx.workdir / "sink.json"
             while not first.exists():
@@ -377,6 +383,12 @@ def main() -> int:
                 k: v for k, v in measured["metrics"].items() if k in names
             }
             result["metrics"]["setup_s"] = {"value": setup_s, "unit": "s"}
+        # every number ``correct`` rests on beside its limit: the last lines
+        # of stderr, and the last key of the result
+        compared = measured.get("compared") or {}
+        for name, c in compared.items():
+            log(f"compared {name}: {c['value']} {c['rule']} {c['limit']}"
+                f"{'' if c['holds'] else '  FAILS'}")
         if not on_chip:
             # a rehearsal: counts and names only, never a device number
             print(json.dumps({
@@ -384,9 +396,10 @@ def main() -> int:
                 "reason": f"the model node said {device.get('platform')!r}, not 'tpu'",
                 "checks_passed": result["correct"],
                 "attempted": result["attempted"], "failed": result["failed"],
-                "metric_names": sorted(result["metrics"]),
+                "metric_names": sorted(result["metrics"]), "compared": compared,
             }), flush=True)
             return 1
+        result["compared"] = compared
         print(json.dumps(result), flush=True)
         status = 0
     except Exception as e:

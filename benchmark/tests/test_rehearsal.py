@@ -30,5 +30,7 @@ def test_tiny_rehearsal_is_never_a_result(cell, trace):
     assert "metrics" not in last and "device" not in last  # no device number
     assert last["checks_passed"] is True, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert last["attempted"] > 0 and last["failed"] == 0
+    assert last["compared"] and all(c["holds"] for c in last["compared"].values())
+    assert proc.stderr.strip().splitlines()[-1].startswith("compared ")
     if trace == 0:
         assert "setup_s" in last["metric_names"]
