@@ -565,37 +565,6 @@ def alerts_ab_leg() -> dict:
     }
 
 
-def serving_engine_ab() -> dict:
-    """Paged-vs-dense serving engine A/B (tools/bench_serving): decode
-    tok/s + TTFT p50/p99 at 4 streams (both engines, the ±3% parity
-    axis) and at 16 streams (paged 16-slot pool vs dense 4-slot queue,
-    SAME KV HBM). Runs in a fresh subprocess so the accelerator isn't
-    claimed by the bench parent (same rule as serving_fps)."""
-    import subprocess
-    import sys as _sys
-
-    proc = subprocess.run(
-        [_sys.executable, "-m", "dora_tpu.tools.bench_serving"],
-        capture_output=True, text=True, timeout=1800,
-        cwd=str(Path(__file__).resolve().parent),
-    )
-    data = None
-    for line in (proc.stdout or "").splitlines():
-        try:
-            row = json.loads(line)
-        except ValueError:
-            continue
-        if "streams4" in row:
-            data = row
-    if proc.returncode != 0 or data is None:
-        return {
-            "streams4": None,
-            "streams16": None,
-            "note": f"subprocess failed: {(proc.stderr or '')[-200:]!r}",
-        }
-    return data
-
-
 def serving_multistep_ab() -> dict:
     """K-sweep of the fused multi-step decode window
     (tools/bench_serving --multistep): host round-trips — engine
@@ -603,8 +572,9 @@ def serving_multistep_ab() -> dict:
     tok/s, at K in {1, 4, 8, 16} for 4 and 16 streams. The headline is
     ``k8_vs_k1_rt_reduction`` (the ≥4x amortization gate), a host-side
     COUNT and therefore independent of how wall-clock serving numbers
-    drift between sessions. Fresh subprocess
-    for the same accelerator-claim reason as serving_engine_ab."""
+    drift between sessions. Runs in a fresh subprocess so the
+    accelerator isn't claimed by the bench parent (same rule as
+    serving_fps)."""
     import subprocess
     import sys as _sys
 
@@ -640,7 +610,7 @@ def serving_trace_ab() -> dict:
     per-chunk s_prefill_chunk spans, and admission spans into the flight
     ring; the gate is ≤3% wall-clock overhead so the serving timeline
     can stay on in production. Fresh subprocess for the same
-    accelerator-claim reason as serving_engine_ab."""
+    accelerator-claim reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -677,7 +647,7 @@ def serving_profiling_ab() -> dict:
     streams on the stub engine, trials interleaved. Gate: <= 3%
     wall-clock overhead so the plane can stay default-on. Fresh
     subprocess for the same accelerator-claim reason as
-    serving_engine_ab."""
+    serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -713,7 +683,7 @@ def serving_fleet_digest_ab() -> dict:
     default) vs off on the 16-stream stub serving leg, interleaved
     paired trials. Gate: <= 3% wall-clock overhead so the fleet plane
     can stay default-on. Fresh subprocess for the same
-    accelerator-claim reason as serving_engine_ab."""
+    accelerator-claim reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -752,7 +722,7 @@ def serving_spec_ab() -> dict:
     multiply what the K-window already amortizes) and
     ``rand_k4_vs_k0_tpd_at_k8`` (the <=10%-regression bound when
     nothing accepts). Fresh subprocess for the same accelerator-claim
-    reason as serving_engine_ab."""
+    reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -789,7 +759,7 @@ def serving_qos_soak() -> dict:
     trace. Headline: ``interactive_p99_on_vs_off`` < 1.0 — shaping
     must buy the interactive class TTFT under overload; shed rate and
     preempt/resume counts ride along. Fresh subprocess for the same
-    accelerator-claim reason as serving_engine_ab."""
+    accelerator-claim reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -827,7 +797,7 @@ def serving_prefix_ab() -> dict:
     halve hit-request TTFT vs the same requests uncached (the serving
     default-on gate); hit rate, prefill-chunk deltas, and eviction
     counts ride along. Fresh subprocess for the same accelerator-claim
-    reason as serving_engine_ab."""
+    reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -864,7 +834,7 @@ def serving_quant_ab() -> dict:
     capacity leg counting concurrent admissions into the same pool
     byte budget. Headline: ``int8_capacity_ratio`` >= 1.8 (concurrent
     streams in the fp pool's HBM footprint). Fresh subprocess for the
-    same accelerator-claim reason as serving_engine_ab."""
+    same accelerator-claim reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -899,7 +869,7 @@ def serving_lora_ab() -> dict:
     engines splitting the same HBM budget, plus the adapter-churn leg
     counting steady-state compiles. Headline: ``lora_aggregate_ratio``
     >= 1.5 and ``churn.steady_state_compiles`` == 0. Fresh subprocess
-    for the same accelerator-claim reason as serving_engine_ab."""
+    for the same accelerator-claim reason as serving_fps."""
     import subprocess
     import sys as _sys
 
@@ -1084,15 +1054,6 @@ def main() -> int:
         }
 
     try:
-        engine_ab = serving_engine_ab()
-    except Exception as exc:
-        engine_ab = {
-            "streams4": None,
-            "streams16": None,
-            "note": f"failed: {exc!r}"[:200],
-        }
-
-    try:
         multistep_ab = serving_multistep_ab()
     except Exception as exc:
         multistep_ab = {
@@ -1206,7 +1167,6 @@ def main() -> int:
         "lockcheck_ab": lockcheck_ab,
         "history_prom_ab": history_prom_ab,
         "alerts_ab": alerts_ab,
-        "serving_engine_ab": engine_ab,
         "serving_multistep_ab": multistep_ab,
         "serving_trace_ab": trace_ab,
         "serving_spec_ab": spec_ab,

@@ -358,73 +358,11 @@ def bench_e2e(tmp: Path, max_new: int = 4, frames: int = 100,
     return json.loads((tmp / "fps.json").read_text())
 
 
-def bench_batch(batches=(1, 4, 8), steps: int = 8, chains: int = 6) -> dict:
-    """Continuous-batching decode throughput: B independent sequences
-    through the batched fused kernels (ops/decode_block.
-    attention_batch_step) — one LM weight stream serves every row, so
-    aggregate tok/s should scale nearly linearly in B (round 5;
-    requires DORA_INT8_DECODE/DORA_INT4_DECODE for the fused layout)."""
-    import jax
-    import jax.numpy as jnp
-
-    from dora_tpu.models import vlm
-
-    cfg = vlm.VLMConfig.bench_2b()
-    print(f"# device {_start('bench_vlm batch')}", file=sys.stderr)
-    t0 = time.perf_counter()
-    params = vlm.init_params(jax.random.PRNGKey(0), cfg)
-    params = jax.jit(lambda p: vlm.quantize_decode(p), donate_argnums=0)(
-        params
-    )
-    jax.block_until_ready(jax.tree.leaves(params)[0])
-    print(f"# params ready {time.perf_counter()-t0:.1f}s", file=sys.stderr)
-
-    results = {}
-    base = None
-    for b in batches:
-        caches = vlm.init_cache(cfg, b)
-        positions = jnp.full((b,), 300, jnp.int32)
-        tokens = jnp.arange(b, dtype=jnp.int32) + 5
-
-        @jax.jit
-        def chain(params, tokens, caches, positions):
-            def body(carry, _):
-                t, c, p = carry
-                nt, c = vlm.decode_batch_fused(params, cfg, t, c, p)
-                return (nt, c, p + 1), None
-            (t, _, _), _ = jax.lax.scan(
-                body, (tokens, caches, positions), None, length=steps
-            )
-            return t[0]
-
-        def run_chains(chain=chain, tokens=tokens, caches=caches,
-                       positions=positions):
-            for _ in range(chains - 1):
-                chain(params, tokens, caches, positions)
-            return chain(params, tokens, caches, positions)
-
-        per_chain = _amortized_s(run_chains, chains)
-        tokps = b * steps / per_chain
-        if base is None:
-            base = tokps
-        results[b] = tokps
-        _emit(
-            f"vlm-2b batched fused decode (batch {b})", tokps, "tokens/s",
-            per_stream=round(tokps / b, 1),
-            vs_batch1=round(tokps / base, 2),
-            ms_per_step=round(per_chain / steps * 1e3, 2),
-        )
-    return results
-
-
 def main() -> int:
     mode = sys.argv[1] if len(sys.argv) > 1 else "model"
     sys.path.insert(0, str(Path(__file__).resolve().parent))
     if mode == "model":
         bench_model(max_new=int(os.environ.get("BENCH_MAX_NEW", "64")))
-    elif mode == "batch":
-        os.environ.setdefault("DORA_INT8_DECODE", "1")
-        bench_batch()
     elif mode == "e2e":
         import tempfile
 
@@ -443,7 +381,7 @@ def main() -> int:
             vs_baseline=data["fps"] / 25.0,  # north star: 25 FPS
         )
     else:
-        raise SystemExit(f"unknown mode {mode!r} (model | batch | e2e)")
+        raise SystemExit(f"unknown mode {mode!r} (model | e2e)")
     return 0
 
 

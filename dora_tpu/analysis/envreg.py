@@ -100,7 +100,6 @@ REGISTRY: dict[str, EnvVar] = dict((
     _e("DORA_MULTISTEP_K", "int", "8", "fused decode window size K", True),
     _e("DORA_STEP_DELAY_S", "float", "0", "stub engine: modelled device time per decode window (tests)"),
     _e("DORA_PREFILL_CHUNK", "int", "0", "chunked prefill size", True),
-    _e("DORA_PAGED_KV", "bool", "0", "paged KV-cache pool", True),
     _e("DORA_PAGE_SIZE", "int", "64", "KV page size (tokens)", True),
     _e("DORA_PREFIX_CACHE", "bool", "0", "shared-prefix KV cache", True),
     _e("DORA_PREFIX_CACHE_PAGES", "int", "0", "prefix cache page budget", True),

@@ -119,9 +119,6 @@ def free_stream_capacity(engine, *, prompt_len: int | None = None,
     Conservative by construction — a router acting on it may under-fill
     a replica, never overload one."""
     free_slots = int(getattr(engine, "free_slots", 0))
-    if not hasattr(engine, "free_pages"):
-        # slot engine: capacity is slots, gated on the request ever fitting
-        return free_slots if engine.fits(prompt_len or 1, max_new) else 0
     if prompt_len is None:
         prompt_len = int(getattr(engine, "chunk", 0)) or 1
     if free_slots == 0 or not engine.fits(prompt_len, max_new):

@@ -198,7 +198,7 @@ class ServingMetrics:
         "adapter_stalls", "model",
     )
 
-    def __init__(self, engine: str = "dense"):
+    def __init__(self, engine: str = "paged"):
         self.ttft = Histogram()
         #: host time from one window's tokens reaching the host
         #: (``collect()`` returning) to the launch of the next device
@@ -281,8 +281,8 @@ class ServingMetrics:
         self.resumed = 0
         #: per-class admission-queue depth gauge (set before snapshot)
         self.qos_depth: dict[str, int] = {}
-        #: live fused-window K (gauge) and autotuner retunes applied —
-        #: 0 autotune_k means "engine exposes no window" (dense)
+        #: live fused-window K (gauge, 0 before the first report) and
+        #: autotuner retunes applied
         self.autotune_k = 0
         self.retunes = 0
         #: shared-prefix KV cache (paged engine, DORA_PREFIX_CACHE):

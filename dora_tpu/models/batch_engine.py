@@ -1,27 +1,19 @@
-"""Continuous batching: B concurrent decode streams on one weight pass.
+"""Continuous batching over a paged KV pool: the serving engine.
 
-Round-4 gap: the fused decode tier was
-batch-1 — the OpenAI server serialized concurrent requests through one
-stream. Batch-1 decode is HBM-bandwidth-bound: every token pays the full
-LM weight stream. The batched kernels (ops.decode_block.
-attention_batch_step) run B independent sequences off ONE weight stream,
-so B concurrent chats decode at nearly the cost of one.
+Batch-1 decode is HBM-bandwidth-bound: every token pays the full LM
+weight stream. The paged kernels (ops.decode_block.
+attention_paged_batch_step and the window programs of models/vlm.py)
+run B independent sequences off ONE weight stream, so B concurrent
+chats decode at nearly the cost of one.
 
-This engine is the host-side slot manager over those kernels:
-
-* ``submit`` prefills a prompt (right-padded to a power-of-two bucket —
-  one XLA compile per bucket, not per prompt length) into a free slot of
-  the batched KV cache tree and returns the first generated token.
-* ``step`` advances EVERY active slot one token with one batched fused
-  pass. New requests join mid-flight — no barrier, no draining: that is
-  the "continuous" in continuous batching.
-* Slots free on EOS / max_new; idle slots ride along masked (their rows
-  compute at position 0 and are discarded — the weight stream already
-  paid for them).
+:class:`PagedBatchEngine` is the host-side slot and page manager over
+those kernels; :class:`PageAllocator` is its refcounted block
+allocator. New requests join mid-flight at window boundaries — no
+barrier, no draining: that is the "continuous" in continuous batching.
 
 The engine is model-family-agnostic: construction takes the family's
-``init_caches`` / ``prefill`` / ``batch_step`` closures (see
-models/hf/qwen2.make_batch_engine).
+``init_pool`` / ``chunk_prefill`` / ``window_step`` closures (see
+models/hf/qwen2.make_paged_engine, models/hf/kimi_k2.make_paged_engine).
 
 Reference parity: the reference's openai-proxy-server serializes
 requests through the dataflow (node-hub/openai-proxy-server/src/
@@ -37,216 +29,6 @@ from collections import deque
 from dataclasses import dataclass, field
 
 from dora_tpu import profiling
-
-
-@dataclass
-class _Slot:
-    request_id: str
-    emitted: int
-    max_new: int
-
-
-def _bucket(n: int, cap: int) -> int:
-    """Smallest power-of-two >= n (min 8), capped at the cache length."""
-    b = 8
-    while b < n:
-        b *= 2
-    return min(b, cap)
-
-
-class BatchEngine:
-    def __init__(self, *, init_caches, prefill, batch_step,
-                 max_slots: int = 4, max_seq: int, eos: int | None = None):
-        import jax
-        import jax.numpy as jnp
-
-        self._jnp = jnp
-        self.max_slots = max_slots
-        self.max_seq = max_seq
-        self.eos = eos
-        self.prefill = prefill
-        self.batch_step = batch_step
-        self.caches = init_caches(max_slots)
-        self.tokens = jnp.zeros((max_slots,), jnp.int32)
-        self.positions = jnp.zeros((max_slots,), jnp.int32)
-        self.slots: list[_Slot | None] = [None] * max_slots
-        # jitted slot-insert: writes one prefilled sequence's cache rows,
-        # token and position into slot b of the batched state.
-        def _insert(caches, tokens, positions, sub, first, pos, b):
-            new = jax.tree.map(
-                lambda big, one: jax.lax.dynamic_update_slice(
-                    big, one, (b,) + (0,) * (one.ndim - 1)
-                ),
-                caches, sub,
-            )
-            tokens = jax.lax.dynamic_update_slice(tokens, first, (b,))
-            positions = jax.lax.dynamic_update_slice(
-                positions, pos.reshape(1), (b,)
-            )
-            return new, tokens, positions
-
-        self._insert = jax.jit(_insert, donate_argnums=(0,))
-        # Active-slot mask, rebuilt only when slot membership changes
-        # (not every step — see step()).
-        self._mask = jnp.zeros((max_slots,), bool)
-        self._imask = self._mask.astype(jnp.int32)
-        self._members_dirty = True
-        #: host->device program launches / device->host token fetches
-        #: driven by this engine — the round-trip accounting behind the
-        #: serving tokens_per_dispatch metric (same fields as the paged
-        #: engine, so the scheduler reads either uniformly)
-        self.dispatches = 0
-        self.fetches = 0
-        #: observability hooks the serving node attaches after
-        #: construction: ``tracer`` is a telemetry.ServingTracer
-        #: (request-lifecycle spans through the flight recorder),
-        #: ``serving_metrics`` a metrics.ServingMetrics (fetch/grant
-        #: histograms). Both default None — raw-engine tests and benches
-        #: pay one attribute check per hook site, nothing more.
-        self.tracer = None
-        self.serving_metrics = None
-
-    # -- admission -----------------------------------------------------------
-
-    @property
-    def free_slots(self) -> int:
-        return sum(s is None for s in self.slots)
-
-    @property
-    def active(self) -> int:
-        return self.max_slots - self.free_slots
-
-    def fits(self, prompt_len: int, max_new: int) -> bool:
-        """Length admissibility alone (a request that never fits must be
-        rejected up front, not parked in a backlog)."""
-        return prompt_len + max_new <= self.max_seq
-
-    def can_admit(self, prompt_len: int, max_new: int) -> bool:
-        return self.free_slots > 0 and self.fits(prompt_len, max_new)
-
-    def submit(self, request_id: str, prompt_ids,
-               max_new: int) -> tuple[int, bool]:
-        """Prefill ``prompt_ids`` (list/array of token ids) into a free
-        slot; returns ``(first_token, done)`` — the first generated
-        token is already emitted by this call (the per-step loop emits
-        the rest); ``done`` is True when the stream completed at this
-        very token (max_new == 1, or the first token is EOS). Raises if
-        no slot is free."""
-        import jax.numpy as jnp
-
-        ids = list(prompt_ids)
-        if not self.can_admit(len(ids), max_new):
-            raise RuntimeError(
-                f"cannot admit: {self.free_slots} slots free, "
-                f"{len(ids)}+{max_new} vs max_seq {self.max_seq}"
-            )
-        t_sub = time.perf_counter()
-        b = self.slots.index(None)
-        tb = _bucket(len(ids), self.max_seq)
-        padded = jnp.asarray(
-            [ids + [0] * (tb - len(ids))], jnp.int32
-        )
-        first, caches_1, pos = self.prefill(
-            padded, jnp.asarray(len(ids), jnp.int32)
-        )
-        self.caches, self.tokens, self.positions = self._insert(
-            self.caches, self.tokens, self.positions, caches_1, first,
-            pos, b,
-        )
-        self.dispatches += 1
-        # Host-read AFTER the insert dispatch: the transfer then overlaps
-        # the insert instead of fencing the device before it is queued.
-        t_fetch = time.perf_counter()
-        token = int(first[0])
-        self.fetches += 1
-        if self.serving_metrics is not None:
-            self.serving_metrics.fetch_latency.observe(
-                (time.perf_counter() - t_fetch) * 1e6
-            )
-        if self.tracer is not None:
-            # One span covers grant + synchronous prefill: the dense
-            # engine has no chunked phase to split out.
-            self.tracer.span(
-                "s_admitted", request_id, f"slot={b} bucket={tb}",
-                dur_ns=int((time.perf_counter() - t_sub) * 1e9),
-            )
-        done = (self.eos is not None and token == self.eos) or max_new <= 1
-        if not done:
-            self.slots[b] = _Slot(request_id, emitted=1, max_new=max_new)
-        # Even an instantly-done submit moved this slot's position off 0
-        # (_insert wrote true_len): the mask/pin state must rebuild.
-        self._members_dirty = True
-        return token, done
-
-    # -- the batched step ----------------------------------------------------
-
-    def step(self) -> list[tuple[str, int, bool]]:
-        """One batched fused pass: every active slot advances one token.
-        Returns [(request_id, token, done)] for active slots (empty when
-        idle). Slots free as they finish; a submit between steps joins
-        the very next pass."""
-        if self.active == 0:
-            return []
-        jnp = self._jnp
-        # Idle slots pin at position 0 (they ride the batched pass
-        # harmlessly but must never walk their cache-row write toward
-        # the end of the cache plane). The mask and the pinning
-        # ``where`` dispatch only when membership changed; steady-state
-        # passes advance active rows with a masked increment, so idle
-        # rows stay pinned without re-pinning every step.
-        if self._members_dirty:
-            self._mask = jnp.asarray(
-                [s is not None for s in self.slots], dtype=bool
-            )
-            self._imask = self._mask.astype(jnp.int32)
-            self.positions = jnp.where(self._mask, self.positions, 0)
-            self._members_dirty = False
-        t_step = time.perf_counter()
-        nxt, self.caches = self.batch_step(
-            self.tokens, self.caches, self.positions
-        )
-        self.dispatches += 1
-        self.tokens = nxt
-        self.positions = self.positions + self._imask
-        emitted = []
-        import numpy as np
-
-        t_fetch = time.perf_counter()
-        host = np.asarray(nxt)  # ONE device->host transfer for all slots
-        t_done = time.perf_counter()
-        self.fetches += 1
-        if self.serving_metrics is not None:
-            self.serving_metrics.fetch_latency.observe(
-                (t_done - t_fetch) * 1e6
-            )
-        step_ns = int((t_done - t_step) * 1e9)
-        for b, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            token = int(host[b])
-            slot.emitted += 1
-            done = (
-                slot.emitted >= slot.max_new
-                or (self.eos is not None and token == self.eos)
-            )
-            if self.tracer is not None:
-                # The dense step is a 1-tick window: same span kind as
-                # the paged K-tick window so the timeline reads uniform.
-                self.tracer.span(
-                    "s_decode_window", slot.request_id,
-                    f"K=1 emitted=1 frozen_at={1 if done else None}",
-                    dur_ns=step_ns,
-                )
-            emitted.append((slot.request_id, token, done))
-            if done:
-                self.slots[b] = None
-                self._members_dirty = True
-        return emitted
-
-
-# ---------------------------------------------------------------------------
-# Paged KV: block allocator + the paged continuous-batching engine
-# ---------------------------------------------------------------------------
 
 
 class PageAllocator:
@@ -437,21 +219,19 @@ class _PagedSlot:
 class PagedBatchEngine:
     """Continuous batching over a paged KV pool with chunked prefill.
 
-    The dense :class:`BatchEngine` reserves ``[max_slots, …, max_seq]``
-    KV up front — concurrency is capped by worst-case context. Here KV
-    lives in a fixed pool of page-size blocks; each slot holds a block
-    TABLE (``[max_pages]`` int32 of physical page ids) and pages are
-    granted at admission for the context the stream can actually reach
-    (``max(chunk-padded prompt, prompt + max_new)`` rows). 16-64 slots
-    fit in the HBM the dense engine needs for 4.
+    KV lives in a fixed pool of page-size blocks; each slot holds a
+    block TABLE (``[max_pages]`` int32 of physical page ids) and pages
+    are granted at admission for the context the stream can actually
+    reach (``max(chunk-padded prompt, prompt + max_new)`` rows), so
+    concurrency is capped by the context streams use, not by
+    ``max_slots`` times the worst case.
 
     Prefill runs as fixed-shape chunks interleaved with decode: one
     chunk of the head-of-line prefilling stream per :meth:`step`, then
     one batched decode pass for every decoding stream — a 2k-token
     prompt no longer freezes active streams for its whole prefill, and
     because the chunk shape is FIXED (position is a traced scalar),
-    prefill compiles exactly one XLA program ever, vs one per
-    power-of-two bucket in the dense engine.
+    prefill compiles exactly one XLA program ever.
 
     Decode runs at WINDOW granularity: each :meth:`step` launches ONE
     fused K-tick program (``window_step``, models/vlm.make_paged_window
@@ -464,11 +244,11 @@ class PagedBatchEngine:
     decisions — admissions, prefill interleave, backlog — happen only
     at window boundaries. ``window=1`` is the per-token behavior.
 
-    Greedy outputs are bit-identical to the dense engine at every K:
-    the paged kernels run the same per-row math, only the cache
-    indexing routes through the block table, and the window carries
-    exactly the state the per-tick loop carried (asserted in
-    tests/test_paged_engine.py).
+    Greedy outputs are bit-identical to the serial reference
+    (models/hf/qwen2.generate) at every K: the paged kernels run the
+    same per-row math, only the cache indexing routes through the
+    block table, and the window carries exactly the state a per-tick
+    loop would carry (asserted in tests/test_paged_engine.py).
 
     Closures (see models/hf/qwen2.make_paged_engine):
       * ``init_pool(num_pages)`` -> pools pytree
@@ -597,8 +377,11 @@ class PagedBatchEngine:
         #: (round-trip accounting behind tokens_per_dispatch)
         self.dispatches = 0
         self.fetches = 0
-        #: observability hooks (see BatchEngine): attached by the
-        #: serving node, None everywhere else — one attribute check per
+        #: observability hooks the serving node attaches after
+        #: construction: ``tracer`` is a telemetry.ServingTracer
+        #: (request-lifecycle spans through the flight recorder),
+        #: ``serving_metrics`` a metrics.ServingMetrics (fetch/grant
+        #: histograms). None everywhere else — one attribute check per
         #: hook site on the step path.
         self.tracer = None
         self.serving_metrics = None
@@ -766,7 +549,7 @@ class PagedBatchEngine:
         """Admit a stream: grant its pages, write its block table and
         queue its prefill. Returns None — the first token is emitted by
         a later :meth:`step` (prefill is chunked and interleaved, not
-        synchronous), unlike the dense engine's submit. ``adapter``
+        synchronous). ``adapter``
         names the stream's LoRA tenant (None = base model); admission
         pins it resident for the stream's lifetime."""
         ids = [int(t) for t in prompt_ids]

@@ -189,87 +189,6 @@ def fused_step(params, cfg, tokens, caches, position):
     )
 
 
-def fused_batch_step(params, cfg, tokens, caches, positions):
-    """One fused decode step for B INDEPENDENT sequences (standard
-    RoPE at each row's own position). tokens/positions: [B] int32;
-    caches: [B, KV, S, hd] per layer. Returns (greedy [B], caches).
-    The continuous-batching engine's inner step (models/batch_engine)."""
-    from dora_tpu.models import vlm as _vlm
-    from dora_tpu.ops import decode_block as DB
-
-    dtype = L.compute_dtype()
-    cos_t, sin_t = L.rope_table(cfg.max_seq, cfg.head_dim,
-                                base=cfg.rope_theta)
-    cos_rows, sin_rows = DB.rope_rows_at(cos_t, sin_t, positions)
-    x = params["embed"].astype(dtype)[tokens]  # [B, dim]
-    return _vlm.fused_decode_pass_batch(
-        params, x, caches, positions, cos_rows, sin_rows,
-        heads=cfg.heads, kv_heads=cfg.kv_heads, head_dim=cfg.head_dim,
-        layers=cfg.layers, eps=cfg.norm_eps,
-    )
-
-
-@partial(jax.jit, static_argnums=(1,))
-def prefill_padded(params, cfg: Qwen2Config, prompt_ids, true_len):
-    """Prefill for the batching engine: ``prompt_ids`` [1, TB] is the
-    prompt RIGHT-padded to a bucket length (one compile per bucket, not
-    per length). Pad rows sit AFTER the real tokens, so no real token
-    ever attends one; the first generated token reads the hidden state
-    at ``true_len - 1``; pad cache rows live at indices >= true_len and
-    are overwritten by decode before they become attendable (decode at
-    position p attends idx < p only). Returns (first [1], caches [1],
-    position = true_len)."""
-    dtype = L.compute_dtype()
-    b, t = prompt_ids.shape
-    head = _head(params, cfg, dtype)
-    h = params["embed"].astype(dtype)[prompt_ids]
-    positions = jnp.broadcast_to(jnp.arange(t), (b, t))
-    mask = L.causal_mask(t, cfg.max_seq) & (
-        jnp.arange(cfg.max_seq)[None, None, None, :] < t
-    )
-    caches = init_cache(cfg, b)
-    h, caches = _lm(
-        params, cfg, h, positions, mask, caches=caches, cache_index=0
-    )
-    last = jnp.take_along_axis(
-        h, (true_len - 1).reshape(1, 1, 1).astype(jnp.int32), axis=1
-    )[:, 0]
-    first = jnp.argmax(_head_logits(last, head), axis=-1).astype(jnp.int32)
-    return first, caches, jnp.asarray(true_len, jnp.int32)
-
-
-def make_batch_engine(params, cfg: Qwen2Config, *, max_slots: int = 4,
-                      eos: int | None = None):
-    """Continuous-batching engine for this family (requires the
-    quantized fused layout — models/vlm.fused_batch_ready)."""
-    from dora_tpu.models import vlm as _vlm
-    from dora_tpu.models.batch_engine import BatchEngine
-
-    assert _vlm.fused_batch_ready(params), (
-        "batch engine needs quantize_decode params (DORA_INT8_DECODE / "
-        "DORA_INT4_DECODE)"
-    )
-    # params is an ARGUMENT of the jitted program, not a closure: a
-    # closed-over array lowers to a constant, which would bake the
-    # weights into every executable and compile-cache entry.
-    step = jax.jit(
-        lambda p, tokens, caches, positions: fused_batch_step(
-            p, cfg, tokens, caches, positions
-        ),
-        donate_argnums=(2,),
-    )
-    return BatchEngine(
-        init_caches=lambda n: init_cache(cfg, n),
-        prefill=lambda ids, true_len: prefill_padded(
-            params, cfg, ids, true_len
-        ),
-        batch_step=partial(step, params),
-        max_slots=max_slots,
-        max_seq=cfg.max_seq,
-        eos=eos,
-    )
-
-
 def fused_paged_batch_step(params, cfg, tokens, pools, positions,
                            block_tables, lora=None):
     """One fused decode step for B independent sequences over PAGED KV
@@ -333,9 +252,10 @@ def fused_paged_chunk_step(params, cfg, chunk_ids, pools, position,
                            block_table, lora=None):
     """One prefill chunk into paged pools: chunk_ids [C] int32 at
     positions ``position..position+C-1`` (both page-multiples; the tail
-    chunk is right-padded — pad rows land beyond ``true_len`` and are
-    overwritten by decode before they become attendable, exactly the
-    :func:`prefill_padded` argument). ``position`` is a TRACED scalar,
+    chunk is right-padded — pad rows sit AFTER the real tokens, so no
+    real token attends one; they land beyond ``true_len`` and are
+    overwritten by decode before they become attendable, since decode
+    at position p attends idx < p only). ``position`` is a TRACED scalar,
     so every chunk of every prompt shares ONE compiled program.
     Returns (greedy [C], pools)."""
     from dora_tpu.models import vlm as _vlm
@@ -467,9 +387,8 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
                       lora_dir: str | None = None,
                       lora_max_resident: int | None = None):
     """Paged-KV continuous-batching engine (requires the quantized fused
-    layout, like :func:`make_batch_engine`). Defaults size the pool to
-    EXACTLY the dense engine's 4-slot HBM footprint (4 * max_seq KV
-    rows per layer, null page included) — the paged engine runs
+    layout — models/vlm.fused_decode_ready). Defaults size the pool to
+    4 * max_seq KV rows per layer, null page included — the engine runs
     ``max_slots`` streams inside it because pages are granted for
     actual context, not worst-case.
 
@@ -504,7 +423,7 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
     from dora_tpu.models import vlm as _vlm
     from dora_tpu.models.batch_engine import PagedBatchEngine
 
-    assert _vlm.fused_batch_ready(params), (
+    assert _vlm.fused_decode_ready(params, 1), (
         "paged engine needs quantize_decode params (DORA_INT8_DECODE / "
         "DORA_INT4_DECODE)"
     )

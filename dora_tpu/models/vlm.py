@@ -452,57 +452,6 @@ def generate(params, cfg: VLMConfig, images, prompt_ids, max_new_tokens: int):
     return tokens.T  # [B, max_new]
 
 
-def fused_batch_ready(params) -> bool:
-    """True when the BATCHED fused tier can serve: same quantized fused
-    layout as :func:`fused_decode_ready`, without the batch-1 gate
-    (ops.decode_block.attention_batch_step serves B independent
-    sequences off one weight stream — the continuous-batching engine's
-    step, models/batch_engine.py)."""
-    return fused_decode_ready(params, 1)
-
-
-def decode_batch_fused(params, cfg: VLMConfig, tokens, caches, positions):
-    """One greedy decode step for B INDEPENDENT sequences.
-
-    tokens: [B] int32; positions: [B] int32 (each row's own cache
-    position); caches: the [B, KV, S, hd]-per-layer tree. One LM weight
-    stream serves all B rows — decode cost is ~flat in B until the
-    per-row attention sweeps dominate. Returns (greedy [B], caches).
-    """
-    from dora_tpu.ops import decode_block as DB
-
-    dtype = L.compute_dtype()
-    x = params["embed"].astype(dtype)[tokens]  # [B, dim]
-    cos_t, sin_t = L.rope_table(cfg.max_seq, cfg.head_dim)
-    cos_rows, sin_rows = DB.rope_rows_at(cos_t, sin_t, positions)
-    return fused_decode_pass_batch(
-        params, x, caches, positions, cos_rows, sin_rows,
-        heads=cfg.heads, kv_heads=cfg.kv_heads, head_dim=cfg.head_dim,
-        layers=cfg.layers,
-    )
-
-
-def fused_decode_pass_batch(params, x, caches, positions, cos_rows,
-                            sin_rows, *, heads: int, kv_heads: int,
-                            head_dim: int, layers: int, eps: float = 1e-6):
-    """Family-agnostic batched fused pass (caller embeds tokens and
-    gathers per-row rope rows; hf families pass their own rope base)."""
-    from dora_tpu.ops import decode_block as DB
-
-    def attn_apply(i, x, blk, wqkv, sqkv, bqkv, wo, swo):
-        x, kc, vc = DB.attention_batch_step(
-            x, blk["attn_norm"], wqkv, sqkv, bqkv, cos_rows, sin_rows,
-            caches[str(i)]["k"], caches[str(i)]["v"], wo, swo, positions,
-            heads=heads, kv_heads=kv_heads, head_dim=head_dim, eps=eps,
-        )
-        return x, {"k": kc, "v": vc}
-
-    return _fused_pass(
-        params, x, attn_apply, heads=heads, kv_heads=kv_heads,
-        head_dim=head_dim, layers=layers, eps=eps,
-    )
-
-
 def fused_paged_pass_batch(params, x, pools, positions, block_tables,
                            cos_rows, sin_rows, *, heads: int, kv_heads: int,
                            head_dim: int, layers: int, eps: float = 1e-6,
@@ -511,8 +460,10 @@ def fused_paged_pass_batch(params, x, pools, positions, block_tables,
     pool of [P, KV, page, hd] blocks and each row's context streams
     through its ``block_tables`` row instead of a contiguous
     [slot, max_seq] plane (ops.decode_block.attention_paged_batch_step).
-    Same per-row math as :func:`fused_decode_pass_batch` — the paged
-    engine's greedy tokens stay identical to the dense engine's."""
+    Same per-row math as :func:`fused_decode_pass` (caller embeds tokens
+    and gathers per-row rope rows; hf families pass their own rope
+    base) — the paged engine's greedy tokens stay identical to the
+    serial reference's."""
     from dora_tpu.ops import decode_block as DB
 
     def attn_apply(i, x, blk, wqkv, sqkv, bqkv, wo, swo):

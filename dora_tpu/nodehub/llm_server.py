@@ -10,16 +10,13 @@ ops/decode_block). Token deltas stream back on ``response`` tagged
 ``{request_id, done}`` — the openai_server's concurrent mode routes
 them to the right SSE stream.
 
-Two engines (models/batch_engine.py):
-
-* PAGED (default): KV lives in a pool of page-size blocks routed
-  through per-slot block tables, prompts prefill in fixed-shape chunks
-  interleaved with decode — concurrency scales with actual context
-  held, long prompts don't stall active streams, and admission is
-  page-aware (a request is admitted only while free pages cover
-  prompt + max_new, so an admitted stream can never OOM mid-decode).
-* DENSE (``DORA_PAGED_KV=0``): the round-5 `[slots, …, max_seq]` plane
-  with synchronous bucket prefill.
+The engine (models/batch_engine.PagedBatchEngine): KV lives in a pool
+of page-size blocks routed through per-slot block tables, prompts
+prefill in fixed-shape chunks interleaved with decode — concurrency
+scales with actual context held, long prompts don't stall active
+streams, and admission is page-aware (a request is admitted only while
+free pages cover prompt + max_new, so an admitted stream can never OOM
+mid-decode).
 
 Model: a Qwen2-family checkpoint from ``DORA_HF_CHECKPOINT`` (quantized
 into the fused decode layout — int8 by default, DORA_INT4_DECODE=1 for
@@ -39,16 +36,15 @@ launch, before its window is collected. Tokens collected and not yet
 sent are flushed before anything reads per-request state (preemption,
 migration, checkpoints, errors, exit, an engine gone idle).
 
-Env: DORA_BATCH_SLOTS (default 16 paged / 4 dense) concurrent streams;
+Env: DORA_BATCH_SLOTS (default 16) concurrent streams;
 DORA_MAX_NEW_TOKENS (default 32) per-request cap (a request's
 ``max_tokens`` lowers it); DORA_MAX_SEQ cache length; DORA_PAGE_SIZE
 (default 16) KV rows per page; DORA_PREFILL_CHUNK prefill chunk rows
 (default min(256, max_seq)); DORA_MULTISTEP_K (default 8) fused decode
-ticks per dispatch (1 = per-token dispatch); DORA_PAGED_KV=0 for the
-dense engine (always per-token); DORA_SPEC_K (default 0 = off) drafts
-k tokens per tick via prompt-lookup and verifies them in the same
-dispatch — up to K·(k+1) tokens per round trip, greedy-exact — with
-DORA_SPEC_NGRAM (default 2) the lookup ngram width.
+ticks per dispatch (1 = per-token dispatch); DORA_SPEC_K (default 0 =
+off) drafts k tokens per tick via prompt-lookup and verifies them in
+the same dispatch — up to K·(k+1) tokens per round trip, greedy-exact —
+with DORA_SPEC_NGRAM (default 2) the lookup ngram width.
 
 Traffic shaping (descriptor ``qos:`` block -> DORA_QOS_* env):
 requests carry a priority class (``interactive``/``standard``/
@@ -70,7 +66,7 @@ Serving metrics (slots, free pages, backlog, decode tokens/s, TTFT
 histogram) ship to the daemon every second and surface in
 ``dora-tpu metrics [--watch]``.
 
-Elastic recovery (paged engines): ``DORA_CHECKPOINT_DIR`` (+
+Elastic recovery: ``DORA_CHECKPOINT_DIR`` (+
 ``DORA_CHECKPOINT_EVERY``, default 8 windows) snapshots live serving
 state atomically — and on SIGTERM — and restores it on respawn,
 resuming mid-generation streams token-identically; every response
@@ -126,19 +122,12 @@ def model_module(model_type: str | None):
 
 
 def make_engine(params, cfg, eos=None, module=None):
-    """Build the serving engine from the env knobs (paged by default).
-    ``module`` is the model's ``models/hf`` module (default: qwen2)."""
+    """Build the serving engine from the env knobs. ``module`` is the
+    model's ``models/hf`` module (default: qwen2)."""
     if module is None:
         from dora_tpu.models.hf import qwen2 as module
 
-    paged = os.environ.get("DORA_PAGED_KV", "1") != "0"
-    slots = int(
-        os.environ.get("DORA_BATCH_SLOTS", "16" if paged else "4")
-    )
-    if not paged:
-        return module.make_batch_engine(
-            params, cfg, max_slots=slots, eos=eos
-        )
+    slots = int(os.environ.get("DORA_BATCH_SLOTS", "16"))
     page_size = int(os.environ.get("DORA_PAGE_SIZE", "16"))
     chunk_env = os.environ.get("DORA_PREFILL_CHUNK")
     chunk = int(chunk_env) if chunk_env else None
@@ -364,15 +353,7 @@ class AdmissionQueue:
             if cls is None:
                 return
             key, ids, max_new, t_in, _dl, adapter = self._q[cls][0]
-            # Dense engines predate the adapter kwarg; only paged
-            # engines ever have a lora pool, and only they see tenant
-            # requests (the front door rejects tenants otherwise).
-            admissible = (
-                self._engine.can_admit(len(ids), max_new, adapter)
-                if adapter
-                else self._engine.can_admit(len(ids), max_new)
-            )
-            if not admissible:
+            if not self._engine.can_admit(len(ids), max_new, adapter):
                 if self._preempt is not None and self._preempt(cls):
                     continue  # a victim was evicted: re-score and retry
                 # Attribute the stall: "adapter_residency" means
@@ -381,10 +362,8 @@ class AdmissionQueue:
                 # reads as plain overload. Re-evaluated every drain
                 # (a capacity stall can become adapter-gated as pages
                 # free), but on_stall fires only on transitions.
-                blocker = getattr(self._engine, "admit_blocker", None)
-                reason = (
-                    blocker(len(ids), max_new, adapter)
-                    if blocker is not None else "capacity"
+                reason = self._engine.admit_blocker(
+                    len(ids), max_new, adapter
                 ) or "capacity"
                 if self._stall_reasons.get(key) != reason:
                     self._stall_reasons[key] = reason
@@ -395,12 +374,7 @@ class AdmissionQueue:
             if self._on_admit is not None:
                 self._on_admit(key, now - t_in)
             self._stall_reasons.pop(key, None)
-            # Same compatibility split as can_admit: pre-adapter start
-            # callbacks take exactly (key, ids, max_new).
-            if adapter:
-                self._start(key, ids, max_new, adapter)
-            else:
-                self._start(key, ids, max_new)
+            self._start(key, ids, max_new, adapter)
 
     def pending(self) -> list[tuple[str, list[int], int, str, str | None]]:
         """Parked requests in class-priority order — serialized into
@@ -446,9 +420,7 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
     the held ones. Then ALWAYS drain the backlog — capacity appears
     when a step frees slots/pages, but also the idle path must admit
     (a parked request with zero active streams used to sit until
-    unrelated traffic arrived). An engine with ``step()`` alone (the
-    dense engine, test fakes) runs it as its ``collect()``: the same
-    order, with nothing in flight to send beside.
+    unrelated traffic arrived).
 
     ``held`` is state the wire has not seen, and whatever reads
     per-request state sends it first (:func:`_flush`). The loop does
@@ -529,16 +501,16 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             # (recv returns immediately once the queue is closed).
             time.sleep(0.05)
         if engine.active:
-            first = half(getattr(engine, "dispatch", list))
-            overlapped = getattr(engine, "in_flight", False)
+            first = half(engine.dispatch)
+            overlapped = engine.in_flight
             t_launch = clock()
             held.extend(first)  # after the previous window's tokens
             sent = _flush(held, emit)
             if overlapped:
                 metrics.emit_overlapped += sent
             else:
-                # Nothing ran beside the emit (a prefill-only dispatch,
-                # a step-only engine): the device waited for it too.
+                # Nothing ran beside the emit (a prefill-only
+                # dispatch): the device waited for it too.
                 t_launch = clock()
             if last_collect_end is not None:
                 # Host time from the previous window's tokens reaching
@@ -548,7 +520,7 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
                 metrics.dispatch_gap.observe(
                     (t_launch - last_collect_end) * 1e6
                 )
-            held.extend(half(getattr(engine, "collect", None) or engine.step))
+            held.extend(half(engine.collect))
             last_collect_end = clock()
             if engine.active == 0:
                 # The loop may now park in recv or exit: nothing stays
@@ -595,13 +567,10 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     engine.tracer = tracer
     engine.serving_metrics = metrics
     telemetry.install_compile_listener()
-    paged = hasattr(engine, "free_pages")
-    # Elastic-recovery env knobs; all off by default, and only engines
-    # exposing the checkpoint surface (paged) can use them.
-    can_ckpt = hasattr(engine, "checkpoint_state")
-    ckpt_dir = os.environ.get("DORA_CHECKPOINT_DIR") if can_ckpt else None
+    # Elastic-recovery env knobs; all off by default.
+    ckpt_dir = os.environ.get("DORA_CHECKPOINT_DIR")
     ckpt_every = int(os.environ.get("DORA_CHECKPOINT_EVERY", "8") or 0)
-    migrate_dir = os.environ.get("DORA_MIGRATE_DIR") if can_ckpt else None
+    migrate_dir = os.environ.get("DORA_MIGRATE_DIR")
     # SLO targets: the daemon injects the descriptor's `slo:` block as
     # DORA_SLO_* at spawn. The daemon-side history ring is the
     # authoritative burn-rate source; the node-side check exists so a
@@ -619,10 +588,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     slo_queue = _slo_env("DORA_SLO_QUEUE_DEPTH_MAX")
     slo_prev: dict = {"t": None, "tokens": 0, "ttft": []}
     # Traffic shaping (descriptor qos: block -> DORA_QOS_* env).
-    # Preemption needs the engine surface (preempt + per-slot request
-    # ids) — the dense engine silently serves without it.
     qos = QosConfig.from_env()
-    can_preempt = qos.preempt_on and hasattr(engine, "preempt")
     #: per-request QoS bookkeeping. req_prompt/req_emitted (token ids)
     #: exist so a preempted stream can resume by re-prefilling
     #: prompt + emitted — only tracked while preemption is on.
@@ -670,7 +636,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         stall_tags.pop(key, None)
         preempted_keys.discard(key)
         pinned = pinned_prefix.pop(key, None)
-        if pinned is not None and hasattr(engine, "prefix_unpin"):
+        if pinned is not None:
             # A parked victim that never resumed (shed, error, drain)
             # must release its eviction pin.
             engine.prefix_unpin(pinned)
@@ -718,7 +684,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         if done:
             finish = "stop" if (eos is not None and token == eos) else "length"
         metrics.decode_tokens += 1
-        if can_preempt and not done and key in req_emitted:
+        if qos.preempt_on and not done and key in req_emitted:
             req_emitted[key].append(token)
         emit_text(key, decode_one(token), done, finish)
 
@@ -760,20 +726,15 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             preempted_keys.discard(key)
             metrics.resumed += 1
             tracer.span("s_resume", key, f"recompute={len(ids)}")
-        if adapter:
-            res = engine.submit(key, ids, max_new, adapter=adapter)
-        else:
-            res = engine.submit(key, ids, max_new)
+        # submit queues the prefill; the first token is emitted by a
+        # later dispatch(), when the final chunk lands.
+        engine.submit(key, ids, max_new, adapter=adapter)
         pinned = pinned_prefix.pop(key, None)
         if pinned is not None:
             # Unpin AFTER submit: the resume lookup refs the shared
             # pages into the new grant first, so dropping the eviction
             # pin can no longer lose them.
             engine.prefix_unpin(pinned)
-        if res is not None:  # dense engine: first token is synchronous
-            emit(key, *res)
-        # paged engine: submit queues the prefill; the first token is
-        # emitted by a later step() when the final chunk lands.
 
     def on_shed(key: str, reason: str, waited_s: float) -> None:
         # Overload -> fast retriable signal, never unbounded backlog:
@@ -794,7 +755,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         cheapest recompute), park it for resume, and report whether
         anything was freed. The queue re-scores and retries after True,
         so multi-victim evictions happen one grant at a time."""
-        if not can_preempt:
+        if not qos.preempt_on:
             return False
         flush()  # req_emitted must match the victim's slot.emitted
         rank = QOS_CLASSES.index(cls)
@@ -828,7 +789,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             list(req_prompt.get(victim, []))
             + list(req_emitted.get(victim, []))
         )
-        if hasattr(engine, "prefix_pin") and engine.prefix_pin(resume_ids):
+        if engine.prefix_pin(resume_ids):
             # The victim's cached prefix pages survive the park on
             # refcount custody: resume re-prefills only the unshared
             # tail instead of re-paying the whole prefill.
@@ -842,7 +803,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     backlog = AdmissionQueue(
         engine, start, on_admit=on_admit, clock=clock,
         qos=qos, on_shed=on_shed,
-        preempt=try_preempt if can_preempt else None,
+        preempt=try_preempt if qos.preempt_on else None,
         on_stall=on_stall,
     )
 
@@ -895,7 +856,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         # pages, one window executable.
         model = str(meta.get("model") or "")
         adapter = model if model not in BASE_MODEL_NAMES else None
-        lora_pool = getattr(engine, "lora", None)
+        lora_pool = engine.lora
         req_adapter[key] = adapter
         if max_new <= 0:
             # max_tokens <= 0 asks for nothing: close the stream
@@ -915,29 +876,24 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                 key, "", True, finish="rejected",
                 extra={"reject_reason": "unknown_model", "model": adapter},
             )
-        elif not (
-            engine.fits(len(ids), max_new, adapter)
-            if adapter
-            else engine.fits(len(ids), max_new)
-        ):
+        elif not engine.fits(len(ids), max_new, adapter):
             # NEVER admissible: close the stream empty with a
             # structured retriable "rejected" (distinct from the shed
             # path's "overloaded" — retrying the same body cannot
             # help, the payload says why: its page grant exceeds the
             # whole pool / block table).
             metrics.rejected += 1
-            extra: dict = {"reject_reason": "oversized"}
-            if paged:
-                extra["pages_needed"] = engine.pages_needed(
-                    len(ids), max_new
-                )
-                extra["pool_pages"] = engine.allocator.num_pages - 1
-                extra["max_seq"] = engine.max_seq
+            extra = {
+                "reject_reason": "oversized",
+                "pages_needed": engine.pages_needed(len(ids), max_new),
+                "pool_pages": engine.allocator.num_pages - 1,
+                "max_seq": engine.max_seq,
+            }
             tracer.instant("s_reject", key, f"oversized len={len(ids)}")
             emit_text(key, "", True, finish="rejected", extra=extra)
         else:
             t_admitted[key] = clock()
-            if can_preempt:
+            if qos.preempt_on:
                 req_prompt[key] = list(ids)
                 req_emitted[key] = []
             if not backlog.push(key, ids, max_new, cls, deadline_s,
@@ -952,7 +908,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                 tracer.instant(
                     "s_page_wait", key,
                     f"qos={cls} backlog={len(backlog)} "
-                    f"free_pages={getattr(engine, 'free_pages', 0)}",
+                    f"free_pages={engine.free_pages}",
                 )
 
     def check_slo(now: float) -> None:
@@ -1016,8 +972,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     # ------------------------------------------------------------------
     at_on = (
         os.environ.get("DORA_AUTOTUNE_K", "") == "1"
-        and hasattr(engine, "set_window")
-        and getattr(engine, "_window_factory", None) is not None
+        and engine._window_factory is not None
     )
     at_interval = float(os.environ.get("DORA_AUTOTUNE_INTERVAL_S", "5") or 5)
     at_hyst = max(1, int(os.environ.get("DORA_AUTOTUNE_HYSTERESIS", "2") or 2))
@@ -1028,16 +983,15 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     try:
         at_ladder = sorted(
             {int(x) for x in _ladder_env.split(",") if int(x) >= 1}
-            | {getattr(engine, "window", 1)}
+            | {engine.window}
         )
     except ValueError:
-        at_ladder = sorted({4, 8, 16} | {getattr(engine, "window", 1)})
+        at_ladder = sorted({4, 8, 16} | {engine.window})
     at_state = {
         "t": None, "tokens": 0, "dispatches": 0, "ttft": [],
         "samples": 0, "burn": 0, "calm": 0, "cooldown": 0,
         "shed": 0,
-        "rung": at_ladder.index(getattr(engine, "window", at_ladder[0]))
-        if getattr(engine, "window", None) in at_ladder else 0,
+        "rung": at_ladder.index(engine.window),
     }
 
     def autotune(now: float) -> None:
@@ -1202,56 +1156,50 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         metrics.slots_active = engine.active
         metrics.slots_total = engine.max_slots
         metrics.backlog_depth = len(backlog)
-        metrics.prefill_chunks = getattr(engine, "chunks_run", 0)
-        metrics.host_dispatches = getattr(engine, "dispatches", 0)
-        metrics.host_fetches = getattr(engine, "fetches", 0)
+        metrics.prefill_chunks = engine.chunks_run
+        metrics.host_dispatches = engine.dispatches
+        metrics.host_fetches = engine.fetches
         metrics.compiles = telemetry.compile_count()
-        if paged:
-            metrics.free_pages = engine.free_pages
-            alloc = getattr(engine, "allocator", None)
-            if alloc is not None:
-                metrics.total_pages = alloc.num_pages - 1
-                metrics.used_pages = alloc.in_use
-                metrics.peak_used_pages = alloc.peak_in_use
-                metrics.largest_contig_free = (
-                    alloc.largest_contiguous_free()
-                )
-            pc = getattr(engine, "prefix_cache", None)
-            if pc is not None:
-                metrics.prefix_hits = pc.hits
-                metrics.prefix_misses = pc.misses
-                metrics.prefix_hit_tokens = pc.hit_tokens
-                metrics.prefix_cached_pages = pc.size
-                metrics.prefix_shared_pages = engine.shared_pages
-                metrics.prefix_cow_copies = pc.cow_copies
-                metrics.prefix_evictions = pc.evicted_pages
-            metrics.kv_dtype = getattr(engine, "kv_dtype", "fp")
-            if hasattr(engine, "kv_pool_bytes"):
-                metrics.kv_pool_bytes = engine.kv_pool_bytes()
-            if hasattr(engine, "kv_quant_error"):
-                metrics.kv_quant_err = engine.kv_quant_error()
-            lp = getattr(engine, "lora", None)
-            if lp is not None:
-                metrics.lora_resident = lp.resident
-                metrics.lora_max_resident = lp.max_resident
-                metrics.lora_resident_bytes = lp.resident_bytes()
-                metrics.lora_loads = lp.loads
-                metrics.lora_evictions = lp.evictions
-                metrics.adapter_streams = lp.streams_by_adapter()
-        counters = getattr(engine, "model_counters", None)  # paged only
+        metrics.free_pages = engine.free_pages
+        alloc = engine.allocator
+        metrics.total_pages = alloc.num_pages - 1
+        metrics.used_pages = alloc.in_use
+        metrics.peak_used_pages = alloc.peak_in_use
+        metrics.largest_contig_free = alloc.largest_contiguous_free()
+        pc = engine.prefix_cache
+        if pc is not None:
+            metrics.prefix_hits = pc.hits
+            metrics.prefix_misses = pc.misses
+            metrics.prefix_hit_tokens = pc.hit_tokens
+            metrics.prefix_cached_pages = pc.size
+            metrics.prefix_shared_pages = engine.shared_pages
+            metrics.prefix_cow_copies = pc.cow_copies
+            metrics.prefix_evictions = pc.evicted_pages
+        metrics.kv_dtype = engine.kv_dtype
+        metrics.kv_pool_bytes = engine.kv_pool_bytes()
+        metrics.kv_quant_err = engine.kv_quant_error()
+        lp = engine.lora
+        if lp is not None:
+            metrics.lora_resident = lp.resident
+            metrics.lora_max_resident = lp.max_resident
+            metrics.lora_resident_bytes = lp.resident_bytes()
+            metrics.lora_loads = lp.loads
+            metrics.lora_evictions = lp.evictions
+            metrics.adapter_streams = lp.streams_by_adapter()
+        counters = engine.model_counters
         if counters is not None:
             # The model's own counters (an expert layer's routing, the
             # latent pool): read here, after collect(), with no window
             # in flight, so the read waits on nothing.
             metrics.model = counters()
         metrics.qos_depth = backlog.depths()
-        metrics.autotune_k = getattr(engine, "window", 0)
+        metrics.autotune_k = engine.window
         if monitor is not None:
-            metrics.device_compute_ns = getattr(engine, "device_compute_ns", 0)
-            metrics.host_dispatch_ns = getattr(engine, "host_dispatch_ns", 0)
-            metrics.device_fetch_ns = getattr(engine, "device_fetch_ns", 0)
-            metrics.dispatched_flops = getattr(engine, "dispatched_flops", 0)
-            metrics.useful_flops = getattr(engine, "useful_flops", 0)
+            metrics.device_compute_ns = engine.device_compute_ns
+            metrics.host_dispatch_ns = engine.host_dispatch_ns
+            metrics.device_fetch_ns = engine.device_fetch_ns
+            metrics.dispatched_flops = engine.dispatched_flops
+            metrics.useful_flops = engine.useful_flops
             mem = monitor.memory()
             metrics.hbm_used_bytes = mem["used"]
             metrics.hbm_limit_bytes = mem["limit"]
@@ -1267,7 +1215,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                 d_flops = metrics.useful_flops - util_prev["flops"]
                 if d_flops < 0:
                     d_flops = metrics.useful_flops
-                peak = getattr(engine, "device_peak_flops", 0.0)
+                peak = engine.device_peak_flops
                 metrics.mfu = (
                     min(1.0, (d_flops / dt) / peak) if peak > 0 else None
                 )
@@ -1381,7 +1329,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         Runs at a window boundary, so clients see at most one window of
         added latency."""
         handoff_dir = (event.get("metadata") or {}).get("handoff_dir", "")
-        if not handoff_dir or not can_ckpt:
+        if not handoff_dir:
             return
         t0 = clock()
         state = engine.drain_streams()
@@ -1495,7 +1443,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                 # Tenant custody rides the stream: the target must be
                 # able to serve (load) the stream's adapter or the
                 # handoff stays on disk for a peer that can.
-                lp = getattr(engine, "lora", None)
+                lp = engine.lora
                 if lp is None or not lp.has(ad):
                     return False
             if m.get("decode"):
@@ -1512,7 +1460,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         for entry in payload.get("backlog") or []:
             ad = entry[4] if len(entry) > 4 else None
             if ad:
-                lp = getattr(engine, "lora", None)
+                lp = engine.lora
                 if lp is None or not lp.has(ad):
                     return False
         return pages <= engine.free_pages
@@ -1611,7 +1559,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             clock=clock,
             on_tick=on_tick if recovery_on else None,
             on_step=on_step if ckpt_dir else None,
-            handle_migrate=handle_migrate if can_ckpt else None,
+            handle_migrate=handle_migrate,
             handle_profile=handle_profile,
             on_engine_error=on_engine_error,
             keep_alive=bool(migrate_dir),
@@ -1673,7 +1621,7 @@ def _stub_main() -> None:
         tick_sleep_s=delay / window,
     )
     serve(
-        Node(), engine, ServingMetrics(engine="paged"),
+        Node(), engine, ServingMetrics(),
         encode=lambda text: [ord(ch) % 97 for ch in text] or [1],
         decode_one=lambda t: f" t{t}",
         max_new_cap=int(os.environ.get("DORA_MAX_NEW_TOKENS", "8")),
@@ -1735,9 +1683,7 @@ def main() -> None:
         return tokenizer.decode([token])
 
     engine = make_engine(params, cfg, eos=eos, module=module)
-    metrics = ServingMetrics(
-        engine="paged" if hasattr(engine, "free_pages") else "dense"
-    )
+    metrics = ServingMetrics()
     backend.report("engine_built", {
         "engine": metrics.engine, "layers": cfg.layers, "dim": cfg.dim,
         "memory": backend.memory_report(),

@@ -651,266 +651,17 @@ def attention_chunk_step(
 
 
 # ---------------------------------------------------------------------------
-# batched attention (continuous batching: B independent sequences)
-# ---------------------------------------------------------------------------
-
-
-def _attn_batch_kernel(
-    pos_ref,  # SMEM (B,) int32 — per-row positions, scalar prefetch
-    x_ref, nw_ref, wqkv_ref, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
-    kc_in, vc_in, wo_ref, swo_ref,
-    out_ref, kc_out, vc_out,
-    kv_row, kblk, vblk, sem, wsem,
-    *, heads: int, kv_heads: int, head_dim: int, bs: int, eps: float,
-    batch: int, residual: bool,
-):
-    """B-row decode step over B INDEPENDENT sequences: row b sits at its
-    own position in its own cache plane ``[b]``. One weight stream (the
-    HBM-bandwidth cost of a single decode step) serves every row — the
-    continuous-batching workhorse. Rows never attend each other."""
-    half = head_dim // 2
-    dtype = x_ref.dtype
-    int4 = wqkv_ref.dtype == jnp.uint8
-    group = heads // kv_heads
-    scale = 1.0 / (head_dim ** 0.5)
-
-    # --- projections (all rows at once: one weight pass) --------------------
-    h = _rms(x_ref, nw_ref, eps).astype(dtype)  # [B, D]
-    qkv = _wdot(h, wqkv_ref, sqkv_ref[...], int4=int4) + bqkv_ref[...].astype(
-        jnp.float32
-    )  # [B, (H+2KV)*hd]
-    cos_b = cos_ref[...].astype(jnp.float32)  # [B, hd]
-    sin_b = sin_ref[...].astype(jnp.float32)
-
-    qf = qkv[:, : heads * head_dim].reshape(batch * heads, head_dim)
-    kf = qkv[:, heads * head_dim : (heads + kv_heads) * head_dim].reshape(
-        batch * kv_heads, head_dim
-    )
-    vf = qkv[:, (heads + kv_heads) * head_dim :].reshape(
-        batch * kv_heads, head_dim
-    )
-
-    def _expand(t, reps):
-        return jnp.broadcast_to(
-            t[:, None, :], (batch, reps, head_dim)
-        ).reshape(batch * reps, head_dim)
-
-    q = _rotate(qf, _expand(cos_b, heads), _expand(sin_b, heads), half)
-    k = _rotate(kf, _expand(cos_b, kv_heads), _expand(sin_b, kv_heads), half)
-    q_b = q.reshape(batch, heads, head_dim)
-    k_b = k.reshape(batch, kv_heads, head_dim)
-    v_b = vf.reshape(batch, kv_heads, head_dim)
-
-    # --- per-row cache RMW (aligned 8-row windows, write-back overlapped) ---
-    pending = []
-    for b in range(batch):
-        pos = pos_ref[b]
-        aligned = pl.multiple_of(pos // 8 * 8, 8)
-        rd_k = pltpu.make_async_copy(
-            kc_out.at[b, :, pl.ds(aligned, 8), :], kv_row.at[0, b],
-            sem.at[0],
-        )
-        rd_v = pltpu.make_async_copy(
-            vc_out.at[b, :, pl.ds(aligned, 8), :], kv_row.at[1, b],
-            sem.at[1],
-        )
-        rd_k.start()
-        rd_v.start()
-        rd_k.wait()
-        rd_v.wait()
-        row_sel = (
-            jax.lax.broadcasted_iota(jnp.int32, (kv_heads, 8, head_dim), 1)
-            == pos - aligned
-        )
-        kv_row[0, b] = jnp.where(
-            row_sel, k_b[b][:, None, :].astype(kv_row.dtype), kv_row[0, b]
-        )
-        kv_row[1, b] = jnp.where(
-            row_sel, v_b[b][:, None, :].astype(kv_row.dtype), kv_row[1, b]
-        )
-        wr_k = pltpu.make_async_copy(
-            kv_row.at[0, b], kc_out.at[b, :, pl.ds(aligned, 8), :],
-            wsem.at[0, b],
-        )
-        wr_v = pltpu.make_async_copy(
-            kv_row.at[1, b], vc_out.at[b, :, pl.ds(aligned, 8), :],
-            wsem.at[1, b],
-        )
-        wr_k.start()
-        wr_v.start()
-        pending += [wr_k, wr_v]
-
-    # --- per-row flash sweep over the prior context -------------------------
-    attn_rows = []
-    for b in range(batch):
-        pos = pos_ref[b]
-        nblocks = (pos + bs - 1) // bs
-        qb = q_b[b]  # [H, hd]
-
-        def body(blk, carry, pos=pos, qb=qb, b=b):
-            m_run, l_run, acc = carry
-            kcp = pltpu.make_async_copy(
-                kc_out.at[b, :, pl.ds(blk * bs, bs), :], kblk, sem.at[2]
-            )
-            vcp = pltpu.make_async_copy(
-                vc_out.at[b, :, pl.ds(blk * bs, bs), :], vblk, sem.at[3]
-            )
-            kcp.start()
-            vcp.start()
-            kcp.wait()
-            vcp.wait()
-            live = (
-                jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) + blk * bs
-            ) < pos
-            scores = []
-            for g in range(kv_heads):
-                s_g = jax.lax.dot_general(
-                    qb[g * group : (g + 1) * group].astype(dtype),
-                    kblk[g].astype(dtype),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                scores.append(s_g)
-            s = jnp.concatenate(scores, axis=0) * scale
-            s = jnp.where(live, s, -jnp.inf)
-            m_new = jnp.maximum(m_run, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_run - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_run * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            pv = []
-            for g in range(kv_heads):
-                pv.append(
-                    jax.lax.dot(
-                        p[g * group : (g + 1) * group].astype(dtype),
-                        vblk[g].astype(dtype),
-                        preferred_element_type=jnp.float32,
-                    )
-                )
-            acc_new = acc * alpha + jnp.concatenate(pv, axis=0)
-            return m_new, l_new, acc_new
-
-        m0 = jnp.full((heads, 1), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((heads, 1), jnp.float32)
-        a0 = jnp.zeros((heads, head_dim), jnp.float32)
-        m_fin, l_fin, acc = jax.lax.fori_loop(0, nblocks, body, (m0, l0, a0))
-
-        # fold in the current position from registers (exact merge)
-        q3 = qb.reshape(kv_heads, group, head_dim)
-        s_new = (
-            jnp.sum(q3 * k_b[b][:, None, :], axis=-1).reshape(heads, 1)
-            * scale
-        )
-        m2 = jnp.maximum(m_fin, s_new)
-        alpha = jnp.exp(m_fin - m2)
-        w_new = jnp.exp(s_new - m2)
-        l2 = l_fin * alpha + w_new
-        v_full = jnp.broadcast_to(
-            v_b[b][:, None, :], (kv_heads, group, head_dim)
-        ).reshape(heads, head_dim)
-        attn_rows.append((acc * alpha + w_new * v_full) / l2)  # [H, hd]
-
-    attn = jnp.stack(attn_rows, axis=0).reshape(batch, heads * head_dim)
-
-    # --- output projection + residual ---------------------------------------
-    o = _wdot(attn.astype(dtype), wo_ref, swo_ref[...], int4=int4)
-    if residual:
-        o = x_ref[...].astype(jnp.float32) + o
-    out_ref[...] = o.astype(out_ref.dtype)
-    for copy in pending:
-        copy.wait()
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("heads", "kv_heads", "head_dim", "eps", "residual"),
-)
-def attention_batch_step(
-    x, norm_w, wqkv, sqkv, bqkv, cos_rows, sin_rows, k_caches, v_caches,
-    wo, swo, positions, *, heads: int, kv_heads: int, head_dim: int,
-    eps: float = 1e-6, residual: bool = True,
-):
-    """Fused decode attention for B independent sequences.
-
-    x: [B, D]; caches: [B, KV, S, hd] (updated in place — row b at
-    ``positions[b]``); cos_rows/sin_rows: [B, hd] per-row rope rows
-    gathered at each row's position (rope_rows_at). Weight layout
-    matches :func:`attention_step`. Returns (x_out [B, D], k_caches,
-    v_caches). Rows are independent: nothing attends across rows, so an
-    idle slot just burns its own flash sweep (mask at the caller).
-    """
-    batch = x.shape[0]
-    seq = k_caches.shape[2]
-    bs = min(512, seq)
-    assert seq % bs == 0, (seq, bs)
-    d = x.shape[-1]
-    n_qkv = wqkv.shape[1]
-    kernel = functools.partial(
-        _attn_batch_kernel, heads=heads, kv_heads=kv_heads,
-        head_dim=head_dim, bs=bs, eps=eps, batch=batch, residual=residual,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # x
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # norm_w
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # wqkv
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # sqkv
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # bqkv
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # cos rows
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # sin rows
-            pl.BlockSpec(memory_space=pl.ANY),      # k_caches (HBM)
-            pl.BlockSpec(memory_space=pl.ANY),      # v_caches (HBM)
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # wo
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # swo
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, batch, kv_heads, 8, head_dim), k_caches.dtype),
-            pltpu.VMEM((kv_heads, bs, head_dim), k_caches.dtype),
-            pltpu.VMEM((kv_heads, bs, head_dim), v_caches.dtype),
-            pltpu.SemaphoreType.DMA((4,)),
-            pltpu.SemaphoreType.DMA((2, batch)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(
-                (batch, d), x.dtype if residual else jnp.float32
-            ),
-            jax.ShapeDtypeStruct(k_caches.shape, k_caches.dtype),
-            jax.ShapeDtypeStruct(v_caches.shape, v_caches.dtype),
-        ],
-        input_output_aliases={8: 1, 9: 2},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=_interpret(),
-    )(
-        jnp.asarray(positions, jnp.int32).reshape(batch),
-        x, norm_w.reshape(1, d), wqkv, sqkv, bqkv.reshape(1, n_qkv),
-        cos_rows, sin_rows, k_caches, v_caches, wo, swo,
-    )
-
-
-# ---------------------------------------------------------------------------
 # paged attention (block-table KV: concurrency decoupled from max_seq)
 # ---------------------------------------------------------------------------
 #
-# The dense batched kernel above streams each row's K/V from a private
-# contiguous [slot, max_seq] plane, so HBM cost is max_slots * max_seq
-# rows whether a slot holds 40 tokens or 2000. The paged tier keeps ONE
-# fixed pool of page-size blocks shared by every slot; a per-slot block
-# table maps logical page j to a physical pool page, so HBM scales with
-# tokens actually held (vLLM's PagedAttention insight). Physical page 0
-# is reserved as the idle dump: inactive rows point at it and their
-# position-0 writes land there harmlessly.
+# A private contiguous [slot, max_seq] cache plane per row would cost
+# max_slots * max_seq rows of HBM whether a slot holds 40 tokens or
+# 2000. The paged tier keeps ONE fixed pool of page-size blocks shared
+# by every slot; a per-slot block table maps logical page j to a
+# physical pool page, so HBM scales with tokens actually held (vLLM's
+# PagedAttention insight). Physical page 0 is reserved as the idle
+# dump: inactive rows point at it and their position-0 writes land
+# there harmlessly.
 #
 # The decode kernel's flash sweep over those pages is a software
 # pipeline (:func:`_paged_sweep`): a page is 16 rows, far too little for
@@ -1095,9 +846,9 @@ def _attn_paged_batch_kernel(
     batch: int, residual: bool, kv_quant: bool = False,
 ):
     """B-row decode over B independent sequences whose K/V live in a
-    shared page pool [P, KV, page, hd]. Identical math to
-    :func:`_attn_batch_kernel`; only the HBM addressing changes — the
-    flash sweep walks pool pages through the row's block table, and the
+    shared page pool [P, KV, page, hd]. Per row, identical math to
+    :func:`_attn_kernel`; only the HBM addressing changes — the flash
+    sweep walks pool pages through the row's block table, and the
     in-place row write targets the row's CURRENT page.
 
     Order of events, so that page DMAs are always in flight: (1) at
@@ -1143,7 +894,7 @@ def _attn_paged_batch_kernel(
     scale = 1.0 / (head_dim ** 0.5)
 
     # --- every row's current 8-row window, requested together ---------------
-    # The aligned 8-row read-modify-write of the dense kernel, but the
+    # The aligned 8-row read-modify-write of :func:`_attn_kernel`, but the
     # window lives inside pool page bt[b, pos // page] at in-page offset
     # pos % page (page is a multiple of 8, so the window never crosses a
     # page boundary). ``windows(b)`` pairs each pool window with its
@@ -1292,7 +1043,10 @@ def attention_paged_batch_step(
     at each row's ``positions[b]`` inside page
     ``block_tables[b, positions[b] // page]``); block_tables:
     [B, max_pages] int32 physical page ids (0 = the reserved idle page).
-    Weight layout matches :func:`attention_batch_step`. Returns
+    Weight layout matches :func:`attention_step` (wqkv int8
+    [D, (H+2KV)*hd] with scale [1, ...], or int4 [D/2, ...] uint8 with
+    group scales); cos_rows/sin_rows: [B, hd] per-row rope rows gathered
+    at each row's position (rope_rows_at). Returns
     (x_out [B, D], k_pool, v_pool).
 
     One kernel call, ``grid=(1,)``. The rows' contexts are swept by

@@ -1,17 +1,6 @@
-"""A/B the paged serving engine against the dense engine.
+"""A/B legs of the paged serving engine, one JSON line on stdout each.
 
-Two axes, one JSON line on stdout:
-
-* ``streams4``  — 4 concurrent requests, paged vs dense (both engines
-  hold 4 slots). The paged path must be throughput-neutral here: block
-  table indirection is supposed to cost ~nothing at the batch size the
-  dense engine was built for (the ±3% acceptance gate).
-* ``streams16`` — 16 requests arriving at once. The paged engine holds
-  16 slots inside the dense engine's 4-slot KV footprint and serves
-  them concurrently; the dense engine (4 slots, SAME HBM) must queue
-  12 of them — wall clock and TTFT p99 show what paging buys.
-
-A third axis behind ``--multistep``: the K-sweep of the fused
+``--multistep``: the K-sweep of the fused
 multi-step decode window (K in {1, 4, 8, 16}) at 4 and 16 streams,
 reporting HOST ROUND-TRIPS (engine dispatches + device->host fetches)
 per emitted token next to tok/s. Round-trips are host-side counts:
@@ -21,18 +10,18 @@ Model: ``DORA_HF_CHECKPOINT`` when set (real numbers on the TPU box);
 otherwise a tiny random Qwen2 is built in-process and the numbers are
 relative-only (CPU smoke A/B, same code path).
 
-A fourth axis behind ``--trace-ab``: the 16-stream paged run with the
+``--trace-ab``: the 16-stream paged run with the
 serving observability plane attached, tracing off vs on (interleaved),
 reporting the wall-clock overhead of the request-lifecycle span
 records — the serving counterpart of bench.py's recorder A/B gate
 (≤3%).
 
-A fifth axis behind ``--spec-ab``: speculative decoding inside the
+``--spec-ab``: speculative decoding inside the
 fused window (DORA_SPEC_K), spec_k in {0, 2, 4} x K in {1, 8} on the
 stub engine's repetitive (best-case acceptance) and random (worst-case)
 token rules — tokens per dispatch and acceptance rate per cell.
 
-A sixth axis behind ``--qos-soak``: open-loop Poisson mixed-class
+``--qos-soak``: open-loop Poisson mixed-class
 overload through the REAL serve() admission path (stub engine, no
 weights), QoS shaping on vs off over the identical arrival trace —
 per-class TTFT p50/p99, shed rate, preempt/resume counts. The
@@ -40,7 +29,7 @@ acceptance headline is ``interactive_p99_on_vs_off`` < 1.0: shaping
 must buy the interactive class latency under overload, paid for by the
 batch class, never by silent loss (completion accounting rides along).
 
-A seventh axis behind ``--prefix-ab``: the shared-prefix KV cache at
+``--prefix-ab``: the shared-prefix KV cache at
 admission (DORA_PREFIX_CACHE), a Zipf-popular template workload (hot
 system prompts, unique tails) replayed open-loop with the cache on vs
 off over the identical arrival trace — hit rate, TTFT p50/p99 for hit
@@ -49,7 +38,7 @@ occupancy. The acceptance headline is ``hit_p50_on_vs_off`` <= 0.5: a
 cache hit must at least halve first-token latency to justify the
 serving default-on.
 
-An eighth axis behind ``--quant-ab``: quantized serving
+``--quant-ab``: quantized serving
 (DORA_KV_INT8 / DORA_WEIGHT_BITS) — the same 4-stream workload on fp
 vs int8-KV vs int8-KV + int4-weight engines (greedy token agreement
 against the fp leg rides along), plus a capacity leg that counts how
@@ -59,7 +48,7 @@ acceptance headline is ``int8_capacity_ratio`` >= 1.8 (a
 spec-acceptance leg rides along: acceptance counters under int8 KV vs
 fp — the round-18 drift signal).
 
-A ninth axis behind ``--lora-ab``: multi-tenant LoRA serving — the
+``--lora-ab``: multi-tenant LoRA serving — the
 aggregate tokens/s of ONE paged engine serving N adapter tenants vs N
 separate engines splitting the same HBM budget, plus an adapter-churn
 leg asserting zero steady-state compiles while tenants rotate through
@@ -68,10 +57,10 @@ the resident budget. The acceptance headline is
 
 Usage::
 
-    python -m dora_tpu.tools.bench_serving [--multistep | --trace-ab |
+    python -m dora_tpu.tools.bench_serving (--multistep | --trace-ab |
                                             --spec-ab | --qos-soak |
                                             --prefix-ab | --quant-ab |
-                                            --lora-ab]
+                                            --lora-ab)
 """
 
 from __future__ import annotations
@@ -116,12 +105,7 @@ def _serve(engine, prompts, max_new: int):
         while backlog and engine.can_admit(len(backlog[0][1]), max_new):
             rid, ids = backlog.popleft()
             active_keys.add(rid)
-            res = engine.submit(str(rid), ids, max_new)
-            if res is not None:  # dense: first token is synchronous
-                tokens += 1
-                ttft.setdefault(rid, time.perf_counter() - t0)
-                if res[1]:
-                    active_keys.discard(rid)
+            engine.submit(str(rid), ids, max_new)
         for key, _token, done in engine.step():
             rid = int(key)
             tokens += 1
@@ -1130,8 +1114,6 @@ def _prefix_ab() -> dict:
 
 
 def main() -> int:
-    import numpy as np
-
     from dora_tpu.models.hf import qwen2
 
     if "--prefix-ab" in sys.argv[1:]:
@@ -1180,81 +1162,7 @@ def main() -> int:
     if "--quant-ab" in sys.argv[1:]:
         print(json.dumps({"quant_ab": _quant_ab(qwen2, path, real)}))
         return 0
-    # Workload scales with the model: the real box gets 64-token prompts
-    # and 32 new tokens inside the default (dense-4-footprint) pool; the
-    # tiny CPU smoke shrinks everything to stay admissible at 16 streams
-    # within the same footprint rule.
-    if real:
-        max_seq = int(os.environ.get("DORA_MAX_SEQ", "512"))
-        page_size, chunk, plen, max_new = 16, 64, 64, 32
-    else:
-        max_seq, page_size, chunk, plen, max_new = 64, 8, 8, 4, 4
-
-    cfg, params = qwen2.load(path, max_seq=max_seq)
-    os.environ.setdefault("DORA_INT8_DECODE", "1")
-    params = qwen2.quantize_decode(params, cfg)
-    rng = np.random.default_rng(0)
-
-    def prompts(n: int) -> list[list[int]]:
-        return [
-            rng.integers(0, cfg.vocab, size=plen).tolist() for _ in range(n)
-        ]
-
-    import jax
-
-    out: dict = {
-        "backend": jax.default_backend(),
-        "model": "checkpoint" if real else "tiny-random",
-        "plen": plen,
-        "max_new": max_new,
-    }
-
-    dense4 = qwen2.make_batch_engine(params, cfg, max_slots=4)
-    paged4 = qwen2.make_paged_engine(
-        params, cfg, max_slots=4, page_size=page_size, chunk=chunk
-    )
-    paged16 = qwen2.make_paged_engine(
-        params, cfg, max_slots=16, page_size=page_size, chunk=chunk
-    )
-
-    # Warmup: run each engine through the full workload shape once so
-    # the measured round holds zero compiles (the paged engine's
-    # steady-state guarantee; the dense engine compiles its buckets).
-    _serve(dense4, prompts(4), max_new)
-    _serve(paged4, prompts(4), max_new)
-    _serve(paged16, prompts(16), max_new)
-
-    p4 = _stats(*_serve(paged4, prompts(4), max_new))
-    d4 = _stats(*_serve(dense4, prompts(4), max_new))
-    out["streams4"] = {
-        "paged": p4,
-        "dense": d4,
-        "paged_vs_dense": (
-            round(p4["decode_tok_s"] / d4["decode_tok_s"], 3)
-            if p4["decode_tok_s"] and d4["decode_tok_s"]
-            else None
-        ),
-    }
-
-    p16 = _stats(*_serve(paged16, prompts(16), max_new))
-    d16 = _stats(*_serve(dense4, prompts(16), max_new))
-    pool_bytes = sum(x.nbytes for x in jax.tree.leaves(paged16.pools))
-    dense_bytes = sum(
-        x.nbytes for x in jax.tree.leaves(qwen2.init_cache(cfg, 4))
-    )
-    out["streams16"] = {
-        "paged_16slot": p16,
-        "dense_4slot_queued": d16,
-        "paged_pool_bytes": pool_bytes,
-        "dense_4slot_cache_bytes": dense_bytes,
-        "wall_speedup": (
-            round(d16["wall_s"] / p16["wall_s"], 2)
-            if p16["wall_s"] and d16["wall_s"]
-            else None
-        ),
-    }
-    print(json.dumps(out))
-    return 0
+    raise SystemExit(__doc__[__doc__.index("Usage::"):])
 
 
 if __name__ == "__main__":

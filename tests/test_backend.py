@@ -203,41 +203,68 @@ def _largest_constant(lowered_text: str) -> int:
     return biggest
 
 
-@pytest.mark.parametrize("engine_kind", ["paged", "dense"])
-def test_engine_programs_take_the_weights_as_arguments(engine_kind):
+def _tiny_kimi(tmp_path):
+    from test_kimi_k2 import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import kimi_k2
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return kimi_k2.load(tmp_path / "ckpt", max_seq=64)
+
+
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2"])
+def test_engine_programs_take_the_weights_as_arguments(
+    module_name, monkeypatch, tmp_path
+):
     """A closed-over array lowers to a stablehlo.constant — gigabytes of
-    weights inside every executable and cache entry at full width. The
-    window, chunk and dense step programs must hold no weight-sized
-    constant: the smallest weight matrix here has dim*kv = 2048 elements,
-    the largest legitimate constant (a rope table) 32*16 = 512."""
-    from dora_tpu.models.hf import qwen2
+    weights inside every executable and cache entry at full width. No
+    program the engine jits (window, chunk, slot insert) may hold a
+    weight-sized constant, and the window and chunk programs must be
+    handed the parameter tree itself: the smallest weight matrix of
+    either tiny model has 2048 elements, the largest legitimate
+    constant (a rope table) 512."""
+    import importlib
 
-    cfg, params = _tiny_model()
-    make = (qwen2.make_paged_engine if engine_kind == "paged"
-            else qwen2.make_batch_engine)
-    engine = make(params, cfg, max_slots=2)
-    seen: dict[str, tuple] = {}
-    names = (("chunk_prefill", "window_step") if engine_kind == "paged"
-             else ("batch_step",))
-    programs = {name: getattr(engine, name) for name in names}
-    for name, program in programs.items():
-        assert program.args[0] is params  # functools.partial(jit, params)
+    module = importlib.import_module(f"dora_tpu.models.hf.{module_name}")
+    cfg, params = (
+        _tiny_model() if module_name == "qwen2" else _tiny_kimi(tmp_path)
+    )
+    #: program -> (abstract arguments of its first call, got the params)
+    seen: dict = {}
+    real_jit = jax.jit
 
-        def spy(*args, _name=name, _program=program):
-            seen.setdefault(_name, args)
-            return _program(*args)
+    def spy_jit(fn, **kw):
+        jitted = real_jit(fn, **kw)
 
-        setattr(engine, name, spy)
+        def call(*args, **static):
+            if static:  # a kernel wrapper imported meanwhile, not ours
+                return jitted(*args, **static)
+            if jitted not in seen:
+                shapes = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
+                    args,
+                )
+                seen[jitted] = (shapes, bool(args) and args[0] is params)
+            return jitted(*args)
+
+        return call
+
+    monkeypatch.setattr(jax, "jit", spy_jit)
+    engine = module.make_paged_engine(
+        params, cfg, max_slots=2, page_size=8, chunk=16, window=2
+    )
+    monkeypatch.undo()
     engine.submit("r", [5, 7, 11], 4)
     for _ in range(20):
         engine.step()
         if not engine.active:
             break
-    assert set(seen) == set(names)
-    for name, program in programs.items():
-        text = program.func.lower(params, *seen[name]).as_text()
+    assert not engine.active
+    assert sum(took for _shapes, took in seen.values()) == 2  # window, chunk
+    for jitted, (shapes, _took) in seen.items():
+        text = jitted.lower(*shapes).as_text()
         assert "stablehlo" in text
-        assert _largest_constant(text) < 1024, name
+        assert _largest_constant(text) < 1024, jitted
 
 
 # -- the smoke test's parent stays off JAX ----------------------------------

@@ -1,9 +1,9 @@
-"""Continuous batching engine (models/batch_engine.py).
+"""Continuous batching engine (models/batch_engine.py): slot custody.
 
-The load-bearing property: streams that join MID-FLIGHT (while other
-slots are decoding) emit exactly the tokens the serial batch-1 path
-emits for that prompt alone — slot isolation across the batched cache
-planes, positions, and the shared weight stream.
+A full engine refuses a submit, a request past ``max_seq`` is never
+admissible, freed slots admit again, and a stream stops AT its EOS
+token. (Mid-flight joins against the serial reference:
+tests/test_paged_engine.py.)
 """
 
 import numpy as np
@@ -48,55 +48,17 @@ def quantized(tiny_qwen2):
     return cfg, qparams
 
 
-def test_mid_flight_joins_match_serial(quantized):
-    import jax.numpy as jnp
-
+def _engine(quantized, **kw):
     from dora_tpu.models.hf import qwen2
 
     cfg, qparams = quantized
-    rng = np.random.default_rng(5)
-    prompts = [
-        rng.integers(0, cfg.vocab, size=n).tolist() for n in (3, 7, 12)
-    ]
-    max_new = 10
-    refs = [
-        np.asarray(
-            qwen2.generate(
-                qparams, cfg, jnp.asarray([p], jnp.int32), max_new
-            )
-        )[0].tolist()
-        for p in prompts
-    ]
-
-    engine = qwen2.make_batch_engine(qparams, cfg, max_slots=3)
-    streams: dict[str, list[int]] = {}
-
-    def drain(events):
-        for rid, token, _done in events:
-            streams[rid].append(token)
-
-    streams["r0"] = [engine.submit("r0", prompts[0], max_new)[0]]
-    drain(engine.step())
-    drain(engine.step())
-    # r1 joins while r0 is mid-decode
-    streams["r1"] = [engine.submit("r1", prompts[1], max_new)[0]]
-    drain(engine.step())
-    # r2 joins while both are mid-decode
-    streams["r2"] = [engine.submit("r2", prompts[2], max_new)[0]]
-    for _ in range(max_new + 2):
-        drain(engine.step())
-    assert engine.active == 0
-
-    assert streams["r0"] == refs[0]
-    assert streams["r1"] == refs[1]
-    assert streams["r2"] == refs[2]
+    return qwen2.make_paged_engine(
+        qparams, cfg, max_slots=2, page_size=8, chunk=8, **kw
+    )
 
 
 def test_slot_reuse_and_admission(quantized):
-    from dora_tpu.models.hf import qwen2
-
-    cfg, qparams = quantized
-    engine = qwen2.make_batch_engine(qparams, cfg, max_slots=2)
+    engine = _engine(quantized)
     assert not engine.can_admit(60, 10)  # exceeds max_seq
     engine.submit("a", [1, 2, 3], 3)
     engine.submit("b", [4, 5], 3)
@@ -105,13 +67,16 @@ def test_slot_reuse_and_admission(quantized):
         engine.submit("c", [6], 3)
     while engine.active:
         engine.step()
-    # freed slots admit again and produce sane output
-    first, done = engine.submit("c", [6, 7, 8, 9], 4)
-    assert 0 <= first < cfg.vocab and not done
+    # freed slots admit again and emit exactly max_new tokens
+    assert engine.free_slots == 2
+    engine.submit("c", [6, 7, 8, 9], 4)
     out = []
     while engine.active:
         out += engine.step()
-    assert len(out) == 3 and out[-1][2] is True
+    cfg = quantized[0]
+    assert [rid for rid, _t, _d in out] == ["c"] * 4
+    assert all(0 <= t < cfg.vocab for _r, t, _d in out)
+    assert [d for _r, _t, d in out] == [False, False, False, True]
 
 
 def test_eos_frees_slot(quantized):
@@ -125,10 +90,11 @@ def test_eos_frees_slot(quantized):
         qwen2.generate(qparams, cfg, jnp.asarray([prompt], jnp.int32), 8)
     )[0].tolist()
     eos = ref[3]  # pretend the 4th emitted token is EOS
-    engine = qwen2.make_batch_engine(qparams, cfg, max_slots=2, eos=eos)
-    stream = [engine.submit("x", prompt, 8)[0]]
+    engine = _engine(quantized, eos=eos)
+    engine.submit("x", prompt, 8)
+    stream = []
     while engine.active:
-        for rid, token, done in engine.step():
+        for _rid, token, _done in engine.step():
             stream.append(token)
     assert stream == ref[:4]  # stops AT the eos token
     assert engine.free_slots == 2

@@ -214,15 +214,6 @@ def test_paged_chunk_step_compiles_at_default_chunk(chip, kv_int8):
     )
 
 
-def test_dense_batch_step_compiles(chip):
-    caches = jax.eval_shape(lambda: qwen2.init_cache(CFG, 8))
-    _compile(
-        _step(qwen2.fused_batch_step),
-        chip(_qparams(CFG)),
-        *chip((_s((8,), I32), caches, _s((8,), I32))),
-    )
-
-
 def test_lora_gather_matmul_compiles(chip):
     from dora_tpu.ops.lora import lora_gather_matmul
 

@@ -126,15 +126,12 @@ def test_free_stream_capacity_shrinks_with_the_page_pool():
     assert fleet.free_stream_capacity(eng) == 0
 
 
-class _SlotEngine:
-    free_slots = 3
-
-    def fits(self, prompt_len, max_new, adapter=None):
-        return True
-
-
-def test_free_stream_capacity_slot_engine_fallback():
-    assert fleet.free_stream_capacity(_SlotEngine()) == 3
+def test_free_stream_capacity_is_zero_for_a_request_that_never_fits():
+    eng = _stub_engine(max_slots=4, num_pages=8, max_seq=32, page_size=8)
+    assert eng.free_slots == 4 and eng.free_pages == 7
+    assert not eng.fits(30, 16)  # 46 rows > max_seq
+    assert fleet.free_stream_capacity(eng, prompt_len=30, max_new=16) == 0
+    assert fleet.free_stream_capacity(eng, prompt_len=8, max_new=8) > 0
 
 
 # ---------------------------------------------------------------------------

@@ -556,8 +556,8 @@ def test_llm_server_main_calls_the_module_as_it_called_qwen2(
     monkeypatch.setattr(llm_server, "Node", lambda: None)
     monkeypatch.setenv("DORA_HF_CHECKPOINT", str(tmp_path))
     monkeypatch.setenv("DORA_MAX_SEQ", "64")
-    for knob in ("DORA_PAGED_KV", "DORA_BATCH_SLOTS", "DORA_PAGE_SIZE",
-                 "DORA_PREFILL_CHUNK", "DORA_MULTISTEP_K", "DORA_PREFIX_CACHE",
+    for knob in ("DORA_BATCH_SLOTS", "DORA_PAGE_SIZE", "DORA_PREFILL_CHUNK",
+                 "DORA_MULTISTEP_K", "DORA_PREFIX_CACHE",
                  "DORA_PREFIX_CACHE_PAGES"):
         monkeypatch.delenv(knob, raising=False)
     llm_server.main()

@@ -37,11 +37,14 @@ class FakeEngine:
     def fits(self, plen: int, max_new: int) -> bool:
         return plen + max_new <= 64
 
-    def can_admit(self, plen: int, max_new: int) -> bool:
+    def can_admit(self, plen: int, max_new: int, adapter=None) -> bool:
         if self.deny_admits > 0:
             self.deny_admits -= 1
             return False
         return self.active < self.max_slots and self.fits(plen, max_new)
+
+    def admit_blocker(self, plen: int, max_new: int, adapter=None):
+        return "capacity"
 
     def submit(self, key: str, ids: list[int], max_new: int):
         assert self.active < self.max_slots
@@ -51,7 +54,12 @@ class FakeEngine:
         self.submits.append((key, self.steps))
         return None
 
-    def step(self):
+    in_flight = False
+
+    def dispatch(self):
+        return []
+
+    def collect(self):
         self.steps += 1
         out = []
         for key in list(self.streams):
@@ -83,7 +91,9 @@ def _drive(engine, events):
     """Run the real serving loop over fakes; returns emitted tokens."""
     metrics = ServingMetrics()
     emitted: list[tuple[str, int, bool]] = []
-    backlog = AdmissionQueue(engine, lambda k, ids, mn: engine.submit(k, ids, mn))
+    backlog = AdmissionQueue(
+        engine, lambda k, ids, mn, adapter: engine.submit(k, ids, mn)
+    )
 
     def handle_input(event):
         rid = event["metadata"]["request_id"]
@@ -103,7 +113,9 @@ def _drive(engine, events):
 
 def test_push_admits_immediately_when_capacity_allows():
     engine = FakeEngine(slots=2)
-    q = AdmissionQueue(engine, lambda k, ids, mn: engine.submit(k, ids, mn))
+    q = AdmissionQueue(
+        engine, lambda k, ids, mn, adapter: engine.submit(k, ids, mn)
+    )
     q.push("a", [1, 2], 4)
     assert engine.active == 1 and len(q) == 0
 

@@ -47,10 +47,13 @@ class _Streams:
     def fits(self, plen: int, max_new: int) -> bool:
         return True
 
-    def can_admit(self, plen: int, max_new: int) -> bool:
+    def can_admit(self, plen: int, max_new: int, adapter=None) -> bool:
         return self.active < self.max_slots
 
-    def submit(self, key: str, ids, max_new: int):
+    def admit_blocker(self, plen: int, max_new: int, adapter=None):
+        return "capacity"
+
+    def submit(self, key: str, ids, max_new: int, adapter=None):
         self.streams[key] = [0, max_new]
         self.fresh.append(key)
 
@@ -101,11 +104,16 @@ class SplitEngine(_Streams):
         )
 
 
-class StepOnlyEngine(_Streams):
-    """The same engine with ``step()`` alone, as the dense engine and
-    the fakes of test_llm_backlog are."""
+class NothingInFlightEngine(_Streams):
+    """The same engine when ``dispatch()`` launches nothing (as after a
+    prefill-only dispatch): ``collect()`` does the whole step."""
 
-    def step(self):
+    in_flight = False
+
+    def dispatch(self):
+        return []
+
+    def collect(self):
         self.log.append(("step",))
         first = self._first()
         return first + self._window(
@@ -142,13 +150,11 @@ class ScriptNode:
 def _drive(engine, log, script, max_new=7, **hooks):
     metrics = ServingMetrics()
     backlog = AdmissionQueue(
-        engine, lambda k, ids, mn: engine.submit(k, ids, mn)
+        engine, lambda k, ids, mn, adapter: engine.submit(k, ids, mn)
     )
 
     def emit(key, token, done):
-        log.append(("emit", key, token, done, bool(
-            getattr(engine, "in_flight", False)
-        )))
+        log.append(("emit", key, token, done, bool(engine.in_flight)))
 
     def handle_input(event):
         backlog.push(event["metadata"]["request_id"], [1, 2], max_new)
@@ -210,10 +216,10 @@ def test_emit_overlapped_counts_tokens_sent_beside_a_window():
     assert metrics.dispatch_gap.count >= 6
 
 
-def test_step_only_engine_runs_the_same_order_with_nothing_overlapped():
+def test_a_dispatch_that_launched_nothing_counts_no_token_as_overlapped():
     log: list = []
     metrics, _ = _drive(
-        StepOnlyEngine(log, slots=2, k=3), log,
+        NothingInFlightEngine(log, slots=2, k=3), log,
         [(0, _input("a")), (0, _input("b"))],
     )
     assert [e[2] for e in _emits(log, "a")] == [1, 2, 3, 4, 5, 6, 7]
@@ -547,9 +553,8 @@ def test_dispatch_collect_under_the_loop_equals_step_in_a_loop(tiny, variant):
     metrics = ServingMetrics(engine="paged")
     backlog = AdmissionQueue(
         engine,
-        lambda k, ids, mn, adapter=None: (
-            engine.submit(k, ids, mn, adapter=adapter) if adapter
-            else engine.submit(k, ids, mn)
+        lambda k, ids, mn, adapter: engine.submit(
+            k, ids, mn, adapter=adapter
         ),
     )
 

@@ -628,65 +628,3 @@ def test_int4_fused_generate_matches_dequantized(monkeypatch):
     monkeypatch.delenv("DORA_FUSED_DECODE")
     spec, passes = vlm.generate_speculative(qparams, cfg, image, prompt, 8)
     np.testing.assert_array_equal(np.asarray(spec), fused)
-
-
-def test_batched_fused_decode_matches_per_row():
-    """attention_batch_step serves B INDEPENDENT sequences (own cache,
-    own position) — each row must emit exactly what the batch-1 fused
-    step emits for that sequence alone, across several steps."""
-    from dora_tpu.models import vlm
-
-    cfg = vlm.VLMConfig.tiny()
-    params = vlm.init_params(jax.random.PRNGKey(0), cfg)
-    qparams = vlm.quantize_decode(params)
-    assert vlm.fused_batch_ready(qparams)
-
-    lens = [4, 6, 3]
-    rows = []
-    for i, t in enumerate(lens):
-        image = jax.random.uniform(
-            jax.random.PRNGKey(10 + i),
-            (1, cfg.image_size, cfg.image_size, 3),
-        )
-        prompt = jax.random.randint(
-            jax.random.PRNGKey(20 + i), (1, t), 0, cfg.vocab
-        )
-        logits, caches, position = vlm.prefill(qparams, cfg, image, prompt)
-        rows.append(
-            {
-                "token": jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                "caches": caches,
-                "position": position,
-            }
-        )
-
-    # reference: per-row batch-1 fused steps
-    refs = [[] for _ in rows]
-    for i, row in enumerate(rows):
-        token = row["token"]
-        caches = jax.tree.map(jnp.copy, row["caches"])
-        pos = row["position"]
-        for _ in range(5):
-            refs[i].append(int(token[0]))
-            token, caches = vlm.decode_step_fused(
-                qparams, cfg, token, caches, pos
-            )
-            pos += 1
-
-    # batched: one kernel pass per step for all rows
-    batch_caches = jax.tree.map(
-        lambda *xs: jnp.concatenate(xs, axis=0),
-        *[r["caches"] for r in rows],
-    )
-    tokens = jnp.concatenate([r["token"] for r in rows])
-    positions = jnp.asarray([r["position"] for r in rows], jnp.int32)
-    outs = [[] for _ in rows]
-    for _ in range(5):
-        for i in range(len(rows)):
-            outs[i].append(int(tokens[i]))
-        tokens, batch_caches = vlm.decode_batch_fused(
-            qparams, cfg, tokens, batch_caches, positions
-        )
-        positions = positions + 1
-
-    assert refs == outs, (refs, outs)

@@ -3,17 +3,16 @@
 The load-bearing properties:
 
 * TOKEN IDENTITY: the paged + chunked-prefill engine emits exactly the
-  greedy tokens the dense engine (and the serial batch-1 path) emits,
+  greedy tokens the serial batch-1 path (qwen2.generate) emits,
   across staggered multi-slot admissions including prompts longer than
   one prefill chunk — block-table indirection and chunk interleaving
   change WHERE the KV rows live and WHEN prefill work runs, never the
   math.
-* CAPACITY: 16 concurrent slots run inside exactly the HBM pool the
-  dense engine spends on 4 (pages are granted for actual context).
+* CAPACITY: 16 concurrent slots run inside the HBM four contiguous
+  [max_seq] cache planes take (pages are granted for actual context).
 * COMPILE COUNT: steady-state serving (admissions at varied prompt
   lengths + decode steps) triggers ZERO new XLA compiles after warmup,
-  and chunked prefill compiles exactly one chunk shape — the dense
-  engine's per-bucket compile zoo is gone.
+  and chunked prefill compiles exactly one chunk shape.
 """
 
 from __future__ import annotations
@@ -150,15 +149,27 @@ def test_pages_needed_covers_chunk_padding():
 
 
 # ---------------------------------------------------------------------------
-# token identity vs the dense engine + serial reference
+# token identity vs the serial reference
 # ---------------------------------------------------------------------------
 
 
-def test_paged_matches_dense_across_staggered_admissions(
-    quantized, serial_ref
-):
-    """Staggered multi-slot admissions, including a 37-token prompt that
-    spans FIVE 8-token chunks admitted while other streams decode."""
+@pytest.fixture(scope="module")
+def staggered(quantized):
+    """``run(window)``: staggered multi-slot admissions, including a
+    37-token prompt that spans FIVE 8-token chunks admitted while other
+    streams decode; (engine, prompts, max_new, streams) after the drain,
+    cached per window."""
+    cache: dict[int, tuple] = {}
+
+    def run(window: int) -> tuple:
+        if window not in cache:
+            cache[window] = _staggered(quantized, window)
+        return cache[window]
+
+    return run
+
+
+def _staggered(quantized, window: int) -> tuple:
     from dora_tpu.models.hf import qwen2
 
     cfg, qparams = quantized
@@ -166,74 +177,61 @@ def test_paged_matches_dense_across_staggered_admissions(
     plens = (3, 7, 12, 37, 5)
     prompts = [rng.integers(0, cfg.vocab, size=n).tolist() for n in plens]
     max_new = 10
+    paged = qwen2.make_paged_engine(
+        qparams, cfg, max_slots=5, page_size=8, chunk=8, window=window
+    )
+    streams: dict[str, list[int]] = {f"r{i}": [] for i in range(len(plens))}
+    paged.submit("r0", prompts[0], max_new)
+    for _ in range(3):
+        _drain(streams, paged.step())
+    paged.submit("r1", prompts[1], max_new)
+    paged.submit("r2", prompts[2], max_new)
+    _drain(streams, paged.step())
+    paged.submit("r3", prompts[3], max_new)  # 5-chunk prompt mid-run
+    _drain(streams, paged.step())
+    paged.submit("r4", prompts[4], max_new)
+    for _ in range(300):
+        if not paged.active:
+            break
+        _drain(streams, paged.step())
+    assert paged.active == 0
+    return paged, prompts, max_new, streams
 
-    # Dense engine streams (the identity baseline).
-    dense = qwen2.make_batch_engine(qparams, cfg, max_slots=3)
-    dstreams: dict[str, list[int]] = {}
-    dstreams["r0"] = [dense.submit("r0", prompts[0], max_new)[0]]
-    _drain(dstreams, dense.step())
-    _drain(dstreams, dense.step())
-    dstreams["r1"] = [dense.submit("r1", prompts[1], max_new)[0]]
-    dstreams["r2"] = [dense.submit("r2", prompts[2], max_new)[0]]
-    while dense.free_slots == 0:
-        _drain(dstreams, dense.step())
-    dstreams["r3"] = [dense.submit("r3", prompts[3], max_new)[0]]
-    while dense.free_slots == 0:
-        _drain(dstreams, dense.step())
-    dstreams["r4"] = [dense.submit("r4", prompts[4], max_new)[0]]
-    while dense.active:
-        _drain(dstreams, dense.step())
 
-    # Paged engine, same prompts, admissions staggered mid-decode —
-    # once at per-token dispatch (K=1) and once with the fused 8-tick
-    # decode window: identical streams either way.
-    rt: dict[int, int] = {}
-    for window in (1, 8):
-        paged = qwen2.make_paged_engine(
-            qparams, cfg, max_slots=5, page_size=8, chunk=8, window=window
+@pytest.mark.parametrize("window", (1, 8))
+def test_paged_matches_serial_across_staggered_admissions(
+    staggered, serial_ref, window
+):
+    """Admissions staggered mid-decode, at per-token dispatch (K=1) and
+    with the fused 8-tick decode window: the serial streams either
+    way."""
+    paged, prompts, max_new, streams = staggered(window)
+    for i, prompt in enumerate(prompts):
+        assert streams[f"r{i}"] == serial_ref(prompt, max_new), (
+            f"K={window} stream r{i} diverged from the serial ref"
         )
-        pstreams: dict[str, list[int]] = {
-            f"r{i}": [] for i in range(len(plens))
-        }
-        paged.submit("r0", prompts[0], max_new)
-        for _ in range(3):
-            _drain(pstreams, paged.step())
-        paged.submit("r1", prompts[1], max_new)
-        paged.submit("r2", prompts[2], max_new)
-        _drain(pstreams, paged.step())
-        paged.submit("r3", prompts[3], max_new)  # 5-chunk prompt mid-run
-        _drain(pstreams, paged.step())
-        paged.submit("r4", prompts[4], max_new)
-        for _ in range(300):
-            if not paged.active:
-                break
-            _drain(pstreams, paged.step())
-        assert paged.active == 0
-        rt[window] = paged.dispatches + paged.fetches
+    # Every page returned to the allocator (no leaks across the run).
+    assert paged.free_pages == paged.allocator.num_pages - 1
 
-        for i in range(len(plens)):
-            rid = f"r{i}"
-            assert pstreams[rid] == dstreams[rid], (
-                f"paged K={window} stream {rid} diverged from dense"
-            )
-            assert pstreams[rid] == serial_ref(prompts[i], max_new), (
-                f"K={window} stream {rid} diverged from the serial ref"
-            )
 
-        # Every page returned to the allocator (no leaks across the run).
-        assert paged.free_pages == paged.allocator.num_pages - 1
-
-    # The window amortizes host round-trips even on this short workload.
+def test_window_amortizes_host_round_trips(staggered):
+    """The window amortizes host round-trips even on this short
+    workload."""
+    rt = {}
+    for k in (1, 8):
+        engine = staggered(k)[0]
+        rt[k] = engine.dispatches + engine.fetches
     assert rt[8] < rt[1], rt
 
 
-def test_window_freezes_streams_mid_window(quantized, serial_ref):
+@pytest.mark.parametrize("window", (1, 8))
+def test_window_freezes_streams_mid_window(quantized, serial_ref, window):
     """Device-side completion INSIDE a K=8 window: one stream hits EOS
     mid-window, another's max_new expires mid-window. The window must
     freeze each the very tick it finishes (KV writes rerouted to the
     null page), the host unpack must truncate at the done offset, and
-    the emitted streams must be identical to K=1 and the dense engine
-    with the same eos."""
+    the emitted streams must be the serial reference cut at the same
+    eos — at K=1 too."""
     from dora_tpu.models.hf import qwen2
 
     cfg, qparams = quantized
@@ -254,50 +252,29 @@ def test_window_freezes_streams_mid_window(quantized, serial_ref):
                 break
         return out
 
-    def run(make):
-        engine = make()
-        streams: dict[str, list[int]] = {"r0": [], "r1": []}
-        first = engine.submit("r0", prompts[0], max_new[0])
-        if first is not None:  # dense submit is synchronous
-            streams["r0"].append(first[0])
-        first = engine.submit("r1", prompts[1], max_new[1])
-        if first is not None:
-            streams["r1"].append(first[0])
-        for _ in range(100):
-            if not engine.active:
-                break
-            _drain(streams, engine.step())
-        assert engine.active == 0
-        return streams
-
-    dense = run(
-        lambda: qwen2.make_batch_engine(qparams, cfg, max_slots=2, eos=eos)
+    engine = qwen2.make_paged_engine(
+        qparams, cfg, max_slots=2, page_size=8, chunk=8, eos=eos,
+        window=window,
     )
-    k1 = run(
-        lambda: qwen2.make_paged_engine(
-            qparams, cfg, max_slots=2, page_size=8, chunk=8, eos=eos,
-            window=1,
-        )
-    )
-    k8 = run(
-        lambda: qwen2.make_paged_engine(
-            qparams, cfg, max_slots=2, page_size=8, chunk=8, eos=eos,
-            window=8,
-        )
-    )
+    streams: dict[str, list[int]] = {"r0": [], "r1": []}
+    engine.submit("r0", prompts[0], max_new[0])
+    engine.submit("r1", prompts[1], max_new[1])
+    for _ in range(100):
+        if not engine.active:
+            break
+        _drain(streams, engine.step())
+    assert engine.active == 0
     for rid, i in (("r0", 0), ("r1", 1)):
-        want = expect(i)
-        assert dense[rid] == want, f"dense {rid}"
-        assert k1[rid] == want, f"paged K=1 {rid}"
-        assert k8[rid] == want, f"paged K=8 {rid}"
+        assert streams[rid] == expect(i), f"paged K={window} {rid}"
     # EOS actually cut r0 short and the cap cut r1 short (mid-window).
-    assert len(k8["r0"]) == 6 and len(k8["r1"]) == 5
+    assert len(streams["r0"]) == 6 and len(streams["r1"]) == 5
 
 
-def test_16_slots_inside_the_dense_4_slot_footprint(quantized, serial_ref):
-    """4x the dense slot count in EXACTLY the dense engine's 4-slot KV
-    HBM: the default pool is 4 * max_seq rows per layer (null page
-    included), and 16 short streams decode concurrently inside it."""
+def test_16_slots_inside_a_4_plane_footprint(quantized, serial_ref):
+    """16 slots in EXACTLY the KV HBM of four contiguous [max_seq]
+    cache planes (qwen2.init_cache, the serial reference's): the
+    default pool is 4 * max_seq rows per layer (null page included),
+    and 16 short streams decode concurrently inside it."""
     import jax
 
     from dora_tpu.models.hf import qwen2
@@ -306,14 +283,14 @@ def test_16_slots_inside_the_dense_4_slot_footprint(quantized, serial_ref):
     paged = qwen2.make_paged_engine(
         qparams, cfg, max_slots=16, page_size=8, chunk=8, window=8
     )
-    dense_caches = qwen2.init_cache(cfg, 4)
+    plane_caches = qwen2.init_cache(cfg, 4)
     pool_bytes = sum(
         leaf.nbytes for leaf in jax.tree.leaves(paged.pools)
     )
-    dense_bytes = sum(
-        leaf.nbytes for leaf in jax.tree.leaves(dense_caches)
+    plane_bytes = sum(
+        leaf.nbytes for leaf in jax.tree.leaves(plane_caches)
     )
-    assert pool_bytes <= dense_bytes
+    assert pool_bytes <= plane_bytes
     assert paged.max_slots == 16
 
     rng = np.random.default_rng(11)
@@ -327,7 +304,7 @@ def test_16_slots_inside_the_dense_4_slot_footprint(quantized, serial_ref):
         streams[rid] = []
         assert paged.can_admit(len(base_prompts[i % 4]), max_new)
         paged.submit(rid, base_prompts[i % 4], max_new)
-    assert paged.active == 16  # all concurrent — dense caps at 4 here
+    assert paged.active == 16  # all concurrent
     for _ in range(200):
         if not paged.active:
             break
@@ -348,7 +325,7 @@ def test_16_slots_inside_the_dense_4_slot_footprint(quantized, serial_ref):
 @pytest.mark.parametrize("window", (1, 8))
 def test_spec_window_token_identity(quantized, serial_ref, spec_k, window):
     """Prompt-lookup speculation folded into the paged window emits
-    EXACTLY the spec-off / dense / serial greedy streams at every
+    EXACTLY the spec-off / serial greedy streams at every
     (K, k): drafts only ever propose, the batched verification pass
     decides — including multi-chunk prompts admitted mid-decode."""
     from dora_tpu.models.hf import qwen2
@@ -463,8 +440,7 @@ def test_steady_state_adds_zero_compiles_and_one_chunk_shape(quantized):
     (positions, block tables, chunk offsets, the active mask and the
     emitted/max_new vectors are all traced operands of fixed shape).
     The chunked-prefill jit and the K-window jit each hold exactly ONE
-    compiled shape — the dense engine's one-compile-per-bucket zoo is
-    structurally gone."""
+    compiled shape."""
     from dora_tpu.models.hf import qwen2
 
     cfg, qparams = quantized
@@ -548,20 +524,26 @@ def test_spec_steady_state_adds_zero_compiles(quantized):
     assert engine.window_step.func._cache_size() == 1
 
 
-def test_dense_engine_mask_cached_across_unchanged_passes(quantized):
-    """Satellite: the dense engine no longer rebuilds the active-slot
-    mask / re-dispatches the position pin when membership is unchanged."""
+def test_window_operands_cached_while_membership_is_unchanged(quantized):
+    """With membership unchanged between two ``dispatch()`` calls the
+    engine rebuilds no block-table / max_new operand (host work inside
+    the dispatch gap); freeing a slot invalidates both."""
     from dora_tpu.models.hf import qwen2
 
     cfg, qparams = quantized
-    engine = qwen2.make_batch_engine(qparams, cfg, max_slots=2)
+    engine = qwen2.make_paged_engine(
+        qparams, cfg, max_slots=2, page_size=8, chunk=8, window=1
+    )
     engine.submit("a", [1, 2, 3], 8)
-    engine.step()  # membership changed by submit: rebuilds + pins
-    assert not engine._members_dirty
-    mask_obj = engine._mask
-    engine.step()
-    engine.step()
-    assert engine._mask is mask_obj  # cached, not rebuilt per pass
+    assert engine._members_dirty
+    engine.step()  # prefill lands, the row joins the window: rebuilds
+    assert not engine._members_dirty and not engine._bt_dirty
+    bt, maxnew = engine._bt_dec, engine._maxnew_dev
+    for _ in range(2):
+        engine.dispatch()
+        assert engine._bt_dec is bt and engine._maxnew_dev is maxnew
+        engine.collect()
     while engine.active:
         engine.step()
-    assert engine._members_dirty  # freeing a slot invalidates the cache
+    # freeing a slot invalidates the cached operands
+    assert engine._members_dirty and engine._bt_dirty

@@ -40,10 +40,14 @@ class SlotEngine:
     def fits(self, plen: int, max_new: int) -> bool:
         return True
 
-    def can_admit(self, plen: int, max_new: int) -> bool:
+    def can_admit(self, plen: int, max_new: int, adapter=None) -> bool:
         return self.active < self.max_slots
 
-    def start(self, key: str, ids: list[int], max_new: int) -> None:
+    def admit_blocker(self, plen: int, max_new: int, adapter=None):
+        return "capacity"
+
+    def start(self, key: str, ids: list[int], max_new: int,
+              adapter=None) -> None:
         self.active += 1
         self.started.append(key)
 
