@@ -129,7 +129,6 @@ REGISTRY: dict[str, EnvVar] = dict((
     _e("DORA_FLASH_ATTENTION", "bool", "0", "flash-attention kernels"),
     _e("DORA_FUSED_DECODE", "bool", "0", "fused decode step"),
     _e("DORA_DECODE_UNROLL", "int", "1", "decode loop unroll factor"),
-    _e("DORA_HEAD_BV", "int", "0", "decode-block head block size"),
     _e("DORA_INT8_DECODE", "bool", "0", "int8 weight quantized decode", True),
     _e("DORA_INT8_PURE", "bool", "0", "pure-int8 matmul path"),
     _e("DORA_INT4_DECODE", "bool", "0", "int4 weight quantized decode", True),

@@ -2084,7 +2084,8 @@ def _head_kernel(
 
     h = _rms(x_ref, nw_ref, eps).astype(dtype)
     logits = _wdot(h, w_ref, s_ref[...], int4=w_ref.dtype == jnp.uint8)  # [M, BV]
-    # Padded vocab tail (if any) must never win.
+    # The ragged last tile's lanes past the vocab hold anything, NaN
+    # included: select, never multiply, so they cannot win.
     col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) + vi * bv
     logits = jnp.where(col < vocab, logits, -jnp.inf)
     blk_max = jnp.max(logits, axis=-1)  # [M]
@@ -2120,21 +2121,19 @@ def lm_head_argmax(x, norm_w, w, s, *, eps: float = 1e-6,
     [M] f32 (the tensor-parallel pass combines per-rank winners with a
     pmax/pmin pair — see parallel/fused_tp.py).
     """
-    import os
-
     m, d = x.shape
     int4 = w.dtype == jnp.uint8
     vocab = w.shape[1]
     # Tile sweep note (v5e, 152k vocab): 2048 keeps the int8 panel +
     # its in-register bf16 conversion inside the double-buffer budget;
     # 4096 measured ~2x slower end-to-end (VMEM pressure serializes the
-    # stream). Override for experiments via DORA_HEAD_BV.
-    bv = int(os.environ.get("DORA_HEAD_BV", "2048"))
-    if vocab % bv:
-        pad = bv - vocab % bv
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-        s = jnp.pad(s, ((0, 0), (0, pad)))
-    nv = w.shape[1] // bv
+    # stream). The head and its scales go in as stored: a vocab that is
+    # no multiple of the tile ends in a ragged block whose lanes past
+    # ``vocab`` hold whatever the buffer held, and the kernel's
+    # ``col < vocab`` select drops them (a column of the product reads
+    # only its own column of the tile and of the scales).
+    bv = min(2048, pl.cdiv(vocab, 128) * 128)
+    nv = pl.cdiv(vocab, bv)
     kernel = functools.partial(
         _head_kernel, nv=nv, bv=bv, vocab=vocab, eps=eps
     )

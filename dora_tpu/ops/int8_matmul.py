@@ -41,9 +41,10 @@ _LANE = 128
 
 # Block sizing is the whole game: each grid step carries fixed overhead
 # (measured ~0.5 us on v5e), so 64 KB blocks cap the sweep at ~130 GB/s
-# while ~2-4 MB blocks reach HBM speed. But padding K/N costs real reads
-# in a bandwidth-bound kernel, so the N block is chosen per shape: the
-# lane multiple nearest ``_TARGET_BYTES / K`` that minimizes padding.
+# while ~2-4 MB blocks reach HBM speed. A block that would need the
+# weight padded costs a copy of the whole weight in every call, so the
+# blocks are fitted to the stored array: the K block divides K, and an
+# N block that does not divide N ends in a ragged last block.
 _TARGET_BYTES = 4 << 20
 #: Above this K the weight panel would not fit VMEM at a useful BN and
 #: the kernel falls back to a sequential K sweep with an accumulator.
@@ -100,20 +101,31 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _best_bn(n: int, bk: int, bn_cap: int) -> int:
-    """Lane-multiple N block <= bn_cap minimizing padded reads, with a
-    mild preference for fewer grid steps."""
-    n128 = _round_up(n, _LANE)
-    if n128 <= bn_cap:
-        return n128
-    best, best_cost = _LANE, None
-    for mult in range(1, bn_cap // _LANE + 1):
-        bn = mult * _LANE
-        waste = _round_up(n128, bn) - n128
-        cost = waste * bk + (n128 // bn + 1) * 4096
-        if best_cost is None or cost < best_cost:
-            best, best_cost = bn, cost
-    return best
+def _best_bk(k: int, whole: int) -> int:
+    """K whole when it is at most ``whole`` (a block that spans the
+    array's dimension needs no lane alignment), else the largest lane
+    multiple <= 2048 that divides ``round_up(k, 128)``: 7168 -> 1792,
+    18432 -> 2048."""
+    if k <= whole:
+        return k
+    lanes = pl.cdiv(k, _LANE)
+    return _LANE * max(
+        d for d in range(1, 2048 // _LANE + 1) if lanes % d == 0
+    )
+
+
+def _best_bn(n: int, bn_cap: int) -> int:
+    """Lane-multiple N block <= bn_cap over the stored N columns: the
+    largest that divides ``round_up(n, 128)`` if that fills half the
+    cap, else the fewest blocks of even width, the last one ragged."""
+    lanes = pl.cdiv(n, _LANE)
+    cap = bn_cap // _LANE
+    if lanes <= cap:
+        return lanes * _LANE
+    div = max(d for d in range(1, cap + 1) if lanes % d == 0)
+    if 2 * div >= cap:
+        return div * _LANE
+    return pl.cdiv(lanes, pl.cdiv(lanes, cap)) * _LANE
 
 
 def _pick_blocks(m_pad: int, k: int, n: int) -> tuple[int, int, int]:
@@ -122,26 +134,28 @@ def _pick_blocks(m_pad: int, k: int, n: int) -> tuple[int, int, int]:
     Matvec regime (decode, M <= 32): the kernel is HBM-bound on the
     weight sweep — K kept whole when it fits (no accumulator sweep),
     BN targets ~_TARGET_BYTES of int8 per block to amortize the
-    per-grid-step overhead, and padding is minimized because padded
-    columns are real extra reads.
+    per-grid-step overhead.
 
     Compute-bound regime (prefill/training, larger M): weight traffic
     amortizes over M rows, so fixed MXU-friendly blocks are used and
     sized to the scoped-VMEM budget (~16 MB with double buffering)
     instead of chasing bandwidth.
+
+    In both, block_k is K itself or divides ``round_up(k, 128)``, so
+    only a K that is no lane multiple and too long for one block is
+    ever padded.
     """
-    k_pad = _round_up(k, _LANE)
     if m_pad <= 32:
-        bk = k_pad if k_pad <= _MAX_BLOCK_K else 2048
-        return m_pad, bk, _best_bn(n, bk, max(_TARGET_BYTES // bk, _LANE))
+        bk = _best_bk(k, _MAX_BLOCK_K)
+        return m_pad, bk, _best_bn(n, max(_TARGET_BYTES // bk, _LANE))
     bm = min(m_pad, 256)
-    bk = min(k_pad, 2048)
+    bk = _best_bk(k, 2048)
     # double-buffered VMEM: 2*(x + w + out) + scratch, bytes
     budget = 10 << 20
     fixed = 2 * (bm * bk * 2)
     per_bn = 2 * (bk * 1 + bm * 2) + bm * 4
     bn_cap = max((budget - fixed) // per_bn // _LANE * _LANE, _LANE)
-    return bm, bk, _best_bn(n, bk, bn_cap)
+    return bm, bk, _best_bn(n, bn_cap)
 
 
 @jax.jit
@@ -150,6 +164,13 @@ def int8_matmul(x, q, scale):
 
     x: [..., K] float; q: [K, N] int8; scale: [1, N] f32.
     Returns [..., N] in x.dtype (accumulation in f32).
+
+    ``q`` and ``scale`` go to the kernel as stored whenever K is a lane
+    multiple (every model width) or fits one block: columns past N in a
+    ragged last block hold anything and only reach output columns that
+    are cut. Only a K that is neither keeps a zero pad of ``x`` and
+    ``q`` up to the next lane multiple, since rows past K would enter
+    every sum.
     """
     *lead, k = x.shape
     kq, n = q.shape
@@ -166,10 +187,8 @@ def int8_matmul(x, q, scale):
     n_pad = _round_up(n, block_n)
     if m_pad != m or k_pad != k:
         x2 = jnp.pad(x2, ((0, m_pad - m), (0, k_pad - k)))
-    if k_pad != k or n_pad != n:
-        q = jnp.pad(q, ((0, k_pad - k), (0, n_pad - n)))
-    if n_pad != n:
-        scale = jnp.pad(scale, ((0, 0), (0, n_pad - n)))
+    if k_pad != k:
+        q = jnp.pad(q, ((0, k_pad - k), (0, 0)))
 
     nm = m_pad // block_m
     nn = n_pad // block_n

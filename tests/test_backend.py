@@ -212,24 +212,16 @@ def _tiny_kimi(tmp_path):
     return kimi_k2.load(tmp_path / "ckpt", max_seq=64)
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2"])
-def test_engine_programs_take_the_weights_as_arguments(
-    module_name, monkeypatch, tmp_path
-):
-    """A closed-over array lowers to a stablehlo.constant — gigabytes of
-    weights inside every executable and cache entry at full width. No
-    program the engine jits (window, chunk, slot insert) may hold a
-    weight-sized constant, and the window and chunk programs must be
-    handed the parameter tree itself: the smallest weight matrix of
-    either tiny model has 2048 elements, the largest legitimate
-    constant (a rope table) 512."""
+def _engine_programs(module_name, monkeypatch, tmp_path):
+    """Run a request through the tiny engine of ``module_name`` and
+    return what it jitted: program -> (abstract arguments of its first
+    call, whether it was handed the parameter tree)."""
     import importlib
 
     module = importlib.import_module(f"dora_tpu.models.hf.{module_name}")
     cfg, params = (
         _tiny_model() if module_name == "qwen2" else _tiny_kimi(tmp_path)
     )
-    #: program -> (abstract arguments of its first call, got the params)
     seen: dict = {}
     real_jit = jax.jit
 
@@ -261,10 +253,89 @@ def test_engine_programs_take_the_weights_as_arguments(
             break
     assert not engine.active
     assert sum(took for _shapes, took in seen.values()) == 2  # window, chunk
+    return seen
+
+
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2"])
+def test_engine_programs_take_the_weights_as_arguments(
+    module_name, monkeypatch, tmp_path
+):
+    """A closed-over array lowers to a stablehlo.constant — gigabytes of
+    weights inside every executable and cache entry at full width. No
+    program the engine jits (window, chunk, slot insert) may hold a
+    weight-sized constant, and the window and chunk programs must be
+    handed the parameter tree itself: the smallest weight matrix of
+    either tiny model has 2048 elements, the largest legitimate
+    constant (a rope table) 512."""
+    seen = _engine_programs(module_name, monkeypatch, tmp_path)
     for jitted, (shapes, _took) in seen.items():
         text = jitted.lower(*shapes).as_text()
         assert "stablehlo" in text
         assert _largest_constant(text) < 1024, jitted
+
+
+# -- weights reach the kernels as stored --------------------------------------
+
+
+def _weight_copies(jaxpr) -> list[str]:
+    """Equations of ``jaxpr`` (sub-programs included; a ``pallas_call``
+    is one equation here, its body is not walked) that copy a quantized
+    weight on its way to a kernel: a ``pad`` or ``concatenate`` with an
+    int8 / packed-int4 operand."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("pad", "concatenate") and any(
+            getattr(v.aval, "dtype", None) in (jnp.int8, jnp.uint8)
+            for v in eqn.invars
+        ):
+            found.append(f"{eqn.primitive.name} {[v.aval for v in eqn.invars]}")
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _weight_copies(sub)
+    return found
+
+
+@pytest.mark.parametrize(
+    "kernel,m,k,n",
+    [
+        # Qwen2.5-1.5B's head: 151,936 = 128 x 1187 columns, a decode tick
+        ("lm_head_argmax", 16, 1536, 151936),
+        # Kimi-K2's K = 7168 = 3.5 x 2048, a prefill chunk
+        ("int8_matmul", 256, 7168, 4096),
+        # its w_qkv_a: N = 2112 is 16.5 lanes, chunk and tick
+        ("int8_matmul", 256, 7168, 2112),
+        ("int8_matmul", 16, 7168, 2112),
+    ],
+)
+def test_kernels_take_the_stored_weight(kernel, m, k, n):
+    """No weight is copied inside a serving program: at the published
+    widths that used to be padded in every tick or chunk, nothing of
+    int8 is padded between the arguments and the ``pallas_call``."""
+    from dora_tpu.ops.decode_block import lm_head_argmax
+    from dora_tpu.ops.int8_matmul import int8_matmul
+
+    s = jax.ShapeDtypeStruct
+    x, w, scale = s((m, k), jnp.float32), s((k, n), jnp.int8), s((1, n), jnp.float32)
+    if kernel == "lm_head_argmax":
+        traced = jax.make_jaxpr(lm_head_argmax)(x, s((k,), jnp.float32), w, scale)
+    else:
+        traced = jax.make_jaxpr(int8_matmul)(x, w, scale)
+    assert "pallas_call" in str(traced)
+    assert _weight_copies(traced.jaxpr) == []
+
+
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2"])
+def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
+    """The same walk over the window and chunk programs as the engine
+    jits them (tiny models: vocab 256 and 128 are no multiple of the
+    head's 2048-column tile, hidden 64 is no lane multiple)."""
+    seen = _engine_programs(module_name, monkeypatch, tmp_path)
+    for jitted, (shapes, took) in seen.items():
+        if took:  # the window and the chunk program, the helper saw both
+            traced = jax.make_jaxpr(jitted)(*shapes)
+            assert "pallas_call" in str(traced)
+            assert _weight_copies(traced.jaxpr) == [], jitted
 
 
 # -- the smoke test's parent stays off JAX ----------------------------------

@@ -158,7 +158,11 @@ def test_quantize_roundtrip_error_bound():
         (1, 256, 256),    # decode matvec, aligned
         (1, 1536, 512),   # bench LM width
         (4, 300, 100),    # both axes unaligned (padding path)
-        (16, 256, 260),   # N pads by 4
+        (16, 256, 260),   # N ends in a ragged block
+        (256, 7168, 256),  # prefill chunk, K = 4 x 1792 unpadded
+        (64, 1792, 260),   # larger M, ragged N
+        (64, 1000, 260),   # K no lane multiple, whole in one block
+        (40, 2100, 130),   # K no lane multiple, too long: zero pad
     ],
 )
 def test_int8_matmul_matches_dequantized(m, k, n):
@@ -379,22 +383,50 @@ def test_decode_mlp_step_matches_dense():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3)
 
 
-@pytest.mark.parametrize("m,vocab", [(1, 256), (5, 300)])
-def test_decode_lm_head_argmax(m, vocab):
-    """Streamed argmax (incl. non-multiple vocab padding and M>1 rows for
-    speculative verify) matches argmax over the dense logits."""
+@pytest.mark.parametrize(
+    "m,vocab,winner",
+    [
+        (1, 256, None),
+        (5, 300, None),
+        (5, 300, 299),            # one tile, wider than the vocab
+        (1, 2048 + 384, 2431),    # winner in the ragged last tile
+        (16, 4096 + 128, 4100),
+        (16, 4096 + 128, 17),     # last tile loses to an earlier one
+    ],
+)
+def test_decode_lm_head_argmax(m, vocab, winner):
+    """Streamed argmax (incl. a vocab that ends in a ragged tile and M>1
+    rows for speculative verify) matches argmax over the dense logits,
+    index and value. The head goes in as stored: the interpreter fills
+    the ragged tile's lanes past the vocab with NaN scales and -128
+    weights, and a NaN logit wins any max that does not mask it."""
     from dora_tpu.ops.decode_block import lm_head_argmax
     from dora_tpu.ops.int8_matmul import dequantize, quantize_int8
 
     rng = np.random.default_rng(m * 1000 + vocab)
     D = 64
-    x = jnp.asarray(rng.standard_normal((m, D)), jnp.float32)
+    # rows that lean one way, so one column can win every row
+    x = jnp.asarray(
+        rng.standard_normal(D) + 0.3 * rng.standard_normal((m, D)),
+        jnp.float32,
+    )
     nw = jnp.asarray(rng.standard_normal(D), jnp.float32)
-    wh = quantize_int8(jnp.asarray(rng.standard_normal((D, vocab)), jnp.float32))
+    w = rng.standard_normal((D, vocab)).astype(np.float32)
+    if winner is not None:
+        lean = np.asarray(L.rms_norm(x, nw)).mean(0)
+        w[:, winner] = 4.0 * lean / np.abs(lean).max()
+    wh = quantize_int8(jnp.asarray(w))
+    logits = L.rms_norm(x, nw) @ dequantize(wh)
 
-    tok = lm_head_argmax(x, nw, wh["int8"], wh["scale"])
-    ref = jnp.argmax(L.rms_norm(x, nw) @ dequantize(wh), axis=-1)
-    np.testing.assert_array_equal(np.asarray(tok), np.asarray(ref))
+    tok, val = lm_head_argmax(x, nw, wh["int8"], wh["scale"], return_val=True)
+    np.testing.assert_array_equal(np.asarray(tok), np.asarray(logits.argmax(-1)))
+    np.testing.assert_allclose(
+        np.asarray(val), np.asarray(logits.max(-1)), atol=1e-3, rtol=1e-4
+    )
+    if winner is not None:
+        assert (np.asarray(tok) == winner).all()
+    only = lm_head_argmax(x, nw, wh["int8"], wh["scale"])
+    np.testing.assert_array_equal(np.asarray(only), np.asarray(tok))
 
 
 def test_fused_decode_generate_matches_vanilla(monkeypatch):
