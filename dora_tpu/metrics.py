@@ -195,7 +195,7 @@ class ServingMetrics:
         "kv_dtype", "kv_pool_bytes", "kv_quant_err",
         "lora_resident", "lora_max_resident", "lora_resident_bytes",
         "lora_loads", "lora_evictions", "adapter_streams",
-        "adapter_stalls", "model",
+        "adapter_stalls", "model", "capture_counters",
     )
 
     def __init__(self, engine: str = "paged"):
@@ -367,6 +367,11 @@ class ServingMetrics:
         #: and give back beside the pools, read after ``collect()``: no
         #: extra device->host fetch per window.
         self.model: dict = {}
+        #: the same counters as they stood when a profiler capture
+        #: started and stopped (``{"start": {...}, "stop": {...}}``), so
+        #: that a reader of the capture counts what the captured ticks
+        #: did and not what a longer stretch of serving did.
+        self.capture_counters: dict = {}
 
     def snapshot(self) -> dict:
         import time
@@ -460,6 +465,7 @@ class ServingMetrics:
             "lora_evictions": self.lora_evictions,
             "adapter_streams": dict(self.adapter_streams),
             "adapter_stalls": self.adapter_stalls,
+            "capture_counters": dict(self.capture_counters),
             **self.model,
         }
 

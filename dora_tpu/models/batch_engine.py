@@ -259,6 +259,24 @@ class PagedBatchEngine:
         active [B], emitted [B], max_new [B])`` ->
         (mat [B, K+1], tokens, positions, active, emitted, pools)
 
+    **Slot state, the second cache kind.** A model whose streams own a
+    fixed recurrent state beside their pages (a state-space mixer) passes
+    ``init_slot_state(max_slots)`` -> a pytree of ``[max_slots, ...]``
+    arrays, row ``b`` being slot ``b``'s. It is never a leaf of
+    ``pools`` (every leaf of those is indexed by page). With it,
+    ``chunk_prefill`` takes two more trailing operands, the slot index
+    and the state, and ``window_step`` one, the state
+    (models/vlm.make_paged_window, ``slot_state=True``); both return the
+    state last, updated in place. The programs keep it right: a chunk at
+    position 0 starts from zeros (the host makes no reset call), a
+    chunk's padding rows and a frozen or mid-prefill row's decode ticks
+    leave it as it was. So :meth:`preempt` just drops the slot,
+    :meth:`save_pools` / :meth:`restore_pools` carry the state beside
+    the pages for :meth:`restore_state` with pinned slots, and what
+    cannot take the state along is refused by name: the prefix cache (a
+    granted prefix would need the state at its end), speculation, LoRA,
+    and :meth:`admit_streams` of a stream in mid-decode.
+
     With ``spec_k > 0`` (prompt-lookup speculation,
     models/vlm.make_paged_spec_window) the window signature instead
     takes and returns two extra per-stream device buffers —
@@ -276,7 +294,7 @@ class PagedBatchEngine:
                  window: int = 8, spec_k: int = 0, spec_ngram: int = 2,
                  window_factory=None, prefix_cache: bool = False,
                  prefix_cache_pages: int = 0, lora_pool=None,
-                 chunk_valid_rows: bool = False):
+                 chunk_valid_rows: bool = False, init_slot_state=None):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -297,11 +315,29 @@ class PagedBatchEngine:
         #: ``chunk_prefill`` takes a trailing traced operand: how many of
         #: the chunk's rows are the prompt's (the rest is the tail
         #: chunk's right padding), for closures that count rows.
+        if chunk_valid_rows and lora_pool is not None:
+            # No model's chunk program takes both; the chunk's operand
+            # list would be a signature that nothing was written for.
+            raise NotImplementedError(
+                "chunk_valid_rows and a LoRA pool: no chunk program takes "
+                "the valid-row count beside the adapter operands")
         self.chunk_valid_rows = chunk_valid_rows
         self.window_step = window_step
         self.window = window
         self.max_pages = max_seq // page_size
         self.pools = init_pool(num_pages)
+        #: per-slot recurrent state (None = the model has none): see the
+        #: class docstring. Donated to and replaced by both programs.
+        self.slot_state = None
+        if init_slot_state is not None:
+            for knob, on in (("a prefix cache", prefix_cache),
+                             ("speculation", spec_k),
+                             ("a LoRA pool", lora_pool is not None)):
+                if on:
+                    raise NotImplementedError(
+                        f"a slot-state engine cannot run with {knob}: the "
+                        f"recurrent state would not follow")
+            self.slot_state = init_slot_state(max_slots)
         self.allocator = PageAllocator(num_pages)
         #: shared-prefix subsystem (models/prefix_cache.py): radix
         #: lookup at admission maps cached prefix pages straight into
@@ -860,23 +896,26 @@ class PagedBatchEngine:
                 if self.chunk_valid_rows else ()
             )
             piece = piece + [0] * (self.chunk - len(piece))
+            operands = list(valid)
             if self.lora is not None:
                 # Adapter id rides as a traced operand (an int32 device
                 # scalar, never a python constant) so chunk prefill
                 # keeps its one-compiled-shape discipline across
                 # tenants.
-                greedy, self.pools = self.chunk_prefill(
-                    jnp.asarray(piece, jnp.int32), self.pools,
-                    jnp.asarray(base, jnp.int32), jnp.asarray(self._bt[b]),
-                    jnp.asarray(s.adapter_idx, jnp.int32),
-                    self.lora.state(),
-                )
-            else:
-                greedy, self.pools = self.chunk_prefill(
-                    jnp.asarray(piece, jnp.int32), self.pools,
-                    jnp.asarray(base, jnp.int32), jnp.asarray(self._bt[b]),
-                    *valid,
-                )
+                operands += [jnp.asarray(s.adapter_idx, jnp.int32),
+                             self.lora.state()]
+            elif self.slot_state is not None:
+                # Which slot the chunk fills, and the slots' state: the
+                # program reads row ``b`` (zeros at position 0) and
+                # writes it back as it stands after the prompt's rows.
+                operands += [jnp.asarray(b, jnp.int32), self.slot_state]
+            greedy, self.pools, *state = self.chunk_prefill(
+                jnp.asarray(piece, jnp.int32), self.pools,
+                jnp.asarray(base, jnp.int32), jnp.asarray(self._bt[b]),
+                *operands,
+            )
+            if state:
+                (self.slot_state,) = state
             t_disp = time.perf_counter()
             s.chunk_base = base + self.chunk
             self.chunks_run += 1
@@ -1032,11 +1071,12 @@ class PagedBatchEngine:
             #: ride every dispatch as trailing traced operands (fixed
             #: shapes — churn rewrites stack contents, never the
             #: program).
-            extra = (
-                (self._adapter_dev, self.lora.state())
-                if self.lora is not None
-                else ()
-            )
+            if self.lora is not None:
+                extra = (self._adapter_dev, self.lora.state())
+            elif self.slot_state is not None:
+                extra = (self.slot_state,)  # carried, and returned last
+            else:
+                extra = ()
             if self.spec_k:
                 (
                     mat,
@@ -1060,11 +1100,14 @@ class PagedBatchEngine:
                     self._mask,
                     self._emitted_dev,
                     self.pools,
+                    *state,
                 ) = self.window_step(
                     self.tokens, self.pools, self.positions, self._bt_dec,
                     self._mask, self._emitted_dev, self._maxnew_dev,
                     *extra,
                 )
+                if state:
+                    (self.slot_state,) = state
             self.dispatches += 1
             t_launched = time.perf_counter()
             if self.device_monitor:
@@ -1271,7 +1314,12 @@ class PagedBatchEngine:
                 # so dispatch counts) identical too.
                 meta["history"] = [int(t) for t in self._hist[b]]
             slots.append(meta)
-        return {"slots": slots, "kv_dtype": self.kv_dtype}
+        state = {"slots": slots, "kv_dtype": self.kv_dtype}
+        if self.slot_state is not None:
+            # The rows themselves travel with the pages (save_pools);
+            # the flag says that a decoding stream here has some.
+            state["slot_state"] = True
+        return state
 
     def restore_state(self, state: dict, *, pin_slots: bool = True) -> list[str]:
         """Rebuild live streams from :meth:`checkpoint_state`; returns
@@ -1298,6 +1346,19 @@ class PagedBatchEngine:
                 f"checkpoint kv_dtype {snap_dtype!r} does not match engine "
                 f"kv_dtype {self.kv_dtype!r}: re-serve the snapshot on an "
                 f"engine built with the same DORA_KV_INT8 setting"
+            )
+        if bool(state.get("slot_state")) != (self.slot_state is not None):
+            raise ValueError(
+                "checkpoint and engine disagree on a per-slot recurrent "
+                "state: restore it on an engine of the same model"
+            )
+        if self.slot_state is not None and not pin_slots and any(
+            m.get("decode") for m in state.get("slots", [])
+        ):
+            raise RuntimeError(
+                "cannot admit a stream in mid-decode into another slot: "
+                "its recurrent state does not travel with the handoff "
+                "(no state transfer yet); re-submit it from its prompt"
             )
         restored: list[str] = []
         metas = state.get("slots", [])
@@ -1421,15 +1482,27 @@ class PagedBatchEngine:
 
     def save_pools(self, path) -> None:
         """Persist the KV pool pytree (orbax, models/checkpoint.py) —
-        needed only for engines whose decode reads the pool."""
+        needed only for engines whose decode reads the pool. A slot
+        state is saved beside it, under its own key."""
         from dora_tpu.models import checkpoint
 
-        checkpoint.save(path, self.pools)
+        checkpoint.save(path, self._cache_tree())
 
     def restore_pools(self, path) -> None:
         from dora_tpu.models import checkpoint
 
-        self.pools = checkpoint.restore(path, self.pools)
+        tree = checkpoint.restore(path, self._cache_tree())
+        if self.slot_state is None:
+            self.pools = tree
+        else:
+            self.pools, self.slot_state = tree["pools"], tree["slot_state"]
+
+    def _cache_tree(self):
+        """What a checkpoint of the cache holds: the pools as they
+        always were, or both cache kinds where there are two."""
+        if self.slot_state is None:
+            return self.pools
+        return {"pools": self.pools, "slot_state": self.slot_state}
 
     def kv_pool_bytes(self) -> int:
         """Total device bytes of the KV pool pytree — int8 pools count

@@ -45,7 +45,6 @@ Text path only: K2.5's vision tower is not part of this module.
 
 from __future__ import annotations
 
-import json
 import os
 from dataclasses import dataclass
 from pathlib import Path
@@ -55,8 +54,8 @@ import jax.numpy as jnp
 
 from dora_tpu import profiling
 from dora_tpu.models import layers as L
-from dora_tpu.models.hf.loader import read_config
-from dora_tpu.ops.int8_matmul import quantize_int8
+from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
 
 MODEL_TYPES = ("kimi_k2", "deepseek_v3")
 
@@ -219,44 +218,6 @@ def expert_share(config: dict, ep_rank: int | None = None) -> tuple[int, int]:
 # ---------------------------------------------------------------------------
 # loading: one layer at a time, only the held experts, int8 on the device
 # ---------------------------------------------------------------------------
-
-
-class TensorFiles:
-    """The safetensors files of a checkpoint directory, read one tensor
-    at a time by name (``model.safetensors``, a sharded index, or any
-    ``*.safetensors``): nothing is read that is not asked for."""
-
-    def __init__(self, model_dir: str | Path):
-        from safetensors import safe_open
-
-        model_dir = Path(model_dir)
-        index = model_dir / "model.safetensors.index.json"
-        if index.exists():
-            weight_map = json.loads(index.read_text())["weight_map"]
-            files = sorted(set(weight_map.values()))
-        else:
-            files = sorted(p.name for p in model_dir.glob("*.safetensors"))
-        if not files:
-            raise FileNotFoundError(f"no safetensors files under {model_dir}")
-        self._open = {
-            f: safe_open(str(model_dir / f), framework="np") for f in files
-        }
-        self.where = {
-            name: f for f, h in self._open.items() for name in h.keys()
-        }
-
-    def __contains__(self, name: str) -> bool:
-        return name in self.where
-
-    def get(self, name: str):
-        return self._open[self.where[name]].get_tensor(name)
-
-
-@jax.jit
-def _quantize_t(*weights):
-    """HF ``[out, in]`` weights -> one int8 ``[in, sum(out)]`` matrix
-    with per-output-channel scales (transposed and joined on the device)."""
-    return quantize_int8(jnp.concatenate([w.T for w in weights], axis=1))
 
 
 def _pad_outputs(w, to: int):
@@ -427,37 +388,19 @@ def mla_output(blk, cfg: KimiK2Config, ctx):
 
 def _attend_blocks(cfg: KimiK2Config, q, rows_of, visible, n_blocks,
                    score: str, mix: str):
-    """Running-softmax attention of absorbed queries ``q [..., row]``
-    over latent rows fetched a block at a time: ``rows_of(j)`` gives
-    block ``j``'s rows (``[..., block, row]``), ``visible(j)`` the
-    mask of which of them each query may see (broadcastable to the
-    scores); ``score`` and ``mix`` are the einsums of queries with rows
-    and of probabilities with rows. Returns the softmax-weighted
-    ``c_kv`` ``[..., kv_rank]`` in float32. ``n_blocks`` is traced:
-    work follows the longest live context."""
-    scale = cfg.softmax_scale
-    lead = q.shape[:-1]
-    neg = jnp.float32(-1e30)
-
-    def body(j, carry):
-        m, l, acc = carry
-        kv, seen = rows_of(j), visible(j)
-        s = jnp.einsum(score, q, kv, preferred_element_type=jnp.float32)
-        s = jnp.where(seen, s * scale, neg)
-        m_new = jnp.maximum(m, s.max(-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            mix, p.astype(kv.dtype), kv[..., : cfg.kv_rank],
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l * alpha + p.sum(-1), acc
-
-    m0 = jnp.full(lead, neg, jnp.float32)
-    l0 = jnp.zeros(lead, jnp.float32)
-    a0 = jnp.zeros((*lead, cfg.kv_rank), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
-    return acc / l[..., None]
+    """:func:`layers.attend_blocks` of absorbed queries ``q [..., row]``
+    over latent rows: ``rows_of(j)`` gives block ``j``'s rows
+    (``[..., block, row]``); ``score`` and ``mix`` are the einsums of
+    queries with rows and of probabilities with rows. Returns the
+    softmax-weighted ``c_kv`` ``[..., kv_rank]`` in float32."""
+    f32 = {"preferred_element_type": jnp.float32}
+    return L.attend_blocks(
+        q, rows_of, visible, n_blocks,
+        lambda q, kv: jnp.einsum(score, q, kv, **f32),
+        lambda p, kv: jnp.einsum(
+            mix, p.astype(kv.dtype), kv[..., : cfg.kv_rank], **f32),
+        scale=cfg.softmax_scale, width=cfg.kv_rank,
+    )
 
 
 def mla_absorbed(blk, cfg: KimiK2Config, x, pool, positions, block_tables,

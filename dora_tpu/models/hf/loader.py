@@ -19,6 +19,37 @@ def read_config(model_dir: str | Path) -> dict:
     return json.loads((Path(model_dir) / "config.json").read_text())
 
 
+class TensorFiles:
+    """The safetensors files of a checkpoint directory, read one tensor
+    at a time by name (``model.safetensors``, a sharded index, or any
+    ``*.safetensors``): nothing is read that is not asked for."""
+
+    def __init__(self, model_dir: str | Path):
+        from safetensors import safe_open
+
+        model_dir = Path(model_dir)
+        index = model_dir / "model.safetensors.index.json"
+        if index.exists():
+            weight_map = json.loads(index.read_text())["weight_map"]
+            files = sorted(set(weight_map.values()))
+        else:
+            files = sorted(p.name for p in model_dir.glob("*.safetensors"))
+        if not files:
+            raise FileNotFoundError(f"no safetensors files under {model_dir}")
+        self._open = {
+            f: safe_open(str(model_dir / f), framework="np") for f in files
+        }
+        self.where = {
+            name: f for f, h in self._open.items() for name in h.keys()
+        }
+
+    def __contains__(self, name: str) -> bool:
+        return name in self.where
+
+    def get(self, name: str):
+        return self._open[self.where[name]].get_tensor(name)
+
+
 def read_safetensors(model_dir: str | Path) -> dict[str, np.ndarray]:
     """All tensors of a checkpoint dir keyed by their checkpoint names.
 

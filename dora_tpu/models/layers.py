@@ -195,6 +195,38 @@ def attention(q, k, v, mask=None):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def attend_blocks(q, fetch, visible, n_blocks, score, mix, *, scale: float,
+                  width: int):
+    """Running-softmax attention of queries ``q [..., dims]`` over cached
+    rows fetched a block at a time, in plain XLA (the paged attention of
+    the models whose cache the fused kernels do not read): ``fetch(j)``
+    gives block ``j`` of the cache (whatever the model keeps there),
+    ``visible(j)`` which of its rows each query may see (broadcastable
+    to the scores), ``score(q, block)`` the float32 scores ``[..., T]``
+    and ``mix(p, block)`` the float32 sum of the block's values under
+    probabilities ``p``, ``[..., width]``. Returns ``[..., width]``
+    float32. ``n_blocks`` is traced: work follows the longest live
+    context."""
+    lead = q.shape[:-1]
+    neg = jnp.float32(-1e30)
+
+    def body(j, carry):
+        m, l, acc = carry
+        kv, seen = fetch(j), visible(j)
+        s = jnp.where(seen, score(q, kv) * scale, neg)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        acc = acc * alpha[..., None] + mix(p, kv)
+        return m_new, l * alpha + p.sum(-1), acc
+
+    m0 = jnp.full(lead, neg, jnp.float32)
+    l0 = jnp.zeros(lead, jnp.float32)
+    a0 = jnp.zeros((*lead, width), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    return acc / l[..., None]
+
+
 def use_flash() -> bool:
     """Flash attention for the no-cache self-attention paths (see
     dora_tpu.ops.flash_attention). Default ON on TPU (the kernel's VMEM

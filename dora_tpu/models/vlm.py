@@ -570,7 +570,7 @@ def fused_paged_pass_spec(params, x, pools, positions, block_tables,
 
 
 def make_paged_window(step_fn, *, k: int, eos: int | None = None,
-                      lora: bool = False):
+                      lora: bool = False, slot_state: bool = False):
     """Fused K-step decode window over a paged batch step.
 
     ONE jitted program runs ``k`` batched decode ticks on device,
@@ -606,18 +606,35 @@ def make_paged_window(step_fn, *, k: int, eos: int | None = None,
     adapters, lora_state)``. Both are fixed-shape (the stack's slot
     count never changes; admission/eviction rewrite contents), so the
     single-program discipline extends to adapter churn.
+
+    With ``slot_state=True`` (a model that keeps a recurrent state per
+    slot beside its pages; not together with ``lora``) the window takes
+    ONE extra trailing operand, the slots' state pytree, carries it
+    through the scan beside the pools and returns it last; ``step_fn``
+    is called as ``step_fn(tokens, pools, positions, bts, active,
+    state) -> (greedy, pools, state)``. It is told the rows' ``active``
+    bits because a frozen row's K/V write can go to the null page but
+    its state has none: the step must leave it as it was.
     """
     from dora_tpu.ops import decode_block as DB
 
+    assert not (lora and slot_state), "no adapters over a slot state"
+
     def window(tokens, pools, positions, bts, active, emitted, max_new,
-               adapters=None, lora_state=None):
+               *operands):
+        # operands: (adapters, lora_state) with ``lora``, (state,) with
+        # ``slot_state``; only the state is carried.
         def tick(carry, _):
-            tokens, pools, positions, active, emitted = carry
+            tokens, pools, positions, active, emitted, *state = carry
             alive = active.astype(jnp.int32)
             pos_in, bts_in = DB.freeze_inactive(positions, bts, active)
-            if lora:
+            if slot_state:
+                nxt, pools, state[0] = step_fn(
+                    tokens, pools, pos_in, bts_in, active, state[0]
+                )
+            elif lora:
                 nxt, pools = step_fn(
-                    tokens, pools, pos_in, bts_in, adapters, lora_state
+                    tokens, pools, pos_in, bts_in, *operands
                 )
             else:
                 nxt, pools = step_fn(tokens, pools, pos_in, bts_in)
@@ -631,16 +648,19 @@ def make_paged_window(step_fn, *, k: int, eos: int | None = None,
             tokens = jnp.where(active, nxt, tokens)
             positions = pos_in + alive
             active = active & ~done
-            return (tokens, pools, positions, active, emitted), out
+            return (tokens, pools, positions, active, emitted, *state), out
 
-        (tokens, pools, positions, active, emitted), toks = jax.lax.scan(
-            tick, (tokens, pools, positions, active, emitted), None,
-            length=k,
+        carried = operands if slot_state else ()
+        (tokens, pools, positions, active, emitted, *state), toks = (
+            jax.lax.scan(
+                tick, (tokens, pools, positions, active, emitted, *carried),
+                None, length=k,
+            )
         )
         mat = jnp.concatenate(
             [toks.T, active.astype(jnp.int32)[:, None]], axis=1
         )
-        return mat, tokens, positions, active, emitted, pools
+        return (mat, tokens, positions, active, emitted, pools, *state)
 
     return window
 

@@ -104,6 +104,7 @@ MODEL_MODULES = {
     "qwen2": "qwen2",
     "kimi_k2": "kimi_k2",
     "deepseek_v3": "kimi_k2",
+    "falcon_h1": "falcon_h1",
 }
 
 
@@ -1084,8 +1085,16 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         "active": False, "dir": "", "deadline": 0.0, "start_error": None,
     }
 
+    def _capture_edge(edge: str) -> None:
+        # Both edges fall between a collect() and the next dispatch():
+        # no window is in flight, so the read waits on nothing, and the
+        # two reads bracket exactly the ticks the capture holds.
+        if engine.model_counters is not None:
+            metrics.capture_counters[edge] = engine.model_counters()
+
     def _finish_profile() -> None:
         artifact, error = "", None
+        _capture_edge("stop")
         try:
             artifact = profiling.stop_capture(
                 profile_state["dir"], profile_state["start_error"]
@@ -1126,6 +1135,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
                     pass
                 return
             profile_state["active"] = True
+            _capture_edge("start")
             profile_state["deadline"] = clock() + float(
                 md.get("seconds") or 0.0
             )
@@ -1643,8 +1653,8 @@ def main() -> None:
         if os.environ.get("DORA_STUB_ENGINE", "") not in ("", "0"):
             return _stub_main()
         raise RuntimeError(
-            "llm_server needs DORA_HF_CHECKPOINT (a Qwen2-family or "
-            "kimi_k2/deepseek_v3 safetensors directory; or "
+            "llm_server needs DORA_HF_CHECKPOINT (a Qwen2-family, "
+            "kimi_k2/deepseek_v3 or falcon_h1 safetensors directory; or "
             "DORA_STUB_ENGINE=1 for the weight-free stub engine)"
         )
     max_seq = int(os.environ.get("DORA_MAX_SEQ", "2048"))

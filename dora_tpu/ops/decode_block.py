@@ -1995,15 +1995,26 @@ def _mlp_kernel(
         out_ref[...] = acc.astype(out_ref.dtype)
 
 
-def _pick_bf(ffn: int) -> int:
-    """Largest lane-multiple tile <= 1024 dividing ffn. The cap keeps
-    the three per-step int8 panels (gate + up + down ~ 3*D*BF bytes)
-    under half of VMEM so Mosaic can double-buffer the stream — a
+#: int8 bytes of weight one grid step of :func:`mlp_step` (gate, up and
+#: down panels: 3 * D * BF) and of :func:`lm_head_argmax` (D * BV) may
+#: stream: what the 1.5B widths take at their measured tiles (BF 1024,
+#: BV 2048 at D = 1536). Twice that, double-buffered, plus the panels'
+#: bf16 copies, stays inside the compiler's 16 MiB scope at any D.
+_MLP_STEP_BYTES = 3 * 1536 * 1024
+_HEAD_STEP_BYTES = 1536 * 2048
+
+
+def _pick_bf(ffn: int, d: int) -> int:
+    """Largest lane-multiple tile dividing ffn, at most 1024 and at most
+    what keeps the three per-step int8 panels (gate + up + down = 3*D*BF
+    bytes) within ``_MLP_STEP_BYTES``, so Mosaic can double-buffer the
+    stream at any hidden size: 1024 at D = 1536, 256 at D = 5120 — a
     bigger tile serializes the DMAs and shows up directly as decode
     latency (measured: 1792 -> 896 on the 2B shape was worth ~5%)."""
     if ffn % _LANE:
         return ffn
-    for bf in range(min(ffn, 1024), 0, -_LANE):
+    cap = max(min(1024, _MLP_STEP_BYTES // (3 * d) // _LANE * _LANE), _LANE)
+    for bf in range(min(ffn, cap), 0, -_LANE):
         if ffn % bf == 0:
             return bf
     return ffn
@@ -2024,7 +2035,7 @@ def mlp_step(x, norm_w, w_gateup, s_gateup, b_gateup, w_down, s_down,
     mrows, d = x.shape
     int4 = w_gateup.dtype == jnp.uint8
     f = w_down.shape[0] * (2 if int4 else 1)
-    bf = _pick_bf(f)
+    bf = _pick_bf(f, d)
     nf = f // bf
     kernel = functools.partial(
         _mlp_kernel, nf=nf, eps=eps, int4=int4, residual=residual
@@ -2132,7 +2143,10 @@ def lm_head_argmax(x, norm_w, w, s, *, eps: float = 1e-6,
     # ``vocab`` hold whatever the buffer held, and the kernel's
     # ``col < vocab`` select drops them (a column of the product reads
     # only its own column of the tile and of the scales).
-    bv = min(2048, pl.cdiv(vocab, 128) * 128)
+    # At a wider D the tile shrinks with it (``_HEAD_STEP_BYTES``: 512
+    # columns at D = 5120).
+    bv = max(min(2048, _HEAD_STEP_BYTES // d // _LANE * _LANE), _LANE)
+    bv = min(bv, pl.cdiv(vocab, 128) * 128)
     nv = pl.cdiv(vocab, bv)
     kernel = functools.partial(
         _head_kernel, nv=nv, bv=bv, vocab=vocab, eps=eps

@@ -73,6 +73,14 @@ def quantize_int8(w, keep_bf16: bool = False) -> dict:
     return out
 
 
+@jax.jit
+def quantize_int8_t(*weights) -> dict:
+    """HF ``[out, in]`` weights -> one int8 ``[in, sum(out)]`` matrix
+    with per-output-channel scales (transposed and joined on the
+    device): what a loader that quantizes layer by layer calls."""
+    return quantize_int8(jnp.concatenate([w.T for w in weights], axis=1))
+
+
 def dequantize(wq: dict, dtype=jnp.float32):
     return (wq["int8"].astype(jnp.float32) * wq["scale"]).astype(dtype)
 

@@ -212,6 +212,18 @@ def _tiny_kimi(tmp_path):
     return kimi_k2.load(tmp_path / "ckpt", max_seq=64)
 
 
+def _tiny_falcon(tmp_path):
+    from test_falcon_h1 import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import falcon_h1
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return falcon_h1.load(tmp_path / "ckpt", max_seq=64)
+
+
+_TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon}
+
+
 def _engine_programs(module_name, monkeypatch, tmp_path):
     """Run a request through the tiny engine of ``module_name`` and
     return what it jitted: program -> (abstract arguments of its first
@@ -220,7 +232,7 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
 
     module = importlib.import_module(f"dora_tpu.models.hf.{module_name}")
     cfg, params = (
-        _tiny_model() if module_name == "qwen2" else _tiny_kimi(tmp_path)
+        _tiny_model() if module_name == "qwen2" else _TINY[module_name](tmp_path)
     )
     seen: dict = {}
     real_jit = jax.jit
@@ -256,7 +268,7 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
     return seen
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2"])
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1"])
 def test_engine_programs_take_the_weights_as_arguments(
     module_name, monkeypatch, tmp_path
 ):
@@ -325,7 +337,7 @@ def test_kernels_take_the_stored_weight(kernel, m, k, n):
     assert _weight_copies(traced.jaxpr) == []
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2"])
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1"])
 def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
     """The same walk over the window and chunk programs as the engine
     jits them (tiny models: vocab 256 and 128 are no multiple of the

@@ -377,3 +377,124 @@ def test_kimi_window_program_compiles_and_updates_the_pool_in_place(chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)
+
+
+# -- Falcon-H1-34B: a recurrent state beside the pages ------------------------
+
+#: the catalog row's widths (benchmark/configs/falcon-h1-34b-pp8.json),
+#: depth 2, an eighth of the vocabulary: hidden 5120, 20/4 heads of 128,
+#: ffn 21504, a Mamba-2 mixer of 32 heads x 128 x state 256.
+FALCON = dict(
+    model_type="falcon_h1", hidden_size=5120, num_attention_heads=20,
+    num_key_value_heads=4, head_dim=128, intermediate_size=21504,
+    num_hidden_layers=2, vocab_size=32640, rms_norm_eps=1e-5,
+    rope_theta=1e11, mamba_d_ssm=4096, mamba_n_heads=32, mamba_d_head=128,
+    mamba_n_groups=2, mamba_d_state=256, mamba_d_conv=4,
+    mamba_chunk_size=128,
+)
+FALCON_SEQ = 2048
+
+
+def _falcon():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool, slot state and counter shapes) for 16 slots."""
+    from dora_tpu.models.hf import falcon_h1
+
+    cfg = falcon_h1.FalconH1Config.from_hf(FALCON, FALCON_SEQ)
+    bf = jnp.bfloat16
+    d = cfg.dim
+    shapes = {
+        "input_layernorm.weight": (d,), "pre_ff_layernorm.weight": (d,),
+        "self_attn.q_proj.weight": (cfg.q_width, d),
+        "self_attn.k_proj.weight": (cfg.kv_width, d),
+        "self_attn.v_proj.weight": (cfg.kv_width, d),
+        "self_attn.o_proj.weight": (d, cfg.q_width),
+        "mamba.in_proj.weight": (cfg.in_width, d),
+        "mamba.conv1d.weight": (cfg.conv_dim, 1, cfg.d_conv),
+        "mamba.conv1d.bias": (cfg.conv_dim,), "mamba.dt_bias": (cfg.ssm_heads,),
+        "mamba.A_log": (cfg.ssm_heads,), "mamba.D": (cfg.ssm_heads,),
+        "mamba.norm.weight": (cfg.d_ssm,),
+        "mamba.out_proj.weight": (d, cfg.d_ssm),
+        "feed_forward.gate_proj.weight": (cfg.ffn, d),
+        "feed_forward.up_proj.weight": (cfg.ffn, d),
+        "feed_forward.down_proj.weight": (d, cfg.ffn),
+    }
+
+    def get(name):
+        return jnp.zeros(shapes[name.split(".", 3)[3]], bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": falcon_h1._quantize_t(1.0, jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): falcon_h1.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(lambda: falcon_h1.init_page_pool(
+        cfg, SLOTS * FALCON_SEQ // PAGE + 1, PAGE))
+    state = jax.eval_shape(lambda: falcon_h1.init_slot_state(cfg, SLOTS))
+    stats = jax.eval_shape(falcon_h1.init_counters)
+    return falcon_h1, cfg, jax.eval_shape(build), pools, state, stats
+
+
+def _whole_array_copies(compiled, *trees) -> list[str]:
+    """``copy`` instructions of the compiled program as large as the
+    largest leaf of ``trees``: a cache that XLA moved instead of
+    updating in place."""
+    import math
+    import re
+
+    least = min(_pool_bytes(t) for t in trees)
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= [a-z]+(\d+)\[([\d,]+)\]\S* copy\(", line)
+        if m and int(m.group(1)) // 8 * math.prod(
+                int(n) for n in m.group(2).split(",")) >= least:
+            found.append(line.strip()[:120])
+    return found
+
+
+def test_falcon_h1_window_program_compiles_and_moves_no_cache(chip):
+    """The K=8 decode window at Falcon-H1-34B's widths, 16 slots: the
+    state-step kernel, ``mlp_step`` at D = 5120 / F = 21504 (tile 256)
+    and ``lm_head_argmax`` (tile 512) under Mosaic, and neither the K/V
+    pool (67 MB a layer) nor the slots' state (67 MB a layer) copied."""
+    falcon_h1, cfg, params, pools, state, stats = _falcon()
+    from dora_tpu.ops import decode_block as DB
+
+    assert DB._pick_bf(cfg.ffn, cfg.dim) == 256
+    assert DB._pick_bf(8960, 1536) == 896  # Qwen2.5-1.5B's, as before
+
+    def program(p, *args):
+        return falcon_h1.window_program(p, cfg, 8, None, falcon_h1.ATTN_BLOCK,
+                                        *args)
+
+    compiled = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, FALCON_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32), state)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _whole_array_copies(compiled, pools, state) == []
+
+
+def test_falcon_h1_chunk_program_compiles_and_moves_no_cache(chip):
+    """The 256-row prefill chunk: the chunked scan in plain XLA (two
+    blocks of 128), every matrix through ``int8_matmul``."""
+    falcon_h1, cfg, params, pools, state, stats = _falcon()
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return falcon_h1.fused_paged_chunk_step(
+            p, cfg, ids, pools, state, stats, position, bt, valid, slot)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3, 6)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((FALCON_SEQ // PAGE,), I32), state, _s((), I32),
+               _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _whole_array_copies(compiled, pools, state) == []
