@@ -348,8 +348,8 @@ def match_selector(selector: str, key: str) -> str | None:
 #: srv:<node>:<name> series shipped by flatten_snapshot, by class —
 #: the lint registry (alert-unknown-metric checks selectors here).
 SERVING_COUNTER_NAMES = frozenset((
-    "decode_tokens", "emit_overlapped", "requests", "rejected",
-    "prefill_chunks",
+    "decode_tokens", "emit_messages", "emit_overlapped", "requests",
+    "rejected", "prefill_chunks",
     "host_dispatches", "compiles", "spec_drafted", "spec_accepted",
     "shed", "preempted", "resumed", "retunes", "prefix_hits",
     "prefix_misses", "prefix_hit_tokens", "prefix_cow_copies",

@@ -166,6 +166,10 @@ def render_metrics(
             # device idles for each period (sending tokens beside a
             # running window is not in it).
             gap = s.get("dispatch_gap_us", {})
+            # EMIT: host time of one dispatch's flush (one message a
+            # stream) beside a running window — the other side of the
+            # period's max(device, emit).
+            emit = s.get("emit_us", {})
             fetch = s.get("fetch_us", {})
             toks = s.get("decode_tokens", 0)
             if rates is not None:
@@ -199,6 +203,7 @@ def render_metrics(
                 _fmt_us(ttft.get("p99_us")),
                 _fmt_us(gap.get("p50_us")),
                 _fmt_us(gap.get("p99_us")),
+                _fmt_us(emit.get("p50_us")),
                 _fmt_us(fetch.get("p50_us")),
                 str(s.get("compiles", 0)),
                 str(s.get("requests", 0)),
@@ -206,7 +211,7 @@ def render_metrics(
         lines += [""] + _table(
             ["SERVING", "SLOTS", "PAGES", "BACKLOG", "TOKENS", "TOK/S",
              "TOK/DISP", "ACC%", "TTFT P50", "TTFT P99", "GAP P50",
-             "GAP P99", "FETCH P50", "COMPILES", "REQS"],
+             "GAP P99", "EMIT P50", "FETCH P50", "COMPILES", "REQS"],
             serving_rows,
         )
         # Page-occupancy sparkline: used/total over the watch history

@@ -174,8 +174,9 @@ class ServingMetrics:
     """
 
     __slots__ = (
-        "ttft", "dispatch_gap", "fetch_latency", "backlog_wait",
-        "grant_pages", "decode_tokens", "emit_overlapped", "prefill_chunks",
+        "ttft", "dispatch_gap", "emit", "fetch_latency", "backlog_wait",
+        "grant_pages", "decode_tokens", "emit_messages", "emit_overlapped",
+        "prefill_chunks",
         "requests", "rejected", "slots_active", "slots_total",
         "free_pages", "total_pages", "used_pages", "peak_used_pages",
         "largest_contig_free", "backlog_depth", "host_dispatches",
@@ -211,6 +212,12 @@ class ServingMetrics:
         #: transfer — a slow device->host path moves the fetch track, a
         #: host-side regression moves the gap track.
         self.dispatch_gap = Histogram()
+        #: host time of one dispatch's flush (the previous window's
+        #: tokens and the dispatch's first tokens, one message a
+        #: stream), observed only where a window ran beside it: the
+        #: emit side of a period's max(device, emit). The device waits
+        #: for the host wherever this outlasts the window.
+        self.emit = Histogram()
         #: blocking device->host fetch durations (the sync points:
         #: chunk greedy reads, the [B, K+1] window matrix), observed by
         #: the engine via its ``serving_metrics`` hook
@@ -222,6 +229,10 @@ class ServingMetrics:
         #: are small ints; fed by the paged engine at submit)
         self.grant_pages: dict[int, int] = {}
         self.decode_tokens = 0
+        #: ``response`` messages that carried those tokens: a flush
+        #: sends one a stream, holding every token it holds for it
+        #: (``decode_tokens`` / ``emit_messages`` = tokens a message)
+        self.emit_messages = 0
         #: of ``decode_tokens``, those sent while a decode window was
         #: in flight on the device (the serving loop emits window N
         #: beside window N+1); the rest were sent with the device
@@ -381,6 +392,7 @@ class ServingMetrics:
             "requests": self.requests,
             "rejected": self.rejected,
             "decode_tokens": self.decode_tokens,
+            "emit_messages": self.emit_messages,
             "emit_overlapped": self.emit_overlapped,
             "prefill_chunks": self.prefill_chunks,
             "slots_active": self.slots_active,
@@ -404,6 +416,7 @@ class ServingMetrics:
             },
             "ttft_us": self.ttft.snapshot(),
             "dispatch_gap_us": self.dispatch_gap.snapshot(),
+            "emit_us": self.emit.snapshot(),
             "fetch_us": self.fetch_latency.snapshot(),
             "backlog_wait_us": self.backlog_wait.snapshot(),
             "checkpoints": self.checkpoints,

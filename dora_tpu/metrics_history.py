@@ -113,9 +113,9 @@ def flatten_snapshot(snap: dict) -> tuple[dict, dict, dict]:
     for node, c in (snap.get("trace") or {}).get("drops", {}).items():
         counters[f"tracedrop:{node}"] = c
     for node, s in snap.get("serving", {}).items():
-        for name in ("decode_tokens", "emit_overlapped", "requests",
-                     "rejected", "prefill_chunks", "host_dispatches",
-                     "compiles",
+        for name in ("decode_tokens", "emit_messages", "emit_overlapped",
+                     "requests", "rejected", "prefill_chunks",
+                     "host_dispatches", "compiles",
                      "spec_drafted", "spec_accepted",
                      "shed", "preempted", "resumed", "retunes",
                      "prefix_hits", "prefix_misses", "prefix_hit_tokens",

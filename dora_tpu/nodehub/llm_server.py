@@ -7,8 +7,11 @@ every ``text`` input carrying a ``request_id`` is admitted into a
 serving-engine slot, and each engine step advances ALL active requests
 one token off a single LM weight stream (the batched fused kernels,
 ops/decode_block). Token deltas stream back on ``response`` tagged
-``{request_id, done}`` — the openai_server's concurrent mode routes
-them to the right SSE stream.
+``{request_id, done, seq, n_tokens}`` — the openai_server's concurrent
+mode routes them to the right SSE stream. A message holds every token
+the loop holds for its stream when it sends (a window's, up to K; a
+first token alone): ``n_tokens`` of them, ``seq`` being the number of
+the request's tokens sent before it.
 
 The engine (models/batch_engine.PagedBatchEngine): KV lives in a pool
 of page-size blocks routed through per-slot block tables, prompts
@@ -30,11 +33,12 @@ dispatch/fetch cost amortizes across K tokens. Admissions, prefill
 chunks and backlog draining happen at window boundaries — backlog
 latency quantizes to one window. The loop is pipelined by one window:
 it launches window N+1 (``engine.dispatch()``), sends window N's
-tokens while the device runs, and only then waits
-(``engine.collect()``); a prompt's first token leaves right after the
-launch, before its window is collected. Tokens collected and not yet
-sent are flushed before anything reads per-request state (preemption,
-migration, checkpoints, errors, exit, an engine gone idle).
+tokens while the device runs — one message per stream — and only then
+waits (``engine.collect()``); a prompt's first token leaves right after
+the launch, ahead of them and before its window is collected. Tokens
+collected and not yet sent are flushed before anything reads
+per-request state (preemption, migration, checkpoints, errors, exit, an
+engine gone idle).
 
 Env: DORA_BATCH_SLOTS (default 16) concurrent streams;
 DORA_MAX_NEW_TOKENS (default 32) per-request cap (a request's
@@ -70,7 +74,8 @@ Elastic recovery: ``DORA_CHECKPOINT_DIR`` (+
 ``DORA_CHECKPOINT_EVERY``, default 8 windows) snapshots live serving
 state atomically — and on SIGTERM — and restores it on respawn,
 resuming mid-generation streams token-identically; every response
-chunk carries a ``seq`` so consumers dedup the at-least-once replay.
+chunk carries ``seq`` and ``n_tokens`` (a replay sends the same text
+under the same pair) so consumers dedup the at-least-once replay.
 ``DORA_MIGRATE_DIR`` makes this node a migration target: it stays
 alive past end-of-stream and admits handoff files drained by
 ``dora-tpu migrate`` from another engine, continuing each stream
@@ -398,11 +403,21 @@ class AdmissionQueue:
 
 
 def _flush(held: list, emit) -> int:
-    """Send every held token, oldest first; returns how many went."""
+    """Send every held token, one ``emit`` a stream: a key's tokens in
+    the order they were held, the keys in the order of their first held
+    token, ``done`` that of the key's last. Returns how many tokens
+    went."""
     n = len(held)
+    by_key: dict[str, list[int]] = {}
+    ended: set[str] = set()
     for key, token, done in held:
-        emit(key, token, done)
+        assert key not in ended, f"{key}: a token held after its last"
+        by_key.setdefault(key, []).append(token)
+        if done:
+            ended.add(key)
     held.clear()
+    for key, tokens in by_key.items():
+        emit(key, tokens, key in ended)
     return n
 
 
@@ -414,14 +429,14 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
     """Window-granular serving loop, factored out of :func:`main` so
     tests can drive it with fake nodes/engines. Each iteration: drain
     the pending events, ``engine.dispatch()`` (one prefill chunk, then
-    the launch of one K-tick decode window), emit the tokens HELD from
-    the previous window and the first token the dispatch returned —
-    the device runs the new window meanwhile — then
-    ``engine.collect()`` waits for the window and its tokens become
-    the held ones. Then ALWAYS drain the backlog — capacity appears
-    when a step frees slots/pages, but also the idle path must admit
-    (a parked request with zero active streams used to sit until
-    unrelated traffic arrived).
+    the launch of one K-tick decode window), emit the first tokens the
+    dispatch returned and the tokens HELD from the previous window, one
+    message a stream (:func:`_flush`) — the device runs the new window
+    meanwhile — then ``engine.collect()`` waits for the window and its
+    tokens become the held ones. Then ALWAYS drain the backlog —
+    capacity appears when a step frees slots/pages, but also the idle
+    path must admit (a parked request with zero active streams used to
+    sit until unrelated traffic arrived).
 
     ``held`` is state the wire has not seen, and whatever reads
     per-request state sends it first (:func:`_flush`). The loop does
@@ -505,10 +520,14 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             first = half(engine.dispatch)
             overlapped = engine.in_flight
             t_launch = clock()
-            held.extend(first)  # after the previous window's tokens
+            # First tokens lead: ttft waits for them, and their streams
+            # have no token in the window held (it ran before them).
+            held[:0] = first
             sent = _flush(held, emit)
             if overlapped:
                 metrics.emit_overlapped += sent
+                # The emit side of max(device, emit) in this period.
+                metrics.emit.observe((clock() - t_launch) * 1e6)
             else:
                 # Nothing ran beside the emit (a prefill-only
                 # dispatch): the device waited for it too.
@@ -617,11 +636,13 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     #: engine key -> arrival wall time, pending first token (TTFT)
     t_admitted: dict[str, float] = {}
     req_counter = [0]
-    #: engine key -> next chunk sequence number. Recovery replays are
-    #: at-least-once: after a crash-restore the engine re-decodes from
-    #: the checkpoint, re-emitting chunks the wire already saw — with
-    #: the SAME (request_id, seq) pair, so consumers dedup instead of
-    #: double-printing.
+    #: engine key -> tokens of the request already sent, the next
+    #: message's ``seq``. Recovery replays are at-least-once: after a
+    #: crash-restore the engine re-decodes from the checkpoint (taken
+    #: with nothing held, so on a message's edge), re-emitting tokens
+    #: the wire already saw — the same text under the SAME
+    #: (request_id, seq) pair, and ``n_tokens`` says how far a message
+    #: reaches, so consumers dedup instead of double-printing.
     seqs: dict[str, int] = {}
     #: wire request_ids already admitted (checkpoint mode only): a
     #: daemon replay of an un-acked input must not re-admit a stream
@@ -644,7 +665,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
 
     def emit_text(
         key: str, text: str, done: bool, finish: str | None = None,
-        extra: dict | None = None,
+        extra: dict | None = None, n_tokens: int = 0,
     ) -> None:
         meta: dict = {"done": bool(done)}
         if done:
@@ -659,12 +680,15 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         stalled = stall_tags.pop(key, None)
         if stalled is not None and "stall_reason" not in meta:
             meta["stall_reason"] = stalled
+        # seq: tokens of this request sent before this message (0, 1,
+        # 2, ... for a stream that sends one token a message).
         seq = seqs.get(key, 0)
         meta["seq"] = seq
+        meta["n_tokens"] = n_tokens
         if done:
             seqs.pop(key, None)
         else:
-            seqs[key] = seq + 1
+            seqs[key] = seq + n_tokens
         rid = wire_ids.get(key)
         if rid is not None:
             meta["request_id"] = rid
@@ -680,14 +704,23 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             _forget(key)
             tracer.finish(key, finish or "stop")
 
-    def emit(key: str, token: int, done: bool) -> None:
+    def emit(key: str, tokens: list[int], done: bool) -> None:
+        """One ``response`` message: every token a flush holds for the
+        stream, ``done`` and the finish reason those of the last."""
         finish = None
         if done:
-            finish = "stop" if (eos is not None and token == eos) else "length"
-        metrics.decode_tokens += 1
-        if qos.preempt_on and not done and key in req_emitted:
-            req_emitted[key].append(token)
-        emit_text(key, decode_one(token), done, finish)
+            finish = (
+                "stop" if (eos is not None and tokens[-1] == eos)
+                else "length"
+            )
+        metrics.decode_tokens += len(tokens)
+        metrics.emit_messages += 1
+        if qos.preempt_on and key in req_emitted:
+            req_emitted[key].extend(tokens)
+        emit_text(
+            key, "".join(decode_one(t) for t in tokens), done, finish,
+            n_tokens=len(tokens),
+        )
 
     #: tokens the engine has handed over and the wire has not seen: the
     #: loop sends them while the next window runs (_run_loop). Readers
@@ -1714,8 +1747,10 @@ def main() -> None:
         # the host time the device was left waiting each period.
         backend.report("serving", {
             "decode_tokens": metrics.decode_tokens,
+            "emit_messages": metrics.emit_messages,
             "emit_overlapped": metrics.emit_overlapped,
             "dispatch_gap_us": metrics.dispatch_gap.snapshot(),
+            "emit_us": metrics.emit.snapshot(),
             **metrics.model,
         })
 

@@ -40,6 +40,7 @@ FAMILIES: dict[str, tuple[str, str]] = {
     "dora_serving_requests_total": ("counter", "Serving requests admitted"),
     "dora_serving_rejected_total": ("counter", "Serving requests rejected at admission"),
     "dora_serving_decode_tokens_total": ("counter", "Decode tokens emitted"),
+    "dora_serving_emit_messages_total": ("counter", "Response messages that carried decode tokens (one a stream a flush)"),
     "dora_serving_emit_overlapped_total": ("counter", "Decode tokens emitted while a window was in flight on the device"),
     "dora_serving_prefill_chunks_total": ("counter", "Prefill chunks dispatched"),
     "dora_serving_host_dispatches_total": ("counter", "Engine device-program launches"),
@@ -104,6 +105,7 @@ _SERVING_COUNTERS = (
     ("requests", "dora_serving_requests_total"),
     ("rejected", "dora_serving_rejected_total"),
     ("decode_tokens", "dora_serving_decode_tokens_total"),
+    ("emit_messages", "dora_serving_emit_messages_total"),
     ("emit_overlapped", "dora_serving_emit_overlapped_total"),
     ("prefill_chunks", "dora_serving_prefill_chunks_total"),
     ("host_dispatches", "dora_serving_host_dispatches_total"),
@@ -424,6 +426,7 @@ def _sample_snapshots() -> dict[str, dict[str, Any]]:
                     "requests": 42,
                     "rejected": 2,
                     "decode_tokens": 4096,
+                    "emit_messages": 700,
                     "emit_overlapped": 4000,
                     "prefill_chunks": 12,
                     "host_dispatches": 512,

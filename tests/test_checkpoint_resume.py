@@ -16,11 +16,12 @@ from dora_tpu.metrics import ServingMetrics
 from tests.test_serving_trace import _ServeNode, _req
 
 
-def _mk_engine(max_slots: int = 2):
+def _mk_engine(max_slots: int = 2, window: int = 1):
     from dora_tpu.models.batch_engine import make_stub_paged_engine
 
     return make_stub_paged_engine(
-        max_slots=max_slots, max_seq=64, page_size=8, chunk=16, window=1
+        max_slots=max_slots, max_seq=64, page_size=8, chunk=16,
+        window=window,
     )
 
 
@@ -326,42 +327,71 @@ def _expected_text(prompt: str, max_new: int) -> str:
     return "".join(out)
 
 
-def _merge_chunks(*nodes) -> dict[str, str]:
-    """Dedup response chunks by (request_id, seq) keeping the FIRST
-    occurrence — the consumer contract that turns at-least-once replay
-    into byte-identical streams."""
-    seen: dict[tuple[str, int], str] = {}
+def _merge_chunks(*nodes, replayed: list | None = None) -> dict[str, str]:
+    """The consumer contract that turns at-least-once replay into
+    byte-identical streams. A message's ``seq`` is the number of the
+    request's tokens sent before it and ``n_tokens`` how many it holds;
+    a replay starts on a message's edge (checkpoints are taken with
+    nothing held) and sends the same text for the same tokens. So the
+    consumer keeps the FIRST text it got for each token and takes from a
+    replayed message only what reaches past it — wherever the restored
+    engine cuts its windows. ``replayed`` collects the (request_id, seq)
+    of messages that brought nothing new."""
+    texts: dict[str, str] = {}
+    #: request -> token index -> characters of the stream before it
+    edges: dict[str, dict[int, int]] = {}
     for node in nodes:
         for _out, value, meta in node.sent:
             rid = meta.get("request_id")
             if rid is None:
                 continue
-            seen.setdefault((rid, int(meta["seq"])), value.to_pylist()[0])
-    texts: dict[str, str] = {}
-    for (rid, seq) in sorted(seen):
-        texts[rid] = texts.get(rid, "") + seen[(rid, seq)]
+            text = value.to_pylist()[0]
+            seq, n = int(meta["seq"]), int(meta["n_tokens"])
+            at = edges.setdefault(rid, {0: 0})[seq]
+            have = texts.get(rid, "")
+            overlap = have[at:at + len(text)]
+            assert text.startswith(overlap), (rid, seq, overlap, text)
+            if at + len(text) > len(have):
+                texts[rid] = have[:at] + text
+            elif n and replayed is not None:
+                replayed.append((rid, seq))
+            edges[rid][seq + n] = at + len(text)
     return texts
 
 
-def test_serve_crash_and_resume_byte_identical(tmp_path, monkeypatch):
-    """serve() checkpointing every window dies mid-generation (recv
+@pytest.mark.parametrize(
+    "window, every, max_new, crash_after",
+    [(1, 1, 8, 6), (4, 3, 20, 7)],
+    ids=["one-token-messages", "window-messages-replayed"],
+)
+def test_serve_crash_and_resume_byte_identical(
+    tmp_path, monkeypatch, window, every, max_new, crash_after
+):
+    """serve() checkpointing on a cadence dies mid-generation (recv
     raises); a second serve() over a FRESH engine restores the snapshot
-    and completes both streams. Merged chunks, deduped by
-    (request_id, seq), equal the analytic uninterrupted output."""
+    and completes both streams. Merged chunks, deduped by token range
+    (``seq`` / ``n_tokens``), equal the analytic uninterrupted output —
+    with one token a message (K = 1) and with a window's tokens a
+    message, some of them sent twice (the crash came after sends the
+    last checkpoint had not seen)."""
     from dora_tpu.nodehub.llm_server import serve
 
     monkeypatch.setenv("DORA_CHECKPOINT_DIR", str(tmp_path / "ckpt"))
-    monkeypatch.setenv("DORA_CHECKPOINT_EVERY", "1")
+    monkeypatch.setenv("DORA_CHECKPOINT_EVERY", str(every))
     prev_term = signal.getsignal(signal.SIGTERM)
     kwargs = dict(
         encode=lambda text: [ord(ch) % 97 for ch in text] or [1],
         decode_one=lambda t: f" t{t}",
-        max_new_cap=8,
+        max_new_cap=max_new,
     )
     try:
-        node1 = _CrashNode([_req("ab", 8), _req("cd", 8)], crash_after=6)
+        node1 = _CrashNode(
+            [_req("ab", max_new), _req("cd", max_new)],
+            crash_after=crash_after,
+        )
         with pytest.raises(RuntimeError, match="simulated kill"):
-            serve(node1, _mk_engine(), ServingMetrics(), **kwargs)
+            serve(node1, _mk_engine(window=window), ServingMetrics(),
+                  **kwargs)
         assert (tmp_path / "ckpt" / "state.json").exists()
         # The crash must NOT have produced complete streams on its own.
         done1 = [m for _o, _v, m in node1.sent if m.get("done")]
@@ -369,16 +399,27 @@ def test_serve_crash_and_resume_byte_identical(tmp_path, monkeypatch):
 
         metrics2 = ServingMetrics()
         node2 = _ServeNode([])  # no new traffic: pure resume
-        serve(node2, _mk_engine(), metrics2, **kwargs)
+        serve(node2, _mk_engine(window=window), metrics2, **kwargs)
         assert metrics2.restored_streams == 2
     finally:
         signal.signal(signal.SIGTERM, prev_term)
 
-    texts = _merge_chunks(node1, node2)
+    replayed: list = []
+    texts = _merge_chunks(node1, node2, replayed=replayed)
     assert texts == {
-        "wire-ab": _expected_text("ab", 8),
-        "wire-cd": _expected_text("cd", 8),
+        "wire-ab": _expected_text("ab", max_new),
+        "wire-cd": _expected_text("cd", max_new),
     }
+    sizes = {m["n_tokens"] for _o, _v, m in node1.sent + node2.sent}
+    if window == 1:
+        assert sizes == {1}
+    else:
+        # a window's tokens travel as one message, and the replay sent
+        # some again: the same text under the same (request_id, seq),
+        # which _merge_chunks asserted as it dropped them
+        assert window in sizes and replayed, (sizes, replayed)
+        first_run = {(m["request_id"], m["seq"]) for _o, _v, m in node1.sent}
+        assert set(replayed) <= first_run
 
 
 def test_serve_replayed_input_not_readmitted(tmp_path, monkeypatch):

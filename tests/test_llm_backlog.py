@@ -105,7 +105,11 @@ def _drive(engine, events):
         backlog,
         metrics,
         handle_input,
-        lambda key, token, done: emitted.append((key, token, done)),
+        # one message a stream a flush; flattened back to token triples
+        lambda key, tokens, done: emitted.extend(
+            (key, token, done and i == len(tokens) - 1)
+            for i, token in enumerate(tokens)
+        ),
         lambda now: None,
     )
     return emitted, backlog

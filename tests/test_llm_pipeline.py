@@ -3,12 +3,16 @@
 
 * ORDER: in steady state the loop launches window N+1, sends window N's
   tokens while it runs, and only then waits — and a prompt's first
-  token leaves right after the dispatch that read it.
+  token leaves right after the dispatch that read it, ahead of them.
+* ONE MESSAGE PER STREAM PER FLUSH: a flush sends every token it holds
+  for a stream as one ``response``: the texts concatenated in order,
+  ``done`` and ``finish`` those of the last, ``seq`` the number of the
+  request's tokens sent before it and ``n_tokens`` how many it holds.
 * FLUSH POINTS: tokens collected and not yet sent are state the wire
   has not seen. Whatever reads per-request state — preemption,
   migration, a checkpoint, the error path, STOP, the end of the input
   stream, an engine gone idle — finds every one of them on the wire
-  first, in order, with consecutive ``seq``.
+  first, in order, each message's ``seq`` the tokens sent before it.
 * TOKEN IDENTITY: streams served through ``dispatch()`` + ``collect()``
   under the loop equal those of ``step()`` called in a loop, on the
   real paged engine at a tiny size (plain / speculative / LoRA /
@@ -87,11 +91,13 @@ class SplitEngine(_Streams):
     ``emit`` shares."""
 
     in_flight = False
+    launched = 0  # dispatches that left a window in flight
 
     def dispatch(self):
         self.log.append(("dispatch",))
         first = self._first()
         self.in_flight = bool(self.streams)
+        self.launched += self.in_flight
         return first
 
     def collect(self):
@@ -153,8 +159,12 @@ def _drive(engine, log, script, max_new=7, **hooks):
         engine, lambda k, ids, mn, adapter: engine.submit(k, ids, mn)
     )
 
-    def emit(key, token, done):
-        log.append(("emit", key, token, done, bool(engine.in_flight)))
+    def emit(key, tokens, done):
+        # one log line a token (only a key's last can be its done one)
+        log.append(("message", key, len(tokens)))
+        for i, token in enumerate(tokens):
+            log.append(("emit", key, token, done and i == len(tokens) - 1,
+                        bool(engine.in_flight)))
 
     def handle_input(event):
         backlog.push(event["metadata"]["request_id"], [1, 2], max_new)
@@ -176,6 +186,10 @@ def _emits(log, key=None):
     return [e for e in log if e[0] == "emit" and key in (None, e[1])]
 
 
+def _messages(log, key=None):
+    return [e for e in log if e[0] == "message" and key in (None, e[1])]
+
+
 # ---------------------------------------------------------------------------
 # the order
 # ---------------------------------------------------------------------------
@@ -186,29 +200,40 @@ def test_steady_state_is_dispatch_then_previous_windows_tokens_then_collect():
     metrics, _ = _drive(SplitEngine(log, slots=1, k=3), log, [(0, _input("a"))])
     kinds = [e[0] for e in log]
     # first token right after the dispatch that read it, before collect
-    assert kinds[:3] == ["dispatch", "emit", "collect"]
-    assert log[1][1:4] == ("a", 1, False)
-    # then: dispatch, the three tokens of the window before, collect
-    assert kinds[3:8] == ["dispatch", "emit", "emit", "emit", "collect"]
-    assert [e[2] for e in log[4:7]] == [2, 3, 4]
+    assert kinds[:4] == ["dispatch", "message", "emit", "collect"]
+    assert log[2][1:4] == ("a", 1, False)
+    # then: dispatch, the three tokens of the window before as ONE
+    # message, collect
+    assert kinds[4:10] == [
+        "dispatch", "message", "emit", "emit", "emit", "collect"
+    ]
+    assert log[5] == ("message", "a", 3)
+    assert [e[2] for e in log[6:9]] == [2, 3, 4]
+    assert [e[2] for e in _messages(log)] == [1, 3, 3]
     # in order, nothing lost, done last
     assert [e[2] for e in _emits(log)] == [1, 2, 3, 4, 5, 6, 7]
     assert [e[3] for e in _emits(log)] == [False] * 6 + [True]
     # a collect is never followed by an emit of ITS tokens before the
     # next dispatch — except where the engine went idle (the last one)
-    for i, e in enumerate(log[:-4]):
+    for i, e in enumerate(log[:-5]):
         if e[0] == "collect":
             assert log[i + 1][0] == "dispatch", log[i : i + 3]
 
 
 def test_emit_overlapped_counts_tokens_sent_beside_a_window():
     log: list = []
+    engine = SplitEngine(log, slots=2, k=3)
     metrics, _ = _drive(
-        SplitEngine(log, slots=2, k=3), log,
-        [(0, _input("a")), (0, _input("b"))], max_new=20,
+        engine, log, [(0, _input("a")), (0, _input("b"))], max_new=20,
     )
     beside = sum(e[4] for e in _emits(log))
     assert metrics.emit_overlapped == beside
+    # the flush's host time is observed once a dispatch that has a
+    # window running beside it
+    assert metrics.emit.count == engine.launched > 6
+    # one message a stream a flush, each holding the stream's window
+    assert len(_messages(log)) < len(_emits(log)) / 2
+    assert {e[2] for e in _messages(log)} == {1, 3}
     # everything but the idle flush of the two streams' last window
     # went out beside a running window
     assert len(_emits(log)) == 40
@@ -225,7 +250,36 @@ def test_a_dispatch_that_launched_nothing_counts_no_token_as_overlapped():
     assert [e[2] for e in _emits(log, "a")] == [1, 2, 3, 4, 5, 6, 7]
     assert [e[2] for e in _emits(log, "b")] == [1, 2, 3, 4, 5, 6, 7]
     assert metrics.emit_overlapped == 0
+    assert metrics.emit.count == 0  # no window ran beside any flush
     assert "dispatch" not in {e[0] for e in log}
+
+
+def test_flush_groups_the_held_tokens_by_stream():
+    """3 streams x 8 tokens as the engine hands them over (tick by
+    tick, rows interleaved) behind one first token: four emits, a
+    key's tokens in the order held, the keys in the order of their
+    first held token, ``done`` that of the key's last."""
+    from dora_tpu.nodehub.llm_server import _flush
+
+    held = [("d", 900, False)]
+    for tick in range(8):
+        for key, base in (("a", 100), ("b", 200), ("c", 300)):
+            held.append((key, base + tick, key == "b" and tick == 7))
+    calls: list = []
+    n = _flush(held, lambda key, tokens, done: calls.append(
+        (key, list(tokens), done)
+    ))
+    assert n == 25 and held == []
+    assert calls == [
+        ("d", [900], False),
+        ("a", list(range(100, 108)), False),
+        ("b", list(range(200, 208)), True),
+        ("c", list(range(300, 308)), False),
+    ]
+    assert _flush(held, calls.append) == 0 and len(calls) == 4
+    # a done can only be a key's last token
+    with pytest.raises(AssertionError, match="after its last"):
+        _flush([("a", 1, True), ("a", 2, False)], lambda *a: None)
 
 
 def test_loop_flushes_before_migrate_error_stop_and_idle():
@@ -289,26 +343,42 @@ def test_loop_flushes_before_migrate_error_stop_and_idle():
 
 
 class _Wire(ScriptNode):
-    """Node fake for serve(): the script is paced by chunks sent so
-    far; captures the chunks' metadata."""
+    """Node fake for serve(): the script is paced by tokens sent so
+    far; captures the messages' metadata (and text, as ``text``)."""
 
     def __init__(self, script, hold_open: int = 0):
-        super().__init__(script, sent=lambda: len(self.sent),
-                         hold_open=hold_open)
+        super().__init__(script, sent=self.count, hold_open=hold_open)
         self.sent: list[dict] = []
         self.closed = False
 
-    def count(self, rid: str) -> int:
-        return sum(m.get("request_id") == rid for m in self.sent)
+    def count(self, rid: str | None = None) -> int:
+        """Tokens on the wire (of ``rid``, or of every stream)."""
+        return sum(
+            m["n_tokens"] for m in self.sent
+            if rid in (None, m.get("request_id"))
+        )
 
     def send_output(self, output_id, value, metadata=None):
-        self.sent.append(dict(metadata or {}))
+        self.sent.append(
+            dict(metadata or {}, text=value.to_pylist()[0])
+        )
 
     def report_serving(self, snapshot):
         pass
 
     def close(self):
         self.closed = True
+
+
+def _serve_stub(wire, engine, metrics) -> None:
+    """The real ``serve()`` over a stub engine: ids from the prompt's
+    characters, one `` t<N>`` word a token."""
+    serve(
+        wire, engine, metrics,
+        encode=lambda text: [ord(ch) % 97 + 1 for ch in text] or [1],
+        decode_one=lambda tok: f" t{tok}",
+        max_new_cap=64,
+    )
 
 
 def _req(rid: str, max_new: int, qos: str | None = None) -> dict:
@@ -339,9 +409,19 @@ def _assert_consecutive(wire: _Wire) -> dict[str, list[dict]]:
     for m in wire.sent:
         by_rid.setdefault(m["request_id"], []).append(m)
     for rid, chunks in by_rid.items():
-        assert [m["seq"] for m in chunks] == list(range(len(chunks))), rid
-        assert all(not m["done"] for m in chunks[:-1]), rid
+        # seq = the request's tokens sent before the message; the text
+        # holds n_tokens of the stub's " t<N>" words
+        sent_before = 0
+        for m in chunks:
+            assert m["seq"] == sent_before, (rid, chunks)
+            assert m["text"].count(" t") == m["n_tokens"], m
+            sent_before += m["n_tokens"]
+        assert all(not m["done"] and m["n_tokens"] for m in chunks[:-1]), rid
     return by_rid
+
+
+def _n(chunks: list[dict]) -> int:
+    return sum(m["n_tokens"] for m in chunks)
 
 
 FLUSH_CASES = [
@@ -394,19 +474,11 @@ def test_every_held_token_is_on_the_wire_before(case, monkeypatch, tmp_path):
         _audit(engine, audited, wire, rids, seen)
     metrics = ServingMetrics(engine="paged")
 
-    def run():
-        serve(
-            wire, engine, metrics,
-            encode=lambda text: [ord(ch) % 97 + 1 for ch in text] or [1],
-            decode_one=lambda tok: f" t{tok}",
-            max_new_cap=64,
-        )
-
     if raises:
         with pytest.raises(RuntimeError, match=raises):
-            run()
+            _serve_stub(wire, engine, metrics)
     else:
-        run()
+        _serve_stub(wire, engine, metrics)
     by_rid = _assert_consecutive(wire)
     if audited:
         live = [s for s in seen if s[2] > 0]
@@ -415,25 +487,30 @@ def test_every_held_token_is_on_the_wire_before(case, monkeypatch, tmp_path):
             assert emitted == on_wire, (name, rid, emitted, on_wire)
     if case == "preempt":
         assert metrics.preempted >= 1 and metrics.resumed >= 1
-        assert by_rid["w-a"][-1]["done"] and len(by_rid["w-a"]) == 24
+        assert by_rid["w-a"][-1]["done"] and _n(by_rid["w-a"]) == 24
     elif case == "migrate":
         assert metrics.migrated_out == 1
         assert not by_rid["w-a"][-1]["done"]  # it moved, it did not end
     elif case == "checkpoint":
         assert metrics.checkpoints >= 3
-        assert len(by_rid["w-a"]) == 24 and by_rid["w-a"][-1]["done"]
+        assert _n(by_rid["w-a"]) == 24 and by_rid["w-a"][-1]["done"]
     elif case == "engine_error":
         # every token the engine counts (the first, then three
         # collected windows: the last of them was held), then the error
         chunks = by_rid["w-a"]
-        assert len(chunks) - 1 == engine.slots[0].emitted == 1 + 3 * 4
+        assert _n(chunks) == engine.slots[0].emitted == 1 + 3 * 4
+        assert [m["n_tokens"] for m in chunks] == [1, 4, 4, 4, 0]
         assert chunks[-1]["finish"] == "error" and chunks[-1]["done"]
+        assert chunks[-1]["seq"] == 13 and chunks[-1]["text"] == ""
     elif case == "stop":
         slot = engine.slots[0]
-        assert slot is not None and len(by_rid["w-a"]) == slot.emitted
+        assert slot is not None and _n(by_rid["w-a"]) == slot.emitted
     elif case == "stream_end":
-        assert len(by_rid["w-a"]) == 24
+        # the first token alone, then a window's four a message (the
+        # last window has three left)
+        assert [m["n_tokens"] for m in by_rid["w-a"]] == [1] + [4] * 5 + [3]
         assert by_rid["w-a"][-1]["finish"] == "length"
+        assert metrics.decode_tokens == 24 and metrics.emit_messages == 7
     elif case == "idle":
         # the loop parked (recv with a timeout) only with all 24 out
         after = [n for t, n in wire.recvs if t and n > 0]
@@ -442,6 +519,101 @@ def test_every_held_token_is_on_the_wire_before(case, monkeypatch, tmp_path):
     if case in ("stream_end", "idle"):
         # all but the idle flush of the last window went out beside one
         assert metrics.decode_tokens - metrics.emit_overlapped <= 4
+
+
+def _stub_words(prompt: bytes, n: int) -> list[str]:
+    """The stub engine's stream for ``prompt``: the affine chain from
+    its last token id, one `` t<N>`` word a token."""
+    t = [ch % 97 + 1 for ch in prompt][-1]
+    words = []
+    for _ in range(n):
+        t = (7 * t + 3) % 97
+        words.append(f" t{t}")
+    return words
+
+
+def test_a_flush_sends_one_message_a_stream_through_serve():
+    """K = 8, three streams decoding, a fourth admitted beside them,
+    served through the real ``serve()`` over the stub paged engine. The
+    flush after the dispatch that read the fourth's first token sends 4
+    messages for 25 tokens: the first token ahead, then each stream's
+    eight as one text; ``seq`` counts the request's tokens sent before,
+    ``n_tokens`` those in the message, and the finishing stream's
+    ``done`` / ``finish`` ride on its one message. Counters: tokens,
+    messages, and one ``emit_us`` reading a dispatch that left a window
+    in flight."""
+    pytest.importorskip("jax")
+    from dora_tpu.models.batch_engine import make_stub_paged_engine
+
+    engine = make_stub_paged_engine(max_slots=4, window=8, max_seq=128)
+    caps = {"w-a": 40, "w-b": 25, "w-c": 40, "w-d": 3}
+    wire = _Wire([(0, _req("w-a", 40)), (0, _req("w-b", 25)),
+                  (0, _req("w-c", 40)), (28, _req("w-d", 3))])
+    flushes: list[list[dict]] = []
+    launched = [0]
+    mark = [0]
+    real_dispatch, real_collect = engine.dispatch, engine.collect
+
+    def dispatch():
+        out = real_dispatch()
+        launched[0] += bool(engine.in_flight)
+        mark[0] = len(wire.sent)
+        return out
+
+    def collect():
+        flushes.append(wire.sent[mark[0]:])  # what the loop sent between
+        return real_collect()
+
+    engine.dispatch, engine.collect = dispatch, collect
+    metrics = ServingMetrics(engine="paged")
+    _serve_stub(wire, engine, metrics)
+    by_rid = _assert_consecutive(wire)
+    want = {rid: _stub_words(b"hello world", cap) for rid, cap in caps.items()}
+    for rid, chunks in by_rid.items():
+        # every message is its tokens' texts concatenated, in order
+        for m in chunks:
+            assert m["text"] == "".join(
+                want[rid][m["seq"]:m["seq"] + m["n_tokens"]]
+            ), m
+        assert _n(chunks) == caps[rid]
+        assert [m["done"] for m in chunks] == [False] * (len(chunks) - 1) + [True]
+        assert chunks[-1]["finish"] == "length"
+        assert all("finish" not in m for m in chunks[:-1])
+    # THE flush: a first token and three streams' windows
+    flush = next(f for f in flushes if f and f[0]["request_id"] == "w-d")
+    assert [
+        (m["request_id"], m["seq"], m["n_tokens"], m["done"]) for m in flush
+    ] == [
+        ("w-d", 0, 1, False), ("w-a", 25, 8, False),
+        ("w-b", 17, 8, True), ("w-c", 9, 8, False),
+    ]
+    assert flush[2]["finish"] == "length"
+    assert flush[2]["text"] == "".join(want["w-b"][17:25])
+    # counters: tokens, the messages that carried them, the flushes
+    assert metrics.decode_tokens == sum(caps.values()) == 108
+    assert metrics.emit_messages == len(wire.sent) == 18
+    assert metrics.emit.count == launched[0] > 0
+    snap = metrics.snapshot()
+    assert snap["emit_messages"] == 18
+    assert snap["emit_us"]["count"] == launched[0]
+
+
+def test_a_lone_k1_stream_sends_the_messages_it_always_sent():
+    """One stream, K = 1: every flush holds one token, so the wire
+    carries what it carried before messages could hold several — seq
+    0, 1, 2, ..., one word each, ``done`` on the last."""
+    pytest.importorskip("jax")
+    from dora_tpu.models.batch_engine import make_stub_paged_engine
+
+    engine = make_stub_paged_engine(max_slots=1, window=1, max_seq=128)
+    wire = _Wire([(0, _req("w-a", 12))])
+    metrics = ServingMetrics(engine="paged")
+    _serve_stub(wire, engine, metrics)
+    assert [m["seq"] for m in wire.sent] == list(range(12))
+    assert [m["n_tokens"] for m in wire.sent] == [1] * 12
+    assert [m["text"] for m in wire.sent] == _stub_words(b"hello world", 12)
+    assert [m["done"] for m in wire.sent] == [False] * 11 + [True]
+    assert metrics.decode_tokens == metrics.emit_messages == 12
 
 
 # ---------------------------------------------------------------------------
@@ -558,9 +730,12 @@ def test_dispatch_collect_under_the_loop_equals_step_in_a_loop(tiny, variant):
         ),
     )
 
-    def emit(key, tok, done):
-        got.setdefault(key, []).append((int(tok), bool(done)))
-        beside[0] += engine.in_flight
+    def emit(key, toks, done):
+        got.setdefault(key, []).extend(
+            (int(tok), bool(done) and i == len(toks) - 1)
+            for i, tok in enumerate(toks)
+        )
+        beside[0] += len(toks) * engine.in_flight
 
     def handle_input(event):
         key = event["metadata"]["request_id"]

@@ -134,6 +134,113 @@ def test_concurrent_streams_route_by_request_id(tmp_path):
     assert "concurrent routing ok" in (log_dir / "log_driver.txt").read_text()
 
 
+def test_a_response_of_several_tokens_is_one_sse_delta(tmp_path):
+    """``llm_server`` sends one ``response`` message per stream per
+    flush, holding every token of a window. The HTTP front writes one
+    SSE event a message, whatever it holds: a 24-character message is
+    ONE delta of 24 characters, and ``seq`` / ``n_tokens`` ride along
+    without changing that."""
+    responder = tmp_path / "windows.py"
+    responder.write_text(textwrap.dedent("""
+        import pyarrow as pa
+
+        from dora_tpu.node import Node
+
+        with Node() as node:
+            for event in node:
+                if event["type"] == "STOP":
+                    break
+                if event["type"] != "INPUT":
+                    continue
+                rid = (event["metadata"] or {})["request_id"]
+                # a first token alone, a window of eight, the last five
+                for seq, n, done in ((0, 1, False), (1, 8, False),
+                                     (9, 5, True)):
+                    text = "".join(
+                        f"t{i:02d}" for i in range(seq, seq + n)
+                    )
+                    meta = {"request_id": rid, "done": done, "seq": seq,
+                            "n_tokens": n}
+                    if done:
+                        meta["finish"] = "length"
+                    node.send_output("reply", pa.array([text]), meta)
+    """))
+    driver = tmp_path / "driver.py"
+    driver.write_text(textwrap.dedent("""
+        import json
+        import time
+        import urllib.request
+
+        from dora_tpu.node import Node
+
+        node = Node()
+        time.sleep(0.5)
+        body = json.dumps({
+            "stream": True,
+            "messages": [{"role": "user", "content": "go"}],
+        }).encode()
+        req = urllib.request.Request(
+            "http://127.0.0.1:8137/v1/chat/completions",
+            data=body, headers={"Content-Type": "application/json"},
+        )
+        for attempt in range(40):
+            try:
+                with urllib.request.urlopen(req, timeout=30) as r:
+                    raw = r.read().decode()
+                break
+            except Exception:
+                time.sleep(0.25)
+        choices = [
+            json.loads(line[6:])["choices"][0]
+            for line in raw.splitlines()
+            if line.startswith("data: ") and line != "data: [DONE]"
+        ]
+        contents = [
+            c["delta"]["content"] for c in choices
+            if c["delta"].get("content")
+        ]
+        want = ["".join(f"t{i:02d}" for i in range(a, b))
+                for a, b in ((0, 1), (1, 9), (9, 14))]
+        assert contents == want, contents
+        assert [len(c) for c in contents] == [3, 24, 15]
+        assert [c["finish_reason"] for c in choices
+                if c.get("finish_reason")] == ["length"]
+        print("one delta a message ok")
+        node.close()
+    """))
+    spec = {
+        "nodes": [
+            {
+                "id": "api",
+                "path": "module:dora_tpu.nodehub.openai_server",
+                "outputs": ["text"],
+                "inputs": {"response": "windows/reply"},
+                "env": {
+                    "PORT": "8137",
+                    "MAX_REQUESTS": "1",
+                    "DORA_OPENAI_CONCURRENT": "1",
+                    "RESPONSE_TIMEOUT": "60",
+                },
+            },
+            {
+                "id": "windows",
+                "path": "windows.py",
+                "inputs": {"text": "api/text"},
+                "outputs": ["reply"],
+            },
+            {"id": "driver", "path": "driver.py"},
+        ]
+    }
+    df = tmp_path / "dataflow.yml"
+    df.write_text(yaml.safe_dump(spec))
+    result = run_dataflow(df, timeout_s=180)
+    assert result.is_ok(), result.errors()
+    log_dir = next((tmp_path / "out").iterdir())
+    assert "one delta a message ok" in (
+        log_dir / "log_driver.txt"
+    ).read_text()
+
+
 @pytest.fixture(scope="module")
 def tiny_checkpoint(tmp_path_factory):
     from transformers import Qwen2Config, Qwen2ForCausalLM

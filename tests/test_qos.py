@@ -211,9 +211,11 @@ class _Node:
         return None
 
     def send_output(self, output_id, value, metadata=None):
-        self.sent.append(
-            (time.monotonic(), output_id, dict(metadata or {}))
-        )
+        # the message's text rides in the captured metadata as "text"
+        self.sent.append((
+            time.monotonic(), output_id,
+            dict(metadata or {}, text=value.to_pylist()[0]),
+        ))
 
     def report_serving(self, snapshot):
         pass
@@ -242,14 +244,17 @@ def _serve(engine, events) -> tuple[_Node, ServingMetrics]:
 
 
 def _streams(node: _Node) -> dict[str, dict]:
-    """Per-wire-id view: first-chunk time, token texts, final meta."""
+    """Per-wire-id view: first-chunk time, each message's (seq,
+    n_tokens), final meta. A message's ``seq`` is the number of the
+    request's tokens sent before it."""
     out: dict[str, dict] = {}
     for ts, _oid, meta in node.sent:
         rid = meta.get("request_id")
         if rid is None:
             continue
         s = out.setdefault(rid, {"t0": ts, "seqs": [], "final": None})
-        s["seqs"].append(meta.get("seq"))
+        assert meta["seq"] == sum(n for _s, n in s["seqs"]), (rid, meta)
+        s["seqs"].append((meta["seq"], meta["n_tokens"]))
         if meta.get("done"):
             s["final"] = meta
     return out
@@ -257,11 +262,14 @@ def _streams(node: _Node) -> dict[str, dict]:
 
 def _tokens(node: _Node, rid: str) -> list[int]:
     """Emitted token values for ``rid`` parsed back out of the ' t<N>'
-    stub decode strings — identity comparisons key on these."""
+    stub decode strings — identity comparisons key on these. A message
+    holds ``n_tokens`` of them."""
     toks = []
     for _ts, _oid, meta in node.sent:
-        if meta.get("request_id") == rid and not meta.get("done"):
-            toks.append(meta["seq"])
+        if meta.get("request_id") == rid:
+            words = meta["text"].split()
+            assert len(words) == meta["n_tokens"], meta
+            toks += [int(w[1:]) for w in words]
     return toks
 
 
@@ -284,23 +292,22 @@ def test_preempted_stream_resumes_token_identical(
             max_slots=1, window=window, spec_k=spec_k, max_seq=128,
         )
 
-    def texts(node, rid):
-        return [
-            m.get("seq") for _t, _o, m in node.sent
-            if m.get("request_id") == rid and not m.get("done")
-        ]
+    def text(node, rid):
+        return "".join(
+            m["text"] for _t, _o, m in node.sent
+            if m.get("request_id") == rid
+        )
 
     # Reference: the batch request alone, QoS off.
     ref_node, _ = _serve(build(), [_req("w-b", "hello world", 24, "batch")])
-    ref = [
-        (m["seq"]) for _t, _o, m in ref_node.sent
-        if m.get("request_id") == "w-b" and not m.get("done")
-    ]
-    ref_text = "".join(
-        str(m.get("seq")) for _t, _o, m in ref_node.sent
-        if m.get("request_id") == "w-b"
-    )
-    assert ref  # the stub actually decoded something
+    ref = _tokens(ref_node, "w-b")
+    ref_text = text(ref_node, "w-b")
+    assert len(ref) == 24  # the stub actually decoded something
+    if spec_k == 0:
+        # one message a stream a flush: the first token, then a window's
+        assert [n for _s, n in _streams(ref_node)["w-b"]["seqs"]] == (
+            [1] * 24 if window == 1 else [1, 8, 8, 7]
+        )
 
     monkeypatch.setenv("DORA_QOS_PREEMPT", "1")
     node, metrics = _serve(
@@ -314,13 +321,13 @@ def test_preempted_stream_resumes_token_identical(
     assert streams["w-b"]["final"] is not None
     assert streams["w-i"]["final"] is not None
     assert metrics.preempted >= 1 and metrics.resumed >= 1
-    got_text = "".join(
-        str(m.get("seq")) for _t, _o, m in node.sent
-        if m.get("request_id") == "w-b"
-    )
-    assert got_text == ref_text  # seq-per-chunk identical => same stream
-    # Compare actual payload ordering too: chunk count and final reason.
-    assert len(texts(node, "w-b")) == len(texts(ref_node, "w-b"))
+    # Byte-identical text; the messages may be cut elsewhere (the
+    # preemption flushes, the resume sends a first token of its own),
+    # but their seq / n_tokens cover the same 24 tokens in order.
+    assert text(node, "w-b") == ref_text
+    assert _tokens(node, "w-b") == ref
+    last_seq, last_n = streams["w-b"]["seqs"][-1]
+    assert last_seq + last_n == 24
     assert streams["w-b"]["final"]["finish"] == \
         _streams(ref_node)["w-b"]["final"]["finish"]
 

@@ -29,9 +29,17 @@ def test_serving_snapshot_shape():
     m.host_dispatches = 16
     m.host_fetches = 12
     m.dispatch_gap.observe(700.0)
+    m.emit_messages = 8
+    m.emit.observe(300.0)
+    m.emit.observe(500.0)
     snap = m.snapshot()
     assert snap["engine"] == "paged"
     assert snap["decode_tokens"] == 40
+    # 40 tokens in 8 response messages: 5 tokens a message; the flush
+    # of one dispatch, beside a running window, took 300 and 500 us
+    assert snap["emit_messages"] == 8
+    assert snap["emit_us"]["count"] == 2 and snap["emit_us"]["sum_us"] == 800.0
+    assert ServingMetrics().snapshot()["emit_us"]["count"] == 0
     assert snap["ttft_us"]["count"] == 2
     assert snap["ttft_us"]["p50_us"] is not None
     # Round-trip amortization keys (multi-step window observability).
@@ -97,6 +105,9 @@ def test_render_serving_table_with_rates():
                     "dispatch_gap_us": {
                         "count": 30, "p50_us": 512.0, "p99_us": 4096.0,
                     },
+                    "emit_us": {
+                        "count": 29, "p50_us": 128.0, "p99_us": 2048.0,
+                    },
                     "fetch_us": {
                         "count": 28, "p50_us": 256.0, "p99_us": 1024.0,
                     },
@@ -113,6 +124,7 @@ def test_render_serving_table_with_rates():
     assert "TOK/DISP" in out and "5.0" in out  # tokens per dispatch
     assert "ACC%" in out and "65%" in out  # speculative acceptance rate
     assert "GAP P50" in out and "512µs" in out  # dispatch-gap histogram
+    assert "EMIT P50" in out and "128µs" in out  # one dispatch's flush
     assert "FETCH P50" in out and "256µs" in out  # fetch split from gap
     assert "COMPILES" in out and "6" in out  # xla compile audit counter
     # Page sparkline with peak + fragmentation gauges.
@@ -121,7 +133,8 @@ def test_render_serving_table_with_rates():
     assert "llm (paged)" in one_shot  # renders without watch deltas too
     # Snapshots predating the window metrics render with dashes.
     bare = snap(10)
-    for key in ("tokens_per_dispatch", "dispatch_gap_us", "fetch_us",
+    for key in ("tokens_per_dispatch", "dispatch_gap_us", "emit_us",
+                "fetch_us",
                 "used_pages", "peak_used_pages", "largest_contig_free",
                 "compiles", "spec_drafted", "spec_accepted",
                 "spec_acceptance"):
