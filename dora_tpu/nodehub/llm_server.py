@@ -110,6 +110,7 @@ MODEL_MODULES = {
     "kimi_k2": "kimi_k2",
     "deepseek_v3": "kimi_k2",
     "falcon_h1": "falcon_h1",
+    "ouro": "ouro",
 }
 
 
@@ -1687,7 +1688,7 @@ def main() -> None:
             return _stub_main()
         raise RuntimeError(
             "llm_server needs DORA_HF_CHECKPOINT (a Qwen2-family, "
-            "kimi_k2/deepseek_v3 or falcon_h1 safetensors directory; or "
+            "kimi_k2/deepseek_v3, falcon_h1 or ouro safetensors directory; or "
             "DORA_STUB_ENGINE=1 for the weight-free stub engine)"
         )
     max_seq = int(os.environ.get("DORA_MAX_SEQ", "2048"))

@@ -53,6 +53,14 @@ _LANE = 128
 #: of VMEM; half of it leaves the surrounding XLA fusions their room.
 _CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
+#: The batched decode kernel keeps ``wqkv`` and ``wo`` whole in VMEM.
+#: Inside the compiler's 16 MiB default scope that holds up to about
+#: this many bytes of them (5.5 MB at the 1.5B widths, whose program is
+#: compiled without a limit of its own, as it always was); wider models
+#: (16.8 MB at hidden 2048 with 16 K/V heads: 22.7 MB of scope, refused
+#: at the default) get the chunk kernel's budget.
+_BATCH_WEIGHTS_IN_DEFAULT_VMEM = 8 * 1024 * 1024
+
 
 def _rms(x_ref, w_ref, eps: float):
     """f32 RMSNorm of a [M, D] ref block against weight [1, D]."""
@@ -1148,6 +1156,9 @@ def attention_paged_batch_step(
     operands = [k_pool, v_pool]
     if kv_quant:
         operands += [k_scale, v_scale]
+    wide = {}
+    if wqkv.size + wo.size > _BATCH_WEIGHTS_IN_DEFAULT_VMEM:  # a byte each
+        wide["vmem_limit_bytes"] = _CHUNK_VMEM_LIMIT
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1163,7 +1174,7 @@ def attention_paged_batch_step(
             {9: 1, 10: 2, 11: 3, 12: 4} if kv_quant else {9: 1, 10: 2}
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",), **wide,
         ),
         interpret=_interpret(),
     )(

@@ -221,7 +221,16 @@ def _tiny_falcon(tmp_path):
     return falcon_h1.load(tmp_path / "ckpt", max_seq=64)
 
 
-_TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon}
+def _tiny_ouro(tmp_path):
+    from test_ouro import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import ouro
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return ouro.load(tmp_path / "ckpt", max_seq=64)
+
+
+_TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon, "ouro": _tiny_ouro}
 
 
 def _engine_programs(module_name, monkeypatch, tmp_path):
@@ -268,7 +277,7 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
     return seen
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1"])
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro"])
 def test_engine_programs_take_the_weights_as_arguments(
     module_name, monkeypatch, tmp_path
 ):
@@ -337,7 +346,7 @@ def test_kernels_take_the_stored_weight(kernel, m, k, n):
     assert _weight_copies(traced.jaxpr) == []
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1"])
+@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro"])
 def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
     """The same walk over the window and chunk programs as the engine
     jits them (tiny models: vocab 256 and 128 are no multiple of the
@@ -348,6 +357,40 @@ def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
             traced = jax.make_jaxpr(jitted)(*shapes)
             assert "pallas_call" in str(traced)
             assert _weight_copies(traced.jaxpr) == [], jitted
+
+
+def _pool_writers(jaxpr, shape) -> list[str]:
+    """Equations of ``jaxpr`` (sub-programs included) that produce an
+    array of a page pool's ``shape`` and are neither a ``pallas_call``
+    (which aliases the pool in and out) nor control flow that carries it
+    (walked instead): a scatter, a ``dynamic_update_slice``, a
+    ``select`` or a ``copy`` of the whole pool."""
+    found = []
+    for eqn in jaxpr.eqns:
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if subs:
+            for sub in subs:
+                found += _pool_writers(sub, shape)
+        elif any(getattr(v.aval, "shape", None) == shape for v in eqn.outvars):
+            found.append(eqn.primitive.name)
+    return found
+
+
+@pytest.mark.parametrize("module_name", ["qwen2", "ouro"])
+def test_engine_programs_copy_no_pool(module_name, monkeypatch, tmp_path):
+    """Between the donated pools and the kernels that alias them nothing
+    makes a pool-sized array: not in the window's scan, and not in the
+    looped model's pass loop inside it, whose pools are ``passes`` deep
+    and ride two carries."""
+    seen = _engine_programs(module_name, monkeypatch, tmp_path)
+    for jitted, (shapes, took) in seen.items():
+        if took:
+            pool = shapes[2]["0"]["k"].shape  # params, ids / tokens, pools
+            traced = jax.make_jaxpr(jitted)(*shapes)
+            assert "pallas_call" in str(traced)
+            assert _pool_writers(traced.jaxpr, pool) == [], jitted
 
 
 # -- the smoke test's parent stays off JAX ----------------------------------

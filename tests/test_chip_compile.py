@@ -498,3 +498,88 @@ def test_falcon_h1_chunk_program_compiles_and_moves_no_cache(chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _whole_array_copies(compiled, pools, state) == []
+
+
+# -- Ouro-2.6B: the layers run four times, the pool four pools deep -----------
+
+#: the catalog row's widths (benchmark/configs/ouro-2p6b.json), depth 2:
+#: hidden 2048, 16 query = 16 K/V heads of 128, ffn 5632, vocabulary 49152,
+#: 4 passes; the pool of the cell, 384 pages.
+OURO = dict(
+    model_type="ouro", hidden_size=2048, num_attention_heads=16,
+    num_key_value_heads=16, head_dim=128, intermediate_size=5632,
+    num_hidden_layers=2, vocab_size=49152, rms_norm_eps=1e-6, rope_theta=1e6,
+    total_ut_steps=4, early_exit_threshold=1,
+)
+OURO_PAGES = 384
+
+
+def _ouro():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool and counter shapes)."""
+    from dora_tpu.models.hf import ouro
+
+    cfg = ouro.OuroConfig.from_hf(OURO, 2048)
+    bf = jnp.bfloat16
+    d, q = cfg.dim, cfg.heads * cfg.head_dim
+    shapes = {
+        "self_attn.q_proj.weight": (q, d), "self_attn.k_proj.weight": (q, d),
+        "self_attn.v_proj.weight": (q, d), "self_attn.o_proj.weight": (d, q),
+        "mlp.gate_proj.weight": (cfg.ffn, d), "mlp.up_proj.weight": (cfg.ffn, d),
+        "mlp.down_proj.weight": (d, cfg.ffn),
+        "embed_tokens.weight": (cfg.vocab, d), "lm_head.weight": (cfg.vocab, d),
+        "early_exit_gate.weight": (1, d), "early_exit_gate.bias": (1,),
+    }
+
+    def get(name):
+        tail = name.removeprefix("model.")
+        if tail.startswith("layers."):
+            tail = tail.split(".", 2)[2]
+        return jnp.zeros(shapes.get(tail, (d,)), bf)  # what is left: the norms
+
+    params = jax.eval_shape(lambda: ouro.map_params(
+        get, lambda name: not name.endswith(".bias") or "gate" in name, cfg))
+    pools = jax.eval_shape(lambda: ouro.init_page_pool(cfg, OURO_PAGES, PAGE))
+    stats = jax.eval_shape(ouro.init_counters)
+    return ouro, cfg, params, pools, stats
+
+
+def test_ouro_window_program_compiles_and_updates_the_pool_in_place(chip):
+    """The K=8 decode window with the pass loop inside it, at the
+    published widths: the batched attention kernel keeps 16.8 MB of int8
+    ``wqkv`` and ``wo`` in VMEM (22.7 MB of scope: refused at the
+    compiler's 16 MiB default, hence the kernel's own limit above 8 MB
+    of weights), its page groups are 16 K/V heads wide, and the pools,
+    four pools deep, ride two loop carries without a copy."""
+    ouro, cfg, params, pools, stats = _ouro()
+    assert pools["0"]["k"].shape == (4 * OURO_PAGES, 16, PAGE, 128)
+    blk = params["blocks"]["0"]
+    assert blk["wqkv"]["int8"].size + blk["wo"]["int8"].size == 16_777_216  # int8
+
+    def program(p, *args):
+        return ouro.window_program(p, cfg, 8, None, *args)
+
+    compiled = jax.jit(program, donate_argnums=(2, 3)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, MAX_PAGES), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools) // 8
+
+
+def test_ouro_chunk_program_compiles_and_updates_the_pool_in_place(chip):
+    """The 256-row prefill chunk, four passes of the fused chunk kernels
+    with ``residual=False`` and float32 sandwich norms between them."""
+    ouro, cfg, params, pools, stats = _ouro()
+    compiled = jax.jit(
+        lambda p, *a: ouro.fused_paged_chunk_step(p, cfg, *a),
+        donate_argnums=(2, 3),
+    ).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((MAX_PAGES,), I32), _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)
