@@ -88,7 +88,7 @@ NOT_OFFERED = {
 }
 
 COUNTERS = ("passes", "kv_rows_read", "decode_ticks", "chunk_rows", "chunks",
-            "chunk_positions", "exit_before_last")
+            "chunk_positions", "exit_before_last", "sweep_groups")
 
 
 @dataclass(frozen=True)
@@ -373,10 +373,16 @@ def paged_batch_rows(params, cfg: OuroConfig, tokens, pools, stats, positions,
         params, cfg, x, pools, block_tables, attend)
     live = block_tables[:, 0] != 0
     n_live = live.sum(dtype=jnp.int32)
+    # the (row, group) steps the decode kernel's sweep schedules: a
+    # group is DB's page group of cache rows, the current token is none
+    group = DB.sweep_group_rows(
+        next(iter(pools.values()))["k"].shape[2], block_tables.shape[1])
     stats = _count(
         stats, passes=cfg.passes * n_live,
         kv_rows_read=cfg.passes * jnp.where(live, positions + 1, 0).sum(
             dtype=jnp.int32),
+        sweep_groups=cfg.passes * jnp.where(
+            live, (positions + group - 1) // group, 0).sum(dtype=jnp.int32),
         decode_ticks=(n_live > 0).astype(jnp.int32),
         exit_before_last=(live & before_last).sum(dtype=jnp.int32))
     return raw, h, pools, stats, lambdas
@@ -539,7 +545,7 @@ def init_counters() -> dict:
 class LoopCounters:
     """The loop's counters of one engine: the device arrays the two
     programs take and give back (``device``) and their host side.
-    :meth:`read` fetches seven scalars; ``llm_server``'s 1 Hz report
+    :meth:`read` fetches eight scalars; ``llm_server``'s 1 Hz report
     calls it at a window boundary, after ``collect()``."""
 
     def __init__(self, cfg: OuroConfig, page_size: int):

@@ -681,6 +681,24 @@ def attention_chunk_step(
 # boundaries, and from kernel entry, before this tick's q exists. The
 # chunk and spec kernels below still fetch one page, wait, and multiply
 # (ROADMAP design debt 3).
+#
+# What a step does with its group has two forms, chosen from the shape
+# the kernel is traced with and nothing else: ``rows``, the query rows a
+# K/V head serves (``heads // kv_heads`` x m). With several rows (Qwen,
+# 12/2 heads: 6) it is two MXU products a K/V head, ``[rows, hd]`` x
+# ``[hd, 128]`` and ``[rows, 128]`` x ``[128, hd]``. With ONE row (no
+# head grouping: Ouro, 16/16) those products would each load a 128 x 128
+# stationary tile to push a single row through it, 2 x KV of them a
+# group, so the step is ONE vector pass over all K/V heads of the group
+# instead: scores as the keys times q broadcast over the group's rows,
+# summed along ``hd`` — which leaves them one per cache row, the
+# orientation the value mix wants (weights broadcast along ``hd``,
+# summed over the rows) — and the softmax state of the whole row loaded
+# and stored once. bf16 x bf16 is exact in float32, so this form only
+# reorders float32 sums. Kernel alone on the v5e at Ouro's widths (16
+# heads of 128, 1 MB of K/V a group; PERF.md section 6, PR 38): 2.60 us
+# a group as products, 1.51 as the vector pass, 1.40 for the group's
+# copies with no arithmetic at all.
 
 #: Cache rows (columns of the score tile) one sweep step covers.
 _SWEEP_COLS = 128
@@ -689,7 +707,10 @@ _SWEEP_COLS = 128
 #: group ahead is enough on the v5e: 2, 3 and 4 slots measured the same
 #: to 1 % at 4, 15 and 16 live rows (PERF.md section 6, PR 28) — a step
 #: is bound by issuing its copies and by its own products, not by their
-#: latency.
+#: latency. At 16 K/V heads a group is 1 MB and the step is bound by its
+#: bytes: 3 and 4 slots measured 1.45 and 1.44 us a group against 1.51
+#: with 2, where the copies alone take 1.40 (PR 38): 4 % of a step, not
+#: taken.
 _SWEEP_SLOTS = 2
 
 
@@ -697,6 +718,11 @@ def _sweep_pages(page: int, max_pages: int) -> int:
     """Pages per sweep group: one ``_SWEEP_COLS``-wide tile's worth, never
     more than a block table holds."""
     return max(1, min(_SWEEP_COLS // page, max_pages))
+
+
+def sweep_group_rows(page: int, max_pages: int) -> int:
+    """Cache rows one (row, group) step of the sweep covers."""
+    return _sweep_pages(page, max_pages) * page
 
 
 def _sweep_scratch(batch, max_pages, kv_heads, rows, head_dim, page,
@@ -751,7 +777,13 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
     of step t+SLOTS-1 (into the slot step t-1 just released: the next
     group of this row or the first of the next live row), waits for its
     own, then does one score product, one online-softmax update and one
-    value product per kv head over the whole group. Columns at or past
+    value product per kv head over the whole group — or, where a kv
+    head serves ONE query row (``rows == 1``, a traced shape), one
+    vector pass over all kv heads of the group: scores ``[KV, cols, 1]``
+    from keys x q summed along ``hd``, one softmax update of the row's
+    whole ``[KV, 1, 1]`` / ``[KV, 1, hd]`` state, the mix summed over
+    the group's rows; same operands (bf16, products exact in float32),
+    same float32 sums in another order. Columns at or past
     the position are masked in ``s`` and again in ``p``. ``run()``
     leaves (max, sum, accumulator) of every row in the state refs, which
     it returns; the caller folds in what it holds in registers.
@@ -762,6 +794,7 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
     sbuf = bufs[2] if kv_quant else None
     slots, kv_heads, cols, _ = kbuf.shape
     gp = cols // page
+    rows = q_ref.shape[2]
 
     def nblocks(b):  # prior context in pages, the partial one included
         return (pos_ref[b] + page - 1) // page
@@ -805,12 +838,41 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
     for t in range(slots - 1):
         pl.when(t < total)(functools.partial(copies, t, "start"))
 
+    def one_row_heads(b, g, slot):
+        """The step's arithmetic where a kv head serves one query row:
+        every array is ``[KV, cols, .]``, cache rows along sublanes."""
+        f32 = jnp.float32
+        if kv_quant:
+            k = kv_dequant(kbuf[slot], sbuf[slot, 0], dtype)
+            v = kv_dequant(vbuf[slot], sbuf[slot, 1], dtype)
+        else:
+            k, v = kbuf[slot].astype(dtype), vbuf[slot].astype(dtype)
+        live = (
+            jax.lax.broadcasted_iota(jnp.int32, (1, cols, 1), 1) + g * cols
+        ) < pos_ref[b]
+        s = jnp.sum(
+            k.astype(f32) * q_ref[b].astype(f32), axis=-1, keepdims=True
+        ) * scale  # [KV, cols, 1]
+        s = jnp.where(live, s, -jnp.inf)
+        m_old = m_ref[b]  # [KV, 1, 1]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        m_ref[b] = m_new
+        l_ref[b] = l_ref[b] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[b] = acc_ref[b] * alpha + jnp.sum(
+            p.astype(dtype).astype(f32) * v.astype(f32), axis=1, keepdims=True
+        )  # [KV, 1, hd]
+
     def step(t, carry):
         ahead = t + slots - 1
         pl.when(ahead < total)(functools.partial(copies, ahead, "start"))
         copies(t, "wait")
         b, g = row_ref[t], grp_ref[t]
         slot = jax.lax.rem(t, slots)
+        if rows == 1:
+            one_row_heads(b, g, slot)
+            return carry
         live = (
             jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) + g * cols
         ) < pos_ref[b]
@@ -868,7 +930,9 @@ def _attn_paged_batch_kernel(
     registers, the write-backs started (waited for at the very end);
     (4) the sweep: groups of 8 pages (128 cache rows at page 16) in
     two slots, one group ahead, one score and one value product per
-    kv head per group; (5) each row's current token folded in from
+    kv head per group, or one vector pass over all kv heads of the
+    group where ``heads == kv_heads`` leaves a kv head one query row
+    (:func:`_paged_sweep`); (5) each row's current token folded in from
     registers (it never round-trips the pool within its own step), the
     output projection and the residual.
 
@@ -1062,7 +1126,10 @@ def attention_paged_batch_step(
     cache rows, at page 16) in ``_SWEEP_SLOTS`` = 2 buffers of
     [KV, 128, hd] each for K and V; only (row, group) pairs below a
     row's position are scheduled, so a frozen row (position 0) costs no
-    step and a 20-token row one. In flight at any time: from kernel
+    step and a 20-token row one. A step multiplies its group on the
+    MXU, two products a kv head, unless a kv head serves one query row
+    (``heads == kv_heads``): then all heads of the group go through one
+    vector pass. In flight at any time: from kernel
     entry, every row's 8-row write window and the first group; during
     the sweep, the group after the one being multiplied (the next row's
     first when a row ends); from the insert of the current token to the

@@ -154,6 +154,29 @@ def test_paged_batch_step_compiles(chip, kv_int8):
     )
 
 
+def _compile_batch_attention(chip, slots, blk, pool, bqkv, **shape):
+    """``attention_paged_batch_step`` alone, ``slots`` rows over tables
+    of ``MAX_PAGES`` pages, for the described chip."""
+    from dora_tpu.ops import decode_block as DB
+
+    hd = shape["head_dim"]
+
+    def step(x, blk, bqkv, cos, sin, kp, vp, positions, tables):
+        w, o = blk["wqkv"], blk["wo"]
+        return DB.attention_paged_batch_step(
+            x, blk["attn_norm"], w["int8"], w["scale"], bqkv, cos, sin,
+            kp, vp, o["int8"], o["scale"], positions, tables, **shape,
+        )
+
+    _compile(
+        step,
+        *chip((_s((slots, blk["wqkv"]["int8"].shape[0]), jnp.bfloat16), blk,
+               bqkv, _s((slots, hd), jnp.float32), _s((slots, hd), jnp.float32),
+               pool["k"], pool["v"], _s((slots,), I32),
+               _s((slots, MAX_PAGES), I32))),
+    )
+
+
 @pytest.mark.parametrize("slots", [SLOTS, 8])
 def test_paged_batch_attention_compiles_with_its_group_buffers(chip, slots):
     """The decode attention kernel alone at the serve cells' shape (16
@@ -165,7 +188,6 @@ def test_paged_batch_attention_compiles_with_its_group_buffers(chip, slots):
     from dora_tpu.ops import decode_block as DB
 
     blk = _qparams(CFG)["blocks"]["0"]
-    pool = _pools(False)["0"]
     hd = CFG.head_dim
     assert DB._sweep_pages(PAGE, MAX_PAGES) * PAGE == 128
     scratch = DB._sweep_scratch(
@@ -173,23 +195,9 @@ def test_paged_batch_attention_compiles_with_its_group_buffers(chip, slots):
         PAGE, jnp.bfloat16, jnp.bfloat16, False)
     assert scratch[0].shape == (DB._SWEEP_SLOTS, CFG.kv_heads, 128, hd)
     assert scratch[3].shape == (slots * MAX_PAGES // 8,)  # step -> row
-
-    def step(x, blk, cos, sin, kp, vp, positions, tables):
-        w, o = blk["wqkv"], blk["wo"]
-        return DB.attention_paged_batch_step(
-            x, blk["attn_norm"], w["int8"], w["scale"], blk["bqkv"], cos,
-            sin, kp, vp, o["int8"], o["scale"], positions, tables,
-            heads=CFG.heads, kv_heads=CFG.kv_heads, head_dim=hd,
-            eps=CFG.norm_eps,
-        )
-
-    _compile(
-        step,
-        *chip((_s((slots, CFG.dim), jnp.bfloat16), blk,
-               _s((slots, hd), jnp.float32), _s((slots, hd), jnp.float32),
-               pool["k"], pool["v"], _s((slots,), I32),
-               _s((slots, MAX_PAGES), I32))),
-    )
+    _compile_batch_attention(
+        chip, slots, blk, _pools(False)["0"], blk["bqkv"], heads=CFG.heads,
+        kv_heads=CFG.kv_heads, head_dim=hd, eps=CFG.norm_eps)
 
 
 @KV_KINDS
@@ -567,6 +575,31 @@ def test_ouro_window_program_compiles_and_updates_the_pool_in_place(chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools) // 8
+
+
+def test_paged_batch_attention_compiles_with_one_query_row_a_kv_head(chip):
+    """The decode attention kernel alone at Ouro's shape: 16 rows, 16
+    query = 16 K/V heads of 128, so a K/V head serves ONE query row and
+    the sweep's step is the vector pass over all 16 heads of a group
+    (``[16, 128, 128]`` products reduced along lanes for the scores and
+    along sublanes for the mix: layouts only Mosaic can refuse), with
+    group buffers of 2 x [16, 128, 128] for K and for V beside 16.8 MB
+    of weights; ``residual=False`` and a float32 result, as the looped
+    model calls it."""
+    from dora_tpu.ops import decode_block as DB
+
+    ouro, cfg, params, pools, _ = _ouro()
+    blk, hd = params["blocks"]["0"], cfg.head_dim
+    assert cfg.heads // cfg.kv_heads == 1
+    scratch = DB._sweep_scratch(
+        SLOTS, MAX_PAGES, cfg.kv_heads, 1, hd, PAGE, jnp.bfloat16,
+        jnp.bfloat16, False)
+    assert scratch[0].shape == (DB._SWEEP_SLOTS, 16, 128, hd)
+    assert scratch[-1].shape == (SLOTS, 16, 1, hd)  # accumulator: one row
+    _compile_batch_attention(
+        chip, SLOTS, blk, pools["0"],
+        _s(((cfg.heads + 2 * cfg.kv_heads) * hd,), jnp.float32),
+        **ouro._shape(cfg))
 
 
 def test_ouro_chunk_program_compiles_and_updates_the_pool_in_place(chip):

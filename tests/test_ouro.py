@@ -214,7 +214,38 @@ def test_chunked_prefill_then_decode_matches_the_reference(model):
         "passes": cfg.passes * WINDOW, "kv_rows_read": cfg.passes * rows_read,
         "decode_ticks": WINDOW, "chunk_rows": 41, "chunks": 3,
         "chunk_positions": 0 + 16 + 32, "exit_before_last": 0,
+        # a group is all 64 rows a table holds here: one step a tick
+        "sweep_groups": cfg.passes * WINDOW,
     }
+
+
+def test_sweep_groups_are_the_steps_the_decode_kernel_schedules(tmp_path):
+    """``loop_sweep_groups`` of one tick, counted by hand at the serving
+    geometry (pages of 16, so a group is 128 cache rows): a frozen row
+    holds no step, a row one step for every started 128 rows BEFORE its
+    position (its current token is folded in from registers), and every
+    pass walks the same schedule."""
+    from dora_tpu.ops import decode_block as DB
+
+    max_seq, page = 256, 16
+    hf = {**TINY, "max_position_embeddings": max_seq}
+    cfg, params = O.load(write_checkpoint(tmp_path / "ckpt", hf), max_seq=max_seq)
+    pages_a_slot = max_seq // page
+    assert DB.sweep_group_rows(page, pages_a_slot) == 128
+    #               frozen  first  last of a group, next group's first two, full
+    positions = np.asarray([0, 1, 127, 128, 129, max_seq - 1], np.int32)
+    steps = [0, 1, 1, 1, 2, 2]
+    bt = 1 + np.arange(SLOTS * pages_a_slot, dtype=np.int32).reshape(SLOTS, -1)
+    bt[0] = 0  # freeze_inactive's zeroed table row
+    pools = O.init_page_pool(cfg, SLOTS * pages_a_slot + 1, page)
+    _, batch_fn = programs_of(cfg)
+    *_, stats, _ = batch_fn(
+        params, jnp.ones((SLOTS,), jnp.int32), pools, O.init_counters(),
+        jnp.asarray(positions), jnp.asarray(bt))
+    stats = {k: int(v) for k, v in stats.items()}
+    assert stats["sweep_groups"] == cfg.passes * sum(steps) == 28
+    assert stats["passes"] == cfg.passes * 5 and stats["decode_ticks"] == 1
+    assert stats["kv_rows_read"] == cfg.passes * int((positions[1:] + 1).sum())
 
 
 @pytest.mark.parametrize("what_if", R.WHAT_IFS)
@@ -284,6 +315,8 @@ def test_concurrent_streams_of_unequal_length_pass_the_reference(model):
     report = engine.model_counters()
     assert report["loop_passes"] == cfg.passes * sum(
         new - 1 for _, new in lengths)  # the first token is the chunk's
+    # a group is the whole table here: one sweep step a live row a pass
+    assert report["loop_sweep_groups"] == report["loop_passes"]
     assert report["loop_chunk_rows"] == sum(p for p, _ in lengths)
     assert report["loop_exit_before_last"] == 0
     assert report["kv_bytes_per_token"] == cfg.passes * 3 * 2 * 4 * 16 * 4

@@ -24,10 +24,15 @@ import jax.numpy as jnp
 from dora_tpu.models import layers as L
 from dora_tpu.ops import decode_block as DB
 
-D, H, KV, HD, PAGE = 64, 4, 2, 16, 16
+D, HD, PAGE = 64, 16, 16
+#: (query heads, K/V heads): Qwen's grouping, two query rows a K/V head,
+#: and no grouping at all (Ouro), where a K/V head serves ONE query row
+#: and the sweep steps all heads of a group in one vector pass
+HEADS = pytest.mark.parametrize(
+    "heads", [(4, 2), (4, 4)], ids=["grouped_4_2", "ungrouped_4_4"])
 MAX_PAGES = 20  # not a multiple of the group: the last group is short
 SEQ = MAX_PAGES * PAGE
-GROUP = DB._sweep_pages(PAGE, MAX_PAGES) * PAGE  # cache rows a step covers
+GROUP = DB.sweep_group_rows(PAGE, MAX_PAGES)  # cache rows a step covers
 #: 0 = a frozen row's position; the last one fills every page of a row
 POSITIONS = (0, 1, PAGE - 1, PAGE, GROUP - 1, GROUP, GROUP + 1, SEQ - 1)
 #: what a page holds where no context was written: finite and far from it
@@ -41,9 +46,10 @@ def test_group_is_one_lane_tile_of_cache_rows():
     assert DB._SWEEP_SLOTS >= 2             # one group ahead at least
 
 
-def _weights(rng):
+def _weights(rng, heads):
     from dora_tpu.ops.int8_matmul import quantize_int8
 
+    H, KV = heads
     nw = jnp.asarray(rng.standard_normal(D), jnp.float32)
     wqkv = quantize_int8(jnp.asarray(
         rng.standard_normal((D, (H + 2 * KV) * HD)) * 0.2, jnp.float32))
@@ -53,7 +59,7 @@ def _weights(rng):
     return nw, wqkv, bqkv, wo
 
 
-def _setup(rng, positions, active, kv_int8):
+def _setup(rng, positions, active, kv_int8, KV):
     """Pools whose every row is stale except the live rows' contexts,
     block tables over shuffled page ids, and the operands of one tick."""
     batch = len(positions)
@@ -83,10 +89,11 @@ def _setup(rng, positions, active, kv_int8):
     return x, pools, (kf, vf), pos_in, bt_in
 
 
-def _reference(x, weights, kf, vf, positions, bt):
+def _reference(x, weights, kf, vf, positions, bt, heads):
     """Per-row attention in float64 numpy: the kernel's own projection
     formulas, then a plain softmax over the row's gathered context and
     its current token. Returns (x_out, k_new, v_new)."""
+    H, KV = heads
     nw, wqkv, bqkv, wo = weights
     x = np.asarray(x, np.float64)
     h = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * np.asarray(nw)
@@ -122,11 +129,13 @@ def _reference(x, weights, kf, vf, positions, bt):
     return out, k_new, v_new
 
 
-def _check(seed, positions, active, kv_int8):
+def _check(seed, positions, active, kv_int8, heads):
+    H, KV = heads
     rng = np.random.default_rng(seed)
-    weights = _weights(rng)
+    weights = _weights(rng, heads)
     nw, wqkv, bqkv, wo = weights
-    x, pools, (kf, vf), pos_in, bt_in = _setup(rng, positions, active, kv_int8)
+    x, pools, (kf, vf), pos_in, bt_in = _setup(
+        rng, positions, active, kv_int8, KV)
     cos_t, sin_t = L.rope_table(SEQ, HD)
     cosr, sinr = DB.rope_rows_at(cos_t, sin_t, pos_in)
     out = DB.attention_paged_batch_step(
@@ -135,7 +144,8 @@ def _check(seed, positions, active, kv_int8):
         *pools[2:], heads=H, kv_heads=KV, head_dim=HD,
     )
     pos_np, bt_np = np.asarray(pos_in), np.asarray(bt_in)
-    want, k_new, v_new = _reference(x, weights, kf, vf, pos_np, bt_np)
+    want, k_new, v_new = _reference(
+        x, weights, kf, vf, pos_np, bt_np, heads)
     got = np.asarray(out[0])
     assert np.isfinite(got).all()
     # float32 sums in another order; one stale row let in moves it by 1e-1
@@ -173,15 +183,18 @@ KV_KINDS = pytest.mark.parametrize(
     "kv_int8", [False, True], ids=["fp_kv", "int8_kv"])
 
 
+@HEADS
 @KV_KINDS
 @pytest.mark.parametrize("pos", POSITIONS)
-def test_one_row_matches_plain_attention(pos, kv_int8):
-    _check(100 + pos, [pos], [pos > 0], kv_int8)
+def test_one_row_matches_plain_attention(pos, kv_int8, heads):
+    _check(100 + pos, [pos], [pos > 0], kv_int8, heads)
 
 
+@HEADS
 @KV_KINDS
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_sixteen_rows_live_and_frozen_match_plain_attention(seed, kv_int8):
+def test_sixteen_rows_live_and_frozen_match_plain_attention(
+        seed, kv_int8, heads):
     """Every boundary position twice over 16 rows in a seeded order, five
     of them frozen mid-life (their positions and tables zeroed by
     ``freeze_inactive``, as a decode window does the tick they finish):
@@ -191,4 +204,4 @@ def test_sixteen_rows_live_and_frozen_match_plain_attention(seed, kv_int8):
     positions = rng.permutation(np.repeat(POSITIONS, 2)).tolist()
     active = np.ones(16, bool)
     active[rng.choice(16, size=5, replace=False)] = False
-    _check(seed, positions, active.tolist(), kv_int8)
+    _check(seed, positions, active.tolist(), kv_int8, heads)
