@@ -21,6 +21,8 @@ from __future__ import annotations
 
 from typing import Any
 
+from dora_tpu import telemetry
+
 #: Histogram buckets are powers of two in microseconds: bucket ``i``
 #: holds values in [2^(i-1), 2^i) µs; bucket 0 holds < 1 µs. 27 buckets
 #: span 1 µs .. ~67 s, which covers everything from a shmem splice to a
@@ -196,7 +198,7 @@ class ServingMetrics:
         "kv_dtype", "kv_pool_bytes", "kv_quant_err",
         "lora_resident", "lora_max_resident", "lora_resident_bytes",
         "lora_loads", "lora_evictions", "adapter_streams",
-        "adapter_stalls", "model", "capture_counters",
+        "adapter_stalls", "model", "capture_counters", "phases",
     )
 
     def __init__(self, engine: str = "paged"):
@@ -218,6 +220,13 @@ class ServingMetrics:
         #: emit side of a period's max(device, emit). The device waits
         #: for the host wherever this outlasts the window.
         self.emit = Histogram()
+        #: the serving loop's phases (telemetry.LOOP_PHASES), one
+        #: histogram a row of that table, fed by ServingTracer as each
+        #: phase is left: what ``dispatch_gap`` and ``emit`` are made
+        #: of. Top-level snapshot keys ``phase_<phase>_us``; read by the
+        #: benchmark and the server's exit line, and deliberately not by
+        #: prom / alerts / metrics_history / the CLI views.
+        self.phases = {name: Histogram() for name in telemetry.LOOP_PHASES}
         #: blocking device->host fetch durations (the sync points:
         #: chunk greedy reads, the [B, K+1] window matrix), observed by
         #: the engine via its ``serving_metrics`` hook
@@ -479,7 +488,14 @@ class ServingMetrics:
             "adapter_streams": dict(self.adapter_streams),
             "adapter_stalls": self.adapter_stalls,
             "capture_counters": dict(self.capture_counters),
+            **self.phase_snapshots(),
             **self.model,
+        }
+
+    def phase_snapshots(self) -> dict:
+        return {
+            telemetry.phase_histogram_key(name): h.snapshot()
+            for name, h in self.phases.items()
         }
 
 

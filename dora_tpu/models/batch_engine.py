@@ -28,7 +28,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
-from dora_tpu import profiling
+from dora_tpu import profiling, telemetry
 
 
 class PageAllocator:
@@ -413,16 +413,18 @@ class PagedBatchEngine:
         #: (round-trip accounting behind tokens_per_dispatch)
         self.dispatches = 0
         self.fetches = 0
-        #: observability hooks the serving node attaches after
-        #: construction: ``tracer`` is a telemetry.ServingTracer
-        #: (request-lifecycle spans through the flight recorder),
-        #: ``serving_metrics`` a metrics.ServingMetrics (fetch/grant
-        #: histograms). None everywhere else — one attribute check per
-        #: hook site on the step path.
-        self.tracer = None
+        #: observability hooks: ``tracer`` is a telemetry.ServingTracer
+        #: (request-lifecycle spans through the flight recorder, and
+        #: the step path's loop phases, whose stamps are the step
+        #: path's clock) — the serving node replaces this sink-less one
+        #: with the one it shares with its loop; ``serving_metrics`` a
+        #: metrics.ServingMetrics (fetch/grant histograms), None until
+        #: the serving node attaches one.
+        self.tracer = telemetry.ServingTracer()
         self.serving_metrics = None
         #: the window :meth:`dispatch` launched and :meth:`collect` has
-        #: not fetched yet: ``(mat, launch start, launch end)``. While
+        #: not fetched yet: ``(mat, launch start, launch end)`` (the
+        #: end only under ``device_monitor``). While
         #: it is set the device-carried state (``tokens``, ``positions``,
         #: ``pools`` …) is one window AHEAD of the host slots, so every
         #: reader of both (checkpoint, preempt, drain) runs only after
@@ -639,7 +641,7 @@ class PagedBatchEngine:
         if self.serving_metrics is not None:
             g = self.serving_metrics.grant_pages
             g[len(pages)] = g.get(len(pages), 0) + 1
-        if self.tracer is not None:
+        if self.tracer.active:
             if base0:
                 self.tracer.span(
                     "s_prefix_hit", request_id,
@@ -811,7 +813,7 @@ class PagedBatchEngine:
         self._free_slot(b)
         if self.serving_metrics is not None:
             self.serving_metrics.preempted += 1
-        if self.tracer is not None:
+        if self.tracer.active:
             self.tracer.span(
                 "s_preempt", request_id,
                 f"slot={b} pages={meta['pages']} emitted={meta['emitted']}",
@@ -884,9 +886,13 @@ class PagedBatchEngine:
         np = self._np
         emitted: list[tuple[str, int, bool]] = []
         sm = self.serving_metrics
+        # The step path's phases (telemetry.LOOP_PHASES): each begins
+        # where the one before ends, on one clock read, and the stamps
+        # the counters below need are the phases' own.
+        tracer = self.tracer
 
         if self._prefillq:
-            t_chunk = time.perf_counter()
+            t_chunk = tracer.switch("chunk_launch")
             b = self._prefillq[0]
             s = self.slots[b]
             base = s.chunk_base
@@ -916,22 +922,16 @@ class PagedBatchEngine:
             )
             if state:
                 (self.slot_state,) = state
-            t_disp = time.perf_counter()
             s.chunk_base = base + self.chunk
             self.chunks_run += 1
             self.dispatches += 1
             if self.device_monitor:
-                self.host_dispatch_ns += int((t_disp - t_chunk) * 1e9)
+                self.host_dispatch_ns += int((tracer.clock() - t_chunk) * 1e9)
                 if self.flops_per_token:
                     self.dispatched_flops += self.chunk * self.flops_per_token
                     self.useful_flops += (
                         min(self.chunk, s.true_len - base)
                         * self.flops_per_token
-                    )
-                if self.tracer is not None:
-                    self.tracer.span(
-                        "s_dev_dispatch", "chunk",
-                        dur_ns=int((t_disp - t_chunk) * 1e9),
                     )
             final_chunk = s.chunk_base >= s.true_len
             if final_chunk:  # final chunk: stream starts
@@ -952,29 +952,19 @@ class PagedBatchEngine:
                 # Host-index AFTER a full [C] fetch — a device gather at
                 # a python index would compile one slice per distinct
                 # prompt-length remainder.
-                t_fetch = time.perf_counter()
+                t_fetch = tracer.enter("first_token_wait")
                 if self.device_monitor:
                     # Non-final chunks stay async (their device time
                     # surfaces as the next window's compute wait); the
                     # final chunk must block for its first token anyway,
                     # so split that wait into compute vs fetch here.
                     greedy.block_until_ready()
-                    t_ready = time.perf_counter()
+                    t_ready = tracer.clock()
                     self.device_compute_ns += int((t_ready - t_fetch) * 1e9)
-                    if self.tracer is not None:
-                        self.tracer.span(
-                            "s_dev_compute", "chunk",
-                            dur_ns=int((t_ready - t_fetch) * 1e9),
-                        )
                 token = int(np.asarray(greedy)[s.true_len - 1 - base])
-                t_first = time.perf_counter()
+                t_first = tracer.leave()
                 if self.device_monitor:
                     self.device_fetch_ns += int((t_first - t_ready) * 1e9)
-                    if self.tracer is not None:
-                        self.tracer.span(
-                            "s_dev_fetch", "chunk",
-                            dur_ns=int((t_first - t_ready) * 1e9),
-                        )
                 self.fetches += 1
                 if sm is not None:
                     sm.fetch_latency.observe((t_first - t_fetch) * 1e6)
@@ -997,18 +987,20 @@ class PagedBatchEngine:
                     )
                     self._members_dirty = True
                     self._bt_dirty = True
-            if self.tracer is not None:
+            if tracer.active:
                 # Non-final chunks are async dispatches, so the span is
                 # dispatch cost only; the final chunk's span includes
                 # its blocking first-token fetch.
-                self.tracer.span(
+                tracer.span(
                     "s_prefill_chunk", s.request_id,
                     f"base={base} chunk={self.chunk}"
                     + (" final" if final_chunk else ""),
-                    dur_ns=int((time.perf_counter() - t_chunk) * 1e9),
+                    dur_ns=int((tracer.clock() - t_chunk) * 1e9),
                 )
 
         if any(self._decode):
+            if self._members_dirty or self._bt_dirty:
+                tracer.switch("rebuild")
             if self._members_dirty:
                 # Membership changed at this boundary: rebuild the
                 # device-carried window state from the host slots. (No
@@ -1066,7 +1058,7 @@ class PagedBatchEngine:
                     self._bt * np.asarray(self._decode, np.int32)[:, None]
                 )
                 self._bt_dirty = False
-            t_win = time.perf_counter()
+            t_win = tracer.switch("window_launch")
             #: multi-tenant serving: adapter ids + the resident stack
             #: ride every dispatch as trailing traced operands (fixed
             #: shapes — churn rewrites stack contents, never the
@@ -1109,14 +1101,10 @@ class PagedBatchEngine:
                 if state:
                     (self.slot_state,) = state
             self.dispatches += 1
-            t_launched = time.perf_counter()
+            t_launched = t_win
             if self.device_monitor:
+                t_launched = tracer.clock()
                 self.host_dispatch_ns += int((t_launched - t_win) * 1e9)
-                if self.tracer is not None:
-                    self.tracer.span(
-                        "s_dev_dispatch", "window",
-                        dur_ns=int((t_launched - t_win) * 1e9),
-                    )
             self._flight = (mat, t_win, t_launched)
         return emitted
 
@@ -1132,7 +1120,8 @@ class PagedBatchEngine:
         sm = self.serving_metrics
         mat, t_win, t_launched = self._flight
         self._flight = None
-        t_fetch = time.perf_counter()
+        tracer = self.tracer
+        t_fetch = tracer.switch("window_wait")
         if self.device_monitor:
             # Block BEFORE the host read so compute and transfer
             # separate cleanly; np.asarray alone conflates them.
@@ -1140,23 +1129,13 @@ class PagedBatchEngine:
             # between dispatch() and here ran beside the window (an
             # upper bound where that outlasted it).
             mat.block_until_ready()
-            t_ready = time.perf_counter()
+            t_ready = tracer.clock()
             self.device_compute_ns += int((t_ready - t_launched) * 1e9)
-            if self.tracer is not None:
-                self.tracer.span(
-                    "s_dev_compute", "window",
-                    dur_ns=int((t_ready - t_launched) * 1e9),
-                )
         host = np.asarray(mat)  # ONE [B, K+1] device->host transfer
-        t_done = time.perf_counter()
+        t_done = tracer.switch("unpack")
         self.fetches += 1
         if self.device_monitor:
             self.device_fetch_ns += int((t_done - t_ready) * 1e9)
-            if self.tracer is not None:
-                self.tracer.span(
-                    "s_dev_fetch", "window",
-                    dur_ns=int((t_done - t_ready) * 1e9),
-                )
             if self.flops_per_token:
                 self.dispatched_flops += profiling.window_flops(
                     flops_per_token=self.flops_per_token,
@@ -1165,7 +1144,7 @@ class PagedBatchEngine:
                 )
         if sm is not None:
             sm.fetch_latency.observe((t_done - t_fetch) * 1e6)
-        if self.tracer is not None:
+        if tracer.active:
             # Span per decoding stream BEFORE the unpack loop frees
             # finished slots; all rows share the window's host span
             # (one dispatch serves them all).
@@ -1185,7 +1164,7 @@ class PagedBatchEngine:
                     n_emit, frozen = window_row_stats(
                         host[b], self.window
                     )
-                self.tracer.span(
+                tracer.span(
                     "s_decode_window", slot.request_id,
                     f"K={self.window} emitted={n_emit} "
                     f"frozen_at={frozen}",

@@ -92,11 +92,12 @@ Dataflow usage::
 from __future__ import annotations
 
 import os
+import sys
 import time
 
 import pyarrow as pa
 
-from dora_tpu import profiling
+from dora_tpu import profiling, telemetry
 from dora_tpu.metrics import percentile_from_counts
 from dora_tpu.node import Node
 
@@ -250,12 +251,16 @@ class AdmissionQueue:
     ``on_admit(key, waited_s)`` (optional) fires just before a parked
     request starts, with how long it sat in the backlog — the server
     feeds the ``backlog_wait`` histogram and the ``queued`` lifecycle
-    span from it."""
+    span from it.
+
+    ``tracer`` (a telemetry.ServingTracer) times the calls into the
+    engine's admission test as the loop phase ``admit.can_admit``."""
 
     def __init__(self, engine, start, on_admit=None, clock=time.monotonic,
                  qos: QosConfig | None = None, on_shed=None, preempt=None,
-                 on_stall=None):
+                 on_stall=None, tracer=None):
         self._engine = engine
+        self._tracer = tracer or telemetry.ServingTracer()
         self._start = start
         self._on_admit = on_admit
         self._clock = clock
@@ -360,7 +365,9 @@ class AdmissionQueue:
             if cls is None:
                 return
             key, ids, max_new, t_in, _dl, adapter = self._q[cls][0]
-            if not self._engine.can_admit(len(ids), max_new, adapter):
+            with self._tracer.phase("admit.can_admit"):
+                fits = self._engine.can_admit(len(ids), max_new, adapter)
+            if not fits:
                 if self._preempt is not None and self._preempt(cls):
                     continue  # a victim was evicted: re-score and retry
                 # Attribute the stall: "adapter_residency" means
@@ -369,9 +376,10 @@ class AdmissionQueue:
                 # reads as plain overload. Re-evaluated every drain
                 # (a capacity stall can become adapter-gated as pages
                 # free), but on_stall fires only on transitions.
-                reason = self._engine.admit_blocker(
-                    len(ids), max_new, adapter
-                ) or "capacity"
+                with self._tracer.phase("admit.can_admit"):
+                    reason = self._engine.admit_blocker(
+                        len(ids), max_new, adapter
+                    ) or "capacity"
                 if self._stall_reasons.get(key) != reason:
                     self._stall_reasons[key] = reason
                     if self._on_stall is not None:
@@ -426,7 +434,8 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
               report, clock=time.monotonic, on_tick=None, on_step=None,
               handle_migrate=None, handle_profile=None,
               on_engine_error=None, keep_alive=False,
-              fleet_tick=None, held: list | None = None) -> None:
+              fleet_tick=None, held: list | None = None,
+              tracer=None) -> None:
     """Window-granular serving loop, factored out of :func:`main` so
     tests can drive it with fake nodes/engines. Each iteration: drain
     the pending events, ``engine.dispatch()`` (one prefill chunk, then
@@ -458,9 +467,20 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
     window boundary, ``on_engine_error()`` fails in-flight requests
     before a step exception propagates. ``keep_alive`` parks instead of
     exiting when the input stream ends (migration targets wait for
-    handoffs until STOP)."""
+    handoffs until STOP).
+
+    A turn is tiled by the phases of ``telemetry.LOOP_PHASES``: the
+    loop ``tracer.switch()``es from one to the next here, the engine
+    does inside ``dispatch()`` and ``collect()`` (the tracer is the one
+    the engine holds, its clock this loop's), and the stamps that
+    ``dispatch_gap`` and ``emit`` are observed from are those switches'
+    own — so the gap's phases add up to the gap."""
     if held is None:
         held = []
+    if tracer is None:
+        tracer = telemetry.ServingTracer(clock=clock)
+        tracer.histograms = metrics.phases
+        engine.tracer = tracer
 
     def half(call):
         """Run one half of the engine's step; if it raises, every
@@ -468,99 +488,119 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
         try:
             return call()
         except Exception:
+            tracer.close()
             _flush(held, emit)
             if on_engine_error is not None:
                 on_engine_error()
             raise
 
     last_collect_end: float | None = None
-    report_last = clock()
-    while True:
-        if on_tick is not None and on_tick():
-            break
-        # Drain a BURST of pending events before the next window (the
-        # first recv parks when the engine is idle; the rest only
-        # poll). One recv per step would cap intake at one request per
-        # dispatch — under an arrival burst the overload then queues
-        # UPSTREAM of the admission plane, where QoS classes, queue
-        # deadlines and preemption cannot see it (regression: the
-        # --qos-soak bench leg read zero sheds at 2x overload). The
-        # bound keeps a flood from starving the decode loop itself.
-        event = None
-        stop = False
-        for burst in range(128):
-            event = node.recv(
-                timeout=0.0 if engine.active or burst else 0.25
-            )
-            if event is None:
+    report_last = tracer.switch("housekeeping")
+    try:
+        while True:
+            if on_tick is not None and on_tick():
                 break
-            if event["type"] == "STOP":
-                stop = True
+            # Drain a BURST of pending events before the next window
+            # (the first recv parks when the engine is idle; the rest
+            # only poll). One recv per step would cap intake at one
+            # request per dispatch — under an arrival burst the overload
+            # then queues UPSTREAM of the admission plane, where QoS
+            # classes, queue deadlines and preemption cannot see it
+            # (regression: the --qos-soak bench leg read zero sheds at
+            # 2x overload). The bound keeps a flood from starving the
+            # decode loop itself.
+            event = None
+            stop = False
+            # Only that first, timed recv is ``parked``: a quarter of a
+            # second's wait is not time spent draining the burst.
+            parked = not engine.active
+            tracer.switch("parked" if parked else "intake")
+            for _burst in range(128):
+                event = node.recv(timeout=0.25 if parked else 0.0)
+                if parked:
+                    tracer.switch("intake")
+                    parked = False
+                if event is None:
+                    break
+                if event["type"] == "STOP":
+                    stop = True
+                    break
+                if event["type"] == "INPUT":
+                    with tracer.phase("intake.handle_input"):
+                        handle_input(event)
+                elif event["type"] == "MIGRATE" and handle_migrate is not None:
+                    _flush(held, emit)
+                    handle_migrate(event)
+                elif event["type"] == "PROFILE" and handle_profile is not None:
+                    handle_profile(event)
+            if stop:
                 break
-            if event["type"] == "INPUT":
-                handle_input(event)
-            elif event["type"] == "MIGRATE" and handle_migrate is not None:
-                _flush(held, emit)
-                handle_migrate(event)
-            elif event["type"] == "PROFILE" and handle_profile is not None:
-                handle_profile(event)
-        if stop:
-            break
-        if (
-            event is None
-            and node.stream_ended
-            and engine.active == 0
-            and len(backlog) == 0
-        ):
-            if not keep_alive:
-                break
-            # Stream closed but handoffs may still arrive: don't spin
-            # (recv returns immediately once the queue is closed).
-            time.sleep(0.05)
-        if engine.active:
-            first = half(engine.dispatch)
-            overlapped = engine.in_flight
-            t_launch = clock()
-            # First tokens lead: ttft waits for them, and their streams
-            # have no token in the window held (it ran before them).
-            held[:0] = first
-            sent = _flush(held, emit)
-            if overlapped:
-                metrics.emit_overlapped += sent
-                # The emit side of max(device, emit) in this period.
-                metrics.emit.observe((clock() - t_launch) * 1e6)
+            if (
+                event is None
+                and node.stream_ended
+                and engine.active == 0
+                and len(backlog) == 0
+            ):
+                if not keep_alive:
+                    break
+                # Stream closed but handoffs may still arrive: don't
+                # spin (recv returns immediately once the queue is
+                # closed).
+                tracer.switch("parked")
+                time.sleep(0.05)
+            if engine.active:
+                first = half(engine.dispatch)
+                overlapped = engine.in_flight
+                t_launch = tracer.switch("emit" if overlapped else "emit_alone")
+                # First tokens lead: ttft waits for them, and their
+                # streams have no token in the window held (it ran
+                # before them).
+                held[:0] = first
+                sent = _flush(held, emit)
+                if overlapped:
+                    metrics.emit_overlapped += sent
+                    # The emit side of max(device, emit) in this period.
+                    metrics.emit.observe((tracer.clock() - t_launch) * 1e6)
+                else:
+                    # Nothing ran beside the emit (a prefill-only
+                    # dispatch): the device waited for it too.
+                    t_launch = tracer.switch("housekeeping")
+                if last_collect_end is not None:
+                    # Host time from the previous window's tokens
+                    # reaching the host to the launch of the next device
+                    # work: what the device sits idle for in each period
+                    # (p50/p99 in the SERVING table).
+                    metrics.dispatch_gap.observe(
+                        (t_launch - last_collect_end) * 1e6
+                    )
+                held.extend(half(engine.collect))
+                # collect()'s return: its last phase ends and the gap
+                # begins on this stamp.
+                last_collect_end = tracer.switch("housekeeping")
+                if engine.active == 0:
+                    # The loop may now park in recv or exit: nothing
+                    # stays in hand.
+                    tracer.switch("emit_alone")
+                    _flush(held, emit)
+                    tracer.switch("housekeeping")
+                if on_step is not None:
+                    on_step()
             else:
-                # Nothing ran beside the emit (a prefill-only
-                # dispatch): the device waited for it too.
-                t_launch = clock()
-            if last_collect_end is not None:
-                # Host time from the previous window's tokens reaching
-                # the host to the launch of the next device work: what
-                # the device sits idle for in each period (p50/p99 in
-                # the SERVING table).
-                metrics.dispatch_gap.observe(
-                    (t_launch - last_collect_end) * 1e6
-                )
-            held.extend(half(engine.collect))
-            last_collect_end = clock()
-            if engine.active == 0:
-                # The loop may now park in recv or exit: nothing stays
-                # in hand.
-                _flush(held, emit)
-            if on_step is not None:
-                on_step()
-        else:
-            last_collect_end = None  # a gap across idle is queue wait
-        backlog.drain()
-        now = clock()
-        if now - report_last >= 1.0:
-            report(now)
-            report_last = now
-        elif fleet_tick is not None:
-            # Fleet digests can run FASTER than the 1 Hz metrics report
-            # (DORA_FLEET_DIGEST_S below 1); report() itself also ticks
-            # the publisher, so the slow cadence costs nothing extra.
-            fleet_tick(now)
+                last_collect_end = None  # a gap across idle is queue wait
+            tracer.switch("admit")
+            backlog.drain()
+            now = tracer.switch("housekeeping")
+            if now - report_last >= 1.0:
+                report(now)
+                report_last = now
+            elif fleet_tick is not None:
+                # Fleet digests can run FASTER than the 1 Hz metrics
+                # report (DORA_FLEET_DIGEST_S below 1); report() itself
+                # also ticks the publisher, so the slow cadence costs
+                # nothing extra.
+                fleet_tick(now)
+    finally:
+        tracer.close()
     _flush(held, emit)
 
 
@@ -578,13 +618,18 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     ``ServingMetrics`` histograms the engine feeds (fetch latency,
     grant sizes), and the runtime XLA compile listener whose counter
     ships with every metrics report."""
-    from dora_tpu import telemetry
-
     if tracer is None:
-        tracer = telemetry.ServingTracer()
+        tracer = telemetry.ServingTracer(clock=clock)
     # The engine records admitted/prefill_chunk/decode_window spans and
     # fetch/grant histograms through these hooks; both are no-ops /
-    # plain counters unless DORA_TRACING=1.
+    # plain counters unless DORA_TRACING=1. The loop's phases go to
+    # the metrics' histograms always, and into a profiler capture where
+    # this process has loaded JAX (the one that holds the chip does;
+    # nothing here imports it).
+    tracer.histograms = metrics.phases
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        tracer.annotation = jax.profiler.TraceAnnotation
     engine.tracer = tracer
     engine.serving_metrics = metrics
     telemetry.install_compile_listener()
@@ -839,7 +884,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         engine, start, on_admit=on_admit, clock=clock,
         qos=qos, on_shed=on_shed,
         preempt=try_preempt if qos.preempt_on else None,
-        on_stall=on_stall,
+        on_stall=on_stall, tracer=tracer,
     )
 
     def handle_input(event) -> None:
@@ -1608,7 +1653,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             on_engine_error=on_engine_error,
             keep_alive=bool(migrate_dir),
             fleet_tick=fleet_pub.tick if fleet_pub.enabled else None,
-            held=held,
+            held=held, tracer=tracer,
         )
         clean = True
     finally:
@@ -1673,7 +1718,7 @@ def _stub_main() -> None:
 
 
 def main() -> None:
-    from dora_tpu import backend, telemetry
+    from dora_tpu import backend
     from dora_tpu.metrics import ServingMetrics
     from dora_tpu.models.hf.loader import read_config
 
@@ -1752,6 +1797,9 @@ def main() -> None:
             "emit_overlapped": metrics.emit_overlapped,
             "dispatch_gap_us": metrics.dispatch_gap.snapshot(),
             "emit_us": metrics.emit.snapshot(),
+            # what the gap and the emit are made of: one histogram a
+            # loop phase (telemetry.LOOP_PHASES), octave counts and all
+            **metrics.phase_snapshots(),
             **metrics.model,
         })
 

@@ -210,8 +210,14 @@ def start_capture(out_dir: str) -> str | None:
     import jax
 
     os.makedirs(out_dir, exist_ok=True)
+    # No Python tracer (the default, level 1, records every Python call
+    # of the serving loop): the loop's own ``loop.*`` annotations
+    # (telemetry.LOOP_PHASES) say what the host does, on the device
+    # planes' clock, and the capture stays small and quick to write.
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     try:
-        jax.profiler.start_trace(out_dir)
+        jax.profiler.start_trace(out_dir, profiler_options=options)
         return None
     except Exception as exc:  # no profiler plugin / already active
         if backend.on_tpu():

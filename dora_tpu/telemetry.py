@@ -283,9 +283,76 @@ FLIGHT = FlightRecorder(
 # ---------------------------------------------------------------------------
 
 
+#: The serving loop's phases — the ONE table of their names; value: does
+#: the phase lie inside what ``dispatch_gap_us`` observes (host time from
+#: ``collect()`` returning to the next launch)? One turn of
+#: ``llm_server._run_loop`` is tiled by them: the loop and the engine
+#: :meth:`ServingTracer.switch` from one to the next on ONE clock read,
+#: so between two ``collect()`` returns every nanosecond lies in exactly
+#: one phase. A dotted name is a child, entered inside its parent and
+#: counted in the parent's histogram too. ``first_token_wait`` runs
+#: inside ``chunk_launch`` and is carved out of it: the host is blocked
+#: on the device there, which is no part of launching a chunk.
+LOOP_PHASES = {
+    # on_tick, on_step, the 1 Hz report() / fleet_tick
+    "housekeeping": True,
+    # backlog.drain(); the child: its calls into engine.can_admit /
+    # admit_blocker, the prefix-cache walk among them
+    "admit": True,
+    "admit.can_admit": True,
+    # the burst of node.recv and its handlers; the child: handle_input
+    # (parse, encode, push)
+    "intake": True,
+    "intake.handle_input": True,
+    # dispatch(): the chunk's operands and enqueue, the prefix-cache
+    # insert, _set_slot — less the blocking read of a final chunk's
+    # first token, which is device time inside the host's gap
+    "chunk_launch": True,
+    "first_token_wait": True,
+    # dispatch(): membership and block table, the jnp.asarray calls
+    "rebuild": True,
+    "window_launch": True,
+    # the flush after a dispatch: beside the window it launched, or
+    # (emit_alone) with nothing running, where the device waits for it
+    "emit": False,
+    "emit_alone": True,
+    # collect(): the wait and the one fetch; then unpack and _free_slot
+    "window_wait": False,
+    "unpack": False,
+    # the timed recv of a turn that finds the engine idle, and the
+    # keep_alive sleep: the device idles for want of work
+    "parked": False,
+}
+
+_LOOP_SPAN_NAMES = {name: f"loop.{name}" for name in LOOP_PHASES}
+
+
+def phase_histogram_key(phase: str) -> str:
+    """A phase's key in the ``ServingMetrics`` snapshot."""
+    return f"phase_{phase.replace('.', '_')}_us"
+
+
+class _Phase:
+    """``with tracer.phase(name):`` — a nested phase, left on every path."""
+
+    __slots__ = ("_tracer", "_name")
+
+    def __init__(self, tracer: "ServingTracer", name: str):
+        self._tracer = tracer
+        self._name = name
+
+    def __enter__(self) -> None:
+        self._tracer.enter(self._name)
+
+    def __exit__(self, *exc) -> None:
+        self._tracer.leave()
+
+
 class ServingTracer:
     """Per-request lifecycle spans for the serving engine, recorded
-    through the flight-recorder ring.
+    through the flight-recorder ring — and the serving loop's phases
+    (:data:`LOOP_PHASES`), the one thing here that describes a turn of
+    the loop and not a request.
 
     One instance per serving process, shared between the server loop
     (``nodehub/llm_server``: queued / finish / reject / page-wait) and
@@ -302,16 +369,91 @@ class ServingTracer:
     recv`` chain that carried the request in. Every method is one
     attribute check when tracing is off — engines keep a tracer
     attached unconditionally and pay ~0 without ``DORA_TRACING=1``.
+
+    A phase is entered and left here and nowhere else, and each leaving
+    is written to three sinks by that one call: ``histograms[phase]``
+    (``ServingMetrics.phases``, always on: the phase's duration less
+    what was carved out of it), an ``annotation`` named
+    ``loop.<phase>`` (``jax.profiler.TraceAnnotation``, handed in by
+    the process that holds the chip — this module imports no JAX — so
+    the phase lands on the ``/host:CPU`` plane of a profiler capture,
+    on the device planes' clock; it records nothing while no capture
+    runs), and an ``s_loop_phase`` span in the ring under
+    ``DORA_TRACING=1``. A tracer with neither sink still gives the
+    stamps: ``enter`` / ``leave`` / ``switch`` return the clock read
+    they made, and callers that need the time at a phase's edge take it
+    from there.
     """
 
-    __slots__ = ("_flight", "_tracing", "_ctx")
+    __slots__ = ("_flight", "_tracing", "_ctx", "clock", "histograms",
+                 "annotation", "_open")
 
     def __init__(self, flight: FlightRecorder | None = None,
-                 tracing: TracingState | None = None):
+                 tracing: TracingState | None = None,
+                 clock=time.monotonic):
         self._flight = flight if flight is not None else FLIGHT
         self._tracing = tracing if tracing is not None else TRACING
         #: request key -> serialized trace context, begin() .. finish()
         self._ctx: dict[str, str] = {}
+        #: seconds; the loop's and the engine's one clock
+        self.clock = clock
+        self.histograms: dict | None = None
+        self.annotation = None
+        #: open phases, outermost first: [name, start, carved s, annotation]
+        self._open: list[list] = []
+
+    # -- loop phases ---------------------------------------------------------
+
+    def enter(self, phase: str) -> float:
+        """Open ``phase`` inside whatever is open; returns the stamp."""
+        now = self.clock()
+        self._push(phase, now)
+        return now
+
+    def leave(self) -> float:
+        """Close the innermost open phase; returns the stamp."""
+        now = self.clock()
+        self._pop(now)
+        return now
+
+    def switch(self, phase: str) -> float:
+        """Close everything open and open ``phase``, on one clock read:
+        how the loop's turn is tiled."""
+        now = self.close()
+        self._push(phase, now)
+        return now
+
+    def close(self) -> float:
+        """Close everything open (the loop's exit, and its error path)."""
+        now = self.clock()
+        while self._open:
+            self._pop(now)
+        return now
+
+    def phase(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def _push(self, phase: str, now: float) -> None:
+        name = _LOOP_SPAN_NAMES[phase]  # the table is closed: KeyError
+        span = None
+        if self.annotation is not None:
+            span = self.annotation(name)
+            span.__enter__()
+        self._open.append([phase, now, 0.0, span])
+
+    def _pop(self, now: float) -> None:
+        phase, start, carved, span = self._open.pop()
+        if span is not None:
+            span.__exit__(None, None, None)
+        dur = now - start
+        if self._open and "." not in phase:
+            self._open[-1][2] += dur
+        if self.histograms is not None:
+            self.histograms[phase].observe((dur - carved) * 1e6)
+        if self._tracing.active:
+            self._flight.record("s_loop_phase", phase, None, int(dur * 1e9))
+
+    # -- request lifecycle ---------------------------------------------------
 
     @property
     def active(self) -> bool:

@@ -75,15 +75,14 @@ SERVING_SPAN_KINDS = {
     # stream's block table (prefill starts at the divergence point).
     # Emitted just before s_admitted, with the same trace context.
     "s_prefix_hit": "prefix_hit",
-    # Device-time attribution (dora_tpu.profiling): each fused window /
-    # final prefill chunk splits its wall time into host-dispatch →
-    # device-compute → device-fetch child spans, emitted per boundary
-    # (keyed "window"/"chunk", no request context — one dispatch serves
-    # every stream): where a window's wall time goes is measured, not
-    # inferred.
-    "s_dev_dispatch": "dev_dispatch",
-    "s_dev_compute": "dev_compute",
-    "s_dev_fetch": "dev_fetch",
+    # The serving loop's phases (telemetry.LOOP_PHASES), the one span
+    # here that describes a turn of the loop and not a request: keyed
+    # by the phase's name, no request context (one turn serves every
+    # stream), a child nested inside its parent. ``chunk_launch`` /
+    # ``window_launch`` are the host's side of a launch,
+    # ``first_token_wait`` / ``window_wait`` the host blocked on the
+    # device.
+    "s_loop_phase": "loop_phase",
 }
 
 #: Hot-path flight events surfaced as instants (everything else recorded
@@ -410,9 +409,9 @@ def _sample_snapshots() -> list[dict]:
                 [52, base + 8_990_000, "s_prefix_hit", "req-1 tokens=16/24 pages=2", rctx, 0],
                 [42, base + 9_000_000, "s_admitted", "req-1 pages=2 shared=2", rctx, 20_000],
                 [43, base + 9_300_000, "s_prefill_chunk", "req-1 base=0", rctx, 200_000],
-                [53, base + 9_500_000, "s_dev_dispatch", "window", rctx, 30_000],
-                [54, base + 9_700_000, "s_dev_compute", "window", rctx, 180_000],
-                [55, base + 9_750_000, "s_dev_fetch", "window", rctx, 40_000],
+                [53, base + 9_500_000, "s_loop_phase", "window_launch", None, 30_000],
+                [54, base + 9_740_000, "s_loop_phase", "window_wait", None, 220_000],
+                [55, base + 9_750_000, "s_loop_phase", "unpack", None, 10_000],
                 [44, base + 9_800_000, "s_decode_window", "req-1 k=8 n=5", rctx, 400_000],
                 [45, base + 9_850_000, "xla_compile", "window", None, 3_000_000],
                 [48, base + 9_860_000, "s_preempt", "req-1 pages=2", rctx, 0],
@@ -466,11 +465,14 @@ def self_check() -> list[str]:
     ]
     chain = [ev["name"].split(" ", 1)[0] for ev in engine_spans]
     want = ["queued", "prefix_hit", "admitted", "prefill_chunk",
-            "dev_dispatch", "dev_compute", "dev_fetch",
+            "loop_phase", "loop_phase", "loop_phase",
             "decode_window", "preempt", "resume", "finish"]
     if chain != want:
         errors.append(f"lifecycle chain broken: {chain}")
-    if any(ev.get("args", {}).get("trace_id") not in ids for ev in engine_spans):
+    if any(
+        ev.get("args", {}).get("trace_id") not in ids
+        for ev in engine_spans if not ev["name"].startswith("loop_phase ")
+    ):
         errors.append("serving spans not linked to the message trace id")
     metas = {
         (ev["pid"], ev["tid"]): ev["args"]["name"]

@@ -112,7 +112,7 @@ def test_peak_flops_knows_the_v5e_and_refuses_the_unknown(monkeypatch):
 def test_profiler_that_cannot_start_is_an_error_on_tpu(monkeypatch, tmp_path):
     from dora_tpu import profiling
 
-    def boom(_dir):
+    def boom(_dir, **_options):
         raise RuntimeError("no profiler here")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)

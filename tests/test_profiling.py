@@ -300,9 +300,11 @@ def test_prom_covers_device_families():
 def test_tracing_self_check_covers_dev_spans():
     from dora_tpu import tracing
 
+    # the three s_dev_* kinds became the loop's phases: chunk_launch /
+    # window_launch, first_token_wait / window_wait hold their intervals
     assert tracing.self_check() == []
-    for kind in ("s_dev_dispatch", "s_dev_compute", "s_dev_fetch"):
-        assert kind in tracing.SERVING_SPAN_KINDS
+    assert "s_loop_phase" in tracing.SERVING_SPAN_KINDS
+    assert not [k for k in tracing.SERVING_SPAN_KINDS if k.startswith("s_dev_")]
 
 
 # ---------------------------------------------------------------------------
