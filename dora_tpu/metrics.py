@@ -498,6 +498,22 @@ class ServingMetrics:
             for name, h in self.phases.items()
         }
 
+    def first_token_reads(self) -> dict:
+        """How often a prompt's first token was read beside the window
+        its dispatch had launched (``deferred``: phase
+        ``first_token_read``) and how often before the launch, holding
+        it (``blocking``: ``first_token_wait`` — speculation, or no
+        window followed). A share of 1.0 is the mechanism engaged:
+        ``phase_first_token_wait_us`` then counts nothing, which is not
+        a reader gone blind."""
+        deferred = self.phases["first_token_read"].count
+        blocking = self.phases["first_token_wait"].count
+        total = deferred + blocking
+        return {
+            "deferred": deferred, "blocking": blocking,
+            "deferred_share": deferred / total if total else None,
+        }
+
 
 def merge_snapshots(snapshots: list[dict]) -> dict:
     """Aggregate per-daemon snapshots into one cluster view (coordinator).

@@ -423,13 +423,20 @@ class PagedBatchEngine:
         self.tracer = telemetry.ServingTracer()
         self.serving_metrics = None
         #: the window :meth:`dispatch` launched and :meth:`collect` has
-        #: not fetched yet: ``(mat, launch start, launch end)`` (the
-        #: end only under ``device_monitor``). While
+        #: not fetched yet: ``(mat, launch start, compute counted
+        #: from)`` (the last a stamp of its own only under
+        #: ``device_monitor``). While
         #: it is set the device-carried state (``tokens``, ``positions``,
         #: ``pools`` …) is one window AHEAD of the host slots, so every
         #: reader of both (checkpoint, preempt, drain) runs only after
         #: ``collect()``.
         self._flight: tuple | None = None
+        #: the stamp on which the last :meth:`dispatch` left
+        #: ``window_launch`` itself, for a first token's read beside the
+        #: window: from there on the device has work, so the host's gap
+        #: ends there. None where the phase is still open when
+        #: ``dispatch()`` returns and the caller's next switch leaves it.
+        self.launched_at: float | None = None
         #: device utilization plane (dora_tpu.profiling): when the
         #: monitor is on, the step path splits each window/chunk's wall
         #: time into host-dispatch / device-compute / device-fetch (a
@@ -465,9 +472,15 @@ class PagedBatchEngine:
             "int8" if isinstance(first, dict) and "ks" in first else "fp"
         )
 
-        def _set_slot(tokens, positions, token, pos, b):
+        def _set_slot(tokens, positions, greedy, row, pos, b):
+            # ``row`` is a traced operand: one program for every prompt
+            # length, where a Python index would compile a slice for
+            # each remainder of a prompt modulo the chunk. The token
+            # goes from the chunk's result to the slot on the device;
+            # the host reads it for the wire alone.
+            token = jax.lax.dynamic_index_in_dim(greedy, row, keepdims=True)
             tokens = jax.lax.dynamic_update_slice(
-                tokens, token.reshape(1), (b,)
+                tokens, token.astype(tokens.dtype), (b,)
             )
             positions = jax.lax.dynamic_update_slice(
                 positions, pos.reshape(1), (b,)
@@ -878,18 +891,29 @@ class PagedBatchEngine:
         """The launching half of :meth:`step`: the prefill chunk, the
         membership / block-table rebuild and the launch of the window
         program — everything up to the point where the host would start
-        to wait. Returns what the host already knows: the first token
-        of a stream whose final chunk just ran (that read blocks, the
-        window needs the token), usually nothing."""
+        to wait for the window. Returns the first token of a stream
+        whose final chunk just ran, usually nothing. That token goes to
+        its slot on the device (``_set_slot`` gathers it from the
+        chunk's result), so the window is launched behind the chunk
+        without it, and the host reads it for the wire AFTER the launch,
+        beside the window (phase ``first_token_read``): the read returns
+        when the chunk is done, not when the window is. The read comes
+        before the launch, blocking (phase ``first_token_wait``), only
+        where the host needs the value first — speculation is on
+        (``spec_k``: the history mirror is rebuilt into the window's
+        operands) — or no window follows to read beside."""
         assert self._flight is None, "dispatch() before collect()"
+        self.launched_at = None
         jnp = self._jnp
         np = self._np
         emitted: list[tuple[str, int, bool]] = []
-        sm = self.serving_metrics
         # The step path's phases (telemetry.LOOP_PHASES): each begins
         # where the one before ends, on one clock read, and the stamps
         # the counters below need are the phases' own.
         tracer = self.tracer
+        #: a final chunk's ``(stream, slot, result, row)`` whose first
+        #: token is read once the window is launched
+        first = None
 
         if self._prefillq:
             t_chunk = tracer.switch("chunk_launch")
@@ -949,48 +973,44 @@ class PagedBatchEngine:
                             s.adapter,
                         )
                 s.prompt = None
-                # Host-index AFTER a full [C] fetch — a device gather at
-                # a python index would compile one slice per distinct
-                # prompt-length remainder.
-                t_fetch = tracer.enter("first_token_wait")
-                if self.device_monitor:
-                    # Non-final chunks stay async (their device time
-                    # surfaces as the next window's compute wait); the
-                    # final chunk must block for its first token anyway,
-                    # so split that wait into compute vs fetch here.
-                    greedy.block_until_ready()
-                    t_ready = tracer.clock()
-                    self.device_compute_ns += int((t_ready - t_fetch) * 1e9)
-                token = int(np.asarray(greedy)[s.true_len - 1 - base])
-                t_first = tracer.leave()
-                if self.device_monitor:
-                    self.device_fetch_ns += int((t_first - t_ready) * 1e9)
-                self.fetches += 1
-                if sm is not None:
-                    sm.fetch_latency.observe((t_first - t_fetch) * 1e6)
+                # Its first token exists, on the device: the window's
+                # completion counter (rebuilt from here) starts behind it.
                 s.emitted = 1
-                done = (
-                    self.eos is not None and token == self.eos
-                ) or s.max_new <= 1
-                emitted.append((s.request_id, token, done))
-                if done:
-                    self._free_slot(b)
-                else:
+                row = s.true_len - 1 - base
+                if s.max_new > 1:
+                    # The stream decodes from the window this dispatch
+                    # launches, whatever its first token is: were it
+                    # ``eos``, the read below frees the slot and
+                    # collect() passes the row over; what the row wrote
+                    # meanwhile fell in pages it held for itself, past
+                    # the prompt's full pages that the cache adopted.
                     self._decode[b] = True
-                    if self._spec_cfg:
-                        self._hist[b].append(token)
                     self.tokens, self.positions = self._set_slot(
-                        self.tokens, self.positions,
-                        jnp.asarray(token, jnp.int32),
+                        self.tokens, self.positions, greedy,
+                        jnp.asarray(row, jnp.int32),
                         jnp.asarray(s.true_len, jnp.int32),
                         jnp.asarray(b, jnp.int32),
                     )
                     self._members_dirty = True
                     self._bt_dirty = True
+                # else one token is all it asked for: the row never
+                # decodes. Its slot too is freed at the read, not here:
+                # the chunk just enqueued may still be reading this
+                # slot's row of the block table (``jnp.asarray`` of a
+                # numpy view need not copy), which _free_slot() zeroes.
+                if self.spec_k or not any(self._decode):
+                    t_fetch = tracer.enter("first_token_wait")
+                    emitted.append(
+                        self._first_token(s, b, greedy, row, t_fetch)
+                    )
+                    tracer.leave()
+                else:
+                    first = (s, b, greedy, row)
             if tracer.active:
-                # Non-final chunks are async dispatches, so the span is
-                # dispatch cost only; the final chunk's span includes
-                # its blocking first-token fetch.
+                # Chunks are async dispatches, so the span is dispatch
+                # cost only — but for a final chunk whose first token
+                # was read here, blocking (speculation, or no window to
+                # read it beside): that span holds the read too.
                 tracer.span(
                     "s_prefill_chunk", s.request_id,
                     f"base={base} chunk={self.chunk}"
@@ -1105,8 +1125,52 @@ class PagedBatchEngine:
             if self.device_monitor:
                 t_launched = tracer.clock()
                 self.host_dispatch_ns += int((t_launched - t_win) * 1e9)
+            if first is not None:
+                # The device has the chunk and the window in its queue:
+                # the host's gap ends here, and the read returns when
+                # the chunk is done, while the window runs.
+                self.launched_at = tracer.switch("first_token_read")
+                emitted.append(self._first_token(*first, self.launched_at))
+                if self.device_monitor:
+                    # the chunk's wait is counted; the window's begins
+                    # where that ended
+                    t_launched = tracer.clock()
             self._flight = (mat, t_win, t_launched)
         return emitted
+
+    def _first_token(self, s: _PagedSlot, b: int, greedy, row: int,
+                     t_fetch: float) -> tuple[str, int, bool]:
+        """The host's read of a final chunk's first token, begun on the
+        stamp ``t_fetch`` (its phase's own), and what the value decides:
+        ``(request_id, token, done)``, the slot freed where the stream
+        ends with it (``eos``, or one token asked for): the chunk is
+        done by then, so nothing reads the slot's pages or its row of
+        the block table any more but a window that passes the row over."""
+        tracer = self.tracer
+        if self.device_monitor:
+            # Chunks that are not final stay async (their device time
+            # surfaces as the next window's compute wait); a final
+            # chunk is waited for, so split that wait into compute and
+            # fetch here.
+            greedy.block_until_ready()
+            t_ready = tracer.clock()
+            self.device_compute_ns += int((t_ready - t_fetch) * 1e9)
+        # Host-index AFTER a full [C] fetch: 1 KB, no program.
+        token = int(self._np.asarray(greedy)[row])
+        t_first = tracer.clock()
+        if self.device_monitor:
+            self.device_fetch_ns += int((t_first - t_ready) * 1e9)
+        self.fetches += 1
+        if self.serving_metrics is not None:
+            self.serving_metrics.fetch_latency.observe(
+                (t_first - t_fetch) * 1e6
+            )
+        done = s.max_new <= 1 or (self.eos is not None and token == self.eos)
+        if done:
+            self._free_slot(b)
+        elif self._spec_cfg:
+            self._hist[b].append(token)
+        return s.request_id, token, done
 
     def collect(self) -> list[tuple[str, int, bool]]:
         """The waiting half of :meth:`step`: block on the window
@@ -1428,10 +1492,12 @@ class PagedBatchEngine:
                     int(t)
                     for t in meta.get("history") or [meta["last_token"]]
                 ]
+            # the chunk's own program: a result's worth of the token
             self.tokens, self.positions = self._set_slot(
                 self.tokens,
                 self.positions,
-                jnp.asarray(meta["last_token"], jnp.int32),
+                jnp.full((self.chunk,), meta["last_token"], jnp.int32),
+                jnp.asarray(0, jnp.int32),
                 jnp.asarray(meta["position"], jnp.int32),
                 jnp.asarray(b, jnp.int32),
             )
@@ -1677,17 +1743,18 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
                 time.sleep(wait)
 
         def dispatch(self):
-            final = False
             if chunk_sleep_s and self._prefillq:
-                s = self.slots[self._prefillq[0]]
-                final = s.chunk_base + self.chunk >= s.true_len
                 self._occupy(chunk_sleep_s)
-            if final:
-                self._wait_device()  # the first token's blocking read
             first = super().dispatch()
             if tick_sleep_s and self.in_flight:
                 self._occupy(tick_sleep_s * self.window)
             return first
+
+        def _first_token(self, *args):
+            # the read returns when the chunk is done: the window, where
+            # one was launched already, occupies the device from then
+            self._wait_device()
+            return super()._first_token(*args)
 
         def collect(self):
             if self.in_flight:

@@ -551,7 +551,13 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             if engine.active:
                 first = half(engine.dispatch)
                 overlapped = engine.in_flight
-                t_launch = tracer.switch("emit" if overlapped else "emit_alone")
+                t_emit = tracer.switch("emit" if overlapped else "emit_alone")
+                # The gap ends on the stamp that left ``window_launch``:
+                # the engine's, where it went on to read a first token
+                # beside the window, else this switch.
+                t_launch = engine.launched_at
+                if t_launch is None:
+                    t_launch = t_emit
                 # First tokens lead: ttft waits for them, and their
                 # streams have no token in the window held (it ran
                 # before them).
@@ -560,7 +566,7 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
                 if overlapped:
                     metrics.emit_overlapped += sent
                     # The emit side of max(device, emit) in this period.
-                    metrics.emit.observe((tracer.clock() - t_launch) * 1e6)
+                    metrics.emit.observe((tracer.clock() - t_emit) * 1e6)
                 else:
                     # Nothing ran beside the emit (a prefill-only
                     # dispatch): the device waited for it too.
@@ -1800,6 +1806,9 @@ def main() -> None:
             # what the gap and the emit are made of: one histogram a
             # loop phase (telemetry.LOOP_PHASES), octave counts and all
             **metrics.phase_snapshots(),
+            # how often a first token's read left the gap (deferred,
+            # beside the window) and how often it still held the launch
+            "first_token_reads": metrics.first_token_reads(),
             **metrics.model,
         })
 

@@ -292,7 +292,11 @@ FLIGHT = FlightRecorder(
 #: one phase. A dotted name is a child, entered inside its parent and
 #: counted in the parent's histogram too. ``first_token_wait`` runs
 #: inside ``chunk_launch`` and is carved out of it: the host is blocked
-#: on the device there, which is no part of launching a chunk.
+#: on the device there, which is no part of launching a chunk. The gap
+#: ends on the stamp that leaves ``window_launch``: the engine's own
+#: switch to ``first_token_read`` where a first token is read beside
+#: the window (``PagedBatchEngine.launched_at``), else the loop's to
+#: ``emit``.
 LOOP_PHASES = {
     # on_tick, on_step, the 1 Hz report() / fleet_tick
     "housekeeping": True,
@@ -305,13 +309,20 @@ LOOP_PHASES = {
     "intake": True,
     "intake.handle_input": True,
     # dispatch(): the chunk's operands and enqueue, the prefix-cache
-    # insert, _set_slot — less the blocking read of a final chunk's
-    # first token, which is device time inside the host's gap
+    # insert, _set_slot (the first token goes to its slot on the device)
+    # — less first_token_wait: the read of a final chunk's first token
+    # where it still comes BEFORE the launch and blocks it (speculation's
+    # history mirror needs the value; no window follows), which is
+    # device time inside the host's gap
     "chunk_launch": True,
     "first_token_wait": True,
     # dispatch(): membership and block table, the jnp.asarray calls
     "rebuild": True,
     "window_launch": True,
+    # dispatch(): the read of a final chunk's first token AFTER the
+    # launch, for the wire: the chunk's remaining device time and the
+    # token's way to the host, while the device goes on to the window
+    "first_token_read": False,
     # the flush after a dispatch: beside the window it launched, or
     # (emit_alone) with nothing running, where the device waits for it
     "emit": False,

@@ -80,8 +80,9 @@ SERVING_SPAN_KINDS = {
     # by the phase's name, no request context (one turn serves every
     # stream), a child nested inside its parent. ``chunk_launch`` /
     # ``window_launch`` are the host's side of a launch,
-    # ``first_token_wait`` / ``window_wait`` the host blocked on the
-    # device.
+    # ``first_token_wait`` / ``first_token_read`` / ``window_wait`` the
+    # host blocked on the device (the first before a launch, the second
+    # beside the window it launched).
     "s_loop_phase": "loop_phase",
 }
 

@@ -55,6 +55,7 @@ class FakeEngine:
         return None
 
     in_flight = False
+    launched_at = None  # no first token read beside a window
 
     def dispatch(self):
         return []

@@ -91,6 +91,7 @@ class SplitEngine(_Streams):
     ``emit`` shares."""
 
     in_flight = False
+    launched_at = None  # no first token read beside a window
     launched = 0  # dispatches that left a window in flight
 
     def dispatch(self):
@@ -115,6 +116,7 @@ class NothingInFlightEngine(_Streams):
     prefill-only dispatch): ``collect()`` does the whole step."""
 
     in_flight = False
+    launched_at = None  # no first token read beside a window
 
     def dispatch(self):
         return []

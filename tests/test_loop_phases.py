@@ -66,24 +66,31 @@ class Annotations:
 
 class PhasedEngine:
     """Streams of ``cap`` tokens, K a window, one prefill chunk a
-    stream. Enters the engine's phases where PagedBatchEngine does."""
+    stream. Enters the engine's phases where PagedBatchEngine does:
+    chunk, set-slot, rebuild, launch, and then the read of the first
+    token beside the window — or, ``blocking`` (an engine that
+    speculates), the read inside the chunk's launch, before the window."""
 
     tracer = None
     CHUNK, FIRST, SET_SLOT, REBUILD, LAUNCH, WAIT, UNPACK = (
         0.003, 0.005, 0.0003, 0.001, 0.0005, 0.020, 0.0002
     )
 
-    def __init__(self, clock, slots=2, k=3, cap=13, fail_at=None):
+    def __init__(self, clock, slots=2, k=3, cap=13, fail_at=None,
+                 blocking=False):
         self.clock, self.slots, self.k, self.cap = clock, slots, k, cap
-        self.fail_at = fail_at
+        self.fail_at, self.blocking = fail_at, blocking
         self.streams: dict[str, int] = {}
         self.prefillq: list[str] = []
         self.decoding: list[str] = []
         self.dirty = False
         self.in_flight = False
+        self.launched_at = None
         self.dispatches = self.chunks = self.rebuilds = self.windows = 0
+        self.reads = 0
         self.can_admit_calls = 0
         self.collect_returns: list[float] = []
+        self.launch_ends: list[float] = []
 
     @property
     def active(self) -> int:
@@ -103,25 +110,32 @@ class PhasedEngine:
         self.streams[key] = 0
         self.prefillq.append(key)
 
+    def _read(self, key):
+        self.clock.tick(self.FIRST)
+        if self.fail_at == self.dispatches:
+            raise RuntimeError("the device fell over")
+        return (key, 0, False)
+
     def dispatch(self):
         tr, tick = self.tracer, self.clock.tick
         self.dispatches += 1
-        first = []
+        self.launched_at = None
+        first, read = [], None
         if self.prefillq:
             tr.switch("chunk_launch")
             tick(self.CHUNK)
             self.chunks += 1
-            tr.enter("first_token_wait")
-            tick(self.FIRST)
-            if self.fail_at == self.dispatches:
-                raise RuntimeError("the device fell over")
-            tr.leave()
             tick(self.SET_SLOT)
             key = self.prefillq.pop(0)
             self.streams[key] = 1
-            first.append((key, 0, False))
             self.decoding.append(key)
             self.dirty = True
+            if self.blocking:
+                tr.enter("first_token_wait")
+                first.append(self._read(key))
+                tr.leave()
+            else:
+                read = key
         if self.decoding:
             if self.dirty:
                 tr.switch("rebuild")
@@ -132,6 +146,11 @@ class PhasedEngine:
             tick(self.LAUNCH)
             self.in_flight = True
             self.windows += 1
+            self.launch_ends.append(self.clock())
+            if read is not None:
+                self.launched_at = tr.switch("first_token_read")
+                self.reads += 1
+                first.append(self._read(read))
         return first
 
     def collect(self):
@@ -290,18 +309,71 @@ def test_a_phase_that_did_not_run_observed_nothing():
     run = _drive([(100.0, _input("a"))], engine=engine, clock=engine.clock)
     phases = run["metrics"].phases
     assert phases["chunk_launch"].count == engine.chunks == 1
-    assert phases["first_token_wait"].count == 1
+    # the one first token was read beside the window it joined
+    assert phases["first_token_read"].count == engine.reads == 1
+    assert phases["first_token_wait"].count == 0
     # membership changed twice: the stream began, the stream ended
     assert phases["rebuild"].count == engine.rebuilds == 1
     assert phases["window_launch"].count == engine.windows == 10
     assert phases["window_wait"].count == phases["unpack"].count == 10
     # the one flush that no window ran beside: the last, with the engine idle
     assert phases["emit_alone"].count == 1
+    assert phases["chunk_launch"].sum_us == pytest.approx(
+        (engine.CHUNK + engine.SET_SLOT) * 1e6, abs=1e-3)
+    assert phases["first_token_read"].sum_us == pytest.approx(
+        engine.FIRST * 1e6, abs=1e-3)
+    # the read left window_launch: that phase holds its own time alone
+    assert phases["window_launch"].sum_us == pytest.approx(
+        10 * engine.LAUNCH * 1e6, abs=1e-3)
+
+
+def test_a_read_that_blocks_the_launch_is_carved_out_of_the_chunks_launch():
+    engine = PhasedEngine(Clock(), slots=1, cap=30, blocking=True)
+    run = _drive([(100.0, _input("a"))], engine=engine, clock=engine.clock)
+    phases = run["metrics"].phases
+    assert phases["first_token_wait"].count == 1
+    assert phases["first_token_read"].count == engine.reads == 0
     # chunk_launch holds its own time, not the wait carved out of it
     assert phases["chunk_launch"].sum_us == pytest.approx(
         (engine.CHUNK + engine.SET_SLOT) * 1e6, abs=1e-3)
     assert phases["first_token_wait"].sum_us == pytest.approx(
         engine.FIRST * 1e6, abs=1e-3)
+
+
+@pytest.mark.parametrize("blocking", [False, True])
+def test_the_gap_ends_on_the_stamp_that_leaves_window_launch(blocking):
+    # the engine never idles, so every turn observes a gap; most of
+    # the dispatches read a first token, beside the window or (blocking)
+    # before its launch
+    script = [(100.0, _input(f"r{i}")) for i in range(12)]
+    engine = PhasedEngine(Clock(), slots=3, cap=10, blocking=blocking)
+    run = _drive(script, engine=engine, clock=engine.clock)
+    metrics, phases = run["metrics"], run["metrics"].phases
+    assert engine.chunks == 12 and engine.reads == (0 if blocking else 12)
+    # each observed gap runs from a collect()'s return to the end of the
+    # next launch — not to dispatch()'s return, a read later
+    ends, returns = engine.launch_ends, engine.collect_returns
+    gaps = [e - r for e, r in zip(ends[1:], returns)]
+    assert metrics.dispatch_gap.count == len(gaps) == engine.windows - 1 >= 12
+    assert metrics.dispatch_gap.sum_us == pytest.approx(sum(gaps) * 1e6, abs=1e-2)
+    # and its phases still add up to it, the read among them only where
+    # the launch waited for it
+    samples = run["samples"]
+    a, b = samples[0], samples[-2]
+    parts = sum(b[p] - a[p] for p in IN_GAP)
+    assert parts == pytest.approx(b["gap_sum_us"] - a["gap_sum_us"], abs=1e-3)
+    assert b["gap_count"] - a["gap_count"] == len(samples) - 2
+    where = "first_token_wait" if blocking else "first_token_read"
+    assert phases[where].sum_us == pytest.approx(12 * engine.FIRST * 1e6, abs=1e-3)
+    assert not LOOP_PHASES["first_token_read"] and LOOP_PHASES["first_token_wait"]
+    # the pair the server's exit line prints: how often the read left the gap
+    assert metrics.first_token_reads() == (
+        {"deferred": 0, "blocking": 12, "deferred_share": 0.0} if blocking
+        else {"deferred": 12, "blocking": 0, "deferred_share": 1.0})
+    assert ServingMetrics().first_token_reads()["deferred_share"] is None
+    # emit_us begins where the read ended, not where the gap did
+    assert metrics.emit.count == engine.windows
+    assert metrics.emit.sum_us == pytest.approx(phases["emit"].sum_us, abs=1e-3)
 
 
 def test_only_the_timed_recv_of_an_idle_turn_is_parked():
@@ -318,29 +390,39 @@ def test_only_the_timed_recv_of_an_idle_turn_is_parked():
     assert phases["intake"].sum_us < 0.01 * 1e6
 
 
-def test_a_dispatch_that_raises_leaves_every_phase_left_and_the_sink_balanced():
+@pytest.mark.parametrize("blocking", [True, False])
+def test_a_dispatch_that_raises_leaves_every_phase_left_and_the_sink_balanced(blocking):
     failed = []
-    engine = PhasedEngine(Clock(), fail_at=1)
+    engine = PhasedEngine(Clock(), fail_at=1, blocking=blocking)
     run = _drive([(100.0, _input("a"))], engine=engine, clock=engine.clock,
                  on_engine_error=lambda: failed.append(True))
     assert isinstance(run["error"], RuntimeError) and failed == [True]
     sink = run["sink"]
     assert run["tracer"]._open == [] and sink.open == [] and sink.balanced
     assert all(s[2] is not None for s in sink.spans)
-    # both phases that were open when it raised were observed, once
+    # the phases that were open when it raised were observed, once: the
+    # chunk's launch and the wait inside it, or the read after the launch
     phases = run["metrics"].phases
-    assert phases["chunk_launch"].count == phases["first_token_wait"].count == 1
+    assert phases["chunk_launch"].count == 1
+    assert phases["first_token_wait"].count == blocking
+    assert phases["first_token_read"].count == (not blocking)
+    assert phases["window_launch"].count == (not blocking)
 
 
-def test_the_annotation_sink_sees_loop_names_children_inside_parents():
-    run = _drive(SCRIPT)
+@pytest.mark.parametrize("blocking", [True, False])
+def test_the_annotation_sink_sees_loop_names_children_inside_parents(blocking):
+    engine = PhasedEngine(Clock(), blocking=blocking)
+    run = _drive(SCRIPT, engine=engine, clock=engine.clock)
     sink = run["sink"]
     assert sink.balanced and not sink.open
     assert {s[0] for s in sink.spans} <= {f"loop.{p}" for p in LOOP_PHASES}
     parents = {"loop.intake.handle_input": {"loop.intake"},
-               "loop.first_token_wait": {"loop.chunk_launch"},
                # drain() runs in admit, and under a handler's push()
                "loop.admit.can_admit": {"loop.admit", "loop.intake.handle_input"}}
+    if blocking:
+        parents["loop.first_token_wait"] = {"loop.chunk_launch"}
+    else:  # the read beside the window is a phase of the turn's own tiling
+        assert any(s[0] == "loop.first_token_read" and s[3] == 0 for s in sink.spans)
     seen = set()
     for name, start, end, depth in sink.spans:
         if depth == 0:
@@ -380,7 +462,7 @@ print("ok")
 def test_the_snapshot_has_one_histogram_a_row_and_the_other_planes_name_none():
     snap = ServingMetrics().snapshot()
     keys = [phase_histogram_key(p) for p in LOOP_PHASES]
-    assert len(set(keys)) == len(LOOP_PHASES) == 14
+    assert len(set(keys)) == len(LOOP_PHASES) == 15
     for key in keys:
         assert set(snap[key]) >= {"count", "sum_us", "counts"}
     assert "phase_admit_can_admit_us" in keys and "phase_intake_handle_input_us" in keys
@@ -455,7 +537,9 @@ def test_the_real_engine_reports_its_phases_once_an_occurrence():
     engine.tracer.close()
     assert engine.chunks_run == 10 and finals == 5 and windows > 5
     assert phases["chunk_launch"].count == engine.chunks_run
-    assert phases["first_token_wait"].count == finals
+    # every first token was read beside the window its stream joined
+    assert phases["first_token_read"].count == finals
+    assert phases["first_token_wait"].count == 0
     assert phases["rebuild"].count == rebuilds >= 5
     assert phases["window_launch"].count == windows
     assert phases["window_wait"].count == phases["unpack"].count == windows

@@ -547,3 +547,194 @@ def test_window_operands_cached_while_membership_is_unchanged(quantized):
         engine.step()
     # freeing a slot invalidates the cached operands
     assert engine._members_dirty and engine._bt_dirty
+
+
+# -- a first token goes to its slot on the device (PR 40) --------------------
+#
+# The stub engine: the real scheduler over the affine rule
+# next = (7 t + 3) % 97, so every stream is known without a model.
+
+_MIXED = [("a", 5, 9), ("b", 20, 6), ("c", 40, 1), ("d", 16, 12), ("e", 33, 7),
+          ("f", 5, 9), ("g", 47, 3), ("h", 1, 2), ("i", 31, 5), ("j", 18, 1)]
+#: sha256(repr(emitted))[:16] of the run below as the PARENT of PR 40
+#: emitted it (the read of a first token before the window's launch),
+#: by ``eos``: 39 is e's first token and a's fourth, 54 h's first and
+#: d's second, 87 the first of a and of its twin f
+_MIXED_AS_BEFORE = {None: (55, "eea7e09dd9be0edb"), 39: (39, "e93fad7339262e64"),
+                    54: (44, "2d8aaea50243cf98"), 87: (38, "a6b8742a262bcdb1")}
+
+
+def _mixed_prompt(rid: str, n: int) -> list[int]:
+    rid = "a" if rid == "f" else rid  # f repeats a: a prefix-cache hit
+    return [(ord(rid) * 5 + 3 * i) % 97 for i in range(n)]
+
+
+def _mixed_run(engine, halves: bool = True, after=None) -> list:
+    """One-chunk, three-chunk and one-token requests, a twin, three
+    slots for ten streams; ``after(engine, first)`` runs between the
+    halves, where the loop sends."""
+    out, pending = [], list(_MIXED)
+    while pending or engine.active:
+        while pending and engine.can_admit(pending[0][1], pending[0][2]):
+            rid, n, max_new = pending.pop(0)
+            engine.submit(rid, _mixed_prompt(rid, n), max_new)
+        if halves:
+            first = engine.dispatch()
+            if after is not None:
+                after(engine, first)
+            out += first + engine.collect()
+        else:
+            out += engine.step()
+        engine.check_invariants()
+    return out
+
+
+def _stub(**kw):
+    from dora_tpu.models.batch_engine import make_stub_paged_engine
+
+    return make_stub_paged_engine(
+        max_slots=3, window=4, chunk=16, max_seq=64, prefix_cache=True, **kw
+    )
+
+
+@pytest.mark.parametrize("eos", list(_MIXED_AS_BEFORE))
+def test_the_emitted_sequence_is_the_one_before_the_read_moved(eos):
+    import hashlib
+
+    from dora_tpu.metrics import ServingMetrics
+
+    engine = _stub(eos=eos)
+    phases = engine.tracer.histograms = ServingMetrics().phases
+    out = _mixed_run(engine)
+    engine.tracer.close()
+    # token for token and in the same order, step() and the halves alike
+    assert out == _mixed_run(_stub(eos=eos), halves=False)
+    count, digest = _MIXED_AS_BEFORE[eos]
+    assert len(out) == count
+    assert hashlib.sha256(repr(out).encode()).hexdigest()[:16] == digest
+    # and each stream is the rule's, cut at its cap or at eos
+    streams: dict[str, list] = {}
+    for rid, token, done in out:
+        streams.setdefault(rid, []).append((token, done))
+    for rid, n, max_new in _MIXED:
+        want, t = [], _mixed_prompt(rid, n)[-1]
+        while len(want) < max_new and (not want or want[-1] != eos):
+            t = (7 * t + 3) % 97
+            want.append(t)
+        assert [tok for tok, _ in streams[rid]] == want, rid
+        assert [d for _, d in streams[rid]] == [False] * (len(want) - 1) + [True]
+    # the order that gave them: every first token whose dispatch also
+    # launched a window was read beside it
+    reads, waits = phases["first_token_read"].count, phases["first_token_wait"].count
+    assert reads + waits == len(_MIXED) and reads >= 8
+
+
+@pytest.mark.parametrize("eos,stream", [(39, "e"), (54, "h"), (87, "a")])
+def test_a_first_token_that_is_eos_ends_its_stream_with_that_token(eos, stream):
+    seen = []
+
+    def after(engine, first):
+        # between dispatch() and collect(): the stream that ended on its
+        # first token holds no slot any more, and nothing took its place
+        for rid, token, done in first:
+            if token == eos:
+                assert done and engine.in_flight
+                assert all(s is None or s.request_id != rid for s in engine.slots)
+                seen.append((rid, engine.free_slots, engine.free_pages))
+
+    engine = _stub(eos=eos)
+    out = _mixed_run(engine, after=after)
+    assert [(t, d) for r, t, d in out if r == stream] == [(eos, True)]
+    assert seen and seen[0][0] == stream and seen[0][1] >= 1
+    # the window it sat in gave it nothing, and its pages came back
+    assert engine.active == 0 and engine.free_slots == 3
+    engine.prefix_cache.evict(engine.allocator.num_pages)
+    assert engine.free_pages == engine.allocator.num_pages - 1
+    engine.check_invariants()
+
+
+def test_a_one_token_stream_keeps_its_slot_until_its_token_is_read():
+    """``max_new`` 1 ends a stream without the token's value, but not
+    before the chunk is done: the chunk was handed a view of the slot's
+    row of the block table, and freeing the slot zeroes that row."""
+    engine = _stub()
+    seen = []
+    read = engine._first_token
+
+    def spy(s, b, *rest):
+        # at the read: the slot and its row are as the chunk met them
+        seen.append((s.request_id, engine.slots[b] is s,
+                     engine._bt[b, : len(s.pages)].tolist() == s.pages))
+        return read(s, b, *rest)
+
+    engine._first_token = spy
+    engine.submit("long", [4, 5], 30)
+    engine.step()
+    engine.submit("one", list(range(20)), 1)  # two chunks, beside a window
+    first = engine.dispatch()
+    assert first == [] and engine.collect()
+    first = engine.dispatch()
+    assert [(r, d) for r, _, d in first] == [("one", True)] and engine.in_flight
+    assert seen == [("long", True, True), ("one", True, True)]
+    # read, it is gone: one slot of three is taken, by the other stream
+    assert engine.free_slots == 2
+    engine.collect()
+    engine.check_invariants()
+
+
+def test_speculation_keeps_the_read_before_the_launch():
+    from dora_tpu.metrics import ServingMetrics
+
+    plain, spec = _stub(), _stub(spec_k=2)
+    phases = spec.tracer.histograms = ServingMetrics().phases
+    out = _mixed_run(spec)
+    spec.tracer.close()
+    assert phases["first_token_wait"].count == len(_MIXED)
+    assert phases["first_token_read"].count == 0 and spec.launched_at is None
+    # the streams are the plain engine's; only the windows' packing differs
+    def by_stream(rows):
+        return {rid: [t for r, t, _ in rows if r == rid] for rid, _, _ in _MIXED}
+
+    assert by_stream(out) == by_stream(_mixed_run(plain))
+    # paused, the window needs no history before its launch: the read moves
+    spec.set_window(4, spec_on=False)
+    _mixed_run(spec)
+    spec.tracer.close()
+    assert phases["first_token_read"].count >= 8
+    assert spec._hist == [[] for _ in spec.slots]
+
+
+def test_set_slot_is_one_program_for_every_remainder_of_a_prompt():
+    engine = _stub()
+    for n in range(1, 34):  # every remainder modulo the chunk, twice
+        engine.submit(f"p{n}", list(range(n)), 3)
+        while engine.active:
+            engine.step()
+    assert engine._set_slot._cache_size() == 1
+    assert engine.chunk_prefill._cache_size() == 1
+    # a restored stream is seated by the same program
+    engine.submit("q", [1, 2, 3], 8)
+    engine.step()
+    state = engine.checkpoint_state()
+    other = _stub()
+    assert other.restore_state(state) == ["q"]
+    other.submit("r", [4, 5], 3)
+    while other.active:
+        other.step()
+    assert other._set_slot._cache_size() == 1
+
+
+def test_the_window_a_stream_joins_counts_its_first_token_as_emitted():
+    """The device's completion counter is rebuilt before the first token
+    is read: a stream of two tokens decodes ONE tick of the window it
+    joins and is frozen for the rest, as when the read came first."""
+    engine = _stub()
+    engine.submit("two", [1, 2, 3], 2)
+    engine.submit("long", [4, 5], 9)
+    first = engine.dispatch()
+    assert [(r, d) for r, _, d in first] == [("two", False)]
+    # as the window returns it: the first token, and the one tick
+    assert np.asarray(engine._emitted_dev).tolist() == [2, 0, 0]
+    row = np.asarray(engine._flight[0])[0]
+    assert (row[: engine.window] >= 0).tolist() == [True, False, False, False]
+    assert [(r, d) for r, _, d in engine.collect()] == [("two", True)]
