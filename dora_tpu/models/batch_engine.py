@@ -260,22 +260,27 @@ class PagedBatchEngine:
         (mat [B, K+1], tokens, positions, active, emitted, pools)
 
     **Slot state, the second cache kind.** A model whose streams own a
-    fixed recurrent state beside their pages (a state-space mixer) passes
-    ``init_slot_state(max_slots)`` -> a pytree of ``[max_slots, ...]``
-    arrays, row ``b`` being slot ``b``'s. It is never a leaf of
-    ``pools`` (every leaf of those is indexed by page). With it,
-    ``chunk_prefill`` takes two more trailing operands, the slot index
-    and the state, and ``window_step`` one, the state
+    fixed per-slot state beside their pages (a state-space mixer's
+    recurrent state; a sliding-window layer's ring of its last
+    ``window`` K/V rows) passes ``init_slot_state(max_slots)`` -> a
+    pytree of ``[max_slots, ...]`` arrays, row ``b`` being slot ``b``'s.
+    It is never a leaf of ``pools`` (every leaf of those is indexed by
+    page; a model may give only some of its layers leaves there). With
+    it, ``chunk_prefill`` takes two more trailing operands, the slot
+    index and the state, and ``window_step`` one, the state
     (models/vlm.make_paged_window, ``slot_state=True``); both return the
-    state last, updated in place. The programs keep it right: a chunk at
-    position 0 starts from zeros (the host makes no reset call), a
-    chunk's padding rows and a frozen or mid-prefill row's decode ticks
-    leave it as it was. So :meth:`preempt` just drops the slot,
-    :meth:`save_pools` / :meth:`restore_pools` carry the state beside
-    the pages for :meth:`restore_state` with pinned slots, and what
-    cannot take the state along is refused by name: the prefix cache (a
-    granted prefix would need the state at its end), speculation, LoRA,
-    and :meth:`admit_streams` of a stream in mid-decode.
+    state last, updated in place. The programs keep it right and the
+    host makes no reset call: a recurrent state starts from zeros in a
+    chunk at position 0; a ring needs no zero-start at all (a row counts
+    by the position it holds, and what an earlier stream left is masked
+    until overwritten); a chunk's padding rows and a frozen or
+    mid-prefill row's decode ticks leave the state as it was. So
+    :meth:`preempt` just drops the slot, :meth:`save_pools` /
+    :meth:`restore_pools` carry the state beside the pages for
+    :meth:`restore_state` with pinned slots, and what cannot take the
+    state along is refused by name: the prefix cache (a granted prefix
+    would need the state at its end), speculation, LoRA, and
+    :meth:`admit_streams` of a stream in mid-decode.
 
     With ``spec_k > 0`` (prompt-lookup speculation,
     models/vlm.make_paged_spec_window) the window signature instead
@@ -326,8 +331,8 @@ class PagedBatchEngine:
         self.window = window
         self.max_pages = max_seq // page_size
         self.pools = init_pool(num_pages)
-        #: per-slot recurrent state (None = the model has none): see the
-        #: class docstring. Donated to and replaced by both programs.
+        #: per-slot state (None = the model has none): see the class
+        #: docstring. Donated to and replaced by both programs.
         self.slot_state = None
         if init_slot_state is not None:
             for knob, on in (("a prefix cache", prefix_cache),
@@ -336,7 +341,7 @@ class PagedBatchEngine:
                 if on:
                     raise NotImplementedError(
                         f"a slot-state engine cannot run with {knob}: the "
-                        f"recurrent state would not follow")
+                        f"per-slot state would not follow")
             self.slot_state = init_slot_state(max_slots)
         self.allocator = PageAllocator(num_pages)
         #: shared-prefix subsystem (models/prefix_cache.py): radix
@@ -1392,15 +1397,15 @@ class PagedBatchEngine:
             )
         if bool(state.get("slot_state")) != (self.slot_state is not None):
             raise ValueError(
-                "checkpoint and engine disagree on a per-slot recurrent "
-                "state: restore it on an engine of the same model"
+                "checkpoint and engine disagree on a per-slot state: "
+                "restore it on an engine of the same model"
             )
         if self.slot_state is not None and not pin_slots and any(
             m.get("decode") for m in state.get("slots", [])
         ):
             raise RuntimeError(
                 "cannot admit a stream in mid-decode into another slot: "
-                "its recurrent state does not travel with the handoff "
+                "its per-slot state does not travel with the handoff "
                 "(no state transfer yet); re-submit it from its prompt"
             )
         restored: list[str] = []

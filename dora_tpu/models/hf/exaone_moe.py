@@ -1,0 +1,883 @@
+"""K-EXAONE causal LM (``model_type`` ``exaone_moe``) on the paged serving
+path, as ONE RANK of an expert group: sliding-window layers beside
+global ones (``layer_types``, three of 128 rows for every global one in
+K-EXAONE-236B-A23B), over a dense SwiGLU in the first layer(s) and a
+sigmoid-routed expert layer in the rest.
+
+The layer, as the published ``config.json`` gives it (``†`` = not
+settled by the config, an assumption written down in
+``KNOWN_ISSUES.md`` "PR 41"; the float32 reference of the same
+mathematics, whole sequence, is ``exaone_moe_reference.py``, where each
+† is a switch):
+
+    h = RMSNorm(x; input_layernorm)                              † pre-norm
+    q, k, v = h Wq, h Wk, h Wv                                   † no bias
+    q, k = RMSNorm_head(q; q_norm), RMSNorm_head(k; k_norm)      † one weight for all heads
+    sliding_attention: rotate-half rotary on q, k; key j visible to row i iff 0 <= i - j < window
+    full_attention:    no rotary †;                 key j visible iff j <= i
+    x = x + softmax(q k^T / sqrt(hd)) v Wo
+    x = x + MLP(RMSNorm(x; post_attention_layernorm))    dense, or the expert layer
+
+The expert layer is ``kimi_k2``'s letter for letter (``route``,
+``held_experts``, ``mlp``, ``swiglu``, ``expert_share`` are imported
+from there): the router keeps its published width, this rank computes
+what its own ``experts_held`` experts give, nothing stands in for the
+absent ranks.
+
+What this module adds to the serving path: **layers that differ in
+cache kind by layer type.**
+
+* a *window* layer never needs more than its last ``sliding_window``
+  rows, so it keeps exactly those, as a **ring a slot**: ``[slots,
+  window, 2 * KV * hd]`` a layer, position ``p`` in row ``p % window``,
+  roped keys then values. The rings are the engine's slot state
+  (``PagedBatchEngine(init_slot_state=...)``), never leaves of the
+  pools. A ring row is visible by position alone, so a new stream in a
+  used slot needs no zero-start: what an earlier stream left is masked
+  until this one has overwritten it. A decode tick writes row ``p %
+  window`` and attends the ring in one einsum; a chunk attends ``[the
+  ring as it stood] ++ [its own rows]`` under the band mask and then
+  writes its last ``min(valid, window)`` valid rows. Padding rows and
+  frozen rows leave the ring as it was. A window layer costs the same
+  at row 16,000 as at row 200, in both programs.
+* a *global* layer has pages under the engine's one block table;
+  ``pools`` holds leaves for those layers only, so a cached token costs
+  ``global layers x 2 x KV x hd`` values (8,192 B for two global layers
+  at bf16) and admission counts pages that a quarter of the layers use.
+  Decode and chunk go through ``layers.attend_blocks`` (plain XLA, a
+  block of ``ATTN_BLOCK`` rows at a time up to the longest live
+  context).
+
+Every matrix goes through ``ops/int8_matmul`` (the fused attention
+kernels hold ``wqkv`` and ``wo`` whole in VMEM: 113 MB of int8 here), the
+head through ``lm_head_argmax``. Text only; the multi-token-prediction
+layer is not served (``num_nextn_predict_layers`` must be 0 or is
+ignored with its weights unread).
+"""
+
+from __future__ import annotations
+
+import logging
+import os
+from dataclasses import dataclass
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+
+from dora_tpu import profiling
+from dora_tpu.models import layers as L
+from dora_tpu.models.hf import kimi_k2 as K
+# rotate-half rotary over the whole head; K and V of a position as one cached
+# row; ``layers.attend_blocks`` over such rows a block at a time
+from dora_tpu.models.hf.falcon_h1 import _attend, _kv_rows, rotate
+from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
+
+MODEL_TYPES = ("exaone_moe",)
+
+#: rows of one attention block of the global layers (a multiple of the
+#: page): their pool is read this many positions at a time, up to the
+#: longest live context.
+ATTN_BLOCK = 256
+#: device memory the default pool leaves to the programs' temporaries
+POOL_HEADROOM_BYTES = 4 << 30
+
+#: serving knobs of the Qwen path that this model refuses (KNOWN_ISSUES.md)
+NOT_OFFERED = {
+    "DORA_KV_INT8": "the int8 page kernels are fused into the Qwen "
+                    "attention kernels, which this model does not run, and "
+                    "a window layer's ring is no page",
+    "DORA_SPEC_K": "a rejected draft would have overwritten ring rows that "
+                   "the accepted prefix still needs; no snapshot is kept",
+    "DORA_LORA_DIR": "the grouped LoRA matmul is fused into the Qwen kernels",
+}
+
+#: the window layers' and the global layers' counters on the device
+SWA_COUNTERS = (
+    "swa_decode_ticks", "swa_row_ticks", "swa_ring_rows_read",
+    "global_kv_rows_read", "global_kv_rows_swept", "swa_chunks",
+    "swa_chunk_rows", "swa_chunk_positions",
+)
+
+_log = logging.getLogger(__name__)
+
+
+@dataclass(frozen=True)
+class ExaoneMoeConfig:
+    vocab: int
+    dim: int
+    layers: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    ffn: int
+    moe_ffn: int
+    n_experts: int  # the router's width: every expert of the model
+    top_k: int
+    n_shared: int
+    routed_scale: float
+    norm_topk: bool
+    norm_eps: float
+    rope_theta: float
+    max_seq: int
+    window: int
+    #: per layer: True = sliding-window attention, False = global
+    sliding: tuple
+    #: per layer: True = expert layer, False = dense MLP
+    sparse: tuple
+    #: this rank's share: experts ``expert_first .. +experts_held``
+    expert_first: int
+    experts_held: int
+
+    @property
+    def q_width(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def kv_width(self) -> int:
+        return self.kv_heads * self.head_dim
+
+    @property
+    def window_layers(self) -> tuple:
+        return tuple(i for i, s in enumerate(self.sliding) if s)
+
+    @property
+    def global_layers(self) -> tuple:
+        return tuple(i for i, s in enumerate(self.sliding) if not s)
+
+    @property
+    def moe_layers(self) -> int:
+        return sum(self.sparse)
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """What a cached position holds in the paged pool: K and V of the
+        GLOBAL layers alone (8,192 B for K-EXAONE's two of eight)."""
+        return (len(self.global_layers) * 2 * self.kv_width
+                * jnp.dtype(L.compute_dtype()).itemsize)
+
+    @property
+    def ring_bytes_per_slot(self) -> int:
+        """The window layers' rings of one slot."""
+        return (len(self.window_layers) * self.window * 2 * self.kv_width
+                * jnp.dtype(L.compute_dtype()).itemsize)
+
+    @classmethod
+    def from_hf(cls, config: dict, max_seq: int | None = None,
+                ep_rank: int | None = None) -> "ExaoneMoeConfig":
+        if config.get("model_type") not in MODEL_TYPES:
+            raise ValueError(
+                f"model_type {config.get('model_type')!r} is not one of "
+                f"{MODEL_TYPES}"
+            )
+        n = config["num_hidden_layers"]
+        kinds = config.get("layer_types")
+        if kinds is None or len(kinds) != n:
+            raise ValueError(
+                f"exaone_moe: layer_types must name all {n} layers, got "
+                f"{kinds!r}")
+        unknown = set(kinds) - {"sliding_attention", "full_attention"}
+        if unknown:
+            raise NotImplementedError(
+                f"exaone_moe: layer_types {sorted(unknown)} is not written")
+        sliding = tuple(k == "sliding_attention" for k in kinds)
+        window = config.get("sliding_window")
+        if any(sliding) and not window:
+            raise ValueError(
+                "exaone_moe: sliding_attention layers need sliding_window")
+        mlp_kinds = config.get("mlp_layer_types")
+        if mlp_kinds is None:
+            dense = config.get("first_k_dense_replace", 0)
+            mlp_kinds = ["dense"] * dense + ["sparse"] * (n - dense)
+        if len(mlp_kinds) != n or set(mlp_kinds) - {"dense", "sparse"}:
+            raise ValueError(
+                f"exaone_moe: mlp_layer_types must name all {n} layers as "
+                f"'dense' or 'sparse', got {mlp_kinds!r}")
+        if config.get("scoring_func", "sigmoid") != "sigmoid":
+            raise NotImplementedError(
+                f"exaone_moe: scoring_func {config['scoring_func']!r} is not "
+                f"written (only sigmoid)")
+        if config.get("n_group", 1) != 1 or config.get("topk_group", 1) != 1:
+            raise NotImplementedError(
+                "exaone_moe: group-limited routing (n_group/topk_group > 1) "
+                "is not written; K-EXAONE has 1")
+        for key in ("attention_bias", "mlp_bias"):
+            if config.get(key):
+                raise NotImplementedError(f"exaone_moe: {key} is not written")
+        rope = config.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default" or config.get(
+                "rope_scaling"):
+            raise NotImplementedError(
+                f"exaone_moe: scaled rotary "
+                f"{config.get('rope_scaling') or rope!r} is not written")
+        first, held = K.expert_share(
+            {"n_routed_experts": config["num_experts"],
+             "ep_size": config.get("ep_size")}, ep_rank)
+        return cls(
+            vocab=config["vocab_size"],
+            dim=config["hidden_size"],
+            layers=n,
+            heads=config["num_attention_heads"],
+            kv_heads=config["num_key_value_heads"],
+            head_dim=config.get("head_dim")
+            or config["hidden_size"] // config["num_attention_heads"],
+            ffn=config["intermediate_size"],
+            moe_ffn=config["moe_intermediate_size"],
+            n_experts=config["num_experts"],
+            top_k=config["num_experts_per_tok"],
+            n_shared=config.get("num_shared_experts") or 0,
+            routed_scale=float(config.get("routed_scaling_factor", 1.0)),
+            norm_topk=bool(config.get("norm_topk_prob", True)),
+            norm_eps=config.get("rms_norm_eps", 1e-5),
+            rope_theta=float(
+                rope.get("rope_theta", config.get("rope_theta", 1e6))),
+            max_seq=max_seq
+            or min(config.get("max_position_embeddings", 2048), 2048),
+            window=int(window or 0),
+            sliding=sliding,
+            sparse=tuple(k == "sparse" for k in mlp_kinds),
+            expert_first=first,
+            experts_held=held,
+        )
+
+
+# ---------------------------------------------------------------------------
+# loading: one layer at a time, only the held experts, int8 on the device
+# ---------------------------------------------------------------------------
+
+
+def _swiglu(get, prefix: str) -> dict:
+    return {
+        "w_gateup": _quantize_t(
+            get(prefix + "gate_proj.weight"), get(prefix + "up_proj.weight")),
+        "w_down": _quantize_t(get(prefix + "down_proj.weight")),
+    }
+
+
+def load_layer(get, cfg: ExaoneMoeConfig, i: int, prefix: str = "model.") -> dict:
+    """Layer ``i``'s serving parameters from ``get(name) -> device
+    array`` under the HF tensor names (EXAONE-4's, with DeepSeek-V3's for
+    the expert layer: †). Reads the held experts only."""
+    lp = f"{prefix}layers.{i}."
+    a, m = lp + "self_attn.", lp + "mlp."
+    block = {
+        "attn_norm": get(lp + "input_layernorm.weight"),
+        "wqkv": _quantize_t(get(a + "q_proj.weight"), get(a + "k_proj.weight"),
+                            get(a + "v_proj.weight")),
+        "q_norm": get(a + "q_norm.weight"),
+        "k_norm": get(a + "k_norm.weight"),
+        "wo": _quantize_t(get(a + "o_proj.weight")),
+        "ffn_norm": get(lp + "post_attention_layernorm.weight"),
+    }
+    if not cfg.sparse[i]:
+        block["dense"] = _swiglu(get, m)
+        return block
+    block["router"] = get(m + "gate.weight").T.astype(L.compute_dtype())
+    block["router_bias"] = get(m + "gate.e_score_correction_bias").astype(
+        jnp.float32)
+    if cfg.n_shared:
+        block["shared"] = _swiglu(get, m + "shared_experts.")
+    block["experts"] = [
+        _swiglu(get, f"{m}experts.{e}.")
+        for e in range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
+    ]
+    return block
+
+
+def load(model_dir: str | Path, max_seq: int | None = None,
+         ep_rank: int | None = None):
+    """(config, serving params) from a HF checkpoint directory, as
+    ``kimi_k2.load``: tensors go from the file to the device one at a
+    time and are quantized there, the embedding, the routers and the
+    norms stay in the compute dtype, absent experts are never read."""
+    cfg = ExaoneMoeConfig.from_hf(read_config(model_dir), max_seq, ep_rank)
+    files = TensorFiles(model_dir)
+    prefix = "model." if "model.embed_tokens.weight" in files else ""
+    dtype = L.compute_dtype()
+
+    def get(name: str):
+        return jnp.asarray(files.get(name)).astype(dtype)
+
+    params = {
+        "embed": get(f"{prefix}embed_tokens.weight"),
+        "out_norm": get(f"{prefix}norm.weight"),
+        "lm_head": _quantize_t(get("lm_head.weight")),
+        "blocks": {
+            str(i): load_layer(get, cfg, i, prefix) for i in range(cfg.layers)
+        },
+    }
+    return cfg, params
+
+
+def quantize_decode(params, cfg=None):
+    """The serving layout IS what :func:`load` returns (int8 from the
+    start); kept so that ``llm_server`` treats every model module alike."""
+    return params
+
+
+# ---------------------------------------------------------------------------
+# attention: a ring for the window layers, pages for the global ones
+# ---------------------------------------------------------------------------
+
+
+def _qkv(blk, cfg: ExaoneMoeConfig, u, rope):
+    """Normed rows ``u [N, dim]`` -> q ``[N, KV, G, hd]``, k and v
+    ``[N, KV, hd]``: projected, q and k normed over the head, and roped
+    where ``rope`` (``(cos, sin) [N, hd/2]``) is given."""
+    n = u.shape[0]
+    kv, hd = cfg.kv_heads, cfg.head_dim
+    p = L.matmul(u, blk["wqkv"])
+    q = p[:, : cfg.q_width].reshape(n, cfg.heads, hd)
+    k = p[:, cfg.q_width : cfg.q_width + cfg.kv_width].reshape(n, kv, hd)
+    v = p[:, cfg.q_width + cfg.kv_width :].reshape(n, kv, hd)
+    with jax.named_scope("qk_norm"):
+        q = L.rms_norm(q, blk["q_norm"], cfg.norm_eps)
+        k = L.rms_norm(k, blk["k_norm"], cfg.norm_eps)
+    if rope is not None:
+        cos, sin = rope
+        q = rotate(q, cos[:, None], sin[:, None])
+        k = rotate(k, cos[:, None], sin[:, None])
+    return q.reshape(n, kv, cfg.heads // kv, hd), k, v
+
+
+def _split_rows(cfg: ExaoneMoeConfig, rows):
+    """Cached rows ``[..., 2 * KV * hd]`` -> keys, values ``[..., KV, hd]``."""
+    rows = rows.reshape(*rows.shape[:-1], 2, cfg.kv_heads, cfg.head_dim)
+    return rows[..., 0, :, :], rows[..., 1, :, :]
+
+
+def _softmax_mix(cfg: ExaoneMoeConfig, s, seen, v, mix: str):
+    """Whole softmax of float32 scores ``s`` under ``seen``, then the
+    values' sum (``mix`` einsum). float32 out."""
+    s = jnp.where(seen, s * cfg.head_dim ** -0.5, -1e30)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = jnp.where(seen, p, 0.0)
+    out = jnp.einsum(mix, p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out / p.sum(-1)[..., None]
+
+
+def _out(blk, cfg: ExaoneMoeConfig, ctx, dtype):
+    return L.matmul(ctx.astype(dtype).reshape(-1, cfg.q_width), blk["wo"])
+
+
+def window_decode(blk, cfg: ExaoneMoeConfig, u, ring, positions, active, rope):
+    """A window layer's decode tick: ``u [B, dim]`` (normed), row = slot,
+    ``ring [B, W, 2 * KV * hd]``. An active row writes its K/V into ring
+    row ``p % W`` and attends the ring rows that hold positions ``p - W +
+    1 .. p`` (all of them once ``p >= W - 1``; before that the rows past
+    ``p`` are an earlier stream's and masked). Returns (output [B, dim],
+    ring)."""
+    with jax.named_scope("attn_window"):
+        b, w = u.shape[0], cfg.window
+        q, k, v = _qkv(blk, cfg, u, rope)
+        rows, at = jnp.arange(b), positions % w
+        new = _kv_rows(cfg, k, v).astype(ring.dtype)
+        ring = ring.at[rows, at].set(
+            jnp.where(active[:, None], new, ring[rows, at]))
+        keys, values = _split_rows(cfg, ring)  # [B, W, KV, hd]
+        j = jnp.arange(w)
+        seen = (j[None, :] <= positions[:, None]) | (positions[:, None] >= w)
+        s = jnp.einsum("bkgd,bwkd->bkgw", q, keys,
+                       preferred_element_type=jnp.float32)
+        ctx = _softmax_mix(cfg, s, seen[:, None, None, :], values,
+                           "bkgw,bwkd->bkgd")
+        return _out(blk, cfg, ctx, u.dtype), ring
+
+
+def window_chunk(blk, cfg: ExaoneMoeConfig, u, ring, slot, position, valid,
+                 rope):
+    """A window layer's prefill chunk: ``u [C, dim]`` (normed) at
+    positions ``position..position+C-1``, of which the first ``valid``
+    are the prompt's; ``ring [slots, W, ...]``, row ``slot`` this
+    stream's. Every row attends ``[the ring as it stood] ++ [the chunk's
+    own rows]`` under the band mask (a ring row counts by the position it
+    holds: none at position 0), then the last ``min(valid, W)`` valid
+    rows go into the ring. Returns (output [C, dim], ring)."""
+    with jax.named_scope("attn_window"):
+        c, w = u.shape[0], cfg.window
+        q, k, v = _qkv(blk, cfg, u, rope)
+        mine = ring[slot]  # [W, 2 * KV * hd]
+        j = jnp.arange(w)
+        # the position ring row j holds once position - 1 was written
+        last = position - 1
+        held = last - (last - j) % w  # < 0: not this stream's
+        new = _kv_rows(cfg, k, v).astype(ring.dtype)
+        keys, values = _split_rows(cfg, jnp.concatenate([mine, new], 0))
+        key_pos = jnp.concatenate([held, position + jnp.arange(c)])
+        q_pos = position + jnp.arange(c)
+        back = q_pos[:, None] - key_pos[None, :]
+        seen = (back >= 0) & (back < w) & (key_pos[None, :] >= 0)
+        s = jnp.einsum("qkgd,tkd->qkgt", q, keys,
+                       preferred_element_type=jnp.float32)
+        ctx = _softmax_mix(cfg, s, seen[:, None, None, :], values,
+                           "qkgt,tkd->qkgd")
+        # ring row j <- the last valid chunk row whose position is j mod W
+        end = position + valid - 1
+        src = end - (end - j) % w - position
+        mine = jnp.where((src >= 0)[:, None], new[jnp.maximum(src, 0)], mine)
+        ring = jax.lax.dynamic_update_index_in_dim(ring, mine, slot, 0)
+        return _out(blk, cfg, ctx, u.dtype), ring
+
+
+def global_decode(blk, cfg: ExaoneMoeConfig, u, pool, positions, block_tables,
+                  block: int):
+    """A global layer's decode tick: writes each row's K/V into its page
+    (a frozen row's, at position 0 of a zeroed table row, into the null
+    page), then attends positions ``0..positions[b]`` through the block
+    table. No rotary. Returns (output [B, dim], pool)."""
+    with jax.named_scope("attn_global"):
+        page = pool.shape[1]
+        b = u.shape[0]
+        q, k, v = _qkv(blk, cfg, u, None)
+        pool = pool.at[
+            block_tables[jnp.arange(b), positions // page], positions % page
+        ].set(_kv_rows(cfg, k, v).astype(pool.dtype))
+        per = block // page
+
+        def kv_of(j):
+            ids = jax.lax.dynamic_slice_in_dim(block_tables, j * per, per, 1)
+            return _split_rows(cfg, pool[ids].reshape(b, block, -1))
+
+        def visible(j):
+            t = j * block + jnp.arange(block)
+            return (t[None, :] <= positions[:, None])[:, None, None, :]
+
+        ctx = _attend(cfg, q, kv_of, visible, positions.max() // block + 1,
+                      "bkgd,btkd->bkgt", "bkgt,btkd->bkgd")
+        return _out(blk, cfg, ctx, u.dtype), pool
+
+
+def global_chunk(blk, cfg: ExaoneMoeConfig, u, pool, position, block_table,
+                 block: int):
+    """A global layer's prefill chunk: writes the chunk's K/V as whole
+    pages, then every row attends causally over ``0..its own position``."""
+    with jax.named_scope("attn_global"):
+        page = pool.shape[1]
+        c = u.shape[0]
+        q, k, v = _qkv(blk, cfg, u, None)
+        ids = jax.lax.dynamic_slice_in_dim(block_table, position // page,
+                                           c // page)
+        pool = pool.at[ids].set(
+            _kv_rows(cfg, k, v).astype(pool.dtype).reshape(
+                c // page, page, 2 * cfg.kv_width))
+        per = block // page
+        q_pos = position + jnp.arange(c)
+
+        def kv_of(j):
+            ids = jax.lax.dynamic_slice_in_dim(block_table, j * per, per)
+            return _split_rows(cfg, pool[ids].reshape(block, -1))
+
+        def visible(j):
+            t = j * block + jnp.arange(block)
+            return (t[None, :] <= q_pos[:, None])[:, None, None, :]
+
+        ctx = _attend(cfg, q, kv_of, visible, (position + c - 1) // block + 1,
+                      "qkgd,tkd->qkgt", "qkgt,tkd->qkgd")
+        return _out(blk, cfg, ctx, u.dtype), pool
+
+
+# ---------------------------------------------------------------------------
+# the stack, the two programs
+# ---------------------------------------------------------------------------
+
+
+def init_counters(cfg: ExaoneMoeConfig) -> dict:
+    """The counters on the device, an operand and a result of their own
+    of both programs (a buffer each: donated one by one), int32 that
+    wraps: ``moe`` are ``kimi_k2``'s routing counters under its names,
+    ``swa`` this module's (:data:`SWA_COUNTERS`)."""
+    return {
+        "moe": K.init_counters(cfg),
+        "swa": {name: jnp.zeros((), jnp.int32) for name in SWA_COUNTERS},
+    }
+
+
+def _layers(params, cfg: ExaoneMoeConfig, x, pools, state, stats, window,
+            attend, live, counted, decode: bool):
+    """The stack: ``window(blk, normed rows, ring) -> (out, ring)`` for a
+    sliding layer, ``attend(blk, normed rows, pool) -> (out, pool)`` for
+    a global one, then ``kimi_k2.mlp``. Returns (rows, pools, state,
+    the routing counters)."""
+    pools, state = dict(pools), dict(state)
+    moe = dict(stats)
+    per_layer = []
+    for i in range(cfg.layers):
+        blk, key = params["blocks"][str(i)], str(i)
+        u = L.rms_norm(x, blk["attn_norm"], cfg.norm_eps)
+        if cfg.sliding[i]:
+            a, ring = window(blk, u, state[key]["kv"])
+            state[key] = {"kv": ring}
+        else:
+            a, kv = attend(blk, u, pools[key]["kv"])
+            pools[key] = {"kv": kv}
+        x = x + a.astype(x.dtype)
+        y, counters = K.mlp(
+            blk, cfg, L.rms_norm(x, blk["ffn_norm"], cfg.norm_eps), live,
+            counted)
+        x = x + y
+        if counters is not None:
+            tokens, pairs, per_expert = counters
+            moe["tokens"] = moe["tokens"] + tokens
+            moe["local_pairs"] = moe["local_pairs"] + pairs
+            per_layer.append(per_expert)
+            if decode:
+                moe["touched"] = moe["touched"] + (per_expert > 0).sum(
+                    dtype=jnp.int32)
+    if per_layer:
+        moe["expert_tokens"] = moe["expert_tokens"] + jnp.stack(per_layer)
+        if decode:
+            moe["decode_ticks"] = moe["decode_ticks"] + counted.any().astype(
+                jnp.int32)
+    return x, pools, state, moe
+
+
+def _rope_rows(cfg: ExaoneMoeConfig, positions):
+    cos, sin = L.rope_table(cfg.max_seq, cfg.head_dim, base=cfg.rope_theta)
+    return cos[positions], sin[positions]
+
+
+def _add(stats: dict, **adds) -> dict:
+    return {k: v + adds.get(k, 0) for k, v in stats.items()}
+
+
+def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
+                     positions, block_tables, active, block: int = ATTN_BLOCK):
+    """One decode step for B = slots independent sequences: tokens,
+    positions, active ``[B]``, block_tables ``[B, max_pages]`` (a frozen
+    row comes with position 0 and a zeroed table row, so its global K/V
+    write lands in the null page; its rings have no null row and are
+    kept by its ``active`` bit; its routing is neither computed on nor
+    counted). Returns (the final rows [B, dim], pools, state, stats)."""
+    rope = _rope_rows(cfg, positions)
+    x = params["embed"].astype(L.compute_dtype())[tokens]
+
+    def window(blk, u, ring):
+        return window_decode(blk, cfg, u, ring, positions, active, rope)
+
+    def attend(blk, u, pool):
+        return global_decode(blk, cfg, u, pool, positions, block_tables, block)
+
+    x, pools, state, moe = _layers(params, cfg, x, pools, state, stats["moe"],
+                                   window, attend, active, active, True)
+    i32 = jnp.int32
+    live = active.sum(dtype=i32)
+    seen = jnp.where(active, positions + 1, 0)
+    swa = _add(
+        stats["swa"],
+        swa_decode_ticks=(live > 0).astype(i32), swa_row_ticks=live,
+        swa_ring_rows_read=len(cfg.window_layers) * jnp.minimum(
+            seen, cfg.window).sum(dtype=i32),
+        global_kv_rows_read=len(cfg.global_layers) * seen.sum(dtype=i32),
+        global_kv_rows_swept=len(cfg.global_layers) * tokens.shape[0] * block
+        * (positions.max() // block + 1).astype(i32),
+    )
+    return x, pools, state, {"moe": moe, "swa": swa}
+
+
+def paged_chunk_rows(params, cfg: ExaoneMoeConfig, chunk_ids, pools, state,
+                     stats, position, block_table, valid, slot,
+                     block: int = ATTN_BLOCK):
+    """One prefill chunk of the stream in ``slot``: ``chunk_ids [C]`` at
+    positions ``position..position+C-1`` (page-aligned), of which the
+    first ``valid`` are the prompt's. ``position``, ``valid`` and
+    ``slot`` are traced: one program for every chunk. Every row is
+    computed; the routing counters count the ``valid`` ones."""
+    c = chunk_ids.shape[0]
+    rope = _rope_rows(cfg, position + jnp.arange(c))
+    x = params["embed"].astype(L.compute_dtype())[chunk_ids]
+    counted = jnp.arange(c) < valid
+
+    def window(blk, u, ring):
+        return window_chunk(blk, cfg, u, ring, slot, position, valid, rope)
+
+    def attend(blk, u, pool):
+        return global_chunk(blk, cfg, u, pool, position, block_table, block)
+
+    x, pools, state, moe = _layers(
+        params, cfg, x, pools, state, stats["moe"], window, attend,
+        jnp.ones((c,), bool), counted, False)
+    i32 = jnp.int32
+    swa = _add(stats["swa"], swa_chunks=jnp.ones((), i32),
+               swa_chunk_rows=valid.astype(i32),
+               swa_chunk_positions=position.astype(i32))
+    return x, pools, state, {"moe": moe, "swa": swa}
+
+
+def head_logits(params, cfg: ExaoneMoeConfig, x):
+    h = L.rms_norm(x, params["out_norm"], cfg.norm_eps)
+    return L.matmul(h, params["lm_head"]).astype(jnp.float32)
+
+
+def head_argmax(params, cfg: ExaoneMoeConfig, x):
+    from dora_tpu.ops import decode_block as DB
+
+    w = params["lm_head"]
+    return DB.lm_head_argmax(x, params["out_norm"], w["int8"], w["scale"],
+                             eps=cfg.norm_eps)
+
+
+def paged_batch_logits(params, cfg, *args, **kw):
+    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
+    return head_logits(params, cfg, x), *rest
+
+
+def paged_chunk_logits(params, cfg, *args, **kw):
+    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
+    return head_logits(params, cfg, x), *rest
+
+
+def fused_paged_batch_step(params, cfg, *args, **kw):
+    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
+    return head_argmax(params, cfg, x), *rest
+
+
+def fused_paged_chunk_step(params, cfg, *args, **kw):
+    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
+    return head_argmax(params, cfg, x), *rest
+
+
+def window_program(params, cfg, k: int, eos, block: int, tokens, pools,
+                   stats, positions, bts, active, emitted, max_new, state):
+    """The K-tick decode window (models/vlm.make_paged_window with a
+    slot state) over :func:`fused_paged_batch_step`: the counters ride
+    the window's carry beside the rings and come back apart. Returns
+    (the window's own results — pools, then state, last — and stats)."""
+    from dora_tpu.models import vlm as _vlm
+
+    def batch(tokens, pools, positions, bts, active, carried):
+        nxt, pools, state, stats = fused_paged_batch_step(
+            params, cfg, tokens, pools, *carried, positions, bts, active,
+            block=block)
+        return nxt, pools, (state, stats)
+
+    *out, (state, stats) = _vlm.make_paged_window(
+        batch, k=k, eos=eos, slot_state=True)(
+        tokens, pools, positions, bts, active, emitted, max_new,
+        (state, stats))
+    return (*out, state), stats
+
+
+# ---------------------------------------------------------------------------
+# the pool, the rings and the engine
+# ---------------------------------------------------------------------------
+
+
+def init_page_pool(cfg: ExaoneMoeConfig, num_pages: int, page_size: int,
+                   dtype=None) -> dict:
+    """K/V page pools of the GLOBAL layers alone, ``{layer: {"kv": [P,
+    page, 2 * KV * hd]}}``: a cached position is one row a layer, its
+    keys then its values (row-major with a lane multiple as the minor
+    dimension, so XLA:TPU scatters into it in place). Page 0 is the null
+    page."""
+    dtype = dtype or L.compute_dtype()
+    shape = (num_pages, page_size, 2 * cfg.kv_width)
+    return {str(i): {"kv": jnp.zeros(shape, dtype)} for i in cfg.global_layers}
+
+
+def init_slot_state(cfg: ExaoneMoeConfig, max_slots: int) -> dict:
+    """The rings of every slot, for the WINDOW layers alone: ``{layer:
+    {"kv": [slots, window, 2 * KV * hd]}}``."""
+    shape = (max_slots, cfg.window, 2 * cfg.kv_width)
+    return {str(i): {"kv": jnp.zeros(shape, L.compute_dtype())}
+            for i in cfg.window_layers}
+
+
+def page_pool_bytes(cfg: ExaoneMoeConfig, page_size: int) -> int:
+    """Bytes one page takes over the layers that have pages."""
+    return page_size * cfg.kv_bytes_per_token
+
+
+def pages_that_fit(cfg: ExaoneMoeConfig, limit: int, used: int,
+                   max_slots: int, page_size: int) -> int:
+    """The rule of :func:`default_num_pages`, in plain numbers."""
+    fits = (limit - used - POOL_HEADROOM_BYTES) // page_pool_bytes(
+        cfg, page_size)
+    return int(max(min(max_slots * cfg.max_seq // page_size + 1, fits),
+                   2 * cfg.max_seq // page_size))
+
+
+def default_num_pages(cfg: ExaoneMoeConfig, max_slots: int,
+                      page_size: int) -> int:
+    """The pool's default size, a rule in bytes as ``ouro``'s: every
+    slot may reach ``max_seq``, capped by what the device has
+    (``bytes_limit``) less what is in use now (the weights) less
+    :data:`POOL_HEADROOM_BYTES`; never fewer than two streams' worth. At
+    K-EXAONE's cut on a 16 GB v5e the cap does not bind: 16 x 16,384 rows
+    x 8,192 B = 2.15 GB. Where the device reports no memory figures (the
+    CPU) ``4 * max_seq`` rows."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit, used = stats.get("bytes_limit"), stats.get("bytes_in_use")
+    if not limit or used is None:
+        return 4 * cfg.max_seq // page_size
+    return pages_that_fit(cfg, limit, used, max_slots, page_size)
+
+
+class SwaMoeCounters:
+    """The counters of one engine: the device arrays the two programs
+    take and give back (``device``) and their host side, which adds up
+    the int32 differences. :meth:`read` fetches a few hundred bytes;
+    ``llm_server``'s 1 Hz report calls it at a window boundary, after
+    ``collect()``. The routing counters come out under ``kimi_k2``'s
+    names (one reader serves both configurations)."""
+
+    def __init__(self, cfg: ExaoneMoeConfig, page_size: int):
+        self.device = init_counters(cfg)
+        #: set by :func:`make_paged_engine`: whose pages ``read`` counts
+        self.engine = None
+        self._cfg = cfg
+        self._page_bytes = page_pool_bytes(cfg, page_size)
+        self._last = None
+        self._expert_tokens = [0] * cfg.experts_held
+        self.totals = dict.fromkeys(
+            ("tokens", "local_pairs", "decode_ticks", "touched")
+            + SWA_COUNTERS, 0)
+
+    def read(self) -> dict:
+        import numpy as np
+
+        now = jax.tree.map(lambda v: np.asarray(v).astype(np.int64),
+                           self.device)
+        last = self._last or jax.tree.map(np.zeros_like, now)
+        self._last = now
+        gained = jax.tree.map(lambda a, b: (a - b) & 0xFFFFFFFF, now, last)
+        t = self.totals
+        for group in ("moe", "swa"):
+            for name, d in gained[group].items():
+                if name != "expert_tokens":
+                    t[name] += int(d)
+        self._expert_tokens = [
+            a + int(b) for a, b in zip(
+                self._expert_tokens, gained["moe"]["expert_tokens"].sum(0))]
+        ticks = t["decode_ticks"] * max(self._cfg.moe_layers, 1)
+        engine = self.engine
+        return {
+            "moe_tokens": t["tokens"],
+            "moe_local_pairs": t["local_pairs"],
+            "moe_expert_tokens": list(self._expert_tokens),
+            "moe_experts_touched": (
+                round(t["touched"] / ticks, 4) if ticks else None),
+            # raw, for a reader that takes it over a capture's ticks
+            "moe_touched": t["touched"],
+            **{name: t[name] for name in SWA_COUNTERS},
+            "kv_bytes_per_token": self._cfg.kv_bytes_per_token,
+            "kv_pool_bytes": engine.allocator.num_pages * self._page_bytes,
+            "kv_pages_free": engine.allocator.free_pages,
+            "swa_ring_bytes": self._cfg.ring_bytes_per_slot * engine.max_slots,
+        }
+
+
+def flops_per_token(cfg: ExaoneMoeConfig) -> float:
+    """Weight-matmul FLOPs of one token on this rank (no score term):
+    attention, the dense layers, the shared expert, the router, the
+    expected ``top_k * held / n_experts`` routed pairs a layer, the head."""
+    attn = cfg.dim * (cfg.q_width + 2 * cfg.kv_width) + cfg.q_width * cfg.dim
+    expert = 3 * cfg.dim * cfg.moe_ffn
+    moe = (cfg.dim * cfg.n_experts + cfg.n_shared * expert
+           + cfg.top_k * cfg.experts_held / cfg.n_experts * expert)
+    dense = 3 * cfg.dim * cfg.ffn
+    return 2.0 * (
+        cfg.layers * attn + cfg.moe_layers * moe
+        + (cfg.layers - cfg.moe_layers) * dense + cfg.dim * cfg.vocab)
+
+
+def make_paged_engine(params, cfg: ExaoneMoeConfig, *, max_slots: int = 16,
+                      eos: int | None = None, page_size: int = 16,
+                      chunk: int | None = None,
+                      num_pages: int | None = None,
+                      window: int | None = None,
+                      prefix_cache: bool | None = None,
+                      prefix_cache_pages: int | None = None,
+                      attn_block: int | None = None):
+    """The paged continuous-batching engine
+    (models/batch_engine.PagedBatchEngine) with the window layers' rings
+    as its slot state and pages for the global layers alone: the same
+    scheduler, allocator and K-tick window as the other families.
+    ``num_pages`` defaults to :func:`default_num_pages`. **No prefix
+    cache, whatever is asked**: a granted prefix would need the window
+    layers' last ``sliding_window`` rows at its end, and none are kept
+    at a page boundary. Speculation, LoRA and int8 pages are not offered
+    (KNOWN_ISSUES.md, PR 41)."""
+    from dora_tpu.models.batch_engine import PagedBatchEngine
+
+    for knob, why in NOT_OFFERED.items():
+        if os.environ.get(knob, "0") not in ("", "0"):
+            raise NotImplementedError(
+                f"exaone_moe: {knob} is not offered: {why}")
+    if cfg.window % page_size:
+        raise NotImplementedError(
+            f"exaone_moe: sliding_window {cfg.window} is no multiple of the "
+            f"page ({page_size} rows)")
+    if prefix_cache or prefix_cache_pages:
+        _log.warning(
+            "exaone_moe: the prefix cache is off for this model: a granted "
+            "prefix needs the window layers' rows at its end, and none are "
+            "kept")
+    chunk = chunk or min(256, cfg.max_seq)
+    if attn_block is None:
+        attn_block = ATTN_BLOCK if cfg.max_seq % ATTN_BLOCK == 0 else chunk
+    assert attn_block % page_size == 0 and cfg.max_seq % attn_block == 0, (
+        attn_block, page_size, cfg.max_seq,
+    )
+    if num_pages is None:
+        num_pages = default_num_pages(cfg, max_slots, page_size)
+    if window is None:
+        window = int(os.environ.get("DORA_MULTISTEP_K", "8"))
+
+    counters = SwaMoeCounters(cfg, page_size)
+
+    # params ride as an argument, never a closed-over constant (see
+    # qwen2.make_paged_engine); the pools, the counters and the rings are
+    # arguments 2, 3 and 9 (6 of the chunk), hence the donation. The
+    # engine sees pools and rings; the counters stay here.
+    def window_factory(k, sk):
+        assert not sk, "exaone_moe: no speculative window"
+
+        def program(p, *args):
+            return window_program(p, cfg, k, eos, attn_block, *args)
+
+        jitted = jax.jit(program, donate_argnums=(2, 3, 9))
+
+        def window_step(tokens, pools, positions, bts, active, emitted,
+                        max_new, state):
+            out, counters.device = jitted(
+                params, tokens, pools, counters.device, positions, bts,
+                active, emitted, max_new, state)
+            return out
+
+        return window_step
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return fused_paged_chunk_step(p, cfg, ids, pools, state, stats,
+                                      position, bt, valid, slot,
+                                      block=attn_block)
+
+    chunk_jitted = jax.jit(step, donate_argnums=(2, 3, 6))
+
+    def chunk_prefill(ids, pools, position, bt, valid, slot, state):
+        greedy, pools, state, counters.device = chunk_jitted(
+            params, ids, pools, counters.device, position, bt, state, valid,
+            slot)
+        return greedy, pools, state
+
+    engine = PagedBatchEngine(
+        init_pool=lambda n: init_page_pool(cfg, n, page_size),
+        init_slot_state=lambda slots: init_slot_state(cfg, slots),
+        chunk_prefill=chunk_prefill,
+        chunk_valid_rows=True,
+        window_step=window_factory(window, 0),
+        window_factory=window_factory,
+        window=window,
+        max_slots=max_slots,
+        max_seq=cfg.max_seq,
+        page_size=page_size,
+        chunk=chunk,
+        num_pages=num_pages,
+        eos=eos,
+    )
+    engine.flops_per_token = flops_per_token(cfg)
+    engine.device_peak_flops = profiling.detect_peak_flops()
+    counters.engine = engine
+    engine.model_counters = counters.read
+    return engine

@@ -112,6 +112,7 @@ MODEL_MODULES = {
     "deepseek_v3": "kimi_k2",
     "falcon_h1": "falcon_h1",
     "ouro": "ouro",
+    "exaone_moe": "exaone_moe",
 }
 
 

@@ -230,7 +230,17 @@ def _tiny_ouro(tmp_path):
     return ouro.load(tmp_path / "ckpt", max_seq=64)
 
 
-_TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon, "ouro": _tiny_ouro}
+def _tiny_exaone(tmp_path):
+    from test_exaone_moe import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import exaone_moe
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return exaone_moe.load(tmp_path / "ckpt", max_seq=64)
+
+
+_TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon, "ouro": _tiny_ouro,
+         "exaone_moe": _tiny_exaone}
 
 
 def _engine_programs(module_name, monkeypatch, tmp_path):
@@ -277,7 +287,8 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
     return seen
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro"])
+@pytest.mark.parametrize(
+    "module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe"])
 def test_engine_programs_take_the_weights_as_arguments(
     module_name, monkeypatch, tmp_path
 ):
@@ -346,7 +357,8 @@ def test_kernels_take_the_stored_weight(kernel, m, k, n):
     assert _weight_copies(traced.jaxpr) == []
 
 
-@pytest.mark.parametrize("module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro"])
+@pytest.mark.parametrize(
+    "module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe"])
 def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
     """The same walk over the window and chunk programs as the engine
     jits them (tiny models: vocab 256 and 128 are no multiple of the

@@ -616,3 +616,125 @@ def test_ouro_chunk_program_compiles_and_updates_the_pool_in_place(chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)
+
+
+# -- K-EXAONE: window layers in a ring a slot, global layers in pages ---------
+
+#: the catalog row's widths (benchmark/configs/k-exaone-236b-ep8.json), one
+#: period ``LLLG`` deep (layer 0 dense, three expert layers), 16 of 128
+#: experts held, the cell's vocabulary slice and context
+EXAONE = dict(
+    model_type="exaone_moe", hidden_size=6144, num_attention_heads=64,
+    num_key_value_heads=8, head_dim=128, intermediate_size=18432,
+    moe_intermediate_size=2048, num_hidden_layers=4, vocab_size=19200,
+    rms_norm_eps=1e-5, rope_parameters={"rope_theta": 1e6, "rope_type": "default"},
+    layer_types=["sliding_attention"] * 3 + ["full_attention"],
+    sliding_window=128, mlp_layer_types=["dense"] + ["sparse"] * 3,
+    num_experts=128, num_experts_per_tok=8, num_shared_experts=1,
+    routed_scaling_factor=2.5, norm_topk_prob=True, scoring_func="sigmoid",
+    n_group=1, topk_group=1, ep_size=8,
+)
+EXAONE_SEQ = 16384
+
+
+def _exaone():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool, ring and counter shapes) for 16 slots of 16,384 rows."""
+    from dora_tpu.models.hf import exaone_moe
+
+    cfg = exaone_moe.ExaoneMoeConfig.from_hf(EXAONE, EXAONE_SEQ, 0)
+    bf, d = jnp.bfloat16, cfg.dim
+    fixed = {
+        "input_layernorm.weight": (d,), "post_attention_layernorm.weight": (d,),
+        "self_attn.q_proj.weight": (cfg.q_width, d),
+        "self_attn.k_proj.weight": (cfg.kv_width, d),
+        "self_attn.v_proj.weight": (cfg.kv_width, d),
+        "self_attn.o_proj.weight": (d, cfg.q_width),
+        "self_attn.q_norm.weight": (cfg.head_dim,),
+        "self_attn.k_norm.weight": (cfg.head_dim,),
+        "mlp.gate.weight": (cfg.n_experts, d),
+        "mlp.gate.e_score_correction_bias": (cfg.n_experts,),
+    }
+
+    def get(name):
+        tail = name.split(".", 3)[3]
+        if tail in fixed:
+            return jnp.zeros(fixed[tail], bf)
+        width = cfg.ffn if tail.count(".") == 2 else cfg.moe_ffn  # mlp.x_proj.weight
+        return jnp.zeros((d, width) if "down_proj" in tail else (width, d), bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": exaone_moe._quantize_t(jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): exaone_moe.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(lambda: exaone_moe.init_page_pool(
+        cfg, SLOTS * EXAONE_SEQ // PAGE + 1, PAGE))
+    state = jax.eval_shape(lambda: exaone_moe.init_slot_state(cfg, SLOTS))
+    stats = jax.eval_shape(lambda: exaone_moe.init_counters(cfg))
+    return exaone_moe, cfg, jax.eval_shape(build), pools, state, stats
+
+
+def _ring_copies(compiled) -> list[str]:
+    """``copy`` instructions of a whole layer's rings, by shape (a
+    gathered block of the global layer's keys has as many bytes and is
+    relaid for its product: not a cache that moved)."""
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if "bf16[16,128,2048]" in line.split(" copy(")[0] and " copy(" in line]
+
+
+def test_exaone_window_program_compiles_and_moves_no_cache(chip):
+    """The K=8 decode window at K-EXAONE's widths, 16 slots of 16,384
+    rows: every matrix through ``int8_matmul`` (K = 6144, 8192, 18432;
+    the 36,864-wide dense gate-up), ``lm_head_argmax`` over 19,200
+    columns, the ring's one einsum and the global layer's block loop in
+    plain XLA; pages only for the global layer (1.07 GB), rings only for
+    the window layers (8 MB each), neither copied."""
+    exaone_moe, cfg, params, pools, state, stats = _exaone()
+    assert set(pools) == {"3"} and set(state) == {"0", "1", "2"}
+    assert pools["3"]["kv"].shape == (SLOTS * EXAONE_SEQ // PAGE + 1, PAGE, 2048)
+    assert state["0"]["kv"].shape == (SLOTS, 128, 2048)
+    assert len(params["blocks"]["1"]["experts"]) == 16
+
+    def program(p, *args):
+        return exaone_moe.window_program(p, cfg, 8, None, exaone_moe.ATTN_BLOCK,
+                                         *args)
+
+    compiled = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, EXAONE_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32), state)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _whole_array_copies(compiled, pools) == []
+    # each 8 MB ring is staged through fast memory (``S(1)``) around its
+    # scatter, once a tick: as many such copies as window layers, no more
+    assert len(_ring_copies(compiled)) <= len(state)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_exaone_chunk_program_compiles_and_moves_no_cache(chip):
+    """The 256-row prefill chunk: the band over ``[ring ++ chunk]`` (384
+    keys) for the window layers, the block loop for the global one, the
+    experts' rows gathered 32 at a time."""
+    exaone_moe, cfg, params, pools, state, stats = _exaone()
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return exaone_moe.fused_paged_chunk_step(
+            p, cfg, ids, pools, state, stats, position, bt, valid, slot)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3, 6)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((EXAONE_SEQ // PAGE,), I32), state, _s((), I32),
+               _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _whole_array_copies(compiled, pools) == []
+    assert _ring_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

@@ -444,10 +444,10 @@ def test_checkpoint_restore_round_trips_a_stream_in_mid_decode(model, tmp_path):
     blank.restore_state(snap)
     assert head + run(blank, "r") != want
     # a handoff to another slot cannot take the state along: refused by name
-    with pytest.raises(RuntimeError, match="recurrent state"):
+    with pytest.raises(RuntimeError, match="per-slot state"):
         make_engine(cfg, params).admit_streams(snap)
     # and a snapshot of one kind of engine is not restored on the other
-    with pytest.raises(ValueError, match="recurrent state"):
+    with pytest.raises(ValueError, match="per-slot state"):
         make_engine(cfg, params).restore_state({**snap, "slot_state": False})
 
 
