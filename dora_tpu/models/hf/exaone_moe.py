@@ -44,9 +44,11 @@ cache kind by layer type.**
   ``pools`` holds leaves for those layers only, so a cached token costs
   ``global layers x 2 x KV x hd`` values (8,192 B for two global layers
   at bf16) and admission counts pages that a quarter of the layers use.
-  Decode and chunk go through ``layers.attend_blocks`` (plain XLA, a
-  block of ``ATTN_BLOCK`` rows at a time up to the longest live
-  context).
+  A decode tick reads them through the decode kernels' sweep without
+  its projections (``ops/decode_block.attention_paged_rows_step``: the
+  live rows' own pages, 128 rows a step, nothing for a frozen row); a
+  chunk, one stream, goes through ``layers.attend_blocks`` (plain XLA,
+  a block of ``ATTN_BLOCK`` rows at a time up to its own position).
 
 Every matrix goes through ``ops/int8_matmul`` (the fused attention
 kernels hold ``wqkv`` and ``wo`` whole in VMEM: 113 MB of int8 here), the
@@ -72,13 +74,14 @@ from dora_tpu.models.hf import kimi_k2 as K
 # row; ``layers.attend_blocks`` over such rows a block at a time
 from dora_tpu.models.hf.falcon_h1 import _attend, _kv_rows, rotate
 from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.ops import decode_block as DB
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
 
 MODEL_TYPES = ("exaone_moe",)
 
-#: rows of one attention block of the global layers (a multiple of the
-#: page): their pool is read this many positions at a time, up to the
-#: longest live context.
+#: rows of one attention block of a global layer's CHUNK (a multiple of
+#: the page): its pool is read this many positions at a time, up to the
+#: chunk's last position.
 ATTN_BLOCK = 256
 #: device memory the default pool leaves to the programs' temporaries
 POOL_HEADROOM_BYTES = 4 << 30
@@ -96,8 +99,8 @@ NOT_OFFERED = {
 #: the window layers' and the global layers' counters on the device
 SWA_COUNTERS = (
     "swa_decode_ticks", "swa_row_ticks", "swa_ring_rows_read",
-    "global_kv_rows_read", "global_kv_rows_swept", "swa_chunks",
-    "swa_chunk_rows", "swa_chunk_positions",
+    "global_kv_rows_read", "global_kv_rows_swept", "global_sweep_groups",
+    "swa_chunks", "swa_chunk_rows", "swa_chunk_positions",
 )
 
 _log = logging.getLogger(__name__)
@@ -422,11 +425,14 @@ def window_chunk(blk, cfg: ExaoneMoeConfig, u, ring, slot, position, valid,
 
 
 def global_decode(blk, cfg: ExaoneMoeConfig, u, pool, positions, block_tables,
-                  block: int):
+                  counts):
     """A global layer's decode tick: writes each row's K/V into its page
     (a frozen row's, at position 0 of a zeroed table row, into the null
-    page), then attends positions ``0..positions[b]`` through the block
-    table. No rotary. Returns (output [B, dim], pool)."""
+    page), then row ``b`` attends its first ``counts[b]`` positions
+    (``positions[b] + 1`` of an active row, 0 of a frozen one, which
+    gets zeros) through the block table, its own pages and no others
+    (``attention_paged_rows_step``). No rotary. Returns (output [B,
+    dim], pool)."""
     with jax.named_scope("attn_global"):
         page = pool.shape[1]
         b = u.shape[0]
@@ -434,18 +440,7 @@ def global_decode(blk, cfg: ExaoneMoeConfig, u, pool, positions, block_tables,
         pool = pool.at[
             block_tables[jnp.arange(b), positions // page], positions % page
         ].set(_kv_rows(cfg, k, v).astype(pool.dtype))
-        per = block // page
-
-        def kv_of(j):
-            ids = jax.lax.dynamic_slice_in_dim(block_tables, j * per, per, 1)
-            return _split_rows(cfg, pool[ids].reshape(b, block, -1))
-
-        def visible(j):
-            t = j * block + jnp.arange(block)
-            return (t[None, :] <= positions[:, None])[:, None, None, :]
-
-        ctx = _attend(cfg, q, kv_of, visible, positions.max() // block + 1,
-                      "bkgd,btkd->bkgt", "bkgt,btkd->bkgd")
+        ctx = DB.attention_paged_rows_step(q, pool, counts, block_tables)
         return _out(blk, cfg, ctx, u.dtype), pool
 
 
@@ -543,7 +538,7 @@ def _add(stats: dict, **adds) -> dict:
 
 
 def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
-                     positions, block_tables, active, block: int = ATTN_BLOCK):
+                     positions, block_tables, active):
     """One decode step for B = slots independent sequences: tokens,
     positions, active ``[B]``, block_tables ``[B, max_pages]`` (a frozen
     row comes with position 0 and a zeroed table row, so its global K/V
@@ -552,26 +547,31 @@ def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
     counted). Returns (the final rows [B, dim], pools, state, stats)."""
     rope = _rope_rows(cfg, positions)
     x = params["embed"].astype(L.compute_dtype())[tokens]
+    seen = jnp.where(active, positions + 1, 0)  # rows each row attends
 
     def window(blk, u, ring):
         return window_decode(blk, cfg, u, ring, positions, active, rope)
 
     def attend(blk, u, pool):
-        return global_decode(blk, cfg, u, pool, positions, block_tables, block)
+        return global_decode(blk, cfg, u, pool, positions, block_tables, seen)
 
     x, pools, state, moe = _layers(params, cfg, x, pools, state, stats["moe"],
                                    window, attend, active, active, True)
     i32 = jnp.int32
     live = active.sum(dtype=i32)
-    seen = jnp.where(active, positions + 1, 0)
+    # the (row, group) steps one global layer's sweep holds: a group is
+    # DB's page group of cache rows, fetched whole for its last row
+    group = DB.sweep_group_rows(
+        next(iter(pools.values()))["kv"].shape[1], block_tables.shape[1])
+    groups = ((seen + group - 1) // group).sum(dtype=i32)
     swa = _add(
         stats["swa"],
         swa_decode_ticks=(live > 0).astype(i32), swa_row_ticks=live,
         swa_ring_rows_read=len(cfg.window_layers) * jnp.minimum(
             seen, cfg.window).sum(dtype=i32),
         global_kv_rows_read=len(cfg.global_layers) * seen.sum(dtype=i32),
-        global_kv_rows_swept=len(cfg.global_layers) * tokens.shape[0] * block
-        * (positions.max() // block + 1).astype(i32),
+        global_kv_rows_swept=len(cfg.global_layers) * group * groups,
+        global_sweep_groups=groups,
     )
     return x, pools, state, {"moe": moe, "swa": swa}
 
@@ -638,8 +638,8 @@ def fused_paged_chunk_step(params, cfg, *args, **kw):
     return head_argmax(params, cfg, x), *rest
 
 
-def window_program(params, cfg, k: int, eos, block: int, tokens, pools,
-                   stats, positions, bts, active, emitted, max_new, state):
+def window_program(params, cfg, k: int, eos, tokens, pools, stats,
+                   positions, bts, active, emitted, max_new, state):
     """The K-tick decode window (models/vlm.make_paged_window with a
     slot state) over :func:`fused_paged_batch_step`: the counters ride
     the window's carry beside the rings and come back apart. Returns
@@ -648,8 +648,7 @@ def window_program(params, cfg, k: int, eos, block: int, tokens, pools,
 
     def batch(tokens, pools, positions, bts, active, carried):
         nxt, pools, state, stats = fused_paged_batch_step(
-            params, cfg, tokens, pools, *carried, positions, bts, active,
-            block=block)
+            params, cfg, tokens, pools, *carried, positions, bts, active)
         return nxt, pools, (state, stats)
 
     *out, (state, stats) = _vlm.make_paged_window(
@@ -835,7 +834,7 @@ def make_paged_engine(params, cfg: ExaoneMoeConfig, *, max_slots: int = 16,
         assert not sk, "exaone_moe: no speculative window"
 
         def program(p, *args):
-            return window_program(p, cfg, k, eos, attn_block, *args)
+            return window_program(p, cfg, k, eos, *args)
 
         jitted = jax.jit(program, donate_argnums=(2, 3, 9))
 

@@ -699,6 +699,14 @@ def attention_chunk_step(
 # heads of 128, 1 MB of K/V a group; PERF.md section 6, PR 38): 2.60 us
 # a group as products, 1.51 as the vector pass, 1.40 for the group's
 # copies with no arithmetic at all.
+#
+# What a step FETCHES has two forms as well, chosen from the pool
+# operand's rank: K and V pools ``[P, KV, page, hd]`` (two copies a
+# page, a buffer each), or one pool ``[P, page, 2 * KV * hd]`` whose row
+# is a position's keys, then its values (one copy a page into one
+# buffer, a head's keys and values its lane slices): the layout of the
+# models that project in XLA and enter through
+# :func:`attention_paged_rows_step`, the sweep and nothing else.
 
 #: Cache rows (columns of the score tile) one sweep step covers.
 _SWEEP_COLS = 128
@@ -726,24 +734,37 @@ def sweep_group_rows(page: int, max_pages: int) -> int:
 
 
 def _sweep_scratch(batch, max_pages, kv_heads, rows, head_dim, page,
-                   pool_dtype, q_dtype, kv_quant):
+                   pool_dtype, q_dtype, kv_quant, joined=False):
     """Scratch of :func:`_paged_sweep`, in the order it unpacks them:
     group buffers, their DMA semaphores, the SMEM schedule, the query
-    rows and the online-softmax state."""
+    rows and the online-softmax state. ``joined``: the pool keeps K and
+    V of a position as ONE row (``[P, page, 2 * KV * hd]``), so a group
+    is one buffer of such rows."""
     gp = _sweep_pages(page, max_pages)
     steps = batch * pl.cdiv(max_pages, gp)
-    group_buf = pltpu.VMEM(
-        (_SWEEP_SLOTS, kv_heads, gp * page, head_dim), pool_dtype
-    )
+    if joined:
+        assert not kv_quant, "no int8 pages in the joined row layout"
+        bufs = [pltpu.VMEM(
+            (_SWEEP_SLOTS, gp * page, 2 * kv_heads * head_dim), pool_dtype
+        )]                                                    # kvbuf
+    else:
+        group_buf = pltpu.VMEM(
+            (_SWEEP_SLOTS, kv_heads, gp * page, head_dim), pool_dtype
+        )
+        bufs = [
+            group_buf,                                        # kbuf
+            group_buf,                                        # vbuf
+            *(
+                [pltpu.VMEM(
+                    (_SWEEP_SLOTS, 2, kv_heads, gp * page), jnp.float32)]
+                if kv_quant else []                           # sbuf
+            ),
+        ]
     state = (batch, kv_heads, rows)
+    copies = 1 if joined else 4 if kv_quant else 2  # DMAs a page
     return [
-        group_buf,                                            # kbuf
-        group_buf,                                            # vbuf
-        *(
-            [pltpu.VMEM((_SWEEP_SLOTS, 2, kv_heads, gp * page), jnp.float32)]
-            if kv_quant else []                               # sbuf
-        ),
-        pltpu.SemaphoreType.DMA((_SWEEP_SLOTS, 4 if kv_quant else 2)),
+        *bufs,
+        pltpu.SemaphoreType.DMA((_SWEEP_SLOTS, copies)),
         pltpu.SMEM((steps,), jnp.int32),                      # step -> row
         pltpu.SMEM((steps,), jnp.int32),                      # step -> group
         pltpu.VMEM((*state, head_dim), q_dtype),              # q
@@ -758,7 +779,13 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
     """Pipelined flash sweep of B paged contexts; call at kernel entry.
 
     ``pools``: (k, v) HBM pools [P, KV, page, hd], plus the (k, v) scale
-    pools [P, KV, page] on the int8-KV path. ``scratch``: what
+    pools [P, KV, page] on the int8-KV path; or ONE pool [P, page,
+    2 * KV * hd] whose row is a position's keys, then its values (the
+    joined layout, told by the pool's rank alone): a page is then one
+    copy, not two, and head ``h``'s keys and values are the lane slices
+    ``[:, h * hd : (h + 1) * hd]`` and ``[:, (KV + h) * hd : ...]`` of
+    the group's buffer; schedule, slots, masking and arithmetic are the
+    same. ``scratch``: what
     :func:`_sweep_scratch` declared. Row b attends pool rows
     ``idx < pos_ref[b]`` through ``bt_ref[b]``, with ``rows`` query rows
     per kv head (group size x m; m = 1 in the decode kernel) that the
@@ -789,12 +816,18 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
     it returns; the caller folds in what it holds in registers.
     """
     kv_quant = len(pools) == 4
+    joined = len(pools[0].shape) == 3
     *bufs, sem, row_ref, grp_ref, q_ref, m_ref, l_ref, acc_ref = scratch
-    kbuf, vbuf = bufs[:2]
-    sbuf = bufs[2] if kv_quant else None
-    slots, kv_heads, cols, _ = kbuf.shape
+    _, kv_heads, rows, hd = q_ref.shape
+    if joined:
+        (kvbuf,) = bufs
+        slots, cols, _ = kvbuf.shape
+        assert rows > 1, "the joined layout has no one-row form"
+    else:
+        kbuf, vbuf = bufs[:2]
+        sbuf = bufs[2] if kv_quant else None
+        slots, _, cols, _ = kbuf.shape
     gp = cols // page
-    rows = q_ref.shape[2]
 
     def nblocks(b):  # prior context in pages, the partial one included
         return (pos_ref[b] + page - 1) // page
@@ -825,7 +858,10 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
         def one(j, carry):
             pg = bt_ref[b, first + j]
             at = pl.ds(pl.multiple_of(j * page, page), page)
-            dsts = [kbuf.at[slot, :, at, :], vbuf.at[slot, :, at, :]]
+            if joined:
+                dsts = [kvbuf.at[slot, at, :]]
+            else:
+                dsts = [kbuf.at[slot, :, at, :], vbuf.at[slot, :, at, :]]
             if kv_quant:
                 dsts += [sbuf.at[slot, 0, :, at], sbuf.at[slot, 1, :, at]]
             for i, (pool, dst) in enumerate(zip(pools, dsts)):
@@ -877,7 +913,11 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
             jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) + g * cols
         ) < pos_ref[b]
         for h in range(kv_heads):
-            if kv_quant:
+            if joined:
+                at_k, at_v = (pl.ds(i * hd, hd) for i in (h, kv_heads + h))
+                k_h = kvbuf[slot, :, at_k].astype(dtype)
+                v_h = kvbuf[slot, :, at_v].astype(dtype)
+            elif kv_quant:
                 k_h = kv_dequant(kbuf[slot, h], sbuf[slot, 0, h], dtype)
                 v_h = kv_dequant(vbuf[slot, h], sbuf[slot, 1, h], dtype)
             else:
@@ -1249,6 +1289,78 @@ def attention_paged_batch_step(
         jnp.asarray(block_tables, jnp.int32),
         x, norm_w.reshape(1, d), wqkv, sqkv, bqkv.reshape(1, n_qkv),
         cos_rows, sin_rows, *operands, wo, swo,
+    )
+
+
+def _attn_paged_rows_kernel(
+    cnt_ref,  # SMEM (B,) int32 — cache rows each batch row attends
+    bt_ref,   # SMEM (B, max_pages) int32 — per-row block tables
+    q_in, pool, out_ref, *sweep_scratch, page: int, batch: int,
+):
+    """:func:`_paged_sweep` and nothing else: the queries come projected,
+    every attended row (the tick's own among them) is in the pool. A row
+    with no step keeps an empty softmax (sum 0, accumulator 0) and
+    leaves as zeros."""
+    q_ref, sweep = _paged_sweep(
+        cnt_ref, bt_ref, (pool,), sweep_scratch, batch=batch, page=page,
+        scale=1.0 / (q_in.shape[-1] ** 0.5), dtype=q_in.dtype,
+    )
+    q_ref[...] = q_in[...]
+    _, l_ref, acc_ref = sweep()
+    total = l_ref[...]
+    out_ref[...] = acc_ref[...] / jnp.where(total > 0.0, total, 1.0)
+
+
+@jax.jit
+def attention_paged_rows_step(q, pool, counts, block_tables):
+    """Paged decode attention of B independent sequences WITHOUT the
+    projections, for a model whose ``wqkv`` and ``wo`` do not fit the
+    fused kernel's VMEM: the caller projects (and norms, ropes) in XLA,
+    writes the tick's K/V row to its page, and hands over
+
+    q: [B, KV, G, hd] queries (``G`` = query rows a K/V head serves, at
+    least 2); pool: [P, page, 2 * KV * hd], a position's keys then its
+    values as one row, left in HBM, read only; counts: [B] int32, the
+    cache rows each batch row attends (positions ``0 .. counts[b] - 1``
+    through ``block_tables[b]``; 0 for a frozen row); block_tables:
+    [B, max_pages] int32. Returns the normalised context [B, KV, G, hd]
+    float32; a row with ``counts`` 0 gets zeros.
+
+    One kernel call, ``grid=(1,)``, the flat (row, group) schedule of
+    :func:`_paged_sweep` over the live rows' pages only: a group is 8
+    pages = 128 cache rows at page 16, one copy a page, two buffer
+    slots, the next group in flight while this one is multiplied (two
+    MXU products a K/V head); probabilities go to ``q``'s dtype before
+    the value product, sums are float32."""
+    batch, kv_heads, rows, head_dim = q.shape
+    page = pool.shape[1]
+    assert pool.shape[2] == 2 * kv_heads * head_dim, (pool.shape, q.shape)
+    assert page % 8 == 0, page
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # q
+            pl.BlockSpec(memory_space=pl.ANY),      # pool (HBM)
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=_sweep_scratch(
+            batch, block_tables.shape[1], kv_heads, rows, head_dim, page,
+            pool.dtype, q.dtype, False, joined=True,
+        ),
+    )
+    return pl.pallas_call(
+        functools.partial(_attn_paged_rows_kernel, page=page, batch=batch),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=_interpret(),
+    )(
+        jnp.asarray(counts, jnp.int32).reshape(batch),
+        jnp.asarray(block_tables, jnp.int32),
+        q, pool,
     )
 
 

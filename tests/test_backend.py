@@ -405,6 +405,47 @@ def test_engine_programs_copy_no_pool(module_name, monkeypatch, tmp_path):
             assert _pool_writers(traced.jaxpr, pool) == [], jitted
 
 
+def _calls_and_pool_gathers(jaxpr, shape, inside_while=False):
+    """(names of the jitted kernel wrappers called, sub-programs
+    included; ``gather`` equations over an array of ``shape`` that sit
+    inside a ``while``)."""
+    calls, gathers = [], []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("jit", "pjit"):
+            calls.append(eqn.params["name"])
+        if name == "gather" and inside_while and (
+                getattr(eqn.invars[0].aval, "shape", None) == shape):
+            gathers.append(str(eqn.invars[0].aval))
+        if name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            c, g = _calls_and_pool_gathers(
+                sub, shape, inside_while or name == "while")
+            calls += c
+            gathers += g
+    return calls, gathers
+
+
+def test_exaone_window_reads_the_global_pages_through_the_sweep(
+        monkeypatch, tmp_path):
+    """The decode window holds one ``attention_paged_rows_step`` a global
+    layer (the tiny model has one) and no block loop: nothing gathers a
+    ``[P, page, 2 * KV * hd]`` pool inside a ``while``. The chunk
+    program keeps its loop (one stream, to its own position), which is
+    what the same walk finds there."""
+    seen = _engine_programs("exaone_moe", monkeypatch, tmp_path)
+    found = {}
+    for jitted, (shapes, took) in seen.items():
+        if took:
+            (pool,) = [leaf["kv"].shape for leaf in shapes[2].values()]
+            calls, gathers = _calls_and_pool_gathers(
+                jax.make_jaxpr(jitted)(*shapes).jaxpr, pool)
+            found[calls.count("attention_paged_rows_step")] = gathers
+    assert found[1] == [], "the window gathers pool pages in a loop"
+    assert len(found[0]) == 1, "the chunk's block loop: one gather of pages"
+
+
 # -- the smoke test's parent stays off JAX ----------------------------------
 
 

@@ -20,6 +20,7 @@ the worker that runs this file loads the TPU library.
 
 from __future__ import annotations
 
+import re
 from functools import partial
 
 import jax
@@ -452,7 +453,6 @@ def _whole_array_copies(compiled, *trees) -> list[str]:
     largest leaf of ``trees``: a cache that XLA moved instead of
     updating in place."""
     import math
-    import re
 
     least = min(_pool_bytes(t) for t in trees)
     found = []
@@ -691,9 +691,12 @@ def test_exaone_window_program_compiles_and_moves_no_cache(chip):
     """The K=8 decode window at K-EXAONE's widths, 16 slots of 16,384
     rows: every matrix through ``int8_matmul`` (K = 6144, 8192, 18432;
     the 36,864-wide dense gate-up), ``lm_head_argmax`` over 19,200
-    columns, the ring's one einsum and the global layer's block loop in
-    plain XLA; pages only for the global layer (1.07 GB), rings only for
-    the window layers (8 MB each), neither copied."""
+    columns, the ring's one einsum in plain XLA, and the global layer's
+    pages through ``attention_paged_rows_step`` under Mosaic (64 / 8
+    heads of 128: 8 query rows a K/V head, a 64 KB block table in SMEM,
+    two 512 KB group buffers); pages only for the global layer (1.07
+    GB, an operand of the kernel left in HBM), rings only for the window
+    layers (8 MB each), neither copied."""
     exaone_moe, cfg, params, pools, state, stats = _exaone()
     assert set(pools) == {"3"} and set(state) == {"0", "1", "2"}
     assert pools["3"]["kv"].shape == (SLOTS * EXAONE_SEQ // PAGE + 1, PAGE, 2048)
@@ -701,8 +704,7 @@ def test_exaone_window_program_compiles_and_moves_no_cache(chip):
     assert len(params["blocks"]["1"]["experts"]) == 16
 
     def program(p, *args):
-        return exaone_moe.window_program(p, cfg, 8, None, exaone_moe.ATTN_BLOCK,
-                                         *args)
+        return exaone_moe.window_program(p, cfg, 8, None, *args)
 
     compiled = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
         chip(params),
@@ -710,7 +712,10 @@ def test_exaone_window_program_compiles_and_moves_no_cache(chip):
                _s((SLOTS, EXAONE_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
                _s((SLOTS,), I32), _s((SLOTS,), I32), state)),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    # one call a global layer, inside the window's loop over its ticks
+    assert len(re.findall(r"= \S+ custom-call\(.*attention_paged_rows_step",
+                          text)) == len(pools)
     assert _whole_array_copies(compiled, pools) == []
     # each 8 MB ring is staged through fast memory (``S(1)``) around its
     # scatter, once a tick: as many such copies as window layers, no more

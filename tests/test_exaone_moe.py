@@ -29,6 +29,7 @@ import pytest
 
 from dora_tpu.models.hf import exaone_moe as E
 from dora_tpu.models.hf import exaone_moe_reference as R
+from dora_tpu.ops import decode_block as DB
 
 TOL = 2e-5
 WINDOW, PAGE, CHUNK, BLOCK, K_TICKS, SLOTS, MAX_SEQ = 8, 8, 32, 16, 4, 3, 128
@@ -140,7 +141,7 @@ def programs(cfg):
     the greedy tokens would be (cfg is static; one trace a config)."""
     return (
         jax.jit(lambda p, *a: E.paged_chunk_logits(p, cfg, *a, block=BLOCK)),
-        jax.jit(lambda p, *a: E.paged_batch_logits(p, cfg, *a, block=BLOCK)),
+        jax.jit(lambda p, *a: E.paged_batch_logits(p, cfg, *a)),
     )
 
 
@@ -246,8 +247,8 @@ def test_chunk_edges_inside_a_revolution(ckpt):
 
 def test_a_short_and_a_long_stream_decode_in_one_window(model):
     """Rows of one tick at positions below the window and several times
-    past it: the ring's mask is the row's own, the global layer's block
-    loop runs to the long row's context for both."""
+    past it: the ring's mask is the row's own, the global layer's sweep
+    fetches each row's own pages and none for the frozen slot."""
     cfg, params, _ = model
     short, long_ = prompt_ids(4, seed=21), prompt_ids(70, seed=22)
     follow = {0: prompt_ids(10, seed=23), 2: prompt_ids(10, seed=24)}
@@ -269,9 +270,14 @@ def test_a_short_and_a_long_stream_decode_in_one_window(model):
     pos = [4 + k for k in range(10)] + [70 + k for k in range(10)]
     assert swa["global_kv_rows_read"] == sum(p + 1 for p in pos)
     assert swa["swa_ring_rows_read"] == 4 * sum(min(p + 1, WINDOW) for p in pos)
-    # rows fetched: blocks to the longest row's context x block x rows
-    assert swa["global_kv_rows_swept"] == sum(
-        ((70 + k) // BLOCK + 1) * BLOCK * SLOTS for k in range(10))
+    # rows fetched: the sweep's (row, group) steps, a group of 128 cache
+    # rows each (x 1 global layer): every live row's own, to its own count
+    group = DB.sweep_group_rows(PAGE, MAX_SEQ // PAGE)
+    assert group == 128
+    assert swa["global_sweep_groups"] == sum(-(-(p + 1) // group) for p in pos)
+    assert swa["global_kv_rows_swept"] == group * swa["global_sweep_groups"]
+    assert (swa["global_kv_rows_swept"] / swa["global_kv_rows_read"]
+            <= 1 + (group - 1) / (min(pos) + 1))
     assert swa["swa_chunks"] == 1 + 3 and swa["swa_chunk_rows"] == 74
     assert swa["swa_chunk_positions"] == 0 + 0 + 32 + 64
 

@@ -11,6 +11,11 @@ file holds the kernel, under the Pallas interpreter, to a per-row
 attention written in numpy over the same pool, at the positions where
 those cases live, with page ids shuffled over the pool, live and frozen
 rows mixed, and loud stale content everywhere the context is not.
+
+``attention_paged_rows_step`` is the same sweep without the projections,
+over a pool that keeps K and V of a position as ONE row (``[P, page,
+2 * KV * hd]``): its cases below hold it to the same numpy attention at
+the same positions, and a row that attends nothing to exact zeros.
 """
 
 from __future__ import annotations
@@ -89,6 +94,13 @@ def _setup(rng, positions, active, kv_int8, KV):
     return x, pools, (kf, vf), pos_in, bt_in
 
 
+def _softmax_mix(q, keys, vals):
+    """One query row over its gathered context, plain softmax."""
+    s = keys @ q / np.sqrt(HD)
+    p = np.exp(s - s.max())
+    return p @ vals / p.sum()
+
+
 def _reference(x, weights, kf, vf, positions, bt, heads):
     """Per-row attention in float64 numpy: the kernel's own projection
     formulas, then a plain softmax over the row's gathered context and
@@ -120,9 +132,7 @@ def _reference(x, weights, kf, vf, positions, bt, heads):
             g = head // (H // KV)
             keys = np.concatenate([kf[pg, g, off], k_new[b, g][None]])
             vals = np.concatenate([vf[pg, g, off], v_new[b, g][None]])
-            s = keys @ q[b, head] / np.sqrt(HD)
-            p = np.exp(s - s.max())
-            attn[b, head] = p @ vals / p.sum()
+            attn[b, head] = _softmax_mix(q[b, head], keys, vals)
     attn = attn.reshape(batch, H * HD).astype(np.float32).astype(np.float64)
     out = x + attn @ np.asarray(wo["int8"], np.float64) * np.asarray(
         wo["scale"], np.float64)
@@ -205,3 +215,70 @@ def test_sixteen_rows_live_and_frozen_match_plain_attention(
     active = np.ones(16, bool)
     active[rng.choice(16, size=5, replace=False)] = False
     _check(seed, positions, active.tolist(), kv_int8, heads)
+
+
+# -- the projection-free entry: K and V of a position as one pool row ----------
+
+#: query rows a K/V head serves: the least the MXU form takes, K-EXAONE's
+ROWS = pytest.mark.parametrize("rows", [2, 8], ids=["rows_2", "rows_8"])
+KV_JOINED = 2
+
+
+def _check_joined(seed, positions, active, rows):
+    """``positions[b]`` is where row b's tick stands: its K/V row is in
+    the pool already (the caller's scatter), so a live row attends
+    ``positions[b] + 1`` rows and a frozen one none."""
+    rng = np.random.default_rng(seed)
+    batch, KV = len(positions), KV_JOINED
+    pages = 1 + batch * MAX_PAGES
+    bt = (1 + rng.permutation(pages - 1).astype(np.int32)).reshape(
+        batch, MAX_PAGES)
+    pool = (STALE * rng.choice([-1.0, 1.0], size=(pages, PAGE, 2 * KV * HD))
+            ).astype(np.float32)
+    counts = np.where(active, np.asarray(positions) + 1, 0)
+    for b, n in enumerate(counts):
+        idx = np.arange(n)
+        pool[bt[b, idx // PAGE], idx % PAGE] = rng.standard_normal(
+            (n, 2 * KV * HD))
+    q = rng.standard_normal((batch, KV, rows, HD)).astype(np.float32)
+    _, bt_in = DB.freeze_inactive(
+        jnp.asarray(positions, jnp.int32), jnp.asarray(bt),
+        jnp.asarray(active))
+    got = np.asarray(DB.attention_paged_rows_step(
+        jnp.asarray(q), jnp.asarray(pool), jnp.asarray(counts, jnp.int32),
+        bt_in))
+    assert got.shape == q.shape and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    want = np.zeros(q.shape)
+    for b, n in enumerate(counts):
+        idx = np.arange(n)
+        ctx = pool[bt[b, idx // PAGE], idx % PAGE].astype(np.float64)
+        ctx = ctx.reshape(n, 2, KV, HD)
+        for h in range(KV if n else 0):
+            for r in range(rows):
+                want[b, h, r] = _softmax_mix(
+                    q[b, h, r].astype(np.float64), ctx[:, 0, h], ctx[:, 1, h])
+    # a row that attends nothing: zeros, whatever the null page holds
+    assert (got[counts == 0] == 0.0).all()
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@ROWS
+@pytest.mark.parametrize("active", [True, False], ids=["live", "frozen"])
+@pytest.mark.parametrize("pos", POSITIONS)
+def test_one_row_of_joined_pages_matches_plain_attention(pos, active, rows):
+    _check_joined(200 + pos, [pos], [active], rows)
+
+
+@ROWS
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sixteen_rows_of_joined_pages_live_and_frozen_match_plain_attention(
+        seed, rows):
+    """The sixteen-row case above for the projection-free entry: every
+    boundary position twice in a seeded order, five rows frozen with
+    their tables zeroed, page ids shuffled over the pool."""
+    rng = np.random.default_rng(seed)
+    positions = rng.permutation(np.repeat(POSITIONS, 2)).tolist()
+    active = np.ones(16, bool)
+    active[rng.choice(16, size=5, replace=False)] = False
+    _check_joined(seed, positions, active.tolist(), rows)
