@@ -468,7 +468,14 @@ def mla_chunk(blk, cfg: KimiK2Config, x, pool, position, block_table,
 
 
 def swiglu(w: dict, x):
+    """``w["limit"]``, where a loader put one beside the matrices (a
+    checkpoint's ``swiglu_limit``; Kimi-K2 has none): the gate held to
+    ``(-inf, limit]`` and the up part to ``[-limit, limit]`` before
+    ``silu(gate) * up``."""
     gate, up = jnp.split(L.matmul(x, w["w_gateup"]), 2, axis=-1)
+    if "limit" in w:
+        gate = jnp.minimum(gate, w["limit"].astype(gate.dtype))
+        up = jnp.clip(up, -w["limit"].astype(up.dtype), w["limit"].astype(up.dtype))
     return L.matmul(jax.nn.silu(gate) * up, w["w_down"])
 
 

@@ -113,6 +113,7 @@ MODEL_MODULES = {
     "falcon_h1": "falcon_h1",
     "ouro": "ouro",
     "exaone_moe": "exaone_moe",
+    "glm5_next_text": "glm5_next",
 }
 
 
@@ -130,9 +131,11 @@ def model_module(model_type: str | None):
     return importlib.import_module(f"dora_tpu.models.hf.{name}")
 
 
-def make_engine(params, cfg, eos=None, module=None):
+def make_engine(params, cfg, eos=None, module=None, **model_kw):
     """Build the serving engine from the env knobs. ``module`` is the
-    model's ``models/hf`` module (default: qwen2)."""
+    model's ``models/hf`` module (default: qwen2); ``model_kw`` goes to
+    its ``make_paged_engine`` as it stands (a cache audit's, never the
+    server's)."""
     if module is None:
         from dora_tpu.models.hf import qwen2 as module
 
@@ -148,7 +151,7 @@ def make_engine(params, cfg, eos=None, module=None):
     return module.make_paged_engine(
         params, cfg, max_slots=slots, eos=eos, page_size=page_size,
         chunk=chunk, window=window, prefix_cache=prefix_on,
-        prefix_cache_pages=prefix_pages,
+        prefix_cache_pages=prefix_pages, **model_kw,
     )
 
 

@@ -239,8 +239,17 @@ def _tiny_exaone(tmp_path):
     return exaone_moe.load(tmp_path / "ckpt", max_seq=64)
 
 
+def _tiny_glm5(tmp_path):
+    from test_glm5_next import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import glm5_next
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return glm5_next.load(tmp_path / "ckpt", max_seq=64)
+
+
 _TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon, "ouro": _tiny_ouro,
-         "exaone_moe": _tiny_exaone}
+         "exaone_moe": _tiny_exaone, "glm5_next": _tiny_glm5}
 
 
 def _engine_programs(module_name, monkeypatch, tmp_path):
@@ -288,7 +297,8 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
 
 
 @pytest.mark.parametrize(
-    "module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe"])
+    "module_name",
+    ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next"])
 def test_engine_programs_take_the_weights_as_arguments(
     module_name, monkeypatch, tmp_path
 ):
@@ -358,7 +368,8 @@ def test_kernels_take_the_stored_weight(kernel, m, k, n):
 
 
 @pytest.mark.parametrize(
-    "module_name", ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe"])
+    "module_name",
+    ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next"])
 def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
     """The same walk over the window and chunk programs as the engine
     jits them (tiny models: vocab 256 and 128 are no multiple of the

@@ -743,3 +743,166 @@ def test_exaone_chunk_program_compiles_and_moves_no_cache(chip):
     assert _whole_array_copies(compiled, pools) == []
     assert _ring_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+# -- GLM-5.3-Flash: delta-rule state a slot, latent and pooled index rows in pages --
+
+#: the catalog row's widths (benchmark/configs/glm-5p3-flash-ep8.json), a dense
+#: delta-rule layer, a sparse one and the sparse-latent layer, 36 of 288
+#: experts held, the cell's vocabulary slice and context
+GLM5 = dict(
+    model_type="glm5_next_text", hidden_size=4096, num_attention_heads=64,
+    num_key_value_heads=64, head_dim=0, intermediate_size=12288,
+    moe_intermediate_size=2048, num_hidden_layers=3, vocab_size=19360,
+    rms_norm_eps=1e-5,
+    layer_types=["linear_attention"] * 2 + ["deepseek_sparse_attention"],
+    mlp_layer_types=["dense"] + ["sparse"] * 2, indexer_types=["full"] * 3,
+    linear_attn_config={"num_heads": 64, "head_dim": 128,
+                        "short_conv_kernel_size": 4, "gate_lower_bound": -5},
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=256, qk_head_dim=256,
+    qk_rope_head_dim=0, v_head_dim=256, mla_use_nope=True,
+    index_n_heads=32, index_head_dim=128, index_topk=2048, index_kpool=4,
+    index_kpool_compress=True, index_kpool_always_select_tail=True,
+    hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, mhc=True,
+    n_routed_experts=288, num_experts_per_tok=8, n_shared_experts=1,
+    routed_scaling_factor=2.5, norm_topk_prob=True, scoring_func="sigmoid",
+    topk_method="noaux_tc", n_group=1, topk_group=1, swiglu_limit=10, ep_size=8,
+)
+GLM5_SEQ = 16384
+
+
+def _glm5():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool, slot-state and counter shapes) for 16 slots of 16,384
+    rows (1,024 pages a row)."""
+    from dora_tpu.models.hf import glm5_next
+
+    cfg = glm5_next.Glm5NextConfig.from_hf(GLM5, GLM5_SEQ, 0)
+    bf, d, hk, r = jnp.bfloat16, cfg.dim, cfg.kda_width, cfg.kda_dim
+    n = cfg.hc
+    fixed = {
+        "input_layernorm.weight": (d,), "post_attention_layernorm.weight": (d,),
+        **{f"hc_{s}_fn": (2 * n + n * n, n * d) for s in ("attn", "ffn")},
+        **{f"hc_{s}_base": (2 * n + n * n,) for s in ("attn", "ffn")},
+        **{f"hc_{s}_scale": (3,) for s in ("attn", "ffn")},
+        **{f"self_attn.{x}_proj.weight": (hk, d) for x in "qkv"},
+        **{f"self_attn.{x}_conv1d.weight": (hk, 1, cfg.conv) for x in "qkv"},
+        "self_attn.f_a_proj.weight": (r, d), "self_attn.f_b_proj.weight": (hk, r),
+        "self_attn.g_a_proj.weight": (r, d), "self_attn.g_b_proj.weight": (hk, r),
+        "self_attn.b_proj.weight": (cfg.kda_heads, d),
+        "self_attn.A_log": (cfg.kda_heads,), "self_attn.dt_bias": (hk,),
+        "self_attn.o_norm.weight": (r,),
+        "self_attn.q_a_proj.weight": (cfg.q_rank, d),
+        "self_attn.q_a_layernorm.weight": (cfg.q_rank,),
+        "self_attn.q_b_proj.weight": (cfg.heads * cfg.nope, cfg.q_rank),
+        "self_attn.kv_a_proj_with_mqa.weight": (cfg.kv_rank, d),
+        "self_attn.kv_a_layernorm.weight": (cfg.kv_rank,),
+        "self_attn.kv_b_proj.weight": (cfg.heads * (cfg.nope + cfg.v_dim),
+                                       cfg.kv_rank),
+        "self_attn.indexer.wq_b.weight": (cfg.idx_heads * cfg.idx_dim, cfg.q_rank),
+        "self_attn.indexer.wk.weight": (cfg.idx_dim, d),
+        "self_attn.indexer.k_norm.weight": (cfg.idx_dim,),
+        "self_attn.indexer.k_norm.bias": (cfg.idx_dim,),
+        "self_attn.indexer.weights_proj.weight": (cfg.idx_heads, d),
+        "mlp.gate.weight": (cfg.n_experts, d),
+        "mlp.gate.e_score_correction_bias": (cfg.n_experts,),
+    }
+
+    def get(name):
+        layer, tail = name.split(".", 3)[2:]
+        if tail == "self_attn.o_proj.weight":
+            wide = hk if cfg.linear[int(layer)] else cfg.heads * cfg.v_dim
+            return jnp.zeros((d, wide), bf)
+        if tail in fixed:
+            return jnp.zeros(fixed[tail], bf)
+        width = cfg.ffn if tail.count(".") == 2 else cfg.moe_ffn  # mlp.x_proj.weight
+        return jnp.zeros((d, width) if "down_proj" in tail else (width, d), bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": glm5_next._quantize_t(jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): glm5_next.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(lambda: glm5_next.init_page_pool(
+        cfg, SLOTS * GLM5_SEQ // PAGE + 1, PAGE))
+    state = jax.eval_shape(lambda: glm5_next.init_slot_state(cfg, SLOTS))
+    stats = jax.eval_shape(lambda: glm5_next.init_counters(cfg))
+    return glm5_next, cfg, jax.eval_shape(build), pools, state, stats
+
+
+def _cache_copies(compiled) -> list[str]:
+    """``copy`` instructions of a whole pool leaf or a whole delta-rule
+    state, by shape: a cache that XLA moved instead of updating in
+    place."""
+    shapes = ("bf16[16385,16,512]", "bf16[16385,4,128]", "f32[16,64,128,128]")
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line
+            and any(s in line.split(" copy(")[0] for s in shapes)]
+
+
+@pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
+def test_glm5_window_program_compiles_and_moves_no_cache(chip, picks):
+    """The K=8 decode window at GLM-5.3-Flash's widths, 16 slots of
+    16,384 rows (1,024 pages a row): every matrix through ``int8_matmul``
+    (the 24,960-wide delta-rule input, the 20,480-wide query and indexer
+    heads), ``lm_head_argmax`` over 19,360 columns; the delta-rule step,
+    the residual maps, the index scores, ``top_k`` of 512 among 4,096 and
+    the gather of 2,052 latent rows a row in plain XLA. Pages only for
+    the sparse-latent layer (two leaves), 67 MB of float32 state a
+    delta-rule layer; no copy of either. As the server jits it, and as a
+    cache audit's engine does (``make_paged_engine(picks=True)``: every
+    tick's picked blocks and sublayer output come out beside)."""
+    glm5_next, cfg, params, pools, state, stats = _glm5()
+    assert set(pools) == {"2"} and set(state) == {"0", "1", "2"}
+    assert pools["2"]["kv"].shape == (SLOTS * GLM5_SEQ // PAGE + 1, PAGE, 512)
+    assert pools["2"]["ik"].shape == (SLOTS * GLM5_SEQ // PAGE + 1, 4, 128)
+    assert state["0"]["s"].shape == (SLOTS, 64, 128, 128)
+    assert state["0"]["conv"].shape == (SLOTS, 3, 3 * 8192)
+    assert state["2"]["acc"].shape == (SLOTS, 128)
+    assert len(params["blocks"]["1"]["experts"]) == 36
+
+    def program(p, *args):
+        return glm5_next.window_program(p, cfg, 8, None, *args, picks=picks)
+
+    lowered = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, GLM5_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32), state)),
+    )
+    looks = jax.tree.leaves(lowered.out_info)[-2:]
+    assert ([x.shape for x in looks] == [(8, SLOTS, 4096), (8, SLOTS, 512)]) == picks
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _cache_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
+def test_glm5_chunk_program_compiles_and_moves_no_cache(chip, picks):
+    """The 256-row prefill chunk: the blocked delta rule (16 blocks of 16
+    rows, the pairwise decays of a block in one fused sum), the index
+    scores of 256 rows against 4,096 pooled rows, ``top_k``, the dense
+    absorbed product under the picked mask a block of 256 cached rows at
+    a time, the experts' rows gathered 32 at a time. As the server jits
+    it, and with a cache audit's look at the selection."""
+    glm5_next, cfg, params, pools, state, stats = _glm5()
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return glm5_next.fused_paged_chunk_step(
+            p, cfg, ids, pools, state, stats, position, bt, valid, slot,
+            picks=picks)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3, 6)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((GLM5_SEQ // PAGE,), I32), state, _s((), I32),
+               _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _cache_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
