@@ -49,9 +49,11 @@ model.**
 * a KDA layer keeps slot state only (``PagedBatchEngine``'s
   ``init_slot_state``): the float32 state ``"s" [slots, H, d_k, d_v]``
   and the convolution's last three rows ``"conv" [slots, 3, 3 H d_k]``.
-  A decode tick steps live rows only; a chunk runs the blocked delta
-  rule (:func:`delta_rule_blocks`) from the slot's state, zeros at
-  position 0, and writes back the state after its last VALID row.
+  A decode tick steps live rows only, in one pass over their state
+  (``ops/kda_state_step``: the state stays in HBM, rows that are not
+  live move none of it); a chunk runs the blocked delta rule
+  (:func:`delta_rule_blocks`) from the slot's state, zeros at position
+  0, and writes back the state after its last VALID row.
 * a sparse-latent layer keeps two leaves of different row rates in
   ``pools`` under the one block table: ``"kv" [P, page, kv_lora_rank]``
   (a row a position) and ``"ik" [P, page / index_kpool,
@@ -94,6 +96,7 @@ from dora_tpu.models.hf.exaone_moe import (  # noqa: F401  (pages_that_fit: test
     pages_that_fit)
 from dora_tpu.models.hf.loader import TensorFiles, read_config
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
+from dora_tpu.ops.kda_state_step import kda_state_step
 
 MODEL_TYPES = ("glm5_next_text",)
 
@@ -589,13 +592,9 @@ def kda_step(blk, cfg: Glm5NextConfig, u, st, active):
         q, k, v = _kda_heads(cfg, conv)
         g, beta, gate = _kda_gates(blk, cfg, fa, ga, b)
     with jax.named_scope("kda_step"):
-        s = st["s"]
-        # products and sums on the vector unit: exact in float32
-        decayed = s * jnp.exp(g)[..., None]
-        pred = (decayed * k[..., None]).sum(-2)  # S~^T k  [B, H, d_v]
-        new = decayed + (beta[..., None] * k)[..., None] * (v - pred)[..., None, :]
-        o = (new * q[..., None]).sum(-2)
-        s = jnp.where(active[:, None, None, None], new, s)
+        # one pass over the live rows' state; products and sums on the
+        # vector unit: exact in float32
+        o, s = kda_state_step(st["s"], g, k, q, v, beta, active)
     return _kda_out(blk, cfg, o, gate), {"s": s, "conv": tail}
 
 

@@ -849,11 +849,14 @@ def test_glm5_window_program_compiles_and_moves_no_cache(chip, picks):
     """The K=8 decode window at GLM-5.3-Flash's widths, 16 slots of
     16,384 rows (1,024 pages a row): every matrix through ``int8_matmul``
     (the 24,960-wide delta-rule input, the 20,480-wide query and indexer
-    heads), ``lm_head_argmax`` over 19,360 columns; the delta-rule step,
-    the residual maps, the index scores, ``top_k`` of 512 among 4,096 and
-    the gather of 2,052 latent rows a row in plain XLA. Pages only for
-    the sparse-latent layer (two leaves), 67 MB of float32 state a
-    delta-rule layer; no copy of either. As the server jits it, and as a
+    heads), ``lm_head_argmax`` over 19,360 columns; the delta-rule step
+    through ``kda_state_step`` (1 MB blocks of 16 heads, a head's columns
+    static lanes of their block); the residual maps, the index scores,
+    ``top_k`` of 512 among 4,096 and the gather of 2,052 latent rows a
+    row in plain XLA. Pages only for the sparse-latent layer (two
+    leaves), 67 MB of float32 state a delta-rule layer; no copy of
+    either, and the state is not staged on chip around the kernel (no
+    ``copy-start`` of it, no ``S(1)`` on it). As the server jits it, and as a
     cache audit's engine does (``make_paged_engine(picks=True)``: every
     tick's picked blocks and sublayer output come out beside)."""
     glm5_next, cfg, params, pools, state, stats = _glm5()
@@ -877,8 +880,14 @@ def test_glm5_window_program_compiles_and_moves_no_cache(chip, picks):
     looks = jax.tree.leaves(lowered.out_info)[-2:]
     assert ([x.shape for x in looks] == [(8, SLOTS, 4096), (8, SLOTS, 512)]) == picks
     compiled = lowered.compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(re.findall(r"%kda_state_step\S* = ", text)) == 2  # a KDA layer
     assert _cache_copies(compiled) == []
+    state = "f32[16,64,128,128]"
+    assert [line.strip()[:120] for line in text.splitlines()
+            if state + "{3,2,1,0:T(8,128)S(1)}" in line
+            or (state in line and "copy-start(" in line)] == []
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 

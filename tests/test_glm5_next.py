@@ -607,6 +607,54 @@ def test_checkpoint_restore_round_trips_a_stream_in_mid_decode(model, tmp_path,
         assert head + rest != want or (top - chosen).max() > TOL
 
 
+def _kernels_and_state_selects(jaxpr, shape):
+    """(names of the ``pallas_call`` equations, sub-programs included;
+    ``select_n`` equations with an operand of ``shape``)."""
+    kernels, selects = [], []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            kernels.append(eqn.params["name"])
+            continue
+        if eqn.primitive.name == "select_n" and any(
+                getattr(v.aval, "shape", None) == shape for v in eqn.invars):
+            selects.append(str(eqn))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            k, s = _kernels_and_state_selects(sub, shape)
+            kernels += k
+            selects += s
+    return kernels, selects
+
+
+@pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
+def test_a_tick_steps_the_state_in_one_kernel_a_layer_and_selects_none(model,
+                                                                       picks):
+    """The decode tick (the served program and the audit's) holds one
+    ``kda_state_step`` a delta-rule layer and no ``jnp.where`` over a
+    whole state array: a frozen row's state is left alone by the kernel's
+    schedule, not selected back. The chunk program does not call the
+    step."""
+    cfg, params, _ = model
+    served = Served(cfg, params, dirty=False)
+    state_shape = served.state["0"]["s"].shape
+    assert state_shape == (SLOTS, cfg.kda_heads, cfg.kda_dim, cfg.kda_dim)
+    i32 = jnp.int32
+    tick = jax.make_jaxpr(
+        lambda p, *a: G.paged_batch_logits(p, cfg, *a, picks=picks))(
+        params, jnp.zeros((SLOTS,), i32), served.pools, served.state,
+        served.stats, jnp.zeros((SLOTS,), i32), jnp.asarray(served.bts),
+        jnp.ones((SLOTS,), bool))
+    kernels, selects = _kernels_and_state_selects(tick.jaxpr, state_shape)
+    assert kernels.count("kda_state_step") == sum(cfg.linear) == 4
+    assert selects == []
+    chunk = jax.make_jaxpr(
+        lambda p, *a: G.paged_chunk_logits(p, cfg, *a, block=BLOCK, picks=picks))(
+        params, jnp.zeros((CHUNK,), i32), served.pools, served.state,
+        served.stats, jnp.asarray(0, i32), jnp.asarray(served.bts[0]),
+        jnp.asarray(CHUNK, i32), jnp.asarray(0, i32))
+    assert "kda_state_step" not in _kernels_and_state_selects(
+        chunk.jaxpr, state_shape)[0]
+
+
 # -- (f) the delta rule's blocked form, Sinkhorn, the picked sets ------------------
 
 
