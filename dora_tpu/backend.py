@@ -18,6 +18,10 @@ whole program to the chip's lowering by patching that one function.
 wherever ``JAX_COMPILATION_CACHE_DIR`` points if it is set (then no
 directory is set in code), else one fixed directory inside the checkout
 (on the chip; the CPU-for-tests mode caches only where it is told to).
+
+:func:`roomy` runs a program's first call — the one that traces, lowers
+and compiles — where the depth of the caller's Python stack cannot make
+it slower.
 """
 
 from __future__ import annotations
@@ -158,3 +162,32 @@ def init_compile_cache() -> str | None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return placed or str(COMPILE_CACHE_DIR)
+
+
+def roomy(call, *args):
+    """``call(*args)``, with room for the Python frames below it.
+
+    CPython (3.11 on) keeps a thread's frames in chunks of 16 KiB and hands
+    a chunk back to the system the moment the frame that opened it returns.
+    A loop that calls small functions from the last frame that still fits a
+    chunk therefore maps, faults in and unmaps a chunk on EVERY call: some 6
+    us a call on this sandbox's CPU against 0.03, more on a host whose many
+    threads each take the unmap's shoot-down. Whether a hot level of JAX's
+    tracer or lowering sits on such an edge depends on the BYTES of stack
+    below it, so on the number of locals of every frame from ``main`` down
+    to the jit call. On the v5e host the first call of K-EXAONE's window
+    program (trace, lowering, a fetch from the compile cache) took 7.7 s
+    from the serving loop as it stood, 12.4 s once a refactor made
+    ``dispatch()``'s frame smaller, and 2.7 s from here, where the chunk
+    is 2 MiB and stays until ``call`` returns: no edge below (PERF.md
+    section 6, PR 46). About 15 us a call: for a program's FIRST
+    call, where seconds are at stake, not for the steady state."""
+    return call(*args)
+
+
+#: A declared evaluation stack of 1 MiB (131,072 slots and a few): no
+#: frame that size fits what is left of a 16 KiB chunk, so entering
+#: ``roomy`` opens a chunk of its own, of 2 MiB, and the 1 MiB behind
+#: its frame holds every frame below. The slots are never written: the
+#: pages stay untouched.
+roomy.__code__ = roomy.__code__.replace(co_stacksize=(1 << 17) + 8)

@@ -178,7 +178,7 @@ class ServingMetrics:
     __slots__ = (
         "ttft", "dispatch_gap", "emit", "fetch_latency", "backlog_wait",
         "grant_pages", "decode_tokens", "emit_messages", "emit_overlapped",
-        "prefill_chunks",
+        "prefill_chunks", "chunks_ahead",
         "requests", "rejected", "slots_active", "slots_total",
         "free_pages", "total_pages", "used_pages", "peak_used_pages",
         "largest_contig_free", "backlog_depth", "host_dispatches",
@@ -249,6 +249,10 @@ class ServingMetrics:
         #: pipelining does not engage
         self.emit_overlapped = 0
         self.prefill_chunks = 0
+        #: of ``prefill_chunks``, those the engine queued behind a
+        #: running window, a period ahead (``PagedBatchEngine.ahead``):
+        #: the host's work after ``collect()`` runs beside such a chunk
+        self.chunks_ahead = 0
         self.requests = 0
         self.rejected = 0
         self.slots_active = 0
@@ -404,6 +408,7 @@ class ServingMetrics:
             "emit_messages": self.emit_messages,
             "emit_overlapped": self.emit_overlapped,
             "prefill_chunks": self.prefill_chunks,
+            "chunks_ahead": self.chunks_ahead,
             "slots_active": self.slots_active,
             "slots_total": self.slots_total,
             "free_pages": self.free_pages,
@@ -513,6 +518,14 @@ class ServingMetrics:
             "deferred": deferred, "blocking": blocking,
             "deferred_share": deferred / total if total else None,
         }
+
+    def chunks_ahead_share(self) -> float | None:
+        """The share of prefill chunks that went to the device behind a
+        running window (``PagedBatchEngine.ahead``): near 1 where prompts
+        queue, near 0 where the queue is empty at nearly every launch."""
+        if not self.prefill_chunks:
+            return None
+        return self.chunks_ahead / self.prefill_chunks
 
 
 def merge_snapshots(snapshots: list[dict]) -> dict:

@@ -28,7 +28,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
-from dora_tpu import profiling, telemetry
+from dora_tpu import backend, profiling, telemetry
 
 
 class PageAllocator:
@@ -412,8 +412,12 @@ class PagedBatchEngine:
             self._hist: list[list[int]] = [[] for _ in range(max_slots)]
             self._hist_dev = jnp.zeros((max_slots, self._hist_buf), jnp.int32)
             self._histlen_dev = jnp.zeros((max_slots,), jnp.int32)
-        #: prefill chunks run (serving metrics)
+        #: the programs :meth:`_launch` has called once
+        self._launched: set = set()
+        #: prefill chunks run (serving metrics), and how many of them
+        #: :meth:`ahead` handed over behind a running window
         self.chunks_run = 0
+        self.chunks_ahead = 0
         #: host->device program launches / device->host token fetches
         #: (round-trip accounting behind tokens_per_dispatch)
         self.dispatches = 0
@@ -436,6 +440,15 @@ class PagedBatchEngine:
         #: reader of both (checkpoint, preempt, drain) runs only after
         #: ``collect()``.
         self._flight: tuple | None = None
+        #: the chunk :meth:`ahead` enqueued behind that window and the
+        #: next :meth:`dispatch` has not adopted yet: ``(slot object,
+        #: slot index, the chunk's result)``. Until then the
+        #: host's side of its stream is untouched (``chunk_base``, the
+        #: prefill queue, ``_decode``, ``emitted``), so whatever reads
+        #: slots between the two sees what it would with the chunk still
+        #: to come, and a stream preempted or drained meanwhile is told
+        #: from a live one by its slot object.
+        self._ahead: tuple | None = None
         #: the stamp on which the last :meth:`dispatch` left
         #: ``window_launch`` itself, for a first token's read beside the
         #: window: from there on the device has work, so the host's gap
@@ -887,26 +900,31 @@ class PagedBatchEngine:
         first token appears the tick its final chunk lands, the rest
         arrive up to K per tick off a single device round-trip.
 
-        ``dispatch()`` then ``collect()``: the serving loop calls the
-        halves itself and sends the previous window's tokens between
-        them, while the device runs this one."""
+        ``dispatch()`` then ``collect()``, every chunk in line: the
+        serving loop calls the halves itself, sends the previous
+        window's tokens between them while the device runs this one, and
+        then puts the next period's chunk behind it (:meth:`ahead`)."""
         return self.dispatch() + self.collect()
 
     def dispatch(self) -> list[tuple[str, int, bool]]:
-        """The launching half of :meth:`step`: the prefill chunk, the
-        membership / block-table rebuild and the launch of the window
-        program — everything up to the point where the host would start
-        to wait for the window. Returns the first token of a stream
-        whose final chunk just ran, usually nothing. That token goes to
-        its slot on the device (``_set_slot`` gathers it from the
-        chunk's result), so the window is launched behind the chunk
-        without it, and the host reads it for the wire AFTER the launch,
-        beside the window (phase ``first_token_read``): the read returns
-        when the chunk is done, not when the window is. The read comes
-        before the launch, blocking (phase ``first_token_wait``), only
-        where the host needs the value first — speculation is on
-        (``spec_k``: the history mirror is rebuilt into the window's
-        operands) — or no window follows to read beside."""
+        """The launching half of :meth:`step`: the period's prefill
+        chunk, the membership / block-table rebuild and the launch of
+        the window program — everything up to the point where the host
+        would start to wait for the window. The chunk is the one
+        :meth:`ahead` put behind the previous window, adopted here, or,
+        where none went ahead, the next of the prefill queue's head,
+        enqueued here in line: one chunk a period either way. Returns
+        the first token of a stream whose final chunk that was, usually
+        nothing. That token goes to its slot on the device
+        (``_set_slot`` gathers it from the chunk's result), so the
+        window is launched behind the chunk without it, and the host
+        reads it for the wire AFTER the launch, beside the window (phase
+        ``first_token_read``): the read returns when the chunk is done,
+        not when the window is. The read comes before the launch,
+        blocking (phase ``first_token_wait``), only where the host needs
+        the value first — speculation is on (``spec_k``: the history
+        mirror is rebuilt into the window's operands) — or no window
+        follows to read beside."""
         assert self._flight is None, "dispatch() before collect()"
         self.launched_at = None
         jnp = self._jnp
@@ -919,112 +937,37 @@ class PagedBatchEngine:
         #: a final chunk's ``(stream, slot, result, row)`` whose first
         #: token is read once the window is launched
         first = None
+        rebuilding = False
 
-        if self._prefillq:
+        went, self._ahead = self._ahead, None
+        if went is not None:
+            # The period's chunk is on the device since the last window's
+            # launch. Its stream may have lost its slot meanwhile
+            # (preempt, drain): then the chunk wrote pages and a state
+            # row that nobody holds, as a preempted stream's chunks
+            # always have, and the period still has had its chunk.
+            s, b, greedy = went
+            if self.slots[b] is s:
+                if self._final_chunk(s, s.chunk_base):
+                    # a stream starts: membership work, as its rebuild is
+                    tracer.switch("rebuild")
+                    rebuilding = True
+                first = self._adopt_chunk(s, b, greedy, emitted)
+        elif self._prefillq:
             t_chunk = tracer.switch("chunk_launch")
             b = self._prefillq[0]
             s = self.slots[b]
             base = s.chunk_base
-            piece = s.prompt[base : base + self.chunk]
-            valid = (
-                (jnp.asarray(len(piece), jnp.int32),)
-                if self.chunk_valid_rows else ()
-            )
-            piece = piece + [0] * (self.chunk - len(piece))
-            operands = list(valid)
-            if self.lora is not None:
-                # Adapter id rides as a traced operand (an int32 device
-                # scalar, never a python constant) so chunk prefill
-                # keeps its one-compiled-shape discipline across
-                # tenants.
-                operands += [jnp.asarray(s.adapter_idx, jnp.int32),
-                             self.lora.state()]
-            elif self.slot_state is not None:
-                # Which slot the chunk fills, and the slots' state: the
-                # program reads row ``b`` (zeros at position 0) and
-                # writes it back as it stands after the prompt's rows.
-                operands += [jnp.asarray(b, jnp.int32), self.slot_state]
-            greedy, self.pools, *state = self.chunk_prefill(
-                jnp.asarray(piece, jnp.int32), self.pools,
-                jnp.asarray(base, jnp.int32), jnp.asarray(self._bt[b]),
-                *operands,
-            )
-            if state:
-                (self.slot_state,) = state
-            s.chunk_base = base + self.chunk
-            self.chunks_run += 1
-            self.dispatches += 1
-            if self.device_monitor:
-                self.host_dispatch_ns += int((tracer.clock() - t_chunk) * 1e9)
-                if self.flops_per_token:
-                    self.dispatched_flops += self.chunk * self.flops_per_token
-                    self.useful_flops += (
-                        min(self.chunk, s.true_len - base)
-                        * self.flops_per_token
-                    )
-            final_chunk = s.chunk_base >= s.true_len
-            if final_chunk:  # final chunk: stream starts
-                self._prefillq.popleft()
-                if self.prefix_cache is not None:
-                    # The prompt's fully-populated pages are immutable
-                    # from here on (decode writes start at true_len,
-                    # past them): adopt them into the radix cache so
-                    # later prompts map them instead of re-prefilling.
-                    n_full = s.true_len // self.page_size
-                    if n_full:
-                        self.prefix_cache.insert(
-                            s.prompt[: n_full * self.page_size],
-                            s.pages[:n_full],
-                            s.adapter,
-                        )
-                s.prompt = None
-                # Its first token exists, on the device: the window's
-                # completion counter (rebuilt from here) starts behind it.
-                s.emitted = 1
-                row = s.true_len - 1 - base
-                if s.max_new > 1:
-                    # The stream decodes from the window this dispatch
-                    # launches, whatever its first token is: were it
-                    # ``eos``, the read below frees the slot and
-                    # collect() passes the row over; what the row wrote
-                    # meanwhile fell in pages it held for itself, past
-                    # the prompt's full pages that the cache adopted.
-                    self._decode[b] = True
-                    self.tokens, self.positions = self._set_slot(
-                        self.tokens, self.positions, greedy,
-                        jnp.asarray(row, jnp.int32),
-                        jnp.asarray(s.true_len, jnp.int32),
-                        jnp.asarray(b, jnp.int32),
-                    )
-                    self._members_dirty = True
-                    self._bt_dirty = True
-                # else one token is all it asked for: the row never
-                # decodes. Its slot too is freed at the read, not here:
-                # the chunk just enqueued may still be reading this
-                # slot's row of the block table (``jnp.asarray`` of a
-                # numpy view need not copy), which _free_slot() zeroes.
-                if self.spec_k or not any(self._decode):
-                    t_fetch = tracer.enter("first_token_wait")
-                    emitted.append(
-                        self._first_token(s, b, greedy, row, t_fetch)
-                    )
-                    tracer.leave()
-                else:
-                    first = (s, b, greedy, row)
-            if tracer.active:
-                # Chunks are async dispatches, so the span is dispatch
-                # cost only — but for a final chunk whose first token
-                # was read here, blocking (speculation, or no window to
-                # read it beside): that span holds the read too.
-                tracer.span(
-                    "s_prefill_chunk", s.request_id,
-                    f"base={base} chunk={self.chunk}"
-                    + (" final" if final_chunk else ""),
-                    dur_ns=int((tracer.clock() - t_chunk) * 1e9),
-                )
+            greedy = self._enqueue_chunk(s, b, t_chunk)
+            first = self._adopt_chunk(s, b, greedy, emitted)
+            # Chunks are async dispatches, so the span is dispatch cost
+            # only — but for a final chunk whose first token was read
+            # here, blocking (speculation, or no window to read it
+            # beside): that span holds the read too.
+            self._chunk_span(s, base, t_chunk)
 
         if any(self._decode):
-            if self._members_dirty or self._bt_dirty:
+            if (self._members_dirty or self._bt_dirty) and not rebuilding:
                 tracer.switch("rebuild")
             if self._members_dirty:
                 # Membership changed at this boundary: rebuild the
@@ -1104,7 +1047,8 @@ class PagedBatchEngine:
                     self.pools,
                     self._hist_dev,
                     self._histlen_dev,
-                ) = self.window_step(
+                ) = self._launch(
+                    self.window_step,
                     self.tokens, self.pools, self.positions, self._bt_dec,
                     self._mask, self._emitted_dev, self._maxnew_dev,
                     self._hist_dev, self._histlen_dev, *extra,
@@ -1118,7 +1062,8 @@ class PagedBatchEngine:
                     self._emitted_dev,
                     self.pools,
                     *state,
-                ) = self.window_step(
+                ) = self._launch(
+                    self.window_step,
                     self.tokens, self.pools, self.positions, self._bt_dec,
                     self._mask, self._emitted_dev, self._maxnew_dev,
                     *extra,
@@ -1142,6 +1087,166 @@ class PagedBatchEngine:
                     t_launched = tracer.clock()
             self._flight = (mat, t_win, t_launched)
         return emitted
+
+    def _launch(self, program, *operands):
+        """Hand the device a chunk or window program. A program's FIRST
+        call traces, lowers and compiles it, seconds of Python whose
+        speed hangs on how many bytes of stack lie below it (the chunk
+        edge of CPython's frame stack): that call runs in a roomy frame
+        (``backend.roomy``), so the serving loop's frames, this
+        module's among them, cannot make a start-up slower."""
+        if program in self._launched:
+            return program(*operands)
+        self._launched.add(program)
+        return backend.roomy(program, *operands)
+
+    def ahead(self) -> None:
+        """Between :meth:`dispatch` and :meth:`collect`, once the
+        dispatch's tokens have left: hand the device the NEXT period's
+        chunk now, behind the window that runs, where the prefill queue
+        holds one. It starts the instant the window ends, and what the
+        host does after ``collect()`` runs beside it; the next
+        ``dispatch()`` adopts it and launches none of its own. The
+        device's order — chunk, window, chunk, window — and each
+        program's operands are those of chunks enqueued in line. Nothing
+        goes ahead where no window was launched, nor a FINAL chunk while
+        speculation is on (its token is needed on the host before the
+        launch it would precede)."""
+        if self._flight is None or self._ahead is not None or not self._prefillq:
+            return
+        b = self._prefillq[0]
+        s = self.slots[b]
+        if self.spec_k and self._final_chunk(s, s.chunk_base):
+            return
+        t_chunk = self.tracer.switch("chunk_ahead")
+        self._ahead = (s, b, self._enqueue_chunk(s, b, t_chunk))
+        self.chunks_ahead += 1
+        self._chunk_span(s, s.chunk_base, t_chunk)
+
+    def _final_chunk(self, s: _PagedSlot, base: int) -> bool:
+        """Does the chunk at ``base`` hold the prompt's last row?"""
+        return base + self.chunk >= s.true_len
+
+    def _enqueue_chunk(self, s: _PagedSlot, b: int, t_chunk: float):
+        """Hand the device the chunk of slot ``b``'s prompt at
+        ``s.chunk_base``; returns its result (greedy ``[C]``), a future.
+        ``pools`` and ``slot_state`` become the chunk's results; the
+        host's side of the stream is :meth:`_adopt_chunk`'s."""
+        jnp = self._jnp
+        base = s.chunk_base
+        piece = s.prompt[base : base + self.chunk]
+        valid = (
+            (jnp.asarray(len(piece), jnp.int32),)
+            if self.chunk_valid_rows else ()
+        )
+        piece = piece + [0] * (self.chunk - len(piece))
+        operands = list(valid)
+        if self.lora is not None:
+            # Adapter id rides as a traced operand (an int32 device
+            # scalar, never a python constant) so chunk prefill
+            # keeps its one-compiled-shape discipline across
+            # tenants.
+            operands += [jnp.asarray(s.adapter_idx, jnp.int32),
+                         self.lora.state()]
+        elif self.slot_state is not None:
+            # Which slot the chunk fills, and the slots' state: the
+            # program reads row ``b`` (zeros at position 0) and
+            # writes it back as it stands after the prompt's rows.
+            operands += [jnp.asarray(b, jnp.int32), self.slot_state]
+        # The slot's row of the block table goes as a COPY:
+        # ``jnp.asarray`` of a numpy view need not copy before it
+        # returns, and _free_slot() zeroes the row in place — a whole
+        # window may pass between this enqueue and the chunk's run.
+        greedy, self.pools, *state = self._launch(
+            self.chunk_prefill, jnp.asarray(piece, jnp.int32), self.pools,
+            jnp.asarray(base, jnp.int32), jnp.asarray(self._bt[b].copy()),
+            *operands,
+        )
+        if state:
+            (self.slot_state,) = state
+        self.chunks_run += 1
+        self.dispatches += 1
+        if self.device_monitor:
+            self.host_dispatch_ns += int((self.tracer.clock() - t_chunk) * 1e9)
+            if self.flops_per_token:
+                self.dispatched_flops += self.chunk * self.flops_per_token
+                self.useful_flops += (
+                    min(self.chunk, s.true_len - base) * self.flops_per_token
+                )
+        return greedy
+
+    def _chunk_span(self, s: _PagedSlot, base: int, t_chunk: float) -> None:
+        tracer = self.tracer
+        if tracer.active:
+            tracer.span(
+                "s_prefill_chunk", s.request_id,
+                f"base={base} chunk={self.chunk}"
+                + (" final" if self._final_chunk(s, base) else ""),
+                dur_ns=int((tracer.clock() - t_chunk) * 1e9),
+            )
+
+    def _adopt_chunk(self, s: _PagedSlot, b: int, greedy,
+                     emitted: list) -> tuple | None:
+        """The host's side of a chunk that is on the device, in
+        ``dispatch()``, before the rebuild: the stream's ``chunk_base``
+        and, behind a FINAL chunk, the start of its decode — off the
+        prefill queue, its pages into the prefix cache, its first token
+        to its slot on the device, the dirty flags. Returns the
+        ``(stream, slot, result, row)`` of a first token to read once
+        the window is launched; one read here, blocking, goes to
+        ``emitted``."""
+        jnp = self._jnp
+        tracer = self.tracer
+        base = s.chunk_base
+        s.chunk_base = base + self.chunk
+        if not self._final_chunk(s, base):
+            return None
+        # final chunk: stream starts
+        assert self._prefillq[0] == b, (self._prefillq, b)
+        self._prefillq.popleft()
+        if self.prefix_cache is not None:
+            # The prompt's fully-populated pages are immutable
+            # from here on (decode writes start at true_len,
+            # past them): adopt them into the radix cache so
+            # later prompts map them instead of re-prefilling.
+            n_full = s.true_len // self.page_size
+            if n_full:
+                self.prefix_cache.insert(
+                    s.prompt[: n_full * self.page_size],
+                    s.pages[:n_full],
+                    s.adapter,
+                )
+        s.prompt = None
+        # Its first token exists, on the device: the window's
+        # completion counter (rebuilt from here) starts behind it.
+        s.emitted = 1
+        row = s.true_len - 1 - base
+        if s.max_new > 1:
+            # The stream decodes from the window this dispatch
+            # launches, whatever its first token is: were it
+            # ``eos``, the read below frees the slot and
+            # collect() passes the row over; what the row wrote
+            # meanwhile fell in pages it held for itself, past
+            # the prompt's full pages that the cache adopted.
+            self._decode[b] = True
+            self.tokens, self.positions = self._set_slot(
+                self.tokens, self.positions, greedy,
+                jnp.asarray(row, jnp.int32),
+                jnp.asarray(s.true_len, jnp.int32),
+                jnp.asarray(b, jnp.int32),
+            )
+            self._members_dirty = True
+            self._bt_dirty = True
+        # else one token is all it asked for: the row never decodes.
+        # Its slot too is freed at the read, not here: whatever reads
+        # the slot between a chunk's enqueue and its token's read finds
+        # the stream as it stands.
+        if self.spec_k or not any(self._decode):
+            t_fetch = tracer.enter("first_token_wait")
+            emitted.append(self._first_token(s, b, greedy, row, t_fetch))
+            tracer.leave()
+            return None
+        return s, b, greedy, row
 
     def _first_token(self, s: _PagedSlot, b: int, greedy, row: int,
                      t_fetch: float) -> tuple[str, int, bool]:
@@ -1521,6 +1626,7 @@ class PagedBatchEngine:
             if s is not None:
                 self._free_slot(b)
         self._prefillq.clear()
+        self._ahead = None  # its stream went with the rest
         return state
 
     def admit_streams(self, state: dict) -> list[str]:
@@ -1733,37 +1839,50 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
         )
 
     class StubEngine(PagedBatchEngine):
-        """The modelled device: one queue, busy until ``_busy_until``."""
+        """The modelled device: one queue, busy until ``_busy_until``;
+        what is handed to it is done where the queue stood plus its own
+        time, and a wait is for one piece of work, not for the queue."""
 
         _busy_until = 0.0
+        _chunk_done_at = 0.0
+        _window_done_at = 0.0
 
-        def _occupy(self, seconds: float) -> None:
+        def _occupy(self, seconds: float) -> float:
             self._busy_until = (
                 max(self._busy_until, time.perf_counter()) + seconds
             )
+            return self._busy_until
 
-        def _wait_device(self) -> None:
-            wait = self._busy_until - time.perf_counter()
+        @staticmethod
+        def _wait_until(done_at: float) -> None:
+            wait = done_at - time.perf_counter()
             if wait > 0:
                 time.sleep(wait)
 
+        def _enqueue_chunk(self, *args):
+            if chunk_sleep_s:
+                self._chunk_done_at = self._occupy(chunk_sleep_s)
+            return super()._enqueue_chunk(*args)
+
         def dispatch(self):
-            if chunk_sleep_s and self._prefillq:
-                self._occupy(chunk_sleep_s)
             first = super().dispatch()
             if tick_sleep_s and self.in_flight:
-                self._occupy(tick_sleep_s * self.window)
+                # behind the period's chunk, which a first token's read
+                # inside super().dispatch() has waited for already
+                self._window_done_at = self._occupy(
+                    tick_sleep_s * self.window
+                )
             return first
 
         def _first_token(self, *args):
-            # the read returns when the chunk is done: the window, where
-            # one was launched already, occupies the device from then
-            self._wait_device()
+            # the read returns when the chunk is done, whatever is
+            # queued behind it
+            self._wait_until(self._chunk_done_at)
             return super()._first_token(*args)
 
         def collect(self):
             if self.in_flight:
-                self._wait_device()
+                self._wait_until(self._window_done_at)
             return super().collect()
 
     engine = StubEngine(

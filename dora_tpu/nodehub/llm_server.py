@@ -35,7 +35,10 @@ latency quantizes to one window. The loop is pipelined by one window:
 it launches window N+1 (``engine.dispatch()``), sends window N's
 tokens while the device runs — one message per stream — and only then
 waits (``engine.collect()``); a prompt's first token leaves right after
-the launch, ahead of them and before its window is collected. Tokens
+the launch, ahead of them and before its window is collected; with
+nothing left to send, the next period's prefill chunk goes to the device
+behind the running window (``engine.ahead()``), so the host's work after
+``collect()`` runs beside a chunk instead of an idle chip. Tokens
 collected and not yet sent are flushed before anything reads
 per-request state (preemption, migration, checkpoints, errors, exit, an
 engine gone idle).
@@ -446,7 +449,10 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
     the launch of one K-tick decode window), emit the first tokens the
     dispatch returned and the tokens HELD from the previous window, one
     message a stream (:func:`_flush`) — the device runs the new window
-    meanwhile — then ``engine.collect()`` waits for the window and its
+    meanwhile — then ``engine.ahead()`` queues the NEXT period's chunk
+    behind that window (the next ``dispatch()`` then launches none: what
+    the host does from here to the next launch runs beside a chunk),
+    then ``engine.collect()`` waits for the window and its
     tokens become the held ones. Then ALWAYS drain the backlog —
     capacity appears when a step frees slots/pages, but also the idle
     path must admit (a parked request with zero active streams used to
@@ -571,6 +577,10 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
                     metrics.emit_overlapped += sent
                     # The emit side of max(device, emit) in this period.
                     metrics.emit.observe((tracer.clock() - t_emit) * 1e6)
+                    # Nothing is left to send: the next period's chunk
+                    # goes to the device now, behind the window, where
+                    # the prefill queue holds one (phase ``chunk_ahead``).
+                    half(engine.ahead)
                 else:
                     # Nothing ran beside the emit (a prefill-only
                     # dispatch): the device waited for it too.
@@ -1176,8 +1186,9 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
 
     def _capture_edge(edge: str) -> None:
         # Both edges fall between a collect() and the next dispatch():
-        # no window is in flight, so the read waits on nothing, and the
-        # two reads bracket exactly the ticks the capture holds.
+        # no window is in flight, so the two reads bracket exactly the
+        # ticks the capture holds. A chunk that went ahead may be: the
+        # read waits for it, at either edge.
         if engine.model_counters is not None:
             metrics.capture_counters[edge] = engine.model_counters()
 
@@ -1256,6 +1267,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         metrics.slots_total = engine.max_slots
         metrics.backlog_depth = len(backlog)
         metrics.prefill_chunks = engine.chunks_run
+        metrics.chunks_ahead = engine.chunks_ahead
         metrics.host_dispatches = engine.dispatches
         metrics.host_fetches = engine.fetches
         metrics.compiles = telemetry.compile_count()
@@ -1289,7 +1301,8 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         if counters is not None:
             # The model's own counters (an expert layer's routing, the
             # latent pool): read here, after collect(), with no window
-            # in flight, so the read waits on nothing.
+            # in flight — so the read waits for a chunk that went ahead
+            # at most, once a second, and for nothing where none did.
             metrics.model = counters()
         metrics.qos_depth = backlog.depths()
         metrics.autotune_k = engine.window
@@ -1813,6 +1826,11 @@ def main() -> None:
             # how often a first token's read left the gap (deferred,
             # beside the window) and how often it still held the launch
             "first_token_reads": metrics.first_token_reads(),
+            # the share of prefill chunks that went to the device
+            # behind a running window, ahead of their period
+            "prefill_chunks": metrics.prefill_chunks,
+            "chunks_ahead": metrics.chunks_ahead,
+            "chunks_ahead_share": metrics.chunks_ahead_share(),
             **metrics.model,
         })
 

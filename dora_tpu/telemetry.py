@@ -291,7 +291,8 @@ FLIGHT = FlightRecorder(
 #: so between two ``collect()`` returns every nanosecond lies in exactly
 #: one phase. A dotted name is a child, entered inside its parent and
 #: counted in the parent's histogram too. ``first_token_wait`` runs
-#: inside ``chunk_launch`` and is carved out of it: the host is blocked
+#: inside ``chunk_launch`` (inside ``rebuild`` behind a chunk that went
+#: ahead) and is carved out of it: the host is blocked
 #: on the device there, which is no part of launching a chunk. The gap
 #: ends on the stamp that leaves ``window_launch``: the engine's own
 #: switch to ``first_token_read`` where a first token is read beside
@@ -308,7 +309,8 @@ LOOP_PHASES = {
     # (parse, encode, push)
     "intake": True,
     "intake.handle_input": True,
-    # dispatch(): the chunk's operands and enqueue, the prefix-cache
+    # dispatch(), where no chunk went ahead of this period: the chunk's
+    # operands and enqueue, the prefix-cache
     # insert, _set_slot (the first token goes to its slot on the device)
     # — less first_token_wait: the read of a final chunk's first token
     # where it still comes BEFORE the launch and blocks it (speculation's
@@ -316,13 +318,19 @@ LOOP_PHASES = {
     # device time inside the host's gap
     "chunk_launch": True,
     "first_token_wait": True,
-    # dispatch(): membership and block table, the jnp.asarray calls
+    # dispatch(): membership and block table, the jnp.asarray calls;
+    # behind a final chunk that went ahead, its adoption too
     "rebuild": True,
     "window_launch": True,
     # dispatch(): the read of a final chunk's first token AFTER the
     # launch, for the wire: the chunk's remaining device time and the
     # token's way to the host, while the device goes on to the window
     "first_token_read": False,
+    # ahead(), after the flush: the NEXT period's chunk, its operands
+    # and enqueue, handed over behind the window that runs; a chunk
+    # that goes ahead is no chunk_launch, and the two phases' counts add
+    # up to the chunks run
+    "chunk_ahead": False,
     # the flush after a dispatch: beside the window it launched, or
     # (emit_alone) with nothing running, where the device waits for it
     "emit": False,

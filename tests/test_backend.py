@@ -460,6 +460,56 @@ def test_exaone_window_reads_the_global_pages_through_the_sweep(
 # -- the smoke test's parent stays off JAX ----------------------------------
 
 
+def _leaf():
+    return None
+
+
+def _faults_a_call(depth: int, calls: int = 100) -> float:
+    """Minor page faults a call of a trivial function, called ``calls``
+    times from ``depth`` frames further down this thread's stack."""
+    import resource
+
+    if depth:
+        return _faults_a_call(depth - 1, calls)
+    before = resource.getrusage(resource.RUSAGE_THREAD).ru_minflt
+    for _ in range(calls):
+        _leaf()
+    return (resource.getrusage(resource.RUSAGE_THREAD).ru_minflt - before) / calls
+
+
+def _chunk_edges(roomy: bool, depths=range(0, 600)) -> list[int]:
+    """The depths at which every call faults a page in, seen from a
+    thread of its own: its frame stack starts empty, whatever called."""
+    import threading
+
+    def scan():
+        return [d for d in depths if _faults_a_call(d) >= 0.9]
+
+    out: list = []
+    thread = threading.Thread(
+        target=lambda: out.append(backend.roomy(scan) if roomy else scan())
+    )
+    thread.start()
+    thread.join()
+    return out[0]
+
+
+def test_roomy_takes_the_chunk_edge_of_the_frame_stack_out_from_under_a_call():
+    """CPython keeps a thread's frames in 16 KiB chunks and unmaps a
+    chunk when the frame that opened it returns: from some depth, every
+    call of a small function maps and faults in a chunk. ``backend.roomy``
+    opens one chunk that holds everything below it: no depth has the
+    edge. Counts of page faults, not seconds."""
+    if not hasattr(__import__("resource"), "RUSAGE_THREAD"):
+        pytest.skip("no per-thread rusage on this platform")
+    assert backend.roomy(lambda a, b: a + b, 2, 3) == 5  # operands go through
+    edges = _chunk_edges(roomy=False)
+    if not edges:
+        pytest.skip("this interpreter or kernel shows no chunk edge to take away")
+    # plain: an edge every 16 KiB of frames; roomy: none in 1 MiB of them
+    assert _chunk_edges(roomy=True) == [], edges
+
+
 def test_chip_smoke_parent_never_imports_jax():
     out = _run(
         "import chip_smoke, sys; assert 'jax' not in sys.modules; "

@@ -438,6 +438,57 @@ def test_preempt_and_resume_give_the_first_streams_tokens(model):
     assert run_one(engine, prompt, 14) == want
 
 
+def test_chunks_ahead_of_their_period_give_the_tokens_of_step(model):
+    """``dispatch → ahead → collect`` against ``step()`` on the MoE
+    model whose window layers keep a ring a slot: multi-chunk prompts
+    whose chunks go behind windows that step other rows' rings and
+    pages give the tokens they give in line, and a stream preempted
+    between ``ahead()`` and its chunk's adoption comes back to them."""
+    cfg, params, _ = model
+    prompts = {"a": prompt_ids(21, seed=61), "long": prompt_ids(45, seed=62),
+               "b": prompt_ids(9, seed=63)}
+    caps = {"a": 9, "long": 14, "b": 6}
+
+    engine = make_engine(cfg, params)
+
+    def serve(halves: bool):
+        ran, ahead = engine.chunks_run, engine.chunks_ahead
+        for rid, prompt in prompts.items():
+            engine.submit(rid, prompt, caps[rid])
+        got = {rid: [] for rid in prompts}
+        preempted = False
+        for _ in range(300):
+            if not engine.active:
+                break
+            if halves:
+                out = engine.dispatch()
+                engine.ahead()
+                out += engine.collect()
+                went = engine._ahead
+                if not preempted and went is not None and went[0].request_id == "b":
+                    # b's chunk is on the device and its stream goes:
+                    # the chunk wrote a ring and pages nobody holds
+                    assert engine.preempt("b") is not None
+                    engine.check_invariants()
+                    engine.submit("b", prompts["b"], caps["b"])
+                    preempted = True
+            else:
+                out = engine.step()
+            for rid, tok, _done in out:
+                got[rid].append(tok)
+        assert halves == preempted
+        engine.check_invariants()
+        return got, engine.chunks_run - ran, engine.chunks_ahead - ahead
+
+    # the same engine, so the same two programs: in line, then ahead
+    want, line_chunks, line_ahead = serve(False)
+    got, chunks, ahead = serve(True)
+    assert got == want and [len(got[r]) for r in caps] == list(caps.values())
+    assert line_ahead == 0 and ahead >= 4
+    # b's chunk ran twice: once for the stream that went, once again
+    assert chunks == line_chunks + 1
+
+
 def run_one(engine, prompt, max_new, rid="r") -> list[int]:
     engine.submit(rid, prompt, max_new)
     return run(engine, rid)

@@ -372,6 +372,39 @@ def test_twins_and_a_reused_slot_give_the_first_streams_tokens(model):
     assert len(got["long"]) == 14
 
 
+def test_chunks_ahead_of_their_period_carry_the_slot_state_as_in_line(model):
+    """``ahead()`` hands the next period's chunk the slots' state as the
+    running window will leave it: three-chunk and two-chunk prompts
+    whose chunks go behind windows that step other rows' state give the
+    tokens they give alone, and a slot taken again starts from zeros."""
+    cfg, params, _ = model
+    prompt, longer = prompt_ids(21), prompt_ids(45, seed=5)
+    alone = make_engine(cfg, params)
+    alone.submit("a", prompt, 9)
+    want = run(alone, "a")
+    alone.submit("b", longer, 14)
+    want_long = run(alone, "b")
+    engine = make_engine(cfg, params)
+    engine.submit("t1", prompt, 9)      # slot 0
+    engine.submit("long", longer, 14)   # slot 1
+    engine.submit("t2", prompt, 9)      # slot 2
+    got: dict[str, list[int]] = {"long": [], "t1": [], "t2": [], "again": []}
+    resubmitted = False
+    for _ in range(200):
+        first = engine.dispatch()
+        engine.ahead()
+        for r, tok, _done in first + engine.collect():
+            got[r].append(tok)
+        if not resubmitted and engine.slots[0] is None:
+            engine.submit("again", prompt, 9)  # slot 0, after "t1"
+            resubmitted = True
+        if resubmitted and not engine.active:
+            break
+    assert got["t1"] == want and got["t2"] == want and got["again"] == want
+    assert got["long"] == want_long
+    assert engine.chunks_run == 2 + 3 + 2 + 2 and engine.chunks_ahead >= 6
+
+
 def test_a_frozen_rows_state_is_bit_identical_across_a_window(model):
     """A stream that finishes on a window's first tick is frozen for the
     rest of it: its state is the reference's after that one token, not

@@ -568,6 +568,46 @@ def test_preempt_and_resume_give_the_first_streams_tokens(model):
     assert run_one(engine, prompt, 14) == want
 
 
+def test_chunks_ahead_of_their_period_give_the_tokens_of_step(model):
+    """``dispatch → ahead → collect`` against ``step()`` where a chunk
+    is NOT idempotent (the delta-rule layers' state a slot): a chunk
+    that goes ahead reads the state the running window leaves and is
+    adopted once, so three- and six-chunk prompts beside streams that
+    decode give the tokens they give in line."""
+    cfg, params, _ = model
+    prompts = {"a": prompt_ids(21, seed=61), "long": prompt_ids(45, seed=62),
+               "b": prompt_ids(9, seed=63)}
+    caps = {"a": 9, "long": 14, "b": 6}
+
+    engine = make_engine(cfg, params)
+
+    def serve(halves: bool):
+        ran, ahead = engine.chunks_run, engine.chunks_ahead
+        for rid, prompt in prompts.items():
+            engine.submit(rid, prompt, caps[rid])
+        got = {rid: [] for rid in prompts}
+        for _ in range(300):
+            if not engine.active:
+                break
+            if halves:
+                out = engine.dispatch()
+                engine.ahead()
+                out += engine.collect()
+            else:
+                out = engine.step()
+            for rid, tok, _done in out:
+                got[rid].append(tok)
+        engine.check_invariants()
+        return got, engine.chunks_run - ran, engine.chunks_ahead - ahead
+
+    # the same engine, so the same two programs: in line, then ahead
+    # (every slot is taken again from zeros)
+    want, line_chunks, line_ahead = serve(False)
+    got, chunks, ahead = serve(True)
+    assert got == want and [len(got[r]) for r in caps] == list(caps.values())
+    assert line_ahead == 0 and ahead >= 3 and chunks == line_chunks
+
+
 @pytest.mark.parametrize("lost", [None, "s", "conv"])
 def test_checkpoint_restore_round_trips_a_stream_in_mid_decode(model, tmp_path,
                                                                lost):

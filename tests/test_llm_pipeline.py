@@ -101,6 +101,9 @@ class SplitEngine(_Streams):
         self.launched += self.in_flight
         return first
 
+    def ahead(self):
+        self.log.append(("ahead",))
+
     def collect(self):
         self.log.append(("collect",))
         if not self.in_flight:
@@ -201,16 +204,18 @@ def test_steady_state_is_dispatch_then_previous_windows_tokens_then_collect():
     log: list = []
     metrics, _ = _drive(SplitEngine(log, slots=1, k=3), log, [(0, _input("a"))])
     kinds = [e[0] for e in log]
-    # first token right after the dispatch that read it, before collect
-    assert kinds[:4] == ["dispatch", "message", "emit", "collect"]
+    # first token right after the dispatch that read it, before collect;
+    # the engine is offered the next period's chunk once nothing is left
+    # to send, never before
+    assert kinds[:5] == ["dispatch", "message", "emit", "ahead", "collect"]
     assert log[2][1:4] == ("a", 1, False)
     # then: dispatch, the three tokens of the window before as ONE
-    # message, collect
-    assert kinds[4:10] == [
-        "dispatch", "message", "emit", "emit", "emit", "collect"
+    # message, ahead, collect
+    assert kinds[5:12] == [
+        "dispatch", "message", "emit", "emit", "emit", "ahead", "collect"
     ]
-    assert log[5] == ("message", "a", 3)
-    assert [e[2] for e in log[6:9]] == [2, 3, 4]
+    assert log[6] == ("message", "a", 3)
+    assert [e[2] for e in log[7:10]] == [2, 3, 4]
     assert [e[2] for e in _messages(log)] == [1, 3, 3]
     # in order, nothing lost, done last
     assert [e[2] for e in _emits(log)] == [1, 2, 3, 4, 5, 6, 7]
@@ -569,6 +574,13 @@ def test_a_flush_sends_one_message_a_stream_through_serve():
     engine.dispatch, engine.collect = dispatch, collect
     metrics = ServingMetrics(engine="paged")
     _serve_stub(wire, engine, metrics)
+    # the loop offered the engine the next period's chunk after every
+    # flush beside a window: w-b's and w-c's went ahead, w-a's (nothing
+    # ran yet) was launched in line
+    assert engine.chunks_run == 4 and engine.chunks_ahead >= 2
+    assert metrics.chunks_ahead == metrics.phases["chunk_ahead"].count
+    assert metrics.chunks_ahead_share() == engine.chunks_ahead / 4
+    assert ServingMetrics().chunks_ahead_share() is None
     by_rid = _assert_consecutive(wire)
     want = {rid: _stub_words(b"hello world", cap) for rid, cap in caps.items()}
     for rid, chunks in by_rid.items():

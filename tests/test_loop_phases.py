@@ -69,7 +69,10 @@ class PhasedEngine:
     stream. Enters the engine's phases where PagedBatchEngine does:
     chunk, set-slot, rebuild, launch, and then the read of the first
     token beside the window — or, ``blocking`` (an engine that
-    speculates), the read inside the chunk's launch, before the window."""
+    speculates), the read inside the chunk's launch, before the window.
+    ``ahead()`` puts the next stream's chunk behind the window that runs
+    (never under ``blocking``: its chunks are final), and the dispatch
+    that finds it adopts it, set-slot and all, as part of its rebuild."""
 
     tracer = None
     CHUNK, FIRST, SET_SLOT, REBUILD, LAUNCH, WAIT, UNPACK = (
@@ -87,7 +90,8 @@ class PhasedEngine:
         self.in_flight = False
         self.launched_at = None
         self.dispatches = self.chunks = self.rebuilds = self.windows = 0
-        self.reads = 0
+        self.reads = self.chunks_ahead = 0
+        self.went = None  # the stream whose chunk went ahead
         self.can_admit_calls = 0
         self.collect_returns: list[float] = []
         self.launch_ends: list[float] = []
@@ -121,12 +125,17 @@ class PhasedEngine:
         self.dispatches += 1
         self.launched_at = None
         first, read = [], None
-        if self.prefillq:
-            tr.switch("chunk_launch")
-            tick(self.CHUNK)
-            self.chunks += 1
+        went, self.went = self.went, None
+        if went is not None or self.prefillq:
+            if went is not None:
+                tr.switch("rebuild")
+            else:
+                tr.switch("chunk_launch")
+                tick(self.CHUNK)
+                self.chunks += 1
             tick(self.SET_SLOT)
             key = self.prefillq.pop(0)
+            assert went in (None, key)
             self.streams[key] = 1
             self.decoding.append(key)
             self.dirty = True
@@ -138,7 +147,8 @@ class PhasedEngine:
                 read = key
         if self.decoding:
             if self.dirty:
-                tr.switch("rebuild")
+                if went is None:
+                    tr.switch("rebuild")
                 tick(self.REBUILD)
                 self.rebuilds += 1
                 self.dirty = False
@@ -152,6 +162,16 @@ class PhasedEngine:
                 self.reads += 1
                 first.append(self._read(read))
         return first
+
+    def ahead(self):
+        if (not self.in_flight or not self.prefillq or self.blocking
+                or self.went is not None):
+            return
+        self.tracer.switch("chunk_ahead")
+        self.clock.tick(self.CHUNK)
+        self.chunks += 1
+        self.chunks_ahead += 1
+        self.went = self.prefillq[0]
 
     def collect(self):
         if not self.in_flight:
@@ -288,10 +308,12 @@ def test_the_whole_run_is_tiled_each_phase_beginning_where_the_last_ended():
     assert total == pytest.approx((run["clock"]() - run["start"]) * 1e6, abs=1e-3)
 
 
-def test_the_gaps_phases_add_up_to_what_dispatch_gap_us_gained():
+@pytest.mark.parametrize("streams,slots,ahead", [(2, 2, 1), (6, 6, 5)])
+def test_the_gaps_phases_add_up_to_what_dispatch_gap_us_gained(streams, slots, ahead):
     # a stretch in which the engine never idles: every turn observes a gap
-    run = _drive([(100.0, _input("a")), (100.0, _input("b"))],
-                 engine=PhasedEngine(Clock(), cap=40))
+    engine = PhasedEngine(Clock(), slots=slots, cap=40)
+    run = _drive([(100.0, _input(f"r{i}")) for i in range(streams)],
+                 engine=engine, clock=engine.clock)
     samples = run["samples"]
     a, b = samples[0], samples[-2]  # the last collect() left the engine idle
     assert b["gap_count"] - a["gap_count"] == len(samples) - 2 >= 10
@@ -300,8 +322,24 @@ def test_the_gaps_phases_add_up_to_what_dispatch_gap_us_gained():
     assert IN_GAP == ["housekeeping", "admit", "intake", "chunk_launch",
                       "first_token_wait", "rebuild", "window_launch", "emit_alone"]
     # emit_us is observed as before: once a window that ran beside a flush
-    assert run["metrics"].emit.count == run["engine"].windows
-    assert run["metrics"].phases["emit"].count == run["engine"].windows
+    assert run["metrics"].emit.count == engine.windows
+    assert run["metrics"].phases["emit"].count == engine.windows
+    # a chunk that found a window to go behind left the gap: it is
+    # entered under chunk_ahead, outside it, and the two phases' counts
+    # add up to the chunks run
+    phases = run["metrics"].phases
+    assert not LOOP_PHASES["chunk_ahead"] and LOOP_PHASES["chunk_launch"]
+    assert phases["chunk_ahead"].count == engine.chunks_ahead == ahead
+    assert (phases["chunk_launch"].count + phases["chunk_ahead"].count
+            == engine.chunks == streams)
+    assert phases["chunk_ahead"].sum_us == pytest.approx(
+        ahead * engine.CHUNK * 1e6, abs=1e-3)
+    # in line a chunk holds its set-slot; one that went ahead leaves it
+    # to the rebuild of the dispatch that adopts it
+    assert phases["chunk_launch"].sum_us == pytest.approx(
+        (streams - ahead) * (engine.CHUNK + engine.SET_SLOT) * 1e6, abs=1e-3)
+    assert phases["rebuild"].count == engine.rebuilds
+    assert phases["first_token_read"].count == engine.reads == streams
 
 
 def test_a_phase_that_did_not_run_observed_nothing():
@@ -462,7 +500,7 @@ print("ok")
 def test_the_snapshot_has_one_histogram_a_row_and_the_other_planes_name_none():
     snap = ServingMetrics().snapshot()
     keys = [phase_histogram_key(p) for p in LOOP_PHASES]
-    assert len(set(keys)) == len(LOOP_PHASES) == 15
+    assert len(set(keys)) == len(LOOP_PHASES) == 16
     for key in keys:
         assert set(snap[key]) >= {"count", "sum_us", "counts"}
     assert "phase_admit_can_admit_us" in keys and "phase_intake_handle_input_us" in keys
@@ -515,7 +553,8 @@ def test_a_switch_returns_its_one_stamp_and_the_table_is_closed():
     assert tracer._open == []
 
 
-def test_the_real_engine_reports_its_phases_once_an_occurrence():
+@pytest.mark.parametrize("ahead", [False, True])
+def test_the_real_engine_reports_its_phases_once_an_occurrence(ahead):
     from dora_tpu.models.batch_engine import make_stub_paged_engine
 
     engine = make_stub_paged_engine(max_slots=4, window=4, chunk=16, max_seq=64)
@@ -533,10 +572,16 @@ def test_the_real_engine_reports_its_phases_once_an_occurrence():
         rebuilds += (engine._maxnew_dev is not before[0]
                      or engine._bt_dec is not before[1])
         windows += engine.in_flight
+        if ahead:  # where the loop calls it: after its flush
+            engine.ahead()
         engine.collect()
     engine.tracer.close()
     assert engine.chunks_run == 10 and finals == 5 and windows > 5
-    assert phases["chunk_launch"].count == engine.chunks_run
+    # a chunk is launched in the gap or goes ahead, behind a window
+    assert phases["chunk_ahead"].count == engine.chunks_ahead
+    assert engine.chunks_ahead >= 6 if ahead else engine.chunks_ahead == 0
+    assert (phases["chunk_launch"].count + phases["chunk_ahead"].count
+            == engine.chunks_run)
     # every first token was read beside the window its stream joined
     assert phases["first_token_read"].count == finals
     assert phases["first_token_wait"].count == 0

@@ -25,6 +25,9 @@ import torch
 #: monitoring event fires once per backend_compile; registered at import
 #: so warmup compiles are counted too)
 _COMPILE_EVENTS: list[str] = []
+#: every trace of a jitted function and every backend compile, as
+#: ``(kind, fun_name)``: what JAX itself times inside a jit call
+_JIT_EVENTS: list[tuple[str, str]] = []
 
 
 def _register_compile_listener() -> None:
@@ -33,6 +36,9 @@ def _register_compile_listener() -> None:
     def _on_duration(event: str, duration: float, **kwargs) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             _COMPILE_EVENTS.append(event)
+            _JIT_EVENTS.append(("compile", str(kwargs.get("fun_name", ""))))
+        elif event == "/jax/core/compile/jaxpr_trace_duration":
+            _JIT_EVENTS.append(("trace", str(kwargs.get("fun_name", ""))))
 
     monitoring.register_event_duration_secs_listener(_on_duration)
 
@@ -738,3 +744,453 @@ def test_the_window_a_stream_joins_counts_its_first_token_as_emitted():
     row = np.asarray(engine._flight[0])[0]
     assert (row[: engine.window] >= 0).tolist() == [True, False, False, False]
     assert [(r, d) for r, _, d in engine.collect()] == [("two", True)]
+
+
+# -- a chunk ahead: the next period's chunk behind the running window (PR 45) -
+#
+# ``dispatch()`` then ``collect()`` is every chunk in line; ``ahead()``
+# between them (``step()`` calls it, and the serving loop after its
+# flush) hands the next period's chunk to the device early. Same
+# programs in the same order, same tokens.
+
+
+def _go_ahead(engine, first):
+    engine.ahead()
+
+
+def _logged(engine) -> list:
+    """Every program the engine hands the device, in the order it does:
+    a chunk with its base and block-table row, a window with its rows."""
+    log = []
+    chunk, window, dispatch = (
+        engine.chunk_prefill, engine.window_step, engine.dispatch)
+
+    def counted_dispatch():
+        log.append(("dispatch",))
+        return dispatch()
+
+    def chunk_prefill(ids, pools, position, bt, *rest):
+        log.append(("chunk", int(position), np.asarray(bt).tolist()))
+        return chunk(ids, pools, position, bt, *rest)
+
+    def window_step(tokens, pools, positions, bts, active, *rest):
+        log.append(("window", np.asarray(active).tolist()))
+        return window(tokens, pools, positions, bts, active, *rest)
+
+    engine.chunk_prefill, engine.window_step = chunk_prefill, window_step
+    engine.dispatch = counted_dispatch
+    engine._window_cache = {(engine.window, engine.spec_k): window_step}
+    return log
+
+
+@pytest.mark.parametrize("eos", list(_MIXED_AS_BEFORE))
+def test_chunks_ahead_emit_what_chunks_in_line_emit(eos):
+    """Multi-chunk and one-chunk prompts, one-token requests, a twin
+    (a prefix-cache hit), first tokens that are ``eos``: token for token
+    and in the same order, from the same programs in the same order."""
+    import hashlib
+
+    from dora_tpu.metrics import ServingMetrics
+
+    line, went = _stub(eos=eos), _stub(eos=eos)
+    programs = _logged(line), _logged(went)
+    phases = went.tracer.histograms = ServingMetrics().phases
+    out = _mixed_run(went, after=_go_ahead)
+    went.tracer.close()
+    assert out == _mixed_run(line)
+    count, digest = _MIXED_AS_BEFORE[eos]
+    assert len(out) == count
+    assert hashlib.sha256(repr(out).encode()).hexdigest()[:16] == digest
+    assert line.chunks_ahead == 0 and line.chunks_run == went.chunks_run
+    # (b) the device's order is chunk, window, chunk, window as in line,
+    # program for program: never two chunks between two windows
+    assert ([p for p in programs[0] if p[0] != "dispatch"]
+            == [p for p in programs[1] if p[0] != "dispatch"])
+    kinds = "".join(p[0][0] for p in programs[1])
+    assert "wcd" in kinds and "dcw" in kinds  # ahead of, and in, its period
+    for between in kinds.split("w")[1:-1]:
+        # a dispatch brings at most one chunk, whichever side of it
+        assert between.count("c") <= between.count("d"), kinds
+    assert "wcdcw" not in kinds and "wcdw" in kinds
+    # (c) a chunk is launched in the gap or goes ahead, never both
+    assert went.chunks_ahead >= went.chunks_run // 2
+    assert phases["chunk_ahead"].count == went.chunks_ahead
+    assert (phases["chunk_launch"].count + phases["chunk_ahead"].count
+            == went.chunks_run)
+    # every first token behind a window was still read beside it
+    reads, waits = phases["first_token_read"].count, phases["first_token_wait"].count
+    assert reads + waits == len(_MIXED) and reads >= 8
+    went.check_invariants()
+
+
+def test_a_chunk_goes_ahead_only_behind_a_window_and_one_a_period():
+    engine = _stub()
+    engine.submit("p", list(range(40)), 20)  # three chunks, nothing decodes
+    for want in (1, 2):
+        assert engine.dispatch() == [] and not engine.in_flight
+        engine.ahead()  # no window was launched: nothing goes ahead
+        assert engine._ahead is None and engine.chunks_run == want
+        assert engine.collect() == []
+    engine.submit("q", list(range(20)), 4)
+    assert [r for r, _, _ in engine.dispatch()] == ["p"] and engine.in_flight
+    engine.ahead()
+    engine.ahead()  # once a period
+    assert engine.chunks_run == 4 and engine.chunks_ahead == 1
+    s, b, _greedy = engine._ahead
+    assert (s.request_id, s.chunk_base) == ("q", 0)  # the host's side waits
+    engine.collect()
+    # the dispatch that finds it launches none of its own
+    assert engine.dispatch() == [] and engine.chunks_run == 4
+    assert engine._ahead is None and s.chunk_base == 16
+    engine.ahead()  # q's final chunk
+    assert engine.chunks_ahead == 2 and engine._prefillq[0] == b
+    assert s.prompt is not None and s.emitted == 0 and not engine._decode[b]
+    engine.collect()
+    # adopted where an in-line chunk's bookkeeping runs: in dispatch()
+    assert [r for r, _, _ in engine.dispatch()] == ["q"]
+    assert s.prompt is None and s.emitted == 1 and engine._decode[b]
+    assert not engine._prefillq and engine.chunks_run == 5
+    engine.collect()
+    engine.check_invariants()
+
+
+@pytest.mark.parametrize(
+    "between", ["checkpoint", "drain", "preempt", "preempt_refill"])
+def test_what_lands_between_a_final_chunk_ahead_and_its_adoption(between):
+    """A reader of slots between ``collect()`` and the next ``dispatch()``
+    sees the stream whose final chunk went ahead as it would with the
+    chunk still to come: the two engines answer alike and go on alike."""
+    runs = []
+    for go in (False, True):
+        engine = _stub()
+        out = []
+
+        def turn():
+            out.extend(engine.dispatch())
+            if go:
+                engine.ahead()
+            out.extend(engine.collect())
+
+        engine.submit("long", [4, 5], 30)
+        turn()
+        engine.submit("x", list(range(7)), 5)
+        engine.submit("y", list(range(9, 20)), 6)
+        turn()  # x in line; y's one chunk, its final, goes ahead
+        assert (engine._ahead is not None) == go
+        if between == "checkpoint":
+            state = engine.checkpoint_state()
+            out.append(state)
+            y = next(m for m in state["slots"] if m["request_id"] == "y")
+            assert not y["decode"] and y["emitted"] == 0 and y["chunk_base"] == 0
+        elif between == "drain":
+            # migrated out and back in: y is prefilled again from scratch
+            state = engine.drain_streams()
+            out.append(state)
+            assert engine._ahead is None and not engine.active
+            assert sorted(engine.admit_streams(state)) == ["long", "y"]
+        else:
+            out.append(engine.preempt("y"))
+            assert out[-1]["emitted"] == 0 and not out[-1]["was_decoding"]
+            engine.check_invariants()
+            if between == "preempt_refill":
+                # its slot goes to another stream before the next dispatch
+                engine.submit("z", list(range(30, 50)), 4)
+            turn()
+            engine.submit("y", list(range(9, 20)), 6)  # resumed from scratch
+        while engine.active:
+            turn()
+        engine.check_invariants()
+        assert engine.chunks_ahead > 0 if go else engine.chunks_ahead == 0
+        runs.append(out)
+    def by_stream(out):
+        rows = [e for e in out if isinstance(e, tuple)]
+        return {rid: [e[1:] for e in rows if e[0] == rid] for rid, _, _ in rows}
+
+    assert by_stream(runs[0]) == by_stream(runs[1])
+    assert set(by_stream(runs[0])) >= {"long", "x", "y"}
+    if between != "preempt_refill":
+        # there the chunk whose stream went was the period's chunk all
+        # the same, so z's begins a period later than in line
+        assert runs[0] == runs[1]
+
+
+def test_a_slot_freed_after_the_enqueue_leaves_the_chunks_block_table_row():
+    """The chunk's row of the block table is handed over as a copy: on
+    this backend ``jnp.asarray`` of a numpy view may alias it, and
+    freeing the slot zeroes the row in place (debt 20)."""
+    engine = _stub()
+    handed = []
+    chunk = engine.chunk_prefill
+
+    def chunk_prefill(ids, pools, position, bt, *rest):
+        handed.append(bt)
+        return chunk(ids, pools, position, bt, *rest)
+
+    engine.chunk_prefill = chunk_prefill
+    engine.submit("long", [4, 5], 30)
+    engine.step()
+    engine.submit("y", list(range(20)), 6)
+    engine.dispatch()
+    engine.ahead()
+    b = engine._ahead[1]
+    row = engine._bt[b].copy()
+    assert row.any() and np.asarray(handed[-1]).tolist() == row.tolist()
+    engine.collect()
+    assert engine.preempt("y") is not None and not engine._bt[b].any()
+    assert np.asarray(handed[-1]).tolist() == row.tolist()
+    # the in-line chunk's operand too
+    engine.submit("w", list(range(5)), 1)
+    engine.dispatch()  # the chunk that went ahead lost its stream: no chunk
+    engine.collect()
+    n = len(handed)
+    first = engine.dispatch()
+    assert len(handed) == n + 1 and [(r, d) for r, _, d in first] == [("w", True)]
+    assert engine.slots[b] is None or engine.slots[b].request_id != "w"
+    assert np.asarray(handed[-1]).any()
+    engine.collect()
+    engine.check_invariants()
+
+
+def test_a_programs_first_call_and_no_other_runs_in_a_roomy_frame(monkeypatch):
+    """The call that traces, lowers and compiles a chunk or window
+    program goes through ``backend.roomy`` (where the stack below cannot
+    slow it: the start-up PR 45 lost); every later call is direct."""
+    from dora_tpu import backend
+
+    roomy, real = [], backend.roomy
+
+    def counted(program, *operands):
+        roomy.append(program)
+        return real(program, *operands)
+
+    monkeypatch.setattr(backend, "roomy", counted)
+    engine = _stub()
+    out = _mixed_run(engine, after=_go_ahead)
+    assert len(out) == _MIXED_AS_BEFORE[None][0]
+    assert engine.chunks_run > 5 and engine.dispatches > 10
+    assert roomy == [engine.chunk_prefill, engine.window_step]
+    # a window program the autotuner brings is new: its first call too
+    assert engine.set_window(2)
+    engine.submit("again", list(range(5)), 4)
+    while engine.active:
+        engine.step()
+    assert roomy == [engine.chunk_prefill, engine._window_cache[(4, 0)],
+                     engine.window_step]
+
+
+def test_under_speculation_a_final_chunk_never_goes_ahead():
+    """With ``spec_k`` the host needs a first token before the launch it
+    would precede (the history mirror goes into the window's operands):
+    a prompt's other chunks go ahead, its final one is launched in line
+    and read before the window, and the tokens are those of ``step()``."""
+    from dora_tpu.metrics import ServingMetrics
+
+    def serve(halves: bool):
+        engine = _stub(spec_k=2)
+        phases = engine.tracer.histograms = ServingMetrics().phases
+        engine.submit("decodes", [4, 5], 24)
+        out = engine.step()
+        engine.submit("p", list(range(40)), 6)  # three chunks
+        engine.submit("q", list(range(7, 12)), 5)  # one, final
+        finals_ahead = 0
+        while engine.active:
+            if halves:
+                out += engine.dispatch()
+                engine.ahead()
+                if engine._ahead is not None:
+                    s = engine._ahead[0]
+                    finals_ahead += engine._final_chunk(s, s.chunk_base)
+                out += engine.collect()
+            else:
+                out += engine.step()
+            engine.check_invariants()
+        engine.tracer.close()
+        return out, engine, phases, finals_ahead
+
+    want, line, _, _ = serve(False)
+    got, went, phases, finals_ahead = serve(True)
+    assert got == want and line.chunks_ahead == 0
+    assert went.chunks_run == 1 + 3 + 1 and went.chunks_ahead == 1
+    assert finals_ahead == 0
+    # every first token was read before its window's launch, blocking
+    assert phases["first_token_wait"].count == 3
+    assert phases["first_token_read"].count == 0
+    assert phases["chunk_launch"].count + phases["chunk_ahead"].count == 5
+
+
+@pytest.mark.parametrize("ahead", [False, True])
+def test_the_stubs_modelled_period_is_window_plus_chunk_behind_a_chunk_ahead(
+    monkeypatch, ahead
+):
+    """The stub's modelled device is one queue and a wait is for one
+    piece of work: with a prompt queued at every launch and the host
+    busy ``GAP`` between a ``collect()`` and the next ``dispatch()``,
+    the period is window + chunk where the chunk went ahead (the gap
+    runs beside it), window + chunk + gap where it is launched in line.
+    On a clock of its own: no second of the wall's is read."""
+    from types import SimpleNamespace
+
+    from dora_tpu.models import batch_engine
+
+    now = [100.0]
+
+    def sleep(seconds: float) -> None:
+        now[0] += seconds
+
+    monkeypatch.setattr(batch_engine, "time", SimpleNamespace(
+        perf_counter=lambda: now[0], sleep=sleep, monotonic=lambda: now[0]))
+    TICK, CHUNK, GAP, K = 0.010, 0.030, 0.020, 4
+    engine = batch_engine.make_stub_paged_engine(
+        max_slots=3, window=K, chunk=16, max_seq=64,
+        tick_sleep_s=TICK, chunk_sleep_s=CHUNK,
+    )
+    engine.submit("decodes", [4, 5], 60)
+    engine.step()
+    ends = []
+    for turn in range(12):
+        if engine.can_admit(20, 2):
+            # two chunks a prompt: the prefill queue is never empty
+            engine.submit(f"p{turn}", list(range(20)), 2)
+        engine.dispatch()
+        if ahead:
+            engine.ahead()
+        engine.collect()
+        ends.append(now[0])
+        sleep(GAP)  # the host between collect() and the next dispatch()
+    periods = [round(b - a, 6) for a, b in zip(ends[2:], ends[3:])]
+    want = K * TICK + CHUNK + (0.0 if ahead else GAP)
+    assert periods == [pytest.approx(want)] * len(periods), periods
+    assert (engine.chunks_ahead > 0) == ahead
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_chunks_ahead_give_the_serial_streams_on_a_model_that_reads_its_cache(
+    quantized, serial_ref, prefix_cache
+):
+    """The real chunk and window programs (tiny Qwen2): five- and
+    two-chunk prompts whose chunks go ahead of their periods behind the
+    windows of streams that decode, then attend what those chunks wrote;
+    the tokens are those of ``step()`` and of the serial reference. With
+    the prefix cache on, a twin of the five-chunk prompt maps the pages
+    a chunk that went ahead wrote and its stream's adoption inserted."""
+    from dora_tpu.models.hf import qwen2
+
+    cfg, qparams = quantized
+    rng = np.random.default_rng(11)
+    plens = (3, 37, 12, 6, 21)
+    prompts = [rng.integers(0, cfg.vocab, size=n).tolist() for n in plens]
+    prompts.append(list(prompts[1]))  # the twin, submitted once r1 decodes
+
+    def serve(halves: bool):
+        paged = qwen2.make_paged_engine(
+            qparams, cfg, max_slots=5, page_size=8, chunk=8, window=4,
+            prefix_cache=prefix_cache,
+        )
+        streams: dict[str, list[int]] = {f"r{i}": [] for i in range(len(prompts))}
+        for i, prompt in enumerate(prompts[:-1]):
+            paged.submit(f"r{i}", prompt, 10)
+        twin = False
+        for _ in range(300):
+            if twin and not paged.active:
+                break
+            if halves:
+                first = paged.dispatch()
+                paged.ahead()
+                _drain(streams, first + paged.collect())
+            else:
+                _drain(streams, paged.step())
+            if not twin and streams["r1"] and paged.can_admit(37, 10):
+                paged.submit("r5", prompts[-1], 10)
+                twin = True
+        assert twin and paged.active == 0
+        paged.check_invariants()
+        return streams, paged
+
+    want, line = serve(False)
+    got, went = serve(True)
+    assert got == want and line.chunks_ahead == 0
+    # the twin re-prefills five chunks, or the one its cached pages leave
+    assert went.chunks_run == line.chunks_run == 12 + (1 if prefix_cache else 5)
+    assert went.chunks_ahead >= 8  # all but those no window ran before
+    for i, prompt in enumerate(prompts):
+        assert got[f"r{i}"] == serial_ref(prompt, 10), f"r{i}"
+    if prefix_cache:
+        assert went.prefix_cache.hits == line.prefix_cache.hits == 1
+    else:
+        assert went.free_pages == went.allocator.num_pages - 1
+
+
+def test_a_warm_wave_traces_and_compiles_both_programs_in_its_first_dispatch(
+    quantized,
+):
+    """The guard for what PR 45 was refused for (a start-up that grew):
+    a warm wave through the serving loop — one request, then fifteen
+    behind it, ``dispatch → emit → ahead → collect`` — traces and
+    compiles the chunk program once and the window program once, both
+    inside the first ``dispatch()``; no later ``dispatch()``, ``ahead()``
+    or ``collect()`` traces or compiles anything. Counts, not seconds."""
+    from dora_tpu.metrics import ServingMetrics
+    from dora_tpu.models.hf import qwen2
+    from dora_tpu.nodehub.llm_server import AdmissionQueue, _run_loop
+
+    cfg, qparams = quantized
+    # shapes no other test of this module compiles: this engine's
+    # programs are traced and compiled here, whatever ran before
+    engine = qwen2.make_paged_engine(
+        qparams, cfg, max_slots=16, page_size=8, chunk=32, window=3
+    )
+    rng = np.random.default_rng(3)
+    prompts = {f"w{i}": rng.integers(0, cfg.vocab, size=20).tolist()
+               for i in range(16)}
+    calls: list[tuple[str, list]] = []
+
+    def counted(name):
+        call = getattr(engine, name)
+
+        def run():
+            before = len(_JIT_EVENTS)
+            out = call()
+            calls.append((name, _JIT_EVENTS[before:]))
+            return out
+
+        setattr(engine, name, run)
+
+    for name in ("dispatch", "ahead", "collect"):
+        counted(name)
+    sent: list[str] = []
+
+    class Node:
+        stream_ended = False
+        script = list(prompts)
+
+        def recv(self, timeout=None):
+            # one request; the other fifteen once its first token left
+            if self.script and (len(self.script) == 16 or sent):
+                return {"type": "INPUT", "rid": self.script.pop(0)}
+            self.stream_ended = not self.script
+            return None
+
+    metrics = ServingMetrics(engine="paged")
+    backlog = AdmissionQueue(
+        engine, lambda k, ids, mn, adapter: engine.submit(k, ids, mn)
+    )
+    _run_loop(
+        Node(), engine, backlog, metrics,
+        lambda event: backlog.push(event["rid"], prompts[event["rid"]],
+                                   2 * (1 + int(event["rid"][1:]))),
+        lambda key, tokens, done: sent.append(key),
+        lambda now: None,
+    )
+    assert engine.chunks_run == 16 and engine.chunks_ahead >= 6
+    assert [name for name, _ in calls[:3]] == ["dispatch", "ahead", "collect"]
+    first = calls[0][1]
+    chunk_name = engine.chunk_prefill.func.__name__
+    window_name = engine.window_step.func.__name__
+    for program in (chunk_name, window_name):
+        assert first.count(("trace", program)) == 1, (program, first)
+        assert sum(kind == "compile" and program in fun
+                   for kind, fun in first) == 1, (program, first)
+    later = [(name, events) for name, events in calls[1:] if events]
+    assert later == [], later[:3]
+    assert engine.chunk_prefill.func._cache_size() == 1
+    assert engine.window_step.func._cache_size() == 1
