@@ -376,7 +376,7 @@ class ServingMetrics:
         #: the model module's own counters, as the engine's
         #: ``model_counters()`` returns them at the 1 Hz report (a dict,
         #: merged into the snapshot and into the node's exit line; empty
-        #: for models that have none). models/hf/kimi_k2.MoeCounters:
+        #: for models that have none). models/moe.report, as kimi_k2 adds to it:
         #: ``moe_tokens`` — rows routed, summed over expert layers (a
         #: chunk's right padding and frozen decode rows are not
         #: counted); ``moe_local_pairs`` — (row, expert) pairs that

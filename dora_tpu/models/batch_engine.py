@@ -2,7 +2,8 @@
 
 Batch-1 decode is HBM-bandwidth-bound: every token pays the full LM
 weight stream. The paged kernels (ops.decode_block.
-attention_paged_batch_step and the window programs of models/vlm.py)
+attention_paged_batch_step under the window programs of
+models/paged_window.py)
 run B independent sequences off ONE weight stream, so B concurrent
 chats decode at nearly the cost of one.
 
@@ -29,6 +30,12 @@ from collections import deque
 from dataclasses import dataclass, field
 
 from dora_tpu import backend, profiling, telemetry
+from dora_tpu.models.paged_window import (
+    make_paged_spec_window,
+    make_paged_window,
+    spec_window_row_stats,
+    window_row_stats,
+)
 
 
 class PageAllocator:
@@ -234,8 +241,8 @@ class PagedBatchEngine:
     prefill compiles exactly one XLA program ever.
 
     Decode runs at WINDOW granularity: each :meth:`step` launches ONE
-    fused K-tick program (``window_step``, models/vlm.make_paged_window
-    with ``k = window``) that detects per-stream completion on device
+    fused K-tick program (``window_step``,
+    models/paged_window.make_paged_window with ``k = window``) that detects per-stream completion on device
     and freezes finished rows mid-window, then fetches one [B, K+1]
     token matrix — host dispatch and device->host fetch cost amortize
     over K emitted tokens instead of being paid per token. The host
@@ -268,8 +275,8 @@ class PagedBatchEngine:
     page; a model may give only some of its layers leaves there). With
     it, ``chunk_prefill`` takes two more trailing operands, the slot
     index and the state, and ``window_step`` one, the state
-    (models/vlm.make_paged_window, ``slot_state=True``); both return the
-    state last, updated in place. The programs keep it right and the
+    (models/paged_window.make_paged_window, ``slot_state=True``); both
+    return the state last, updated in place. The programs keep it right and the
     host makes no reset call: a recurrent state starts from zeros in a
     chunk at position 0; a ring needs no zero-start at all (a row counts
     by the position it holds, and what an earlier stream left is masked
@@ -283,7 +290,7 @@ class PagedBatchEngine:
     :meth:`admit_streams` of a stream in mid-decode.
 
     With ``spec_k > 0`` (prompt-lookup speculation,
-    models/vlm.make_paged_spec_window) the window signature instead
+    models/paged_window.make_paged_spec_window) the window signature instead
     takes and returns two extra per-stream device buffers —
     ``history [B, hist_buf]`` and ``hist_len [B]`` — and ``mat`` is the
     ragged ``[B, K*(spec_k+1) + 1]`` emission matrix; each dispatch can
@@ -1322,10 +1329,6 @@ class PagedBatchEngine:
             # Span per decoding stream BEFORE the unpack loop frees
             # finished slots; all rows share the window's host span
             # (one dispatch serves them all).
-            from dora_tpu.models.vlm import (
-                spec_window_row_stats, window_row_stats,
-            )
-
             win_ns = int((t_done - t_win) * 1e9)
             for b, slot in enumerate(self.slots):
                 if slot is None or not self._decode[b]:
@@ -1713,8 +1716,8 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
                            peak_flops: float = 1e12,
                            lora_max_resident: int = 0):
     """A weight-free :class:`PagedBatchEngine` over the REAL window
-    machinery: the decode window is ``vlm.make_paged_window`` (the same
-    ``lax.scan`` + ``freeze_inactive`` program serving runs) with the
+    machinery: the decode window is ``paged_window.make_paged_window``
+    (the same ``lax.scan`` + ``freeze_inactive`` program serving runs) with the
     model's batched step replaced by the affine token rule
     ``next = (7*t + 3) % vocab``, applied identically by the chunk-
     prefill stub — so token streams are deterministic, cheap to compile
@@ -1734,8 +1737,8 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
     beside the window, as on the chip (the TTFT regression test needs
     windows that measurably take K ticks).
 
-    ``spec_k > 0`` swaps in ``vlm.make_paged_spec_window`` (prompt-
-    lookup speculation, the production serving path's window) with the
+    ``spec_k > 0`` swaps in ``paged_window.make_paged_spec_window``
+    (prompt-lookup speculation, the production serving path's window) with the
     stub rule doubling as the verifier: the rule is memoryless, so
     verifying candidate ``c`` is just ``rule(c)``, and emitted streams
     stay identical to the spec-off stub at every (K, k). ``cycle``
@@ -1758,8 +1761,6 @@ def make_stub_paged_engine(*, max_slots: int = 4, max_seq: int = 64,
     --lora-ab legs drive churn/eviction through it engine-free."""
     import jax
     import jax.numpy as jnp
-
-    from dora_tpu.models.vlm import make_paged_spec_window, make_paged_window
 
     if num_pages is None:
         num_pages = max_slots * (max_seq // page_size) + 1

@@ -18,11 +18,10 @@ mathematics, whole sequence, is ``exaone_moe_reference.py``, where each
     x = x + softmax(q k^T / sqrt(hd)) v Wo
     x = x + MLP(RMSNorm(x; post_attention_layernorm))    dense, or the expert layer
 
-The expert layer is ``kimi_k2``'s letter for letter (``route``,
-``held_experts``, ``mlp``, ``swiglu``, ``expert_share`` are imported
-from there): the router keeps its published width, this rank computes
-what its own ``experts_held`` experts give, nothing stands in for the
-absent ranks.
+The expert layer is Kimi-K2's letter for letter (``models/moe.py``:
+``route``, ``held_experts``, ``mlp``, ``swiglu``, ``expert_share``): the
+router keeps its published width, this rank computes what its own
+``experts_held`` experts give, nothing stands in for the absent ranks.
 
 What this module adds to the serving path: **layers that differ in
 cache kind by layer type.**
@@ -60,20 +59,18 @@ ignored with its weights unread).
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from dora_tpu import profiling
 from dora_tpu.models import layers as L
-from dora_tpu.models.hf import kimi_k2 as K
-# rotate-half rotary over the whole head; K and V of a position as one cached
-# row; ``layers.attend_blocks`` over such rows a block at a time
-from dora_tpu.models.hf.falcon_h1 import _attend, _kv_rows, rotate
+from dora_tpu.models import moe
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops import decode_block as DB
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
 
@@ -83,8 +80,6 @@ MODEL_TYPES = ("exaone_moe",)
 #: the page): its pool is read this many positions at a time, up to the
 #: chunk's last position.
 ATTN_BLOCK = 256
-#: device memory the default pool leaves to the programs' temporaries
-POOL_HEADROOM_BYTES = 4 << 30
 
 #: serving knobs of the Qwen path that this model refuses (KNOWN_ISSUES.md)
 NOT_OFFERED = {
@@ -214,7 +209,7 @@ class ExaoneMoeConfig:
             raise NotImplementedError(
                 f"exaone_moe: scaled rotary "
                 f"{config.get('rope_scaling') or rope!r} is not written")
-        first, held = K.expert_share(
+        first, held = moe.expert_share(
             {"n_routed_experts": config["num_experts"],
              "ep_size": config.get("ep_size")}, ep_rank)
         return cls(
@@ -250,14 +245,6 @@ class ExaoneMoeConfig:
 # ---------------------------------------------------------------------------
 
 
-def _swiglu(get, prefix: str) -> dict:
-    return {
-        "w_gateup": _quantize_t(
-            get(prefix + "gate_proj.weight"), get(prefix + "up_proj.weight")),
-        "w_down": _quantize_t(get(prefix + "down_proj.weight")),
-    }
-
-
 def load_layer(get, cfg: ExaoneMoeConfig, i: int, prefix: str = "model.") -> dict:
     """Layer ``i``'s serving parameters from ``get(name) -> device
     array`` under the HF tensor names (EXAONE-4's, with DeepSeek-V3's for
@@ -274,18 +261,9 @@ def load_layer(get, cfg: ExaoneMoeConfig, i: int, prefix: str = "model.") -> dic
         "ffn_norm": get(lp + "post_attention_layernorm.weight"),
     }
     if not cfg.sparse[i]:
-        block["dense"] = _swiglu(get, m)
+        block["dense"] = moe.swiglu_weights(get, m)
         return block
-    block["router"] = get(m + "gate.weight").T.astype(L.compute_dtype())
-    block["router_bias"] = get(m + "gate.e_score_correction_bias").astype(
-        jnp.float32)
-    if cfg.n_shared:
-        block["shared"] = _swiglu(get, m + "shared_experts.")
-    block["experts"] = [
-        _swiglu(get, f"{m}experts.{e}.")
-        for e in range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
-    ]
-    return block
+    return {**block, **moe.expert_layer_weights(get, cfg, m)}
 
 
 def load(model_dir: str | Path, max_seq: int | None = None,
@@ -339,8 +317,8 @@ def _qkv(blk, cfg: ExaoneMoeConfig, u, rope):
         k = L.rms_norm(k, blk["k_norm"], cfg.norm_eps)
     if rope is not None:
         cos, sin = rope
-        q = rotate(q, cos[:, None], sin[:, None])
-        k = rotate(k, cos[:, None], sin[:, None])
+        q = L.rotate_half(q, cos[:, None], sin[:, None])
+        k = L.rotate_half(k, cos[:, None], sin[:, None])
     return q.reshape(n, kv, cfg.heads // kv, hd), k, v
 
 
@@ -376,7 +354,7 @@ def window_decode(blk, cfg: ExaoneMoeConfig, u, ring, positions, active, rope):
         b, w = u.shape[0], cfg.window
         q, k, v = _qkv(blk, cfg, u, rope)
         rows, at = jnp.arange(b), positions % w
-        new = _kv_rows(cfg, k, v).astype(ring.dtype)
+        new = L.kv_rows(cfg, k, v).astype(ring.dtype)
         ring = ring.at[rows, at].set(
             jnp.where(active[:, None], new, ring[rows, at]))
         keys, values = _split_rows(cfg, ring)  # [B, W, KV, hd]
@@ -406,7 +384,7 @@ def window_chunk(blk, cfg: ExaoneMoeConfig, u, ring, slot, position, valid,
         # the position ring row j holds once position - 1 was written
         last = position - 1
         held = last - (last - j) % w  # < 0: not this stream's
-        new = _kv_rows(cfg, k, v).astype(ring.dtype)
+        new = L.kv_rows(cfg, k, v).astype(ring.dtype)
         keys, values = _split_rows(cfg, jnp.concatenate([mine, new], 0))
         key_pos = jnp.concatenate([held, position + jnp.arange(c)])
         q_pos = position + jnp.arange(c)
@@ -439,7 +417,7 @@ def global_decode(blk, cfg: ExaoneMoeConfig, u, pool, positions, block_tables,
         q, k, v = _qkv(blk, cfg, u, None)
         pool = pool.at[
             block_tables[jnp.arange(b), positions // page], positions % page
-        ].set(_kv_rows(cfg, k, v).astype(pool.dtype))
+        ].set(L.kv_rows(cfg, k, v).astype(pool.dtype))
         ctx = DB.attention_paged_rows_step(q, pool, counts, block_tables)
         return _out(blk, cfg, ctx, u.dtype), pool
 
@@ -455,7 +433,7 @@ def global_chunk(blk, cfg: ExaoneMoeConfig, u, pool, position, block_table,
         ids = jax.lax.dynamic_slice_in_dim(block_table, position // page,
                                            c // page)
         pool = pool.at[ids].set(
-            _kv_rows(cfg, k, v).astype(pool.dtype).reshape(
+            L.kv_rows(cfg, k, v).astype(pool.dtype).reshape(
                 c // page, page, 2 * cfg.kv_width))
         per = block // page
         q_pos = position + jnp.arange(c)
@@ -468,8 +446,9 @@ def global_chunk(blk, cfg: ExaoneMoeConfig, u, pool, position, block_table,
             t = j * block + jnp.arange(block)
             return (t[None, :] <= q_pos[:, None])[:, None, None, :]
 
-        ctx = _attend(cfg, q, kv_of, visible, (position + c - 1) // block + 1,
-                      "qkgd,tkd->qkgt", "qkgt,tkd->qkgd")
+        ctx = L.attend_kv_blocks(
+            cfg, q, kv_of, visible, (position + c - 1) // block + 1,
+            "qkgd,tkd->qkgt", "qkgt,tkd->qkgd")
         return _out(blk, cfg, ctx, u.dtype), pool
 
 
@@ -481,10 +460,10 @@ def global_chunk(blk, cfg: ExaoneMoeConfig, u, pool, position, block_table,
 def init_counters(cfg: ExaoneMoeConfig) -> dict:
     """The counters on the device, an operand and a result of their own
     of both programs (a buffer each: donated one by one), int32 that
-    wraps: ``moe`` are ``kimi_k2``'s routing counters under its names,
-    ``swa`` this module's (:data:`SWA_COUNTERS`)."""
+    wraps: ``moe`` are the expert layer's routing counters
+    (``moe.init_counters``), ``swa`` this module's (:data:`SWA_COUNTERS`)."""
     return {
-        "moe": K.init_counters(cfg),
+        "moe": moe.init_counters(cfg),
         "swa": {name: jnp.zeros((), jnp.int32) for name in SWA_COUNTERS},
     }
 
@@ -493,10 +472,10 @@ def _layers(params, cfg: ExaoneMoeConfig, x, pools, state, stats, window,
             attend, live, counted, decode: bool):
     """The stack: ``window(blk, normed rows, ring) -> (out, ring)`` for a
     sliding layer, ``attend(blk, normed rows, pool) -> (out, pool)`` for
-    a global one, then ``kimi_k2.mlp``. Returns (rows, pools, state,
-    the routing counters)."""
+    a global one, then ``moe.mlp``. Returns (rows, pools, state, the
+    routing counters)."""
     pools, state = dict(pools), dict(state)
-    moe = dict(stats)
+    routed = dict(stats)
     per_layer = []
     for i in range(cfg.layers):
         blk, key = params["blocks"][str(i)], str(i)
@@ -508,33 +487,18 @@ def _layers(params, cfg: ExaoneMoeConfig, x, pools, state, stats, window,
             a, kv = attend(blk, u, pools[key]["kv"])
             pools[key] = {"kv": kv}
         x = x + a.astype(x.dtype)
-        y, counters = K.mlp(
+        y, counters = moe.mlp(
             blk, cfg, L.rms_norm(x, blk["ffn_norm"], cfg.norm_eps), live,
             counted)
         x = x + y
-        if counters is not None:
-            tokens, pairs, per_expert = counters
-            moe["tokens"] = moe["tokens"] + tokens
-            moe["local_pairs"] = moe["local_pairs"] + pairs
-            per_layer.append(per_expert)
-            if decode:
-                moe["touched"] = moe["touched"] + (per_expert > 0).sum(
-                    dtype=jnp.int32)
-    if per_layer:
-        moe["expert_tokens"] = moe["expert_tokens"] + jnp.stack(per_layer)
-        if decode:
-            moe["decode_ticks"] = moe["decode_ticks"] + counted.any().astype(
-                jnp.int32)
-    return x, pools, state, moe
+        moe.add_layer(routed, per_layer, counters, decode)
+    moe.add_stack(routed, per_layer, counted, decode)
+    return x, pools, state, routed
 
 
 def _rope_rows(cfg: ExaoneMoeConfig, positions):
     cos, sin = L.rope_table(cfg.max_seq, cfg.head_dim, base=cfg.rope_theta)
     return cos[positions], sin[positions]
-
-
-def _add(stats: dict, **adds) -> dict:
-    return {k: v + adds.get(k, 0) for k, v in stats.items()}
 
 
 def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
@@ -555,8 +519,9 @@ def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
     def attend(blk, u, pool):
         return global_decode(blk, cfg, u, pool, positions, block_tables, seen)
 
-    x, pools, state, moe = _layers(params, cfg, x, pools, state, stats["moe"],
-                                   window, attend, active, active, True)
+    x, pools, state, routed = _layers(
+        params, cfg, x, pools, state, stats["moe"], window, attend, active,
+        active, True)
     i32 = jnp.int32
     live = active.sum(dtype=i32)
     # the (row, group) steps one global layer's sweep holds: a group is
@@ -564,7 +529,7 @@ def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
     group = DB.sweep_group_rows(
         next(iter(pools.values()))["kv"].shape[1], block_tables.shape[1])
     groups = ((seen + group - 1) // group).sum(dtype=i32)
-    swa = _add(
+    swa = PM.add_counts(
         stats["swa"],
         swa_decode_ticks=(live > 0).astype(i32), swa_row_ticks=live,
         swa_ring_rows_read=len(cfg.window_layers) * jnp.minimum(
@@ -573,7 +538,7 @@ def paged_batch_rows(params, cfg: ExaoneMoeConfig, tokens, pools, state, stats,
         global_kv_rows_swept=len(cfg.global_layers) * group * groups,
         global_sweep_groups=groups,
     )
-    return x, pools, state, {"moe": moe, "swa": swa}
+    return x, pools, state, {"moe": routed, "swa": swa}
 
 
 def paged_chunk_rows(params, cfg: ExaoneMoeConfig, chunk_ids, pools, state,
@@ -595,63 +560,33 @@ def paged_chunk_rows(params, cfg: ExaoneMoeConfig, chunk_ids, pools, state,
     def attend(blk, u, pool):
         return global_chunk(blk, cfg, u, pool, position, block_table, block)
 
-    x, pools, state, moe = _layers(
+    x, pools, state, routed = _layers(
         params, cfg, x, pools, state, stats["moe"], window, attend,
         jnp.ones((c,), bool), counted, False)
     i32 = jnp.int32
-    swa = _add(stats["swa"], swa_chunks=jnp.ones((), i32),
-               swa_chunk_rows=valid.astype(i32),
-               swa_chunk_positions=position.astype(i32))
-    return x, pools, state, {"moe": moe, "swa": swa}
+    swa = PM.add_counts(
+        stats["swa"], swa_chunks=jnp.ones((), i32),
+        swa_chunk_rows=valid.astype(i32),
+        swa_chunk_positions=position.astype(i32))
+    return x, pools, state, {"moe": routed, "swa": swa}
 
 
-def head_logits(params, cfg: ExaoneMoeConfig, x):
-    h = L.rms_norm(x, params["out_norm"], cfg.norm_eps)
-    return L.matmul(h, params["lm_head"]).astype(jnp.float32)
-
-
-def head_argmax(params, cfg: ExaoneMoeConfig, x):
-    from dora_tpu.ops import decode_block as DB
-
-    w = params["lm_head"]
-    return DB.lm_head_argmax(x, params["out_norm"], w["int8"], w["scale"],
-                             eps=cfg.norm_eps)
-
-
-def paged_batch_logits(params, cfg, *args, **kw):
-    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
-    return head_logits(params, cfg, x), *rest
-
-
-def paged_chunk_logits(params, cfg, *args, **kw):
-    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
-    return head_logits(params, cfg, x), *rest
-
-
-def fused_paged_batch_step(params, cfg, *args, **kw):
-    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
-    return head_argmax(params, cfg, x), *rest
-
-
-def fused_paged_chunk_step(params, cfg, *args, **kw):
-    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
-    return head_argmax(params, cfg, x), *rest
+paged_batch_logits, fused_paged_batch_step = PM.under_the_head(paged_batch_rows)
+paged_chunk_logits, fused_paged_chunk_step = PM.under_the_head(paged_chunk_rows)
 
 
 def window_program(params, cfg, k: int, eos, tokens, pools, stats,
                    positions, bts, active, emitted, max_new, state):
-    """The K-tick decode window (models/vlm.make_paged_window with a
+    """The K-tick decode window (models/paged_window.make_paged_window with a
     slot state) over :func:`fused_paged_batch_step`: the counters ride
     the window's carry beside the rings and come back apart. Returns
     (the window's own results — pools, then state, last — and stats)."""
-    from dora_tpu.models import vlm as _vlm
-
     def batch(tokens, pools, positions, bts, active, carried):
         nxt, pools, state, stats = fused_paged_batch_step(
             params, cfg, tokens, pools, *carried, positions, bts, active)
         return nxt, pools, (state, stats)
 
-    *out, (state, stats) = _vlm.make_paged_window(
+    *out, (state, stats) = make_paged_window(
         batch, k=k, eos=eos, slot_state=True)(
         tokens, pools, positions, bts, active, emitted, max_new,
         (state, stats))
@@ -688,83 +623,31 @@ def page_pool_bytes(cfg: ExaoneMoeConfig, page_size: int) -> int:
     return page_size * cfg.kv_bytes_per_token
 
 
-def pages_that_fit(cfg: ExaoneMoeConfig, limit: int, used: int,
-                   max_slots: int, page_size: int) -> int:
-    """The rule of :func:`default_num_pages`, in plain numbers."""
-    fits = (limit - used - POOL_HEADROOM_BYTES) // page_pool_bytes(
-        cfg, page_size)
-    return int(max(min(max_slots * cfg.max_seq // page_size + 1, fits),
-                   2 * cfg.max_seq // page_size))
-
-
 def default_num_pages(cfg: ExaoneMoeConfig, max_slots: int,
                       page_size: int) -> int:
-    """The pool's default size, a rule in bytes as ``ouro``'s: every
-    slot may reach ``max_seq``, capped by what the device has
-    (``bytes_limit``) less what is in use now (the weights) less
-    :data:`POOL_HEADROOM_BYTES`; never fewer than two streams' worth. At
-    K-EXAONE's cut on a 16 GB v5e the cap does not bind: 16 x 16,384 rows
-    x 8,192 B = 2.15 GB. Where the device reports no memory figures (the
-    CPU) ``4 * max_seq`` rows."""
-    stats = jax.devices()[0].memory_stats() or {}
-    limit, used = stats.get("bytes_limit"), stats.get("bytes_in_use")
-    if not limit or used is None:
-        return 4 * cfg.max_seq // page_size
-    return pages_that_fit(cfg, limit, used, max_slots, page_size)
+    """The pool's default size, ``paged_model.default_num_pages``' rule in
+    bytes. At K-EXAONE's cut on a 16 GB v5e the cap does not bind: 16 x
+    16,384 rows x 8,192 B = 2.15 GB, every slot may reach ``max_seq``."""
+    return PM.default_num_pages(
+        page_pool_bytes(cfg, page_size), max_slots, cfg.max_seq, page_size)
 
 
-class SwaMoeCounters:
-    """The counters of one engine: the device arrays the two programs
-    take and give back (``device``) and their host side, which adds up
-    the int32 differences. :meth:`read` fetches a few hundred bytes;
-    ``llm_server``'s 1 Hz report calls it at a window boundary, after
-    ``collect()``. The routing counters come out under ``kimi_k2``'s
-    names (one reader serves both configurations)."""
-
-    def __init__(self, cfg: ExaoneMoeConfig, page_size: int):
-        self.device = init_counters(cfg)
-        #: set by :func:`make_paged_engine`: whose pages ``read`` counts
-        self.engine = None
-        self._cfg = cfg
-        self._page_bytes = page_pool_bytes(cfg, page_size)
-        self._last = None
-        self._expert_tokens = [0] * cfg.experts_held
-        self.totals = dict.fromkeys(
-            ("tokens", "local_pairs", "decode_ticks", "touched")
-            + SWA_COUNTERS, 0)
-
-    def read(self) -> dict:
-        import numpy as np
-
-        now = jax.tree.map(lambda v: np.asarray(v).astype(np.int64),
-                           self.device)
-        last = self._last or jax.tree.map(np.zeros_like, now)
-        self._last = now
-        gained = jax.tree.map(lambda a, b: (a - b) & 0xFFFFFFFF, now, last)
-        t = self.totals
-        for group in ("moe", "swa"):
-            for name, d in gained[group].items():
-                if name != "expert_tokens":
-                    t[name] += int(d)
-        self._expert_tokens = [
-            a + int(b) for a, b in zip(
-                self._expert_tokens, gained["moe"]["expert_tokens"].sum(0))]
-        ticks = t["decode_ticks"] * max(self._cfg.moe_layers, 1)
-        engine = self.engine
-        return {
-            "moe_tokens": t["tokens"],
-            "moe_local_pairs": t["local_pairs"],
-            "moe_expert_tokens": list(self._expert_tokens),
-            "moe_experts_touched": (
-                round(t["touched"] / ticks, 4) if ticks else None),
-            # raw, for a reader that takes it over a capture's ticks
-            "moe_touched": t["touched"],
-            **{name: t[name] for name in SWA_COUNTERS},
-            "kv_bytes_per_token": self._cfg.kv_bytes_per_token,
-            "kv_pool_bytes": engine.allocator.num_pages * self._page_bytes,
-            "kv_pages_free": engine.allocator.free_pages,
-            "swa_ring_bytes": self._cfg.ring_bytes_per_slot * engine.max_slots,
-        }
+def report(cfg: ExaoneMoeConfig, page_size: int, totals: dict, engine) -> dict:
+    """The gauges of one engine (``paged_model.build_engine``'s
+    ``report``): the routing counters under the names every expert-layer
+    model gives them (``moe.report``), this module's own, the pool and
+    the rings."""
+    return {
+        **moe.report(totals["moe"], cfg.moe_layers),
+        # raw, for a reader that takes it over a capture's ticks
+        "moe_touched": int(totals["moe"]["touched"]),
+        **{name: int(totals["swa"][name]) for name in SWA_COUNTERS},
+        "kv_bytes_per_token": cfg.kv_bytes_per_token,
+        "kv_pool_bytes": engine.allocator.num_pages * page_pool_bytes(
+            cfg, page_size),
+        "kv_pages_free": engine.allocator.free_pages,
+        "swa_ring_bytes": cfg.ring_bytes_per_slot * engine.max_slots,
+    }
 
 
 def flops_per_token(cfg: ExaoneMoeConfig) -> float:
@@ -792,18 +675,14 @@ def make_paged_engine(params, cfg: ExaoneMoeConfig, *, max_slots: int = 16,
     """The paged continuous-batching engine
     (models/batch_engine.PagedBatchEngine) with the window layers' rings
     as its slot state and pages for the global layers alone: the same
-    scheduler, allocator and K-tick window as the other families.
-    ``num_pages`` defaults to :func:`default_num_pages`. **No prefix
-    cache, whatever is asked**: a granted prefix would need the window
-    layers' last ``sliding_window`` rows at its end, and none are kept
-    at a page boundary. Speculation, LoRA and int8 pages are not offered
-    (KNOWN_ISSUES.md, PR 41)."""
-    from dora_tpu.models.batch_engine import PagedBatchEngine
-
-    for knob, why in NOT_OFFERED.items():
-        if os.environ.get(knob, "0") not in ("", "0"):
-            raise NotImplementedError(
-                f"exaone_moe: {knob} is not offered: {why}")
+    scheduler, allocator and K-tick window as the other families
+    (``paged_model.build_engine``; the pools, the counters and the rings
+    are arguments 2, 3 and 9 of the window and 2, 3 and 6 of the chunk,
+    hence the donation). ``num_pages`` defaults to
+    :func:`default_num_pages`. **No prefix cache, whatever is asked**: a
+    granted prefix would need the window layers' last ``sliding_window``
+    rows at its end, and none are kept at a page boundary. Speculation,
+    LoRA and int8 pages are not offered (KNOWN_ISSUES.md, PR 41)."""
     if cfg.window % page_size:
         raise NotImplementedError(
             f"exaone_moe: sliding_window {cfg.window} is no multiple of the "
@@ -813,70 +692,26 @@ def make_paged_engine(params, cfg: ExaoneMoeConfig, *, max_slots: int = 16,
             "exaone_moe: the prefix cache is off for this model: a granted "
             "prefix needs the window layers' rows at its end, and none are "
             "kept")
-    chunk = chunk or min(256, cfg.max_seq)
-    if attn_block is None:
-        attn_block = ATTN_BLOCK if cfg.max_seq % ATTN_BLOCK == 0 else chunk
-    assert attn_block % page_size == 0 and cfg.max_seq % attn_block == 0, (
-        attn_block, page_size, cfg.max_seq,
-    )
+    chunk = PM.default_chunk(chunk, cfg.max_seq)
+    attn_block = PM.default_attn_block(attn_block, ATTN_BLOCK, chunk,
+                                       cfg.max_seq, page_size)
     if num_pages is None:
         num_pages = default_num_pages(cfg, max_slots, page_size)
-    if window is None:
-        window = int(os.environ.get("DORA_MULTISTEP_K", "8"))
-
-    counters = SwaMoeCounters(cfg, page_size)
-
-    # params ride as an argument, never a closed-over constant (see
-    # qwen2.make_paged_engine); the pools, the counters and the rings are
-    # arguments 2, 3 and 9 (6 of the chunk), hence the donation. The
-    # engine sees pools and rings; the counters stay here.
-    def window_factory(k, sk):
-        assert not sk, "exaone_moe: no speculative window"
-
-        def program(p, *args):
-            return window_program(p, cfg, k, eos, *args)
-
-        jitted = jax.jit(program, donate_argnums=(2, 3, 9))
-
-        def window_step(tokens, pools, positions, bts, active, emitted,
-                        max_new, state):
-            out, counters.device = jitted(
-                params, tokens, pools, counters.device, positions, bts,
-                active, emitted, max_new, state)
-            return out
-
-        return window_step
 
     def step(p, ids, pools, stats, position, bt, state, valid, slot):
         return fused_paged_chunk_step(p, cfg, ids, pools, state, stats,
                                       position, bt, valid, slot,
                                       block=attn_block)
 
-    chunk_jitted = jax.jit(step, donate_argnums=(2, 3, 6))
-
-    def chunk_prefill(ids, pools, position, bt, valid, slot, state):
-        greedy, pools, state, counters.device = chunk_jitted(
-            params, ids, pools, counters.device, position, bt, state, valid,
-            slot)
-        return greedy, pools, state
-
-    engine = PagedBatchEngine(
-        init_pool=lambda n: init_page_pool(cfg, n, page_size),
+    return PM.build_engine(
+        "exaone_moe", cfg, params,
+        window_program=lambda p, k, *args: window_program(
+            p, cfg, k, eos, *args),
+        chunk_step=step, donate_window=(2, 3, 9), donate_chunk=(2, 3, 6),
+        init_page_pool=lambda n: init_page_pool(cfg, n, page_size),
         init_slot_state=lambda slots: init_slot_state(cfg, slots),
-        chunk_prefill=chunk_prefill,
-        chunk_valid_rows=True,
-        window_step=window_factory(window, 0),
-        window_factory=window_factory,
-        window=window,
-        max_slots=max_slots,
-        max_seq=cfg.max_seq,
-        page_size=page_size,
-        chunk=chunk,
-        num_pages=num_pages,
-        eos=eos,
-    )
-    engine.flops_per_token = flops_per_token(cfg)
-    engine.device_peak_flops = profiling.detect_peak_flops()
-    counters.engine = engine
-    engine.model_counters = counters.read
-    return engine
+        counters=init_counters(cfg), report=partial(report, cfg, page_size),
+        not_offered=NOT_OFFERED, flops_per_token=flops_per_token(cfg),
+        max_slots=max_slots, eos=eos, page_size=page_size, chunk=chunk,
+        num_pages=num_pages, window=window, prefix_cache=False,
+        prefix_cache_pages=0)

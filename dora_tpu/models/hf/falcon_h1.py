@@ -57,16 +57,18 @@ here, 94 MB with their bf16 copies) and were not re-tiled in this PR
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from dora_tpu import profiling
 from dora_tpu.models import layers as L
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.models.paged_window import make_paged_window
+from dora_tpu.ops import decode_block as DB
 from dora_tpu.ops.int8_matmul import quantize_int8_t
 from dora_tpu.ops.ssm_state_step import ssm_state_step
 
@@ -84,6 +86,9 @@ NOT_OFFERED = {
                    "rolled back; no snapshot of it is kept",
     "DORA_LORA_DIR": "the grouped LoRA matmul is fused into the Qwen kernels",
 }
+
+#: the mixer's counters on the device
+COUNTERS = ("row_ticks", "decode_ticks", "chunk_rows", "zero_starts")
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _log = logging.getLogger(__name__)
@@ -308,15 +313,6 @@ def quantize_decode(params, cfg=None):
 # ---------------------------------------------------------------------------
 
 
-def rotate(x, cos, sin):
-    """Rotate-half rotary over the whole head: ``x [..., hd]``, ``cos``
-    / ``sin`` ``[..., hd/2]`` broadcastable to the halves."""
-    xf = x.astype(jnp.float32)
-    x1, x2 = jnp.split(xf, 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
-
-
 def _qkv(blk, cfg: FalconH1Config, u, cos, sin):
     """Normed rows ``u [N, dim]`` at rotary rows ``cos/sin [N, hd/2]`` ->
     roped q ``[N, KV, G, hd]``, roped k and v ``[N, KV, hd]`` (the key's
@@ -327,32 +323,9 @@ def _qkv(blk, cfg: FalconH1Config, u, cos, sin):
     q = p[:, : cfg.q_width].reshape(n, cfg.heads, hd)
     k = p[:, cfg.q_width : cfg.q_width + cfg.kv_width].reshape(n, kv, hd)
     v = p[:, cfg.q_width + cfg.kv_width :].reshape(n, kv, hd)
-    q = rotate(q, cos[:, None], sin[:, None])
-    k = rotate(k, cos[:, None], sin[:, None])
+    q = L.rotate_half(q, cos[:, None], sin[:, None])
+    k = L.rotate_half(k, cos[:, None], sin[:, None])
     return q.reshape(n, kv, cfg.heads // kv, hd), k, v
-
-
-def _attend(cfg: FalconH1Config, q, kv_of, visible, n_blocks, score: str,
-            mix: str):
-    """:func:`layers.attend_blocks` of ``q [..., KV, G, hd]`` over pool
-    rows: ``kv_of(j)`` gives block ``j``'s keys and values (``[...,
-    block, KV, hd]`` each); ``score`` and ``mix`` are the einsums of
-    queries with keys and of probabilities with values. Returns
-    ``[..., KV, G, hd]`` float32."""
-    f32 = {"preferred_element_type": jnp.float32}
-    return L.attend_blocks(
-        q, kv_of, visible, n_blocks,
-        lambda q, kv: jnp.einsum(score, q, kv[0], **f32),
-        lambda p, kv: jnp.einsum(mix, p.astype(kv[1].dtype), kv[1], **f32),
-        scale=cfg.head_dim ** -0.5, width=cfg.head_dim,
-    )
-
-
-def _kv_rows(cfg: FalconH1Config, k, v):
-    """Roped keys and values ``[N, KV, hd]`` -> the rows as cached."""
-    n = k.shape[0]
-    return jnp.concatenate(
-        [k.reshape(n, cfg.kv_width), v.reshape(n, cfg.kv_width)], axis=-1)
 
 
 def attn_decode(blk, cfg: FalconH1Config, u, pool, positions, block_tables,
@@ -368,7 +341,7 @@ def attn_decode(blk, cfg: FalconH1Config, u, pool, positions, block_tables,
         q, k, v = _qkv(blk, cfg, u, cos, sin)
         pool = pool.at[
             block_tables[jnp.arange(b), positions // page], positions % page
-        ].set(_kv_rows(cfg, k, v).astype(pool.dtype))
+        ].set(L.kv_rows(cfg, k, v).astype(pool.dtype))
         per = block // page
 
         def kv_of(j):
@@ -380,8 +353,9 @@ def attn_decode(blk, cfg: FalconH1Config, u, pool, positions, block_tables,
             t = j * block + jnp.arange(block)
             return (t[None, :] <= positions[:, None])[:, None, None, :]
 
-        ctx = _attend(cfg, q, kv_of, visible, positions.max() // block + 1,
-                      "bkgd,btkd->bkgt", "bkgt,btkd->bkgd")
+        ctx = L.attend_kv_blocks(
+            cfg, q, kv_of, visible, positions.max() // block + 1,
+            "bkgd,btkd->bkgt", "bkgt,btkd->bkgd")
         out = L.matmul(ctx.astype(u.dtype).reshape(b, cfg.q_width), blk["wo"])
         return out.astype(jnp.float32), pool
 
@@ -399,7 +373,7 @@ def attn_chunk(blk, cfg: FalconH1Config, u, pool, position, block_table,
         ids = jax.lax.dynamic_slice_in_dim(block_table, position // page,
                                            c // page)
         pool = pool.at[ids].set(
-            _kv_rows(cfg, k, v).astype(pool.dtype).reshape(
+            L.kv_rows(cfg, k, v).astype(pool.dtype).reshape(
                 c // page, page, 2 * cfg.kv_width))
         per = block // page
         q_pos = position + jnp.arange(c)
@@ -413,8 +387,9 @@ def attn_chunk(blk, cfg: FalconH1Config, u, pool, position, block_table,
             t = j * block + jnp.arange(block)
             return (t[None, :] <= q_pos[:, None])[:, None, None, :]
 
-        ctx = _attend(cfg, q, kv_of, visible, (position + c - 1) // block + 1,
-                      "qkgd,tkd->qkgt", "qkgt,tkd->qkgd")
+        ctx = L.attend_kv_blocks(
+            cfg, q, kv_of, visible, (position + c - 1) // block + 1,
+            "qkgd,tkd->qkgt", "qkgt,tkd->qkgd")
         out = L.matmul(ctx.astype(u.dtype).reshape(c, cfg.q_width), blk["wo"])
         return out.astype(jnp.float32), pool
 
@@ -568,8 +543,6 @@ def mixer_chunk(blk, cfg: FalconH1Config, u, state, slot, position, valid):
 def mlp_decode(blk, cfg: FalconH1Config, x):
     """``x + MLP(RMSNorm(x))`` for the decode rows: one fused sweep over
     the ffn tiles (``ops.decode_block.mlp_step``)."""
-    from dora_tpu.ops import decode_block as DB
-
     gu, dn = blk["w_gateup"], blk["w_down"]
     return DB.mlp_step(
         x, blk["ffn_norm"], gu["int8"], gu["scale"],
@@ -604,23 +577,6 @@ def _layers(params, cfg: FalconH1Config, x, pools, state, attend, mix, mlp):
     return x, pools, state
 
 
-def head_logits(params, cfg: FalconH1Config, x):
-    h = L.rms_norm(x, params["out_norm"], cfg.norm_eps)
-    return L.matmul(h, params["lm_head"]).astype(jnp.float32)
-
-
-def head_argmax(params, cfg: FalconH1Config, x):
-    from dora_tpu.ops import decode_block as DB
-
-    w = params["lm_head"]
-    return DB.lm_head_argmax(x, params["out_norm"], w["int8"], w["scale"],
-                             eps=cfg.norm_eps)
-
-
-def _count(stats, **adds):
-    return {k: v + adds.get(k, 0) for k, v in stats.items()}
-
-
 def paged_batch_rows(params, cfg: FalconH1Config, tokens, pools, state, stats,
                      positions, block_tables, active, block: int = ATTN_BLOCK):
     """One decode step for B = slots independent sequences: tokens,
@@ -643,8 +599,8 @@ def paged_batch_rows(params, cfg: FalconH1Config, tokens, pools, state, stats,
     x, pools, state = _layers(params, cfg, x, pools, state, attend, mix,
                               mlp_decode)
     live = active.sum(dtype=jnp.int32)
-    stats = _count(stats, row_ticks=live,
-                   decode_ticks=(live > 0).astype(jnp.int32))
+    stats = PM.add_counts(
+        stats, row_ticks=live, decode_ticks=(live > 0).astype(jnp.int32))
     return x, pools, state, stats
 
 
@@ -670,46 +626,29 @@ def paged_chunk_rows(params, cfg: FalconH1Config, chunk_ids, pools, state,
 
     x, pools, state = _layers(params, cfg, x, pools, state, attend, mix,
                               mlp_chunk)
-    stats = _count(stats, chunk_rows=valid.astype(jnp.int32),
-                   zero_starts=(position == 0).astype(jnp.int32))
+    stats = PM.add_counts(
+        stats, chunk_rows=valid.astype(jnp.int32),
+        zero_starts=(position == 0).astype(jnp.int32))
     return x, pools, state, stats
 
 
-def paged_batch_logits(params, cfg, *args, **kw):
-    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
-    return head_logits(params, cfg, x), *rest
-
-
-def paged_chunk_logits(params, cfg, *args, **kw):
-    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
-    return head_logits(params, cfg, x), *rest
-
-
-def fused_paged_batch_step(params, cfg, *args, **kw):
-    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
-    return head_argmax(params, cfg, x), *rest
-
-
-def fused_paged_chunk_step(params, cfg, *args, **kw):
-    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
-    return head_argmax(params, cfg, x), *rest
+paged_batch_logits, fused_paged_batch_step = PM.under_the_head(paged_batch_rows)
+paged_chunk_logits, fused_paged_chunk_step = PM.under_the_head(paged_chunk_rows)
 
 
 def window_program(params, cfg, k: int, eos, block: int, tokens, pools,
                    stats, positions, bts, active, emitted, max_new, state):
-    """The K-tick decode window (models/vlm.make_paged_window with a
+    """The K-tick decode window (models/paged_window.make_paged_window with a
     slot state) over :func:`fused_paged_batch_step`: the counters ride
     the window's carry beside the state and come back apart. Returns
     (the window's own results — pools, then state, last — and stats)."""
-    from dora_tpu.models import vlm as _vlm
-
     def batch(tokens, pools, positions, bts, active, carried):
         nxt, pools, state, stats = fused_paged_batch_step(
             params, cfg, tokens, pools, *carried, positions, bts, active,
             block=block)
         return nxt, pools, (state, stats)
 
-    *out, (state, stats) = _vlm.make_paged_window(
+    *out, (state, stats) = make_paged_window(
         batch, k=k, eos=eos, slot_state=True)(
         tokens, pools, positions, bts, active, emitted, max_new,
         (state, stats))
@@ -751,39 +690,19 @@ def init_slot_state(cfg: FalconH1Config, max_slots: int) -> dict:
 def init_counters() -> dict:
     """The mixer's counters on the device: an operand and a result of
     their own of both programs (a buffer each: donated one by one),
-    int32 that wraps; :class:`SsmCounters` adds up the differences."""
-    names = ("row_ticks", "decode_ticks", "chunk_rows", "zero_starts")
-    return {name: jnp.zeros((), jnp.int32) for name in names}
+    int32 that wraps; ``paged_model.DeviceCounters`` adds up the
+    differences."""
+    return {name: jnp.zeros((), jnp.int32) for name in COUNTERS}
 
 
-class SsmCounters:
-    """The mixer's counters of one engine: the device arrays the two
-    programs take and give back (``device``) and their host side.
-    :meth:`read` fetches four scalars; ``llm_server``'s 1 Hz report calls
-    it at a window boundary, after ``collect()``."""
-
-    def __init__(self, cfg: FalconH1Config):
-        self.device = init_counters()
-        #: set by :func:`make_paged_engine`: whose slots ``read`` counts
-        self.engine = None
-        self._slot_bytes = cfg.state_bytes_per_slot
-        self._last: dict | None = None
-        self.totals = {f"ssm_{k}": 0 for k in self.device}
-
-    def read(self) -> dict:
-        import numpy as np
-
-        now = {k: int(np.asarray(v)) for k, v in self.device.items()}
-        last = self._last or dict.fromkeys(now, 0)
-        self._last = now
-        for k in now:
-            self.totals[f"ssm_{k}"] += (now[k] - last[k]) & 0xFFFFFFFF
-        engine = self.engine
-        return {
-            **self.totals,
-            "ssm_state_bytes": self._slot_bytes * engine.max_slots,
-            "ssm_slots_live": engine.active,
-        }
+def report(cfg: FalconH1Config, totals: dict, engine) -> dict:
+    """The gauges of one engine (``paged_model.build_engine``'s
+    ``report``): the mixer's counters' sums and the slots' state."""
+    return {
+        **{f"ssm_{k}": int(totals[k]) for k in COUNTERS},
+        "ssm_state_bytes": cfg.state_bytes_per_slot * engine.max_slots,
+        "ssm_slots_live": engine.active,
+    }
 
 
 def flops_per_token(cfg: FalconH1Config) -> float:
@@ -805,86 +724,39 @@ def make_paged_engine(params, cfg: FalconH1Config, *, max_slots: int = 16,
     """The paged continuous-batching engine
     (models/batch_engine.PagedBatchEngine) with a per-slot recurrent
     state beside the K/V pool: the same scheduler, allocator and K-tick
-    window as the other two families. ``num_pages`` defaults to every
-    slot reaching ``max_seq``. **No prefix cache, whatever is asked**: a
-    granted prefix would need the recurrent state at its end, and no
-    snapshot is kept at a page boundary. Speculation, LoRA and int8
-    pages are not offered (KNOWN_ISSUES.md)."""
-    from dora_tpu.models.batch_engine import PagedBatchEngine
-
-    for knob, why in NOT_OFFERED.items():
-        if os.environ.get(knob, "0") not in ("", "0"):
-            raise NotImplementedError(
-                f"falcon_h1: {knob} is not offered: {why}")
+    window as the other families (``paged_model.build_engine``; the
+    pools, the counters and the slot state are arguments 2, 3 and 9 of
+    the window and 2, 3 and 6 of the chunk, hence the donation).
+    ``num_pages`` defaults to every slot reaching ``max_seq``. **No
+    prefix cache, whatever is asked**: a granted prefix would need the
+    recurrent state at its end, and no snapshot is kept at a page
+    boundary. Speculation, LoRA and int8 pages are not offered
+    (KNOWN_ISSUES.md)."""
     if prefix_cache or prefix_cache_pages:
         _log.warning(
             "falcon_h1: the prefix cache is off for this model: a granted "
             "prefix needs the recurrent state at its end, and none is kept")
-    chunk = chunk or min(256, cfg.max_seq)
+    chunk = PM.default_chunk(chunk, cfg.max_seq)
     assert chunk % min(cfg.scan_chunk, chunk) == 0, (chunk, cfg.scan_chunk)
-    if attn_block is None:
-        attn_block = ATTN_BLOCK if cfg.max_seq % ATTN_BLOCK == 0 else chunk
-    assert attn_block % page_size == 0 and cfg.max_seq % attn_block == 0, (
-        attn_block, page_size, cfg.max_seq,
-    )
+    attn_block = PM.default_attn_block(attn_block, ATTN_BLOCK, chunk,
+                                       cfg.max_seq, page_size)
     if num_pages is None:
         num_pages = max_slots * cfg.max_seq // page_size + 1
-    if window is None:
-        window = int(os.environ.get("DORA_MULTISTEP_K", "8"))
-
-    counters = SsmCounters(cfg)
-
-    # params ride as an argument, never a closed-over constant (see
-    # qwen2.make_paged_engine); the pools, the counters and the slot
-    # state are arguments 2, 3 and 9 (6 of the chunk), hence the
-    # donation. The engine sees pools and state; the counters stay here.
-    def window_factory(k, sk):
-        assert not sk, "falcon_h1: no speculative window"
-
-        def program(p, *args):
-            return window_program(p, cfg, k, eos, attn_block, *args)
-
-        jitted = jax.jit(program, donate_argnums=(2, 3, 9))
-
-        def window_step(tokens, pools, positions, bts, active, emitted,
-                        max_new, state):
-            out, counters.device = jitted(
-                params, tokens, pools, counters.device, positions, bts,
-                active, emitted, max_new, state)
-            return out
-
-        return window_step
 
     def step(p, ids, pools, stats, position, bt, state, valid, slot):
         return fused_paged_chunk_step(p, cfg, ids, pools, state, stats,
                                       position, bt, valid, slot,
                                       block=attn_block)
 
-    chunk_jitted = jax.jit(step, donate_argnums=(2, 3, 6))
-
-    def chunk_prefill(ids, pools, position, bt, valid, slot, state):
-        greedy, pools, state, counters.device = chunk_jitted(
-            params, ids, pools, counters.device, position, bt, state, valid,
-            slot)
-        return greedy, pools, state
-
-    engine = PagedBatchEngine(
-        init_pool=lambda n: init_page_pool(cfg, n, page_size),
+    return PM.build_engine(
+        "falcon_h1", cfg, params,
+        window_program=lambda p, k, *args: window_program(
+            p, cfg, k, eos, attn_block, *args),
+        chunk_step=step, donate_window=(2, 3, 9), donate_chunk=(2, 3, 6),
+        init_page_pool=lambda n: init_page_pool(cfg, n, page_size),
         init_slot_state=lambda slots: init_slot_state(cfg, slots),
-        chunk_prefill=chunk_prefill,
-        chunk_valid_rows=True,
-        window_step=window_factory(window, 0),
-        window_factory=window_factory,
-        window=window,
-        max_slots=max_slots,
-        max_seq=cfg.max_seq,
-        page_size=page_size,
-        chunk=chunk,
-        num_pages=num_pages,
-        eos=eos,
-    )
-    engine.flops_per_token = flops_per_token(cfg)
-    engine.device_peak_flops = profiling.detect_peak_flops()
-    counters.engine = engine
-    engine.model_counters = counters.read
-    return engine
+        counters=init_counters(), report=partial(report, cfg),
+        not_offered=NOT_OFFERED, flops_per_token=flops_per_token(cfg),
+        max_slots=max_slots, eos=eos, page_size=page_size, chunk=chunk,
+        num_pages=num_pages, window=window, prefix_cache=False,
+        prefix_cache_pages=0)

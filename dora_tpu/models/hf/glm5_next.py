@@ -4,7 +4,7 @@ layers of per-slot state, three for every latent-attention layer whose
 rows a learned indexer picks (DeepSeek sparse attention over MLA without
 a rotary part), ``hc_mult`` residual streams mixed by Sinkhorn-normalised
 matrices around every sublayer (mHC), over a dense SwiGLU in the first
-layer(s) and ``kimi_k2``'s sigmoid-routed expert layer in the rest.
+layer(s) and the sigmoid-routed expert layer (``models/moe.py``) in the rest.
 
 The layer, as the published ``config.json`` names it (``†`` = a detail
 the config does not settle, an assumption written down in
@@ -39,8 +39,8 @@ dim]``:
                score(t, b) = sum_j w[t, j] relu(qI[t, j] . kI~_b)   for b < floor(t / index_kpool)
                picked(t) = the positions of the top index_topk / index_kpool blocks        †6
                            + positions index_kpool floor(t / index_kpool) .. t; all of 0..t while t < index_topk
-      attend: absorbed MLA (kimi_k2's) over picked(t) alone, scale qk_head_dim^-0.5
-    mlp: kimi_k2.mlp (route / held_experts / the shared expert), every SwiGLU
+      attend: absorbed MLA (Kimi-K2's) over picked(t) alone, scale qk_head_dim^-0.5
+    mlp: moe.mlp (route / held_experts / the shared expert), every SwiGLU
          clamped by swiglu_limit (kept beside its matrices by the loader)   †7
 
 What this module adds to the serving path: **three cache kinds in one
@@ -78,23 +78,18 @@ tower are not served.
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from dora_tpu import profiling
 from dora_tpu.models import layers as L
-from dora_tpu.models.hf import kimi_k2 as K
-# the pool's size as a rule in bytes (every slot may reach max_seq, capped by
-# what the device has left less 4 GiB: at this model's 1,088 B a token the cap
-# does not bind, 16 x 16,384 rows are 285 MB), the counters' adder, the head
-from dora_tpu.models.hf.exaone_moe import (  # noqa: F401  (pages_that_fit: tests)
-    _add, default_num_pages, head_argmax, head_logits, page_pool_bytes,
-    pages_that_fit)
+from dora_tpu.models import moe
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
 from dora_tpu.ops.kda_state_step import kda_state_step
 
@@ -299,7 +294,7 @@ class Glm5NextConfig:
             raise ValueError(
                 f"glm5_next: index_topk {topk} is no multiple of index_kpool "
                 f"{pool}")
-        first, held = K.expert_share(config, ep_rank)
+        first, held = moe.expert_share(config, ep_rank)
         limit = config.get("swiglu_limit")
         return cls(
             vocab=config["vocab_size"],
@@ -346,7 +341,7 @@ class Glm5NextConfig:
 
 def _pad_to_lanes(w):
     """Zero output channels (HF layout: rows) up to a multiple of 128."""
-    return K._pad_outputs(w, w.shape[0] + (-w.shape[0]) % 128)
+    return moe.pad_outputs(w, w.shape[0] + (-w.shape[0]) % 128)
 
 
 def _load_kda(get, cfg: Glm5NextConfig, a: str) -> dict:
@@ -372,10 +367,7 @@ def _load_kda(get, cfg: Glm5NextConfig, a: str) -> dict:
 
 
 def _load_dsa(get, cfg: Glm5NextConfig, a: str) -> dict:
-    h, nope, v = cfg.heads, cfg.nope, cfg.v_dim
     kvb = _quantize_t(get(a + "kv_b_proj.weight"))  # [kv_rank, H*(nope+v)]
-    kvb8 = kvb["int8"].reshape(cfg.kv_rank, h, nope + v)
-    kvbs = kvb["scale"].reshape(h, nope + v)
     return {
         # the query's and the cache's latents, the indexer's key and its
         # head weights read the same row: one matrix
@@ -390,23 +382,18 @@ def _load_dsa(get, cfg: Glm5NextConfig, a: str) -> dict:
                              get(a + "indexer.wq_b.weight")),
         "idx_norm_w": get(a + "indexer.k_norm.weight").astype(jnp.float32),
         "idx_norm_b": get(a + "indexer.k_norm.bias").astype(jnp.float32),
-        # kimi_k2's absorbed layout (mla_output reads it)
-        "w_kv_b": {
-            "k8": jnp.transpose(kvb8[:, :, :nope], (1, 2, 0)),
-            "ks": kvbs[:, :nope],
-            "v8": jnp.transpose(kvb8[:, :, nope:], (1, 0, 2)),
-            "vs": kvbs[:, nope:],
-        },
+        # Kimi-K2's absorbed layout (layers.mla_output reads it)
+        "w_kv_b": L.mla_kv_b_weights(kvb, cfg),
         "wo": _quantize_t(get(a + "o_proj.weight")),
     }
 
 
 def _swiglu(get, cfg: Glm5NextConfig, prefix: str) -> dict:
-    """``kimi_k2``'s SwiGLU matrices with the checkpoint's clamp beside
-    them (``kimi_k2.swiglu`` applies a ``"limit"`` where it finds one: a
-    static argument at its call sites would be plainer, and moves the
-    source columns inside kimi's and K-EXAONE's serialized kernels)."""
-    w = K._swiglu(get, prefix)
+    """The SwiGLU's matrices with the checkpoint's clamp beside them
+    (``moe.swiglu`` applies a ``"limit"`` where it finds one: a static
+    argument at its call sites would be plainer, and moves the source
+    columns inside Kimi-K2's and K-EXAONE's serialized kernels)."""
+    w = moe.swiglu_weights(get, prefix)
     if cfg.swiglu_limit is not None:
         w["limit"] = jnp.asarray(cfg.swiglu_limit, jnp.float32)
     return w
@@ -435,16 +422,8 @@ def load_layer(get, cfg: Glm5NextConfig, i: int, prefix: str = "model.") -> dict
     if not cfg.sparse[i]:
         block["dense"] = _swiglu(get, cfg, m)
         return block
-    block["router"] = get(m + "gate.weight").T.astype(L.compute_dtype())
-    block["router_bias"] = get(m + "gate.e_score_correction_bias").astype(
-        jnp.float32)
-    if cfg.n_shared:
-        block["shared"] = _swiglu(get, cfg, m + "shared_experts.")
-    block["experts"] = [
-        _swiglu(get, cfg, f"{m}experts.{e}.")
-        for e in range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
-    ]
-    return block
+    return {**block, **moe.expert_layer_weights(
+        get, cfg, m, lambda get, prefix: _swiglu(get, cfg, prefix))}
 
 
 def load(model_dir: str | Path, max_seq: int | None = None,
@@ -706,7 +685,7 @@ def latent_project(blk, cfg: Glm5NextConfig, u):
     """Normed rows ``u [N, dim]`` -> (absorbed queries [N, H, kv_rank],
     the cache rows [N, kv_rank], the indexer's queries [N, J, d_I], its
     key [N, d_I] float32, its head weights [N, J] float32).
-    ``kimi_k2.mla_project``'s arithmetic with no rotary part, and the
+    Kimi-K2's ``mla_project`` arithmetic with no rotary part, and the
     query's latent kept for the indexer."""
     f32 = jnp.float32
     n, h, nope = u.shape[0], cfg.heads, cfg.nope
@@ -814,7 +793,7 @@ def dsa_decode(blk, cfg: Glm5NextConfig, u, pool, st, positions, block_tables,
         p = _masked_softmax(s, seen[:, None, :])
         ctx = jnp.einsum("bhn,bnc->bhc", p.astype(latent.dtype), latent,
                          preferred_element_type=f32)
-        out = K.mla_output(blk, cfg, ctx)
+        out = L.mla_output(blk, cfg, ctx)
     return out, {"kv": kvp, "ik": ikp}, {"acc": acc}, {
         "rows": seen.sum(-1, dtype=jnp.int32), "picked": ids, "attended": out}
 
@@ -890,10 +869,10 @@ def dsa_chunk(blk, cfg: Glm5NextConfig, u, pool, st, slot, position,
             dense = (q_pos < cfg.idx_topk)[:, None]
             return (causal & (dense | mine | tail))[:, None, :]
 
-        ctx = K._attend_blocks(
+        ctx = L.attend_latent_blocks(
             cfg, q_abs, rows_of, visible, (position + c - 1) // block + 1,
             "qhc,tc->qht", "qht,tc->qhc")
-        out = K.mla_output(blk, cfg, ctx)
+        out = L.mla_output(blk, cfg, ctx)
     return out, {"kv": kvp, "ik": ikp}, {"acc": acc}, {
         "picked": top, "attended": out}
 
@@ -912,10 +891,10 @@ def rows_picked(cfg: Glm5NextConfig, t):
 def init_counters(cfg: Glm5NextConfig) -> dict:
     """The counters on the device, an operand and a result of their own
     of both programs (a buffer each: donated one by one), int32 that
-    wraps: ``moe`` are ``kimi_k2``'s routing counters under its names,
-    ``kda`` this module's (:data:`KDA_COUNTERS`)."""
+    wraps: ``moe`` are the expert layer's routing counters
+    (``moe.init_counters``), ``kda`` this module's (:data:`KDA_COUNTERS`)."""
     return {
-        "moe": K.init_counters(cfg),
+        "moe": moe.init_counters(cfg),
         "kda": {name: jnp.zeros((), jnp.int32) for name in KDA_COUNTERS},
     }
 
@@ -925,14 +904,14 @@ def _layers(params, cfg: Glm5NextConfig, x, pools, state, stats, mix, attend,
     """The stack over the residual streams: ``mix(blk, normed rows, layer
     state) -> (out, layer state)`` for a delta-rule layer, ``attend(blk,
     normed rows, layer pool, layer state) -> (out, pool, state, extra)``
-    for a sparse-latent one, then ``kimi_k2.mlp`` (the clamp rides the
+    for a sparse-latent one, then ``moe.mlp`` (the clamp rides the
     SwiGLU weights: :func:`_swiglu`); each
     inside :func:`mhc_sublayer`. ``x [N, dim]`` is copied to every stream
     at the entry and the streams are summed at the exit. Returns (rows,
     pools, state, the routing counters, the sparse-latent layers'
     extras)."""
     pools, state = dict(pools), dict(state)
-    moe = dict(stats)
+    routed = dict(stats)
     per_layer, extras = [], []
     streams = jnp.broadcast_to(x[:, None, :], (x.shape[0], cfg.hc, cfg.dim))
     for i in range(cfg.layers):
@@ -948,28 +927,17 @@ def _layers(params, cfg: Glm5NextConfig, x, pools, state, stats, mix, attend,
             return out
 
         def ffn(h, blk=blk):
-            y, counters = K.mlp(blk, cfg, h, live, counted)
-            if counters is not None:
-                tokens, pairs, per_expert = counters
-                moe["tokens"] = moe["tokens"] + tokens
-                moe["local_pairs"] = moe["local_pairs"] + pairs
-                per_layer.append(per_expert)
-                if decode:
-                    moe["touched"] = moe["touched"] + (per_expert > 0).sum(
-                        dtype=jnp.int32)
+            y, counters = moe.mlp(blk, cfg, h, live, counted)
+            moe.add_layer(routed, per_layer, counters, decode)
             return y
 
         streams = mhc_sublayer(blk["hc_attn"], cfg, streams, blk["attn_norm"],
                                mixer)
         streams = mhc_sublayer(blk["hc_ffn"], cfg, streams, blk["ffn_norm"],
                                ffn)
-    if per_layer:
-        moe["expert_tokens"] = moe["expert_tokens"] + jnp.stack(per_layer)
-        if decode:
-            moe["decode_ticks"] = moe["decode_ticks"] + counted.any().astype(
-                jnp.int32)
+    moe.add_stack(routed, per_layer, counted, decode)
     x = streams.astype(jnp.float32).sum(1).astype(streams.dtype)
-    return x, pools, state, moe, extras
+    return x, pools, state, routed, extras
 
 
 def paged_batch_rows(params, cfg: Glm5NextConfig, tokens, pools, state, stats,
@@ -991,14 +959,14 @@ def paged_batch_rows(params, cfg: Glm5NextConfig, tokens, pools, state, stats,
         return dsa_decode(blk, cfg, u, pool, st, positions, block_tables,
                           active)
 
-    x, pools, state, moe, looks = _layers(
+    x, pools, state, routed, looks = _layers(
         params, cfg, x, pools, state, stats["moe"], mix, attend, active,
         active, True)
     i32 = jnp.int32
     live = active.sum(dtype=i32)
     n_dsa = len(cfg.dsa_layers)
     selecting = active & (positions >= cfg.idx_topk)
-    kda = _add(
+    kda = PM.add_counts(
         stats["kda"],
         kda_decode_ticks=(live > 0).astype(i32),
         kda_row_ticks=len(cfg.kda_layers) * live,
@@ -1010,7 +978,7 @@ def paged_batch_rows(params, cfg: Glm5NextConfig, tokens, pools, state, stats,
             selecting, positions // cfg.idx_pool, 0).sum(dtype=i32),
         dsa_row_ticks_selecting=selecting.sum(dtype=i32),
     )
-    out = (x, pools, state, {"moe": moe, "kda": kda})
+    out = (x, pools, state, {"moe": routed, "kda": kda})
     if picks:
         return (*out, [{k: a[k] for k in ("picked", "attended")} for a in looks])
     return out
@@ -1039,7 +1007,7 @@ def paged_chunk_rows(params, cfg: Glm5NextConfig, chunk_ids, pools, state,
         return dsa_chunk(blk, cfg, u, pool, st, slot, position, block_table,
                          valid, block)
 
-    x, pools, state, moe, picked = _layers(
+    x, pools, state, routed, picked = _layers(
         params, cfg, x, pools, state, stats["moe"], mix, attend,
         jnp.ones((c,), bool), counted, False)
     i32 = jnp.int32
@@ -1051,7 +1019,7 @@ def paged_chunk_rows(params, cfg: Glm5NextConfig, chunk_ids, pools, state,
     def over_valid(values):
         return jnp.where(counted, values, 0).sum(dtype=i32)
 
-    kda = _add(
+    kda = PM.add_counts(
         stats["kda"],
         kda_chunks=jnp.ones((), i32), kda_chunk_rows=valid.astype(i32),
         dsa_chunk_rows_in_context=n_dsa * over_valid(q_pos + 1),
@@ -1061,34 +1029,18 @@ def paged_chunk_rows(params, cfg: Glm5NextConfig, chunk_ids, pools, state,
             selecting, q_pos // cfg.idx_pool, 0).sum(dtype=i32),
         dsa_chunk_rows_selecting=selecting.sum(dtype=i32),
     )
-    out = (x, pools, state, {"moe": moe, "kda": kda})
+    out = (x, pools, state, {"moe": routed, "kda": kda})
     return (*out, picked) if picks else out
 
 
-def paged_batch_logits(params, cfg, *args, **kw):
-    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
-    return head_logits(params, cfg, x), *rest
-
-
-def paged_chunk_logits(params, cfg, *args, **kw):
-    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
-    return head_logits(params, cfg, x), *rest
-
-
-def fused_paged_batch_step(params, cfg, *args, **kw):
-    x, *rest = paged_batch_rows(params, cfg, *args, **kw)
-    return head_argmax(params, cfg, x), *rest
-
-
-def fused_paged_chunk_step(params, cfg, *args, **kw):
-    x, *rest = paged_chunk_rows(params, cfg, *args, **kw)
-    return head_argmax(params, cfg, x), *rest
+paged_batch_logits, fused_paged_batch_step = PM.under_the_head(paged_batch_rows)
+paged_chunk_logits, fused_paged_chunk_step = PM.under_the_head(paged_chunk_rows)
 
 
 def window_program(params, cfg, k: int, eos, tokens, pools, stats,
                    positions, bts, active, emitted, max_new, state,
                    picks: bool = False):
-    """The K-tick decode window (models/vlm.make_paged_window with a
+    """The K-tick decode window (models/paged_window.make_paged_window with a
     slot state) over :func:`fused_paged_batch_step`: the counters ride
     the window's carry beside the slot state and come back apart.
     Returns (the window's own results — pools, then state, last — and
@@ -1096,8 +1048,6 @@ def window_program(params, cfg, k: int, eos, tokens, pools, stats,
     tick last: ``"picked" [K, B, picked_blocks]``, ``"attended" [K, B,
     dim]`` float32 (tick ``j`` of a row that came in at position ``p`` is
     the row at ``p + j``)."""
-    from dora_tpu.models import vlm as _vlm
-
     def batch(tokens, pools, positions, bts, active, carried):
         state, stats, *seen = carried
         nxt, pools, state, stats, *look = fused_paged_batch_step(
@@ -1118,7 +1068,7 @@ def window_program(params, cfg, k: int, eos, tokens, pools, stats,
             "picked": jnp.zeros((k, b, cfg.picked_blocks), jnp.int32),
             "attended": jnp.zeros((k, b, cfg.dim), jnp.float32),
         } for _ in cfg.dsa_layers])
-    *out, (state, stats, *seen) = _vlm.make_paged_window(
+    *out, (state, stats, *seen) = make_paged_window(
         batch, k=k, eos=eos, slot_state=True)(
         tokens, pools, positions, bts, active, emitted, max_new, carried)
     result = ((*out, state), stats)
@@ -1163,58 +1113,31 @@ def init_slot_state(cfg: Glm5NextConfig, max_slots: int) -> dict:
     return state
 
 
-class KdaDsaCounters:
-    """The counters of one engine: the device arrays the two programs
-    take and give back (``device``) and their host side, which adds up
-    the int32 differences. :meth:`read` fetches a few hundred bytes;
-    ``llm_server``'s 1 Hz report calls it at a window boundary, after
-    ``collect()``. The routing counters come out under ``kimi_k2``'s
-    names (one reader serves the three configurations)."""
+def default_num_pages(cfg: Glm5NextConfig, max_slots: int,
+                      page_size: int) -> int:
+    """The pool's default size, ``paged_model.default_num_pages``' rule in
+    bytes. At this model's 1,088 B a token the cap does not bind: 16 x
+    16,384 rows are 285 MB, every slot may reach ``max_seq``."""
+    return PM.default_num_pages(
+        page_size * cfg.kv_bytes_per_token, max_slots, cfg.max_seq, page_size)
 
-    def __init__(self, cfg: Glm5NextConfig, page_size: int):
-        self.device = init_counters(cfg)
-        #: set by :func:`make_paged_engine`: whose pages ``read`` counts
-        self.engine = None
-        self._cfg = cfg
-        self._page_bytes = page_pool_bytes(cfg, page_size)
-        self._last = None
-        self._expert_tokens = [0] * cfg.experts_held
-        self.totals = dict.fromkeys(
-            ("tokens", "local_pairs", "decode_ticks", "touched")
-            + KDA_COUNTERS, 0)
 
-    def read(self) -> dict:
-        import numpy as np
-
-        now = jax.tree.map(lambda v: np.asarray(v).astype(np.int64),
-                           self.device)
-        last = self._last or jax.tree.map(np.zeros_like, now)
-        self._last = now
-        gained = jax.tree.map(lambda a, b: (a - b) & 0xFFFFFFFF, now, last)
-        t = self.totals
-        for group in ("moe", "kda"):
-            for name, d in gained[group].items():
-                if name != "expert_tokens":
-                    t[name] += int(d)
-        self._expert_tokens = [
-            a + int(b) for a, b in zip(
-                self._expert_tokens, gained["moe"]["expert_tokens"].sum(0))]
-        ticks = t["decode_ticks"] * max(self._cfg.moe_layers, 1)
-        engine = self.engine
-        return {
-            "moe_tokens": t["tokens"],
-            "moe_local_pairs": t["local_pairs"],
-            "moe_expert_tokens": list(self._expert_tokens),
-            "moe_experts_touched": (
-                round(t["touched"] / ticks, 4) if ticks else None),
-            # raw, for a reader that takes it over a capture's ticks
-            "moe_touched": t["touched"],
-            **{name: t[name] for name in KDA_COUNTERS},
-            "kv_bytes_per_token": self._cfg.kv_bytes_per_token,
-            "kv_pool_bytes": engine.allocator.num_pages * self._page_bytes,
-            "kv_pages_free": engine.allocator.free_pages,
-            "kda_state_bytes": self._cfg.state_bytes_per_slot * engine.max_slots,
-        }
+def report(cfg: Glm5NextConfig, page_size: int, totals: dict, engine) -> dict:
+    """The gauges of one engine (``paged_model.build_engine``'s
+    ``report``): the routing counters under the names every expert-layer
+    model gives them (``moe.report``), this module's own, the pool and
+    the slots' state."""
+    return {
+        **moe.report(totals["moe"], cfg.moe_layers),
+        # raw, for a reader that takes it over a capture's ticks
+        "moe_touched": int(totals["moe"]["touched"]),
+        **{name: int(totals["kda"][name]) for name in KDA_COUNTERS},
+        "kv_bytes_per_token": cfg.kv_bytes_per_token,
+        "kv_pool_bytes": (engine.allocator.num_pages * page_size
+                          * cfg.kv_bytes_per_token),
+        "kv_pages_free": engine.allocator.free_pages,
+        "kda_state_bytes": cfg.state_bytes_per_slot * engine.max_slots,
+    }
 
 
 def flops_per_token(cfg: Glm5NextConfig) -> float:
@@ -1252,16 +1175,15 @@ def make_paged_engine(params, cfg: Glm5NextConfig, *, max_slots: int = 16,
     states, tails and the indexer's accumulators as its slot state and
     pages (latent rows and pooled indexer rows) for the sparse-latent
     layers alone: the same scheduler, allocator and K-tick window as the
-    other families. ``num_pages`` defaults to ``exaone_moe.default_num_pages``.
-    **No prefix cache, whatever is asked**: a granted prefix would need
-    the delta-rule state at its boundary, and none is kept. Speculation,
-    LoRA and int8 pages are not offered (KNOWN_ISSUES.md, PR 43)."""
-    from dora_tpu.models.batch_engine import PagedBatchEngine
-
-    for knob, why in NOT_OFFERED.items():
-        if os.environ.get(knob, "0") not in ("", "0"):
-            raise NotImplementedError(
-                f"glm5_next: {knob} is not offered: {why}")
+    other families (``paged_model.build_engine``; the pools, the counters
+    and the slot state are arguments 2, 3 and 9 of the window and 2, 3
+    and 6 of the chunk, hence the donation). ``num_pages`` defaults to
+    :func:`default_num_pages`. **No prefix cache, whatever is asked**: a
+    granted prefix would need the delta-rule state at its boundary, and
+    none is kept. Speculation, LoRA and int8 pages are not offered
+    (KNOWN_ISSUES.md, PR 43). With ``picks`` (a cache audit's engine,
+    never the server's) ``engine.selection`` holds the looks, a
+    sparse-latent layer each, of the last chunk and of the last window."""
     if page_size % cfg.idx_pool:
         raise NotImplementedError(
             f"glm5_next: index_kpool {cfg.idx_pool} does not divide the page "
@@ -1275,76 +1197,30 @@ def make_paged_engine(params, cfg: Glm5NextConfig, *, max_slots: int = 16,
             "glm5_next: the prefix cache is off for this model: a granted "
             "prefix needs the delta-rule state at its boundary, and none "
             "is kept")
-    chunk = chunk or min(256, cfg.max_seq)
-    if attn_block is None:
-        attn_block = ATTN_BLOCK if cfg.max_seq % ATTN_BLOCK == 0 else chunk
-    assert attn_block % page_size == 0 and cfg.max_seq % attn_block == 0, (
-        attn_block, page_size, cfg.max_seq,
-    )
+    chunk = PM.default_chunk(chunk, cfg.max_seq)
+    attn_block = PM.default_attn_block(attn_block, ATTN_BLOCK, chunk,
+                                       cfg.max_seq, page_size)
     if num_pages is None:
         num_pages = default_num_pages(cfg, max_slots, page_size)
-    if window is None:
-        window = int(os.environ.get("DORA_MULTISTEP_K", "8"))
-
-    counters = KdaDsaCounters(cfg, page_size)
-    #: with ``picks``: the looks, a sparse-latent layer each, of the last
-    #: chunk and of the last window; empty in a served engine
-    selection = {"chunk": [], "window": []}
-
-    # params ride as an argument, never a closed-over constant (see
-    # qwen2.make_paged_engine); the pools, the counters and the slot state
-    # are arguments 2, 3 and 9 (6 of the chunk), hence the donation. The
-    # engine sees pools and slot state; the counters stay here.
-    def window_factory(k, sk):
-        assert not sk, "glm5_next: no speculative window"
-
-        def program(p, *args):
-            return window_program(p, cfg, k, eos, *args, picks=picks)
-
-        jitted = jax.jit(program, donate_argnums=(2, 3, 9))
-
-        def window_step(tokens, pools, positions, bts, active, emitted,
-                        max_new, state):
-            out, counters.device, *look = jitted(
-                params, tokens, pools, counters.device, positions, bts,
-                active, emitted, max_new, state)
-            selection["window"] = look[0] if look else []
-            return out
-
-        return window_step
 
     def step(p, ids, pools, stats, position, bt, state, valid, slot):
         return fused_paged_chunk_step(p, cfg, ids, pools, state, stats,
                                       position, bt, valid, slot,
                                       block=attn_block, picks=picks)
 
-    chunk_jitted = jax.jit(step, donate_argnums=(2, 3, 6))
-
-    def chunk_prefill(ids, pools, position, bt, valid, slot, state):
-        greedy, pools, state, counters.device, *look = chunk_jitted(
-            params, ids, pools, counters.device, position, bt, state, valid,
-            slot)
-        selection["chunk"] = look[0] if look else []
-        return greedy, pools, state
-
-    engine = PagedBatchEngine(
-        init_pool=lambda n: init_page_pool(cfg, n, page_size),
+    selection = {"chunk": [], "window": []}
+    engine = PM.build_engine(
+        "glm5_next", cfg, params,
+        window_program=lambda p, k, *args: window_program(
+            p, cfg, k, eos, *args, picks=picks),
+        chunk_step=step, donate_window=(2, 3, 9), donate_chunk=(2, 3, 6),
+        init_page_pool=lambda n: init_page_pool(cfg, n, page_size),
         init_slot_state=lambda slots: init_slot_state(cfg, slots),
-        chunk_prefill=chunk_prefill,
-        chunk_valid_rows=True,
-        window_step=window_factory(window, 0),
-        window_factory=window_factory,
-        window=window,
-        max_slots=max_slots,
-        max_seq=cfg.max_seq,
-        page_size=page_size,
-        chunk=chunk,
-        num_pages=num_pages,
-        eos=eos,
-    )
-    engine.flops_per_token = flops_per_token(cfg)
-    engine.device_peak_flops = profiling.detect_peak_flops()
-    counters.engine = engine
-    engine.model_counters = counters.read
+        counters=init_counters(cfg), report=partial(report, cfg, page_size),
+        not_offered=NOT_OFFERED, flops_per_token=flops_per_token(cfg),
+        looks=selection,
+        max_slots=max_slots, eos=eos, page_size=page_size, chunk=chunk,
+        num_pages=num_pages, window=window, prefix_cache=False,
+        prefix_cache_pages=0)
     engine.selection = selection
     return engine

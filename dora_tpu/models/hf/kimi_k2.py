@@ -35,7 +35,8 @@ What this module adds to the serving path:
   experts. One chip runs the layer without its exchange.
 
 The share: ``ep_size`` is the checkpoint's (``config.json``, HF's key),
-the rank is the process's (``DORA_EP_RANK``); see :func:`expert_share`.
+the rank is the process's (``DORA_EP_RANK``); see ``models/moe.expert_share``,
+where the layer itself lives (K-EXAONE and GLM-5.3-Flash run it too).
 
 Plain ``jax.numpy`` + ``ops/int8_matmul``: no fused kernel is written
 here. The float32 reference of the same mathematics (expanded MLA, a
@@ -45,16 +46,18 @@ Text path only: K2.5's vision tower is not part of this module.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from dora_tpu import profiling
 from dora_tpu.models import layers as L
+from dora_tpu.models import moe
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
 
 MODEL_TYPES = ("kimi_k2", "deepseek_v3")
@@ -62,9 +65,6 @@ MODEL_TYPES = ("kimi_k2", "deepseek_v3")
 #: rows of one attention block (a multiple of the page): the pool is read
 #: this many positions at a time, up to the longest live context.
 ATTN_BLOCK = 512
-#: rows one expert computes at a time in a prefill chunk. A decode batch
-#: of at most this many rows goes to a touched expert whole.
-EXPERT_BLOCK = 32
 #: share of the device memory left after the weights that the default
 #: latent pool may take (the rest is the programs' temporaries).
 POOL_SHARE_OF_FREE = 0.5
@@ -154,7 +154,7 @@ class KimiK2Config:
             )
         if config.get("scoring_func", "sigmoid") != "sigmoid":
             raise NotImplementedError("kimi_k2: only sigmoid routing")
-        first, held = expert_share(config, ep_rank)
+        first, held = moe.expert_share(config, ep_rank)
         rs = config.get("rope_scaling")
         yarn = None
         if rs:
@@ -194,44 +194,9 @@ class KimiK2Config:
         )
 
 
-def expert_share(config: dict, ep_rank: int | None = None) -> tuple[int, int]:
-    """``(first, held)``: the experts of every layer that this rank
-    computes. HF's meaning of the keys: ``n_routed_experts`` counts the
-    model's experts and ``ep_size`` the ranks that divide them, each
-    holding ``n_routed_experts // ep_size`` consecutive ones. The ranks
-    of a group share one checkpoint directory, so ``ep_size`` is its
-    ``config.json``'s and nothing else's; which share is this process's
-    is the launcher's to say: ``ep_rank``, else ``DORA_EP_RANK``, else 0."""
-    total = config["n_routed_experts"]
-    ep_size = int(config.get("ep_size") or 1)
-    if ep_rank is None:
-        ep_rank = int(os.environ.get("DORA_EP_RANK") or 0)
-    if total % ep_size or not 0 <= ep_rank < ep_size:
-        raise ValueError(
-            f"kimi_k2: {total} experts do not divide over ep_size "
-            f"{ep_size} (rank {ep_rank})"
-        )
-    held = total // ep_size
-    return ep_rank * held, held
-
-
 # ---------------------------------------------------------------------------
 # loading: one layer at a time, only the held experts, int8 on the device
 # ---------------------------------------------------------------------------
-
-
-def _pad_outputs(w, to: int):
-    """Zero output channels up to ``to`` (HF layout: rows are outputs)."""
-    return jnp.pad(w, ((0, to - w.shape[0]), (0, 0)))
-
-
-def _swiglu(get, prefix: str) -> dict:
-    return {
-        "w_gateup": _quantize_t(
-            get(prefix + "gate_proj.weight"), get(prefix + "up_proj.weight")
-        ),
-        "w_down": _quantize_t(get(prefix + "down_proj.weight")),
-    }
 
 
 def load_layer(get, cfg: KimiK2Config, i: int, prefix: str = "model.") -> dict:
@@ -239,47 +204,26 @@ def load_layer(get, cfg: KimiK2Config, i: int, prefix: str = "model.") -> dict:
     array`` under the HF tensor names. Reads the held experts only."""
     lp = f"{prefix}layers.{i}."
     a = lp + "self_attn."
-    h, nope, v = cfg.heads, cfg.nope, cfg.v_dim
     # q_a and kv_a read the same row: one matrix, padded to a lane multiple
     kv_a = get(a + "kv_a_proj_with_mqa.weight")
     width = cfg.q_rank + cfg.latent
-    kv_a = _pad_outputs(kv_a, kv_a.shape[0] + (-width) % 128)
+    kv_a = moe.pad_outputs(kv_a, kv_a.shape[0] + (-width) % 128)
     kvb = _quantize_t(get(a + "kv_b_proj.weight"))  # [kv_rank, H*(nope+v)]
-    kvb8 = kvb["int8"].reshape(cfg.kv_rank, h, nope + v)
-    kvbs = kvb["scale"].reshape(h, nope + v)
     block = {
         "attn_norm": get(lp + "input_layernorm.weight"),
         "w_qkv_a": _quantize_t(get(a + "q_a_proj.weight"), kv_a),
         "q_norm": get(a + "q_a_layernorm.weight"),
         "w_q_b": _quantize_t(get(a + "q_b_proj.weight")),
         "kv_norm": get(a + "kv_a_layernorm.weight"),
-        # W_kvb per head, split for the absorbed form: the key part
-        # [H, nope, kv_rank] folds into the query, the value part
-        # [H, kv_rank, v] into the output; the scales are per (head, column)
-        "w_kv_b": {
-            "k8": jnp.transpose(kvb8[:, :, :nope], (1, 2, 0)),
-            "ks": kvbs[:, :nope],
-            "v8": jnp.transpose(kvb8[:, :, nope:], (1, 0, 2)),
-            "vs": kvbs[:, nope:],
-        },
+        "w_kv_b": L.mla_kv_b_weights(kvb, cfg),
         "wo": _quantize_t(get(a + "o_proj.weight")),
         "ffn_norm": get(lp + "post_attention_layernorm.weight"),
     }
     m = lp + "mlp."
     if i < cfg.first_dense:
-        block["dense"] = _swiglu(get, m)
+        block["dense"] = moe.swiglu_weights(get, m)
         return block
-    block["router"] = get(m + "gate.weight").T.astype(L.compute_dtype())
-    block["router_bias"] = get(m + "gate.e_score_correction_bias").astype(
-        jnp.float32
-    )
-    if cfg.n_shared:
-        block["shared"] = _swiglu(get, m + "shared_experts.")
-    block["experts"] = [
-        _swiglu(get, f"{m}experts.{e}.")
-        for e in range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
-    ]
-    return block
+    return {**block, **moe.expert_layer_weights(get, cfg, m)}
 
 
 def load(model_dir: str | Path, max_seq: int | None = None,
@@ -371,38 +315,6 @@ def mla_project(blk, cfg: KimiK2Config, x, cos, sin):
     )
 
 
-def mla_output(blk, cfg: KimiK2Config, ctx):
-    """``ctx [N, H, kv_rank]`` (softmax-weighted latent rows, float32)
-    -> the attention sublayer's output ``[N, dim]``."""
-    kb = blk["w_kv_b"]
-    dtype = L.compute_dtype()
-    o = jnp.einsum(
-        "nhc,hcj->nhj", ctx.astype(dtype), kb["v8"].astype(dtype),
-        preferred_element_type=jnp.float32,
-    ) * kb["vs"]
-    return L.matmul(
-        o.astype(dtype).reshape(ctx.shape[0], cfg.heads * cfg.v_dim),
-        blk["wo"],
-    )
-
-
-def _attend_blocks(cfg: KimiK2Config, q, rows_of, visible, n_blocks,
-                   score: str, mix: str):
-    """:func:`layers.attend_blocks` of absorbed queries ``q [..., row]``
-    over latent rows: ``rows_of(j)`` gives block ``j``'s rows
-    (``[..., block, row]``); ``score`` and ``mix`` are the einsums of
-    queries with rows and of probabilities with rows. Returns the
-    softmax-weighted ``c_kv`` ``[..., kv_rank]`` in float32."""
-    f32 = {"preferred_element_type": jnp.float32}
-    return L.attend_blocks(
-        q, rows_of, visible, n_blocks,
-        lambda q, kv: jnp.einsum(score, q, kv, **f32),
-        lambda p, kv: jnp.einsum(
-            mix, p.astype(kv.dtype), kv[..., : cfg.kv_rank], **f32),
-        scale=cfg.softmax_scale, width=cfg.kv_rank,
-    )
-
-
 def mla_absorbed(blk, cfg: KimiK2Config, x, pool, positions, block_tables,
                  cos, sin, block: int):
     """Decode: ``x [B, dim]`` (normed), one new position a row. Writes
@@ -426,11 +338,11 @@ def mla_absorbed(blk, cfg: KimiK2Config, x, pool, positions, block_tables,
             t = j * block + jnp.arange(block)
             return (t[None, :] <= positions[:, None])[:, None, :]
 
-        ctx = _attend_blocks(
+        ctx = L.attend_latent_blocks(
             cfg, q, rows_of, visible, positions.max() // block + 1,
             "bhc,btc->bht", "bht,btc->bhc",
         )
-        return mla_output(blk, cfg, ctx), pool
+        return L.mla_output(blk, cfg, ctx), pool
 
 
 def mla_chunk(blk, cfg: KimiK2Config, x, pool, position, block_table,
@@ -460,123 +372,11 @@ def mla_chunk(blk, cfg: KimiK2Config, x, pool, position, block_table,
             t = j * block + jnp.arange(block)
             return (t[None, :] <= q_pos[:, None])[:, None, :]
 
-        ctx = _attend_blocks(
+        ctx = L.attend_latent_blocks(
             cfg, q, rows_of, visible, (position + c - 1) // block + 1,
             "qhc,tc->qht", "qht,tc->qhc",
         )
-        return mla_output(blk, cfg, ctx), pool
-
-
-def swiglu(w: dict, x):
-    """``w["limit"]``, where a loader put one beside the matrices (a
-    checkpoint's ``swiglu_limit``; Kimi-K2 has none): the gate held to
-    ``(-inf, limit]`` and the up part to ``[-limit, limit]`` before
-    ``silu(gate) * up``."""
-    gate, up = jnp.split(L.matmul(x, w["w_gateup"]), 2, axis=-1)
-    if "limit" in w:
-        gate = jnp.minimum(gate, w["limit"].astype(gate.dtype))
-        up = jnp.clip(up, -w["limit"].astype(up.dtype), w["limit"].astype(up.dtype))
-    return L.matmul(jax.nn.silu(gate) * up, w["w_down"])
-
-
-def route(blk, cfg: KimiK2Config, x):
-    """``noaux_tc`` routing with one group: sigmoid scores in float32
-    over all experts; the top-k of ``score + bias`` are chosen; the
-    weights are the UNBIASED scores of the chosen, normalised over all
-    of them, times ``routed_scaling_factor``. Returns (ids [N, k] —
-    global expert numbers — and weights [N, k], float32)."""
-    with jax.named_scope("moe_router"):
-        logits = jnp.dot(
-            x.astype(jnp.float32), blk["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        scores = jax.nn.sigmoid(logits)
-        _, ids = jax.lax.top_k(scores + blk["router_bias"], cfg.top_k)
-        w = jnp.take_along_axis(scores, ids, axis=-1)
-        if cfg.norm_topk:
-            w = w / (w.sum(-1, keepdims=True) + 1e-20)
-        return ids, w * cfg.routed_scale
-
-
-def held_experts(blk, cfg: KimiK2Config, x, local, weights, live):
-    """This rank's part of the routed sum: ``sum over chosen ∩ held of
-    w_i E_i(x)`` for rows ``x [N, dim]``; ``local [N, k]`` numbers the
-    chosen experts from this rank's first (outside ``0..held`` = absent). Work follows the pairs that
-    land here: an expert no live row chose is skipped (its weights are
-    not read), and in a chunk an expert computes only its own rows,
-    ``EXPERT_BLOCK`` at a time, gathered and scattered by one-hot
-    products. ``live [N]`` masks rows whose result nobody reads (frozen
-    decode rows). Returns y [N, dim] in float32."""
-    n = x.shape[0]
-    y = jnp.zeros((n, cfg.dim), jnp.float32)
-    with jax.named_scope("moe_experts"):
-        for e, w in enumerate(blk["experts"]):
-            hit = (local == e) & live[:, None]  # [N, k]
-            mine = hit.any(-1)
-            w_e = (weights * hit).sum(-1)  # [N] float32, 0 where not chosen
-            n_e = mine.sum().astype(jnp.int32)
-            if n <= EXPERT_BLOCK:
-                y = jax.lax.cond(
-                    n_e > 0,
-                    lambda y, w=w, w_e=w_e: y
-                    + swiglu(w, x).astype(jnp.float32) * w_e[:, None],
-                    lambda y: y,
-                    y,
-                )
-                continue
-            # rank of each of the expert's rows among them, in order
-            rank = jnp.cumsum(mine) - 1
-
-            def body(j, y, w=w, w_e=w_e, mine=mine, rank=rank):
-                slot = j * EXPERT_BLOCK + jnp.arange(EXPERT_BLOCK)
-                pick = (mine[None, :] & (rank[None, :] == slot[:, None]))
-                pick = pick.astype(x.dtype)  # [block, N] one-hot rows
-                out = swiglu(w, pick @ x)  # this block's rows, in order
-                back = jnp.dot(pick.T, out, preferred_element_type=jnp.float32)
-                return y + back * w_e[:, None]
-
-            blocks = (n_e + EXPERT_BLOCK - 1) // EXPERT_BLOCK
-            y = jax.lax.fori_loop(0, blocks, body, y)
-    return y
-
-
-def mlp(blk, cfg: KimiK2Config, x, live, counted):
-    """The feed-forward sublayer on normed rows ``x``. Returns (output
-    [N, dim], counters or None): for an expert layer ``(rows routed,
-    pairs that landed on held experts, rows per held expert [held])``
-    over the rows ``counted`` marks."""
-    if "dense" in blk:
-        with jax.named_scope("dense_mlp"):
-            return swiglu(blk["dense"], x), None
-    ids, weights = route(blk, cfg, x)
-    local = ids - cfg.expert_first
-    y = held_experts(blk, cfg, x, local, weights, live)
-    if "shared" in blk:
-        with jax.named_scope("moe_shared"):
-            y = y + swiglu(blk["shared"], x).astype(jnp.float32)
-    landed = (local >= 0) & (local < cfg.experts_held) & counted[:, None]
-    per_expert = (
-        (local[..., None] == jnp.arange(cfg.experts_held)) & landed[..., None]
-    ).sum((0, 1)).astype(jnp.int32)
-    return y.astype(x.dtype), (
-        counted.sum().astype(jnp.int32), landed.sum().astype(jnp.int32),
-        per_expert,
-    )
-
-
-def init_counters(cfg: KimiK2Config) -> dict:
-    """Routing counters on the device: an operand and a result of their
-    own of the window and the chunk program, donated like the pools but
-    no part of them (the cache's snapshot, restore and byte count never
-    see them). int32 that wraps; :class:`MoeCounters` adds up the
-    differences on the host."""
-    names = ("tokens", "local_pairs", "decode_ticks", "touched")
-    return {
-        # a buffer each: the programs donate them one by one
-        **{name: jnp.zeros((), jnp.int32) for name in names},
-        "expert_tokens": jnp.zeros((cfg.moe_layers, cfg.experts_held),
-                                   jnp.int32),
-    }
+        return L.mla_output(blk, cfg, ctx), pool
 
 
 def _layers(params, cfg: KimiK2Config, x, pools, stats, attend, live,
@@ -593,31 +393,14 @@ def _layers(params, cfg: KimiK2Config, x, pools, stats, attend, live,
                        lp["kv"])
         pools[str(i)] = {**lp, "kv": kv}
         x = x + a.astype(x.dtype)
-        y, counters = mlp(
+        y, counters = moe.mlp(
             blk, cfg, L.rms_norm(x, blk["ffn_norm"], cfg.norm_eps), live,
             counted,
         )
         x = x + y
-        if counters is not None:
-            tokens, pairs, per_expert = counters
-            stats["tokens"] = stats["tokens"] + tokens
-            stats["local_pairs"] = stats["local_pairs"] + pairs
-            per_layer.append(per_expert)
-            if decode:
-                stats["touched"] = stats["touched"] + (per_expert > 0).sum(
-                    dtype=jnp.int32
-                )
-    if per_layer:
-        stats["expert_tokens"] = stats["expert_tokens"] + jnp.stack(per_layer)
-        if decode:
-            stats["decode_ticks"] = stats["decode_ticks"] + counted.any(
-            ).astype(jnp.int32)
+        moe.add_layer(stats, per_layer, counters, decode)
+    moe.add_stack(stats, per_layer, counted, decode)
     return x, pools, stats
-
-
-def head_logits(params, cfg: KimiK2Config, x):
-    h = L.rms_norm(x, params["out_norm"], cfg.norm_eps)
-    return L.matmul(h, params["lm_head"]).astype(jnp.float32)
 
 
 def paged_batch_logits(params, cfg: KimiK2Config, tokens, pools, stats,
@@ -627,7 +410,7 @@ def paged_batch_logits(params, cfg: KimiK2Config, tokens, pools, stats,
     null page; a frozen row comes with position 0 and a zeroed table
     row, which is also how this step knows it: its routing is neither
     computed on nor counted). ``stats`` are the routing counters
-    (:func:`init_counters`). Returns (logits [B, vocab] f32, pools,
+    (``moe.init_counters``). Returns (logits [B, vocab] f32, pools,
     stats)."""
     cos_t, sin_t = rope_tables(cfg)
     cos, sin = cos_t[positions], sin_t[positions]
@@ -640,7 +423,7 @@ def paged_batch_logits(params, cfg: KimiK2Config, tokens, pools, stats,
 
     x, pools, stats = _layers(params, cfg, x, pools, stats, attend, live,
                               live, True)
-    return head_logits(params, cfg, x), pools, stats
+    return PM.head_logits(params, cfg, x), pools, stats
 
 
 def paged_chunk_logits(params, cfg: KimiK2Config, chunk_ids, pools, stats,
@@ -668,7 +451,7 @@ def paged_chunk_logits(params, cfg: KimiK2Config, chunk_ids, pools, stats,
 
     x, pools, stats = _layers(params, cfg, x, pools, stats, attend, live,
                               counted, False)
-    return head_logits(params, cfg, x), pools, stats
+    return PM.head_logits(params, cfg, x), pools, stats
 
 
 def fused_paged_batch_step(params, cfg, tokens, pools, stats, positions,
@@ -688,18 +471,16 @@ def fused_paged_chunk_step(params, cfg, chunk_ids, pools, stats, position,
 
 def window_program(params, cfg, k: int, eos, block: int, tokens, pools,
                    stats, *rest):
-    """The K-tick decode window (models/vlm.make_paged_window) over
-    :func:`fused_paged_batch_step`: the pools and the counters ride the
-    window's carry together and come back apart. Returns (the window's
-    own results, pools last; stats)."""
-    from dora_tpu.models import vlm as _vlm
-
+    """The K-tick decode window (models/paged_window.make_paged_window)
+    over :func:`fused_paged_batch_step`: the pools and the counters ride
+    the window's carry together and come back apart. Returns (the
+    window's own results, pools last; stats)."""
     def batch(tokens, carried, positions, bts):
         nxt, pools, stats = fused_paged_batch_step(
             params, cfg, tokens, *carried, positions, bts, block=block)
         return nxt, (pools, stats)
 
-    *out, (pools, stats) = _vlm.make_paged_window(batch, k=k, eos=eos)(
+    *out, (pools, stats) = make_paged_window(batch, k=k, eos=eos)(
         tokens, (pools, stats), *rest)
     return (*out, pools), stats
 
@@ -747,58 +528,16 @@ def default_num_pages(cfg: KimiK2Config, max_slots: int,
                2 * cfg.max_seq // page_size)
 
 
-class MoeCounters:
-    """The routing counters of one engine: the device arrays the two
-    programs take and give back (``device``), and their host side, which
-    adds up the int32 differences. :meth:`read` fetches a few hundred
-    bytes; ``llm_server``'s 1 Hz report calls it at a window boundary,
-    after ``collect()``, when the arrays are ready and nothing waits."""
-
-    def __init__(self, cfg: KimiK2Config, page_size: int):
-        self.device = init_counters(cfg)
-        #: set by :func:`make_paged_engine`: whose pages ``read`` counts
-        self.allocator = None
-        self._rows_per_page = page_size
-        self._row_bytes = page_pool_bytes(cfg, page_size) // page_size
-        self._last: dict | None = None
-        self.totals = {
-            "moe_tokens": 0, "moe_local_pairs": 0, "moe_decode_ticks": 0,
-            "moe_touched": 0,
-            "moe_expert_tokens": [0] * cfg.experts_held,
-        }
-        self._layers = max(cfg.moe_layers, 1)
-
-    def read(self) -> dict:
-        import numpy as np
-
-        now = {
-            k: np.asarray(v).astype(np.int64) for k, v in self.device.items()
-        }
-        last = self._last or {k: np.zeros_like(v) for k, v in now.items()}
-        self._last = now
-        d = {k: (now[k] - last[k]) & 0xFFFFFFFF for k in now}
-        t = self.totals
-        t["moe_tokens"] += int(d["tokens"])
-        t["moe_local_pairs"] += int(d["local_pairs"])
-        t["moe_decode_ticks"] += int(d["decode_ticks"])
-        t["moe_touched"] += int(d["touched"])
-        t["moe_expert_tokens"] = [
-            a + int(b)
-            for a, b in zip(t["moe_expert_tokens"], d["expert_tokens"].sum(0))
-        ]
-        ticks = t["moe_decode_ticks"] * self._layers
-        alloc = self.allocator
-        return {
-            "moe_tokens": t["moe_tokens"],
-            "moe_local_pairs": t["moe_local_pairs"],
-            "moe_expert_tokens": list(t["moe_expert_tokens"]),
-            "moe_experts_touched": (
-                round(t["moe_touched"] / ticks, 4) if ticks else None
-            ),
-            "latent_rows_in_use": alloc.in_use * self._rows_per_page,
-            "latent_pool_bytes": alloc.num_pages * self._rows_per_page
-            * self._row_bytes,
-        }
+def report(cfg: KimiK2Config, page_size: int, totals: dict, engine) -> dict:
+    """The gauges of one engine (``paged_model.build_engine``'s
+    ``report``): the routing counters' sums and the latent pool."""
+    alloc = engine.allocator
+    row_bytes = page_pool_bytes(cfg, page_size) // page_size
+    return {
+        **moe.report(totals, cfg.moe_layers),
+        "latent_rows_in_use": alloc.in_use * page_size,
+        "latent_pool_bytes": alloc.num_pages * page_size * row_bytes,
+    }
 
 
 def flops_per_token(cfg: KimiK2Config) -> float:
@@ -836,80 +575,30 @@ def make_paged_engine(params, cfg: KimiK2Config, *, max_slots: int = 16,
     """The paged continuous-batching engine
     (models/batch_engine.PagedBatchEngine) over the latent pool: the
     same scheduler, allocator, prefix cache and K-tick window
-    (models/vlm.make_paged_window) as the Qwen engine, with this
-    module's three closures. ``num_pages`` defaults to
-    :func:`default_num_pages`. Speculation, LoRA and int8 pages are not
-    offered for this model (KNOWN_ISSUES.md)."""
-    from dora_tpu.models.batch_engine import PagedBatchEngine
-
-    for knob, why in NOT_OFFERED.items():
-        if os.environ.get(knob, "0") not in ("", "0"):
-            raise NotImplementedError(f"kimi_k2: {knob} is not offered: {why}")
-    chunk = chunk or min(256, cfg.max_seq)
-    if attn_block is None:
-        attn_block = ATTN_BLOCK if cfg.max_seq % ATTN_BLOCK == 0 else chunk
-    assert attn_block % page_size == 0 and cfg.max_seq % attn_block == 0, (
-        attn_block, page_size, cfg.max_seq,
-    )
+    (models/paged_window.make_paged_window) as the Qwen engine, with this
+    module's two programs (``paged_model.build_engine``; the pools and
+    the routing counters are arguments 2 and 3 of both, hence the
+    donation). ``num_pages`` defaults to :func:`default_num_pages`.
+    Speculation, LoRA and int8 pages are not offered for this model
+    (KNOWN_ISSUES.md)."""
+    chunk = PM.default_chunk(chunk, cfg.max_seq)
+    attn_block = PM.default_attn_block(attn_block, ATTN_BLOCK, chunk,
+                                       cfg.max_seq, page_size)
     if num_pages is None:
         num_pages = default_num_pages(cfg, max_slots, page_size)
-    if window is None:
-        window = int(os.environ.get("DORA_MULTISTEP_K", "8"))
-    if prefix_cache is None:
-        prefix_cache = os.environ.get("DORA_PREFIX_CACHE", "0") != "0"
-    if prefix_cache_pages is None:
-        prefix_cache_pages = int(os.environ.get("DORA_PREFIX_CACHE_PAGES", "0"))
-
-    counters = MoeCounters(cfg, page_size)
-
-    # params ride as an argument, never a closed-over constant (see
-    # qwen2.make_paged_engine); the pools and the routing counters are
-    # arguments 2 and 3, hence the donation. The engine sees the pools
-    # alone: the counters stay with ``counters``.
-    def window_factory(k, sk):
-        assert not sk, "kimi_k2: no speculative window"
-
-        def program(p, *args):
-            return window_program(p, cfg, k, eos, attn_block, *args)
-
-        jitted = jax.jit(program, donate_argnums=(2, 3))
-
-        def window_step(tokens, pools, *rest):
-            out, counters.device = jitted(params, tokens, pools,
-                                          counters.device, *rest)
-            return out
-
-        return window_step
 
     def step(p, ids, pools, stats, position, bt, valid):
         return fused_paged_chunk_step(p, cfg, ids, pools, stats, position,
                                       bt, valid, block=attn_block)
 
-    chunk_jitted = jax.jit(step, donate_argnums=(2, 3))
-
-    def chunk_prefill(ids, pools, position, bt, valid):
-        greedy, pools, counters.device = chunk_jitted(
-            params, ids, pools, counters.device, position, bt, valid)
-        return greedy, pools
-
-    engine = PagedBatchEngine(
-        init_pool=lambda n: init_page_pool(cfg, n, page_size),
-        chunk_prefill=chunk_prefill,
-        chunk_valid_rows=True,
-        window_step=window_factory(window, 0),
-        window_factory=window_factory,
-        window=window,
-        max_slots=max_slots,
-        max_seq=cfg.max_seq,
-        page_size=page_size,
-        chunk=chunk,
-        num_pages=num_pages,
-        eos=eos,
-        prefix_cache=prefix_cache,
-        prefix_cache_pages=prefix_cache_pages,
-    )
-    engine.flops_per_token = flops_per_token(cfg)
-    engine.device_peak_flops = profiling.detect_peak_flops()
-    counters.allocator = engine.allocator
-    engine.model_counters = counters.read
-    return engine
+    return PM.build_engine(
+        "kimi_k2", cfg, params,
+        window_program=lambda p, k, *args: window_program(
+            p, cfg, k, eos, attn_block, *args),
+        chunk_step=step, donate_window=(2, 3), donate_chunk=(2, 3),
+        init_page_pool=lambda n: init_page_pool(cfg, n, page_size),
+        counters=moe.init_counters(cfg), report=partial(report, cfg, page_size),
+        not_offered=NOT_OFFERED, flops_per_token=flops_per_token(cfg),
+        max_slots=max_slots, eos=eos, page_size=page_size, chunk=chunk,
+        num_pages=num_pages, window=window, prefix_cache=prefix_cache,
+        prefix_cache_pages=prefix_cache_pages)

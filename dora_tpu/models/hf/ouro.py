@@ -52,25 +52,21 @@ What this module adds to the serving path:
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from dora_tpu import profiling
 from dora_tpu.models import layers as L
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
+from dora_tpu.models.paged_window import make_paged_window
+from dora_tpu.ops import decode_block as DB
 from dora_tpu.ops.int8_matmul import quantize_int8_t
 
 MODEL_TYPES = ("ouro",)
-
-#: device memory the default pool leaves free beside the weights: the
-#: two programs' temporaries, the allocator's fragmentation over 96
-#: arrays of 100 MB, and a profiler capture. Stated, not tuned: the
-#: traced run's ``memory_peak_bytes`` says how much of it is used.
-POOL_HEADROOM_BYTES = 4 << 30
 
 #: the default pool is a multiple of this many pages
 POOL_PAGE_MULTIPLE = 8
@@ -264,8 +260,6 @@ def _one_pass(params, cfg: OuroConfig, x, pools, attend):
     ``attend(x in the compute dtype, blk, layer pool) -> (float32
     sublayer output, k pool, v pool)``. Returns (the rows before the
     final norm, pools)."""
-    from dora_tpu.ops import decode_block as DB
-
     dtype = L.compute_dtype()
     eps = cfg.norm_eps
     pools = dict(pools)
@@ -345,10 +339,6 @@ def _shape(cfg: OuroConfig) -> dict:
             "head_dim": cfg.head_dim, "eps": cfg.norm_eps, "residual": False}
 
 
-def _count(stats, **adds):
-    return {k: v + adds.get(k, 0) for k, v in stats.items()}
-
-
 def paged_batch_rows(params, cfg: OuroConfig, tokens, pools, stats, positions,
                      block_tables):
     """One decode step for B = slots independent sequences: tokens and
@@ -357,8 +347,6 @@ def paged_batch_rows(params, cfg: OuroConfig, tokens, pools, stats, positions,
     step knows it: its K/V writes land in each pass's null page and it
     is not counted). Returns (the last pass's rows before the final
     norm, the normed rows, pools, stats, lambdas)."""
-    from dora_tpu.ops import decode_block as DB
-
     cos_t, sin_t = L.rope_table(cfg.max_seq, cfg.head_dim, base=cfg.rope_theta)
     cos_rows, sin_rows = DB.rope_rows_at(cos_t, sin_t, positions)
     x = params["embed"][tokens].astype(jnp.float32)
@@ -377,7 +365,7 @@ def paged_batch_rows(params, cfg: OuroConfig, tokens, pools, stats, positions,
     # group is DB's page group of cache rows, the current token is none
     group = DB.sweep_group_rows(
         next(iter(pools.values()))["k"].shape[2], block_tables.shape[1])
-    stats = _count(
+    stats = PM.add_counts(
         stats, passes=cfg.passes * n_live,
         kv_rows_read=cfg.passes * jnp.where(live, positions + 1, 0).sum(
             dtype=jnp.int32),
@@ -396,8 +384,6 @@ def paged_chunk_rows(params, cfg: OuroConfig, chunk_ids, pools, stats,
     whole pages of its own and attends causally over them and the
     context before. ``position`` and ``valid`` are traced: one program
     for every chunk."""
-    from dora_tpu.ops import decode_block as DB
-
     c = chunk_ids.shape[0]
     cos_t, sin_t = L.rope_table(cfg.max_seq, cfg.head_dim, base=cfg.rope_theta)
     cos_rows, sin_rows = DB.rope_rows(cos_t, sin_t, position, c)
@@ -412,7 +398,7 @@ def paged_chunk_rows(params, cfg: OuroConfig, chunk_ids, pools, stats,
     raw, h, pools, lambdas, before_last = looped(
         params, cfg, x, pools, block_table, attend)
     prompt = jnp.arange(c) < valid
-    stats = _count(
+    stats = PM.add_counts(
         stats, chunk_rows=valid.astype(jnp.int32), chunks=1,
         chunk_positions=jnp.asarray(position, jnp.int32),
         exit_before_last=(prompt & before_last).sum(dtype=jnp.int32))
@@ -428,8 +414,6 @@ def head_logits(params, h):
 def head_argmax(params, cfg: OuroConfig, raw):
     """The streamed head over the last pass's rows: the kernel applies
     the final norm itself, so it is given the rows before it."""
-    from dora_tpu.ops import decode_block as DB
-
     w = params["lm_head"]
     return DB.lm_head_argmax(raw.astype(L.compute_dtype()), params["out_norm"],
                              w["int8"], w["scale"], eps=cfg.norm_eps)
@@ -459,18 +443,16 @@ def fused_paged_chunk_step(params, cfg, *args):
 
 
 def window_program(params, cfg, k: int, eos, tokens, pools, stats, *rest):
-    """The K-tick decode window (models/vlm.make_paged_window) over
-    :func:`fused_paged_batch_step`: the pools and the counters ride the
-    window's carry together and come back apart. Returns (the window's
-    own results, pools last; stats)."""
-    from dora_tpu.models import vlm as _vlm
-
+    """The K-tick decode window (models/paged_window.make_paged_window)
+    over :func:`fused_paged_batch_step`: the pools and the counters ride
+    the window's carry together and come back apart. Returns (the
+    window's own results, pools last; stats)."""
     def batch(tokens, carried, positions, bts):
         nxt, pools, stats = fused_paged_batch_step(
             params, cfg, tokens, *carried, positions, bts)
         return nxt, (pools, stats)
 
-    *out, (pools, stats) = _vlm.make_paged_window(batch, k=k, eos=eos)(
+    *out, (pools, stats) = make_paged_window(batch, k=k, eos=eos)(
         tokens, (pools, stats), *rest)
     return (*out, pools), stats
 
@@ -508,70 +490,34 @@ def page_pool_bytes(cfg: OuroConfig, page_size: int) -> int:
     return page_size * cfg.kv_bytes_per_token
 
 
-def pages_that_fit(cfg: OuroConfig, limit: int, used: int, max_slots: int,
-                   page_size: int) -> int:
-    """The rule of :func:`default_num_pages`, in plain numbers."""
-    fits = (limit - used - POOL_HEADROOM_BYTES) // page_pool_bytes(
-        cfg, page_size)
-    fits -= fits % POOL_PAGE_MULTIPLE
-    return int(max(min(max_slots * cfg.max_seq // page_size + 1, fits),
-                   2 * cfg.max_seq // page_size))
-
-
 def default_num_pages(cfg: OuroConfig, max_slots: int, page_size: int) -> int:
-    """The pool's default size, a rule in bytes: what the device has
-    (``bytes_limit``), less what is in use now (the weights, loaded
-    before the engine is built), less :data:`POOL_HEADROOM_BYTES`, in
-    whole pages, rounded down to a multiple of
-    :data:`POOL_PAGE_MULTIPLE`; never more than every slot reaching
-    ``max_seq``, never fewer than two streams' worth. At Ouro-2.6B on a
-    16 GB v5e: (16.91 - 2.77 - 4.29) GB / 25,165,824 B = 391 -> 384
-    pages, 9.66 GB. Where the device reports no memory figures (the
-    CPU) the Qwen engine's ``4 * max_seq`` rows."""
-    stats = jax.devices()[0].memory_stats() or {}
-    limit, used = stats.get("bytes_limit"), stats.get("bytes_in_use")
-    if not limit or used is None:
-        return 4 * cfg.max_seq // page_size
-    return pages_that_fit(cfg, limit, used, max_slots, page_size)
+    """The pool's default size, ``paged_model.default_num_pages``' rule in
+    bytes in multiples of :data:`POOL_PAGE_MULTIPLE`: here the cache, not
+    the weights, is the largest thing on the chip. At Ouro-2.6B on a 16
+    GB v5e: (16.91 - 2.77 - 4.29) GB / 25,165,824 B = 391 -> 384 pages,
+    9.66 GB."""
+    return PM.default_num_pages(
+        page_pool_bytes(cfg, page_size), max_slots, cfg.max_seq, page_size,
+        multiple=POOL_PAGE_MULTIPLE)
 
 
 def init_counters() -> dict:
     """The loop's counters on the device: an operand and a result of
-    their own of both programs, int32 that wraps; :class:`LoopCounters`
-    adds up the differences."""
+    their own of both programs, int32 that wraps;
+    ``paged_model.DeviceCounters`` adds up the differences."""
     return {name: jnp.zeros((), jnp.int32) for name in COUNTERS}
 
 
-class LoopCounters:
-    """The loop's counters of one engine: the device arrays the two
-    programs take and give back (``device``) and their host side.
-    :meth:`read` fetches eight scalars; ``llm_server``'s 1 Hz report
-    calls it at a window boundary, after ``collect()``."""
-
-    def __init__(self, cfg: OuroConfig, page_size: int):
-        self.device = init_counters()
-        #: set by :func:`make_paged_engine`: whose pages ``read`` counts
-        self.allocator = None
-        self._token_bytes = cfg.kv_bytes_per_token
-        self._page_bytes = page_pool_bytes(cfg, page_size)
-        self._last: dict | None = None
-        self.totals = {f"loop_{k}": 0 for k in COUNTERS}
-
-    def read(self) -> dict:
-        import numpy as np
-
-        now = {k: int(np.asarray(v)) for k, v in self.device.items()}
-        last = self._last or dict.fromkeys(now, 0)
-        self._last = now
-        for k in now:
-            self.totals[f"loop_{k}"] += (now[k] - last[k]) & 0xFFFFFFFF
-        alloc = self.allocator
-        return {
-            **self.totals,
-            "kv_bytes_per_token": self._token_bytes,
-            "kv_pool_bytes": alloc.num_pages * self._page_bytes,
-            "kv_pages_free": alloc.free_pages,
-        }
+def report(cfg: OuroConfig, page_size: int, totals: dict, engine) -> dict:
+    """The gauges of one engine (``paged_model.build_engine``'s
+    ``report``): the loop's counters' sums and the pool."""
+    alloc = engine.allocator
+    return {
+        **{f"loop_{k}": int(totals[k]) for k in COUNTERS},
+        "kv_bytes_per_token": cfg.kv_bytes_per_token,
+        "kv_pool_bytes": alloc.num_pages * page_pool_bytes(cfg, page_size),
+        "kv_pages_free": alloc.free_pages,
+    }
 
 
 def flops_per_token(cfg: OuroConfig) -> float:
@@ -592,75 +538,28 @@ def make_paged_engine(params, cfg: OuroConfig, *, max_slots: int = 16,
     """The paged continuous-batching engine
     (models/batch_engine.PagedBatchEngine) over the looped pool: the
     same scheduler, allocator, prefix cache and K-tick window
-    (models/vlm.make_paged_window) as the Qwen engine, with this
-    module's closures. ``num_pages`` defaults to
-    :func:`default_num_pages`. Speculation, LoRA and int8 pages are not
-    offered for this model (KNOWN_ISSUES.md)."""
-    from dora_tpu.models.batch_engine import PagedBatchEngine
-
-    for knob, why in NOT_OFFERED.items():
-        if os.environ.get(knob, "0") not in ("", "0"):
-            raise NotImplementedError(f"ouro: {knob} is not offered: {why}")
-    chunk = chunk or min(256, cfg.max_seq)
+    (models/paged_window.make_paged_window) as the Qwen engine, with this
+    module's two programs (``paged_model.build_engine``; the pools and
+    the counters are arguments 2 and 3 of both, hence the donation).
+    ``num_pages`` defaults to :func:`default_num_pages`. Speculation,
+    LoRA and int8 pages are not offered for this model
+    (KNOWN_ISSUES.md)."""
     if num_pages is None:
         num_pages = default_num_pages(cfg, max_slots, page_size)
-    if window is None:
-        window = int(os.environ.get("DORA_MULTISTEP_K", "8"))
-    if prefix_cache is None:
-        prefix_cache = os.environ.get("DORA_PREFIX_CACHE", "0") != "0"
-    if prefix_cache_pages is None:
-        prefix_cache_pages = int(os.environ.get("DORA_PREFIX_CACHE_PAGES", "0"))
-
-    counters = LoopCounters(cfg, page_size)
-
-    # params ride as an argument, never a closed-over constant (see
-    # qwen2.make_paged_engine); the pools and the counters are arguments
-    # 2 and 3, hence the donation. The engine sees the pools alone: the
-    # counters stay with ``counters``.
-    def window_factory(k, sk):
-        assert not sk, "ouro: no speculative window"
-
-        def program(p, *args):
-            return window_program(p, cfg, k, eos, *args)
-
-        jitted = jax.jit(program, donate_argnums=(2, 3))
-
-        def window_step(tokens, pools, *rest):
-            out, counters.device = jitted(params, tokens, pools,
-                                          counters.device, *rest)
-            return out
-
-        return window_step
 
     def step(p, ids, pools, stats, position, bt, valid):
         return fused_paged_chunk_step(p, cfg, ids, pools, stats, position,
                                       bt, valid)
 
-    chunk_jitted = jax.jit(step, donate_argnums=(2, 3))
-
-    def chunk_prefill(ids, pools, position, bt, valid):
-        greedy, pools, counters.device = chunk_jitted(
-            params, ids, pools, counters.device, position, bt, valid)
-        return greedy, pools
-
-    engine = PagedBatchEngine(
-        init_pool=lambda n: init_page_pool(cfg, n, page_size),
-        chunk_prefill=chunk_prefill,
-        chunk_valid_rows=True,
-        window_step=window_factory(window, 0),
-        window_factory=window_factory,
-        window=window,
-        max_slots=max_slots,
-        max_seq=cfg.max_seq,
-        page_size=page_size,
-        chunk=chunk,
-        num_pages=num_pages,
-        eos=eos,
-        prefix_cache=prefix_cache,
-        prefix_cache_pages=prefix_cache_pages,
-    )
-    engine.flops_per_token = flops_per_token(cfg)
-    engine.device_peak_flops = profiling.detect_peak_flops()
-    counters.allocator = engine.allocator
-    engine.model_counters = counters.read
-    return engine
+    return PM.build_engine(
+        "ouro", cfg, params,
+        window_program=lambda p, k, *args: window_program(
+            p, cfg, k, eos, *args),
+        chunk_step=step, donate_window=(2, 3), donate_chunk=(2, 3),
+        init_page_pool=lambda n: init_page_pool(cfg, n, page_size),
+        counters=init_counters(), report=partial(report, cfg, page_size),
+        not_offered=NOT_OFFERED, flops_per_token=flops_per_token(cfg),
+        max_slots=max_slots, eos=eos, page_size=page_size,
+        chunk=PM.default_chunk(chunk, cfg.max_seq), num_pages=num_pages,
+        window=window, prefix_cache=prefix_cache,
+        prefix_cache_pages=prefix_cache_pages)

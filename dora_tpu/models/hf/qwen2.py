@@ -26,6 +26,10 @@ from dora_tpu.models.hf.loader import (
     read_config,
     read_safetensors,
 )
+from dora_tpu.models.paged_window import (
+    make_paged_spec_window,
+    make_paged_window,
+)
 
 
 #: What the chip's compiler says to the three int8-KV paged kernels
@@ -38,7 +42,7 @@ KV_INT8_REFUSED = (
     "must be aligned to tiling (128), but is 8' (16 for the chunk kernel). "
     "The planes' minor dimension (the page, 16 rows) is narrower than a "
     "128-lane tile, so even a whole-page slice is refused; they need a "
-    "lane-dense layout (KNOWN_ISSUES.md, ROADMAP speed item 5). Serve "
+    "lane-dense layout (KNOWN_ISSUES.md, ROADMAP speed item 9). Serve "
     "with fp KV pages on the chip."
 )
 
@@ -224,7 +228,7 @@ def fused_paged_spec_step(params, cfg, chunks, pools, positions,
     the caller's acceptance test over (greedy, drafts) replays the
     serial spec_decode contract exactly. Returns (greedy [B, m],
     pools). The spec window's inner step
-    (models/vlm.make_paged_spec_window)."""
+    (models/paged_window.make_paged_spec_window)."""
     from dora_tpu.models import vlm as _vlm
     from dora_tpu.ops import decode_block as DB
 
@@ -394,7 +398,7 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
 
     ``window`` is the multi-step decode window K (default: env
     ``DORA_MULTISTEP_K``, else 8): each engine step runs K fused decode
-    ticks in ONE jitted device program (models/vlm.make_paged_window)
+    ticks in ONE jitted device program (models/paged_window.make_paged_window)
     and fetches one [B, K+1] token matrix, amortizing host dispatch and
     device->host fetch cost across K tokens. ``window=1`` is the
     per-token dispatch behavior of the pre-window engine, same greedy
@@ -402,7 +406,7 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
 
     ``spec_k`` (default: env ``DORA_SPEC_K``, else 0 = off) folds
     prompt-lookup speculation INTO each window tick
-    (models/vlm.make_paged_spec_window): per tick every stream drafts
+    (models/paged_window.make_paged_spec_window): per tick every stream drafts
     ``spec_k`` tokens by trailing-ngram lookup (``spec_ngram``, env
     ``DORA_SPEC_NGRAM``, default 2) and one batched verification pass
     checks them all — up to ``window * (spec_k + 1)`` tokens per
@@ -473,11 +477,10 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
         )
 
     # Every program takes ``params`` as its FIRST ARGUMENT and the engine
-    # gets ``partial(program, params)``: a closed-over array lowers to a
-    # constant, which would bake gigabytes of weights into the window
-    # program, the chunk program and every autotune rung (compile time,
-    # HBM per executable and compile-cache entry size all scale with it).
-    # Pools are argument 2 of the jitted callable, hence the donation.
+    # gets ``partial(program, params)``, never a closed-over constant
+    # (why: paged_model.build_engine, which wires the other five
+    # families the same way). Pools are argument 2 of the jitted
+    # callable, hence the donation.
     has_lora = lora_pool is not None
 
     def with_lora(step_fn):
@@ -497,11 +500,11 @@ def make_paged_engine(params, cfg: Qwen2Config, *, max_slots: int = 16,
         # once per process.
         if sk:
             step = with_lora(fused_paged_spec_step)
-            make = partial(_vlm.make_paged_spec_window, k=k, spec_k=sk,
+            make = partial(make_paged_spec_window, k=k, spec_k=sk,
                            ngram=spec_ngram, eos=eos, lora=has_lora)
         else:
             step = with_lora(fused_paged_batch_step)
-            make = partial(_vlm.make_paged_window, k=k, eos=eos,
+            make = partial(make_paged_window, k=k, eos=eos,
                            lora=has_lora)
 
         def program(p, *args):

@@ -227,6 +227,91 @@ def attend_blocks(q, fetch, visible, n_blocks, score, mix, *, scale: float,
     return acc / l[..., None]
 
 
+def rotate_half(x, cos, sin):
+    """Rotate-half rotary over the whole head: ``x [..., hd]``, ``cos``
+    / ``sin`` ``[..., hd/2]`` broadcastable to the halves."""
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def kv_rows(cfg, k, v):
+    """Keys and values ``[N, KV, hd]`` -> the rows as a ``[P, page, 2 * KV
+    * hd]`` pool caches them: a position's keys, then its values
+    (``cfg.kv_width`` = KV * hd)."""
+    n = k.shape[0]
+    return jnp.concatenate(
+        [k.reshape(n, cfg.kv_width), v.reshape(n, cfg.kv_width)], axis=-1)
+
+
+def attend_kv_blocks(cfg, q, kv_of, visible, n_blocks, score: str, mix: str):
+    """:func:`attend_blocks` of ``q [..., KV, G, hd]`` over pool rows:
+    ``kv_of(j)`` gives block ``j``'s keys and values (``[..., block, KV,
+    hd]`` each); ``score`` and ``mix`` are the einsums of queries with
+    keys and of probabilities with values. Returns ``[..., KV, G, hd]``
+    float32."""
+    f32 = {"preferred_element_type": jnp.float32}
+    return attend_blocks(
+        q, kv_of, visible, n_blocks,
+        lambda q, kv: jnp.einsum(score, q, kv[0], **f32),
+        lambda p, kv: jnp.einsum(mix, p.astype(kv[1].dtype), kv[1], **f32),
+        scale=cfg.head_dim ** -0.5, width=cfg.head_dim,
+    )
+
+
+def attend_latent_blocks(cfg, q, rows_of, visible, n_blocks, score: str,
+                         mix: str):
+    """:func:`attend_blocks` of absorbed MLA queries ``q [..., row]`` over
+    latent rows: ``rows_of(j)`` gives block ``j``'s rows (``[..., block,
+    row]``, the first ``cfg.kv_rank`` of each its ``c_kv``); ``score``
+    and ``mix`` are the einsums of queries with rows and of probabilities
+    with rows. Returns the softmax-weighted ``c_kv`` ``[..., kv_rank]``
+    in float32, under ``cfg.softmax_scale``."""
+    f32 = {"preferred_element_type": jnp.float32}
+    return attend_blocks(
+        q, rows_of, visible, n_blocks,
+        lambda q, kv: jnp.einsum(score, q, kv, **f32),
+        lambda p, kv: jnp.einsum(
+            mix, p.astype(kv.dtype), kv[..., : cfg.kv_rank], **f32),
+        scale=cfg.softmax_scale, width=cfg.kv_rank,
+    )
+
+
+def mla_kv_b_weights(kvb: dict, cfg) -> dict:
+    """``W_kvb`` (int8 ``[kv_rank, H * (nope + v)]`` with per-column
+    scales) per head, split for the absorbed form: the key part ``"k8"
+    [H, nope, kv_rank]`` folds into the query, the value part ``"v8" [H,
+    kv_rank, v]`` into the output (:func:`mla_output`); the scales
+    ``"ks"`` / ``"vs"`` are per (head, column)."""
+    h, nope = cfg.heads, cfg.nope
+    kvb8 = kvb["int8"].reshape(cfg.kv_rank, h, nope + cfg.v_dim)
+    kvbs = kvb["scale"].reshape(h, nope + cfg.v_dim)
+    return {
+        "k8": jnp.transpose(kvb8[:, :, :nope], (1, 2, 0)),
+        "ks": kvbs[:, :nope],
+        "v8": jnp.transpose(kvb8[:, :, nope:], (1, 0, 2)),
+        "vs": kvbs[:, nope:],
+    }
+
+
+def mla_output(blk, cfg, ctx):
+    """``ctx [N, H, kv_rank]`` (softmax-weighted latent rows, float32)
+    -> an absorbed MLA sublayer's output ``[N, dim]``: the value half of
+    ``W_kvb`` per head (``blk["w_kv_b"]``: int8 ``"v8" [H, kv_rank, v]``,
+    scales ``"vs"``), then ``blk["wo"]``."""
+    kb = blk["w_kv_b"]
+    dtype = compute_dtype()
+    o = jnp.einsum(
+        "nhc,hcj->nhj", ctx.astype(dtype), kb["v8"].astype(dtype),
+        preferred_element_type=jnp.float32,
+    ) * kb["vs"]
+    return matmul(
+        o.astype(dtype).reshape(ctx.shape[0], cfg.heads * cfg.v_dim),
+        blk["wo"],
+    )
+
+
 def use_flash() -> bool:
     """Flash attention for the no-cache self-attention paths (see
     dora_tpu.ops.flash_attention). Default ON on TPU (the kernel's VMEM

@@ -240,7 +240,7 @@ def _tiny_exaone(tmp_path):
 
 
 def _tiny_glm5(tmp_path):
-    from test_glm5_next import TINY, write_checkpoint
+    from tests.glm5_next_tiny import TINY, write_checkpoint
 
     from dora_tpu.models.hf import glm5_next
 

@@ -304,6 +304,7 @@ def _kimi():
     """Config and parameter shapes of rank 0 of 32 (12 experts a layer) at
     Kimi-K2.5's widths, depth cut to the dense layer and one expert
     layer, as ``kimi_k2.load`` builds them (int8 from the start)."""
+    from dora_tpu.models import moe
     from dora_tpu.models.hf import kimi_k2
 
     cfg = kimi_k2.KimiK2Config.from_hf(KIMI_HF, max_seq=KIMI_SEQ)
@@ -342,7 +343,7 @@ def _kimi():
 
     pools = jax.eval_shape(
         lambda: kimi_k2.init_page_pool(cfg, SLOTS * KIMI_SEQ // PAGE, PAGE))
-    stats = jax.eval_shape(lambda: kimi_k2.init_counters(cfg))
+    stats = jax.eval_shape(lambda: moe.init_counters(cfg))
     return kimi_k2, cfg, jax.eval_shape(build), pools, stats
 
 

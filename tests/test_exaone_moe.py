@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf import exaone_moe as E
 from dora_tpu.models.hf import exaone_moe_reference as R
 from dora_tpu.ops import decode_block as DB
@@ -351,7 +352,7 @@ def test_the_shares_add_up_to_the_uncut_layer(ckpt):
     """The routed parts of all ``ep_size`` shares plus the shared expert
     once equal the uncut reference's expert layer, in the program
     (``kimi_k2.mlp`` under this config) and in the reference."""
-    from dora_tpu.models.hf import kimi_k2 as K
+    from dora_tpu.models import moe as K
 
     x = jnp.asarray(np.random.default_rng(3).standard_normal((24, 64)),
                     jnp.float32)
@@ -599,10 +600,14 @@ def test_the_pool_rule_in_bytes(model):
     token = big.kv_bytes_per_token  # float32 on the CPU: twice bf16's 8,192
     assert token == 2 * 2 * 1024 * jnp.dtype(E.L.compute_dtype()).itemsize
     limit, used = 16_909_336_064, 6_200_000_000
-    assert E.pages_that_fit(big, limit, used, 16, 16) == min(
+    def fit(limit, used):
+        return PM.pages_that_fit(E.page_pool_bytes(big, 16), limit, used, 16,
+                                 big.max_seq, 16)
+
+    assert fit(limit, used) == min(
         16 * 16384 // 16 + 1, (limit - used - (4 << 30)) // (16 * token))
-    assert E.pages_that_fit(big, 8 << 30, 3 << 30, 16, 16) == (1 << 30) // (16 * token)
-    assert E.pages_that_fit(big, 8 << 30, 6 << 30, 16, 16) == 2 * 16384 // 16
+    assert fit(8 << 30, 3 << 30) == (1 << 30) // (16 * token)
+    assert fit(8 << 30, 6 << 30) == 2 * 16384 // 16
 
 
 # (g) no weight is copied or closed over in the two programs:

@@ -23,234 +23,22 @@ the logits past float32 noise; test (f) holds the published value.
 
 from __future__ import annotations
 
-import functools
 import json
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf import glm5_next as G
 from dora_tpu.models.hf import glm5_next_reference as R
-
-TOL = 3e-4
-TOPK, KPOOL, PAGE, CHUNK, BLOCK, K_TICKS, SLOTS, MAX_SEQ = 16, 4, 8, 32, 16, 4, 3, 128
-KINDS = ["linear_attention"] * 4 + ["deepseek_sparse_attention"]
-
-TINY = dict(
-    model_type="glm5_next_text", hidden_size=64, num_attention_heads=4,
-    num_key_value_heads=4, head_dim=0, intermediate_size=128,
-    moe_intermediate_size=32, num_hidden_layers=5, vocab_size=128,
-    rms_norm_eps=1e-5, max_position_embeddings=MAX_SEQ,
-    layer_types=KINDS, mlp_layer_types=["dense"] + ["sparse"] * 4,
-    first_k_dense_replace=1, indexer_types=["full"] * 5,
-    linear_attn_config={
-        "num_heads": 4, "head_dim": 16, "short_conv_kernel_size": 4,
-        "gate_lower_bound": -5, "kda_layers": [0, 1, 2, 3],
-        "full_attn_layers": [4]},
-    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16, qk_head_dim=16,
-    qk_rope_head_dim=0, v_head_dim=16, mla_use_nope=True,
-    index_n_heads=2, index_head_dim=8, index_topk=TOPK, index_kpool=KPOOL,
-    index_kpool_compress=True, index_kpool_always_select_tail=True,
-    hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-2, mhc=True,
-    n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
-    routed_scaling_factor=2.5, norm_topk_prob=True, scoring_func="sigmoid",
-    topk_method="noaux_tc", n_group=1, topk_group=1, swiglu_limit=1.0,
-    ep_size=4, tie_word_embeddings=False, num_nextn_predict_layers=0,
-    attention_bias=False,
+from tests.glm5_next_tiny import (  # noqa: F401  (ckpt, model: fixtures)
+    BLOCK, CHUNK, KINDS, KPOOL, MAX_SEQ, SLOTS, TINY, TOL, TOPK, Served,
+    ckpt, held_of, make_engine, model, prompt_ids, reference_logits,
 )
 
 
-def write_checkpoint(path: Path, cfg: dict, seed: int = 0) -> None:
-    """A whole (all experts) float32 checkpoint under the HF names."""
-    from safetensors.numpy import save_file
-
-    rng = np.random.default_rng(seed)
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    lin = cfg["linear_attn_config"]
-    kh, kd, taps = lin["num_heads"], lin["head_dim"], lin["short_conv_kernel_size"]
-    n = cfg["hc_mult"]
-    t: dict[str, np.ndarray] = {}
-
-    def w(out, inp, scale=None):
-        return (rng.standard_normal((out, inp)) * (scale or inp ** -0.5)
-                ).astype(np.float32)
-
-    def vec(size, scale=1.0, mean=0.0):
-        return (mean + scale * rng.standard_normal(size)).astype(np.float32)
-
-    def ffn(prefix, width):
-        t[prefix + "gate_proj.weight"] = w(width, d, 2.0 * d ** -0.5)
-        t[prefix + "up_proj.weight"] = w(width, d, 2.0 * d ** -0.5)
-        t[prefix + "down_proj.weight"] = w(d, width)
-
-    t["model.embed_tokens.weight"] = w(cfg["vocab_size"], d, 1.0)
-    t["model.norm.weight"] = vec(d, 0.1, 1.0)
-    t["lm_head.weight"] = w(cfg["vocab_size"], d)
-    for i in range(cfg["num_hidden_layers"]):
-        p = f"model.layers.{i}."
-        t[p + "input_layernorm.weight"] = vec(d, 0.1, 1.0)
-        t[p + "post_attention_layernorm.weight"] = vec(d, 0.1, 1.0)
-        for sub in ("attn", "ffn"):
-            t[p + f"hc_{sub}_fn"] = w(2 * n + n * n, n * d)
-            t[p + f"hc_{sub}_base"] = vec(2 * n + n * n, 0.5)
-            t[p + f"hc_{sub}_scale"] = vec(3, 0.2, 1.0)
-        a, m = p + "self_attn.", p + "mlp."
-        if cfg["layer_types"][i] == "linear_attention":
-            for name in "qkv":
-                t[a + f"{name}_proj.weight"] = w(kh * kd, d)
-                t[a + f"{name}_conv1d.weight"] = w(kh * kd, taps, 0.5).reshape(
-                    kh * kd, 1, taps)
-            t[a + "f_a_proj.weight"] = w(kd, d)
-            t[a + "f_b_proj.weight"] = w(kh * kd, kd, 2.0 * kd ** -0.5)
-            t[a + "g_a_proj.weight"] = w(kd, d)
-            t[a + "g_b_proj.weight"] = w(kh * kd, kd)
-            t[a + "b_proj.weight"] = w(kh, d)
-            t[a + "A_log"] = vec(kh, 0.3)
-            t[a + "dt_bias"] = vec(kh * kd, 1.0)
-            t[a + "o_norm.weight"] = vec(kd, 0.1, 1.0)
-            t[a + "o_proj.weight"] = w(d, kh * kd)
-        else:
-            qr, kvr = cfg["q_lora_rank"], cfg["kv_lora_rank"]
-            nope, v = cfg["qk_nope_head_dim"], cfg["v_head_dim"]
-            ih, idim = cfg["index_n_heads"], cfg["index_head_dim"]
-            t[a + "q_a_proj.weight"] = w(qr, d)
-            t[a + "q_a_layernorm.weight"] = vec(qr, 0.1, 1.0)
-            t[a + "q_b_proj.weight"] = w(h * nope, qr)
-            t[a + "kv_a_proj_with_mqa.weight"] = w(kvr, d)
-            t[a + "kv_a_layernorm.weight"] = vec(kvr, 0.1, 1.0)
-            t[a + "kv_b_proj.weight"] = w(h * (nope + v), kvr)
-            t[a + "o_proj.weight"] = w(d, h * v)
-            t[a + "indexer.wq_b.weight"] = w(ih * idim, qr)
-            t[a + "indexer.wk.weight"] = w(idim, d)
-            t[a + "indexer.k_norm.weight"] = vec(idim, 0.1, 1.0)
-            t[a + "indexer.k_norm.bias"] = vec(idim, 0.1)
-            t[a + "indexer.weights_proj.weight"] = w(ih, d)
-        if cfg["mlp_layer_types"][i] == "dense":
-            ffn(m, cfg["intermediate_size"])
-            continue
-        t[m + "gate.weight"] = w(cfg["n_routed_experts"], d)
-        t[m + "gate.e_score_correction_bias"] = vec(cfg["n_routed_experts"], 0.1)
-        ffn(m + "shared_experts.", cfg["moe_intermediate_size"])
-        for e in range(cfg["n_routed_experts"]):
-            ffn(f"{m}experts.{e}.", cfg["moe_intermediate_size"])
-    path.mkdir(parents=True, exist_ok=True)
-    save_file(t, str(path / "model.safetensors"))
-    (path / "config.json").write_text(json.dumps(cfg))
-
-
-@pytest.fixture(scope="module")
-def ckpt(tmp_path_factory) -> Path:
-    path = tmp_path_factory.mktemp("glm5") / "ckpt"
-    write_checkpoint(path, TINY)
-    return path
-
-
-@pytest.fixture(scope="module")
-def model(ckpt):
-    """Rank 0's share (experts 0-1 of 8): (cfg, params, reference params)."""
-    cfg, params = G.load(ckpt, max_seq=MAX_SEQ, ep_rank=0)
-    return cfg, params, R.reference_params(params, cfg)
-
-
-def prompt_ids(n: int, seed: int = 1) -> list[int]:
-    return np.random.default_rng(seed).integers(1, 128, size=n).tolist()
-
-
-def make_engine(cfg, params, **kw):
-    kw = {"max_slots": SLOTS, "page_size": PAGE, "chunk": CHUNK,
-          "window": K_TICKS, "attn_block": BLOCK, **kw}
-    return G.make_paged_engine(params, cfg, **kw)
-
-
-@functools.lru_cache(maxsize=None)
-def programs(cfg):
-    """The two programs as the engine jits them, but with logits where
-    the greedy tokens would be (cfg is static; one trace a config)."""
-    return (
-        jax.jit(lambda p, *a: G.paged_chunk_logits(p, cfg, *a, block=BLOCK,
-                                                   picks=True)),
-        jax.jit(lambda p, *a: G.paged_batch_logits(p, cfg, *a, picks=True)),
-    )
-
-
-class Served:
-    """What the engine does, by hand, keeping the logits: pools, slot
-    state and counters of ``SLOTS`` slots, each stream with pages of its
-    own. ``dirty``: every slot-state leaf starts as an earlier stream
-    left it (a chunk at position 0 must zero-start)."""
-
-    def __init__(self, cfg, params, chunk: int = CHUNK, dirty: bool = True):
-        self.cfg, self.params, self.chunk = cfg, params, chunk
-        self.chunk_fn, self.tick_fn = programs(cfg)
-        pages = SLOTS * MAX_SEQ // PAGE + 1
-        self.pools = G.init_page_pool(cfg, pages, PAGE)
-        self.state = G.init_slot_state(cfg, SLOTS)
-        if dirty:
-            self.state = jax.tree.map(lambda a: a + 3.0, self.state)
-            self.pools = jax.tree.map(lambda a: a + 2.0, self.pools)
-        self.stats = G.init_counters(cfg)
-        per = MAX_SEQ // PAGE
-        self.bts = np.zeros((SLOTS, per), np.int32)
-        for b in range(SLOTS):
-            self.bts[b] = 1 + b * per + np.arange(per)
-        self.positions = np.zeros((SLOTS,), np.int32)
-        self.picked = {}  # slot -> [T, picked_blocks] of the chunks
-        self.ticked = {}  # slot -> [[picked_blocks] a decode tick]
-
-    def prefill(self, slot: int, prompt: list[int], pad_id: int = 0):
-        """Chunked prefill into ``slot``; the prompt's logits [T, vocab]."""
-        out, picked = [], []
-        for base in range(0, len(prompt), self.chunk):
-            piece = prompt[base : base + self.chunk]
-            ids = piece + [pad_id] * (self.chunk - len(piece))
-            logits, self.pools, self.state, self.stats, picks = self.chunk_fn(
-                self.params, jnp.asarray(ids, jnp.int32), self.pools,
-                self.state, self.stats, jnp.asarray(base, jnp.int32),
-                jnp.asarray(self.bts[slot]), jnp.asarray(len(piece), jnp.int32),
-                jnp.asarray(slot, jnp.int32))
-            out.append(np.asarray(logits)[: len(piece)])
-            picked.append(np.asarray(picks[0]["picked"])[: len(piece)])
-        self.positions[slot] = len(prompt)
-        self.picked[slot] = np.concatenate(picked)
-        return np.concatenate(out)
-
-    def tick(self, tokens: dict[int, int]):
-        """One decode tick: ``tokens`` = slot -> its next input token;
-        the other rows are frozen (position 0, zeroed table row). ->
-        slot -> logits [vocab]."""
-        active = np.zeros((SLOTS,), bool)
-        toks = np.zeros((SLOTS,), np.int32)
-        for b, tok in tokens.items():
-            active[b], toks[b] = True, tok
-        pos = np.where(active, self.positions, 0).astype(np.int32)
-        bts = np.where(active[:, None], self.bts, 0).astype(np.int32)
-        logits, self.pools, self.state, self.stats, picks = self.tick_fn(
-            self.params, jnp.asarray(toks), self.pools, self.state, self.stats,
-            jnp.asarray(pos), jnp.asarray(bts), jnp.asarray(active))
-        for b in tokens:
-            self.ticked.setdefault(b, []).append(np.asarray(picks[0]["picked"][b]))
-        self.positions[active] += 1
-        return {b: np.asarray(logits[b]) for b in tokens}
-
-    def serve(self, slot: int, prompt: list[int], emitted: list[int]):
-        """Prefill then teacher-forced decode: logits [T + E, vocab]."""
-        rows = [self.prefill(slot, prompt)]
-        for tok in emitted:
-            rows.append(self.tick({slot: tok})[slot][None])
-        return np.concatenate(rows)
-
-
-def held_of(cfg):
-    return range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
-
-
-def reference_logits(model, tokens, **switches):
-    cfg, _, rp = model
-    return np.asarray(R.forward(rp, cfg, jnp.asarray(tokens), held=held_of(cfg),
-                                **switches))
 
 
 # -- (a) state, pages and pooled rows against the whole forward pass -----------
@@ -274,126 +62,6 @@ def test_chunked_prefill_then_decode_matches_the_reference(model, n, chunk):
     assert np.abs(got - want).max() < TOL
 
 
-def test_a_short_and_a_long_stream_decode_in_one_window(model):
-    """Rows of one tick below ``index_topk`` and several times past it:
-    one row attends ``0..t``, the other its picked blocks and its tail,
-    and the frozen slot nothing; the counters say so."""
-    cfg, params, _ = model
-    short, long_ = prompt_ids(4, seed=21), prompt_ids(70, seed=22)
-    follow = {0: prompt_ids(10, seed=23), 2: prompt_ids(10, seed=24)}
-    served = Served(cfg, params)
-    served.prefill(0, short)
-    served.prefill(2, long_)
-    got = {0: [], 2: []}
-    for k in range(10):
-        rows = served.tick({b: follow[b][k] for b in follow})
-        for b in follow:
-            got[b].append(rows[b])
-    for b, prompt in ((0, short), (2, long_)):
-        want = reference_logits(model, prompt + follow[b])[len(prompt):]
-        assert np.abs(np.stack(got[b]) - want).max() < TOL
-    kda = {k: int(v) for k, v in served.stats["kda"].items()}
-    pos = [4 + k for k in range(10)] + [70 + k for k in range(10)]
-
-    def picked(t):
-        return TOPK + t % KPOOL + 1 if t >= TOPK else t + 1
-
-    assert kda["kda_decode_ticks"] == 10 and kda["kda_row_ticks"] == 4 * 20
-    assert kda["dsa_rows_in_context"] == sum(p + 1 for p in pos)
-    assert kda["dsa_rows_picked"] == sum(picked(p) for p in pos)
-    assert kda["dsa_rows_fetched"] == 20 * (TOPK + KPOOL)
-    assert kda["dsa_row_ticks_selecting"] == 10
-    assert kda["dsa_index_rows_scored"] == sum(p // KPOOL for p in pos if p >= TOPK)
-    assert kda["kda_chunks"] == 1 + 3 and kda["kda_chunk_rows"] == 74
-    chunk_pos = list(range(4)) + list(range(70))
-    assert kda["dsa_chunk_rows_in_context"] == sum(p + 1 for p in chunk_pos)
-    assert kda["dsa_chunk_rows_picked"] == sum(picked(p) for p in chunk_pos)
-    assert kda["dsa_chunk_rows_selecting"] == 70 - TOPK
-    # the dense product under the mask sweeps blocks of 16 cached rows to the
-    # chunk's last row (padding included), for every valid row
-    assert kda["dsa_chunk_rows_fetched"] == 4 * 32 + 32 * 32 + 32 * 64 + 6 * 96
-    assert kda["dsa_rows_picked"] / kda["dsa_rows_in_context"] < 0.5
-
-
-def test_engine_tokens_are_the_references_argmax(model):
-    """Through ``PagedBatchEngine`` itself (scheduler, allocator, K-tick
-    window, greedy head): every emitted token is the top of the
-    reference's teacher-forced logits, or within TOL of it."""
-    cfg, params, _ = model
-    engine = make_engine(cfg, params)
-    prompts = {"a": prompt_ids(6, 31), "b": prompt_ids(50, 32),
-               "c": prompt_ids(33, 33)}
-    for rid, prompt in prompts.items():
-        engine.submit(rid, prompt, 13)
-    out = {rid: [] for rid in prompts}
-    for _ in range(200):
-        for rid, tok, _done in engine.step():
-            out[rid].append(tok)
-        if not engine.active:
-            break
-    for rid, prompt in prompts.items():
-        assert len(out[rid]) == 13
-        want = reference_logits(model, prompt + out[rid])[len(prompt) - 1 : -1]
-        chosen = want[np.arange(13), out[rid]]
-        assert (want.max(-1) - chosen).max() < TOL
-    report = engine.model_counters()
-    assert report["kv_bytes_per_token"] == (16 + 8 // 4) * 4  # one layer, f32
-    assert report["kda_state_bytes"] == SLOTS * (
-        4 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4) + 8 * 4)
-    assert report["moe_tokens"] > 0 and len(report["moe_expert_tokens"]) == 2
-    assert report["kda_row_ticks"] > 0 and report["dsa_row_ticks_selecting"] > 0
-    assert set(engine.pools) == {"4"} and set(engine.pools["4"]) == {"kv", "ik"}
-    assert engine.pools["4"]["ik"].shape[1:] == (PAGE // KPOOL, 8)
-    assert {k: set(v) for k, v in engine.slot_state.items()} == {
-        "0": {"s", "conv"}, "1": {"s", "conv"}, "2": {"s", "conv"},
-        "3": {"s", "conv"}, "4": {"acc"}}
-
-
-def test_an_audited_engine_hands_out_its_selection_and_serves_the_same(model):
-    """``make_paged_engine(picks=True)`` (a cache audit's, through
-    ``llm_server.make_engine``'s keywords): the tokens of a served engine,
-    and behind every chunk and window each sparse-latent layer's picked
-    blocks and output rows; a served engine keeps nothing. A window's tick
-    ``j`` of a row that came in at position ``p`` is the row at ``p + j``:
-    its picks are the reference's at that position."""
-    from dora_tpu.nodehub import llm_server
-
-    cfg, params, rp = model
-    prompt = prompt_ids(41, 71)
-    tokens, engines = {}, {}
-    for picks in (False, True):
-        engine = engines[picks] = make_engine(cfg, params, picks=picks)
-        seen = []
-        if picks:
-            window = engine.window_step
-
-            def window_step(tokens, pools, positions, *rest, window=window):
-                first = int(np.asarray(positions)[0])
-                out = window(tokens, pools, positions, *rest)
-                seen.append((first, np.asarray(engine.selection["window"][0]["picked"])[:, 0]))
-                return out
-
-            engine.window_step = window_step
-        engine.submit("a", prompt, 9)
-        tokens[picks] = [tok for _ in range(40) for _, tok, _d in engine.step()]
-    assert tokens[True] == tokens[False] and len(tokens[True]) == 9
-    assert engines[False].selection == {"chunk": [], "window": []}
-    look = engines[True].selection
-    assert look["chunk"][0]["picked"].shape == (CHUNK, TOPK // KPOOL)
-    assert look["chunk"][0]["attended"].shape == (CHUNK, 64)
-    assert look["window"][0]["picked"].shape == (K_TICKS, SLOTS, TOPK // KPOOL)
-    assert look["window"][0]["attended"].shape == (K_TICKS, SLOTS, 64)
-    _, kept = R.forward(rp, cfg, jnp.asarray(prompt + tokens[True]),
-                        held=held_of(cfg), rows=True)
-    own = np.asarray(kept[4]["picked"])
-    assert seen[0][0] == len(prompt)
-    for first, picked in seen:
-        for j in range(K_TICKS):
-            if first + j < len(prompt) + 8:  # the ticks that fed a token
-                assert set(picked[j]) == set(own[first + j]), (first, j)
-    # the server's way in: keywords go to the module's engine as they stand
-    with pytest.raises(TypeError, match="no_such_keyword"):
-        llm_server.make_engine(params, cfg, module=G, no_such_keyword=1)
 
 
 # -- (b) each † switch, flipped, fails the same limit ---------------------------
@@ -441,7 +109,7 @@ def test_the_shares_add_up_to_the_uncut_layer(ckpt):
     once equal the uncut reference's expert layer, in the program
     (``kimi_k2.mlp`` under this config and its clamp) and in the
     reference."""
-    from dora_tpu.models.hf import kimi_k2 as K
+    from dora_tpu.models import moe as K
 
     x = jnp.asarray(np.random.default_rng(3).standard_normal((24, 64)),
                     jnp.float32)
@@ -530,121 +198,6 @@ def test_padding_rows_and_frozen_rows_leave_every_cache_untouched(model):
     zero = [int(p) for p in served.bts[0]] + [int(p) for p in served.bts[2]]
     for name in ("kv", "ik"):
         assert (np.asarray(served.pools["4"][name])[zero] == pools[name][zero]).all()
-
-
-# -- (e) preempt, save and restore carry every slot-state leaf -------------------
-
-
-def run(engine, rid) -> list[int]:
-    """Step until ``rid`` is done; its tokens."""
-    out = []
-    for _ in range(300):
-        for r, tok, done in engine.step():
-            if r == rid:
-                out.append(tok)
-                if done:
-                    return out
-    raise AssertionError(f"{rid} never finished")
-
-
-def run_one(engine, prompt, max_new, rid="r") -> list[int]:
-    engine.submit(rid, prompt, max_new)
-    return run(engine, rid)
-
-
-def test_preempt_and_resume_give_the_first_streams_tokens(model):
-    cfg, params, _ = model
-    prompt = prompt_ids(29, seed=51)
-    want = run_one(make_engine(cfg, params), prompt, 14)
-    engine = make_engine(cfg, params)
-    engine.submit("r", prompt, 14)
-    head = []
-    while len(head) < 5:
-        head += [tok for _r, tok, _d in engine.step()]
-    engine.preempt("r")
-    assert engine.active == 0
-    # another stream dirties the slot's state, then the first comes back
-    assert len(run_one(engine, prompt_ids(40, seed=52), 9, "other")) == 9
-    assert run_one(engine, prompt, 14) == want
-
-
-def test_chunks_ahead_of_their_period_give_the_tokens_of_step(model):
-    """``dispatch → ahead → collect`` against ``step()`` where a chunk
-    is NOT idempotent (the delta-rule layers' state a slot): a chunk
-    that goes ahead reads the state the running window leaves and is
-    adopted once, so three- and six-chunk prompts beside streams that
-    decode give the tokens they give in line."""
-    cfg, params, _ = model
-    prompts = {"a": prompt_ids(21, seed=61), "long": prompt_ids(45, seed=62),
-               "b": prompt_ids(9, seed=63)}
-    caps = {"a": 9, "long": 14, "b": 6}
-
-    engine = make_engine(cfg, params)
-
-    def serve(halves: bool):
-        ran, ahead = engine.chunks_run, engine.chunks_ahead
-        for rid, prompt in prompts.items():
-            engine.submit(rid, prompt, caps[rid])
-        got = {rid: [] for rid in prompts}
-        for _ in range(300):
-            if not engine.active:
-                break
-            if halves:
-                out = engine.dispatch()
-                engine.ahead()
-                out += engine.collect()
-            else:
-                out = engine.step()
-            for rid, tok, _done in out:
-                got[rid].append(tok)
-        engine.check_invariants()
-        return got, engine.chunks_run - ran, engine.chunks_ahead - ahead
-
-    # the same engine, so the same two programs: in line, then ahead
-    # (every slot is taken again from zeros)
-    want, line_chunks, line_ahead = serve(False)
-    got, chunks, ahead = serve(True)
-    assert got == want and [len(got[r]) for r in caps] == list(caps.values())
-    assert line_ahead == 0 and ahead >= 3 and chunks == line_chunks
-
-
-@pytest.mark.parametrize("lost", [None, "s", "conv"])
-def test_checkpoint_restore_round_trips_a_stream_in_mid_decode(model, tmp_path,
-                                                               lost):
-    """With every leaf carried (each read back bit for bit, the
-    accumulator of an unfinished block among them) the stream goes on as
-    if nothing happened; with the states or the tails blanked it goes
-    elsewhere."""
-    cfg, params, _ = model
-    prompt = prompt_ids(42, seed=61)  # ends two rows into a pooled block
-    want = run_one(make_engine(cfg, params), prompt, 18)
-    engine = make_engine(cfg, params)
-    engine.submit("r", prompt, 18)
-    head = []
-    while len(head) < 5:
-        head += [tok for _r, tok, _d in engine.step()]
-    snap = engine.checkpoint_state()
-    assert snap["slot_state"] is True
-    engine.save_pools(tmp_path / "pools")
-    fresh = make_engine(cfg, params)
-    fresh.restore_pools(tmp_path / "pools")
-    saved, back = (jax.tree.map(np.asarray, e.slot_state) for e in (engine, fresh))
-    assert jax.tree.all(jax.tree.map(lambda a, b: (a == b).all(), saved, back))
-    assert all(np.abs(leaf).max() > 0 for leaf in jax.tree.leaves(saved))
-    if lost is not None:
-        fresh.slot_state = {
-            key: {name: jnp.zeros_like(leaf) if name == lost else leaf
-                  for name, leaf in leaves.items()}
-            for key, leaves in fresh.slot_state.items()}
-    fresh.restore_state(snap)
-    rest = run(fresh, "r")
-    if lost is None:
-        assert head + rest == want
-    else:
-        logits = reference_logits(model, prompt + head + rest)
-        chosen = logits[np.arange(len(prompt) - 1, len(logits) - 1), head + rest]
-        top = logits[len(prompt) - 1 : -1].max(-1)
-        assert head + rest != want or (top - chosen).max() > TOL
 
 
 def _kernels_and_state_selects(jaxpr, shape):
@@ -873,8 +426,9 @@ def test_the_published_cut_in_bytes():
     assert per_slot == 4 * (64 * 128 * 128 * 4 + 3 * 3 * 8192 * item) + 128 * 4
     assert 64 * 128 * 128 * 4 == 4_194_304
     limit, used = 16_909_336_064, 5_000_000_000
-    assert G.pages_that_fit(cfg, limit, used, 16, 16) == 16 * 16384 // 16 + 1
-    assert G.pages_that_fit(cfg, 8 << 30, 6 << 30, 16, 16) == 2 * 16384 // 16
+    page = 16 * cfg.kv_bytes_per_token
+    assert PM.pages_that_fit(page, limit, used, 16, 16384, 16) == 16 * 16384 // 16 + 1
+    assert PM.pages_that_fit(page, 8 << 30, 6 << 30, 16, 16384, 16) == 2 * 16384 // 16
 
 
 # (h) no weight is copied or closed over in the two programs:

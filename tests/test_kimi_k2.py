@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from dora_tpu.models import layers as L
+from dora_tpu.models import moe as M
 from dora_tpu.models.hf import kimi_k2 as K
 from dora_tpu.models.hf import kimi_k2_reference as R
 
@@ -126,7 +127,7 @@ def share(ckpt):
 @pytest.fixture()
 def small_expert_blocks(monkeypatch):
     """Chunks of 16 rows take the blocked expert path (8 rows a block)."""
-    monkeypatch.setattr(K, "EXPERT_BLOCK", 8)
+    monkeypatch.setattr(M, "EXPERT_BLOCK", 8)
 
 
 def prompt_ids(n: int, seed: int = 1) -> list[int]:
@@ -205,7 +206,7 @@ def test_router_bias_only_in_the_choice(share, which):
     bias = np.zeros(32, np.float32)
     bias[loser] = 10.0
     blk["router_bias"] = r["router_bias"] = jnp.asarray(bias)
-    ids, w = (K.route(blk, cfg, x) if which == "program"
+    ids, w = (M.route(blk, cfg, x) if which == "program"
               else R.route(r, cfg, x))
     ids, w = np.asarray(ids), np.asarray(w)
     assert (ids == loser).any(-1).all()
@@ -236,8 +237,8 @@ def test_four_shares_add_up_to_the_uncut_layer(ckpt):
         cfg, params = K.load(shared_by(ckpt, 4), max_seq=128, ep_rank=rank)
         assert (cfg.expert_first, cfg.experts_held) == (8 * rank, 8)
         blk = params["blocks"]["2"]
-        y, (tokens, landed, per_expert) = K.mlp(blk, cfg, x, live, live)
-        shared = np.asarray(K.swiglu(blk["shared"], x))
+        y, (tokens, landed, per_expert) = M.mlp(blk, cfg, x, live, live)
+        shared = np.asarray(M.swiglu(blk["shared"], x))
         total += np.asarray(y) - (shared if rank else 0)
         pairs += int(landed)
         assert int(tokens) == 24 and int(per_expert.sum()) == int(landed)
@@ -272,7 +273,7 @@ def test_absorbed_attention_equals_expanded(share):
         np.asarray(pool[3, 0]), np.asarray(rows[0]), atol=1e-6)
     assert cfg.row == 128 and not np.asarray(pool[..., cfg.latent:]).any()
     no_pe = pool.at[:, :, cfg.kv_rank:].set(0.0)
-    ctx = K._attend_blocks(
+    ctx = L.attend_latent_blocks(
         cfg, q,
         lambda j: no_pe[jax.lax.dynamic_slice_in_dim(bt, j * 2, 2)].reshape(
             16, cfg.row),
@@ -280,7 +281,7 @@ def test_absorbed_attention_equals_expanded(share):
                    )[:, None, :],
         2, "qhc,tc->qht", "qht,tc->qhc",
     )
-    assert np.abs(np.asarray(K.mla_output(blk, cfg, ctx)) - want).max() > 1e-2
+    assert np.abs(np.asarray(L.mla_output(blk, cfg, ctx)) - want).max() > 1e-2
 
 
 def test_chunked_prefill_then_decode_windows_match_the_full_forward(
@@ -291,7 +292,7 @@ def test_chunked_prefill_then_decode_windows_match_the_full_forward(
     over prompt + emitted, at every position."""
     cfg, params, rp = share
     prompt = prompt_ids(41)
-    pools, stats = K.init_page_pool(cfg, 40, PAGE), K.init_counters(cfg)
+    pools, stats = K.init_page_pool(cfg, 40, PAGE), M.init_counters(cfg)
     bt = np.zeros((128 // PAGE,), np.int32)
     bt[:10] = np.arange(1, 11)[::-1]
     logits, pools, stats = run_chunks(params, cfg, prompt, pools, stats, bt)
@@ -343,7 +344,7 @@ def test_dropping_the_routed_experts_or_the_shared_expert_fails_the_tolerance(
         bt = np.zeros((128 // PAGE,), np.int32)
         bt[:2] = (1, 2)
         got, _, _ = run_chunks(cut, cut_cfg, prompt, pools,
-                               K.init_counters(cut_cfg), bt)
+                               M.init_counters(cut_cfg), bt)
         assert np.abs(got - want).max() > 50 * TOL, drop
 
 
@@ -491,15 +492,15 @@ def test_loader_maps_hf_names_and_reads_only_held_experts(ckpt, monkeypatch):
 def test_expert_share_size_from_the_checkpoint_rank_from_the_launcher(
         monkeypatch):
     hf = {"n_routed_experts": 384, "ep_size": 32}
-    assert K.expert_share(hf) == (0, 12)
-    assert K.expert_share({"n_routed_experts": 384}) == (0, 384)
+    assert M.expert_share(hf) == (0, 12)
+    assert M.expert_share({"n_routed_experts": 384}) == (0, 384)
     monkeypatch.setenv("DORA_EP_RANK", "7")
-    assert K.expert_share(hf) == (84, 12)
-    assert K.expert_share(hf, ep_rank=5) == (60, 12)  # the argument wins
+    assert M.expert_share(hf) == (84, 12)
+    assert M.expert_share(hf, ep_rank=5) == (60, 12)  # the argument wins
     with pytest.raises(ValueError, match="do not divide"):
-        K.expert_share({**hf, "ep_size": 7})
+        M.expert_share({**hf, "ep_size": 7})
     with pytest.raises(ValueError, match="rank 32"):
-        K.expert_share(hf, ep_rank=32)
+        M.expert_share(hf, ep_rank=32)
 
 
 def test_unsupported_variants_are_refused_by_name():

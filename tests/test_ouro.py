@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf import ouro as O
 from dora_tpu.models.hf import ouro_reference as R
 
@@ -558,4 +559,6 @@ def test_the_pools_default_size_is_a_rule_in_bytes(monkeypatch, limit, used, wan
     cfg = O.OuroConfig.from_hf(hf, 2048)
     assert cfg.kv_entries == 192 and cfg.kv_bytes_per_token == 1_572_864
     assert O.page_pool_bytes(cfg, 16) == 25_165_824
-    assert O.pages_that_fit(cfg, limit, used, 16, 16) == want
+    assert PM.pages_that_fit(
+        O.page_pool_bytes(cfg, 16), limit, used, 16, cfg.max_seq, 16,
+        multiple=O.POOL_PAGE_MULTIPLE) == want
