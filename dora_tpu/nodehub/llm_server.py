@@ -117,6 +117,7 @@ MODEL_MODULES = {
     "ouro": "ouro",
     "exaone_moe": "exaone_moe",
     "glm5_next_text": "glm5_next",
+    "KeyeVL2": "keye_vl2",
 }
 
 

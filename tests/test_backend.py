@@ -248,8 +248,18 @@ def _tiny_glm5(tmp_path):
     return glm5_next.load(tmp_path / "ckpt", max_seq=64)
 
 
+def _tiny_keye(tmp_path):
+    from test_keye_vl2 import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import keye_vl2
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return keye_vl2.load(tmp_path / "ckpt", max_seq=64)
+
+
 _TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon, "ouro": _tiny_ouro,
-         "exaone_moe": _tiny_exaone, "glm5_next": _tiny_glm5}
+         "exaone_moe": _tiny_exaone, "glm5_next": _tiny_glm5,
+         "keye_vl2": _tiny_keye}
 
 
 def _engine_programs(module_name, monkeypatch, tmp_path):
@@ -298,7 +308,8 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
 
 @pytest.mark.parametrize(
     "module_name",
-    ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next"])
+    ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next",
+     "keye_vl2"])
 def test_engine_programs_take_the_weights_as_arguments(
     module_name, monkeypatch, tmp_path
 ):
@@ -369,7 +380,8 @@ def test_kernels_take_the_stored_weight(kernel, m, k, n):
 
 @pytest.mark.parametrize(
     "module_name",
-    ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next"])
+    ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next",
+     "keye_vl2"])
 def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
     """The same walk over the window and chunk programs as the engine
     jits them (tiny models: vocab 256 and 128 are no multiple of the

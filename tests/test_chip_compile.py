@@ -916,3 +916,138 @@ def test_glm5_chunk_program_compiles_and_moves_no_cache(chip, picks):
     assert "tpu_custom_call" in compiled.as_text()
     assert _cache_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+# -- Keye-VL-2.0: GQA pages read through an indexer's picks, two leaves a layer --
+
+#: the catalog row's widths (benchmark/configs/keye-vl2-30b-ep8.json), two of
+#: its identical layers, 16 of 128 experts held, the cell's vocabulary slice
+#: and context
+KEYE = dict(
+    model_type="KeyeVL2", hidden_size=2048, num_attention_heads=32,
+    num_key_value_heads=4, head_dim=128, intermediate_size=6144,
+    moe_intermediate_size=768, num_hidden_layers=2, vocab_size=18992,
+    rms_norm_eps=1e-6, rope_theta=10000000,
+    rope_scaling={"mrope_section": [16, 24, 24], "rope_type": "default",
+                  "type": "default"},
+    sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+               "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+               "q_chunk_size": 512, "topk": 2048},
+    num_experts=128, num_local_experts=128, num_experts_per_tok=8,
+    norm_topk_prob=True, decoder_sparse_step=1, mlp_only_layers=[],
+    sliding_window=None, use_sliding_window=False, attention_bias=False,
+    tie_word_embeddings=False, ep_size=8,
+)
+KEYE_SEQ = 16384
+
+
+def _keye():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool and counter shapes) for 16 slots of 16,384 rows."""
+    from dora_tpu.models.hf import keye_vl2
+
+    cfg = keye_vl2.KeyeVL2Config.from_hf(KEYE, KEYE_SEQ, 0)
+    bf, d = jnp.bfloat16, cfg.dim
+    fixed = {
+        "input_layernorm.weight": (d,), "post_attention_layernorm.weight": (d,),
+        "self_attn.q_proj.weight": (cfg.q_width, d),
+        "self_attn.k_proj.weight": (cfg.kv_width, d),
+        "self_attn.v_proj.weight": (cfg.kv_width, d),
+        "self_attn.o_proj.weight": (d, cfg.q_width),
+        "self_attn.q_norm.weight": (cfg.head_dim,),
+        "self_attn.k_norm.weight": (cfg.head_dim,),
+        "self_attn.indexer.wq.weight": (cfg.idx_heads * cfg.idx_dim, d),
+        "self_attn.indexer.wk.weight": (cfg.idx_dim, d),
+        "self_attn.indexer.k_norm.weight": (cfg.idx_dim,),
+        "self_attn.indexer.k_norm.bias": (cfg.idx_dim,),
+        "self_attn.indexer.weights_proj.weight": (cfg.idx_heads, d),
+        "mlp.gate.weight": (cfg.n_experts, d),
+    }
+
+    def get(name):
+        tail = name.split(".", 3)[3]
+        if tail in fixed:
+            return jnp.zeros(fixed[tail], bf)
+        return jnp.zeros(
+            (d, cfg.moe_ffn) if "down_proj" in tail else (cfg.moe_ffn, d), bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": keye_vl2._quantize_t(jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): keye_vl2.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(lambda: keye_vl2.init_page_pool(
+        cfg, SLOTS * KEYE_SEQ // PAGE + 1, PAGE))
+    stats = jax.eval_shape(lambda: keye_vl2.init_counters(cfg))
+    return keye_vl2, cfg, jax.eval_shape(build), pools, stats
+
+
+def _keye_pool_copies(compiled) -> list[str]:
+    """``copy`` instructions of a whole pool leaf, by shape."""
+    shapes = ("bf16[16385,16,1024]", "bf16[16385,8,128]")
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line
+            and any(s in line.split(" copy(")[0] for s in shapes)]
+
+
+@pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
+def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
+    """The K=8 decode window at Keye-VL-2.0's widths, 16 slots of 16,384
+    rows: every matrix through ``int8_matmul`` (the 6,336-wide fused
+    attention and indexer projection), ``lm_head_argmax`` over 18,992
+    columns; the index scores a block of 2,048 positions at a time,
+    ``top_k`` of 2,048 among 16,384 and the gather of 2,048 K|V rows a
+    row in plain XLA. Two leaves of pages a layer; no copy of either. As
+    the server jits it, and as a cache audit's engine does (the slot-state
+    window over ``audit_state``, every tick's picks and sublayer output
+    beside)."""
+    keye_vl2, cfg, params, pools, stats = _keye()
+    assert pools["0"]["kv"].shape == (SLOTS * KEYE_SEQ // PAGE + 1, PAGE, 1024)
+    assert pools["0"]["ik"].shape == (SLOTS * KEYE_SEQ // PAGE + 1, PAGE // 2, 128)
+    assert len(params["blocks"]["1"]["experts"]) == 16
+    assert params["blocks"]["0"]["wqkv"]["int8"].shape == (2048, 6336)
+
+    def program(p, *args):
+        return keye_vl2.window_program(
+            p, cfg, 8, None, keye_vl2.INDEX_BLOCK, *args, picks=picks)
+
+    rest = (_s((SLOTS,), I32), _s((SLOTS, KEYE_SEQ // PAGE), I32),
+            _s((SLOTS,), jnp.bool_), _s((SLOTS,), I32), _s((SLOTS,), I32))
+    if picks:
+        rest += (jax.eval_shape(lambda: keye_vl2.audit_state(SLOTS)),)
+    lowered = jax.jit(program, donate_argnums=(2, 3)).lower(
+        chip(params), *chip((_s((SLOTS,), I32), pools, stats, *rest)))
+    looks = jax.tree.leaves(lowered.out_info)[-2:]
+    assert ([x.shape for x in looks] == [(8, SLOTS, 2048), (8, SLOTS, 2048)]
+            ) == picks
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _keye_pool_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
+def test_keye_chunk_program_compiles_and_moves_no_pool(chip, picks):
+    """The 256-row prefill chunk: the index scores of 256 rows against
+    the cached keys a block of 2,048 at a time, ``top_k`` of 2,048 among
+    16,384 a row, the mask by threshold (no scatter), attention over
+    cached blocks of 256 rows under it, the experts' rows gathered 32 at
+    a time."""
+    keye_vl2, cfg, params, pools, stats = _keye()
+
+    def step(p, ids, pools, stats, position, bt, valid):
+        return keye_vl2.fused_paged_chunk_step(
+            p, cfg, ids, pools, stats, position, bt, valid, picks=picks)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((KEYE_SEQ // PAGE,), I32), _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _keye_pool_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
