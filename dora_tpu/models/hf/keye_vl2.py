@@ -41,9 +41,11 @@ per-row, per-tick selection.**
   rows ``DECODE_ROWS`` at a time (a frozen row scores nothing): scores
   their own pages a block of ``INDEX_BLOCK`` positions at a time up to
   their longest context, ``lax.top_k`` (ties to the lower position),
-  gathers the picked rows through the block table and attends those
-  alone (``dsa_rows_fetched`` = ``topk`` a row taken a layer: the live
-  ones, rounded up to whole groups).
+  finds the picked rows in the pool, gathers them in one XLA gather and
+  attends those alone (``ops/picked_rows``: ``pool_rows``, ``attend_rows``).
+  ``dsa_rows_fetched`` = what that gather reads, fixed by its shapes:
+  ``topk`` a row of every whole group (no kernel copies rows by count:
+  Mosaic names no single row of such a leaf, ``KNOWN_ISSUES.md`` "PR 50").
 * a chunk writes whole pages, every row scores and picks as a tick at
   its position would, and attention runs over cached blocks of
   ``ATTN_BLOCK`` rows under the picked mask (``layers.attend_kv_blocks``;
@@ -77,6 +79,7 @@ from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
 from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
+from dora_tpu.ops.picked_rows import attend_rows, pool_rows
 
 MODEL_TYPES = ("KeyeVL2",)
 
@@ -105,7 +108,8 @@ NOT_OFFERED = {
 #: the selection's counters on the device: GLM-5.3-Flash's names and
 #: meanings (one reader serves both), and three of this module's that its
 #: shares divide by (``dsa_decode_ticks``, ``dsa_row_ticks`` = live rows
-#: summed over ticks, ``dsa_chunk_rows`` = prompt rows prefilled)
+#: summed over ticks, ``dsa_chunk_rows`` = prompt rows prefilled);
+#: ``dsa_rows_fetched`` = the rows the tick's gather reads (its shapes')
 DSA_COUNTERS = (
     "dsa_decode_ticks", "dsa_row_ticks", "dsa_chunk_rows",
     "dsa_rows_in_context", "dsa_rows_picked", "dsa_rows_fetched",
@@ -472,19 +476,9 @@ def dsa_decode(blk, cfg: KeyeVL2Config, u, pool, positions, block_tables,
         with jax.named_scope("dsa_select"):
             ids = jnp.where(selecting[:, None], ids, first)
             seen = (selecting[:, None] | (ids <= t_g[:, None])) & ok[:, None]
-            held = flat[jnp.take_along_axis(bt, ids // page, 1) * page
-                        + ids % page]
+            held = flat[pool_rows(bt, ids, page)]
         with jax.named_scope("dsa_attend"):
-            keys, values = _split_rows(cfg, held)  # [R, topk, KV, hd]
-            s = jnp.einsum("bkgd,bnkd->bkgn", q[mine], keys,
-                           preferred_element_type=f32)
-            s = jnp.where(seen[:, None, None, :], s * cfg.head_dim ** -0.5,
-                          -1e30)
-            p = jnp.where(seen[:, None, None, :],
-                          jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
-            mix = jnp.einsum("bkgn,bnkd->bkgd", p.astype(values.dtype),
-                             values, preferred_element_type=f32)
-            mix = mix / jnp.maximum(p.sum(-1), 1e-30)[..., None]
+            mix = attend_rows(q[mine], held, seen)
         # a short last group's spare entries are frozen slots: zeros there
         return (ctx.at[mine].set(mix),
                 seen_rows.at[mine].set(seen.sum(-1, dtype=jnp.int32)),

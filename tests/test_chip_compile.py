@@ -1001,10 +1001,12 @@ def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
     attention and indexer projection), ``lm_head_argmax`` over 18,992
     columns; the index scores a block of 2,048 positions at a time,
     ``top_k`` of 2,048 among 16,384 and the gather of 2,048 K|V rows a
-    row in plain XLA. Two leaves of pages a layer; no copy of either. As
-    the server jits it, and as a cache audit's engine does (the slot-state
-    window over ``audit_state``, every tick's picks and sublayer output
-    beside)."""
+    row in plain XLA: ONE row gather a layer, the rows' addresses with no
+    gather of scalars (``pool_rows``), keys and values of a head as lane
+    slices of the gathered rows, no relayout of them (``attend_rows``).
+    Two leaves of pages a layer; no copy of either. As the server jits
+    it, and as a cache audit's engine does (the slot-state window over
+    ``audit_state``, every tick's picks and sublayer output beside)."""
     keye_vl2, cfg, params, pools, stats = _keye()
     assert pools["0"]["kv"].shape == (SLOTS * KEYE_SEQ // PAGE + 1, PAGE, 1024)
     assert pools["0"]["ik"].shape == (SLOTS * KEYE_SEQ // PAGE + 1, PAGE // 2, 128)
@@ -1025,9 +1027,56 @@ def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
     assert ([x.shape for x in looks] == [(8, SLOTS, 2048), (8, SLOTS, 2048)]
             ) == picks
     compiled = lowered.compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     assert _keye_pool_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    gathered = [line.split(" gather(")[0].split(" = ")[1]
+                for line in text.splitlines() if " gather(" in line]
+    assert sum(g.startswith("bf16[4,2048,1024]") for g in gathered) == cfg.layers
+    assert not [g for g in gathered if g.startswith(("s32[4,2048]", "s32[8192]"))]
+    assert "bf16[4,2048,2,4,128]" not in text
+
+
+def test_mosaic_copies_no_single_row_of_a_joined_page(chip):
+    """Why the picked rows are XLA's gather and no kernel's copies: a
+    ``[P, page, 2 * KV * hd]`` leaf is tiled (8, 128) over its last two
+    dimensions, and Mosaic slices a tiled dimension by whole tiles, so a
+    kernel cannot name ONE position's row as the source of a copy. (Over
+    a leaf whose row is one tile, ``[P, page, 2 * KV, hd]``, it can, at
+    21 ns a copy: ``PERF.md`` section 6, PR 50.) The day this compiles,
+    ``ROADMAP.md`` reach A6 is open again."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(ids, pool, out, buf, sem):
+        def one(j, carry):
+            pltpu.make_async_copy(
+                pool.at[ids[j] // PAGE, ids[j] % PAGE], buf.at[j], sem.at[0]
+            ).start()
+            return carry
+
+        jax.lax.fori_loop(0, 256, one, 0)
+        pltpu.make_async_copy(buf, buf, sem.at[0]).wait()
+        out[...] = buf[...]
+
+    def copy_rows(ids, pool):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+                scratch_shapes=[pltpu.VMEM((256, 1024), jnp.bfloat16),
+                                pltpu.SemaphoreType.DMA((1,))]),
+            out_shape=jax.ShapeDtypeStruct((256, 1024), jnp.bfloat16),
+        )(ids, pool)
+
+    lowered = jax.jit(copy_rows).lower(
+        *chip((_s((256,), I32), _s((SLOTS * KEYE_SEQ // PAGE + 1, PAGE, 1024),
+                                    jnp.bfloat16))))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        lowered.compile()
 
 
 @pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
