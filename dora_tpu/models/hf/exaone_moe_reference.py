@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from dora_tpu.models.hf import exaone_moe as E
+from dora_tpu.models.moe import unstack_experts
 from dora_tpu.ops.int8_matmul import dequantize
 
 SWITCHES = ("post_norm", "qkv_bias", "qk_norm", "rope_on_global",
@@ -78,7 +79,7 @@ def reference_params(params, cfg: E.ExaoneMoeConfig) -> dict:
                 r["shared"] = swiglu(blk["shared"])
             r["experts"] = {
                 cfg.expert_first + e: swiglu(w)
-                for e, w in enumerate(blk["experts"])
+                for e, w in enumerate(unstack_experts(blk["experts"]))
             }
         out["blocks"][i] = r
     return out

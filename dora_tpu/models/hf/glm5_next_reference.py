@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from dora_tpu.models.hf import glm5_next as G
+from dora_tpu.models.moe import unstack_experts
 from dora_tpu.ops.int8_matmul import dequantize
 
 SWITCHES = ("hc_eps_inside", "first_in_mean_out", "softplus_gate", "max_pool",
@@ -119,7 +120,7 @@ def reference_params(params, cfg: G.Glm5NextConfig) -> dict:
                 p["shared"] = swiglu(blk["shared"])
             p["experts"] = {
                 cfg.expert_first + e: swiglu(w)
-                for e, w in enumerate(blk["experts"])
+                for e, w in enumerate(unstack_experts(blk["experts"]))
             }
         out["blocks"][i] = p
     return out

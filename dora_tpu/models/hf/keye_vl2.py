@@ -281,11 +281,7 @@ def load_layer(get, cfg: KeyeVL2Config, i: int, prefix: str = "model.") -> dict:
         "wo": _quantize_t(get(a + "o_proj.weight")),
         "ffn_norm": get(lp + "post_attention_layernorm.weight"),
         "router": get(m + "gate.weight").T.astype(L.compute_dtype()),
-        "experts": [
-            moe.swiglu_weights(get, f"{m}experts.{e}.")
-            for e in range(cfg.expert_first,
-                           cfg.expert_first + cfg.experts_held)
-        ],
+        "experts": moe.stack_experts(get, cfg, m),
     }
 
 
@@ -597,7 +593,7 @@ def dsa_chunk(blk, cfg: KeyeVL2Config, u, pool, position, block_table, rope,
 
 
 # ---------------------------------------------------------------------------
-# the expert layer: softmax scores over models/moe.py's loop and counters
+# the expert layer: softmax scores over models/moe.py's routed sum and counters
 # ---------------------------------------------------------------------------
 
 

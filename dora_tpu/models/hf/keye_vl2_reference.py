@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from dora_tpu.models.hf import keye_vl2 as K
+from dora_tpu.models.moe import unstack_experts
 from dora_tpu.ops.int8_matmul import dequantize
 
 SWITCHES = ("index_reads_residual", "index_plain_key", "index_unscaled",
@@ -94,7 +95,7 @@ def reference_params(params, cfg: K.KeyeVL2Config) -> dict:
             "router": blk["router"].astype(f32),
             "experts": {
                 cfg.expert_first + e: swiglu(w)
-                for e, w in enumerate(blk["experts"])
+                for e, w in enumerate(unstack_experts(blk["experts"]))
             },
         }
     return out

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from dora_tpu.models.hf import kimi_k2 as K
+from dora_tpu.models.moe import unstack_experts
 from dora_tpu.ops.int8_matmul import dequantize
 
 
@@ -65,7 +66,7 @@ def reference_params(params, cfg: K.KimiK2Config) -> dict:
                 r["shared"] = swiglu(blk["shared"])
             r["experts"] = {
                 cfg.expert_first + e: swiglu(w)
-                for e, w in enumerate(blk["experts"])
+                for e, w in enumerate(unstack_experts(blk["experts"]))
             }
         out["blocks"][i] = r
     return out

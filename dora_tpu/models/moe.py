@@ -1,5 +1,6 @@
-"""The expert layer that three served configurations run (Kimi-K2,
-K-EXAONE, GLM-5.3-Flash), as ONE RANK of an expert group computes it.
+"""The expert layer that four served configurations run (Kimi-K2,
+K-EXAONE, GLM-5.3-Flash; Keye-VL-2.0 under a router of its own), as ONE
+RANK of an expert group computes it.
 
 The router keeps its published width (a sigmoid score for every expert
 of the model, top-k of the biased scores, unbiased normalised weights
@@ -12,9 +13,10 @@ experts would add. One chip runs the layer without its exchange. Plain
 A model file (``models/hf/``) brings the config and the weights:
 ``cfg`` is any object with the fields :class:`ExpertLayerConfig` names,
 a layer's ``blk`` holds ``"dense"`` (a SwiGLU) or ``"router"``,
-``"router_bias"``, ``"experts"`` (a list of SwiGLUs) and, where the
-model has one, ``"shared"``; :func:`expert_layer_weights` and
-:func:`swiglu_weights` load them.
+``"router_bias"``, ``"experts"`` (the held experts' SwiGLUs as ONE
+stack: every leaf of a SwiGLU with a leading axis over them) and, where
+the model has one, ``"shared"``; :func:`expert_layer_weights`,
+:func:`swiglu_weights` and :func:`stack_experts` load them.
 """
 
 from __future__ import annotations
@@ -26,10 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from dora_tpu.models import layers as L
-from dora_tpu.ops.int8_matmul import quantize_int8_t
+from dora_tpu.ops.int8_matmul import int8_matmul_grouped, quantize_int8_t
 
 #: rows one expert computes at a time in a prefill chunk. A decode batch
-#: of at most this many rows goes to a touched expert whole.
+#: of at most this many rows goes to every expert it touched whole.
 EXPERT_BLOCK = 32
 
 
@@ -85,12 +87,36 @@ def swiglu_weights(get, prefix: str) -> dict:
     }
 
 
+@jax.jit
+def _stack(each: list):
+    return jax.tree.map(lambda *leaves: jnp.stack(leaves), *each)
+
+
+def stack_experts(get, cfg: ExpertLayerConfig, prefix: str,
+                  swiglu_weights=swiglu_weights) -> dict:
+    """The HELD experts under ``prefix`` (``experts.<e>.``; an absent
+    expert is never read) as one SwiGLU whose leaves have a leading axis
+    over them: ``w_gateup`` ``{"int8": [E, K, 2I], "scale": [E, 1,
+    2I]}``, ``w_down`` alike, a ``"limit"`` an expert where
+    ``swiglu_weights`` put one. One program a layer shape joins them."""
+    return _stack([
+        swiglu_weights(get, f"{prefix}experts.{e}.")
+        for e in range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
+    ])
+
+
+def unstack_experts(stack: dict) -> list:
+    """The stack's SwiGLUs one by one (what a plain reference reads)."""
+    held = stack["w_down"]["int8"].shape[0]
+    return [jax.tree.map(lambda leaf: leaf[e], stack) for e in range(held)]
+
+
 def expert_layer_weights(get, cfg: ExpertLayerConfig, prefix: str,
                          swiglu_weights=swiglu_weights) -> dict:
     """An expert layer's entries of ``blk`` from the tensors under
     ``prefix`` (HF's DeepseekV3 names): the router and its bias, the
-    shared expert where ``cfg.n_shared``, and the HELD experts alone (an
-    absent expert is never read). ``swiglu_weights`` loads one SwiGLU."""
+    shared expert where ``cfg.n_shared``, and the stack of the held
+    experts. ``swiglu_weights`` loads one SwiGLU."""
     block = {
         "router": get(prefix + "gate.weight").T.astype(L.compute_dtype()),
         "router_bias": get(prefix + "gate.e_score_correction_bias").astype(
@@ -98,23 +124,40 @@ def expert_layer_weights(get, cfg: ExpertLayerConfig, prefix: str,
     }
     if cfg.n_shared:
         block["shared"] = swiglu_weights(get, prefix + "shared_experts.")
-    block["experts"] = [
-        swiglu_weights(get, f"{prefix}experts.{e}.")
-        for e in range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
-    ]
+    block["experts"] = stack_experts(get, cfg, prefix, swiglu_weights)
     return block
 
 
+def _gated(h, limit=None):
+    """``silu(gate) * up`` of a first projection's result ``h [..., 2I]``;
+    with a ``limit``, the gate held to ``(-inf, limit]`` and the up part
+    to ``[-limit, limit]`` first."""
+    gate, up = jnp.split(h, 2, axis=-1)
+    if limit is not None:
+        limit = limit.astype(h.dtype)
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
 def swiglu(w: dict, x):
-    """``w["limit"]``, where a loader put one beside the matrices (a
-    checkpoint's ``swiglu_limit``; Kimi-K2 has none): the gate held to
-    ``(-inf, limit]`` and the up part to ``[-limit, limit]`` before
-    ``silu(gate) * up``."""
-    gate, up = jnp.split(L.matmul(x, w["w_gateup"]), 2, axis=-1)
-    if "limit" in w:
-        gate = jnp.minimum(gate, w["limit"].astype(gate.dtype))
-        up = jnp.clip(up, -w["limit"].astype(up.dtype), w["limit"].astype(up.dtype))
-    return L.matmul(jax.nn.silu(gate) * up, w["w_down"])
+    """One SwiGLU on rows ``x [N, dim]``. ``w["limit"]``, where a loader
+    put one beside the matrices (a checkpoint's ``swiglu_limit``; Kimi-K2
+    has none), is :func:`_gated`'s."""
+    h = L.matmul(x, w["w_gateup"])
+    return L.matmul(_gated(h, w.get("limit")), w["w_down"])
+
+
+def stacked_swiglu(stack: dict, x, ids, n_groups):
+    """SwiGLU ``ids[g]`` of ``stack`` (:func:`stack_experts`) on rows ``x
+    [N, dim]`` for each of the first ``n_groups`` entries of ``ids [G]``,
+    a grouped int8 product a projection: ``[G, N, dim]``, anything past
+    ``n_groups``. Only the experts named there are read."""
+    up, down = stack["w_gateup"], stack["w_down"]
+    h = int8_matmul_grouped(x, up["int8"], up["scale"], ids, n_groups)
+    limit = stack["limit"][ids][:, None, None] if "limit" in stack else None
+    return int8_matmul_grouped(
+        _gated(h, limit), down["int8"], down["scale"], ids, n_groups)
 
 
 def route(blk, cfg: ExpertLayerConfig, x):
@@ -138,41 +181,55 @@ def route(blk, cfg: ExpertLayerConfig, x):
 
 def held_experts(blk, cfg: ExpertLayerConfig, x, local, weights, live):
     """This rank's part of the routed sum: ``sum over chosen ∩ held of
-    w_i E_i(x)`` for rows ``x [N, dim]``; ``local [N, k]`` numbers the
-    chosen experts from this rank's first (outside ``0..held`` = absent). Work follows the pairs that
-    land here: an expert no live row chose is skipped (its weights are
-    not read), and in a chunk an expert computes only its own rows,
-    ``EXPERT_BLOCK`` at a time, gathered and scattered by one-hot
-    products. ``live [N]`` masks rows whose result nobody reads (frozen
-    decode rows). Returns y [N, dim] in float32."""
+    w_i E_i(x)`` for rows ``x [N, dim]``, in ascending order of expert;
+    ``local [N, k]`` numbers the chosen experts from this rank's first
+    (outside ``0..held`` = absent). Work follows the pairs that land
+    here: an expert no live row chose is not read. A decode tick's rows
+    go whole to every expert they touched, the touched experts' numbers
+    compacted to the front of one grouped product a projection; in a
+    chunk an expert computes only its own rows, ``EXPERT_BLOCK`` at a
+    time, gathered and scattered by one-hot products. ``live [N]`` masks
+    rows whose result nobody reads (frozen decode rows). Returns y [N,
+    dim] in float32."""
     n = x.shape[0]
-    y = jnp.zeros((n, cfg.dim), jnp.float32)
+    stack = blk["experts"]
+    held = stack["w_down"]["int8"].shape[0]
     with jax.named_scope("moe_experts"):
-        for e, w in enumerate(blk["experts"]):
+        if n <= EXPERT_BLOCK:
+            experts = jnp.arange(held)
+            landed = jnp.where(live[:, None], local, -1)  # [N, k]
+            touched = (landed == experts[:, None, None]).any((1, 2))  # [E]
+            count = touched.sum().astype(jnp.int32)
+            # group g <- the g-th touched expert, in ascending order
+            place = (touched & (experts <= experts[:, None])).sum(-1) - 1
+            ids = jnp.where(touched & (place == experts[:, None]), experts,
+                            0).sum(-1).astype(jnp.int32)  # [G]
+            ran = experts < count
+            # [G, N] float32, 0 where the row did not choose the group's
+            w = jnp.where(ran[:, None, None] & (landed == ids[:, None, None]),
+                          weights, 0.0).sum(-1)
+            out = stacked_swiglu(stack, x, ids, count)
+            return jnp.where(ran[:, None, None],
+                             out.astype(jnp.float32) * w[:, :, None], 0.0).sum(0)
+        y = jnp.zeros((n, cfg.dim), jnp.float32)
+        for e in range(held):
             hit = (local == e) & live[:, None]  # [N, k]
             mine = hit.any(-1)
             w_e = (weights * hit).sum(-1)  # [N] float32, 0 where not chosen
-            n_e = mine.sum().astype(jnp.int32)
-            if n <= EXPERT_BLOCK:
-                y = jax.lax.cond(
-                    n_e > 0,
-                    lambda y, w=w, w_e=w_e: y
-                    + swiglu(w, x).astype(jnp.float32) * w_e[:, None],
-                    lambda y: y,
-                    y,
-                )
-                continue
             # rank of each of the expert's rows among them, in order
             rank = jnp.cumsum(mine) - 1
+            only = jnp.full((1,), e, jnp.int32)
 
-            def body(j, y, w=w, w_e=w_e, mine=mine, rank=rank):
+            def body(j, y, only=only, w_e=w_e, mine=mine, rank=rank):
                 slot = j * EXPERT_BLOCK + jnp.arange(EXPERT_BLOCK)
                 pick = (mine[None, :] & (rank[None, :] == slot[:, None]))
                 pick = pick.astype(x.dtype)  # [block, N] one-hot rows
-                out = swiglu(w, pick @ x)  # this block's rows, in order
+                # this block's rows, in order
+                out = stacked_swiglu(stack, pick @ x, only, 1)[0]
                 back = jnp.dot(pick.T, out, preferred_element_type=jnp.float32)
                 return y + back * w_e[:, None]
 
+            n_e = mine.sum().astype(jnp.int32)
             blocks = (n_e + EXPERT_BLOCK - 1) // EXPERT_BLOCK
             y = jax.lax.fori_loop(0, blocks, body, y)
     return y

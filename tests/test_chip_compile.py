@@ -368,6 +368,8 @@ def test_kimi_chunk_program_compiles_and_updates_the_pool_in_place(chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the int8 matmul kernel
     assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)
+    assert _expert_stack_readers(
+        compiled, params["blocks"]["1"]["experts"]) == []
 
 
 def test_kimi_window_program_compiles_and_updates_the_pool_in_place(chip):
@@ -387,6 +389,10 @@ def test_kimi_window_program_compiles_and_updates_the_pool_in_place(chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < _pool_bytes(pools)
+    stack = params["blocks"]["1"]["experts"]
+    assert stack["w_gateup"]["int8"].shape == (12, 7168, 4096)
+    assert _expert_conds(compiled) == []
+    assert _expert_stack_readers(compiled, stack) == []
 
 
 # -- Falcon-H1-34B: a recurrent state beside the pages ------------------------
@@ -463,6 +469,39 @@ def _whole_array_copies(compiled, *trees) -> list[str]:
                 int(n) for n in m.group(2).split(",")) >= least:
             found.append(line.strip()[:120])
     return found
+
+
+def _expert_conds(compiled) -> list[str]:
+    """``conditional`` instructions under the expert layer's scope: the
+    routed sum branching on what an expert was given."""
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " conditional(" in line and "moe_experts" in line]
+
+
+def _expert_stack_readers(compiled, stack) -> list[str]:
+    """Instructions of the compiled program that read an int8 matrix stack
+    of the experts and are neither the grouped kernel nor a loop's
+    plumbing: a copy of the stack, or of one expert's ``[K, N]`` cut from
+    it, where the kernel's index map should have named the block. XLA's
+    own staging of a kernel's operand in fast memory (``copy-start`` /
+    ``slice-start`` into ``S(1)``: Keye's 50 MB and 25 MB stacks fit it,
+    and the parent's program staged the separate matrices the same way)
+    is a read ahead of the call, not a second copy in HBM, and passes."""
+    text = compiled.as_text()
+    shapes = tuple("s8[%s]" % ",".join(map(str, w["int8"].shape))
+                   for w in (stack["w_gateup"], stack["w_down"]))
+    lines = [m.groups() for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = (.*?)\s([a-z][a-z0-9-]*)\((.*)$", text, re.M)]
+    stacks = {name for name, kind, _, _ in lines if kind.startswith(shapes)}
+    assert stacks  # the program holds them under these shapes
+    plumbing = ("parameter", "tuple", "get-tuple-element", "while")
+    staging = ("copy-start", "slice-start")
+    return [f"{name} = {kind} {op}({rest}"[:160]
+            for name, kind, op, rest in lines
+            if stacks & set(re.findall(r"%[\w.-]+", rest.split("), ")[0]))
+            and op not in plumbing
+            and not (op in staging and "S(1)" in kind)
+            and not (op == "custom-call" and "int8_matmul_grouped" in name)]
 
 
 def test_falcon_h1_window_program_compiles_and_moves_no_cache(chip):
@@ -702,7 +741,9 @@ def test_exaone_window_program_compiles_and_moves_no_cache(chip):
     assert set(pools) == {"3"} and set(state) == {"0", "1", "2"}
     assert pools["3"]["kv"].shape == (SLOTS * EXAONE_SEQ // PAGE + 1, PAGE, 2048)
     assert state["0"]["kv"].shape == (SLOTS, 128, 2048)
-    assert len(params["blocks"]["1"]["experts"]) == 16
+    stack = params["blocks"]["1"]["experts"]
+    assert stack["w_gateup"]["int8"].shape == (16, 6144, 4096)
+    assert stack["w_down"]["scale"].shape == (16, 1, 6144)
 
     def program(p, *args):
         return exaone_moe.window_program(p, cfg, 8, None, *args)
@@ -722,6 +763,8 @@ def test_exaone_window_program_compiles_and_moves_no_cache(chip):
     # scatter, once a tick: as many such copies as window layers, no more
     assert len(_ring_copies(compiled)) <= len(state)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    assert _expert_conds(compiled) == []
+    assert _expert_stack_readers(compiled, stack) == []
 
 
 def test_exaone_chunk_program_compiles_and_moves_no_cache(chip):
@@ -744,6 +787,8 @@ def test_exaone_chunk_program_compiles_and_moves_no_cache(chip):
     assert _whole_array_copies(compiled, pools) == []
     assert _ring_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+    assert _expert_stack_readers(
+        compiled, params["blocks"]["1"]["experts"]) == []
 
 
 # -- GLM-5.3-Flash: delta-rule state a slot, latent and pooled index rows in pages --
@@ -867,7 +912,9 @@ def test_glm5_window_program_compiles_and_moves_no_cache(chip, picks):
     assert state["0"]["s"].shape == (SLOTS, 64, 128, 128)
     assert state["0"]["conv"].shape == (SLOTS, 3, 3 * 8192)
     assert state["2"]["acc"].shape == (SLOTS, 128)
-    assert len(params["blocks"]["1"]["experts"]) == 36
+    stack = params["blocks"]["1"]["experts"]
+    assert stack["w_gateup"]["int8"].shape == (36, 4096, 4096)
+    assert stack["limit"].shape == (36,)
 
     def program(p, *args):
         return glm5_next.window_program(p, cfg, 8, None, *args, picks=picks)
@@ -890,6 +937,8 @@ def test_glm5_window_program_compiles_and_moves_no_cache(chip, picks):
             if state + "{3,2,1,0:T(8,128)S(1)}" in line
             or (state in line and "copy-start(" in line)] == []
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert _expert_conds(compiled) == []
+    assert _expert_stack_readers(compiled, stack) == []
 
 
 @pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
@@ -916,6 +965,8 @@ def test_glm5_chunk_program_compiles_and_moves_no_cache(chip, picks):
     assert "tpu_custom_call" in compiled.as_text()
     assert _cache_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    assert _expert_stack_readers(
+        compiled, params["blocks"]["1"]["experts"]) == []
 
 
 # -- Keye-VL-2.0: GQA pages read through an indexer's picks, two leaves a layer --
@@ -1010,7 +1061,9 @@ def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
     keye_vl2, cfg, params, pools, stats = _keye()
     assert pools["0"]["kv"].shape == (SLOTS * KEYE_SEQ // PAGE + 1, PAGE, 1024)
     assert pools["0"]["ik"].shape == (SLOTS * KEYE_SEQ // PAGE + 1, PAGE // 2, 128)
-    assert len(params["blocks"]["1"]["experts"]) == 16
+    stack = params["blocks"]["1"]["experts"]
+    assert stack["w_gateup"]["int8"].shape == (16, 2048, 1536)
+    assert stack["w_down"]["int8"].shape == (16, 768, 2048)
     assert params["blocks"]["0"]["wqkv"]["int8"].shape == (2048, 6336)
 
     def program(p, *args):
@@ -1036,6 +1089,8 @@ def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
     assert sum(g.startswith("bf16[4,2048,1024]") for g in gathered) == cfg.layers
     assert not [g for g in gathered if g.startswith(("s32[4,2048]", "s32[8192]"))]
     assert "bf16[4,2048,2,4,128]" not in text
+    assert _expert_conds(compiled) == []
+    assert _expert_stack_readers(compiled, stack) == []
 
 
 def test_mosaic_copies_no_single_row_of_a_joined_page(chip):
@@ -1100,3 +1155,5 @@ def test_keye_chunk_program_compiles_and_moves_no_pool(chip, picks):
     assert "tpu_custom_call" in compiled.as_text()
     assert _keye_pool_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    assert _expert_stack_readers(
+        compiled, params["blocks"]["1"]["experts"]) == []

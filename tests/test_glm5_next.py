@@ -120,7 +120,8 @@ def test_the_shares_add_up_to_the_uncut_layer(ckpt):
         blk = params["blocks"]["1"]
         both, _ = K.mlp(blk, cfg, x, live, live)
         shared = K.swiglu(blk["shared"], x)
-        assert float(blk["shared"]["limit"]) == float(blk["experts"][0]["limit"]) == 1.0
+        assert float(blk["shared"]["limit"]) == 1.0
+        assert blk["experts"]["limit"].tolist() == [1.0] * cfg.experts_held
         parts.append(np.asarray(both - shared))
         rp = R.reference_params(params, cfg)["blocks"]["1"]
         with jax.default_matmul_precision("highest"):

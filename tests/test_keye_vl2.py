@@ -714,7 +714,9 @@ def test_the_loader_reads_the_held_experts_alone(ckpt, monkeypatch):
     experts = {n.split("experts.")[1].split(".")[0] for n in asked if "experts." in n}
     assert experts == {"10", "11"}
     assert not any("e_score_correction_bias" in n or "visual" in n for n in asked)
-    assert len(params["blocks"]["0"]["experts"]) == 2
+    stack = params["blocks"]["0"]["experts"]
+    assert sorted(stack) == ["w_down", "w_gateup"]
+    assert [v.shape[0] for v in jax.tree.leaves(stack)] == [2] * 4
     # one fused matrix: q, k, v, the indexer's q and k, its head weights
     assert params["blocks"]["0"]["wqkv"]["int8"].shape == (
         64, 64 + 32 + 32 + 16 + 8 + 128)

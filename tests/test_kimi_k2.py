@@ -334,12 +334,12 @@ def test_dropping_the_routed_experts_or_the_shared_expert_fails_the_tolerance(
     prompt = prompt_ids(16, seed=9)
     want = np.asarray(R.forward(rp, cfg, jnp.asarray(prompt)))
     for drop in ("experts", "shared"):
+        # no routed expert: a share past the model's last, so no pair lands
         cut = {**params, "blocks": {
-            i: ({**b, "experts": []} if drop == "experts" and "experts" in b
-                else {k: v for k, v in b.items() if k != drop})
+            i: {k: v for k, v in b.items() if (k, drop) != ("shared", "shared")}
             for i, b in params["blocks"].items()}}
         cut_cfg = cfg if drop == "shared" else K.KimiK2Config(
-            **{**cfg.__dict__, "experts_held": 0})
+            **{**cfg.__dict__, "expert_first": cfg.n_experts})
         pools = K.init_page_pool(cut_cfg, 8, PAGE)
         bt = np.zeros((128 // PAGE,), np.int32)
         bt[:2] = (1, 2)
@@ -472,7 +472,9 @@ def test_loader_maps_hf_names_and_reads_only_held_experts(ckpt, monkeypatch):
     assert experts == {12, 13, 14, 15}
     assert len(read) == len(set(read))  # every tensor once
     blk = params["blocks"]["1"]
-    assert len(blk["experts"]) == 4 and blk["router"].shape == (64, 32)
+    assert blk["experts"]["w_gateup"]["int8"].shape == (4, 64, 2 * 32)
+    assert blk["experts"]["w_down"]["scale"].shape == (4, 1, 64)
+    assert blk["router"].shape == (64, 32)
     assert blk["w_qkv_a"]["int8"].shape == (64, 128)  # 32 + 32 + 8, padded
     assert blk["w_kv_b"]["k8"].shape == (4, 16, 32)
     assert "dense" in params["blocks"]["0"] and "router" not in params["blocks"]["0"]

@@ -189,6 +189,105 @@ def test_int8_matmul_3d_input():
     )
 
 
+def _int8_stack(e: int, k: int, n: int, seed: int = 5):
+    each = [quantize_int8(jax.random.normal(jax.random.PRNGKey(seed + i),
+                                            (k, n), jnp.float32))
+            for i in range(e)]
+    return (jnp.stack([w["int8"] for w in each]),
+            jnp.stack([w["scale"] for w in each]))
+
+
+@pytest.mark.parametrize(
+    "ids,n_groups,m,k,n,shared",
+    [
+        ([0, 1, 2, 3], 4, 16, 256, 256, True),    # every expert touched
+        ([2, 0, 0, 0], 1, 16, 256, 256, False),   # one
+        ([0, 0, 0, 0], 0, 16, 256, 256, True),    # none: nothing to compare
+        ([1, 3, 99, -7], 2, 5, 256, 256, False),  # garbage ids past n_groups
+        ([3, 1, 2], 3, 16, 256, 260, False),      # a ragged N
+        ([3, 0], 2, 32, 2100, 130, True),         # K zero-padded, a chunk's block
+        ([1], 1, 64, 1792, 260, False),           # larger M: two M blocks a group
+    ],
+)
+def test_int8_matmul_grouped_equals_separate_calls(ids, n_groups, m, k, n,
+                                                   shared):
+    """Group ``g`` of the grouped entry is ``int8_matmul`` against matrix
+    ``ids[g]`` of the stack, to the bit, for every group that runs."""
+    from dora_tpu.ops.int8_matmul import int8_matmul_grouped
+
+    q, scale = _int8_stack(4, k, n)
+    x = jax.random.normal(jax.random.PRNGKey(11),
+                          (m, k) if shared else (len(ids), m, k), jnp.float32)
+    out = int8_matmul_grouped(x, q, scale, jnp.asarray(ids, jnp.int32),
+                              jnp.int32(n_groups))
+    assert out.shape == (len(ids), m, n)
+    for g in range(n_groups):
+        want = int8_matmul(x if shared else x[g], q[ids[g]], scale[ids[g]])
+        np.testing.assert_array_equal(np.asarray(out[g]), np.asarray(want))
+
+
+def _plain_routed_sum(stack, x, local, weights, live):
+    """``held_experts``' result by a plain loop over the experts: the
+    dequantized SwiGLU of every expert on every row, weighted."""
+    y = np.zeros((x.shape[0], stack["w_down"]["int8"].shape[-1]), np.float32)
+    for e in range(stack["w_down"]["int8"].shape[0]):
+        w = {k: {"int8": v["int8"][e], "scale": v["scale"][e]}
+             for k, v in stack.items() if k != "limit"}
+        gate, up = jnp.split(x @ dequantize(w["w_gateup"]), 2, axis=-1)
+        if "limit" in stack:
+            gate = jnp.minimum(gate, stack["limit"][e])
+            up = jnp.clip(up, -stack["limit"][e], stack["limit"][e])
+        out = (jax.nn.silu(gate) * up) @ dequantize(w["w_down"])
+        w_e = (weights * ((local == e) & live[:, None])).sum(-1)
+        y = y + np.asarray(out * w_e[:, None])
+    return y
+
+
+@pytest.mark.parametrize("case", ["all_live", "frozen_rows", "absent_picks",
+                                  "limit", "none_touched", "chunk"])
+def test_held_experts_against_a_plain_loop(case):
+    """The decode branch (N <= ``EXPERT_BLOCK``: one grouped product a
+    projection over the touched experts) and the chunk branch (an expert's
+    own rows, a block at a time) against a plain per-expert loop."""
+    from types import SimpleNamespace
+
+    from dora_tpu.models import moe
+
+    held, dim, inner, top_k = 6, 128, 64, 3
+    n = 80 if case == "chunk" else 12
+    gu_q, gu_s = _int8_stack(held, dim, 2 * inner, seed=20)
+    dn_q, dn_s = _int8_stack(held, inner, dim, seed=40)
+    stack = {"w_gateup": {"int8": gu_q, "scale": gu_s},
+             "w_down": {"int8": dn_q, "scale": dn_s}}
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
+    # picks over 10 experts of which this rank holds 6, from its 2nd on:
+    # local runs -2 .. 7, so some picks are absent on either side
+    local = jnp.asarray(np.stack(
+        [rng.choice(10, top_k, replace=False) for _ in range(n)]) - 2, jnp.int32)
+    weights = jnp.asarray(rng.random((n, top_k)), jnp.float32)
+    live = jnp.ones((n,), bool)
+    if case == "frozen_rows":
+        live = jnp.asarray(rng.random(n) < 0.5)
+    if case == "absent_picks":
+        local = local.at[:, 0].set(-1).at[::2, 1].set(held)
+    if case == "limit":
+        stack["limit"] = jnp.full((held,), 0.05, jnp.float32)
+    if case == "none_touched":
+        live = jnp.zeros((n,), bool)
+    cfg = SimpleNamespace(dim=dim)
+    got = moe.held_experts({"experts": stack}, cfg, x, local, weights, live)
+    want = _plain_routed_sum(stack, x, local, weights, live)
+    assert got.shape == (n, dim) and got.dtype == jnp.float32
+    if case == "none_touched":
+        assert not np.asarray(got).any()
+    else:
+        assert np.abs(want).max() > 1e-3
+    # float32 sums in another order, values in the hundreds
+    assert np.abs(np.asarray(got) - want).max() <= 1e-5 * max(
+        np.abs(want).max(), 1.0)
+
+
 def test_quantize_tree_targets_decode_weights_only():
     blocks = {
         "0": {
