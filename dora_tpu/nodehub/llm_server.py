@@ -118,6 +118,7 @@ MODEL_MODULES = {
     "exaone_moe": "exaone_moe",
     "glm5_next_text": "glm5_next",
     "KeyeVL2": "keye_vl2",
+    "zaya": "zaya",
 }
 
 

@@ -1157,3 +1157,155 @@ def test_keye_chunk_program_compiles_and_moves_no_pool(chip, picks):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
     assert _expert_stack_readers(
         compiled, params["blocks"]["1"]["experts"]) == []
+
+
+# -- ZAYA1-8B: convolution and value-shift tails a slot beside 1 KB pages, top-1 of 16 held --
+
+#: the catalog row's widths (benchmark/configs/zaya1-8b-pp2.json), two of
+#: its identical layers, every expert held, the cell's vocabulary slice
+#: and context
+ZAYA = dict(
+    model_type="zaya", hidden_size=2048, num_attention_heads=8,
+    num_key_value_heads=2, head_dim=128, moe_intermediate_size=2048,
+    num_hidden_layers=2, layer_types=["hybrid"] * 2, vocab_size=131136,
+    rms_norm_eps=1e-5, partial_rotary_factor=0.5, cca_time0=2, cca_time1=2,
+    rope_parameters={"hybrid": {"partial_rotary_factor": 0.5,
+                                "rope_theta": 5000000, "rope_type": "default"},
+                     "rope_type": "default"},
+    num_experts=16, num_experts_per_tok=1, router_hidden_size=256,
+    hidden_act="silu", attention_bias=False, lm_head_bias=False,
+    sliding_window=None, tie_word_embeddings=True,
+)
+ZAYA_SEQ = 8192
+
+
+def _zaya():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool, tail and counter shapes) for 16 slots of 8,192 rows."""
+    from dora_tpu.models.hf import zaya
+
+    cfg = zaya.ZayaConfig.from_hf(ZAYA, ZAYA_SEQ, 0)
+    bf, d, hd, r = jnp.bfloat16, cfg.dim, cfg.head_dim, cfg.router_hidden
+    width = cfg.conv_width
+    fixed = {
+        "input_layernorm.weight": (d,), "post_attention_layernorm.weight": (d,),
+        "self_attn.q_proj.weight": (cfg.q_width, d),
+        "self_attn.k_proj.weight": (cfg.kv_width, d),
+        "self_attn.v_proj1.weight": (hd, d), "self_attn.v_proj2.weight": (hd, d),
+        "self_attn.o_proj.weight": (d, cfg.q_width),
+        "self_attn.conv_qk.0.weight": (width, 1, 2),
+        "self_attn.conv_qk.0.bias": (width,),
+        "self_attn.conv_qk.1.weight": (width, hd, 2),
+        "self_attn.conv_qk.1.bias": (width,), "self_attn.temp": (cfg.kv_heads,),
+        "mlp.router.down_proj.weight": (r, d), "mlp.router.down_proj.bias": (r,),
+        "mlp.router.state_scale": (r,), "mlp.router.norm.weight": (r,),
+        "mlp.router.mlp.0.weight": (r, r), "mlp.router.mlp.0.bias": (r,),
+        "mlp.router.mlp.1.weight": (r, r), "mlp.router.mlp.1.bias": (r,),
+        "mlp.router.mlp.2.weight": (cfg.n_experts, r),
+        "mlp.router.balancing_bias": (cfg.n_experts,),
+    }
+
+    def get(name):
+        tail = name.split(".", 3)[3]
+        if tail in fixed:
+            return jnp.zeros(fixed[tail], bf)
+        if "_residual." in tail:
+            return jnp.zeros((d,), bf)
+        return jnp.zeros(
+            (d, cfg.moe_ffn) if "down_proj" in tail else (cfg.moe_ffn, d), bf)
+
+    def build():
+        embed = jnp.zeros((cfg.vocab, d), bf)
+        return {
+            "embed": embed, "out_norm": jnp.zeros((d,), bf),
+            "lm_head": zaya._quantize_t(embed),
+            "blocks": {str(i): zaya.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(lambda: zaya.init_page_pool(
+        cfg, SLOTS * ZAYA_SEQ // PAGE + 1, PAGE))
+    state = jax.eval_shape(lambda: zaya.init_slot_state(cfg, SLOTS))
+    stats = jax.eval_shape(lambda: zaya.init_counters(cfg))
+    return zaya, cfg, jax.eval_shape(build), pools, state, stats
+
+
+def _zaya_cache_copies(compiled, pools) -> list[str]:
+    """Whole-array copies as large as a pool leaf, but for the one this
+    cut's head brings: 131,136 columns (64 x 2,049) are no lane multiple,
+    so XLA relays the int8 head for ``lm_head_argmax`` once a program, a
+    copy of the PARAMETER and so outside the window's loop over its ticks
+    (``KNOWN_ISSUES.md`` "PR 52"; the published 262,272 columns, 128 x
+    2,049, need none)."""
+    copies = _whole_array_copies(compiled, pools)
+    head = [c for c in copies if "s8[2048,131136]" in c]
+    assert len(head) <= 1 and all("copy(%p__lm_head" in c for c in head), head
+    return [c for c in copies if c not in head]
+
+
+def test_zaya_window_program_compiles_and_moves_no_cache(chip):
+    """The K=8 decode window at ZAYA1-8B's widths, 16 slots of 8,192 rows:
+    the 1,536-wide fused projection and ``Wo`` through ``int8_matmul``,
+    the two convolutions over ``[tail ++ row]`` and the router's MLP in
+    float32 XLA, the pages (one ``[P, 16, 512]`` leaf a layer, 134 MB)
+    through ``attention_paged_rows_step`` under Mosaic (8 / 2 heads of
+    128: 4 query rows a K/V head), the 16 held experts as one stack of
+    which a tick's grouped product reads the touched ones,
+    ``lm_head_argmax`` over 131,136 columns. Neither a pool nor the
+    experts' stack is copied. Every tick's top-1 picks come
+    back beside (the server's program and a cache audit's are this one)."""
+    zaya, cfg, params, pools, state, stats = _zaya()
+    assert set(pools) == set(state) == {"0", "1"}
+    assert pools["0"]["kv"].shape == (SLOTS * ZAYA_SEQ // PAGE + 1, PAGE, 512)
+    assert state["0"]["c"].shape == (SLOTS, 2, 1280)
+    assert state["0"]["v"].shape == (SLOTS, 128)
+    stack = params["blocks"]["1"]["experts"]
+    assert stack["w_gateup"]["int8"].shape == (16, 2048, 4096)
+    assert stack["w_down"]["int8"].shape == (16, 2048, 2048)
+    assert params["blocks"]["0"]["wqkv"]["int8"].shape == (2048, 1536)
+    assert params["blocks"]["0"]["conv1_w"].shape == (2, 10, 128, 128)
+
+    def program(p, *args):
+        return zaya.window_program(p, cfg, 8, None, *args)
+
+    lowered = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, ZAYA_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32), state)),
+    )
+    assert jax.tree.leaves(lowered.out_info)[-1].shape == (8, 2, SLOTS)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    # one call a layer, inside the window's loop over its ticks
+    assert len(re.findall(r"= \S+ custom-call\(.*attention_paged_rows_step",
+                          text)) == cfg.layers
+    assert _zaya_cache_copies(compiled, pools) == []
+    # 268.6 MB of it the relaid head, 4 MB the rest
+    assert compiled.memory_analysis().temp_size_in_bytes < 288 << 20
+    assert _expert_conds(compiled) == []
+    assert _expert_stack_readers(compiled, stack) == []
+
+
+def test_zaya_chunk_program_compiles_and_moves_no_cache(chip):
+    """The 256-row prefill chunk: the convolutions over ``[tail ++ chunk]``
+    (258 rows), the block loop over the cached rows, the experts' rows
+    gathered 32 at a time (some 16 rows an expert at top-1 of 16: a block
+    runs half empty)."""
+    zaya, cfg, params, pools, state, stats = _zaya()
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return zaya.fused_paged_chunk_step(
+            p, cfg, ids, pools, state, stats, position, bt, valid, slot)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3, 6)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((ZAYA_SEQ // PAGE,), I32), state, _s((), I32),
+               _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _zaya_cache_copies(compiled, pools) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert _expert_stack_readers(
+        compiled, params["blocks"]["1"]["experts"]) == []

@@ -22,8 +22,9 @@ from dora_tpu.models import paged_model as PM
 HF = Path(PM.__file__).parent / "hf"
 #: every module under models/hf/ but the package file
 FILES = sorted(p.stem for p in HF.glob("*.py") if p.stem != "__init__")
-#: the six files whose engines ``build_engine`` builds
-BUILT = ("kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next", "keye_vl2")
+#: the seven files whose engines ``build_engine`` builds
+BUILT = ("kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next", "keye_vl2",
+         "zaya")
 #: a tower over a whole text model sits ABOVE that model's file (the
 #: frames tier, ROADMAP debt 2); nothing else may look sideways
 WRAPS = {"internvl": {"qwen2"}, "qwen2_vl": {"qwen2"}}
@@ -140,6 +141,9 @@ V5E = 16_909_336_064  # bytes_limit of a 16 GB v5e
     # 26,112 B a token: 6.85 GB of (16.91 - 1.4 - 4.29) GB, still every slot
     ("keye-vl2-30b-ep8.video-qa-16", 16 * 26112, 1_400_000_000, 16384, 1,
      16 * 16384 // 16 + 1),
+    # 20,480 B a token: 2.68 GB of (16.91 - 5.0 - 4.29) GB, every slot
+    ("zaya1-8b-pp2.reason-16", 16 * 20480, 5_000_000_000, 8192, 1,
+     16 * 8192 // 16 + 1),
 ])
 def test_pages_that_fit_gives_each_cell_its_pool(cell, page_bytes, used,
                                                  max_seq, multiple, want):
@@ -154,8 +158,8 @@ def test_pages_that_fit_never_goes_under_two_streams_and_the_cpu_has_its_own():
     assert PM.default_num_pages(25_165_824, 16, 2048, 16, multiple=8) == 4 * 2048 // 16
 
 
-def test_the_four_models_rules_are_that_one(monkeypatch):
-    from dora_tpu.models.hf import exaone_moe, glm5_next, keye_vl2, ouro
+def test_the_five_models_rules_are_that_one(monkeypatch):
+    from dora_tpu.models.hf import exaone_moe, glm5_next, keye_vl2, ouro, zaya
 
     seen = []
     monkeypatch.setattr(PM, "default_num_pages",
@@ -164,11 +168,11 @@ def test_the_four_models_rules_are_that_one(monkeypatch):
     class Cfg:
         max_seq, kv_bytes_per_token = 2048, 1000
 
-    for module in (ouro, exaone_moe, glm5_next, keye_vl2):
+    for module in (ouro, exaone_moe, glm5_next, keye_vl2, zaya):
         assert module.default_num_pages(Cfg, 16, 16) == 99
-    assert [a for a, _ in seen] == [(16_000, 16, 2048, 16)] * 4
+    assert [a for a, _ in seen] == [(16_000, 16, 2048, 16)] * 5
     assert [kw for _, kw in seen] == [
-        {"multiple": ouro.POOL_PAGE_MULTIPLE}, {}, {}, {}]
+        {"multiple": ouro.POOL_PAGE_MULTIPLE}, {}, {}, {}, {}]
 
 
 # -- (d) knobs the model does not offer are refused by name ----------------------
