@@ -63,9 +63,13 @@ model.**
   is written when the block's last position is (decode: from the
   accumulator; chunk: the blocks that complete inside it, the rest left
   in the accumulator; a chunk starts on a page and so on a block).
-* decode scores a row's ``floor(t / index_kpool)`` pooled rows,
-  ``lax.top_k`` (ties to the lower block), gathers ``index_topk + index_kpool``
-  latent rows through the block table and attends those alone; a chunk
+* decode takes the LIVE rows alone, ``DECODE_ROWS`` at a time (a frozen
+  slot scores, sorts and gathers nothing): scores a row's ``floor(t /
+  index_kpool)`` pooled rows, ``lax.top_k`` (ties to the lower block),
+  finds the picked BLOCKS in the pool by a compare and a sum over the
+  row's block table (``ops/picked_rows.pool_rows``: no gather of
+  scalars), gathers their ``index_topk + index_kpool`` latent rows and
+  attends those alone; a chunk
   scores each of its rows, and runs the dense absorbed product a block
   of cached rows at a time UNDER the picked mask (the counters say which
   was done: ``dsa_rows_fetched`` against ``dsa_rows_picked``).
@@ -78,6 +82,7 @@ tower are not served.
 from __future__ import annotations
 
 import logging
+import math
 from dataclasses import dataclass
 from functools import partial
 from pathlib import Path
@@ -92,6 +97,7 @@ from dora_tpu.models.hf.loader import TensorFiles, read_config
 from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
 from dora_tpu.ops.kda_state_step import kda_state_step
+from dora_tpu.ops.picked_rows import pool_rows
 
 MODEL_TYPES = ("glm5_next_text",)
 
@@ -103,6 +109,13 @@ ATTN_BLOCK = 256
 #: 1, whatever ``gate_lower_bound``), so a block costs ``block^2 * d_k`` a
 #: head; between blocks the state is carried.
 KDA_BLOCK = 16
+#: positions of one block of pooled indexer keys in a decode tick's scoring
+#: loop (a multiple of the page): work follows a group's longest context
+INDEX_BLOCK = 2048
+#: live rows a sparse-latent layer's decode tick scores, sorts, gathers
+#: and attends at a time: its selection follows the rows that are live,
+#: not the slots
+DECODE_ROWS = 4
 #: eps of the indexer's LayerNorm and of the l2 norms (DeepSeek-V3.2's and
 #: Kimi Linear's; neither is a key of the config)
 INDEX_NORM_EPS = 1e-6
@@ -118,7 +131,9 @@ NOT_OFFERED = {
     "DORA_LORA_DIR": "the grouped LoRA matmul is fused into the Qwen kernels",
 }
 
-#: the delta-rule layers' and the sparse-latent layers' counters on the device
+#: the delta-rule layers' and the sparse-latent layers' counters on the
+#: device; ``dsa_rows_fetched`` = the rows a tick's gathers name (their
+#: shapes': a group's, for every group that ran)
 KDA_COUNTERS = (
     "kda_decode_ticks", "kda_row_ticks", "kda_chunks", "kda_chunk_rows",
     "dsa_rows_in_context", "dsa_rows_picked", "dsa_rows_fetched",
@@ -735,23 +750,49 @@ def _masked_softmax(s, seen):
     return p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
 
 
+def decode_group(slots: int) -> int:
+    """The rows of one group of a decode tick's selection: ``DECODE_ROWS``,
+    or what of it divides the slots."""
+    return math.gcd(DECODE_ROWS, slots)
+
+
+def decode_groups(cfg: Glm5NextConfig, slots: int, rows_fetched: int) -> int:
+    """The groups of ``DECODE_ROWS`` live rows that ONE sparse-latent
+    layer's decode ticks ran (1 a tick up to four live rows, 4 with 16),
+    from the rows their gathers named: every group names
+    ``decode_group(slots) x (index_topk + index_kpool)`` a layer. Not a
+    counter of its own on the device: the counters are operands of the
+    chunk program too."""
+    return rows_fetched // (len(cfg.dsa_layers) * decode_group(slots)
+                            * (cfg.idx_topk + cfg.idx_pool))
+
+
 def dsa_decode(blk, cfg: Glm5NextConfig, u, pool, st, positions, block_tables,
-               active):
+               active, live, index_block: int = INDEX_BLOCK):
     """A sparse-latent layer's decode tick: ``u [B, dim]`` (normed), row
     = slot. Each row's latent goes to its page (a frozen row's, at
     position 0 of a zeroed table row, to the null page); its indexer key
     joins the slot's accumulator, and where the row's position closes a
-    block of ``index_kpool`` the block's mean goes to ``"ik"``. A row at
-    ``t >= index_topk`` scores its ``floor(t / index_kpool)`` pooled rows
-    and attends the positions of the top blocks and its own unfinished
-    block; below that it attends ``0..t``. Either way ``index_topk +
-    index_kpool`` latent rows are gathered through the block table.
-    Returns (output [B, dim], pool, state, a look at the selection: the
-    rows attended ``"rows" [B]``, the picked blocks ``"picked" [B,
-    picked_blocks]`` and the output rows themselves)."""
+    block of ``index_kpool`` the block's mean goes to ``"ik"``. Then the
+    LIVE rows alone (``live`` = the slots with the live ones first, and
+    how many they are), ``DECODE_ROWS`` at a time: a row at ``t >=
+    index_topk`` scores its ``floor(t / index_kpool)`` pooled rows (its
+    own pages' a block of ``index_block`` positions at a time, to the
+    group's longest context) and attends the positions of the top blocks
+    and its own unfinished block; below that it attends ``0..t``. Either
+    way ``index_topk + index_kpool`` latent rows a live row are gathered:
+    the ``picked_blocks + 1`` BLOCKS are found in the pool by a compare
+    and a sum over the row's block table, and a block's rows, which follow
+    one another in its page, are named one by one (on the chip a block is
+    no contiguous 4 KB: XLA relays the whole leaf to fetch it whole,
+    KNOWN_ISSUES.md "PR 53"). A frozen row scores, sorts and gathers
+    nothing and puts out zeros. Returns (output [B, dim], pool, state, a
+    look at the selection: the rows attended ``"rows" [B]``, the picked
+    blocks ``"picked" [B, picked_blocks]`` and the output rows
+    themselves)."""
     f32 = jnp.float32
     kvp, ikp, acc = pool["kv"], pool["ik"], st["acc"]
-    page, kp = kvp.shape[1], cfg.idx_pool
+    page, kp, n_picked = kvp.shape[1], cfg.idx_pool, cfg.picked_blocks
     b = u.shape[0]
     rows = jnp.arange(b)
     t = positions
@@ -767,35 +808,72 @@ def dsa_decode(blk, cfg: Glm5NextConfig, u, pool, st, positions, block_tables,
             (summed / kp).astype(ikp.dtype))
         acc = jnp.where(active[:, None],
                         jnp.where(closes[:, None], 0.0, summed), acc)
-    with jax.named_scope("dsa_select"):
-        selecting = active & (t >= cfg.idx_topk)
-        first = jnp.broadcast_to(jnp.arange(cfg.picked_blocks), (b, cfg.picked_blocks))
+    order, n_live = live
+    r = decode_group(b)
+    first = jnp.broadcast_to(jnp.arange(n_picked), (r, n_picked))
+    flat = kvp.reshape(-1, cfg.kv_rank)  # a cached row a position
+    # pages, and their pooled rows, of one block of the scoring loop
+    per = math.gcd(index_block // page, block_tables.shape[1])
+    n_block = per * (page // kp)
+
+    def group(g, carry):
+        ctx, seen_rows, picked = carry
+        mine = jax.lax.dynamic_slice_in_dim(order, g * r, r)  # slots
+        ok = g * r + jnp.arange(r) < n_live
+        t_g, bt = t[mine], block_tables[mine]
+        selecting = ok & (t_g >= cfg.idx_topk)
 
         def scored(_):
-            pooled = ikp[block_tables].reshape(b, -1, cfg.idx_dim)
-            _, ids = jax.lax.top_k(
-                index_scores(cfg, qi, wi, pooled, t // kp), cfg.picked_blocks)
-            return ids
+            complete = jnp.where(selecting, t_g // kp, 0)
 
-        ids = jax.lax.cond(selecting.any(), scored, lambda _: first, None)
-        ids = jnp.where(selecting[:, None], ids, first)
-        picked = (ids[:, :, None] * kp + jnp.arange(kp)).reshape(b, -1)
-        tail = (t // kp * kp)[:, None] + jnp.arange(kp)[None, :]
-        at = jnp.concatenate([picked, tail], 1)  # [B, topk + kpool]
-        seen = jnp.concatenate([
-            selecting[:, None] | (picked <= t[:, None]),
-            selecting[:, None] & (tail <= t[:, None])], 1) & active[:, None]
-        at = jnp.minimum(at, cfg.max_seq - 1)
-        latent = kvp[block_tables[rows[:, None], at // page], at % page]
+            def block(j, s):
+                ids = jax.lax.dynamic_slice_in_dim(bt, j * per, per, 1)
+                part = index_scores(
+                    cfg, qi[mine], wi[mine],
+                    ikp[ids].reshape(r, n_block, cfg.idx_dim),
+                    complete - j * n_block)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    s, part, j * n_block, 1)
+
+            with jax.named_scope("dsa_index"):
+                s = jax.lax.fori_loop(
+                    0, (complete.max() + n_block - 1) // n_block, block,
+                    jnp.full((r, bt.shape[1] * (page // kp)), -jnp.inf, f32))
+            return jax.lax.top_k(s, n_picked)[1]
+
+        with jax.named_scope("dsa_select"):
+            ids = jax.lax.cond(selecting.any(), scored, lambda _: first, None)
+            ids = jnp.where(selecting[:, None], ids, first)
+            picked_at = (ids[:, :, None] * kp + jnp.arange(kp)).reshape(r, -1)
+            tail = (t_g // kp * kp)[:, None] + jnp.arange(kp)[None, :]
+            seen = jnp.concatenate([
+                selecting[:, None] | (picked_at <= t_g[:, None]),
+                selecting[:, None] & (tail <= t_g[:, None])], 1) & ok[:, None]
+            # the picked blocks, then the unfinished one; a block's rows
+            # follow one another in its page: [r, topk + kpool, kv_rank]
+            held = jnp.concatenate([ids, (t_g // kp)[:, None]], 1)
+            at = pool_rows(bt, held, page // kp)[:, :, None] * kp + jnp.arange(kp)
+            latent = flat[at.reshape(r, -1)]
+        with jax.named_scope("dsa_attend"):
+            s = jnp.einsum("bhc,bnc->bhn", q_abs[mine], latent,
+                           preferred_element_type=f32) * cfg.softmax_scale
+            p = _masked_softmax(s, seen[:, None, :])
+            mix = jnp.einsum("bhn,bnc->bhc", p.astype(latent.dtype), latent,
+                             preferred_element_type=f32)
+        # a short last group's spare entries are frozen slots: zeros there
+        return (ctx.at[mine].set(mix),
+                seen_rows.at[mine].set(seen.sum(-1, dtype=jnp.int32)),
+                picked.at[mine].set(ids))
+
+    ctx, seen_rows, picked = jax.lax.fori_loop(
+        0, (n_live + r - 1) // r, group,
+        (jnp.zeros((b, cfg.heads, cfg.kv_rank), f32),
+         jnp.zeros((b,), jnp.int32),
+         jnp.broadcast_to(jnp.arange(n_picked), (b, n_picked))))
     with jax.named_scope("dsa_attend"):
-        s = jnp.einsum("bhc,bnc->bhn", q_abs, latent,
-                       preferred_element_type=f32) * cfg.softmax_scale
-        p = _masked_softmax(s, seen[:, None, :])
-        ctx = jnp.einsum("bhn,bnc->bhc", p.astype(latent.dtype), latent,
-                         preferred_element_type=f32)
         out = L.mla_output(blk, cfg, ctx)
     return out, {"kv": kvp, "ik": ikp}, {"acc": acc}, {
-        "rows": seen.sum(-1, dtype=jnp.int32), "picked": ids, "attended": out}
+        "rows": seen_rows, "picked": picked, "attended": out}
 
 
 def picked_mask(cfg: Glm5NextConfig, ids, q_pos, n_blocks: int):
@@ -941,7 +1019,8 @@ def _layers(params, cfg: Glm5NextConfig, x, pools, state, stats, mix, attend,
 
 
 def paged_batch_rows(params, cfg: Glm5NextConfig, tokens, pools, state, stats,
-                     positions, block_tables, active, picks: bool = False):
+                     positions, block_tables, active, picks: bool = False,
+                     index_block: int = INDEX_BLOCK):
     """One decode step for B = slots independent sequences: tokens,
     positions, active ``[B]``, block_tables ``[B, max_pages]`` (a frozen
     row comes with position 0 and a zeroed table row, so its latent lands
@@ -951,20 +1030,24 @@ def paged_batch_rows(params, cfg: Glm5NextConfig, tokens, pools, state, stats,
     state, stats), and with ``picks`` each sparse-latent layer's look
     last (:func:`dsa_decode`'s ``"picked"`` and ``"attended"``)."""
     x = params["embed"].astype(L.compute_dtype())[tokens]
+    i32 = jnp.int32
+    live = active.sum(dtype=i32)
+    # the slots with the live ones first (in slot order), and how many
+    ordered = jnp.argsort(~active, stable=True), live
 
     def mix(blk, u, st):
         return kda_step(blk, cfg, u, st, active)
 
     def attend(blk, u, pool, st):
         return dsa_decode(blk, cfg, u, pool, st, positions, block_tables,
-                          active)
+                          active, ordered, index_block)
 
     x, pools, state, routed, looks = _layers(
         params, cfg, x, pools, state, stats["moe"], mix, attend, active,
         active, True)
-    i32 = jnp.int32
-    live = active.sum(dtype=i32)
     n_dsa = len(cfg.dsa_layers)
+    r = decode_group(active.shape[0])
+    groups = (live + r - 1) // r
     selecting = active & (positions >= cfg.idx_topk)
     kda = PM.add_counts(
         stats["kda"],
@@ -973,7 +1056,8 @@ def paged_batch_rows(params, cfg: Glm5NextConfig, tokens, pools, state, stats,
         dsa_rows_in_context=n_dsa * jnp.where(active, positions + 1, 0).sum(
             dtype=i32),
         dsa_rows_picked=sum(a["rows"].sum(dtype=i32) for a in looks),
-        dsa_rows_fetched=n_dsa * (cfg.idx_topk + cfg.idx_pool) * live,
+        # a short last group gathers for its spare entries too
+        dsa_rows_fetched=n_dsa * (cfg.idx_topk + cfg.idx_pool) * groups * r,
         dsa_index_rows_scored=n_dsa * jnp.where(
             selecting, positions // cfg.idx_pool, 0).sum(dtype=i32),
         dsa_row_ticks_selecting=selecting.sum(dtype=i32),
@@ -1132,6 +1216,8 @@ def report(cfg: Glm5NextConfig, page_size: int, totals: dict, engine) -> dict:
         # raw, for a reader that takes it over a capture's ticks
         "moe_touched": int(totals["moe"]["touched"]),
         **{name: int(totals["kda"][name]) for name in KDA_COUNTERS},
+        "dsa_decode_groups": decode_groups(
+            cfg, engine.max_slots, int(totals["kda"]["dsa_rows_fetched"])),
         "kv_bytes_per_token": cfg.kv_bytes_per_token,
         "kv_pool_bytes": (engine.allocator.num_pages * page_size
                           * cfg.kv_bytes_per_token),

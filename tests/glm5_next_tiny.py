@@ -155,13 +155,16 @@ def make_engine(cfg, params, **kw):
 
 
 @functools.lru_cache(maxsize=None)
-def programs(cfg):
+def programs(cfg, index_block: int = G.INDEX_BLOCK):
     """The two programs as the engine jits them, but with logits where
-    the greedy tokens would be (cfg is static; one trace a config)."""
+    the greedy tokens would be (cfg is static; one trace a config).
+    ``index_block``: the positions of one block of a tick's scoring loop
+    (the engine's covers ``MAX_SEQ`` in one)."""
     return (
         jax.jit(lambda p, *a: G.paged_chunk_logits(p, cfg, *a, block=BLOCK,
                                                    picks=True)),
-        jax.jit(lambda p, *a: G.paged_batch_logits(p, cfg, *a, picks=True)),
+        jax.jit(lambda p, *a: G.paged_batch_logits(
+            p, cfg, *a, picks=True, index_block=index_block)),
     )
 
 
@@ -171,23 +174,25 @@ class Served:
     own. ``dirty``: every slot-state leaf starts as an earlier stream
     left it (a chunk at position 0 must zero-start)."""
 
-    def __init__(self, cfg, params, chunk: int = CHUNK, dirty: bool = True):
-        self.cfg, self.params, self.chunk = cfg, params, chunk
-        self.chunk_fn, self.tick_fn = programs(cfg)
-        pages = SLOTS * MAX_SEQ // PAGE + 1
+    def __init__(self, cfg, params, chunk: int = CHUNK, dirty: bool = True,
+                 slots: int = SLOTS, index_block: int = G.INDEX_BLOCK):
+        self.cfg, self.params, self.chunk, self.slots = cfg, params, chunk, slots
+        self.chunk_fn, self.tick_fn = programs(cfg, index_block)
+        pages = slots * MAX_SEQ // PAGE + 1
         self.pools = G.init_page_pool(cfg, pages, PAGE)
-        self.state = G.init_slot_state(cfg, SLOTS)
+        self.state = G.init_slot_state(cfg, slots)
         if dirty:
             self.state = jax.tree.map(lambda a: a + 3.0, self.state)
             self.pools = jax.tree.map(lambda a: a + 2.0, self.pools)
         self.stats = G.init_counters(cfg)
         per = MAX_SEQ // PAGE
-        self.bts = np.zeros((SLOTS, per), np.int32)
-        for b in range(SLOTS):
+        self.bts = np.zeros((slots, per), np.int32)
+        for b in range(slots):
             self.bts[b] = 1 + b * per + np.arange(per)
-        self.positions = np.zeros((SLOTS,), np.int32)
+        self.positions = np.zeros((slots,), np.int32)
         self.picked = {}  # slot -> [T, picked_blocks] of the chunks
         self.ticked = {}  # slot -> [[picked_blocks] a decode tick]
+        self.look = None  # the last tick's look, every slot's row
 
     def prefill(self, slot: int, prompt: list[int], pad_id: int = 0):
         """Chunked prefill into ``slot``; the prompt's logits [T, vocab]."""
@@ -210,8 +215,8 @@ class Served:
         """One decode tick: ``tokens`` = slot -> its next input token;
         the other rows are frozen (position 0, zeroed table row). ->
         slot -> logits [vocab]."""
-        active = np.zeros((SLOTS,), bool)
-        toks = np.zeros((SLOTS,), np.int32)
+        active = np.zeros((self.slots,), bool)
+        toks = np.zeros((self.slots,), np.int32)
         for b, tok in tokens.items():
             active[b], toks[b] = True, tok
         pos = np.where(active, self.positions, 0).astype(np.int32)
@@ -219,8 +224,9 @@ class Served:
         logits, self.pools, self.state, self.stats, picks = self.tick_fn(
             self.params, jnp.asarray(toks), self.pools, self.state, self.stats,
             jnp.asarray(pos), jnp.asarray(bts), jnp.asarray(active))
+        self.look = jax.tree.map(np.asarray, picks[0])
         for b in tokens:
-            self.ticked.setdefault(b, []).append(np.asarray(picks[0]["picked"][b]))
+            self.ticked.setdefault(b, []).append(self.look["picked"][b])
         self.positions[active] += 1
         return {b: np.asarray(logits[b]) for b in tokens}
 

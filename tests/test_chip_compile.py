@@ -941,6 +941,59 @@ def test_glm5_window_program_compiles_and_moves_no_cache(chip, picks):
     assert _expert_stack_readers(compiled, stack) == []
 
 
+def _block_a_row(kv, ids):
+    return kv.reshape(-1, 4 * kv.shape[-1])[ids].reshape(4, -1, kv.shape[-1])
+
+
+def _block_a_slice(kv, ids):
+    def block(i):
+        return jax.lax.dynamic_slice(
+            kv, (i // 4, i % 4 * 4, 0), (1, 4, kv.shape[-1]))[0]
+
+    return jax.vmap(jax.vmap(block))(ids).reshape(4, -1, kv.shape[-1])
+
+
+def _row_at_a_time(kv, ids):
+    at = (ids[:, :, None] * 4 + jnp.arange(4)).reshape(4, -1)
+    return kv.reshape(-1, kv.shape[-1])[at]
+
+
+@pytest.mark.parametrize("fetch,relaid", [
+    (_block_a_row, " reshape("), (_block_a_slice, " copy("),
+    (_row_at_a_time, None)], ids=["block-a-row", "block-a-slice", "row-at-a-time"])
+def test_xla_relays_the_latent_pool_to_fetch_a_pooled_block_whole(
+        chip, fetch, relaid):
+    """Why ``glm5_next.dsa_decode`` names a picked block's four latent rows
+    one by one: the four follow one another in their page, 4 KB in
+    row-major order, but not on the chip, where a ``[P, 16, 512]`` bf16
+    leaf lies in tiles of 16 rows x 128 lanes. The pool seen a block a
+    row (``[P * 4, 2048]``) is another layout, and so is a gather of
+    ``[4, 512]`` slices at an offset inside the page: for either XLA
+    relays the WHOLE leaf, 268 MB read and written a tick, before it
+    gathers 8 MB from it. Seen a position a row (``[P * 16, 512]``) the
+    leaf is a bitcast and nothing moves (``PERF.md`` section 6, PR 53).
+    The day the first two compile without the copy, a block is one fetch
+    of four."""
+    pool = SLOTS * GLM5_SEQ // PAGE + 1
+
+    def tick(kv, ids, row, pages):
+        kv = kv.at[pages, pages % PAGE].set(row)  # the pool is a result too
+        return kv, fetch(kv, ids)
+
+    compiled = jax.jit(tick, donate_argnums=(0,)).lower(*chip((
+        _s((pool, PAGE, 512), jnp.bfloat16), _s((4, 513), I32),
+        _s((SLOTS, 512), jnp.bfloat16), _s((SLOTS,), I32)))).compile()
+    whole = [line.strip()[:100] for line in compiled.as_text().splitlines()
+             if re.search(r"= bf16\[(16385,16,512|65540,2048)\]\S* (reshape|copy)\(",
+                          line)]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if relaid is None:
+        assert whole == [] and temp < 1 << 20
+    else:
+        assert [line for line in whole if relaid in line], whole
+        assert temp >= pool * PAGE * 512 * 2
+
+
 @pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
 def test_glm5_chunk_program_compiles_and_moves_no_cache(chip, picks):
     """The 256-row prefill chunk: the blocked delta rule (16 blocks of 16

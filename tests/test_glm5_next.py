@@ -23,6 +23,7 @@ the logits past float32 noise; test (f) holds the published value.
 
 from __future__ import annotations
 
+import copy
 import json
 
 import jax
@@ -34,7 +35,7 @@ from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf import glm5_next as G
 from dora_tpu.models.hf import glm5_next_reference as R
 from tests.glm5_next_tiny import (  # noqa: F401  (ckpt, model: fixtures)
-    BLOCK, CHUNK, KINDS, KPOOL, MAX_SEQ, SLOTS, TINY, TOL, TOPK, Served,
+    BLOCK, CHUNK, KINDS, KPOOL, MAX_SEQ, PAGE, SLOTS, TINY, TOL, TOPK, Served,
     ckpt, held_of, make_engine, model, prompt_ids, reference_logits,
 )
 
@@ -247,6 +248,155 @@ def test_a_tick_steps_the_state_in_one_kernel_a_layer_and_selects_none(model,
         jnp.asarray(CHUNK, i32), jnp.asarray(0, i32))
     assert "kda_state_step" not in _kernels_and_state_selects(
         chunk.jaxpr, state_shape)[0]
+
+
+# -- (e) a tick selects, addresses and gathers for the live rows alone ----------
+
+WIDE = 16  # slots: four groups of DECODE_ROWS
+#: the prompt in each of the sixteen slots: below ``index_topk``, at it, past
+#: it; ends inside a block, on a block's last row and on a page's
+WIDE_PROMPTS = [5, 16, 19, 23, 31, 37, 40, 47, 52, 58, 63, 64, 70, 75, 81, 90]
+WIDE_TICKS = 3
+
+
+@pytest.fixture(scope="module")
+def wide(model):
+    """Sixteen slots, every one prefilled (a frozen slot has a state, a
+    tail, an accumulator and pages to lose), and the reference's logits,
+    picked blocks and sparse-latent output rows of each stream followed by
+    its decode tokens."""
+    cfg, params, rp = model
+    # four pages a block of the scoring loop: up to four turns a group
+    served = Served(cfg, params, slots=WIDE, index_block=4 * PAGE)
+    streams, want = {}, {}
+    real, outs = R.dsa, []
+
+    def spy(*args, **kw):  # the reference keeps no sublayer output: listen
+        out, cached = real(*args, **kw)
+        outs.append(np.asarray(out))
+        return out, cached
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(R, "dsa", spy)
+        for b, n in enumerate(WIDE_PROMPTS):
+            streams[b] = (prompt_ids(n, seed=300 + b),
+                          prompt_ids(WIDE_TICKS, seed=400 + b))
+            served.prefill(b, streams[b][0])
+            tokens = jnp.asarray(streams[b][0] + streams[b][1])
+            logits, kept = R.forward(rp, cfg, tokens, held=held_of(cfg), rows=True)
+            want[b] = {"logits": np.asarray(logits),
+                       "picked": np.asarray(kept[4]["picked"]),
+                       "attended": outs.pop()}
+    return served, streams, want
+
+
+def _rows_attended(t: int) -> int:
+    return TOPK + t % KPOOL + 1 if t >= TOPK else t + 1
+
+
+@pytest.mark.parametrize("live", [
+    (1, 5, 6, 11, 15),     # scattered: a whole group and a short one
+    (),                    # none live: no group runs
+    tuple(range(WIDE)),    # all live: four whole groups
+    (0, 2, 3, 4, 9, 12),   # rows below index_topk beside selecting ones
+    (7,),                  # one row: one group, three spare entries
+    (3, 8, 10, 14),        # exactly one group
+], ids=["scattered", "none", "all", "short-last-group", "one", "one-group"])
+def test_a_tick_selects_and_gathers_for_the_live_rows_alone(wide, live):
+    """Whatever slots are live, a live row picks the reference's blocks,
+    attends them and its tail and nothing else, and puts out the
+    reference's row; a frozen row puts out zeros and every cache,
+    accumulator and state of its slot stays bit for bit; the counters
+    read the groups that ran and the rows their gathers named."""
+    prefilled, streams, want = wide
+    served = copy.copy(prefilled)  # the arrays are immutable; ticks rebind them
+    served.positions, served.ticked = prefilled.positions.copy(), {}
+    cfg = served.cfg
+    frozen = [b for b in range(WIDE) if b not in live]
+    state = jax.tree.map(np.asarray, served.state)
+    pools = jax.tree.map(np.asarray, served.pools["4"])
+    stats = {k: int(v) for k, v in served.stats["kda"].items()}
+    picked_rows = 0
+    for k in range(WIDE_TICKS):
+        t = {b: len(streams[b][0]) + k for b in live}
+        rows = served.tick({b: streams[b][1][k] for b in live})
+        look = served.look
+        assert look["picked"].shape == (WIDE, TOPK // KPOOL)
+        assert look["attended"].shape == (WIDE, cfg.dim)
+        for b in live:
+            if t[b] >= TOPK:
+                assert set(look["picked"][b]) == set(want[b]["picked"][t[b]]), (b, k)
+            assert np.abs(look["attended"][b] - want[b]["attended"][t[b]]).max() < TOL
+            assert np.abs(rows[b] - want[b]["logits"][t[b]]).max() < TOL, (b, k)
+            picked_rows += _rows_attended(t[b])
+        assert (look["attended"][frozen] == 0).all()
+    for key, leaves in served.state.items():
+        for name, leaf in leaves.items():
+            leaf = np.asarray(leaf)
+            assert (leaf[frozen] == state[key][name][frozen]).all(), (key, name)
+            for b in live:
+                assert (leaf[b] != state[key][name][b]).any(), (key, name, b)
+    theirs = [int(p) for b in frozen for p in served.bts[b]]
+    for name in ("kv", "ik"):
+        assert (np.asarray(served.pools["4"][name])[theirs]
+                == pools[name][theirs]).all()
+    gained = {k: int(v) - stats[k] for k, v in served.stats["kda"].items()}
+    groups = -(-len(live) // G.DECODE_ROWS)
+    assert G.decode_group(WIDE) == G.DECODE_ROWS == 4
+    assert gained["dsa_rows_fetched"] == WIDE_TICKS * groups * 4 * (TOPK + KPOOL)
+    assert G.decode_groups(cfg, WIDE, gained["dsa_rows_fetched"]) == (
+        WIDE_TICKS * groups)
+    assert gained["dsa_rows_picked"] == picked_rows  # the tail's rows among them
+    assert gained["kda_decode_ticks"] == (WIDE_TICKS if live else 0)
+    assert gained["kda_row_ticks"] == 4 * WIDE_TICKS * len(live)
+
+
+def _gathers(jaxpr):
+    """Every ``gather`` equation, sub-programs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _gathers(sub)
+    return found
+
+
+@pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
+def test_a_tick_gathers_no_address_and_a_groups_latent_rows(model, picks):
+    """The decode tick (the served program and the audit's) takes no
+    picked row's address by a gather of scalars through the block table
+    (XLA:TPU gathers scalars one at a time: 319 us a tick at the served
+    shapes): the table gives up whole rows, a group's, and the one page
+    a slot's new row goes to. The latent rows come out of ONE gather a
+    group, ``DECODE_ROWS x (index_topk + index_kpool)`` rows of
+    ``kv_rank``, never a slot's worth for every slot."""
+    cfg, params, _ = model
+    served = Served(cfg, params, dirty=False, slots=WIDE)
+    i32 = jnp.int32
+    tick = jax.make_jaxpr(
+        lambda p, *a: G.paged_batch_logits(p, cfg, *a, picks=picks))(
+        params, jnp.zeros((WIDE,), i32), served.pools, served.state,
+        served.stats, jnp.zeros((WIDE,), i32), jnp.asarray(served.bts),
+        jnp.ones((WIDE,), bool))
+    gathers = _gathers(tick.jaxpr)
+    max_pages = served.bts.shape[1]
+    through_table = [
+        e for e in gathers
+        if e.invars[0].aval.dtype == jnp.int32
+        and e.invars[0].aval.shape[1:] == (max_pages,)]  # [rows, max_pages]
+    scalars = [e for e in through_table
+               if set(e.params["slice_sizes"]) == {1}]
+    # the page each slot's new row is written to, and nothing else
+    assert [e.outvars[0].aval.shape for e in scalars] == [(WIDE,)]
+    assert {e.outvars[0].aval.shape for e in through_table} == {
+        (WIDE,), (G.DECODE_ROWS, max_pages)}
+    flat_rows = served.pools["4"]["kv"].shape[0] * served.pools["4"]["kv"].shape[1]
+    latent = [e for e in gathers
+              if e.invars[0].aval.shape == (flat_rows, cfg.kv_rank)]
+    assert [(e.outvars[0].aval.shape, tuple(e.params["slice_sizes"]))
+            for e in latent] == [
+        ((G.DECODE_ROWS, TOPK + KPOOL, cfg.kv_rank), (1, cfg.kv_rank))]
 
 
 # -- (f) the delta rule's blocked form, Sinkhorn, the picked sets ------------------

@@ -89,6 +89,11 @@ def test_engine_tokens_are_the_references_argmax(model):
         4 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4) + 8 * 4)
     assert report["moe_tokens"] > 0 and len(report["moe_expert_tokens"]) == 2
     assert report["kda_row_ticks"] > 0 and report["dsa_row_ticks_selecting"] > 0
+    # three slots: groups of one row, so a group a live row a tick
+    assert G.decode_group(SLOTS) == 1
+    assert report["dsa_decode_groups"] == report["kda_row_ticks"] // 4
+    assert report["dsa_rows_fetched"] == (
+        report["dsa_decode_groups"] * (TOPK + KPOOL))
     assert set(engine.pools) == {"4"} and set(engine.pools["4"]) == {"kv", "ik"}
     assert engine.pools["4"]["ik"].shape[1:] == (PAGE // KPOOL, 8)
     assert {k: set(v) for k, v in engine.slot_state.items()} == {
