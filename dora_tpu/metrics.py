@@ -199,6 +199,7 @@ class ServingMetrics:
         "lora_resident", "lora_max_resident", "lora_resident_bytes",
         "lora_loads", "lora_evictions", "adapter_streams",
         "adapter_stalls", "model", "capture_counters", "phases",
+        "stages",
     )
 
     def __init__(self, engine: str = "paged"):
@@ -227,6 +228,16 @@ class ServingMetrics:
         #: benchmark and the server's exit line, and deliberately not by
         #: prom / alerts / metrics_history / the CLI views.
         self.phases = {name: Histogram() for name in telemetry.LOOP_PHASES}
+        #: a request's stages up to its first message sent
+        #: (telemetry.REQUEST_STAGES), one histogram a row that this
+        #: process records, fed by ServingTracer: what ``ttft`` is made
+        #: of. Top-level snapshot keys ``stage_<stage>_us``, read as the
+        #: phases are and by nothing else.
+        self.stages = {
+            name: Histogram()
+            for name in telemetry.stages_observed("arrival")
+            + telemetry.stages_observed("first_send")
+        }
         #: blocking device->host fetch durations (the sync points:
         #: chunk greedy reads, the [B, K+1] window matrix), observed by
         #: the engine via its ``serving_metrics`` hook
@@ -494,6 +505,7 @@ class ServingMetrics:
             "adapter_stalls": self.adapter_stalls,
             "capture_counters": dict(self.capture_counters),
             **self.phase_snapshots(),
+            **self.stage_snapshots(),
             **self.model,
         }
 
@@ -501,6 +513,12 @@ class ServingMetrics:
         return {
             telemetry.phase_histogram_key(name): h.snapshot()
             for name, h in self.phases.items()
+        }
+
+    def stage_snapshots(self) -> dict:
+        return {
+            telemetry.stage_histogram_key(name): h.snapshot()
+            for name, h in self.stages.items()
         }
 
     def first_token_reads(self) -> dict:

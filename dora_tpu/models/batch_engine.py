@@ -1171,6 +1171,8 @@ class PagedBatchEngine:
         )
         if state:
             (self.slot_state,) = state
+        # the request's first chunk ends its wait in the prefill queue
+        self.tracer.request_chunk(s.request_id, t_chunk)
         self.chunks_run += 1
         self.dispatches += 1
         if self.device_monitor:
@@ -1275,6 +1277,7 @@ class PagedBatchEngine:
         # Host-index AFTER a full [C] fetch: 1 KB, no program.
         token = int(self._np.asarray(greedy)[row])
         t_first = tracer.clock()
+        tracer.request_token(s.request_id, t_first)
         if self.device_monitor:
             self.device_fetch_ns += int((t_first - t_ready) * 1e9)
         self.fetches += 1

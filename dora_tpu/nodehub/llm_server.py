@@ -439,12 +439,86 @@ def _flush(held: list, emit) -> int:
     return n
 
 
+class ProfileCapture:
+    """An on-demand profiler capture (``cm.StartProfile`` /
+    ``StopProfile``), as the serving loop sees it. ``handle`` takes the
+    PROFILE events; while ``active`` the loop calls ``tick(now)`` every
+    turn, so the deadline is met within a turn and not at the next
+    once-a-second report. Both edges fall between a ``collect()`` and
+    the next ``dispatch()``: the model's counters are read there
+    (``on_edge``), so the two reads bracket exactly the ticks the
+    capture holds (a chunk that went ahead may be in flight, and the
+    read waits for it, at either edge). Writing the capture
+    (``stop``) runs on the loop's thread and holds it, seconds to a
+    minute on the chip: on a thread of its own the write let the loop go
+    on but took several times as long, past the end of a benchmark
+    window (``PERF.md`` section 6, PR 54)."""
+
+    def __init__(self, node, tracer, clock, on_edge,
+                 start=profiling.start_capture, stop=profiling.stop_capture):
+        self._node, self._tracer, self._clock = node, tracer, clock
+        self._on_edge, self._start, self._stop = on_edge, start, stop
+        self.active = False
+        self._dir = ""
+        self._deadline = 0.0
+        self._start_error: str | None = None
+
+    def _reply(self, artifact: str, error: str | None) -> None:
+        try:
+            self._node.report_profile(artifact, error)
+        except Exception:
+            pass  # capture is best-effort; serving never blocks on it
+
+    def handle(self, event) -> None:
+        md = event.get("metadata") or {}
+        action = md.get("action", "")
+        if action == "start":
+            if self.active:
+                self._reply("", "capture already active")
+                return
+            self._dir = os.path.join(
+                profiling.profile_dir(),
+                f"capture-{os.getpid()}-{int(time.time())}",
+            )
+            try:
+                self._start_error = self._start(self._dir)
+            except Exception as exc:  # on the chip: an error reply
+                self._reply("", f"{type(exc).__name__}: {exc}")
+                return
+            self.active = True
+            self._on_edge("start")
+            self._deadline = self._clock() + float(md.get("seconds") or 0.0)
+            self._tracer.instant("profile_start", "(engine)", self._dir)
+        elif action == "stop":
+            if self.active:
+                self._finish()
+            else:
+                self._reply("", "no capture active")
+
+    def tick(self, now: float) -> None:
+        """One turn of the loop with a capture active."""
+        if now >= self._deadline:
+            self._finish()
+
+    def _finish(self) -> None:
+        artifact, error = "", None
+        self._on_edge("stop")
+        try:
+            artifact = self._stop(self._dir, self._start_error)
+        except Exception as exc:  # on the chip a failed capture is said so
+            error = f"{type(exc).__name__}: {exc}"
+        self.active = False
+        self._start_error = None
+        self._tracer.instant("profile_stop", "(engine)", artifact or error)
+        self._reply(artifact, error)
+
+
 def _run_loop(node, engine, backlog, metrics, handle_input, emit,
               report, clock=time.monotonic, on_tick=None, on_step=None,
-              handle_migrate=None, handle_profile=None,
+              handle_migrate=None,
               on_engine_error=None, keep_alive=False,
               fleet_tick=None, held: list | None = None,
-              tracer=None) -> None:
+              tracer=None, capture: ProfileCapture | None = None) -> None:
     """Window-granular serving loop, factored out of :func:`main` so
     tests can drive it with fake nodes/engines. Each iteration: drain
     the pending events, ``engine.dispatch()`` (one prefill chunk, then
@@ -479,7 +553,9 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
     window boundary, ``on_engine_error()`` fails in-flight requests
     before a step exception propagates. ``keep_alive`` parks instead of
     exiting when the input stream ends (migration targets wait for
-    handoffs until STOP).
+    handoffs until STOP). ``capture`` (a :class:`ProfileCapture`) takes
+    the PROFILE events and, while one is active, a look every turn: its
+    deadline is met within a turn, not at the next report.
 
     A turn is tiled by the phases of ``telemetry.LOOP_PHASES``: the
     loop ``tracer.switch()``es from one to the next here, the engine
@@ -543,8 +619,8 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
                 elif event["type"] == "MIGRATE" and handle_migrate is not None:
                     _flush(held, emit)
                     handle_migrate(event)
-                elif event["type"] == "PROFILE" and handle_profile is not None:
-                    handle_profile(event)
+                elif event["type"] == "PROFILE" and capture is not None:
+                    capture.handle(event)
             if stop:
                 break
             if (
@@ -612,6 +688,8 @@ def _run_loop(node, engine, backlog, metrics, handle_input, emit,
             tracer.switch("admit")
             backlog.drain()
             now = tracer.switch("housekeeping")
+            if capture is not None and capture.active:
+                capture.tick(now)
             if now - report_last >= 1.0:
                 report(now)
                 report_last = now
@@ -649,6 +727,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     # this process has loaded JAX (the one that holds the chip does;
     # nothing here imports it).
     tracer.histograms = metrics.phases
+    tracer.stage_histograms = metrics.stages
     jax = sys.modules.get("jax")
     if jax is not None:
         tracer.annotation = jax.profiler.TraceAnnotation
@@ -764,8 +843,14 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         if t0 is not None:
             # The loop sends a first token right after the dispatch
             # that read it, before the window it joins is collected:
-            # what is observed here is what the client saw.
-            metrics.ttft.observe(max(0.0, clock() - t0) * 1e6)
+            # what is observed here is what the client saw. The
+            # request's stages end on the same stamp, and the send
+            # itself is the front's to time: its stamp rides this, the
+            # stream's first message, alone.
+            now = clock()
+            metrics.ttft.observe(max(0.0, now - t0) * 1e6)
+            tracer.request_sent(key, now)
+            meta[telemetry.STAMP_EMIT] = time.time_ns()
         node.send_output("response", pa.array([text]), meta)
         if done:
             wire_ids.pop(key, None)
@@ -810,6 +895,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
 
     def on_admit(key: str, waited_s: float) -> None:
         metrics.backlog_wait.observe(waited_s * 1e6)
+        tracer.request_admitted(key, waited_s)
         reason = backlog.stall_reason(key)
         if reason == "adapter_residency":
             stall_tags[key] = reason
@@ -912,6 +998,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
     def handle_input(event) -> None:
         from dora_tpu.telemetry import OTEL_CTX_KEY
 
+        t_in_ns = time.time_ns()
         meta = event.get("metadata") or {}
         rid = meta.get("request_id")
         if ckpt_dir and rid is not None:
@@ -934,6 +1021,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         key = f"req-{req_counter[0]}"
         wire_ids[key] = rid
         metrics.requests += 1
+        tracer.request_arrived(meta, t_in_ns)
         # Engine spans join the trace of the message that carried the
         # request in — one trace id covers send → route → deliver →
         # queued → admitted → … → finish.
@@ -995,6 +1083,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             emit_text(key, "", True, finish="rejected", extra=extra)
         else:
             t_admitted[key] = clock()
+            tracer.request_pushed(key, t_admitted[key])
             if qos.preempt_on:
                 req_prompt[key] = list(ids)
                 req_emitted[key] = []
@@ -1179,77 +1268,13 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         profiling.DeviceMonitor() if profiling.monitor_enabled() else None
     )
     util_prev = {"busy_ns": 0, "flops": 0, "t": clock()}
-    # On-demand deep capture (cm.StartProfile/StopProfile): start arms
-    # a deadline checked at report cadence; stop (or the deadline)
-    # closes the capture and reports the artifact path to the daemon.
-    profile_state: dict = {
-        "active": False, "dir": "", "deadline": 0.0, "start_error": None,
-    }
-
+    # On-demand deep capture (cm.StartProfile/StopProfile): the loop
+    # looks at an active one every turn (ProfileCapture).
     def _capture_edge(edge: str) -> None:
-        # Both edges fall between a collect() and the next dispatch():
-        # no window is in flight, so the two reads bracket exactly the
-        # ticks the capture holds. A chunk that went ahead may be: the
-        # read waits for it, at either edge.
         if engine.model_counters is not None:
             metrics.capture_counters[edge] = engine.model_counters()
 
-    def _finish_profile() -> None:
-        artifact, error = "", None
-        _capture_edge("stop")
-        try:
-            artifact = profiling.stop_capture(
-                profile_state["dir"], profile_state["start_error"]
-            )
-        except Exception as exc:  # on the chip a failed capture is said so
-            error = f"{type(exc).__name__}: {exc}"
-        profile_state["active"] = False
-        profile_state["start_error"] = None
-        tracer.instant("profile_stop", "(engine)", artifact or error)
-        try:
-            node.report_profile(artifact, error)
-        except Exception:
-            pass  # capture is best-effort; serving never blocks on it
-
-    def handle_profile(event) -> None:
-        md = event.get("metadata") or {}
-        action = md.get("action", "")
-        if action == "start":
-            if profile_state["active"]:
-                try:
-                    node.report_profile("", "capture already active")
-                except Exception:
-                    pass
-                return
-            out_dir = os.path.join(
-                profiling.profile_dir(),
-                f"capture-{os.getpid()}-{int(time.time())}",
-            )
-            profile_state["dir"] = out_dir
-            try:
-                profile_state["start_error"] = profiling.start_capture(
-                    out_dir
-                )
-            except Exception as exc:  # on the chip: an error reply
-                try:
-                    node.report_profile("", f"{type(exc).__name__}: {exc}")
-                except Exception:
-                    pass
-                return
-            profile_state["active"] = True
-            _capture_edge("start")
-            profile_state["deadline"] = clock() + float(
-                md.get("seconds") or 0.0
-            )
-            tracer.instant("profile_start", "(engine)", out_dir)
-        elif action == "stop":
-            if profile_state["active"]:
-                _finish_profile()
-            else:
-                try:
-                    node.report_profile("", "no capture active")
-                except Exception:
-                    pass
+    capture = ProfileCapture(node, tracer, clock, _capture_edge)
 
     # Fleet plane: publish this engine's state digest on its own cadence
     # (DORA_FLEET_DIGEST_S; 0 disables), piggybacked on the report path
@@ -1336,8 +1361,6 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             util_prev["busy_ns"] = metrics.device_compute_ns
             util_prev["flops"] = metrics.useful_flops
             util_prev["t"] = now
-        if profile_state["active"] and now >= profile_state["deadline"]:
-            _finish_profile()
         check_slo(now)
         autotune(now)
         try:
@@ -1646,6 +1669,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
         # restart policy respawns the node.
         engine_failed[0] = True
         for key in list(wire_ids):
+            t_admitted.pop(key, None)  # an error is no first token
             try:
                 emit_text(key, "", True, finish="error")
             except Exception:
@@ -1674,7 +1698,7 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             on_tick=on_tick if recovery_on else None,
             on_step=on_step if ckpt_dir else None,
             handle_migrate=handle_migrate,
-            handle_profile=handle_profile,
+            capture=capture,
             on_engine_error=on_engine_error,
             keep_alive=bool(migrate_dir),
             fleet_tick=fleet_pub.tick if fleet_pub.enabled else None,
@@ -1825,6 +1849,12 @@ def main() -> None:
             # what the gap and the emit are made of: one histogram a
             # loop phase (telemetry.LOOP_PHASES), octave counts and all
             **metrics.phase_snapshots(),
+            # a request's time to its first message sent, and what it
+            # is made of: the wait for a slot and one histogram a stage
+            # (telemetry.REQUEST_STAGES) that this process records
+            "ttft_us": metrics.ttft.snapshot(),
+            "backlog_wait_us": metrics.backlog_wait.snapshot(),
+            **metrics.stage_snapshots(),
             # how often a first token's read left the gap (deferred,
             # beside the window) and how often it still held the launch
             "first_token_reads": metrics.first_token_reads(),

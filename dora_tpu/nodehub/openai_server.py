@@ -26,6 +26,14 @@ dataflow (openai-proxy-server/src/main.rs:30-50); this is the axis it
 concedes. Responder contract: every ``response`` message carries
 metadata ``request_id`` (echoed) and ``done`` (bool, last chunk).
 
+Concurrent mode also times its own part of a request's way to its first
+delta (``telemetry.REQUEST_STAGES``): it stamps the request's metadata
+where the body has been read and just before the publish (the responder
+observes those two), and observes here, off the responder's stamp on a
+stream's first message, that message's way back and the hand-off to the
+socket — two histograms, printed cumulatively once a second and at exit
+as a ``dora_tpu.backend front: {json}`` line of this node's log.
+
 Dataflow usage::
 
     - id: api
@@ -48,6 +56,8 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pyarrow as pa
 
+from dora_tpu import backend, telemetry
+from dora_tpu.metrics import Histogram
 from dora_tpu.node import Node
 
 
@@ -71,6 +81,25 @@ def main() -> None:
     # (node.send_output is not thread-safe).
     send_lock = tracked_lock("nodehub.openai.send", allow_blocking=True)
     served = [0]
+    #: this process's rows of telemetry.REQUEST_STAGES, in the table's
+    #: order: the first observed by the main loop, the second by the
+    #: handler threads (under ``stage_lock``, which the report takes too)
+    stage_names = telemetry.stages_observed("api")
+    stages = {name: Histogram() for name in stage_names}
+    on_receive, on_flush = (stages[name] for name in stage_names)
+    stage_lock = tracked_lock("nodehub.openai.stages")
+
+    def report_front() -> None:
+        with stage_lock:
+            payload = {
+                "t_mono": time.monotonic(),
+                **{
+                    telemetry.stage_histogram_key(name): h.snapshot()
+                    for name, h in stages.items()
+                },
+                "requests": on_flush.count,  # that got a first delta
+            }
+        backend.report("front", payload)
 
     class Handler(BaseHTTPRequestHandler):
         def log_message(self, *args):
@@ -104,7 +133,9 @@ def main() -> None:
             stream = bool(body.get("stream"))
             model = body.get("model", "dora-tpu")
             if concurrent:
-                self._serve_concurrent(body, text, stream, model)
+                self._serve_concurrent(
+                    body, text, stream, model, time.time_ns()
+                )
                 return
             with send_lock:
                 # Drain stale responses, publish, await the next one.
@@ -158,16 +189,17 @@ def main() -> None:
                 finally:
                     served[0] += 1
 
-        def _serve_concurrent(self, body, text, stream, model):
+        def _serve_concurrent(self, body, text, stream, model, t_http_ns):
             """Routed request: publish tagged with a request_id, stream
             chunks back as they arrive — other requests interleave
-            freely (the responder batches them; nothing serializes)."""
+            freely (the responder batches them; nothing serializes).
+            ``t_http_ns``: when the body had been read."""
             rid = uuid.uuid4().hex[:12]
             chunks: queue.Queue = queue.Queue()
             with routed_lock:
                 routed[rid] = chunks
             try:
-                meta = {"request_id": rid}
+                meta = {"request_id": rid, telemetry.STAMP_HTTP: t_http_ns}
                 if isinstance(body.get("max_tokens"), int):
                     meta["max_new_tokens"] = body["max_tokens"]
                 # Multi-tenant LoRA routing: the requested model name
@@ -199,6 +231,7 @@ def main() -> None:
                 if isinstance(deadline, (int, float)) and deadline > 0:
                     meta["deadline_ms"] = float(deadline)
                 with send_lock:  # send_output is not thread-safe
+                    meta[telemetry.STAMP_PUBLISH] = time.time_ns()
                     node.send_output("text", pa.array([text]), meta)
                 if stream:
                     self._sse_start()
@@ -209,7 +242,7 @@ def main() -> None:
                 extra: dict = {}  # shed/reject detail (retry_after_ms, ...)
                 while True:
                     try:
-                        delta, done, finish, extra = chunks.get(
+                        delta, done, finish, extra, t_recv_ns = chunks.get(
                             timeout=timeout_s
                         )
                     except queue.Empty:
@@ -225,6 +258,11 @@ def main() -> None:
                     if delta:
                         if stream:
                             self._sse_chunk(model, {"content": delta})
+                            if t_recv_ns is not None:
+                                # the stream's first delta is on the wire
+                                took_us = (time.time_ns() - t_recv_ns) / 1e3
+                                with stage_lock:
+                                    on_flush.observe(took_us)
                         else:
                             parts.append(delta)
                     if done:
@@ -331,10 +369,15 @@ def main() -> None:
     threading.Thread(target=server.serve_forever, daemon=True).start()
     print(f"openai server listening on 127.0.0.1:{server.server_address[1]}")
 
+    reported = time.monotonic()
     try:
         while True:
             if max_requests and served[0] >= max_requests:
                 break
+            now = time.monotonic()
+            if concurrent and now - reported >= 1.0:
+                report_front()
+                reported = now
             event = node.recv(timeout=0.25)
             if event is None:
                 if node.stream_ended:
@@ -344,6 +387,15 @@ def main() -> None:
                 break
             if event["type"] != "INPUT":
                 continue
+            meta = event.get("metadata") or {}
+            # The responder stamps a stream's first message only; its way
+            # back ends here, before this loop decodes it.
+            t_recv_ns = None
+            t_emit_ns = meta.get(telemetry.STAMP_EMIT)
+            if isinstance(t_emit_ns, int):
+                t_recv_ns = time.time_ns()
+                with stage_lock:
+                    on_receive.observe((t_recv_ns - t_emit_ns) / 1e3)
             value = event["value"]
             if isinstance(value, pa.Array):
                 items = value.to_pylist()
@@ -355,7 +407,6 @@ def main() -> None:
                     answer = tokenizer.decode(items)
             else:
                 answer = bytes(value or b"").decode(errors="replace")
-            meta = event.get("metadata") or {}
             rid = meta.get("request_id")
             if rid is not None:
                 with routed_lock:
@@ -369,12 +420,14 @@ def main() -> None:
                     }
                     target.put(
                         (answer, bool(meta.get("done")),
-                         meta.get("finish"), extra)
+                         meta.get("finish"), extra, t_recv_ns)
                     )
                 continue
             responses.put(answer)
     finally:
         server.shutdown()
+        if concurrent:
+            report_front()
         node.close()
 
 

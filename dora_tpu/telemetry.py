@@ -351,6 +351,82 @@ def phase_histogram_key(phase: str) -> str:
     return f"phase_{phase.replace('.', '_')}_us"
 
 
+#: A REQUEST's life up to its first delta on the wire — the ONE table of
+#: its stages' names, in the order it passes them, as ``LOOP_PHASES``
+#: tiles a turn of the loop. The value says who observes the stage:
+#:
+#: * ``"arrival"`` — by ``llm_server.handle_input``, once a request, off
+#:   the stamps the HTTP front put into the request's metadata
+#:   (:data:`STAMP_HTTP`, :data:`STAMP_PUBLISH`; ``time.time_ns()``, the
+#:   message plane's cross-process convention, as the daemon's
+#:   ``t_route``). A request without them (another node sent it)
+#:   observes neither.
+#: * ``"first_send"`` — kept on the request as it passes each edge
+#:   (admitted, its first chunk handed to the device, its first token on
+#:   the host; the loop's and the engine's one clock) and observed
+#:   TOGETHER where its first message is sent, beside ``ttft_us``: the
+#:   three and ``ttft_us`` hold the same requests, and for each of them
+#:   backlog wait + these three = what ``ttft_us`` observed.
+#: * ``"api"`` — in the HTTP front's own process, off :data:`STAMP_EMIT`
+#:   (``time.time_ns()`` again), printed in its ``front`` report lines.
+#: * anything else is the snapshot key of a histogram that held the
+#:   stage before this table did; the row names it, nothing new is
+#:   recorded.
+#:
+#: Recorded rows have the key ``stage_<name>_us``
+#: (:func:`stage_histogram_key`). Read by the benchmark and the nodes'
+#: exit lines, and deliberately by no Prometheus family, alert, history
+#: series or CLI view; nothing of it goes to the ring (``s_queued``,
+#: ``s_admitted``, ``s_prefill_chunk``, ``s_finish`` draw a request
+#: there already).
+REQUEST_STAGES = {
+    # do_POST has read the body -> the stamp taken inside the send lock,
+    # just before node.send_output (JSON, metadata, the wait for the lock)
+    "front": "arrival",
+    # -> handle_input entered: node publish, daemon route, the receiver's
+    # event queue, the wait for the loop's next intake
+    "route_in": "arrival",
+    # -> backlog.push: parse, encode
+    "intake": "phase_intake_handle_input_us",
+    # -> slot and pages granted
+    "backlog": "backlog_wait_us",
+    # -> the request's FIRST chunk handed to the device (in line or
+    # ahead; behind a prefix grant, the first chunk it still runs): the
+    # wait behind other prompts' chunks, one a period
+    "prefill_queue": "first_send",
+    # -> its first token on the host: its own chunks and the read
+    "prefill": "first_send",
+    # -> the stream's first ``response`` message handed to
+    # node.send_output (ttft_us's own stamp): the rest of the dispatch,
+    # the wait in ``held``, the flush's order
+    "first_emit": "first_send",
+    # -> the front's main loop has received that message
+    "route_out": "api",
+    # -> the handler thread's flush of the first content delta returned
+    "sse": "api",
+}
+
+#: metadata keys of the three cross-process stamps (``time.time_ns()``)
+STAMP_HTTP, STAMP_PUBLISH, STAMP_EMIT = "t_http_ns", "t_publish_ns", "t_emit_ns"
+
+
+def stages_observed(how: str) -> list[str]:
+    """The stages one observer records (``"arrival"``, ``"first_send"``,
+    ``"api"``), in the table's order."""
+    return [name for name, by in REQUEST_STAGES.items() if by == how]
+
+
+def stage_histogram_key(stage: str) -> str:
+    """A stage's key in the snapshot that holds it (the table is
+    closed: KeyError)."""
+    by = REQUEST_STAGES[stage]
+    return f"stage_{stage}_us" if by in ("arrival", "first_send", "api") else by
+
+
+_ARRIVAL_STAGES = stages_observed("arrival")
+_FIRST_SEND_STAGES = stages_observed("first_send")
+
+
 class _Phase:
     """``with tracer.phase(name):`` — a nested phase, left on every path."""
 
@@ -402,10 +478,20 @@ class ServingTracer:
     stamps: ``enter`` / ``leave`` / ``switch`` return the clock read
     they made, and callers that need the time at a phase's edge take it
     from there.
+
+    A request's stages (:data:`REQUEST_STAGES`) are entered and left
+    here and nowhere else too, always on, into ``stage_histograms``
+    (``ServingMetrics.stages``) alone: :meth:`request_arrived` observes
+    the two the front stamped; :meth:`request_pushed` ..
+    :meth:`request_token` keep a stamp an edge on the request, and
+    :meth:`request_sent` observes the three they bound, once, where the
+    first message goes. One dict entry a live request until then, or
+    until :meth:`finish` (shed, failed) or :meth:`release` (migrated
+    away): it does not grow with request count.
     """
 
     __slots__ = ("_flight", "_tracing", "_ctx", "clock", "histograms",
-                 "annotation", "_open")
+                 "annotation", "_open", "stage_histograms", "_edges")
 
     def __init__(self, flight: FlightRecorder | None = None,
                  tracing: TracingState | None = None,
@@ -420,6 +506,10 @@ class ServingTracer:
         self.annotation = None
         #: open phases, outermost first: [name, start, carved s, annotation]
         self._open: list[list] = []
+        self.stage_histograms: dict | None = None
+        #: request key -> the stamps of the edges it has passed, on
+        #: ``clock``: [pushed, admitted, first chunk, first token]
+        self._edges: dict[str, list[float]] = {}
 
     # -- loop phases ---------------------------------------------------------
 
@@ -472,6 +562,60 @@ class ServingTracer:
         if self._tracing.active:
             self._flight.record("s_loop_phase", phase, None, int(dur * 1e9))
 
+    # -- request stages ------------------------------------------------------
+
+    def request_arrived(self, metadata: dict, now_ns: int) -> None:
+        """``handle_input`` was entered at ``now_ns`` (``time.time_ns()``)
+        for a request with this metadata: observe what the front's two
+        stamps bound. A request without them observes nothing."""
+        stamps = (metadata.get(STAMP_HTTP), metadata.get(STAMP_PUBLISH), now_ns)
+        if self.stage_histograms is None or not all(
+            isinstance(t, int) for t in stamps
+        ):
+            return
+        for stage, start, end in zip(_ARRIVAL_STAGES, stamps, stamps[1:]):
+            self.stage_histograms[stage].observe((end - start) / 1e3)
+
+    def request_pushed(self, key: str, now: float) -> None:
+        """The request goes to the backlog: ``ttft_us`` counts from
+        ``now``, and so do its stages."""
+        self._edges[key] = [now]
+
+    def request_admitted(self, key: str, waited_s: float) -> None:
+        """Slot and pages granted after ``waited_s`` in the backlog (what
+        ``backlog_wait_us`` observes). A stream admitted again (resumed
+        behind a preemption) keeps its first admission's stamp."""
+        edges = self._edges.get(key)
+        if edges is not None:
+            self._edge(key, 1, edges[0] + waited_s)
+
+    def request_chunk(self, key: str, now: float) -> None:
+        """A chunk of the request's prompt was handed to the device on
+        the stamp ``now``; its first is an edge."""
+        self._edge(key, 2, now)
+
+    def request_token(self, key: str, now: float) -> None:
+        """The request's first token reached the host on the stamp ``now``."""
+        self._edge(key, 3, now)
+
+    def _edge(self, key: str, passed: int, now: float) -> None:
+        """Stamp the request's next edge, if it has passed just
+        ``passed`` of them: an edge met again, or out of turn, or of a
+        request never pushed, is passed over."""
+        edges = self._edges.get(key)
+        if edges is not None and len(edges) == passed:
+            edges.append(now)
+
+    def request_sent(self, key: str, now: float) -> None:
+        """The request's first message is handed to the node on the stamp
+        ``now`` (``ttft_us``'s own): observe its stages, and forget it."""
+        edges = self._edges.pop(key, None)
+        if self.stage_histograms is None or edges is None or len(edges) != 4:
+            return
+        edges.append(now)
+        for stage, start, end in zip(_FIRST_SEND_STAGES, edges[1:], edges[2:]):
+            self.stage_histograms[stage].observe((end - start) * 1e6)
+
     # -- request lifecycle ---------------------------------------------------
 
     @property
@@ -510,6 +654,7 @@ class ServingTracer:
         """Close a request: records ``s_finish`` and releases its
         context (the dict must not grow with request count)."""
         ctx = self._ctx.pop(key, None)
+        self._edges.pop(key, None)
         if not self._tracing.active:
             return
         self._flight.record("s_finish", f"{key} {reason}", ctx, 0)
@@ -524,6 +669,7 @@ class ServingTracer:
         """Drop a request's context without an ``s_finish`` span — for
         streams that migrate away rather than finishing here."""
         self._ctx.pop(key, None)
+        self._edges.pop(key, None)
 
 
 # ---------------------------------------------------------------------------

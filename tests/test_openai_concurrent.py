@@ -134,6 +134,121 @@ def test_concurrent_streams_route_by_request_id(tmp_path):
     assert "concurrent routing ok" in (log_dir / "log_driver.txt").read_text()
 
 
+def test_the_front_stamps_a_request_and_times_its_first_delta(tmp_path):
+    """Concurrent mode's part of ``telemetry.REQUEST_STAGES``: the
+    request's metadata carries ``t_http_ns`` and ``t_publish_ns``; a
+    stream's first message, which the responder stamps ``t_emit_ns``, is
+    observed once on its way back and once where its delta is flushed;
+    and the two histograms come out as a ``front`` line of the node's
+    log that the benchmark's ``node_reports.parse`` reads."""
+    responder = tmp_path / "stamped.py"
+    responder.write_text(textwrap.dedent("""
+        import time
+
+        import pyarrow as pa
+
+        from dora_tpu.node import Node
+
+        with Node() as node:
+            for event in node:
+                if event["type"] == "STOP":
+                    break
+                if event["type"] != "INPUT":
+                    continue
+                meta = event["metadata"] or {}
+                stamps = [meta["t_http_ns"], meta["t_publish_ns"],
+                          time.time_ns()]
+                assert all(isinstance(t, int) for t in stamps), stamps
+                assert stamps == sorted(stamps), stamps
+                assert stamps[2] - stamps[0] < 60e9, stamps
+                print("stamps ok", flush=True)
+                for seq in range(3):
+                    out = {"request_id": meta["request_id"], "seq": seq,
+                           "n_tokens": 1, "done": seq == 2}
+                    if seq == 0:
+                        out["t_emit_ns"] = time.time_ns()
+                    node.send_output("reply", pa.array([f"w{seq}"]), out)
+    """))
+    driver = tmp_path / "driver.py"
+    driver.write_text(textwrap.dedent("""
+        import json
+        import time
+        import urllib.request
+
+        from dora_tpu.node import Node
+
+        node = Node()
+        time.sleep(0.5)
+        # three streams, then one answer in one piece: no delta to flush
+        for stream in (True, True, True, False):
+            body = json.dumps({
+                "stream": stream,
+                "messages": [{"role": "user", "content": "hi"}],
+            }).encode()
+            req = urllib.request.Request(
+                "http://127.0.0.1:8139/v1/chat/completions",
+                data=body, headers={"Content-Type": "application/json"},
+            )
+            for attempt in range(40):
+                try:
+                    with urllib.request.urlopen(req, timeout=30) as r:
+                        raw = r.read().decode()
+                    break
+                except Exception:
+                    time.sleep(0.25)
+            assert "w0" in raw and "w2" in raw, raw
+        print("four answers ok")
+        node.close()
+    """))
+    spec = {
+        "nodes": [
+            {
+                "id": "api",
+                "path": "module:dora_tpu.nodehub.openai_server",
+                "outputs": ["text"],
+                "inputs": {"response": "stamped/reply"},
+                "env": {
+                    "PORT": "8139",
+                    "MAX_REQUESTS": "4",
+                    "DORA_OPENAI_CONCURRENT": "1",
+                    "RESPONSE_TIMEOUT": "60",
+                },
+            },
+            {
+                "id": "stamped",
+                "path": "stamped.py",
+                "inputs": {"text": "api/text"},
+                "outputs": ["reply"],
+            },
+            {"id": "driver", "path": "driver.py"},
+        ]
+    }
+    df = tmp_path / "dataflow.yml"
+    df.write_text(yaml.safe_dump(spec))
+    result = run_dataflow(df, timeout_s=180)
+    assert result.is_ok(), result.errors()
+    log_dir = next((tmp_path / "out").iterdir())
+    assert "four answers ok" in (log_dir / "log_driver.txt").read_text()
+    assert (log_dir / "log_stamped.txt").read_text().count("stamps ok") == 4
+
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmark" / "lib"))
+    try:
+        import node_reports
+    finally:
+        sys.path.pop(0)
+    lines = node_reports.parse((log_dir / "log_api.txt").read_text())["front"]
+    last = lines[-1]
+    assert [ln["t_mono"] for ln in lines] == sorted(ln["t_mono"] for ln in lines)
+    # every stream's first message came back once; three had a delta to flush
+    assert last["stage_route_out_us"]["count"] == 4
+    assert last["stage_sse_us"]["count"] == last["requests"] == 3
+    for key in ("stage_route_out_us", "stage_sse_us"):
+        assert 0 < last[key]["sum_us"] < 60e6 and sum(last[key]["counts"]) == last[key]["count"]
+
+
 def test_a_response_of_several_tokens_is_one_sse_delta(tmp_path):
     """``llm_server`` sends one ``response`` message per stream per
     flush, holding every token of a window. The HTTP front writes one
