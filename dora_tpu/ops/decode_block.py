@@ -53,13 +53,21 @@ _LANE = 128
 #: of VMEM; half of it leaves the surrounding XLA fusions their room.
 _CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
-#: The batched decode kernel keeps ``wqkv`` and ``wo`` whole in VMEM.
-#: Inside the compiler's 16 MiB default scope that holds up to about
-#: this many bytes of them (5.5 MB at the 1.5B widths, whose program is
-#: compiled without a limit of its own, as it always was); wider models
-#: (16.8 MB at hidden 2048 with 16 K/V heads: 22.7 MB of scope, refused
-#: at the default) get the chunk kernel's budget.
-_BATCH_WEIGHTS_IN_DEFAULT_VMEM = 8 * 1024 * 1024
+#: The batched decode kernel takes ``wqkv`` and ``wo`` from HBM by column
+#: tiles of about this many bytes, through a ring of ``_WEIGHT_SLOTS``
+#: buffers. Kernel alone on the v5e at Ouro's widths (16.8 MB of int8,
+#: 34 groups a call; PERF.md section 6, PR 55): 0.5, 0.75 and 1 MiB with
+#: 3 and 4 buffers measured 75.1-77.5 us a call, 2 MiB 80.9; 1 MiB and 3
+#: leave the call's scope inside the compiler's default 16 MiB at every
+#: width served and at Falcon-H1's (no limit of the kernel's own).
+_WEIGHT_TILE_BYTES = 1024 * 1024
+_WEIGHT_SLOTS = 3
+
+
+def _weight_tile_cols(rows: int) -> int:
+    """Columns of a streamed tile of a weight of ``rows`` rows, a byte a
+    value: whole lane tiles, one at least."""
+    return max(_LANE, _WEIGHT_TILE_BYTES // rows // _LANE * _LANE)
 
 
 def _rms(x_ref, w_ref, eps: float):
@@ -720,6 +728,14 @@ _SWEEP_COLS = 128
 #: with 2, where the copies alone take 1.40 (PR 38): 4 % of a step, not
 #: taken.
 _SWEEP_SLOTS = 2
+#: A caller's chores (``run(chores)``: work that needs no step's result,
+#: done under the groups' copies) go into every third step from the
+#: second. The decode kernel's are products of a 1 MiB weight tile whose
+#: copy then shares the bus with three groups of 1 MB at 16 K/V heads:
+#: one in every step stalled the steps on their pages (79.2 us a call),
+#: every second 77.1, every third 73.6-75.1, spread evenly over the
+#: whole schedule 76.6 (kernel alone, Ouro's widths, PR 55).
+_SWEEP_CHORE_EVERY = 3
 
 
 def _sweep_pages(page: int, max_pages: int) -> int:
@@ -814,6 +830,10 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
     the position are masked in ``s`` and again in ``p``. ``run()``
     leaves (max, sum, accumulator) of every row in the state refs, which
     it returns; the caller folds in what it holds in registers.
+    ``run(chores)`` also calls each of the caller's thunks once, in
+    order: chore j in step ``1 + _SWEEP_CHORE_EVERY * j``, between the
+    start of the next group's copies and the wait for this group's, or
+    after the last step where the schedule is shorter than that.
     """
     kv_quant = len(pools) == 4
     joined = len(pools[0].shape) == 3
@@ -900,9 +920,11 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
             p.astype(dtype).astype(f32) * v.astype(f32), axis=1, keepdims=True
         )  # [KV, 1, hd]
 
-    def step(t, carry):
+    def step(t, carry, chores=()):
         ahead = t + slots - 1
         pl.when(ahead < total)(functools.partial(copies, ahead, "start"))
+        for at, chore in chores:
+            pl.when(t == at)(chore)
         copies(t, "wait")
         b, g = row_ref[t], grp_ref[t]
         slot = jax.lax.rem(t, slots)
@@ -941,8 +963,13 @@ def _paged_sweep(pos_ref, bt_ref, pools, scratch, *, batch: int, page: int,
             )
         return carry
 
-    def run():
-        jax.lax.fori_loop(0, total, step, 0)
+    def run(chores=()):
+        due = [
+            (1 + _SWEEP_CHORE_EVERY * j, chore) for j, chore in enumerate(chores)
+        ]
+        jax.lax.fori_loop(0, total, functools.partial(step, chores=due), 0)
+        for at, chore in due:  # the schedule ended before its step
+            pl.when(total <= at)(chore)
         return m_ref, l_ref, acc_ref
 
     return q_ref, run
@@ -961,25 +988,34 @@ def _attn_paged_batch_kernel(
     sweep walks pool pages through the row's block table, and the
     in-place row write targets the row's CURRENT page.
 
-    Order of events, so that page DMAs are always in flight: (1) at
-    entry, every row's 8-row window of its current page is requested
-    (one semaphore per row, no wait yet) and :func:`_paged_sweep` starts
-    the first group of the flat (row, group) schedule; (2) RMSNorm,
-    the fused qkv product and RoPE run over all rows while those land;
-    (3) one wait for the windows, the current token's K/V inserted in
-    registers, the write-backs started (waited for at the very end);
-    (4) the sweep: groups of 8 pages (128 cache rows at page 16) in
-    two slots, one group ahead, one score and one value product per
-    kv head per group, or one vector pass over all kv heads of the
-    group where ``heads == kv_heads`` leaves a kv head one query row
-    (:func:`_paged_sweep`); (5) each row's current token folded in from
-    registers (it never round-trips the pool within its own step), the
-    output projection and the residual.
+    ``wqkv`` and ``wo`` stay in HBM; the body copies them itself, a
+    column tile at a time, as it copies pages. Order of events, so that
+    weight bytes and page bytes move together and the arithmetic runs
+    under them: (1) at entry the first tiles of ``wqkv`` are requested,
+    then every row's 8-row window of its current page (one semaphore
+    per row) and, by :func:`_paged_sweep`, the first group of the flat
+    (row, group) schedule; nothing is waited for yet; (2) RMSNorm, then
+    the fused projection by column tiles, THE QUERY COLUMNS FIRST: a
+    tile's product runs as its copy lands while the next tiles' copies
+    are in flight; RoPE on q, and q stands; (3) the sweep starts: groups
+    of 8 pages (128 cache rows at page 16) in two slots, one group
+    ahead, one score and one value product per kv head per group, or one
+    vector pass over all kv heads of the group where ``heads ==
+    kv_heads`` leaves a kv head one query row; the K and V columns'
+    tiles are projected BETWEEN its steps, one every third step
+    (``run(chores)``), and the copies of ``wo``'s first tiles follow
+    them into the ring; (4) RoPE on the current token's k, one wait for
+    the windows, the token's K/V inserted in registers, the write-backs
+    started (waited for at the very end); (5) each row's current token
+    folded in from registers (it never round-trips the pool within its
+    own step), the output projection by ``wo``'s column tiles, the
+    residual. Column tiles leave every output column's sum over ``D``
+    as it was, so the result is the whole-weight product's to the bit.
 
-    The sweep may read a row's current page while its window is being
-    written back: the window's seven other rows are rewritten with the
-    bytes they held, and the eighth is column ``pos``, which the sweep
-    masks.
+    The sweep reads a row's current page before its window is written
+    back, and the next call's sweep after: the window's seven other rows
+    are rewritten with the bytes they held, and the eighth is column
+    ``pos``, which this call's sweep masks.
 
     ``kv_quant`` adds the int8-KV pools: values are
     :func:`kv_quant_rows`-quantized in-register right before the RMW
@@ -988,22 +1024,64 @@ def _attn_paged_batch_kernel(
     dequantizes each streamed group in-register — HBM traffic per page
     is the int8 bytes plus a [KV, page] scale plane."""
     if kv_quant:
-        (x_ref, nw_ref, wqkv_ref, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
-         kp_in, vp_in, ks_in, vs_in, wo_ref, swo_ref,
+        (x_ref, nw_ref, wqkv_hbm, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
+         kp_in, vp_in, ks_in, vs_in, wo_hbm, swo_ref,
          out_ref, kp_out, vp_out, ks_out, vs_out,
-         kv_row, s_row, wsem, *sweep_scratch) = refs
+         kv_row, s_row, wsem, wbuf, tsem, qkv_ref, *sweep_scratch) = refs
         pools = (kp_out, vp_out, ks_out, vs_out)
     else:
-        (x_ref, nw_ref, wqkv_ref, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
-         kp_in, vp_in, wo_ref, swo_ref,
+        (x_ref, nw_ref, wqkv_hbm, sqkv_ref, bqkv_ref, cos_ref, sin_ref,
+         kp_in, vp_in, wo_hbm, swo_ref,
          out_ref, kp_out, vp_out,
-         kv_row, wsem, *sweep_scratch) = refs
+         kv_row, wsem, wbuf, tsem, qkv_ref, *sweep_scratch) = refs
         pools = (kp_out, vp_out)
     half = head_dim // 2
     dtype = x_ref.dtype
-    int4 = wqkv_ref.dtype == jnp.uint8
+    int4 = wqkv_hbm.dtype == jnp.uint8
     group = heads // kv_heads
     scale = 1.0 / (head_dim ** 0.5)
+
+    # --- the weights' column tiles, the first ones requested ----------------
+    # ``wqkv``'s query columns, its K and V columns, then ``wo``, as ONE
+    # static list of column tiles through the ring ``wbuf``: tile i
+    # lives in slot i % ring, and taking it starts the copy of tile
+    # i + ring - 1 into the slot tile i - 1 just left, as the sweep does
+    # with its groups. A tile takes the same columns of its scales (int8:
+    # one a column; int4: a group's row of them), which stay VMEM operands.
+    ring, _, tile_cols = wbuf.shape
+    n_q = heads * head_dim
+
+    def column_tiles(w, start, end):
+        return [
+            (w, c0, min(tile_cols, end - c0))
+            for c0 in range(start, end, tile_cols)
+        ]
+
+    tiles = column_tiles(wqkv_hbm, 0, n_q)
+    n_q_tiles = len(tiles)
+    tiles += column_tiles(wqkv_hbm, n_q, wqkv_hbm.shape[1])
+    n_qkv_tiles = len(tiles)
+    tiles += column_tiles(wo_hbm, 0, wo_hbm.shape[1])
+
+    def tile_slot(i):
+        w, _, width = tiles[i]
+        return wbuf.at[i % ring, pl.ds(0, w.shape[0]), pl.ds(0, width)]
+
+    def tile_copy(i):
+        w, c0, width = tiles[i]
+        return pltpu.make_async_copy(
+            w.at[:, pl.ds(c0, width)], tile_slot(i), tsem.at[i % ring]
+        )
+
+    def take_tile(i):
+        """Tile i, landed in its slot, and the columns it holds."""
+        if i + ring - 1 < len(tiles):
+            tile_copy(i + ring - 1).start()
+        tile_copy(i).wait()
+        return tile_slot(i), pl.ds(*tiles[i][1:])
+
+    for i in range(min(ring - 1, len(tiles))):
+        tile_copy(i).start()
 
     # --- every row's current 8-row window, requested together ---------------
     # The aligned 8-row read-modify-write of :func:`_attn_kernel`, but the
@@ -1046,30 +1124,43 @@ def _attn_paged_batch_kernel(
         batch=batch, page=page, scale=scale, dtype=dtype,
     )
 
-    # --- projections (all rows at once: one weight pass) --------------------
+    # --- the query columns' tiles, RoPE, and q stands -----------------------
     h = _rms(x_ref, nw_ref, eps).astype(dtype)  # [B, D]
-    qkv = _wdot(h, wqkv_ref, sqkv_ref[...], int4=int4) + bqkv_ref[...].astype(
-        jnp.float32
-    )
+
+    def project(i):
+        w_tile, at = take_tile(i)
+        qkv_ref[:, at] = _wdot(
+            h, w_tile, sqkv_ref[:, at], int4=int4
+        ) + bqkv_ref[:, at].astype(jnp.float32)
+
+    for i in range(n_q_tiles):
+        project(i)
     cos_b = cos_ref[...].astype(jnp.float32)
     sin_b = sin_ref[...].astype(jnp.float32)
-
-    qf = qkv[:, : heads * head_dim].reshape(batch * heads, head_dim)
-    kf = qkv[:, heads * head_dim : (heads + kv_heads) * head_dim].reshape(
-        batch * kv_heads, head_dim
-    )
-    vf = qkv[:, (heads + kv_heads) * head_dim :].reshape(
-        batch * kv_heads, head_dim
-    )
 
     def _expand(t, reps):
         return jnp.broadcast_to(
             t[:, None, :], (batch, reps, head_dim)
         ).reshape(batch * reps, head_dim)
 
+    qf = qkv_ref[:, :n_q].reshape(batch * heads, head_dim)
     q = _rotate(qf, _expand(cos_b, heads), _expand(sin_b, heads), half)
-    k = _rotate(kf, _expand(cos_b, kv_heads), _expand(sin_b, kv_heads), half)
     q_b = q.reshape(batch, kv_heads, group, head_dim)
+
+    # --- the pipelined sweep over every row's prior context, the K and V ----
+    # --- columns' tiles projected between its steps -------------------------
+    q_ref[...] = q_b.astype(q_ref.dtype)
+    m_ref, l_ref, acc_ref = sweep([
+        functools.partial(project, i) for i in range(n_q_tiles, n_qkv_tiles)
+    ])
+
+    kf = qkv_ref[:, n_q : n_q + kv_heads * head_dim].reshape(
+        batch * kv_heads, head_dim
+    )
+    vf = qkv_ref[:, n_q + kv_heads * head_dim :].reshape(
+        batch * kv_heads, head_dim
+    )
+    k = _rotate(kf, _expand(cos_b, kv_heads), _expand(sin_b, kv_heads), half)
     k_b = k.reshape(batch, kv_heads, head_dim)
     v_b = vf.reshape(batch, kv_heads, head_dim)
 
@@ -1107,10 +1198,6 @@ def _attn_paged_batch_kernel(
             wr.start()
         pending += writes
 
-    # --- the pipelined sweep over every row's prior context -----------------
-    q_ref[...] = q_b.astype(q_ref.dtype)
-    m_ref, l_ref, acc_ref = sweep()
-
     # --- fold in each row's current position from registers (exact merge) ---
     attn_rows = []
     for b in range(batch):
@@ -1130,11 +1217,14 @@ def _attn_paged_batch_kernel(
 
     attn = jnp.stack(attn_rows, axis=0).reshape(batch, heads * head_dim)
 
-    # --- output projection + residual ---------------------------------------
-    o = _wdot(attn.astype(dtype), wo_ref, swo_ref[...], int4=int4)
-    if residual:
-        o = x_ref[...].astype(jnp.float32) + o
-    out_ref[...] = o.astype(out_ref.dtype)
+    # --- output projection + residual, a column tile of ``wo`` at a time ----
+    attn = attn.astype(dtype)
+    for i in range(n_qkv_tiles, len(tiles)):
+        w_tile, at = take_tile(i)
+        o = _wdot(attn, w_tile, swo_ref[:, at], int4=int4)
+        if residual:
+            o = x_ref[:, at].astype(jnp.float32) + o
+        out_ref[:, at] = o.astype(out_ref.dtype)
     for copy in pending:
         copy.wait()
 
@@ -1169,11 +1259,17 @@ def attention_paged_batch_step(
     step and a 20-token row one. A step multiplies its group on the
     MXU, two products a kv head, unless a kv head serves one query row
     (``heads == kv_heads``): then all heads of the group go through one
-    vector pass. In flight at any time: from kernel
-    entry, every row's 8-row write window and the first group; during
-    the sweep, the group after the one being multiplied (the next row's
-    first when a row ends); from the insert of the current token to the
-    end, the 2B window write-backs.
+    vector pass. ``wqkv`` and ``wo`` are HBM operands: the kernel
+    copies them by column tiles of ``_WEIGHT_TILE_BYTES`` through
+    ``_WEIGHT_SLOTS`` buffers of ``[max(rows of wqkv, rows of wo), tile
+    columns]``, whatever their size, so the call's scope is the sweep's
+    buffers and three tiles. In flight at any time: from kernel entry,
+    two weight tiles, every row's 8-row write window and the first
+    group; during the projection of q, the two tiles after the one
+    being multiplied; during the sweep, the group after the one being
+    multiplied (the next row's first when a row ends) and two tiles of
+    K, V or ``wo`` columns; from the insert of the current token, after
+    the sweep, to the end, the 2B window write-backs.
 
     ``k_scale``/``v_scale`` (both or neither) switch on the int8-KV
     path: pools must be int8 and the scales are parallel [P, KV, page]
@@ -1197,6 +1293,8 @@ def attention_paged_batch_step(
         )
     d = x.shape[-1]
     n_qkv = wqkv.shape[1]
+    tile_rows = max(wqkv.shape[0], wo.shape[0])
+    tile_cols = _weight_tile_cols(tile_rows)
     kernel = functools.partial(
         _attn_paged_batch_kernel, heads=heads, kv_heads=kv_heads,
         head_dim=head_dim, page=page, eps=eps, batch=batch,
@@ -1237,13 +1335,13 @@ def attention_paged_batch_step(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),  # x
             pl.BlockSpec(memory_space=pltpu.VMEM),  # norm_w
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # wqkv
+            pl.BlockSpec(memory_space=pl.ANY),      # wqkv (HBM)
             pl.BlockSpec(memory_space=pltpu.VMEM),  # sqkv
             pl.BlockSpec(memory_space=pltpu.VMEM),  # bqkv
             pl.BlockSpec(memory_space=pltpu.VMEM),  # cos rows
             pl.BlockSpec(memory_space=pltpu.VMEM),  # sin rows
             *pool_specs,
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # wo
+            pl.BlockSpec(memory_space=pl.ANY),      # wo (HBM)
             pl.BlockSpec(memory_space=pltpu.VMEM),  # swo
         ],
         out_specs=[
@@ -1254,6 +1352,9 @@ def attention_paged_batch_step(
             pltpu.VMEM((2, batch, kv_heads, 8, head_dim), k_pool.dtype),
             *scale_scratch,
             pltpu.SemaphoreType.DMA((len(pool_specs), batch)),  # wsem
+            pltpu.VMEM((_WEIGHT_SLOTS, tile_rows, tile_cols), wqkv.dtype),
+            pltpu.SemaphoreType.DMA((_WEIGHT_SLOTS,)),            # tsem
+            pltpu.VMEM((batch, n_qkv), jnp.float32),              # qkv
             *_sweep_scratch(
                 batch, block_tables.shape[1], kv_heads, heads // kv_heads,
                 head_dim, page, k_pool.dtype, x.dtype, kv_quant,
@@ -1263,9 +1364,6 @@ def attention_paged_batch_step(
     operands = [k_pool, v_pool]
     if kv_quant:
         operands += [k_scale, v_scale]
-    wide = {}
-    if wqkv.size + wo.size > _BATCH_WEIGHTS_IN_DEFAULT_VMEM:  # a byte each
-        wide["vmem_limit_bytes"] = _CHUNK_VMEM_LIMIT
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1281,7 +1379,7 @@ def attention_paged_batch_step(
             {9: 1, 10: 2, 11: 3, 12: 4} if kv_quant else {9: 1, 10: 2}
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), **wide,
+            dimension_semantics=("arbitrary",),
         ),
         interpret=_interpret(),
     )(
@@ -1314,8 +1412,10 @@ def _attn_paged_rows_kernel(
 @jax.jit
 def attention_paged_rows_step(q, pool, counts, block_tables):
     """Paged decode attention of B independent sequences WITHOUT the
-    projections, for a model whose ``wqkv`` and ``wo`` do not fit the
-    fused kernel's VMEM: the caller projects (and norms, ropes) in XLA,
+    projections, for a model that projects outside the kernel (its
+    callers' ``wqkv`` and ``wo`` did not fit VMEM while the fused kernel
+    held them whole, and they norm each head between the projection and
+    the sweep): the caller projects (and norms, ropes) in XLA,
     writes the tick's K/V row to its page, and hands over
 
     q: [B, KV, G, hd] queries (``G`` = query rows a K/V head serves, at
