@@ -221,6 +221,12 @@ class _PagedSlot:
     #: the traced adapter-id vector unchanged between rebuilds.
     adapter: str | None = None
     adapter_idx: int = 0
+    #: state snapshots (a slot-state engine with a prefix cache): the
+    #: radix node whose snapshot this stream was granted and has not
+    #: copied into its slot yet, and the ``(depth, row)`` of the one it
+    #: saved and has not handed to the cache yet
+    snap_from: object | None = None
+    snap_saved: tuple | None = None
 
 
 class PagedBatchEngine:
@@ -285,9 +291,26 @@ class PagedBatchEngine:
     :meth:`preempt` just drops the slot, :meth:`save_pools` /
     :meth:`restore_pools` carry the state beside the pages for
     :meth:`restore_state` with pinned slots, and what cannot take the
-    state along is refused by name: the prefix cache (a granted prefix
-    would need the state at its end), speculation, LoRA, and
-    :meth:`admit_streams` of a stream in mid-decode.
+    state along is refused by name: speculation, LoRA,
+    :meth:`admit_streams` of a stream in mid-decode, and the prefix
+    cache unless the model keeps **state snapshots** (``state_snapshots``
+    rows: a granted prefix needs the state at its end). With them the
+    engine holds ``snapshot_pool``, ``init_slot_state(state_snapshots)``,
+    the same leaves with a snapshot a row. The chunk program leaves the
+    slot's state as it stands after the chunk's last valid row, so after
+    a prompt's last FULL chunk the slot holds the state at a chunk (and
+    so page) boundary: a copy program enqueued right behind that chunk
+    (``state_snapshot``; on the device, every leaf in its own dtype)
+    puts it in a row of the pool, and the prompt's pages enter the radix
+    cache down to THAT depth with the row on the deepest node. A grant
+    is trimmed to the deepest node of the match that holds a row, never
+    between two, and the row is copied into the slot right before the
+    stream's first chunk, which then starts at that depth. Rows are
+    counted as pages are (:meth:`check_invariants`). :meth:`preempt`
+    gives back what its stream had not handed over and leaves the
+    cache's rows where they are; :meth:`save_pools` does not carry the
+    snapshot pool and :meth:`checkpoint_state` not the radix tree, so a
+    restored engine starts with none.
 
     With ``spec_k > 0`` (prompt-lookup speculation,
     models/paged_window.make_paged_spec_window) the window signature instead
@@ -306,7 +329,8 @@ class PagedBatchEngine:
                  window: int = 8, spec_k: int = 0, spec_ngram: int = 2,
                  window_factory=None, prefix_cache: bool = False,
                  prefix_cache_pages: int = 0, lora_pool=None,
-                 chunk_valid_rows: bool = False, init_slot_state=None):
+                 chunk_valid_rows: bool = False, init_slot_state=None,
+                 state_snapshots: int = 0):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -341,15 +365,26 @@ class PagedBatchEngine:
         #: per-slot state (None = the model has none): see the class
         #: docstring. Donated to and replaced by both programs.
         self.slot_state = None
+        #: rows shaped like one slot's state that the prefix cache's nodes
+        #: hold (None = no snapshots): see the class docstring
+        self.snapshot_pool = None
         if init_slot_state is not None:
-            for knob, on in (("a prefix cache", prefix_cache),
-                             ("speculation", spec_k),
+            for knob, on in (("speculation", spec_k),
                              ("a LoRA pool", lora_pool is not None)):
                 if on:
                     raise NotImplementedError(
                         f"a slot-state engine cannot run with {knob}: the "
                         f"per-slot state would not follow")
+            if prefix_cache and not state_snapshots:
+                raise NotImplementedError(
+                    "a slot-state engine cannot run with a prefix cache "
+                    "unless its model keeps state snapshots (its "
+                    "make_paged_engine passes state_snapshots; this one "
+                    "passes none): a granted prefix needs the per-slot "
+                    "state at its end")
             self.slot_state = init_slot_state(max_slots)
+            if prefix_cache:
+                self.snapshot_pool = init_slot_state(state_snapshots)
         self.allocator = PageAllocator(num_pages)
         #: shared-prefix subsystem (models/prefix_cache.py): radix
         #: lookup at admission maps cached prefix pages straight into
@@ -361,6 +396,8 @@ class PagedBatchEngine:
 
             self.prefix_cache = PrefixCache(
                 self.allocator, page_size, max_pages=prefix_cache_pages,
+                snapshots=state_snapshots if self.snapshot_pool is not None
+                else 0,
             )
         else:
             self.prefix_cache = None
@@ -514,6 +551,33 @@ class PagedBatchEngine:
 
         self._set_slot = jax.jit(_set_slot, donate_argnums=(0, 1))
 
+        def _copy_row(into, of, to_row, from_row):
+            # every leaf's row ``from_row`` of ``of`` into row ``to_row``
+            # of ``into``, in the leaf's own dtype: bits, not values
+            with jax.named_scope("state_snapshot"):
+                return jax.tree.map(
+                    lambda a, b: jax.lax.dynamic_update_index_in_dim(
+                        a, jax.lax.dynamic_index_in_dim(
+                            b, from_row, keepdims=False), to_row, 0),
+                    into, of)
+
+        self._copy_row = jax.jit(_copy_row, donate_argnums=(0,))
+        #: snapshots copied out of a slot and into one, and their bytes
+        self.snapshots_saved = 0
+        self.snapshots_restored = 0
+        self.snapshot_bytes = (
+            sum(x.nbytes // x.shape[0]
+                for x in jax.tree.leaves(self.snapshot_pool))
+            if self.snapshot_pool is not None else 0)
+        if self.snapshot_pool is not None:
+            # both copies compile here, at start-up, over zeros: the first
+            # grant of a session may come minutes into serving
+            zero = jnp.zeros((), jnp.int32)
+            self.snapshot_pool = self._launch(
+                self._copy_row, self.snapshot_pool, self.slot_state, zero, zero)
+            self.slot_state = self._launch(
+                self._copy_row, self.slot_state, self.snapshot_pool, zero, zero)
+
     # -- admission -----------------------------------------------------------
 
     @property
@@ -647,9 +711,9 @@ class PagedBatchEngine:
                     f"of pinned adapters ({adapter!r} not resident)"
                 )
         b = self.slots.index(None)
-        base0, shared = (0, [])
+        base0, shared, granted = (0, [], None)
         if self.prefix_cache is not None:
-            base0, shared = self._prefix_grant(ids, max_new, adapter)
+            base0, shared, granted = self._prefix_grant(ids, max_new, adapter)
         need = self.pages_needed(len(ids), max_new, base0) - len(shared)
         if need > self.allocator.free_pages and self.prefix_cache is not None:
             self.prefix_cache.evict(need - self.allocator.free_pages)
@@ -657,6 +721,8 @@ class PagedBatchEngine:
         if fresh is None:
             if shared:
                 self.allocator.unref(shared)
+            if granted is not None:
+                self.prefix_cache.snapshot_release(granted)
             if adapter:
                 self.lora.release(adapter)
             raise RuntimeError(
@@ -670,6 +736,7 @@ class PagedBatchEngine:
             request_id, emitted=0, max_new=max_new, pages=pages,
             prompt=ids, true_len=len(ids), chunk_base=base0,
             shared=len(shared), adapter=adapter, adapter_idx=aidx,
+            snap_from=granted,
         )
         self._decode[b] = False
         self._prefillq.append(b)
@@ -693,7 +760,8 @@ class PagedBatchEngine:
         return None
 
     def _prefix_grant(self, ids: list[int], max_new: int,
-                      adapter: str | None = None) -> tuple[int, list[int]]:
+                      adapter: str | None = None
+                      ) -> tuple[int, list[int], object | None]:
         """Longest usable cached prefix for a new prompt: looks up the
         radix cache, trims the match so (a) at least the final prompt
         token is re-prefilled (the first generated token comes off the
@@ -701,7 +769,9 @@ class PagedBatchEngine:
         stays inside the block table, and (c) the fresh-page need fits
         free + evictable pages (sharing must never turn an admissible
         request inadmissible). Refs the shared pages into this stream's
-        custody and returns ``(divergence_base, shared_page_ids)``.
+        custody and returns ``(divergence_base, shared_page_ids, node)``:
+        ``node`` is the radix node whose snapshot the grant ends at,
+        promised to this admission (None without snapshots or a grant).
 
         Trimmed boundary pages are re-materialized privately by the
         divergence chunk — the copy-on-write boundary copy (the copy
@@ -715,11 +785,25 @@ class PagedBatchEngine:
         matched, pages, mid_page = cache.lookup(ids, adapter)
         cap = (len(ids) - 1) // ps * ps
         lo = min(matched, cap)
+        #: with state snapshots a grant may end only where one stands:
+        #: the depths of the match that hold one, shallowest first
+        stands = None
+        if self.snapshot_pool is not None:
+            stands = cache.snapshots_on_path(ids, adapter, lo)
+            lo = stands[-1][0] if stands else 0
+
+        def step_down(lo: int) -> int:
+            if stands is None:
+                return lo - ps
+            while stands and stands[-1][0] >= lo:
+                stands.pop()
+            return stands[-1][0] if stands else 0
+
         while lo and (
             lo + -(-(len(ids) - lo) // self.chunk) * self.chunk
             > self.max_seq
         ):
-            lo -= ps
+            lo = step_down(lo)
         shared = pages[: lo // ps]
         if shared:
             self.allocator.ref(shared)
@@ -734,10 +818,18 @@ class PagedBatchEngine:
             free = self.allocator.free_pages
             if need <= free or need <= free + cache.evictable_pages():
                 break
-            self.allocator.unref([shared.pop()])
-            lo -= ps
+            was, lo = lo, step_down(lo)
+            for _ in range((was - lo) // ps):
+                self.allocator.unref([shared.pop()])
         if not shared:
             lo = 0
+        # the node whose snapshot the grant ends at: promised here, so
+        # that no other admission's save takes its row before this
+        # stream's first chunk has copied it
+        granted = None
+        if stands is not None and lo:
+            granted = stands[-1][1]
+            cache.snapshot_promise(granted)
         if lo:
             cache.hits += 1
             cache.hit_tokens += lo
@@ -748,13 +840,14 @@ class PagedBatchEngine:
         # final-token / reach / capacity rules above.
         if matched > lo or mid_page:
             cache.cow_copies += 1
-        return lo, shared
+        return lo, shared, granted
 
     def _free_slot(self, b: int) -> None:
         # unref, not free: leading pages may be shared with the prefix
         # cache / other streams — the page pool reclaims each page only
         # when its last holder lets go.
         self.allocator.unref(self.slots[b].pages)
+        self._let_go_of_snapshots(self.slots[b])
         if self.lora is not None and self.slots[b].adapter:
             # Drop the stream's residency pin; the adapter STAYS warm
             # until eviction needs its slot (prefix-cache discipline).
@@ -766,6 +859,31 @@ class PagedBatchEngine:
         self._members_dirty = True
         if self._spec_cfg:
             self._hist[b] = []
+
+    def _let_go_of_snapshots(self, s: _PagedSlot) -> None:
+        """A stream leaves (or its final chunk is adopted) with a promise
+        it did not use or a row it did not hand over."""
+        if s.snap_from is not None:
+            self.prefix_cache.snapshot_release(s.snap_from)
+            s.snap_from = None
+        if s.snap_saved is not None:
+            self.prefix_cache.snapshot_give_back(s.snap_saved[1])
+            s.snap_saved = None
+
+    def snapshot_stats(self) -> dict:
+        """The snapshot pool's counters and gauges (empty without one)."""
+        if self.snapshot_pool is None:
+            return {}
+        cache = self.prefix_cache
+        return {
+            "state_snapshots_saved": self.snapshots_saved,
+            "state_snapshots_restored": self.snapshots_restored,
+            "state_snapshots_evicted": cache.snapshots_evicted,
+            "state_snapshot_bytes_copied": self.snapshot_bytes * (
+                self.snapshots_saved + self.snapshots_restored),
+            "state_snapshots_held": cache.snapshots_held,
+            "state_snapshot_pool_bytes": self.snapshot_bytes * cache.snapshots,
+        }
 
     # -- prefix-cache custody / invariants -----------------------------------
 
@@ -811,6 +929,22 @@ class PagedBatchEngine:
             f"{self.allocator.in_use} pages in use but only "
             f"{len(held)} held by slots/cache"
         )
+        if self.snapshot_pool is not None:
+            # snapshot rows, as pages: every row is free, on a node, or on
+            # its way to one in a stream's hands, and in one place only
+            cache = self.prefix_cache
+            rows = Counter(cache._snap_free)
+            rows.update(cache.snapshot_rows())
+            rows.update(s.snap_saved[1] for s in self.slots
+                        if s is not None and s.snap_saved is not None)
+            assert sorted(rows) == list(range(cache.snapshots)) and all(
+                n == 1 for n in rows.values()), (
+                f"snapshot rows {dict(rows)} of {cache.snapshots}")
+            for s in self.slots:
+                if s is not None and s.snap_from is not None:
+                    assert s.snap_from.snap is not None and (
+                        s.snap_from.snap_pins > 0), (
+                        f"{s.request_id}: promised a snapshot that is gone")
 
     # -- preemption / retuning (window-boundary only) ------------------------
 
@@ -1141,6 +1275,17 @@ class PagedBatchEngine:
         host's side of the stream is :meth:`_adopt_chunk`'s."""
         jnp = self._jnp
         base = s.chunk_base
+        if s.snap_from is not None:
+            # the stream's first chunk: the granted snapshot goes into
+            # the slot ahead of it, and the promise ends (the device
+            # runs what it is handed in order)
+            self.slot_state = self._launch(
+                self._copy_row, self.slot_state, self.snapshot_pool,
+                jnp.asarray(b, jnp.int32),
+                jnp.asarray(s.snap_from.snap, jnp.int32))
+            self.prefix_cache.snapshot_release(s.snap_from)
+            s.snap_from = None
+            self.snapshots_restored += 1
         piece = s.prompt[base : base + self.chunk]
         valid = (
             (jnp.asarray(len(piece), jnp.int32),)
@@ -1171,6 +1316,8 @@ class PagedBatchEngine:
         )
         if state:
             (self.slot_state,) = state
+        if self.snapshot_pool is not None:
+            self._save_snapshot(s, b, base + self.chunk)
         # the request's first chunk ends its wait in the prefill queue
         self.tracer.request_chunk(s.request_id, t_chunk)
         self.chunks_run += 1
@@ -1183,6 +1330,31 @@ class PagedBatchEngine:
                     min(self.chunk, s.true_len - base) * self.flops_per_token
                 )
         return greedy
+
+    def _save_snapshot(self, s: _PagedSlot, b: int, depth: int) -> None:
+        """Behind the chunk that ends at ``depth``: where that was the
+        prompt's last FULL chunk, the slot now holds the state at
+        ``depth`` and nothing but the stream's next chunk will change it
+        (a decode tick leaves a prefilling row's state alone), so copy it
+        into a row of the pool here, in line behind the chunk. The row is
+        the stream's until its final chunk is adopted and the cache takes
+        it (:meth:`_adopt_chunk`). No row where the depth holds one
+        already, or every row is promised."""
+        if depth != s.true_len // self.chunk * self.chunk:
+            return
+        cache = self.prefix_cache
+        stands = cache.snapshots_on_path(s.prompt, s.adapter, depth)
+        if stands and stands[-1][0] == depth:
+            return
+        row = cache.snapshot_take()
+        if row is None:
+            return
+        self.snapshot_pool = self._launch(
+            self._copy_row, self.snapshot_pool, self.slot_state,
+            self._jnp.asarray(row, self._jnp.int32),
+            self._jnp.asarray(b, self._jnp.int32))
+        s.snap_saved = (depth, row)
+        self.snapshots_saved += 1
 
     def _chunk_span(self, s: _PagedSlot, base: int, t_chunk: float) -> None:
         tracer = self.tracer
@@ -1219,12 +1391,21 @@ class PagedBatchEngine:
             # past them): adopt them into the radix cache so
             # later prompts map them instead of re-prefilling.
             n_full = s.true_len // self.page_size
+            if self.snapshot_pool is not None:
+                # ... down to the depth whose state this stream saved,
+                # and no further: a page past the deepest snapshot can
+                # be granted to nobody
+                n_full = s.snap_saved[0] // self.page_size if s.snap_saved else 0
             if n_full:
                 self.prefix_cache.insert(
                     s.prompt[: n_full * self.page_size],
                     s.pages[:n_full],
                     s.adapter,
                 )
+            if s.snap_saved is not None and self.prefix_cache.snapshot_attach(
+                    s.prompt, *s.snap_saved, s.adapter):
+                s.snap_saved = None
+            self._let_go_of_snapshots(s)
         s.prompt = None
         # Its first token exists, on the device: the window's
         # completion counter (rebuilt from here) starts behind it.

@@ -52,7 +52,7 @@ model.**
   A decode tick steps live rows only, in one pass over their state
   (``ops/kda_state_step``: the state stays in HBM, rows that are not
   live move none of it); a chunk runs the blocked delta rule
-  (:func:`delta_rule_blocks`) from the slot's state, zeros at position
+  (``models/delta_rule.delta_rule_blocks``) from the slot's state, zeros at position
   0, and writes back the state after its last VALID row.
 * a sparse-latent layer keeps two leaves of different row rates in
   ``pools`` under the one block table: ``"kv" [P, page, kv_lora_rank]``
@@ -92,11 +92,11 @@ import jax.numpy as jnp
 
 from dora_tpu.models import layers as L
 from dora_tpu.models import moe
+from dora_tpu.models.delta_rule import delta_rule_blocks, delta_rule_step
 from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
 from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
-from dora_tpu.ops.kda_state_step import kda_state_step
 from dora_tpu.ops.picked_rows import pool_rows
 
 MODEL_TYPES = ("glm5_next_text",)
@@ -588,72 +588,8 @@ def kda_step(blk, cfg: Glm5NextConfig, u, st, active):
     with jax.named_scope("kda_step"):
         # one pass over the live rows' state; products and sums on the
         # vector unit: exact in float32
-        o, s = kda_state_step(st["s"], g, k, q, v, beta, active)
+        o, s = delta_rule_step(st["s"], g, k, q, v, beta, active)
     return _kda_out(blk, cfg, o, gate), {"s": s, "conv": tail}
-
-
-def delta_rule_blocks(q, k, v, g, beta, s0, block: int):
-    """The blocked (WY) form of the gated delta rule over ``C`` rows,
-    ``block`` at a time: q, k ``[C, H, d_k]``, v ``[C, H, d_v]``, g ``[C,
-    H, d_k]`` (log decays, <= 0; 0 with beta 0 for a row that must leave
-    the state alone), beta ``[C, H]``, s0 ``[H, d_k, d_v]``, float32.
-    Returns (o ``[C, H, d_v]``, the state after the last row).
-
-    With ``G`` the running sum of ``g`` inside a block and ``u_t = beta_t
-    (v_t - S~_t^T k_t)``: ``(I + A) U = beta (V - (K exp(G)) S_0)`` where
-    ``A[t, s] = beta_t sum_d k_t k_s exp(G_t - G_s)`` for ``s < t``; ``O =
-    (Q exp(G)) S_0 + B U`` with ``B[t, s] = sum_d q_t k_s exp(G_t - G_s)``
-    for ``s <= t``; ``S_end = exp(G_end) S_0 + (K exp(G_end - G))^T U``.
-    Every exponent is <= 0, so nothing overflows at any decay; ``A`` and
-    ``B`` do not depend on the state and are computed for all blocks at
-    once; ``(I + A)^-1 = prod_j (I + (-A)^(2^j))`` (``A`` is strictly
-    lower triangular). The matrix products run at HIGHEST precision."""
-    c, h, dk = q.shape
-    qn = min(block, c)
-    assert c % qn == 0 and qn & (qn - 1) == 0, (c, qn)
-    nb = c // qn
-
-    def blocks(t):
-        return t.reshape(nb, qn, *t.shape[1:])
-
-    qb, kb, vb, gb, bb = map(blocks, (q, k, v, g, beta))
-    gsum = jnp.cumsum(gb, axis=1)  # [nb, Q, H, d_k], inclusive
-    t_idx = jnp.arange(qn)
-    lower = t_idx[:, None] >= t_idx[None, :]  # s <= t
-    pair = jnp.exp(jnp.where(
-        lower[None, :, :, None, None],
-        gsum[:, :, None] - gsum[:, None, :], -jnp.inf))  # [nb, t, s, H, d_k]
-    kk = (kb[:, :, None] * kb[:, None, :] * pair).sum(-1)  # [nb, t, s, H]
-    qk = (qb[:, :, None] * kb[:, None, :] * pair).sum(-1)
-    a = jnp.where((t_idx[:, None] > t_idx[None, :])[None, :, :, None],
-                  bb[:, :, None, :] * kk, 0.0)
-    a = jnp.moveaxis(a, -1, 1)  # [nb, H, t, s]
-    b_mat = jnp.moveaxis(qk, -1, 1)
-
-    def mm(x, y):
-        return jnp.matmul(x, y, precision=_HIGHEST)
-
-    power = -a
-    inv = jnp.eye(qn, dtype=a.dtype) + power
-    for _ in range(qn.bit_length() - 2):
-        power = mm(power, power)
-        inv = inv + mm(inv, power)
-    decay = jnp.exp(gsum)  # from the block's start to each row
-    to_end = jnp.exp(gsum[:, -1:] - gsum)  # from each row to the block's end
-
-    def body(s, inp):
-        q_, k_, v_, beta_, decay_, to_end_, inv_, b_ = inp
-        rhs = beta_[..., None] * (v_ - jnp.einsum(
-            "thk,hkv->thv", k_ * decay_, s, precision=_HIGHEST))
-        u = jnp.einsum("hts,shv->thv", inv_, rhs, precision=_HIGHEST)
-        o = jnp.einsum("thk,hkv->thv", q_ * decay_, s, precision=_HIGHEST) \
-            + jnp.einsum("hts,shv->thv", b_, u, precision=_HIGHEST)
-        s = s * decay_[-1][..., None] + jnp.einsum(
-            "thk,thv->hkv", k_ * to_end_, u, precision=_HIGHEST)
-        return s, o
-
-    s, o = jax.lax.scan(body, s0, (qb, kb, vb, bb, decay, to_end, inv, b_mat))
-    return o.reshape(c, h, -1), s
 
 
 def kda_chunk(blk, cfg: Glm5NextConfig, u, st, slot, position, valid):

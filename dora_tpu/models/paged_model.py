@@ -84,6 +84,18 @@ def pages_that_fit(page_bytes: int, limit: int, used: int, max_slots: int,
                    2 * max_seq // page_size))
 
 
+def snapshots_that_fit(snapshot_bytes: int, left: int, max_slots: int) -> int:
+    """Rows of a state-snapshot pool (``PagedBatchEngine``'s
+    ``state_snapshots``) as a rule in bytes: an eighth of ``left`` (what
+    the device has after the weights, the slots' own state and
+    :data:`POOL_HEADROOM_BYTES`; the pages take the rest) in whole
+    snapshots of ``snapshot_bytes``, never fewer than two a slot (a
+    session's newest snapshot, and the one its turn in flight is about to
+    leave) nor more than four."""
+    return int(min(4 * max_slots,
+                   max(2 * max_slots, left // 8 // snapshot_bytes)))
+
+
 def default_num_pages(page_bytes: int, max_slots: int, max_seq: int,
                       page_size: int, *, multiple: int = 1) -> int:
     """A pool's default size as a rule in bytes, for a model whose cache
@@ -157,7 +169,8 @@ def build_engine(name: str, cfg, params, *, window_program, chunk_step,
                  flops_per_token: float, looks: dict | None = None,
                  max_slots: int, eos: int | None, page_size: int,
                  chunk: int, num_pages: int, window: int | None,
-                 prefix_cache: bool | None, prefix_cache_pages: int | None):
+                 prefix_cache: bool | None, prefix_cache_pages: int | None,
+                 state_snapshots: int = 0):
     """A ``PagedBatchEngine`` over a model's two programs.
 
     ``window_program(params, k, tokens, pools, counters, *rest)`` is the
@@ -186,7 +199,11 @@ def build_engine(name: str, cfg, params, *, window_program, chunk_step,
     ``not_offered`` names the serving knobs of the Qwen path that the
     model refuses, each with its reason. ``window``, ``prefix_cache`` and
     ``prefix_cache_pages`` left None take ``DORA_MULTISTEP_K`` (8),
-    ``DORA_PREFIX_CACHE`` (off) and ``DORA_PREFIX_CACHE_PAGES`` (0)."""
+    ``DORA_PREFIX_CACHE`` (off) and ``DORA_PREFIX_CACHE_PAGES`` (0).
+    ``state_snapshots`` is a slot-state model's way to opt in to the
+    prefix cache: the rows of the engine's snapshot pool (0 = none, and
+    such a model with a prefix cache is refused); the pool's counters and
+    gauges (``engine.snapshot_stats``) join the model's in ``report``."""
     for knob, why in not_offered.items():
         if os.environ.get(knob, "0") not in ("", "0"):
             raise NotImplementedError(f"{name}: {knob} is not offered: {why}")
@@ -249,6 +266,7 @@ def build_engine(name: str, cfg, params, *, window_program, chunk_step,
         eos=eos,
         prefix_cache=prefix_cache,
         prefix_cache_pages=prefix_cache_pages,
+        state_snapshots=state_snapshots,
     )
     engine.flops_per_token = flops_per_token
     engine.device_peak_flops = profiling.detect_peak_flops()
@@ -260,7 +278,8 @@ def build_engine(name: str, cfg, params, *, window_program, chunk_step,
 
     def model_counters() -> dict:
         counters.gained()
-        return report(counters.totals, alive())
+        engine = alive()
+        return {**report(counters.totals, engine), **engine.snapshot_stats()}
 
     engine.model_counters = model_counters
     return engine

@@ -54,6 +54,20 @@ The cache therefore keys every path on ``(adapter, tokens)`` — one
 radix root per adapter identity (the stable tenant NAME, not the
 resident slot index, which is recycled by eviction) — and eviction /
 accounting walk all roots.
+
+**State snapshots, the second kind of thing a node can hold.** A model
+whose streams keep a per-slot state beside their pages (a delta-rule
+layer's state and convolution tail) cannot start a prompt at a cached
+depth with the pages alone: it needs the state as it stood after that
+depth's last row. The engine keeps a pool of ``snapshots`` rows shaped
+like one slot's state (``PagedBatchEngine``, ``state_snapshots``) and
+this cache keeps the custody of its rows, as it keeps the pages': a
+node may hold one row (``snap``), the engine grants a prefix only down
+to a node that holds one, a row leaves with its node (``evict``), and a
+full pool gives up the least recently used row that no admission was
+promised (``snapshot_take``; a promise is ``snap_pins``, held from the
+grant to the copy into the slot). A node left without a snapshot at or
+below it is of no use to such an engine and goes with the row.
 """
 
 from __future__ import annotations
@@ -98,6 +112,7 @@ def prompt_hash_chain(ids, page_size: int, adapter: str | None = None):
 class _Node:
     __slots__ = (
         "key", "page", "children", "parent", "last_used", "pins", "chain",
+        "snap", "snap_used", "snap_pins",
     )
 
     def __init__(self, key: tuple, page: int | None, parent: "_Node | None",
@@ -109,13 +124,17 @@ class _Node:
         self.last_used = 0
         self.pins = 0
         self.chain = chain      # cumulative blake2b chain root..here
+        self.snap: int | None = None  # row of the engine's snapshot pool
+        self.snap_used = 0      # LRU stamp of the snapshot's last grant
+        self.snap_pins = 0      # grants whose copy into a slot is to come
 
 
 class PrefixCache:
     """See module docstring. One instance per PagedBatchEngine; all
     methods run on the scheduler thread (no locking)."""
 
-    def __init__(self, allocator, page_size: int, *, max_pages: int = 0):
+    def __init__(self, allocator, page_size: int, *, max_pages: int = 0,
+                 snapshots: int = 0):
         self.allocator = allocator
         self.page_size = page_size
         #: optional hard cap on cached pages (0 = bounded only by pool
@@ -141,6 +160,12 @@ class PrefixCache:
         #: divergence, or a fully-cached prompt re-running its final
         #: page to produce the first token)
         self.cow_copies = 0
+        #: rows of the engine's snapshot pool (0 = the engine keeps
+        #: none): the free ones, and the life of the others in counts
+        self.snapshots = snapshots
+        self._snap_free = list(range(snapshots - 1, -1, -1))
+        self.snapshots_attached = 0
+        self.snapshots_evicted = 0
 
     def _chunks(self, ids) -> list[tuple]:
         ps = self.page_size
@@ -299,12 +324,120 @@ class PrefixCache:
                     best = n
             if best is None:
                 break
-            del best.parent.children[best.key]
-            self.allocator.unref([best.page])
-            self.size -= 1
+            self._drop(best)
             freed += 1
         self.evicted_pages += freed
         return freed
+
+    def _drop(self, node: _Node) -> None:
+        """Take a leaf out of the tree: its page's reference, and its
+        snapshot row with it."""
+        del node.parent.children[node.key]
+        self.allocator.unref([node.page])
+        self.size -= 1
+        if node.snap is not None:
+            self._snap_free.append(node.snap)
+            node.snap = None
+            self.snapshots_evicted += 1
+
+    # -- state snapshots (a slot-state engine's second cache kind) -----------
+
+    def _node_at(self, ids, depth: int, adapter: str | None) -> _Node | None:
+        node = self._root_for(adapter)
+        for key in self._chunks(ids[:depth]):
+            node = node.children.get(key)
+            if node is None:
+                return None
+        return node if node.page is not None else None
+
+    def snapshots_on_path(self, ids, adapter: str | None = None,
+                          limit: int | None = None) -> list[tuple[int, _Node]]:
+        """``(depth, node)`` of every node on the cached path of ``ids``
+        that holds a snapshot, shallowest first, down to ``limit``
+        tokens. Touches no stamp."""
+        node, found, depth = self._root_for(adapter), [], 0
+        for key in self._chunks(ids if limit is None else ids[:limit]):
+            node = node.children.get(key)
+            if node is None:
+                break
+            depth += self.page_size
+            if node.snap is not None:
+                found.append((depth, node))
+        return found
+
+    def snapshot_promise(self, node: _Node) -> None:
+        """A grant ends at ``node``: its row stays until the engine has
+        copied it into the slot (:meth:`snapshot_release`)."""
+        node.snap_pins += 1
+        node.snap_used = next(self._clock)
+
+    def snapshot_release(self, node: _Node) -> None:
+        if node.snap_pins > 0:
+            node.snap_pins -= 1
+
+    def snapshot_take(self) -> int | None:
+        """A row for a new snapshot: a free one, else the least recently
+        granted one that no admission in flight was promised, taken from
+        its node (and the node's now useless pages with it, as far up as
+        nothing else hangs on them). None when every row is promised or
+        on its way to a node."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        best = min((n for n in self._nodes()
+                    if n.snap is not None and not n.snap_pins),
+                   key=lambda n: n.snap_used, default=None)
+        if best is None:
+            return None
+        row, best.snap = best.snap, None
+        self.snapshots_evicted += 1
+        while (best.parent is not None and not best.children
+               and best.snap is None and not best.pins
+               and self.allocator.refcount(best.page) == 1):
+            parent = best.parent
+            self._drop(best)
+            self.evicted_pages += 1
+            best = parent
+        return row
+
+    def snapshot_give_back(self, row: int) -> None:
+        """A row that never reached a node (its stream went first)."""
+        self._snap_free.append(row)
+
+    def snapshot_attach(self, ids, depth: int, row: int,
+                        adapter: str | None = None) -> bool:
+        """Hand ``row`` to the node ``depth`` tokens down the path of
+        ``ids``. False (and the row is the caller's still) where there is
+        no such node or it holds a snapshot already: first writer wins,
+        as with pages."""
+        node = self._node_at(ids, depth, adapter)
+        if node is None or node.snap is not None:
+            return False
+        node.snap = row
+        node.snap_used = next(self._clock)
+        self.snapshots_attached += 1
+        return True
+
+    def _nodes(self):
+        """Every node of every tenant's tree, parents before children."""
+        stack = [c for root in self._roots.values()
+                 for c in root.children.values()]
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            yield n
+
+    def snapshot_rows(self):
+        """Iterate the rows that nodes hold (invariant checks)."""
+        return (n.snap for n in self._nodes() if n.snap is not None)
+
+    @property
+    def snapshots_free(self) -> int:
+        return len(self._snap_free)
+
+    @property
+    def snapshots_held(self) -> int:
+        """Rows on nodes: every one attached and not yet taken back."""
+        return self.snapshots_attached - self.snapshots_evicted
 
     def flush(self) -> int:
         """Evict everything evictable (tests / shutdown)."""

@@ -119,6 +119,7 @@ MODEL_MODULES = {
     "glm5_next_text": "glm5_next",
     "KeyeVL2": "keye_vl2",
     "zaya": "zaya",
+    "olmo_hybrid": "olmo_hybrid",
 }
 
 

@@ -1404,3 +1404,197 @@ def test_zaya_chunk_program_compiles_and_moves_no_cache(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
     assert _expert_stack_readers(
         compiled, params["blocks"]["1"]["experts"]) == []
+
+
+# -- Olmo-Hybrid-7B: delta-rule heads of 96 x 192, 30 ungrouped K/V heads -----
+
+OLMO = dict(
+    model_type="olmo_hybrid", vocab_size=100352, hidden_size=3840,
+    intermediate_size=11008, num_hidden_layers=4, num_attention_heads=30,
+    num_key_value_heads=30, rms_norm_eps=1e-6, tie_word_embeddings=False,
+    attention_bias=False,
+    layer_types=["linear_attention"] * 3 + ["full_attention"],
+    linear_num_key_heads=30, linear_num_value_heads=30,
+    linear_key_head_dim=96, linear_value_head_dim=192,
+    linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    rope_parameters={"rope_theta": None},
+)
+OLMO_SEQ, OLMO_PAGES = 12288, 6844
+
+
+def _olmo():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool, slot-state and counter shapes) for ONE period of the 16
+    published layers (every period is the same four programs), 16 slots
+    of 12,288 rows and the pool the bytes' rule gives the cell."""
+    from dora_tpu.models.hf import olmo_hybrid
+
+    cfg = olmo_hybrid.OlmoHybridConfig.from_hf(OLMO, OLMO_SEQ)
+    bf, d = jnp.bfloat16, cfg.dim
+    kw, vw = cfg.gdn_key_width, cfg.gdn_value_width
+    shapes = {
+        "post_attention_layernorm.weight": (d,),
+        "post_feedforward_layernorm.weight": (d,),
+        "linear_attn.q_proj.weight": (kw, d), "linear_attn.k_proj.weight": (kw, d),
+        "linear_attn.v_proj.weight": (vw, d), "linear_attn.g_proj.weight": (vw, d),
+        "linear_attn.a_proj.weight": (cfg.gdn_heads, d),
+        "linear_attn.b_proj.weight": (cfg.gdn_heads, d),
+        "linear_attn.q_conv1d.weight": (kw, 1, cfg.conv),
+        "linear_attn.k_conv1d.weight": (kw, 1, cfg.conv),
+        "linear_attn.v_conv1d.weight": (vw, 1, cfg.conv),
+        "linear_attn.A_log": (cfg.gdn_heads,), "linear_attn.dt_bias": (cfg.gdn_heads,),
+        "linear_attn.o_norm.weight": (cfg.gdn_dv,),
+        "linear_attn.o_proj.weight": (d, vw),
+        **{f"self_attn.{x}_proj.weight": (d, d) for x in "qkvo"},
+        "self_attn.q_norm.weight": (d,), "self_attn.k_norm.weight": (d,),
+        "mlp.gate_proj.weight": (cfg.ffn, d), "mlp.up_proj.weight": (cfg.ffn, d),
+        "mlp.down_proj.weight": (d, cfg.ffn),
+    }
+
+    def get(name):
+        return jnp.zeros(shapes[name.split(".", 3)[3]], bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": olmo_hybrid._quantize_t(jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): olmo_hybrid.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(
+        lambda: olmo_hybrid.init_page_pool(cfg, OLMO_PAGES, PAGE))
+    state = jax.eval_shape(lambda: olmo_hybrid.init_slot_state(cfg, SLOTS))
+    stats = jax.eval_shape(lambda: olmo_hybrid.init_counters(cfg))
+    return olmo_hybrid, cfg, jax.eval_shape(build), pools, state, stats
+
+
+def _olmo_cache_copies(compiled) -> list[str]:
+    """``copy`` instructions of a whole pool leaf or a whole delta-rule
+    state, by shape (the 1.1 MB tail a layer is relaid into fast memory
+    by XLA for the convolution's sum, as GLM's is: 0.6 % of a tick's
+    bytes)."""
+    shapes = (f"bf16[{OLMO_PAGES},16,7680]", "f32[16,30,96,192]")
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line
+            and any(s in line.split(" copy(")[0] for s in shapes)]
+
+
+def test_delta_rule_step_kernel_compiles_at_30_heads_of_96_by_192(chip):
+    """``ops/kda_state_step`` at Olmo-Hybrid-7B's heads: 30 has no divisor
+    that is a multiple of 8 under the 1 MB cap (14 heads of 73,728 B), so
+    a grid step holds all 30 (2.2 MB, four of those in VMEM); ``d_v`` =
+    192 is one and a half lane tiles. Mosaic takes both. The gate comes a
+    head and is spread over the key channels outside the kernel."""
+    from dora_tpu.models.delta_rule import delta_rule_step
+    from dora_tpu.ops.kda_state_step import head_block
+
+    assert head_block(30, 96, 192) == 30
+    assert head_block(64, 128, 128) == 16  # GLM's: as it was
+    f32 = jnp.float32
+    h, dk, dv = 30, 96, 192
+    compiled = jax.jit(delta_rule_step, donate_argnums=(0,)).lower(*chip((
+        _s((SLOTS, h, dk, dv), f32), _s((SLOTS, h), f32), _s((SLOTS, h, dk), f32),
+        _s((SLOTS, h, dk), f32), _s((SLOTS, h, dv), f32), _s((SLOTS, h), f32),
+        _s((SLOTS,), jnp.bool_)))).compile()
+    text = compiled.as_text()
+    assert "kda_state_step" in text and "tpu_custom_call" in text
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "f32[16,30,96,192]" in line.split(" copy(")[0]]
+
+
+def test_paged_rows_attention_compiles_with_30_kv_heads_of_one_query_row(chip):
+    """``attention_paged_rows_step`` as Olmo-Hybrid-7B's full layers call
+    it: 30 K/V heads, each with ONE query row and a zero row beside it
+    (the joined layout has no one-row form: the kernel refuses ``G`` = 1
+    by name), a position's row 15,360 B, a 128-row group 1.97 MB a
+    buffer."""
+    from dora_tpu.ops import decode_block as DB
+
+    bf = jnp.bfloat16
+    pool = _s((OLMO_PAGES, PAGE, 2 * 30 * 128), bf)
+    rest = (_s((SLOTS,), I32), _s((SLOTS, OLMO_SEQ // PAGE), I32))
+    with pytest.raises(AssertionError, match="no one-row form"):
+        jax.jit(DB.attention_paged_rows_step).lower(
+            *chip((_s((SLOTS, 30, 1, 128), bf), pool, *rest)))
+    compiled = jax.jit(DB.attention_paged_rows_step).lower(
+        *chip((_s((SLOTS, 30, 2, 128), bf), pool, *rest))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_olmo_hybrid_window_program_compiles_and_moves_no_cache(chip):
+    """The K=8 decode window at Olmo-Hybrid-7B's widths, one period, 16
+    slots of 12,288 rows over 6,844 pages: the 17,536-wide fused input
+    matrix of a linear layer and the 11,520-wide ``wqkv`` of the full one
+    through ``int8_matmul``, the convolution over ``[tail ++ row]`` in
+    float32 XLA, the state through ``kda_state_step`` (one call a linear
+    layer a tick), the pages through ``attention_paged_rows_step``,
+    ``lm_head_argmax`` over 100,352 columns (784 lane tiles). Neither the
+    pool nor a state is copied."""
+    olmo, cfg, params, pools, state, stats = _olmo()
+    assert set(pools) == {"3"} and set(state) == {"0", "1", "2"}
+    assert pools["3"]["kv"].shape == (OLMO_PAGES, PAGE, 7680)
+    assert state["0"]["s"].shape == (SLOTS, 30, 96, 192)
+    assert state["0"]["conv"].shape == (SLOTS, 3, 11520)
+    assert params["blocks"]["0"]["w_in"]["int8"].shape == (3840, 17536)
+    assert params["blocks"]["3"]["wqkv"]["int8"].shape == (3840, 11520)
+
+    def program(p, *args):
+        return olmo.window_program(p, cfg, 8, None, *args)
+
+    compiled = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
+        chip(params),
+        *chip((_s((SLOTS,), I32), pools, stats, _s((SLOTS,), I32),
+               _s((SLOTS, OLMO_SEQ // PAGE), I32), _s((SLOTS,), jnp.bool_),
+               _s((SLOTS,), I32), _s((SLOTS,), I32), state)),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_state_step\S* = ", text)) == 3  # a linear layer
+    assert len(re.findall(r"= \S+ custom-call\(.*attention_paged_rows_step",
+                          text)) == 1
+    assert _olmo_cache_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_olmo_hybrid_chunk_program_compiles_and_moves_no_cache(chip):
+    """The 256-row prefill chunk: the blocked delta rule in four blocks of
+    64 rows with its unit-triangular systems SOLVED (``beta`` reaches 2:
+    XLA's triangular solve, which the chip's compiler takes), the full
+    layer's block loop over the cached rows."""
+    olmo, cfg, params, pools, state, stats = _olmo()
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return olmo.fused_paged_chunk_step(
+            p, cfg, ids, pools, state, stats, position, bt, valid, slot)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3, 6)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((OLMO_SEQ // PAGE,), I32), state, _s((), I32),
+               _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _olmo_cache_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_state_snapshot_copy_compiles_in_place(chip):
+    """The engine's snapshot copy (a row of the slots' state into a row of
+    the 35-row pool, and back) at the published state: a dynamic slice and
+    a dynamic update a leaf on the donated tree, no whole-array copy."""
+    olmo, cfg, _params, _pools, state, _stats = _olmo()
+    rows = jax.eval_shape(lambda: olmo.init_slot_state(cfg, 35))
+
+    def copy_row(into, of, to_row, from_row):
+        return jax.tree.map(
+            lambda a, b: jax.lax.dynamic_update_index_in_dim(
+                a, jax.lax.dynamic_index_in_dim(b, from_row, keepdims=False),
+                to_row, 0), into, of)
+
+    for into, of, shape in ((rows, state, "f32[35,30,96,192]"),
+                            (state, rows, "f32[16,30,96,192]")):
+        compiled = jax.jit(copy_row, donate_argnums=(0,)).lower(
+            *chip((into, of, _s((), I32), _s((), I32)))).compile()
+        assert not [line for line in compiled.as_text().splitlines()
+                    if " copy(" in line and shape in line.split(" copy(")[0]]

@@ -146,3 +146,36 @@ def test_the_state_is_aliased_and_the_call_carries_its_name():
               if jnp.issubdtype(v.aval.dtype, jnp.floating)}
     assert floats == {jnp.dtype(jnp.float32)}
     assert K.head_block(64, 128, 128) == 16  # 1 MB a grid step, 4 steps a row
+
+
+@pytest.mark.parametrize("active", ["all", "some"])
+def test_the_kernel_holds_the_references_step_at_30_heads_of_96_by_192(active):
+    """Olmo-Hybrid-7B's heads (one block of all 30: no divisor of 30 is a
+    multiple of 8), ONE decay a head spread over the key channels by
+    ``models/delta_rule.delta_rule_step``, ``beta`` in (1, 2)
+    (``linear_allow_neg_eigval``): against the plain reference's step,
+    ``S~ = alpha S; S' = S~ + beta k (v - S~^T k)^T; o = S'^T q``, as
+    ``olmo_hybrid_reference.delta_rule`` writes it."""
+    from dora_tpu.models.delta_rule import delta_rule_step
+
+    shape = (3, 30, 96, 192)
+    assert K.head_block(*shape[1:]) == 30
+    state, _g, k, q, v, beta = inputs(shape, seed=9)
+    beta = 1.0 + beta
+    assert float(beta.min()) > 1.0 and float(beta.max()) < 2.0
+    rng = np.random.default_rng(10)
+    g = -jnp.exp(jnp.asarray(rng.standard_normal(shape[:2]), jnp.float32) - 2.0)
+    on = jnp.asarray(ACTIVE[active](shape[0]), bool)
+
+    def step(s, g_, k_, q_, v_, b_):
+        s = s * jnp.exp(g_)[:, None, None]
+        pred = jnp.einsum("hkv,hk->hv", s, k_)
+        s = s + (b_[:, None] * k_)[:, :, None] * (v_ - pred)[:, None, :]
+        return jnp.einsum("hkv,hk->hv", s, q_), s
+
+    o_ref, s_ref = jax.vmap(step)(state, g, k, q, v, beta)
+    o, s = delta_rule_step(state, g, k, q, v, beta, on)
+    live = np.asarray(on)
+    assert np.abs(np.asarray(o) - np.asarray(o_ref))[live].max() < 2e-5
+    assert np.abs(np.asarray(s) - np.asarray(s_ref))[live].max() < 2e-5
+    assert (np.asarray(s)[~live] == np.asarray(state)[~live]).all()

@@ -22,9 +22,9 @@ from dora_tpu.models import paged_model as PM
 HF = Path(PM.__file__).parent / "hf"
 #: every module under models/hf/ but the package file
 FILES = sorted(p.stem for p in HF.glob("*.py") if p.stem != "__init__")
-#: the seven files whose engines ``build_engine`` builds
+#: the eight files whose engines ``build_engine`` builds
 BUILT = ("kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next", "keye_vl2",
-         "zaya")
+         "zaya", "olmo_hybrid")
 #: a tower over a whole text model sits ABOVE that model's file (the
 #: frames tier, ROADMAP debt 2); nothing else may look sideways
 WRAPS = {"internvl": {"qwen2"}, "qwen2_vl": {"qwen2"}}
@@ -144,6 +144,10 @@ V5E = 16_909_336_064  # bytes_limit of a 16 GB v5e
     # 20,480 B a token: 2.68 GB of (16.91 - 5.0 - 4.29) GB, every slot
     ("zaya1-8b-pp2.reason-16", 16 * 20480, 5_000_000_000, 8192, 1,
      16 * 8192 // 16 + 1),
+    # 61,440 B a token; in use: 4.49 GB of weights, 16 slots' state and 35
+    # snapshots of 27.4 MB: 6.72 GB of pages, memory's bound and not the cap
+    ("olmo-hybrid-7b-pp2.sessions-16", 16 * 61440,
+     4_490_000_000 + (16 + 35) * 27_371_520, 12288, 1, 6844),
 ])
 def test_pages_that_fit_gives_each_cell_its_pool(cell, page_bytes, used,
                                                  max_seq, multiple, want):
@@ -206,6 +210,9 @@ def test_a_built_engine_goes_when_its_last_holder_lets_go(monkeypatch):
     class Engine:
         def __init__(self, **kw):
             self.kw, self.allocator = kw, object()
+
+        def snapshot_stats(self):
+            return {}
 
     monkeypatch.setattr(PM, "PagedBatchEngine", Engine)
     for knob in KNOBS:

@@ -272,6 +272,7 @@ def test_admission_counts_evictable_pages_only_when_the_free_list_is_short(
     eng = SimpleNamespace(
         allocator=a, prefix_cache=c, page_size=4, chunk=4, max_seq=64,
         lora=None, free_slots=1, _spec_cfg=0, max_slots=1,
+        snapshot_pool=None,
     )
     for name in ("can_admit", "fits", "pages_needed", "spec_headroom",
                  "_prefix_grant"):
@@ -279,7 +280,7 @@ def test_admission_counts_evictable_pages_only_when_the_free_list_is_short(
     eng.free_pages = a.free_pages
     ids = list(range(1, 17)) + [99, 98]  # 4 cached pages + a tail
     assert eng.can_admit(len(ids), 2)  # needs 5 pages
-    base, shared = eng._prefix_grant(ids, 2)
+    base, shared, _node = eng._prefix_grant(ids, 2)
     assert (base, len(shared)) == (16, 4)
     assert bool(walks) == (not free_enough)
     a.unref(shared)
