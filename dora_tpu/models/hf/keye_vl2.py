@@ -40,9 +40,12 @@ per-row, per-tick selection.**
 * a decode tick writes the row's ``kv`` and ``ik``, then takes the LIVE
   rows ``DECODE_ROWS`` at a time (a frozen row scores nothing): scores
   their own pages a block of ``INDEX_BLOCK`` positions at a time up to
-  their longest context, ``lax.top_k`` (ties to the lower position),
-  finds the picked rows in the pool, gathers them in one XLA gather and
-  attends those alone (``ops/picked_rows``: ``pool_rows``, ``attend_rows``).
+  their longest context, names the ``topk`` best without a sort
+  (``ops/picked_ids``: the ``topk``-th largest by counts, the ids by
+  compare-and-sum, in ascending position: ``lax.top_k``'s set, ties to
+  the lower position), finds the picked rows in the pool, gathers them in
+  one XLA gather and attends those alone (``ops/picked_rows``:
+  ``pool_rows``, ``attend_rows``).
   ``dsa_rows_fetched`` = what that gather reads, fixed by its shapes:
   ``topk`` a row of every whole group (no kernel copies rows by count:
   Mosaic names no single row of such a leaf, ``KNOWN_ISSUES.md`` "PR 50").
@@ -79,6 +82,7 @@ from dora_tpu.models import paged_model as PM
 from dora_tpu.models.hf.loader import TensorFiles, read_config
 from dora_tpu.models.paged_window import make_paged_window
 from dora_tpu.ops.int8_matmul import quantize_int8_t as _quantize_t
+from dora_tpu.ops.picked_ids import picked_ids
 from dora_tpu.ops.picked_rows import attend_rows, pool_rows
 
 MODEL_TYPES = ("KeyeVL2",)
@@ -89,8 +93,9 @@ ATTN_BLOCK = 256
 #: positions of one block of cached indexer keys in both programs'
 #: scoring loop (a multiple of the page): work follows the longest context
 INDEX_BLOCK = 2048
-#: live rows a decode tick scores, sorts, gathers and attends at a time:
-#: its selection follows the rows that are live, not the slots
+#: live rows a decode tick scores, picks for (one grid step of
+#: ``ops/picked_ids``), gathers and attends at a time: its selection
+#: follows the rows that are live, not the slots
 DECODE_ROWS = 4
 #: eps of the indexer's LayerNorm (DeepSeek-V3.2's; no key of the config)
 INDEX_NORM_EPS = 1e-6
@@ -423,10 +428,10 @@ def dsa_decode(blk, cfg: KeyeVL2Config, u, pool, positions, block_tables,
     are), ``DECODE_ROWS`` at a time: a row at ``t >= topk`` scores
     positions ``0..t`` of its own pages and attends the ``topk`` best;
     below that it attends ``0..t``. Either way ``topk`` rows a live row
-    are gathered through the block table; a frozen row scores, sorts and
+    are gathered through the block table; a frozen row scores, picks and
     gathers nothing and puts out zeros. Returns (output [B, dim], pool, a
     look at the selection: the rows attended ``"rows" [B]``, the picked
-    positions ``"picked" [B, topk]`` and the output rows)."""
+    positions ``"picked" [B, topk]``, ascending, and the output rows)."""
     f32 = jnp.float32
     kvp, ikp = pool["kv"], pool["ik"]
     page, k_ = kvp.shape[1], cfg.idx_topk
@@ -466,7 +471,7 @@ def dsa_decode(blk, cfg: KeyeVL2Config, u, pool, positions, block_tables,
                     jnp.where(selecting, t_g + 1, 0),
                     jnp.where(selecting, t_g, 0).max() // block + 1, block)
             with jax.named_scope("dsa_select"):
-                return jax.lax.top_k(s, k_)[1]
+                return picked_ids(s, k_)
 
         ids = jax.lax.cond(selecting.any(), scored, lambda _: first, None)
         with jax.named_scope("dsa_select"):
@@ -513,8 +518,9 @@ def picked_mask(cfg: KeyeVL2Config, s, q_pos, ids: bool = False):
     with ``ids`` the picked positions themselves ``[C, topk]``, else
     None): the scores held to the row's ``topk``-th largest
     (:func:`kth_largest`), equal scores going to the lower positions as
-    ``lax.top_k`` breaks its ties, which is what gives the ids (an audit's
-    look: a sort of every row, which a served chunk does not pay). Rows
+    ``lax.top_k`` breaks its ties; the ids are the mask's own positions
+    (an audit's look, which a served chunk does not pay: the one way from
+    scores to ids, ``ops/picked_ids``, over the mask as its scores). Rows
     below ``topk`` pick by position, not here."""
     k_ = cfg.idx_topk
     keys, kth = kth_largest(s, k_)
@@ -529,7 +535,7 @@ def picked_mask(cfg: KeyeVL2Config, s, q_pos, ids: bool = False):
     if not ids:
         return sel & selecting, None
     return sel & selecting, jnp.where(
-        selecting, jax.lax.top_k(s, k_)[1], jnp.arange(k_))
+        selecting, picked_ids(sel.astype(jnp.float32), k_), jnp.arange(k_))
 
 
 def dsa_chunk(blk, cfg: KeyeVL2Config, u, pool, position, block_table, rope,

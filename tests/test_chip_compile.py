@@ -1132,6 +1132,35 @@ def _keye():
     return keye_vl2, cfg, jax.eval_shape(build), pools, stats
 
 
+def _instructions(text: str, op: str) -> list[tuple[str, str]]:
+    """(result shape, ``op_name``) of every ``op`` instruction of an
+    optimised HLO module's text."""
+    found = []
+    for line in text.splitlines():
+        if f" {op}(" not in line:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        found.append((line.split(f" {op}(")[0].split(" = ", 1)[1],
+                      name.group(1) if name else ""))
+    return found
+
+
+def test_picked_ids_compiles_with_no_sort_and_no_scatter(chip):
+    """The tick's ids of one group, ``[4, 16384]`` float32 scores -> 2,048
+    positions a row: ONE Mosaic kernel (the scores whole in VMEM, the
+    counts' and the compaction's small products on the MXU) and nothing
+    beside it that sorts, scatters or gathers."""
+    from dora_tpu.ops.picked_ids import picked_ids
+
+    compiled = picked_ids.lower(
+        chip(_s((4, KEYE_SEQ), jnp.float32)), k=2048).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    for op in ("sort", "scatter", "gather"):
+        assert _instructions(text, op) == [], op
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def _keye_pool_copies(compiled) -> list[str]:
     """``copy`` instructions of a whole pool leaf, by shape."""
     shapes = ("bf16[16385,16,1024]", "bf16[16385,8,128]")
@@ -1145,9 +1174,10 @@ def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
     """The K=8 decode window at Keye-VL-2.0's widths, 16 slots of 16,384
     rows: every matrix through ``int8_matmul`` (the 6,336-wide fused
     attention and indexer projection), ``lm_head_argmax`` over 18,992
-    columns; the index scores a block of 2,048 positions at a time,
-    ``top_k`` of 2,048 among 16,384 and the gather of 2,048 K|V rows a
-    row in plain XLA: ONE row gather a layer, the rows' addresses with no
+    columns; the index scores a block of 2,048 positions at a time and
+    the gather of 2,048 K|V rows a row in plain XLA, the 2,048 of 16,384
+    between them named by ``ops/picked_ids`` with no sort and no scatter:
+    ONE row gather a layer, the rows' addresses with no
     gather of scalars (``pool_rows``), keys and values of a head as lane
     slices of the gathered rows, no relayout of them (``attend_rows``).
     Two leaves of pages a layer; no copy of either. As the server jits
@@ -1179,13 +1209,23 @@ def test_keye_window_program_compiles_and_moves_no_pool(chip, picks):
     assert "tpu_custom_call" in text
     assert _keye_pool_copies(compiled) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    gathered = [line.split(" gather(")[0].split(" = ")[1]
-                for line in text.splitlines() if " gather(" in line]
+    gathered = [shape for shape, _ in _instructions(text, "gather")]
     assert sum(g.startswith("bf16[4,2048,1024]") for g in gathered) == cfg.layers
     assert not [g for g in gathered if g.startswith(("s32[4,2048]", "s32[8192]"))]
     assert "bf16[4,2048,2,4,128]" not in text
     assert _expert_conds(compiled) == []
     assert _expert_stack_readers(compiled, stack) == []
+    # the tick's ids come without a sort and without a scatter: the sorts
+    # left are the router's 8 of 128 a layer and the slots' live order, and
+    # nothing under the selection's scope sorts or scatters
+    # (``ops/picked_ids``; ``nonzero`` or ``argsort`` would bring them back)
+    sorts = _instructions(text, "sort")
+    assert len(sorts) == cfg.layers + 1
+    assert all("moe_router/top_k" in name or "argsort" in name
+               for _, name in sorts)
+    assert not [name for op in ("sort", "scatter")
+                for _, name in _instructions(text, op) if "dsa_select" in name]
+    assert "picked_ids" in text
 
 
 def test_mosaic_copies_no_single_row_of_a_joined_page(chip):
@@ -1232,10 +1272,11 @@ def test_mosaic_copies_no_single_row_of_a_joined_page(chip):
 @pytest.mark.parametrize("picks", [False, True], ids=["served", "audited"])
 def test_keye_chunk_program_compiles_and_moves_no_pool(chip, picks):
     """The 256-row prefill chunk: the index scores of 256 rows against
-    the cached keys a block of 2,048 at a time, ``top_k`` of 2,048 among
-    16,384 a row, the mask by threshold (no scatter), attention over
-    cached blocks of 256 rows under it, the experts' rows gathered 32 at
-    a time."""
+    the cached keys a block of 2,048 at a time, the mask by threshold at
+    each row's 2,048th largest of 16,384 (no sort, no scatter; an audit's
+    ids are the mask's positions through ``ops/picked_ids``), attention
+    over cached blocks of 256 rows under it, the experts' rows gathered
+    32 at a time."""
     keye_vl2, cfg, params, pools, stats = _keye()
 
     def step(p, ids, pools, stats, position, bt, valid):

@@ -1,8 +1,10 @@
 """The decode tick's fetch of the picked rows and their attention
 (``ops/picked_rows``: ``pool_rows`` + the flat row gather + ``attend_rows``)
-against a plain ``jax.numpy`` gather-and-softmax, and ``dsa_decode``
-through them against the XLA form they replaced (PR 49's, kept below
-line for line as the reference), on the CPU in float32.
+against a plain ``jax.numpy`` gather-and-softmax, the tick's ids without a
+sort (``ops/picked_ids``) against ``lax.top_k``'s set, and ``dsa_decode``
+through all of them against the XLA form they replaced (PR 49's with its
+``lax.top_k``, kept below line for line as the reference), on the CPU in
+float32.
 
 No kernel is under test: the picked-rows kernel this file was asked for
 cannot be written over a ``[P, page, 2 * KV * hd]`` leaf (Mosaic slices a
@@ -20,6 +22,7 @@ import numpy as np
 import pytest
 
 from dora_tpu.models.hf import keye_vl2 as K
+from dora_tpu.ops.picked_ids import picked_ids
 from dora_tpu.ops.picked_rows import attend_rows, pool_rows
 from tests.test_keye_vl2 import (  # noqa: F401  (ckpt, model: fixtures)
     MAX_SEQ, PAGE, TOPK, ckpt, model)
@@ -118,15 +121,96 @@ def test_page_numbers_past_sixteen_bits_come_through():
 
 
 # ---------------------------------------------------------------------------
+# the ids without a sort
+# ---------------------------------------------------------------------------
+
+
+def _random(rng, r, n, k):
+    return rng.standard_normal((r, n))
+
+
+def _integers(rng, r, n, k):
+    """A handful of levels: the k-th largest is shared, and its level
+    holds more positions than have room."""
+    return rng.integers(-2, 3, (r, n))
+
+
+def _level_fits(rng, r, n, k):
+    """Ties across the k-th that do not overflow: k - 2 distinct scores on
+    top, then exactly two at the level, the rest below."""
+    s = np.tile(np.arange(n, 0, -1.0), (r, 1))
+    s[:, k - 2 : k] = s[:, k - 1 : k]
+    return np.stack([rng.permutation(row) for row in s])
+
+
+def _both_zeros(rng, r, n, k):
+    """``+0.0`` beside ``-0.0`` across the k-th: ``lax.top_k`` holds the
+    first above the second (the sort's total order)."""
+    s = np.where(rng.random((r, n)) < 0.5, 0.0, -0.0)
+    s[:, : k // 2] = rng.standard_normal((r, k // 2))
+    return s
+
+
+def _tail(finite):
+    def make(rng, r, n, k):
+        s = rng.standard_normal((r, n))
+        s[:, k + finite:] = -np.inf
+        return s
+    return make
+
+
+def _all_equal(rng, r, n, k):
+    return np.full((r, n), 0.25)
+
+
+@pytest.mark.parametrize("rows,n,k", [
+    (1, 32, 8), (4, 32, 8), (16, 32, 8), (4, 16384, 2048),  # the cell's group
+], ids=["1x32-8", "4x32-8", "16x32-8", "4x16384-2048"])
+@pytest.mark.parametrize("scores", [
+    _random, _integers, _level_fits, _both_zeros, _tail(0), _tail(1),
+    _all_equal,
+], ids=["random", "integers", "level-fits", "both-zeros", "k-finite",
+        "k-plus-1-finite", "all-equal"])
+def test_picked_ids_are_top_ks_set_in_ascending_position(scores, rows, n, k):
+    rng = np.random.default_rng(rows * n + k)
+    s = jnp.asarray(scores(rng, rows, n, k), jnp.float32)
+    ids = np.asarray(picked_ids(s, k))
+    want = np.asarray(jax.lax.top_k(s, k)[1])
+    assert ids.shape == (rows, k) and ids.dtype == np.int32
+    for r in range(rows):
+        assert (np.diff(ids[r]) > 0).all(), r  # ascending, so distinct
+        assert ids[r].tolist() == sorted(want[r].tolist()), r
+
+
+@pytest.mark.parametrize("k,n,dtype,match", [
+    (0, 32, jnp.float32, "0 of 32"), (33, 32, jnp.float32, "33 of 32"),
+    (8, 32, jnp.bfloat16, "float32"),
+])
+def test_picked_ids_refuses_what_it_cannot_pick(k, n, dtype, match):
+    with pytest.raises(ValueError, match=match):
+        picked_ids(jnp.zeros((2, n), dtype), k)
+
+
+def test_picked_ids_counts_past_256_blocks_exactly():
+    """Over 32,768 scores a trial's partial counts pass what bf16 holds:
+    they are taken 256 blocks at a time."""
+    rng = np.random.default_rng(9)
+    s = jnp.asarray(np.round(rng.standard_normal((2, 40000)) * 2), jnp.float32)
+    want = np.asarray(jax.lax.top_k(s, 3000)[1])
+    assert np.asarray(picked_ids(s, 3000)).tolist() == np.sort(want, -1).tolist()
+
+
+# ---------------------------------------------------------------------------
 # dsa_decode against the form it replaced
 # ---------------------------------------------------------------------------
 
 
 def dsa_decode_pr49(blk, cfg, u, pool, positions, block_tables, live, rope,
                     block):
-    """``keye_vl2.dsa_decode`` as PR 49 left it: the addresses by
-    ``take_along_axis``, the rows split into keys and values of every head
-    (``_split_rows``), two einsums over all heads."""
+    """``keye_vl2.dsa_decode`` as PR 49 left it: the ids by ``lax.top_k``
+    (in descending score), the addresses by ``take_along_axis``, the rows
+    split into keys and values of every head (``_split_rows``), two einsums
+    over all heads."""
     f32 = jnp.float32
     kvp, ikp = pool["kv"], pool["ik"]
     page, k_ = kvp.shape[1], cfg.idx_topk
@@ -197,8 +281,10 @@ def dsa_decode_pr49(blk, cfg, u, pool, positions, block_tables, live, rope,
 def test_dsa_decode_against_the_form_it_replaced(model, slots):
     """A tick of every layer's sublayer on the tiny model, rows below and
     past ``topk`` beside frozen ones, over pages that a long run would
-    have written (random, the same for both): the same picks, the same
-    rows attended, the same pool, the output to float32 summation order."""
+    have written (random, the same for both): the same picked SETS (the
+    tick names them in ascending position, ``lax.top_k`` in descending
+    score), the same rows attended, the same pool, the output to float32
+    summation order."""
     cfg, params, _ = model
     max_pages = MAX_SEQ // PAGE
     rng = np.random.default_rng(slots)
@@ -219,7 +305,8 @@ def test_dsa_decode_against_the_form_it_replaced(model, slots):
         out, pool_new, look = jax.jit(K.dsa_decode, static_argnums=(1, 8))(*args)
         ref_out, ref_pool, ref_look = jax.jit(
             dsa_decode_pr49, static_argnums=(1, 8))(*args)
-        np.testing.assert_array_equal(look["picked"], ref_look["picked"])
+        np.testing.assert_array_equal(
+            look["picked"], np.sort(ref_look["picked"], -1))
         np.testing.assert_array_equal(look["rows"], ref_look["rows"])
         assert np.asarray(look["rows"])[np.asarray(active)].tolist() == [
             min(int(t) + 1, TOPK) for t in np.asarray(positions)[active]]
