@@ -227,6 +227,12 @@ class _PagedSlot:
     #: saved and has not handed to the cache yet
     snap_from: object | None = None
     snap_saved: tuple | None = None
+    #: where the prompt leaves what the radix tree already knew: the last
+    #: chunk edge inside its match and past its grant (0 = none), and the
+    #: ``(depth, row)`` of the snapshot saved there, the stream's until
+    #: its final chunk is adopted
+    branch_edge: int = 0
+    snap_branch: tuple | None = None
 
 
 class PagedBatchEngine:
@@ -305,8 +311,16 @@ class PagedBatchEngine:
     cache down to THAT depth with the row on the deepest node. A grant
     is trimmed to the deepest node of the match that holds a row, never
     between two, and the row is copied into the slot right before the
-    stream's first chunk, which then starts at that depth. Rows are
-    counted as pages are (:meth:`check_invariants`). :meth:`preempt`
+    stream's first chunk, which then starts at that depth. **A second
+    snapshot where a prompt branches**: at admission the radix match
+    reaches a depth ``m`` and the grant a depth ``d <= m``; where a chunk
+    edge ``e`` with ``d < e <= m`` exists, the last such edge gets a row
+    too, behind the chunk that ends there, and the row goes to the node
+    at ``e`` (first writer wins). The tree has seen two prompts share
+    ``[0, m)``, which is the evidence that a third will, and the third is
+    granted to within a chunk and a page of the shared depth; a session
+    that resends its history has ``m - d`` under a chunk and saves none.
+    Rows are counted as pages are (:meth:`check_invariants`). :meth:`preempt`
     gives back what its stream had not handed over and leaves the
     cache's rows where they are; :meth:`save_pools` does not carry the
     snapshot pool and :meth:`checkpoint_state` not the radix tree, so a
@@ -565,6 +579,8 @@ class PagedBatchEngine:
         #: snapshots copied out of a slot and into one, and their bytes
         self.snapshots_saved = 0
         self.snapshots_restored = 0
+        #: of the saved, those at a prompt's branch edge
+        self.snapshots_branch_saved = 0
         self.snapshot_bytes = (
             sum(x.nbytes // x.shape[0]
                 for x in jax.tree.leaves(self.snapshot_pool))
@@ -711,9 +727,10 @@ class PagedBatchEngine:
                     f"of pinned adapters ({adapter!r} not resident)"
                 )
         b = self.slots.index(None)
-        base0, shared, granted = (0, [], None)
+        base0, shared, granted, branch = (0, [], None, 0)
         if self.prefix_cache is not None:
-            base0, shared, granted = self._prefix_grant(ids, max_new, adapter)
+            base0, shared, granted, branch = self._prefix_grant(
+                ids, max_new, adapter)
         need = self.pages_needed(len(ids), max_new, base0) - len(shared)
         if need > self.allocator.free_pages and self.prefix_cache is not None:
             self.prefix_cache.evict(need - self.allocator.free_pages)
@@ -736,7 +753,7 @@ class PagedBatchEngine:
             request_id, emitted=0, max_new=max_new, pages=pages,
             prompt=ids, true_len=len(ids), chunk_base=base0,
             shared=len(shared), adapter=adapter, adapter_idx=aidx,
-            snap_from=granted,
+            snap_from=granted, branch_edge=branch,
         )
         self._decode[b] = False
         self._prefillq.append(b)
@@ -761,7 +778,7 @@ class PagedBatchEngine:
 
     def _prefix_grant(self, ids: list[int], max_new: int,
                       adapter: str | None = None
-                      ) -> tuple[int, list[int], object | None]:
+                      ) -> tuple[int, list[int], object | None, int]:
         """Longest usable cached prefix for a new prompt: looks up the
         radix cache, trims the match so (a) at least the final prompt
         token is re-prefilled (the first generated token comes off the
@@ -769,9 +786,12 @@ class PagedBatchEngine:
         stays inside the block table, and (c) the fresh-page need fits
         free + evictable pages (sharing must never turn an admissible
         request inadmissible). Refs the shared pages into this stream's
-        custody and returns ``(divergence_base, shared_page_ids, node)``:
-        ``node`` is the radix node whose snapshot the grant ends at,
-        promised to this admission (None without snapshots or a grant).
+        custody and returns ``(divergence_base, shared_page_ids, node,
+        branch_edge)``: ``node`` is the radix node whose snapshot the
+        grant ends at, promised to this admission (None without snapshots
+        or a grant); ``branch_edge`` is the last chunk edge past the grant
+        and inside the match (0 without snapshots or such an edge): where
+        this prompt's second snapshot goes.
 
         Trimmed boundary pages are re-materialized privately by the
         divergence chunk — the copy-on-write boundary copy (the copy
@@ -840,7 +860,12 @@ class PagedBatchEngine:
         # final-token / reach / capacity rules above.
         if matched > lo or mid_page:
             cache.cow_copies += 1
-        return lo, shared, granted
+        branch = 0
+        if stands is not None:
+            # the stream's chunks start at ``lo``: its edges are lo + n chunk
+            edge = lo + (min(matched, cap) - lo) // self.chunk * self.chunk
+            branch = edge if edge > lo else 0
+        return lo, shared, granted, branch
 
     def _free_slot(self, b: int) -> None:
         # unref, not free: leading pages may be shared with the prefix
@@ -866,9 +891,10 @@ class PagedBatchEngine:
         if s.snap_from is not None:
             self.prefix_cache.snapshot_release(s.snap_from)
             s.snap_from = None
-        if s.snap_saved is not None:
-            self.prefix_cache.snapshot_give_back(s.snap_saved[1])
-            s.snap_saved = None
+        for kept in (s.snap_saved, s.snap_branch):
+            if kept is not None:
+                self.prefix_cache.snapshot_give_back(kept[1])
+        s.snap_saved = s.snap_branch = None
 
     def snapshot_stats(self) -> dict:
         """The snapshot pool's counters and gauges (empty without one)."""
@@ -878,6 +904,7 @@ class PagedBatchEngine:
         return {
             "state_snapshots_saved": self.snapshots_saved,
             "state_snapshots_restored": self.snapshots_restored,
+            "state_snapshots_branch_saved": self.snapshots_branch_saved,
             "state_snapshots_evicted": cache.snapshots_evicted,
             "state_snapshot_bytes_copied": self.snapshot_bytes * (
                 self.snapshots_saved + self.snapshots_restored),
@@ -935,8 +962,9 @@ class PagedBatchEngine:
             cache = self.prefix_cache
             rows = Counter(cache._snap_free)
             rows.update(cache.snapshot_rows())
-            rows.update(s.snap_saved[1] for s in self.slots
-                        if s is not None and s.snap_saved is not None)
+            rows.update(kept[1] for s in self.slots if s is not None
+                        for kept in (s.snap_saved, s.snap_branch)
+                        if kept is not None)
             assert sorted(rows) == list(range(cache.snapshots)) and all(
                 n == 1 for n in rows.values()), (
                 f"snapshot rows {dict(rows)} of {cache.snapshots}")
@@ -1333,14 +1361,16 @@ class PagedBatchEngine:
 
     def _save_snapshot(self, s: _PagedSlot, b: int, depth: int) -> None:
         """Behind the chunk that ends at ``depth``: where that was the
-        prompt's last FULL chunk, the slot now holds the state at
-        ``depth`` and nothing but the stream's next chunk will change it
-        (a decode tick leaves a prefilling row's state alone), so copy it
-        into a row of the pool here, in line behind the chunk. The row is
-        the stream's until its final chunk is adopted and the cache takes
-        it (:meth:`_adopt_chunk`). No row where the depth holds one
-        already, or every row is promised."""
-        if depth != s.true_len // self.chunk * self.chunk:
+        prompt's last FULL chunk or its branch edge (where it leaves what
+        the radix tree knew at its admission), the slot now holds the
+        state at ``depth`` and nothing but the stream's next chunk will
+        change it (a decode tick leaves a prefilling row's state alone),
+        so copy it into a row of the pool here, in line behind the chunk.
+        The row is the stream's until its final chunk is adopted and the
+        cache takes it (:meth:`_adopt_chunk`). No row where the depth
+        holds one already, or every row is promised."""
+        last_full = depth == s.true_len // self.chunk * self.chunk
+        if not last_full and depth != s.branch_edge:
             return
         cache = self.prefix_cache
         stands = cache.snapshots_on_path(s.prompt, s.adapter, depth)
@@ -1353,8 +1383,16 @@ class PagedBatchEngine:
             self._copy_row, self.snapshot_pool, self.slot_state,
             self._jnp.asarray(row, self._jnp.int32),
             self._jnp.asarray(b, self._jnp.int32))
-        s.snap_saved = (depth, row)
+        if last_full:
+            s.snap_saved = (depth, row)
+        else:
+            s.snap_branch = (depth, row)
         self.snapshots_saved += 1
+        if depth == s.branch_edge:
+            self.snapshots_branch_saved += 1
+            if self.tracer.active:
+                self.tracer.span("s_branch_snapshot", s.request_id,
+                                 f"depth={depth} row={row}")
 
     def _chunk_span(self, s: _PagedSlot, base: int, t_chunk: float) -> None:
         tracer = self.tracer
@@ -1395,16 +1433,24 @@ class PagedBatchEngine:
                 # ... down to the depth whose state this stream saved,
                 # and no further: a page past the deepest snapshot can
                 # be granted to nobody
-                n_full = s.snap_saved[0] // self.page_size if s.snap_saved else 0
+                saved = [kept for kept in (s.snap_branch, s.snap_saved)
+                         if kept is not None]
+                n_full = max((d for d, _ in saved), default=0) // self.page_size
             if n_full:
                 self.prefix_cache.insert(
                     s.prompt[: n_full * self.page_size],
                     s.pages[:n_full],
                     s.adapter,
                 )
-            if s.snap_saved is not None and self.prefix_cache.snapshot_attach(
-                    s.prompt, *s.snap_saved, s.adapter):
-                s.snap_saved = None
+            if self.snapshot_pool is not None:
+                # a row whose depth holds one already stays the stream's,
+                # and goes back with whatever else it did not hand over
+                if s.snap_branch and self.prefix_cache.snapshot_attach(
+                        s.prompt, *s.snap_branch, s.adapter):
+                    s.snap_branch = None
+                if s.snap_saved and self.prefix_cache.snapshot_attach(
+                        s.prompt, *s.snap_saved, s.adapter):
+                    s.snap_saved = None
             self._let_go_of_snapshots(s)
         s.prompt = None
         # Its first token exists, on the device: the window's

@@ -30,9 +30,12 @@ import jax.numpy as jnp
 from dora_tpu.models import layers as L
 from dora_tpu.ops.int8_matmul import int8_matmul_grouped, quantize_int8_t
 
-#: rows one expert computes at a time in a prefill chunk. A decode batch
-#: of at most this many rows goes to every expert it touched whole.
+#: rows one expert computes at a time in a prefill chunk.
 EXPERT_BLOCK = 32
+#: a batch of at most this many rows (a decode tick's, up to 64 slots)
+#: goes to every expert it touched whole: two grouped products a layer,
+#: where the chunk's form would run a loop an expert a tick.
+WHOLE_ROWS = 64
 
 
 class ExpertLayerConfig(Protocol):
@@ -195,7 +198,7 @@ def held_experts(blk, cfg: ExpertLayerConfig, x, local, weights, live):
     stack = blk["experts"]
     held = stack["w_down"]["int8"].shape[0]
     with jax.named_scope("moe_experts"):
-        if n <= EXPERT_BLOCK:
+        if n <= WHOLE_ROWS:
             experts = jnp.arange(held)
             landed = jnp.where(live[:, None], local, -1)  # [N, k]
             touched = (landed == experts[:, None, None]).any((1, 2))  # [E]

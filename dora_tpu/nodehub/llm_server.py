@@ -120,6 +120,7 @@ MODEL_MODULES = {
     "KeyeVL2": "keye_vl2",
     "zaya": "zaya",
     "olmo_hybrid": "olmo_hybrid",
+    "kimi_linear": "kimi_linear",
 }
 
 

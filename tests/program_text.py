@@ -1,7 +1,7 @@
 """What the server lowers, as a table of hashes: the check that a change
 to shared code left every model's programs alone (ROADMAP debt 21 (b)).
 
-Not a test module. For each of the nine serving model files a tiny
+Not a test module. For each of the ten serving model files a tiny
 checkpoint is written, loaded and served as ``llm_server.make_engine``
 builds it, one stream runs one chunk and two windows, and every module
 JAX lowered on the way (``jax_dump_ir_to``: the StableHLO of each jit,
@@ -11,7 +11,7 @@ names its name locations held (the ``jax.named_scope`` paths a trace is
 reduced by live only there). Two trees whose tables are equal lower the
 same programs under the same names, wherever their source lines moved.
 
-    python -m tests.program_text --out FILE                 # the nine, tiny, CPU
+    python -m tests.program_text --out FILE                 # the ten, tiny, CPU
     python -m tests.program_text --chip-compile --out FILE  # real widths, v5e
 
 ``--chip-compile`` runs ``tests/test_chip_compile.py`` in this process
@@ -144,7 +144,7 @@ def serve_tiny(name: str, checkpoint: Path) -> None:
 
 
 MODELS = ("qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next",
-          "keye_vl2", "zaya", "olmo_hybrid")
+          "keye_vl2", "zaya", "olmo_hybrid", "kimi_linear")
 
 
 def tiny_tables(models=MODELS) -> dict[str, dict[str, list[str]]]:

@@ -275,10 +275,19 @@ def _tiny_olmo(tmp_path):
     return olmo_hybrid.load(tmp_path / "ckpt", max_seq=64)
 
 
+def _tiny_kimi_linear(tmp_path):
+    from tests.kimi_linear_tiny import TINY, write_checkpoint
+
+    from dora_tpu.models.hf import kimi_linear
+
+    write_checkpoint(tmp_path / "ckpt", TINY)
+    return kimi_linear.load(tmp_path / "ckpt", max_seq=64)
+
+
 _TINY = {"kimi_k2": _tiny_kimi, "falcon_h1": _tiny_falcon, "ouro": _tiny_ouro,
          "exaone_moe": _tiny_exaone, "glm5_next": _tiny_glm5,
          "keye_vl2": _tiny_keye, "zaya": _tiny_zaya,
-         "olmo_hybrid": _tiny_olmo}
+         "olmo_hybrid": _tiny_olmo, "kimi_linear": _tiny_kimi_linear}
 
 
 def _engine_programs(module_name, monkeypatch, tmp_path):
@@ -328,7 +337,7 @@ def _engine_programs(module_name, monkeypatch, tmp_path):
 @pytest.mark.parametrize(
     "module_name",
     ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next",
-     "keye_vl2", "zaya", "olmo_hybrid"])
+     "keye_vl2", "zaya", "olmo_hybrid", "kimi_linear"])
 def test_engine_programs_take_the_weights_as_arguments(
     module_name, monkeypatch, tmp_path
 ):
@@ -400,7 +409,7 @@ def test_kernels_take_the_stored_weight(kernel, m, k, n):
 @pytest.mark.parametrize(
     "module_name",
     ["qwen2", "kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next",
-     "keye_vl2", "zaya", "olmo_hybrid"])
+     "keye_vl2", "zaya", "olmo_hybrid", "kimi_linear"])
 def test_engine_programs_copy_no_weight(module_name, monkeypatch, tmp_path):
     """The same walk over the window and chunk programs as the engine
     jits them (tiny models: vocab 256 and 128 are no multiple of the

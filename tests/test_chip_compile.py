@@ -1639,3 +1639,154 @@ def test_state_snapshot_copy_compiles_in_place(chip):
             *chip((into, of, _s((), I32), _s((), I32)))).compile()
         assert not [line for line in compiled.as_text().splitlines()
                     if " copy(" in line and shape in line.split(" copy(")[0]]
+
+
+# -- Kimi-Linear-48B-A3B: 64 slots, KDA heads of 128 x 128, a 640-wide latent row --
+
+KIMI_LINEAR = dict(
+    model_type="kimi_linear", vocab_size=40960, hidden_size=2304,
+    intermediate_size=9216, moe_intermediate_size=1024, num_hidden_layers=5,
+    num_attention_heads=32, kv_lora_rank=512, q_lora_rank=None,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    mla_use_nope=True, rope_scaling=None, rms_norm_eps=1e-5,
+    linear_attn_config=dict(kda_layers=[1, 2, 3, 5], full_attn_layers=[4],
+                            num_heads=32, head_dim=128,
+                            short_conv_kernel_size=4),
+    first_k_dense_replace=1, moe_layer_freq=1, num_experts=256, ep_size=4,
+    num_experts_per_token=8, num_shared_experts=1, moe_renormalize=True,
+    moe_router_activation_func="sigmoid", num_expert_group=1, topk_group=1,
+    routed_scaling_factor=2.446, tie_word_embeddings=False,
+)
+KL_SLOTS, KL_SEQ = 64, 16384
+KL_PAGES = KL_SLOTS * KL_SEQ // PAGE + 1
+
+
+def _kimi_linear():
+    """(module, cfg, the serving parameters' shapes as ``load`` builds
+    them, pool, slot-state and counter shapes) for published layers 1-5
+    (the dense layer and one whole period: the cell's second period is the
+    same programs), 64 slots of 16,384 rows, every slot's pages."""
+    from dora_tpu.models.hf import kimi_linear
+
+    cfg = kimi_linear.KimiLinearConfig.from_hf(KIMI_LINEAR, KL_SEQ, ep_rank=0)
+    bf, d, hk, r = jnp.bfloat16, cfg.dim, cfg.kda_width, cfg.kda_dim
+    ffn = {"gate_proj.weight": (cfg.moe_ffn, d), "up_proj.weight": (cfg.moe_ffn, d),
+           "down_proj.weight": (d, cfg.moe_ffn), "w1.weight": (cfg.moe_ffn, d),
+           "w3.weight": (cfg.moe_ffn, d), "w2.weight": (d, cfg.moe_ffn)}
+    kda = {
+        **{f"self_attn.{x}_proj.weight": (hk, d) for x in "qkv"},
+        **{f"self_attn.{x}_conv1d.weight": (hk, 1, cfg.conv) for x in "qkv"},
+        "self_attn.f_a_proj.weight": (r, d), "self_attn.f_b_proj.weight": (hk, r),
+        "self_attn.g_a_proj.weight": (r, d), "self_attn.g_b_proj.weight": (hk, r),
+        "self_attn.b_proj.weight": (cfg.kda_heads, d),
+        "self_attn.A_log": (cfg.kda_heads,), "self_attn.dt_bias": (hk,),
+        "self_attn.o_norm.weight": (r,), "self_attn.o_proj.weight": (d, hk),
+    }
+    mla = {
+        "self_attn.q_proj.weight": (cfg.heads * (cfg.nope + cfg.shared), d),
+        "self_attn.kv_a_proj_with_mqa.weight": (cfg.latent, d),
+        "self_attn.kv_a_layernorm.weight": (cfg.kv_rank,),
+        "self_attn.kv_b_proj.weight": (cfg.heads * (cfg.nope + cfg.v_dim),
+                                       cfg.kv_rank),
+        "self_attn.o_proj.weight": (d, cfg.heads * cfg.v_dim),
+    }
+
+    def get(name):
+        layer, rest = name.split(".", 3)[2:]
+        if rest in ("input_layernorm.weight", "post_attention_layernorm.weight"):
+            return jnp.zeros((d,), bf)
+        if rest.startswith("self_attn."):
+            return jnp.zeros((kda if cfg.linear[int(layer)] else mla)[rest], bf)
+        if rest.startswith("mlp."):
+            shape = ffn[rest.split(".", 1)[1]]
+            return jnp.zeros(tuple(cfg.ffn if n == cfg.moe_ffn else n
+                                   for n in shape), bf)
+        if rest == "block_sparse_moe.gate.weight":
+            return jnp.zeros((cfg.n_experts, d), bf)
+        if rest == "block_sparse_moe.gate.e_score_correction_bias":
+            return jnp.zeros((cfg.n_experts,), bf)
+        return jnp.zeros(ffn[rest.rsplit(".", 2)[1] + ".weight"], bf)
+
+    def build():
+        return {
+            "embed": jnp.zeros((cfg.vocab, d), bf),
+            "out_norm": jnp.zeros((d,), bf),
+            "lm_head": kimi_linear._quantize_t(jnp.zeros((cfg.vocab, d), bf)),
+            "blocks": {str(i): kimi_linear.load_layer(get, cfg, i)
+                       for i in range(cfg.layers)},
+        }
+
+    pools = jax.eval_shape(
+        lambda: kimi_linear.init_page_pool(cfg, KL_PAGES, PAGE))
+    state = jax.eval_shape(lambda: kimi_linear.init_slot_state(cfg, KL_SLOTS))
+    stats = jax.eval_shape(lambda: kimi_linear.init_counters(cfg))
+    return kimi_linear, cfg, jax.eval_shape(build), pools, state, stats
+
+
+def _kimi_linear_cache_copies(compiled) -> list[str]:
+    """``copy`` instructions of a whole pool leaf or a whole delta-rule
+    state, by shape."""
+    shapes = (f"bf16[{KL_PAGES},16,640]", "f32[64,32,128,128]")
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " copy(" in line
+            and any(s in line.split(" copy(")[0] for s in shapes)]
+
+
+def test_kimi_linear_window_program_compiles_at_64_slots(chip):
+    """The K=8 decode window at Kimi-Linear-48B-A3B's widths with 64 SLOTS
+    of 16,384 rows over every slot's pages (2.68 GB a latent layer's pool
+    at 640 stored values a row): the 12,672-wide fused input matrix of a
+    KDA layer and the 6,784-wide one of the latent layer through
+    ``int8_matmul`` at M = 64, the state through ``kda_state_step`` (one
+    call a KDA layer a tick, 64 rows of 2 MB), the latent rows through
+    ``attend_latent_blocks`` 512 at a time, the 64 held experts in two
+    grouped products a layer (64 rows go whole), ``lm_head_argmax`` over
+    40,960 columns. Neither the pool nor a state is copied."""
+    kl, cfg, params, pools, state, stats = _kimi_linear()
+    assert set(pools) == {"3"} and set(state) == {"0", "1", "2", "4"}
+    assert pools["3"]["kv"].shape == (KL_PAGES, PAGE, 640)
+    assert state["0"]["s"].shape == (64, 32, 128, 128)
+    assert state["0"]["conv"].shape == (64, 3, 12288)
+    assert params["blocks"]["0"]["w_in"]["int8"].shape == (2304, 12672)
+    assert params["blocks"]["3"]["w_in"]["int8"].shape == (2304, 6784)
+    assert params["blocks"]["1"]["experts"]["w_gateup"]["int8"].shape == (
+        64, 2304, 2048)
+
+    def program(p, *args):
+        return kl.window_program(p, cfg, 8, None, 512, *args)
+
+    compiled = jax.jit(program, donate_argnums=(2, 3, 9)).lower(
+        chip(params),
+        *chip((_s((KL_SLOTS,), I32), pools, stats, _s((KL_SLOTS,), I32),
+               _s((KL_SLOTS, KL_SEQ // PAGE), I32), _s((KL_SLOTS,), jnp.bool_),
+               _s((KL_SLOTS,), I32), _s((KL_SLOTS,), I32), state)),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_state_step\S* = ", text)) == 4  # a KDA layer
+    assert _kimi_linear_cache_copies(compiled) == []
+    assert _expert_stack_readers(
+        compiled, params["blocks"]["1"]["experts"]) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_kimi_linear_chunk_program_compiles_at_64_slots(chip):
+    """The 256-row prefill chunk beside 64 slots' state: the blocked delta
+    rule in 16 blocks of 16 rows from the slot's row of ``[64, 32, 128,
+    128]``, the latent layer's block loop over the cached rows, every held
+    expert its own rows 32 at a time."""
+    kl, cfg, params, pools, state, stats = _kimi_linear()
+
+    def step(p, ids, pools, stats, position, bt, state, valid, slot):
+        return kl.fused_paged_chunk_step(
+            p, cfg, ids, pools, state, stats, position, bt, valid, slot,
+            block=512)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3, 6)).lower(
+        chip(params),
+        *chip((_s((CHUNK,), I32), pools, stats, _s((), I32),
+               _s((KL_SEQ // PAGE,), I32), state, _s((), I32),
+               _s((), I32))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _kimi_linear_cache_copies(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -22,9 +22,9 @@ from dora_tpu.models import paged_model as PM
 HF = Path(PM.__file__).parent / "hf"
 #: every module under models/hf/ but the package file
 FILES = sorted(p.stem for p in HF.glob("*.py") if p.stem != "__init__")
-#: the eight files whose engines ``build_engine`` builds
+#: the nine files whose engines ``build_engine`` builds
 BUILT = ("kimi_k2", "falcon_h1", "ouro", "exaone_moe", "glm5_next", "keye_vl2",
-         "zaya", "olmo_hybrid")
+         "zaya", "olmo_hybrid", "kimi_linear")
 #: a tower over a whole text model sits ABOVE that model's file (the
 #: frames tier, ROADMAP debt 2); nothing else may look sideways
 WRAPS = {"internvl": {"qwen2"}, "qwen2_vl": {"qwen2"}}

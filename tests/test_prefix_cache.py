@@ -280,7 +280,7 @@ def test_admission_counts_evictable_pages_only_when_the_free_list_is_short(
     eng.free_pages = a.free_pages
     ids = list(range(1, 17)) + [99, 98]  # 4 cached pages + a tail
     assert eng.can_admit(len(ids), 2)  # needs 5 pages
-    base, shared, _node = eng._prefix_grant(ids, 2)
+    base, shared, _node, _branch = eng._prefix_grant(ids, 2)
     assert (base, len(shared)) == (16, 4)
     assert bool(walks) == (not free_enough)
     a.unref(shared)

@@ -12,7 +12,11 @@ Olmo-Hybrid (tests/olmo_hybrid_tiny.py: page 8, chunk 32, 3 slots).
     through ``save_pools`` / ``restore_state``;
 (d) a chunk that went ``ahead()`` still saves the right state;
 (e) the four other slot-state models' engines still refuse a prefix cache,
-    each by the message that names the snapshot.
+    each by the message that names the snapshot;
+(f) the second snapshot, where a prompt branches: two prompts that share
+    2.5 chunks and then differ leave a row at the last chunk edge inside
+    what they share, a third is granted to it; a session-shaped pair saves
+    none; the rows are counted and evicted as the others are.
 """
 
 from __future__ import annotations
@@ -163,9 +167,10 @@ def test_a_grant_lands_on_a_snapshots_depth_and_never_between(model):
     assert cache.hits == hits
     run(engine, "c")
     engine.check_invariants()
-    # the longer prompt left a deeper snapshot on the same path
+    # the longer prompt left a deeper snapshot on the same path, and "c",
+    # which left the tree's path after 40 rows, one at its branch edge (32)
     assert [d for d, _ in cache.snapshots_on_path(
-        first + prompt_ids(30, seed=8))] == [64, 96]
+        first + prompt_ids(30, seed=8))] == [32, 64, 96]
 
 
 def test_a_prompt_under_one_chunk_saves_none_and_is_granted_none(model):
@@ -401,3 +406,105 @@ def test_the_other_slot_state_models_still_refuse_a_prefix_cache(
     with pytest.raises(NotImplementedError,
                        match="unless its model keeps state snapshots"):
         module.make_paged_engine(params, cfg, **sizes)
+
+
+# -- (f) the second snapshot: where a prompt branches ---------------------------------
+
+
+def branching(n: int, shared: int, tail: int, seed: int):
+    """``n`` prompts that share their first ``shared`` rows (a system
+    prompt) and differ after them (``tail`` rows each)."""
+    prefix = prompt_ids(shared, seed=seed)
+    return [prefix + prompt_ids(tail, seed=seed + 1 + i) for i in range(n)]
+
+
+def test_f_a_prompt_that_branches_saves_at_the_edge_and_the_third_is_granted(model):
+    cfg, params, _ = model
+    # 80 shared rows = 2.5 chunks = 10 pages; tails of 21: 101 rows, whose
+    # last full chunk ends at 96, past the shared part
+    prompts = branching(4, shared=80, tail=21, seed=40)
+    off = make_engine(cfg, params, prefix_cache=False)
+    want = serve_all(off, prompts)
+    on = make_engine(cfg, params, prefix_cache=True)
+    cache = on.prefix_cache
+    on.submit("t0", prompts[0], 5)
+    slot = next(s for s in on.slots if s is not None)
+    assert slot.chunk_base == 0 and slot.branch_edge == 0  # an empty tree
+    got = [run(on, "t0")]
+    assert on.snapshots_branch_saved == 0
+    assert [d for d, _ in cache.snapshots_on_path(prompts[0])] == [96]
+    # the second matches 80 rows, none of them under a snapshot: it starts
+    # at row 0 and saves where it leaves the tree, at the chunk edge 64
+    on.submit("t1", prompts[1], 5)
+    slot = next(s for s in on.slots if s is not None)
+    assert slot.chunk_base == 0 and slot.branch_edge == 64
+    assert slot.snap_from is None
+    got.append(run(on, "t1"))
+    on.check_invariants()
+    assert on.snapshots_branch_saved == 1 and on.snapshots_restored == 0
+    assert [d for d, _ in cache.snapshots_on_path(prompts[1])] == [64, 96]
+    # the row holds the state after the shared prompt's first 64 rows
+    row = cache.snapshots_on_path(prompts[1])[0][1].snap
+    served = Served(cfg, params, dirty=False)
+    served.prefill(0, prompts[1][:64])
+    for key, leaves in served.state.items():
+        for name, leaf in leaves.items():
+            assert (np.asarray(on.snapshot_pool[key][name][row])
+                    == np.asarray(leaf[0])).all(), (key, name)
+    # the third and the fourth are granted to it: one chunk and a page short
+    # of the shared depth at most, and no further branch row (64 is taken)
+    for n in (2, 3):
+        chunks = on.chunks_run
+        on.submit(f"t{n}", prompts[n], 5)
+        slot = next(s for s in on.slots if s is not None)
+        assert slot.chunk_base == 64 and slot.shared == 64 // PAGE
+        assert slot.branch_edge == 0
+        got.append(run(on, f"t{n}"))
+        assert on.chunks_run - chunks == 2  # rows 64..100, not 0..100
+        on.check_invariants()
+    assert got == want
+    assert on.snapshots_branch_saved == 1 and on.snapshots_restored == 2
+    stats = on.model_counters()
+    assert stats["state_snapshots_branch_saved"] == 1
+    assert stats["state_snapshots_saved"] == on.snapshots_saved == 5
+
+
+def test_f_a_session_shaped_pair_saves_no_branch_row(model):
+    """A turn that resends its history matches down to the last turn's
+    snapshot and no further (the pages past it were never inserted): no
+    chunk edge lies between the grant and the match."""
+    cfg, params, _ = model
+    engine = make_engine(cfg, params, prefix_cache=True)
+    serve_all(engine, conversation(5, first=75, seed=41))
+    assert engine.snapshots_restored == 4
+    assert engine.snapshots_branch_saved == 0
+    assert engine.model_counters()["state_snapshots_branch_saved"] == 0
+
+
+def test_f_branch_rows_are_counted_and_evicted_as_the_others(model):
+    cfg, params, _ = model
+    engine = make_engine(cfg, params, prefix_cache=True, state_snapshots=3)
+    cache = engine.prefix_cache
+    # four system prompts, three requests each: every second request saves
+    # two rows (its branch edge, its last full chunk) into a pool of three
+    for group in range(4):
+        prompts = branching(3, shared=80, tail=21, seed=50 + 10 * group)
+        serve_all(engine, prompts, max_new=2, prefix=f"g{group}_")
+        engine.check_invariants()
+        assert [d for d, _ in cache.snapshots_on_path(prompts[2])][:1] == [64]
+    assert engine.snapshots_branch_saved == 4
+    assert cache.snapshots_evicted > 0
+    assert cache.snapshots_held + cache.snapshots_free == 3
+    # a stream preempted between its branch edge and its final chunk gives
+    # the row back
+    first, second = branching(2, shared=80, tail=21, seed=90)
+    serve_all(engine, [first], max_new=2, prefix="p")
+    engine.submit("q", second, 2)
+    for _ in range(3):  # chunks 0, 32, 64: the branch row is the stream's
+        engine.step()
+    slot = next(s for s in engine.slots if s is not None)
+    assert slot.snap_branch is not None and slot.snap_branch[0] == 64
+    engine.check_invariants()
+    engine.preempt("q")
+    engine.check_invariants()
+    assert cache.snapshots_held + cache.snapshots_free == 3
