@@ -191,6 +191,7 @@ class ServingMetrics:
         "prefix_hits", "prefix_misses", "prefix_hit_tokens",
         "prefix_cached_pages", "prefix_shared_pages",
         "prefix_cow_copies", "prefix_evictions",
+        "prefix_evict_calls", "prefix_evict_visits",
         "device_compute_ns", "host_dispatch_ns", "device_fetch_ns",
         "dispatched_flops", "useful_flops",
         "hbm_used_bytes", "hbm_limit_bytes", "hbm_peak_bytes",
@@ -334,6 +335,11 @@ class ServingMetrics:
         self.prefix_shared_pages = 0
         self.prefix_cow_copies = 0
         self.prefix_evictions = 0
+        #: ``PrefixCache.evict`` calls that freed a page and the nodes
+        #: they looked at: visits over evictions is what a freed page
+        #: costs the admission that waits for it
+        self.prefix_evict_calls = 0
+        self.prefix_evict_visits = 0
         #: device utilization plane (dora_tpu.profiling, DORA_DEVICE_MONITOR):
         #: cumulative window/chunk wall time attributed by the engine to
         #: host dispatch vs device compute vs the device->host fetch (ns
@@ -483,6 +489,8 @@ class ServingMetrics:
             "prefix_shared_pages": self.prefix_shared_pages,
             "prefix_cow_copies": self.prefix_cow_copies,
             "prefix_evictions": self.prefix_evictions,
+            "prefix_evict_calls": self.prefix_evict_calls,
+            "prefix_evict_visits": self.prefix_evict_visits,
             "device_compute_ns": self.device_compute_ns,
             "host_dispatch_ns": self.host_dispatch_ns,
             "device_fetch_ns": self.device_fetch_ns,

@@ -73,6 +73,7 @@ below it is of no use to such an engine and goes with the row.
 from __future__ import annotations
 
 import hashlib
+import heapq
 import itertools
 
 
@@ -155,6 +156,11 @@ class PrefixCache:
         self.hit_tokens = 0
         self.inserted_pages = 0
         self.evicted_pages = 0
+        #: ``evict`` calls that freed a page, and the nodes they looked
+        #: at (walked or popped): visits a page freed is what eviction
+        #: costs the admission that asked for it
+        self.evict_calls = 0
+        self.evict_visits = 0
         #: boundary pages re-materialized privately because the
         #: divergence point fell inside a cached page (mid-page
         #: divergence, or a fully-cached prompt re-running its final
@@ -304,29 +310,44 @@ class PrefixCache:
         """Free up to ``need`` pages, least-recently-used leaves first
         (a parent becomes a leaf once its children are gone, so cold
         branches unwind bottom-up). Skips pinned nodes and pages still
-        shared with live streams. Returns pages actually freed."""
+        shared with live streams. Returns pages actually freed.
+
+        ONE walk finds every leaf that may go and puts it on a heap by
+        its stamp; a pop drops the oldest and, where that leaves its
+        parent a leaf that may go too, pushes the parent: nodes + need x
+        log nodes, where a walk a page was need x nodes (0.7 s for 600
+        pages of 8,000 under a full pool). The pages and their order
+        are those of the walk a page. Ties: the stamp, then the place
+        in the walk (``_nodes``' order, which a dropped leaf does not
+        change for the nodes that stay). Stamps come from one clock and
+        a touch stamps one path from its root, so in a tree that only
+        ``lookup`` and ``insert`` stamped no two leaves tie. Nothing is
+        kept between calls: refcounts change outside the cache."""
+        if need <= 0:
+            return 0
+        refcount = self.allocator.refcount
+
+        def may_go(n: _Node) -> bool:
+            return not n.children and not n.pins and refcount(n.page) == 1
+
+        place: dict[_Node, int] = {}
+        heap = []
+        for at, n in enumerate(self._nodes()):
+            place[n] = at
+            if may_go(n):
+                heap.append((n.last_used, at, n))
+        heapq.heapify(heap)
         freed = 0
-        while freed < need:
-            best: _Node | None = None
-            stack = [
-                c
-                for root in self._roots.values()
-                for c in root.children.values()
-            ]
-            while stack:
-                n = stack.pop()
-                stack.extend(n.children.values())
-                if n.children or n.pins:
-                    continue
-                if self.allocator.refcount(n.page) != 1:
-                    continue
-                if best is None or n.last_used < best.last_used:
-                    best = n
-            if best is None:
-                break
-            self._drop(best)
+        while heap and freed < need:
+            node = heapq.heappop(heap)[2]
+            parent = node.parent
+            self._drop(node)
             freed += 1
+            if parent.parent is not None and may_go(parent):
+                heapq.heappush(heap, (parent.last_used, place[parent], parent))
         self.evicted_pages += freed
+        self.evict_calls += bool(freed)
+        self.evict_visits += len(place) + freed
         return freed
 
     def _drop(self, node: _Node) -> None:

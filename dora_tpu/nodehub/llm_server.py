@@ -1315,6 +1315,8 @@ def serve(node, engine, metrics, *, encode, decode_one, eos=None,
             metrics.prefix_shared_pages = engine.shared_pages
             metrics.prefix_cow_copies = pc.cow_copies
             metrics.prefix_evictions = pc.evicted_pages
+            metrics.prefix_evict_calls = pc.evict_calls
+            metrics.prefix_evict_visits = pc.evict_visits
         metrics.kv_dtype = engine.kv_dtype
         metrics.kv_pool_bytes = engine.kv_pool_bytes()
         metrics.kv_quant_err = engine.kv_quant_error()
@@ -1865,6 +1867,10 @@ def main() -> None:
             "prefill_chunks": metrics.prefill_chunks,
             "chunks_ahead": metrics.chunks_ahead,
             "chunks_ahead_share": metrics.chunks_ahead_share(),
+            # what eviction cost admission: nodes looked at a page freed
+            "prefix_evictions": metrics.prefix_evictions,
+            "prefix_evict_calls": metrics.prefix_evict_calls,
+            "prefix_evict_visits": metrics.prefix_evict_visits,
             **metrics.model,
         })
 

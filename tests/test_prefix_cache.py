@@ -303,6 +303,219 @@ def test_radix_max_pages_cap_evicts_on_insert():
 
 
 # ---------------------------------------------------------------------------
+# evict: one walk and a heap, against the walk a page it replaced
+# ---------------------------------------------------------------------------
+
+
+def _evict_a_walk_a_page(c, need: int) -> int:
+    """``PrefixCache.evict`` as it stood before the heap, the plain
+    reference: every page freed costs a walk of every node of every
+    tenant's tree to find the least recently used leaf that may go."""
+    freed = 0
+    while freed < need:
+        best = None
+        stack = [
+            n for root in c._roots.values() for n in root.children.values()
+        ]
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            if n.children or n.pins:
+                continue
+            if c.allocator.refcount(n.page) != 1:
+                continue
+            if best is None or n.last_used < best.last_used:
+                best = n
+        if best is None:
+            break
+        c._drop(best)
+        freed += 1
+    c.evicted_pages += freed
+    return freed
+
+
+def _random_forest(seed: int, ties: bool):
+    """A cache as traffic leaves it, the same for the same seed: three
+    tenants, prompts that branch off one another at a random page, one
+    chain deeper than the recursion limit with a branch off its middle,
+    pinned paths, pages a "live stream" still holds, nodes with snapshot
+    rows, lookups that restamp paths. ``ties`` then writes a few stamps
+    over many nodes, as no clock would: the tie rule's case. Returns the
+    allocator, the cache and what a later step lets go of."""
+    import random
+    import sys
+
+    rng = random.Random(seed)
+    ps = 2
+    a, c = _cache(num_pages=4096, page_size=ps, snapshots=64)
+    tenants = [None, "ann", "bob"]
+    prompts: list[tuple[str | None, list[int]]] = []
+
+    def adopt(adapter, ids):
+        pages = a.alloc(len(ids) // ps)
+        c.insert(ids, pages, adapter)
+        a.unref(pages)  # the stream ends: what the cache took stays
+        prompts.append((adapter, ids))
+
+    deep = (sys.getrecursionlimit() + 40) * ps
+    chain = [rng.randrange(1, 50) for _ in range(deep)]
+    adopt("ann", chain)
+    adopt("ann", chain[: deep // 2] + [77] * 12)
+    for _ in range(60):
+        adapter = rng.choice(tenants)
+        own = [ids for t, ids in prompts if t == adapter and len(ids) < 200]
+        head = []
+        if own and rng.random() < 0.7:
+            stem = rng.choice(own)
+            head = stem[: rng.randrange(0, len(stem) // ps + 1) * ps]
+        tail = [rng.randrange(1, 6) for _ in range(rng.randrange(1, 9) * ps)]
+        adopt(adapter, head + tail)
+        if rng.random() < 0.5:
+            t, ids = rng.choice(prompts)
+            c.lookup(ids[: rng.randrange(1, len(ids) + 1)], t)
+    pinned = rng.sample(prompts, 6)
+    for t, ids in pinned:
+        c.pin(ids, t)
+    live = []
+    for t, ids in rng.sample(prompts, 8):
+        pages = c.lookup(ids, t)[1]
+        held = pages[: rng.randrange(1, len(pages) + 1)]
+        a.ref(held)
+        live.append(held)
+    for t, ids in rng.sample(prompts, 20):
+        row = c.snapshot_take()
+        depth = rng.randrange(1, len(ids) // ps + 1) * ps
+        if not c.snapshot_attach(ids, depth, row, t):
+            c.snapshot_give_back(row)
+    if ties:
+        for n in c._nodes():
+            if rng.random() < 0.6:
+                n.last_used = rng.randrange(1, 6)
+    return a, c, (pinned, live)
+
+
+def _evict_and_note(a, c, evict, need: int) -> tuple[int, list[int]]:
+    """What ``evict(need)`` returns and the pages it let go, in order."""
+    gone: list[int] = []
+    unref = a.unref
+    a.unref = lambda pages: (gone.extend(pages), unref(pages))[1]
+    try:
+        return evict(need), gone
+    finally:
+        del a.unref
+
+
+def _custody(a, c) -> dict:
+    return {
+        "size": c.size, "evicted_pages": c.evicted_pages,
+        "snapshots_evicted": c.snapshots_evicted,
+        "snapshots_free": list(c._snap_free),
+        "snapshot_rows": sorted(c.snapshot_rows()),
+        "refcounts": dict(a._ref), "free_list": list(a._free),
+        "cached": sorted(c.pages()), "evictable": c.evictable_pages(),
+    }
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["clock", "ties"])
+@pytest.mark.parametrize("need", [1, 7, 60, 400, 10_000])
+@pytest.mark.parametrize("seed", [3, 11, 29])
+def test_evict_frees_the_pages_the_walk_a_page_freed_in_its_order(
+    seed, need, ties,
+):
+    """Page for page: the heap pops what the repeated walk picked, under
+    pins, shared pages, snapshot rows, several tenants and a chain deeper
+    than the recursion limit; with stamps that tie, the first in the
+    walk's order goes first. A second call, after a pinned path and a
+    live stream let go, finds the same pages again: nothing was kept."""
+    a, c, (pinned, live) = _random_forest(seed, ties)
+    ra, rc, (rpinned, rlive) = _random_forest(seed, ties)
+    assert _custody(a, c) == _custody(ra, rc) and c.size > 1_200
+    for _ in range(2):
+        got = _evict_and_note(a, c, c.evict, need)
+        want = _evict_and_note(
+            ra, rc, lambda k: _evict_a_walk_a_page(rc, k), need)
+        assert got == want
+        assert got[0] == len(got[1]) <= need
+        assert _custody(a, c) == _custody(ra, rc)
+        for cache, alloc, pins, held in (
+            (c, a, pinned, live), (rc, ra, rpinned, rlive),
+        ):
+            for t, ids in pins[::2]:
+                cache.unpin(ids, t)
+            for pages in held[::2]:
+                alloc.unref(pages)
+            del pins[::2], held[::2]
+    a.check_invariants()
+    assert set(c.pages()) <= set(a._ref)
+
+
+def _chains_and_branches(nodes: int, branches: int):
+    """A trunk with ``branches`` chains off its end, ``nodes`` in all,
+    page size 1, every page in the cache's custody alone."""
+    # twice the nodes: a later arm's pages for the trunk go home again
+    a, c = _cache(num_pages=2 * nodes, page_size=1)
+    trunk = list(range(1, nodes // (branches + 1) + 1))
+    arm = (nodes - len(trunk)) // branches
+    for b in range(branches):
+        ids = trunk + [1_000_000 * (b + 1) + i for i in range(arm)]
+        pages = a.alloc(len(ids))
+        c.insert(ids, pages)
+        a.unref(pages)
+    assert c.size == len(trunk) + branches * arm
+    return a, c
+
+
+@pytest.mark.parametrize("case", ["600_of_8000", "need_0", "empty_tree",
+                                  "every_leaf_pinned"])
+def test_evict_visits_a_node_once_whatever_it_frees(case):
+    """The count, no clock: ``evict_visits`` is the nodes the call walked
+    plus the leaves it popped. The walk a page read ``need`` x nodes."""
+    a, c = _chains_and_branches(8_000 if case == "600_of_8000" else 90, 15)
+    nodes = c.size
+    if case == "600_of_8000":
+        assert c.evict(600) == 600
+        assert c.evict_calls == 1
+        assert nodes + 600 <= c.evict_visits <= 3 * nodes
+        assert c.evict_visits / c.evicted_pages < 20
+        return
+    if case == "empty_tree":
+        assert c.flush() == nodes and c.size == 0
+        nodes = 0
+    if case == "every_leaf_pinned":
+        for b in range(15):
+            c.pin(list(range(1, 6)) + [1_000_000 * (b + 1) + i for i in range(5)])
+    calls, visits, freed = c.evict_calls, c.evict_visits, c.evicted_pages
+    assert c.evict(0 if case == "need_0" else 600) == 0
+    assert c.evict_calls == calls and c.evicted_pages == freed
+    assert c.evict_visits - visits <= (0 if case == "need_0" else nodes)
+    assert c.size == nodes
+    a.check_invariants()
+
+
+def test_the_serving_snapshot_carries_what_eviction_cost():
+    """``prefix_evict_calls`` and ``prefix_evict_visits`` beside
+    ``prefix_evictions``, from the cache's own counts at the loop's last
+    report: a pool of 8 pages under prompts that never repeat."""
+    from dora_tpu.metrics import ServingMetrics
+    from tests.test_serving_trace import _serve_once
+
+    assert ServingMetrics().snapshot()["prefix_evict_visits"] == 0
+    eng = _stub(num_pages=9, max_slots=1, prefix_cache=True)
+    metrics = ServingMetrics()
+    events = [
+        {"type": "INPUT", "value": (chr(65 + i) * 40).encode(),
+         "metadata": {"request_id": f"r{i}", "max_new_tokens": 4}}
+        for i in range(4)
+    ]
+    _serve_once(eng, metrics, events)
+    snap, pc = metrics.snapshot(), eng.prefix_cache
+    assert snap["prefix_evictions"] == pc.evicted_pages >= 4
+    assert snap["prefix_evict_calls"] == pc.evict_calls >= 2
+    assert snap["prefix_evict_visits"] == pc.evict_visits >= pc.evicted_pages
+    eng.check_invariants()
+
+
+# ---------------------------------------------------------------------------
 # stub-engine scheduler: sharing, COW, eviction, backoff
 # ---------------------------------------------------------------------------
 
